@@ -299,8 +299,8 @@ class Trainer:
                 all(isinstance(a, (str, type(None))) for a in x)))
         sp_on = AXIS_SEQUENCE in manual
         batch_spec = P(None, AXIS_SEQUENCE) if sp_on else P()
-        from autodist_tpu.parallel.axes import shard_map_compat
-        mapped = shard_map_compat(
+        from autodist_tpu.parallel.axes import shard_map
+        mapped = shard_map(
             per_token, self.mesh,
             (param_specs, batch_spec),
             (P(None, AXIS_SEQUENCE) if sp_on else P(), P()),

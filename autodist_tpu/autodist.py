@@ -47,18 +47,23 @@ def get_default_autodist():
     return _DEFAULT_AUTODIST.get(os.getpid(), None)
 
 
-def _default_resource_info():
-    """Single-node spec from the locally visible jax devices."""
+def _default_resource_info(devices=None):
+    """Single-node spec over ``devices`` (default: the locally visible
+    jax devices). Device entries are ORDINALS (what the DeviceResolver
+    indexes), not jax device ids; a real accelerator names its
+    ``device_kind`` so the topology takes that kind's row (or fails on
+    an unknown one)."""
     import jax
-    devs = jax.local_devices()
-    accel = [d.id for d in devs if d.platform not in ('cpu',)]
+    devs = list(devices) if devices is not None else jax.local_devices()
     node = {'address': 'localhost', 'chief': True, 'cpus': [0],
             'network_bandwidth': 100}
-    if accel:
-        node['tpus'] = accel
-    else:
+    info = {'nodes': [node]}
+    if devs[0].platform == 'cpu':
         node['gpus'] = list(range(len(devs)))  # virtual CPU devices
-    return {'nodes': [node]}
+    else:
+        node['tpus'] = list(range(len(devs)))
+        info['topology'] = {'device_kind': devs[0].device_kind}
+    return info
 
 
 class AutoDist:

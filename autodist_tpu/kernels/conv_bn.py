@@ -23,8 +23,9 @@ MXU-shaped, so the custom kernel is only needed where XLA could not
 fuse: the forward's stats+normalize traffic.
 
 Like kernels/flash_attention.py, the same kernel runs in Pallas
-interpret mode on non-TPU backends so the CPU test mesh exercises the
-identical code path.
+interpret mode on the CPU backend only, so the CPU test mesh exercises
+the identical code path; any other backend compiles it
+(``chip_smoke.py`` compiles it on the chip and compares with plain jnp).
 """
 import functools
 
@@ -33,12 +34,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from autodist_tpu.kernels.pallas_compat import \
-    CompilerParams as _CompilerParams
 
 
 def _interpret_default():
-    return jax.default_backend() != 'tpu'
+    return jax.default_backend() == 'cpu'
 
 
 def supports(n_rows, c_in, c_out, block_n=None):
@@ -129,7 +128,7 @@ def _fwd_call(x2d, w, a, b, prologue_relu, want_stats, out_dtype,
             jax.ShapeDtypeStruct((1, c_out), jnp.float32),
             jax.ShapeDtypeStruct((1, c_out), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary', 'arbitrary')),
         interpret=interpret,
     )(x2d, w.astype(x2d.dtype), a.reshape(1, c_in).astype(jnp.float32),

@@ -15,8 +15,9 @@ batch/heads/q-blocks are parallel. Causal masking is by global position,
 and fully-masked tiles are skipped with predication (the classic ~2x
 saving on causal attention).
 
-On non-TPU backends the same kernels run in Pallas interpret mode, so the
-CPU test mesh exercises the identical code path (tests/test_flash_attention.py).
+On the CPU backend the same kernels run in Pallas interpret mode, so the
+CPU test mesh exercises the identical code path (tests/test_flash_attention.py);
+every other backend compiles them.
 """
 import functools
 
@@ -24,9 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from autodist_tpu.kernels.pallas_compat import \
-    CompilerParams as _CompilerParams
 
 NEG_INF = -1e30   # same masking constant as parallel/ring_attention.py
 _LANES = 128      # TPU lane width: m/l scratch replicate across lanes
@@ -75,8 +73,7 @@ def preferred(shape):
 
 
 def _interpret_default():
-    return jax.default_backend() != 'tpu'
-
+    return jax.default_backend() == 'cpu'
 
 
 def _causal_mask(s, qi, ki, bq, bk):
@@ -168,7 +165,7 @@ def _fwd(q, k, v, causal, sm_scale, bq, bk, interpret):
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
@@ -292,7 +289,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk, interpret):
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
@@ -322,7 +319,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
@@ -360,8 +357,9 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     Differentiable (custom VJP, flash backward). Requires ``seq`` to
     split into uniform blocks (``supports()``); callers fall back to the
     jnp path otherwise. Block sizes default to a measured seq-dependent
-    heuristic. ``interpret`` defaults to True off-TPU so the same kernel
-    code runs on the CPU test mesh.
+    heuristic. ``interpret`` defaults to True on the CPU backend only
+    (so the same kernel code runs on the CPU test mesh); any other
+    backend compiles the kernel or fails.
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5

@@ -64,9 +64,9 @@ class MultiHeadAttention(Module):
             o = fa.flash_attention(q, k, v, causal=self.causal)
         elif self._tp_manual_shape(q.shape) is not None:
             # dp/tp GSPMD mesh at long seq: attention is independent per
-            # (batch, head), so hop into a nested manual region over the
-            # data+model axes and run the flash kernel on local shards —
-            # GSPMD alone cannot partition an opaque pallas_call.
+            # (batch, head), so hop into a nested manual region and run
+            # the flash kernel on local shards — GSPMD alone cannot
+            # partition an opaque pallas_call.
             o = self._tp_manual_flash(q, k, v)
         else:
             o = local_flash_attention(q, k, v, causal=self.causal)
@@ -82,9 +82,8 @@ class MultiHeadAttention(Module):
         with a live data and/or heads axis, batch/head dims divisible,
         and the per-shard shape past the kernel crossover. Mesh axes
         OTHER than data/heads (pipe, seq, expert) may be live: attention
-        inputs are not sharded over them, so the nested region simply
-        leaves them untouched (round-2 fix — they used to drop long-seq
-        attention to the jnp path silently)."""
+        inputs are not sharded over them, so they must not drop
+        long-seq attention to the jnp path."""
         if active_manual_axes():
             return None
         mesh = current_mesh()
@@ -99,15 +98,19 @@ class MultiHeadAttention(Module):
         return local if fa.preferred(local) else None
 
     def _tp_manual_flash(self, q, k, v):
+        """Flash kernel on local (batch, head) shards. The region is
+        manual over EVERY mesh axis, size-1 ones included: Mosaic
+        refuses to lower a kernel while any axis of the mesh is still
+        automatic (found on the first four-chip run — interpret mode on
+        the CPU mesh never goes through that check). Axes the spec does
+        not name see replicated operands, which is what attention
+        inputs are over pipe/seq/expert."""
         mesh = current_mesh()
-        heads_axis = live_mesh_axis('heads')
         spec = P(AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None,
-                 heads_axis)
-        names = {a for a in (AXIS_DATA, heads_axis)
-                 if a and mesh.shape.get(a, 1) > 1}
-        from autodist_tpu.parallel.axes import shard_map_compat
-        fn = shard_map_compat(
+                 live_mesh_axis('heads'))
+        from autodist_tpu.parallel.axes import shard_map
+        fn = shard_map(
             lambda q, k, v: fa.flash_attention(q, k, v,
                                                causal=self.causal),
-            mesh, (spec,) * 3, spec, axis_names=names)
+            mesh, (spec,) * 3, spec)
         return fn(q, k, v)

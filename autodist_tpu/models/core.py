@@ -44,24 +44,18 @@ def sharded_embedding_lookup(table, ids, axis):
 
     if manual_axis(axis):
         return masked(table, ids)
-    try:
-        in_auto_ctx = bool(jax.sharding.get_abstract_mesh().shape)
-        partial_manual = hasattr(jax, 'shard_map')
-    except AttributeError:   # older jax: no mesh-context introspection
-        in_auto_ctx, partial_manual = False, False
-    if in_auto_ctx or not partial_manual:
+    if jax.sharding.get_abstract_mesh().shape:
         # already inside a manual region where the vocab axis stays auto
-        # (shardy rejects a nested shard_map re-entering those axes), or
-        # a jax without partial-manual shard_map: fall back to the
-        # one-hot matmul (partitions cleanly under GSPMD and runs on
-        # the MXU).
+        # (shardy rejects a nested shard_map re-entering those axes):
+        # fall back to the one-hot matmul (partitions cleanly under
+        # GSPMD and runs on the MXU).
         vocab = table.shape[0]
         oh = jax.nn.one_hot(ids, vocab, dtype=table.dtype)
         return oh @ table
     from jax.sharding import PartitionSpec as P
 
-    from autodist_tpu.parallel.axes import shard_map_compat
-    return shard_map_compat(
+    from autodist_tpu.parallel.axes import shard_map
+    return shard_map(
         masked, current_mesh(), (P(axis), P()), P(),
         axis_names={axis})(table, ids)
 

@@ -222,59 +222,23 @@ class sharding_ctx:
          _CTX.options) = self._old
 
 
-def axis_size(axis_name):
-    """``lax.axis_size`` across jax versions: older jax has no such
-    helper, but ``psum(1, axis)`` constant-folds to the axis size."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return int(jax.lax.psum(1, axis_name))
+def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
+    """``jax.shard_map`` as this package uses it: replication checking
+    off (these regions mix manual collectives with replicated outputs)
+    and ``axis_names`` naming the manual subset of the mesh axes (None =
+    all of them, a fully manual region).
 
-
-def supports_partial_manual():
-    """True when this jax can lower partial-manual shard_map regions
-    (jax>=0.6 ``jax.shard_map`` with ``axis_names=``). Old jax's
-    partial-auto spelling crashes in lowering, so
-    :func:`shard_map_compat` refuses it up front — tests gate the
-    nested-manual kernel-dispatch paths on this probe."""
-    return hasattr(jax, 'shard_map')
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None):
-    """Partial-manual shard_map across jax spellings.
-
-    jax>=0.6 exposes ``jax.shard_map`` with ``axis_names=`` (the manual
-    set) and ``check_vma``; older jax spells the manual set as its
-    complement ``auto=`` on ``jax.experimental.shard_map.shard_map``
-    and the flag ``check_rep``. Replication checking is off either way
-    (these regions mix manual collectives with replicated outputs).
-    """
-    import jax as _jax
-    if hasattr(_jax, 'shard_map'):
-        kw = {}
-        if axis_names is not None:
-            kw['axis_names'] = set(axis_names)
-        try:
-            return _jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False,
-                                  **kw)
-        except TypeError:   # pragma: no cover - intermediate jax
-            return _jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_rep=False,
-                                  **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    if axis_names is not None:
-        # old jax's partial-auto shard_map (auto=) lowers these regions
-        # to PartitionId crashes — often after a multi-minute doomed
-        # compile. Refuse up front with an actionable error instead:
-        # the functional partial-manual paths need jax>=0.6.
-        raise NotImplementedError(
-            'partial-manual shard_map over %s needs jax>=0.6 '
-            '(jax.shard_map axis_names=); this jax has only the '
-            'experimental fully-manual shard_map'
-            % sorted(axis_names))
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    A partial region also takes the mesh's size-1 axes along as manual,
+    which is a no-op for the math: Mosaic refuses to lower a Pallas
+    kernel while ANY mesh axis is still automatic, so a region whose
+    named axes are all the size>1 ones must not leave the idle ones
+    auto."""
+    names = set(axis_names or ())
+    if names:
+        names |= {a for a, n in mesh.shape.items() if n == 1}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=names,
+                         check_vma=False)
 
 
 def manual_axis(mesh_axis):

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 
 from autodist_tpu.const import AXIS_DATA
-from autodist_tpu.parallel.axes import axis_size
 
 _REGISTRY = {}
 
@@ -157,7 +156,7 @@ def int8_ring_all_reduce(x, axis_name, block=None):
     bound an outlier's quantization damage to its own block; callers
     carry an error-feedback residual for unbiasedness.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     block = block or quant_block_size()
@@ -326,7 +325,7 @@ class Int8RingCompressor(Compressor):
         compensated = grad + residual
         transmitted = block_roundtrip(compensated)
         env.aux_updates[key] = {'residual': compensated - transmitted}
-        n = axis_size(AXIS_DATA)
+        n = jax.lax.axis_size(AXIS_DATA)
         return int8_ring_all_reduce(transmitted, AXIS_DATA) / n
 
 

@@ -65,12 +65,11 @@ def device_mesh_array(sizes, devices, dcn_dp=1):
         subs = [device_mesh_array(ici_shape, list(g)) for g in groups]
         return np.stack(subs).reshape(sizes)
     if len(devices) > 1 and all(d.platform == 'tpu' for d in devices):
+        # a failure here (e.g. a device subset that is not a cuboid of
+        # the slice) is an error: a silent row-major order would run,
+        # but over the wrong ICI neighbours
         from jax.experimental import mesh_utils
-        try:
-            return mesh_utils.create_device_mesh(sizes, devices)
-        except Exception as e:   # noqa: BLE001 - topology probe only
-            logging.warning('topology-aware mesh failed (%s); '
-                            'falling back to row-major order', e)
+        return mesh_utils.create_device_mesh(sizes, devices)
     return np.array(devices).reshape(sizes)
 
 

@@ -55,7 +55,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from autodist_tpu.parallel.axes import axis_size
 
 
 def _ceil_div(a, b):
@@ -150,7 +149,7 @@ def gpipe(block_fn, stacked_params, x, axis_name, microbatches):
     microbatches=1 (pinned by test_moe_aux_loss_kept_under_pipelining);
     beyond that the objective is the grouped one, by design.
     """
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B = x.shape[0]
     M = int(microbatches)
@@ -236,7 +235,7 @@ def one_f_one_b(block_fn, stacked_params, x, axis_name, microbatches,
     per step); only the small per-microbatch tail outputs use masked
     psum delivery to their owner rank.
     """
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     M = int(microbatches)
     stack = _local_stack_fn(block_fn)
 
@@ -274,7 +273,7 @@ def _legacy_1f1b(block_fn, stacked_params, x, axis_name, M, tail_fn,
                  extra):
     """Autodiff-through-the-scan 1F1B memory profile (see
     :func:`one_f_one_b`)."""
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B = x.shape[0]
     assert B % M == 0, 'batch %d not divisible by microbatches %d' % (B, M)
@@ -367,7 +366,7 @@ def _fused_1f1b(block_fn, stacked_params, x, axis_name, M, tail_fn,
     re-forward, no relay), paying only the vjp-internal recompute.
     ``'auto'`` resolves to 'stash' while the stash fits
     ``AUTODIST_PP_STASH_LIMIT_MB`` per rank."""
-    pp = axis_size(axis_name)
+    pp = jax.lax.axis_size(axis_name)
     B = x.shape[0]
     assert B % M == 0, 'batch %d not divisible by microbatches %d' % (B, M)
     mb = B // M

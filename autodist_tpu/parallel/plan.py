@@ -51,8 +51,7 @@ def ring_all_reduce(x, axis_name):
     does better, so this only runs when forced. Wire volume is pinned by
     ``tests/test_hlo_collectives.py`` against the compiled HLO.
     """
-    from autodist_tpu.parallel.axes import axis_size
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     shape = x.shape

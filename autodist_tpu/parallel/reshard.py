@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from autodist_tpu.const import AXIS_DATA
-from autodist_tpu.parallel.axes import shard_map_compat as _shard_map
+from autodist_tpu.parallel.axes import shard_map as _shard_map
 from autodist_tpu.utils import logging
 
 

@@ -18,7 +18,6 @@ instead (kernels/flash_attention.py via models/attention.py).
 import jax
 import jax.numpy as jnp
 
-from autodist_tpu.parallel.axes import axis_size
 
 
 def _block_attn(q, k, v, mask, sm_scale):
@@ -48,7 +47,7 @@ def ring_attention(q, k, v, axis_name, causal=True, sm_scale=None):
     Returns:
         [batch, heads, seq_shard, head_dim] local output shard.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     s_shard = q.shape[2]
     if sm_scale is None:

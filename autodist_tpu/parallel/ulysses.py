@@ -18,7 +18,7 @@ it caps at n <= n_heads; ring has no such cap. Select per-step with
 import jax
 
 from autodist_tpu.kernels import flash_attention as fa
-from autodist_tpu.parallel.axes import axis_size, unsharded_execution
+from autodist_tpu.parallel.axes import unsharded_execution
 from autodist_tpu.parallel.ring_attention import local_flash_attention
 
 
@@ -44,7 +44,7 @@ def ulysses_attention(q, k, v, axis_name, causal=True, sm_scale=None):
     Returns:
         [batch, heads, seq_shard, head_dim] local output shard.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     heads = q.shape[1]
     if heads % n != 0:
         raise ValueError(

@@ -97,16 +97,11 @@ class Cluster:
                      '%s:%d' % (self._resource_spec.chief,
                                 DEFAULT_JAX_COORD_PORT))
             pid = ENV.AUTODIST_PROCESS_ID.val
-            try:
-                # CPU backends need an explicit cross-process collectives
-                # implementation (TPU ICI needs none). Must be set before
-                # the backend initializes; harmless otherwise.
-                jax.config.update('jax_cpu_collectives_implementation',
-                                  'gloo')
-            except Exception:   # noqa: BLE001 - older jaxlib w/o gloo
-                logging.warning('CPU collectives backend unavailable; '
-                                'multi-process CPU runs will not form a '
-                                'global mesh')
+            # CPU backends need an explicit cross-process collectives
+            # implementation (TPU ICI needs none). Must be set before
+            # the backend initializes; harmless otherwise.
+            jax.config.update('jax_cpu_collectives_implementation',
+                              'gloo')
             logging.info('jax.distributed.initialize(%s, %d, %d)',
                          coord, num_procs, pid)
             jax.distributed.initialize(

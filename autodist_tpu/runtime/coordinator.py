@@ -444,6 +444,71 @@ class AutoscaleController:
         return rec
 
 
+def children_take_chips():
+    """False only when the processes this one starts are pinned to the
+    CPU by name: ``JAX_PLATFORMS=cpu`` in the environment they inherit,
+    or the same setting in this process's jax config (children re-run
+    the same program). Reads configuration only; never initializes a
+    backend."""
+    import jax
+    platforms = jax.config.jax_platforms or ''
+    return platforms.split(',')[0].strip().lower() != 'cpu'
+
+
+def same_host_chip_env(resource_spec, addresses):
+    """One process per chip: the libtpu environment that gives each of
+    several processes on THIS host a chip of its own.
+
+    A process that initializes the TPU backend takes every chip it can
+    see, so a second one on the same host dies at start-up (libtpu's
+    lockfile). When more than one of ``addresses`` is this host and the
+    children are not pinned to the CPU, every such node must declare
+    exactly one chip (``tpus: [i]``), each a different one; the result
+    maps each address to the variables that confine its process to that
+    chip, as a one-chip slice of its own (right for loose mode, where
+    processes meet only at the PS; an SPMD program should run one
+    process per host, which drives all of the host's chips). Blocks of
+    several chips are not handed out: which chips are ICI neighbours
+    differs from host to host — the same two-chip assignment built its
+    mesh on two v5e 2x2 hosts and failed to ("duplicate coordinate
+    assignment") on two others (PR 21 chip runs). Anything else raises
+    ``ValueError`` saying what to change. Returns {} when no two
+    processes share this host or the children run on the CPU.
+    """
+    from autodist_tpu.runtime.cluster import is_local_address
+    local = [a for a in addresses if is_local_address(a)]
+    if len(local) < 2 or not children_take_chips():
+        return {}
+    advice = (
+        'give each of these nodes one chip of its own in the resource '
+        'spec (`tpus: [0]`, `tpus: [1]`, ...), run one process per host '
+        '(it drives all of the host\'s chips), or set JAX_PLATFORMS=cpu '
+        'for a CPU run')
+    taken = {}
+    envs = {}
+    for address in local:
+        chips = resource_spec.declared_tpus(address)
+        if chips is None or len(chips) != 1:
+            raise ValueError(
+                'nodes %s are all this host, so each needs exactly one '
+                'explicit chip, but node %s declares tpus: %s; %s'
+                % (local, address, 'no list' if chips is None else chips,
+                   advice))
+        chip = chips[0]
+        if chip in taken:
+            raise ValueError('chip %d is given to both %s and %s; %s'
+                             % (chip, taken[chip], address, advice))
+        taken[chip] = address
+        envs[address] = {
+            'TPU_VISIBLE_CHIPS': str(chip),
+            'TPU_CHIPS_PER_PROCESS_BOUNDS': '1,1,1',
+            'TPU_PROCESS_BOUNDS': '1,1,1',
+            # several libtpu clients on one host, each on its own chip
+            'ALLOW_MULTIPLE_LIBTPU_LOAD': '1',
+        }
+    return envs
+
+
 # AUTODIST_COORD_TOKEN is deliberately NOT in _FORWARDED_FLAGS: env
 # assignments ride the remote ssh command line, which is world-readable
 # in `ps` on the worker host. The secret ships as a mode-0600 file
@@ -729,6 +794,20 @@ class Coordinator:
         under a policy-aware :class:`WorkerSupervisor`."""
         chief = self._resource_spec.chief
         workers = [n for n in self._resource_spec.nodes if n != chief]
+        # the chief is itself a training process: on a TPU host it owns
+        # (or is about to own) every chip, so a worker it starts on its
+        # own host would die at backend start-up. Only the launcher,
+        # which holds no chips, can split a host between processes.
+        from autodist_tpu.runtime.cluster import is_local_address
+        shared = [n for n in workers if is_local_address(n)]
+        if shared and children_take_chips():
+            raise RuntimeError(
+                'worker node(s) %s are on the chief\'s own host, whose '
+                'TPU chips this chief process claims; start same-host '
+                'processes with `python -m autodist_tpu.launch --spec '
+                '...` and one chip per node (`tpus: [i]`), run one '
+                'process per host, or set JAX_PLATFORMS=cpu for a CPU '
+                'run' % (shared,))
         policy = self._effective_policy()
         for i, address in enumerate(workers, start=1):
             self._launch_supervised(address, i, policy)
@@ -869,6 +948,14 @@ def launch_cli(argv=None):
     coord = '%s:%d' % (chief, ns.coordinator_port)
     coord_service = ENV.AUTODIST_COORD_SERVICE_ADDR.val or \
         '%s:%d' % (chief, DEFAULT_COORD_PORT)
+    # this parent never initializes a JAX backend (it would take the
+    # chips its children need); same-host children get disjoint chips
+    # from the spec, or the launch is refused before anything starts
+    try:
+        chip_env = same_host_chip_env(spec, nodes)
+    except ValueError as e:
+        print('autodist_tpu.launch: %s' % e, file=sys.stderr)
+        return 2
 
     os.makedirs(DEFAULT_WORKING_DIR, exist_ok=True)
     # The launcher owns the coord service (and any local PS endpoint
@@ -906,6 +993,7 @@ def launch_cli(argv=None):
         cmd = [sys.executable, ns.script] + ns.args
         if is_local_address(address):
             # same-host process (multi-process-per-host and test tiers)
+            env.update(chip_env.get(address, {}))
             procs.append(subprocess.Popen(cmd, env=env))
         else:
             ssh_config = spec.ssh_config(address) if spec else None

@@ -29,12 +29,9 @@ from autodist_tpu import telemetry as _telemetry
 from autodist_tpu.const import (AXIS_DATA, DEFAULT_CHECKPOINT_DIR,
                                 DEFAULT_TRACE_DIR, ENV)
 from autodist_tpu.frontend import graph as fe
+from autodist_tpu.parallel.axes import shard_map as _shard_map
 from autodist_tpu.parallel.plan import ShardedGrad
 from autodist_tpu.utils import logging
-
-# jax-version-portable shard_map (check_vma/check_rep spelling handled
-# by the shared compat helper)
-from autodist_tpu.parallel.axes import shard_map_compat as _shard_map
 
 
 class RunOptions:

@@ -248,10 +248,10 @@ def trainer_from_strategy(model, optimizer, strategy_builder,
     gi = PytreeGraphItem(model)
     if resource_spec is None:
         import jax as _jax
-        n = len(_jax.devices())
-        resource_spec = ResourceSpec(resource_info={'nodes': [{
-            'address': 'localhost', 'chief': True, 'cpus': [0],
-            'gpus': list(range(n)), 'network_bandwidth': 100}]})
+
+        from autodist_tpu.autodist import _default_resource_info
+        resource_spec = ResourceSpec(
+            resource_info=_default_resource_info(_jax.devices()))
     strategy = strategy_builder.build(gi, resource_spec)
     trainer = Trainer(model, optimizer, spec=spec, **kw)
     trainer.param_shardings = apply_strategy_to_shardings(

@@ -8,7 +8,7 @@ ParallelSpec (dp/tp/sp/pp/zero).
         python examples/bert.py --config tiny --tp 2 --steps 3
 """
 import argparse
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 
 import numpy as np
 

@@ -19,7 +19,7 @@ real work.
 """
 import argparse
 
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 import numpy as np
 
 import flax.linen as nn

@@ -12,7 +12,7 @@ synthetic mode); point ``SYS_DATA_PATH`` or ``--data`` at a directory of
         python examples/imagenet.py --model resnet50 --tiny --steps 3
 """
 import argparse
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 import os
 
 import numpy as np

@@ -9,7 +9,7 @@ batches. Runs on 1 chip or any local device mesh:
         python examples/linear_regression.py --strategy PartitionedPS
 """
 import argparse
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 
 import numpy as np
 

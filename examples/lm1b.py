@@ -8,7 +8,7 @@ LM with a large vocabulary — the reference pairs it with PartitionedPS
         python examples/lm1b.py --tiny --steps 3
 """
 import argparse
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 import os
 
 import numpy as np

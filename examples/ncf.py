@@ -9,7 +9,7 @@ the reference's pairing: PSLoadBalancing with partitioned embeddings
         python examples/ncf.py --tiny --steps 3
 """
 import argparse
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 
 import numpy as np
 

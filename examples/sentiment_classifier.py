@@ -19,7 +19,7 @@ a real tokenized dataset for real work.
 import argparse
 import time
 
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 import numpy as np
 
 import autodist_tpu as ad

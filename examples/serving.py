@@ -10,7 +10,7 @@ jax + numpy can serve — no framework import needed at serving time.
         python examples/serving.py
 """
 import argparse
-import _common  # noqa: F401  (path + JAX env bootstrap)
+import _common  # noqa: F401  (path + compile-cache bootstrap)
 
 import numpy as np
 

@@ -48,16 +48,10 @@ def _shutdown_service(addr):
 
 COMMON_PRELUDE = textwrap.dedent("""
     import json, os, sys, time
-    os.environ['XLA_FLAGS'] = ' '.join(
-        f for f in os.environ.get('XLA_FLAGS', '').split()
-        if 'xla_force_host_platform_device_count' not in f)
     import numpy as np
     import jax
     jax.config.update('jax_platforms', 'cpu')
-    try:
-        jax.config.update('jax_num_cpu_devices', 1)
-    except AttributeError:
-        pass
+    jax.config.update('jax_num_cpu_devices', 1)
     sys.path.insert(0, %(repo)r)
     import autodist_tpu as ad
 

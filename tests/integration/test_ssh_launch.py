@@ -64,19 +64,10 @@ exec cp "$src" "$dest"
 
 PROG = textwrap.dedent("""
     import json, os, sys, time
-    # conftest's inherited XLA_FLAGS would give this worker 8 virtual
-    # devices on jax without jax_num_cpu_devices; strip it BEFORE the
-    # backend initializes so every worker runs the intended 1 device
-    os.environ['XLA_FLAGS'] = ' '.join(
-        f for f in os.environ.get('XLA_FLAGS', '').split()
-        if 'xla_force_host_platform_device_count' not in f)
     import numpy as np
     import jax
     jax.config.update('jax_platforms', 'cpu')
-    try:
-        jax.config.update('jax_num_cpu_devices', 1)
-    except AttributeError:   # older jax: single CPU device is the default
-        pass
+    jax.config.update('jax_num_cpu_devices', 1)
     sys.path.insert(0, %(repo)r)
     import autodist_tpu as ad
 

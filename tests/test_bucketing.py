@@ -20,7 +20,7 @@ from autodist_tpu.frontend import graph as fe
 from autodist_tpu.parallel.plan import (ExecutionPlan, ShardedGrad,
                                         bucket_bytes_cap, pack_buckets)
 from autodist_tpu.resource_spec import ResourceSpec
-from autodist_tpu.parallel.axes import shard_map_compat as _shard_map
+from autodist_tpu.parallel.axes import shard_map as _shard_map
 from autodist_tpu.strategy import AllReduce, PartitionedPS
 from autodist_tpu.strategy.adapter import (FunctionalModel,
                                            PytreeGraphItem,

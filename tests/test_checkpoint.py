@@ -133,12 +133,6 @@ def test_full_state_resume_via_orbax_live_arrays(tmp_path):
         assert np.allclose(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.skipif(
-    not __import__('autodist_tpu.parallel.axes', fromlist=['x'])
-    .supports_partial_manual(),
-    reason='the tp=2 leg needs jax>=0.6 partial-manual shard_map; the '
-           'old-jax fallback lowering diverges numerically (tier-1 '
-           'triage, ISSUE 5)')
 def test_full_state_resume_is_exact(tmp_path):
     """Interrupt-and-resume reproduces the uninterrupted run exactly:
     optimizer slots and step ride the checkpoint, and restore works onto

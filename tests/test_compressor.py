@@ -24,8 +24,8 @@ def test_int8_ring_matches_psum():
     def ring(x):
         return int8_ring_all_reduce(x, 'data')
 
-    from autodist_tpu.parallel.axes import shard_map_compat
-    got = jax.jit(shard_map_compat(ring, mesh, P('data'),
+    from autodist_tpu.parallel.axes import shard_map
+    got = jax.jit(shard_map(ring, mesh, P('data'),
                                    P('data')))(x)
     want = x.sum(axis=0, keepdims=True).repeat(8, 0)
     # three quantization stages, each ~|max|/127 -> few-percent tolerance

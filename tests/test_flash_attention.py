@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from autodist_tpu.kernels import flash_attention as fa
-from autodist_tpu.parallel.axes import supports_partial_manual
 from autodist_tpu.parallel.ring_attention import local_flash_attention
 
 
@@ -84,10 +83,6 @@ def test_supports_and_preferred():
     assert fa.preferred((1, 1, 2048, 64))
 
 
-@pytest.mark.skipif(
-    not supports_partial_manual(),
-    reason='nested-manual dispatch needs jax>=0.6 partial-manual '
-           'shard_map (jax.shard_map axis_names=); this jax lacks it')
 def test_tp_mesh_dispatches_via_nested_manual(monkeypatch):
     """Under a dp/tp GSPMD mesh the module hops into a nested shard_map
     so the kernel runs on local shards — and the numbers still match the
@@ -132,10 +127,6 @@ def test_tp_mesh_dispatches_via_nested_manual(monkeypatch):
     np.testing.assert_allclose(tp_losses, dp_losses, atol=3e-4)
 
 
-@pytest.mark.skipif(
-    not supports_partial_manual(),
-    reason='nested-manual dispatch needs jax>=0.6 partial-manual '
-           'shard_map (jax.shard_map axis_names=); this jax lacks it')
 def test_flash_parity_on_dp8_gspmd_mesh_long_seq(monkeypatch):
     """dp=8 GSPMD mesh at seq 2048 (the real crossover regime,
     MIN_KERNEL_SEQ untouched): the nested-manual flash path engages and
@@ -177,10 +168,6 @@ def test_flash_parity_on_dp8_gspmd_mesh_long_seq(monkeypatch):
     np.testing.assert_allclose(flash_loss, jnp_loss, rtol=2e-4)
 
 
-@pytest.mark.skipif(
-    not supports_partial_manual(),
-    reason='nested-manual dispatch needs jax>=0.6 partial-manual '
-           'shard_map (jax.shard_map axis_names=); this jax lacks it')
 def test_flash_dispatch_with_extra_live_mesh_axes(monkeypatch):
     """A live size>1 mesh axis beyond data/heads (here: expert) no
     longer drops long-seq attention to the jnp path (round-2 weak item):

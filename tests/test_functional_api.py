@@ -13,8 +13,7 @@ import optax
 
 from autodist_tpu.api import Trainer
 from autodist_tpu.models.transformer import TransformerConfig, TransformerLM
-from autodist_tpu.parallel.axes import (ParallelSpec,
-                                        supports_partial_manual)
+from autodist_tpu.parallel.axes import ParallelSpec
 from autodist_tpu.parallel.ring_attention import (local_flash_attention,
                                                   ring_attention)
 
@@ -42,46 +41,6 @@ def run_losses(model, spec, batch, steps=2):
     return out
 
 
-# tier-1 triage (ISSUE 5): the tp/sp/pp/ep lowerings and this file's
-# raw jax.shard_map(axis_names=...) harnesses need jax>=0.6's
-# partial-manual shard_map; on older jax they either cannot lower
-# (NotImplementedError/AttributeError) or the fully-manual fallback's
-# replication semantics diverge numerically.
-OLD_JAX_REASON = ('needs jax>=0.6 partial-manual shard_map '
-                  '(jax.shard_map axis_names=); unavailable or '
-                  'numerically divergent on this jax')
-needs_partial_manual = pytest.mark.skipif(
-    not supports_partial_manual(), reason=OLD_JAX_REASON)
-
-
-def test_partial_manual_gates_are_evaluated():
-    """Carry-over guard: every ``supports_partial_manual``-gated skip
-    in tests/ CALLS the probe. A bare function reference inside a
-    skipif is always truthy, so one dropped ``()`` silently flips a
-    whole gate to skip-always (or, under ``not``, run-always on jax
-    that cannot lower) — and the probe itself must stay pinned to the
-    one capability it documents."""
-    import ast
-    import pathlib
-    from autodist_tpu.parallel import axes
-    assert axes.supports_partial_manual() == hasattr(jax, 'shard_map')
-    offenders = []
-    for path in sorted(pathlib.Path(__file__).parent.glob('**/*.py')):
-        tree = ast.parse(path.read_text(), filename=str(path))
-        call_funcs = {id(node.func) for node in ast.walk(tree)
-                      if isinstance(node, ast.Call)}
-        for node in ast.walk(tree):
-            ref = (isinstance(node, ast.Name)
-                   and node.id == 'supports_partial_manual') or \
-                  (isinstance(node, ast.Attribute)
-                   and node.attr == 'supports_partial_manual')
-            if ref and id(node) not in call_funcs:
-                offenders.append('%s:%d' % (path.name, node.lineno))
-    assert not offenders, (
-        'supports_partial_manual referenced without being CALLED '
-        '(gates must evaluate the probe): %s' % offenders)
-
-
 @pytest.fixture(scope='module')
 def dp_losses(tiny_lm, batch):
     return run_losses(tiny_lm, ParallelSpec(), batch)
@@ -98,9 +57,6 @@ def dp_losses(tiny_lm, batch):
     dict(tp=4, dp=2),
 ], ids=lambda d: '_'.join('%s%s' % kv for kv in d.items()))
 def test_parallel_modes_match_dp(tiny_lm, batch, dp_losses, spec_kw):
-    if not supports_partial_manual() and (
-            spec_kw.get('tp', 1) > 1 or spec_kw.get('sp', 1) > 1):
-        pytest.skip(OLD_JAX_REASON)
     losses = run_losses(tiny_lm, ParallelSpec(**spec_kw), batch)
     assert np.allclose(losses, dp_losses, atol=2e-4), \
         (losses, dp_losses)
@@ -110,7 +66,6 @@ def test_loss_decreases(tiny_lm, batch, dp_losses):
     assert dp_losses[-1] < dp_losses[0]
 
 
-@needs_partial_manual
 def test_pipeline_parallel_matches_dp(batch):
     """GPipe over pipe=2 (with tp=2) reproduces the DP numbers exactly."""
     cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=4)
@@ -122,7 +77,6 @@ def test_pipeline_parallel_matches_dp(batch):
 
 
 @pytest.mark.parametrize('variant', ['remat', 'stash'])
-@needs_partial_manual
 def test_pipeline_1f1b_matches_dp(batch, variant):
     """The 1F1B schedule (per-rank microbatch residency) is numerically
     identical to DP, like GPipe — in both backward variants (remat:
@@ -138,7 +92,6 @@ def test_pipeline_1f1b_matches_dp(batch, variant):
 
 
 @pytest.mark.parametrize('variant', ['remat', 'stash'])
-@needs_partial_manual
 def test_pipeline_1f1b_ragged_microbatches(batch, variant):
     """M % pp may be ragged — even M < pp (round-4: residency slots are
     padded and masked, lifting the round-3 M %% pp == 0 restriction):
@@ -154,7 +107,6 @@ def test_pipeline_1f1b_ragged_microbatches(batch, variant):
 
 
 @pytest.mark.parametrize('variant', ['remat', 'stash'])
-@needs_partial_manual
 def test_fused_1f1b_direct_no_head(variant):
     """Direct pipeline API, fused mode WITHOUT a head (float x enters
     the pipe, loss folded in the tail): gradients for blocks, tail
@@ -223,7 +175,6 @@ def test_fused_1f1b_direct_no_head(variant):
                                    atol=1e-4)
 
 
-@needs_partial_manual
 def test_pipeline_1f1b_reduces_peak_memory():
     """The point of 1F1B: the custom-vjp backward interleaves
     recompute-forwards and backwards with a 2(pp-1)+1-slot circular
@@ -270,7 +221,6 @@ def test_pipeline_1f1b_reduces_peak_memory():
     assert stash_bytes < gpipe_bytes, (stash_bytes, gpipe_bytes)
 
 
-@needs_partial_manual
 def test_moe_aux_loss_kept_under_pipelining(batch):
     """The MoE router balance loss survives GPipe: with microbatches=1
     the pipelined loss (incl. aux) matches the DP loss exactly; a
@@ -284,7 +234,6 @@ def test_moe_aux_loss_kept_under_pipelining(batch):
 
 
 @pytest.mark.parametrize('variant', ['remat', 'stash'])
-@needs_partial_manual
 def test_moe_aux_loss_through_fused_1f1b(batch, variant):
     """The aux cotangent path through BOTH fused-1F1B backwards: with a
     nonzero router balance loss, multi-step training (losses depend on
@@ -308,7 +257,6 @@ def test_moe_aux_loss_through_fused_1f1b(batch, variant):
     assert float(aux) > 1e-4
 
 
-@needs_partial_manual
 def test_moe_expert_parallel_matches_dp(batch):
     """MoE routing/capacity math is sharding-invariant over ep/tp."""
     cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=2,
@@ -320,7 +268,6 @@ def test_moe_expert_parallel_matches_dp(batch):
     assert base[-1] < base[0]
 
 
-@needs_partial_manual
 def test_ring_attention_matches_dense():
     from jax.sharding import Mesh, PartitionSpec as P
     B, H, S, D = 2, 4, 64, 16
@@ -339,7 +286,6 @@ def test_ring_attention_matches_dense():
         assert err < 1e-5, (causal, err)
 
 
-@needs_partial_manual
 def test_ulysses_attention_matches_dense():
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -360,7 +306,6 @@ def test_ulysses_attention_matches_dense():
         assert err < 1e-5, (causal, err)
 
 
-@needs_partial_manual
 def test_ulysses_attention_grads_match_dense():
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -389,7 +334,6 @@ def test_ulysses_attention_grads_match_dense():
         assert float(jnp.max(jnp.abs(a - b))) < 1e-4
 
 
-@needs_partial_manual
 def test_ulysses_rejects_indivisible_heads():
     from jax.sharding import Mesh, PartitionSpec as P
 
@@ -405,7 +349,6 @@ def test_ulysses_rejects_indivisible_heads():
         jax.jit(f)(q)
 
 
-@needs_partial_manual
 def test_ring_attention_grads_match_dense():
     from jax.sharding import Mesh, PartitionSpec as P
     B, H, S, D = 1, 2, 32, 8

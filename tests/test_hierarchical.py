@@ -13,7 +13,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from autodist_tpu.const import AXIS_DATA
 from autodist_tpu.frontend import graph as fe
-from autodist_tpu.parallel.axes import shard_map_compat
+from autodist_tpu.parallel.axes import shard_map
 from autodist_tpu.parallel.mesh import data_axis_node_groups
 from autodist_tpu.parallel.plan import (ExecutionPlan, ShardedGrad,
                                         static_collective_schedule)
@@ -230,7 +230,7 @@ def _sync_outputs(gi, strategy, grads, mesh):
         return tuple(o.value if isinstance(o, ShardedGrad) else o
                      for o in out)
 
-    f = jax.jit(shard_map_compat(sync, mesh,
+    f = jax.jit(shard_map(sync, mesh,
                                  tuple(P() for _ in grads),
                                  tuple(P() for _ in grads)))
     return [np.asarray(o) for o in f(*grads)], plan
@@ -363,7 +363,7 @@ def test_static_schedule_matches_traced_hierarchical(monkeypatch):
         return tuple(o.value if isinstance(o, ShardedGrad) else o
                      for o in out)
 
-    f = shard_map_compat(sync, mesh, tuple(P() for _ in grads),
+    f = shard_map(sync, mesh, tuple(P() for _ in grads),
                          tuple(P() for _ in grads))
     jax.eval_shape(f, *grads)
     traced = plan.last_bucket_stats

@@ -10,17 +10,6 @@ import jax.numpy as jnp
 from autodist_tpu.utils.profiling import format_breakdown, per_op_breakdown
 
 
-def _has_profile_data():
-    try:
-        from jax.profiler import ProfileData  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
-@pytest.mark.skipif(not _has_profile_data(),
-                    reason='jax.profiler.ProfileData unavailable '
-                           '(older jax)')
 def test_breakdown_from_real_trace(tmp_path):
     @jax.jit
     def step(a, b):
@@ -85,8 +74,6 @@ def test_corrupt_trace_degrades_to_empty(tmp_path):
 def test_missing_line_name_degrades_to_empty(tmp_path):
     """A real trace aggregated under a line name it does not contain
     must degrade to empty (device planes only carry 'XLA Ops')."""
-    if not _has_profile_data():
-        pytest.skip('jax.profiler.ProfileData unavailable (older jax)')
     import jax as _jax
 
     @_jax.jit

@@ -404,7 +404,7 @@ def test_int8_bucket_fusion_and_per_member_residuals():
     from jax.sharding import PartitionSpec as P
 
     from autodist_tpu.frontend import graph as fe
-    from autodist_tpu.parallel.axes import shard_map_compat
+    from autodist_tpu.parallel.axes import shard_map
     from autodist_tpu.parallel.plan import ExecutionPlan, ShardedGrad
     from autodist_tpu.resource_spec import ResourceSpec
     from autodist_tpu.strategy import AllReduce
@@ -442,7 +442,7 @@ def test_int8_bucket_fusion_and_per_member_residuals():
                     for v in sources)
         return outs, res
 
-    f = jax.jit(shard_map_compat(
+    f = jax.jit(shard_map(
         sync, mesh, tuple(P() for _ in grads),
         (tuple(P() for _ in grads), tuple(P() for _ in grads))))
     outs, res = f(*grads)

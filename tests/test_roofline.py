@@ -21,7 +21,8 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-from autodist_tpu.resource_spec import ResourceSpec, PEAKS_BY_KIND  # noqa: E402
+from autodist_tpu.resource_spec import (DEVICE_KINDS,  # noqa: E402
+                                        ResourceSpec, peak_flops_for_kind)
 from autodist_tpu.telemetry import roofline as rl  # noqa: E402
 
 
@@ -36,13 +37,30 @@ def _spec(topology=None, gpus=8):
 
 # -- Topology peak table ---------------------------------------------------
 
-def test_topology_peak_defaults_per_kind():
-    topo = _spec({'device_kind': 'v5e'}).topology
-    assert topo.peak_flops == PEAKS_BY_KIND['v5e'][0]
-    assert topo.peak_hbm_gbps == PEAKS_BY_KIND['v5e'][1]
-    pf, ph = topo.peaks()
-    assert pf == PEAKS_BY_KIND['v5e'][0]
-    assert ph == PEAKS_BY_KIND['v5e'][1] * 1e9
+@pytest.mark.parametrize('kind', ['v5e', 'TPU v5 lite'])
+def test_topology_peak_defaults_per_kind(kind):
+    """The short alias and the string JAX really reports for a v5e
+    (``jax.devices()[0].device_kind``) both take the v5e row."""
+    flops, hbm = DEVICE_KINDS['tpu v5 lite'][:2]
+    assert (flops, hbm) == (197e12, 819.0)
+    topo = _spec({'device_kind': kind}).topology
+    assert topo.peak_flops == flops
+    assert topo.peak_hbm_gbps == hbm
+    assert topo.peaks() == (flops, hbm * 1e9)
+    assert peak_flops_for_kind(kind) == flops
+
+
+def test_no_peak_is_assumed_for_an_unknown_tpu():
+    """An unknown TPU ``device_kind`` raises (no nearest-row guess),
+    and a TPU spec that names no kind has NO peak, not the v5e's."""
+    for kind in ('TPU v9', 'tpu v5', 'v5'):
+        with pytest.raises(ValueError, match='device table'):
+            peak_flops_for_kind(kind)
+    assert peak_flops_for_kind('cpu') is None
+    unnamed = ResourceSpec(resource_info={'nodes': [{
+        'address': 'localhost', 'chief': True, 'tpus': [0, 1],
+        'network_bandwidth': 100}]}).topology
+    assert unnamed.peaks() == (None, None)
 
 
 def test_topology_cpu_kind_resolves_to_none_peaks():
@@ -194,7 +212,7 @@ def _bucketed_plan(n_vars=6, dim=64, chunk=2):
 
     from autodist_tpu.const import AXIS_DATA
     from autodist_tpu.frontend import graph as fe
-    from autodist_tpu.parallel.axes import shard_map_compat
+    from autodist_tpu.parallel.axes import shard_map
     from autodist_tpu.parallel.plan import ExecutionPlan, ShardedGrad
     from autodist_tpu.strategy import AllReduce
     from autodist_tpu.strategy.adapter import (FunctionalModel,
@@ -219,7 +237,7 @@ def _bucketed_plan(n_vars=6, dim=64, chunk=2):
         return tuple(o.value if isinstance(o, ShardedGrad) else o
                      for o in out)
 
-    f = jax.jit(shard_map_compat(sync, mesh,
+    f = jax.jit(shard_map(sync, mesh,
                                  tuple(P() for _ in grads),
                                  tuple(P() for _ in grads)))
     jax.block_until_ready(f(*grads))

@@ -246,7 +246,7 @@ def test_static_schedule_matches_traced_bucket_layout():
 
     from autodist_tpu.const import AXIS_DATA
     from autodist_tpu.frontend import graph as fe
-    from autodist_tpu.parallel.axes import shard_map_compat
+    from autodist_tpu.parallel.axes import shard_map
     from autodist_tpu.parallel.plan import (ExecutionPlan, ShardedGrad,
                                             static_collective_schedule)
 
@@ -268,7 +268,7 @@ def test_static_schedule_matches_traced_bucket_layout():
         return tuple(o.value if isinstance(o, ShardedGrad) else o
                      for o in out)
 
-    f = shard_map_compat(sync, mesh, tuple(P() for _ in grads),
+    f = shard_map(sync, mesh, tuple(P() for _ in grads),
                          tuple(P() for _ in grads))
     jax.eval_shape(f, *grads)   # trace only — records bucket stats
     traced = plan.last_bucket_stats

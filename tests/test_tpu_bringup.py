@@ -1,0 +1,261 @@
+"""TPU bring-up invariants (PR 21), all checkable without a chip.
+
+``chip_smoke.py`` stays runnable and honest: it refuses to run without
+a TPU, its ``--cpu-dry-run`` drives every phase on the CPU, no phase's
+failure can be swallowed, and the compile cache it (and every other
+entry point) uses can be placed from outside. One process per chip: the
+launcher parent holds no chips, and processes that share a TPU host
+either get one chip each from the resource spec or are refused before
+anything starts. And the steps that carry a Pallas kernel must lower for
+the TPU, which cross-platform lowering from the CPU can check.
+
+The subprocess tests here are the slow ones of this file's family, so
+the file sorts late in the suite on purpose.
+"""
+import ast
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
+import pytest
+
+from autodist_tpu.resource_spec import ResourceSpec
+from autodist_tpu.runtime import coordinator
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, 'chip_smoke.py')
+
+
+# -- Mosaic's lowering checks, without a chip ------------------------------
+
+@pytest.mark.parametrize('spec_kw,seq', [
+    (dict(dp=2, tp=2), 512),                      # nested-manual route
+    (dict(dp=1, sp=2, sp_mode='ulysses'), 1024),  # Trainer's sp region
+], ids=['dp2_tp2', 'sp2_ulysses'])
+def test_kernel_regions_lower_for_tpu(monkeypatch, spec_kw, seq):
+    """The step must LOWER for the TPU with the real Mosaic kernel in
+    it, under GSPMD (dp x tp) and inside the Trainer's manual region.
+    Interpret mode never reaches Mosaic's check that no mesh axis is
+    left automatic around a kernel, so the CPU mesh ran these routes
+    for twenty PRs while the first four-chip run refused them ("Mosaic
+    kernels cannot be automatically partitioned"). Cross-platform
+    lowering from the CPU runs that check without a chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    monkeypatch.setattr(fa, '_interpret_default', lambda: False)
+    cfg = TransformerConfig.tiny(dtype=jnp.bfloat16, n_layers=1,
+                                 max_len=seq)
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(**spec_kw))
+    state = tr.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(0)
+    batch = {'tokens': rng.randint(0, 256, (4, seq), dtype=np.int32),
+             'targets': rng.randint(0, 256, (4, seq), dtype=np.int32)}
+    step = tr._ensure_step(tr._step_key(batch), state, batch)
+    exported = jax.export.export(step, platforms=['tpu'])(
+        state, tr.shard_batch(batch))
+    assert 'tpu_custom_call' in exported.mlir_module()
+
+
+# -- chip_smoke.py ---------------------------------------------------------
+
+def _run_smoke(*args, devices=1):
+    env = dict(os.environ, JAX_PLATFORMS='cpu',
+               XLA_FLAGS='--xla_force_host_platform_device_count=%d'
+               % devices)
+    return subprocess.run([sys.executable, SMOKE, *args], env=env,
+                          capture_output=True, text=True, timeout=600,
+                          cwd=REPO)
+
+
+def test_cpu_dry_run_drives_every_phase():
+    """Four virtual devices, so the dp=4 / dp=2 x tp=2 legs and the
+    four-device session legs are on the dry run's path too."""
+    out = _run_smoke('--cpu-dry-run', devices=4)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = out.stdout.strip().splitlines()
+    assert lines[0].startswith('CPU DRY RUN'), lines[0]
+    assert json.loads(lines[-1]) == {'ok': True, 'cpu_dry_run': True}
+    for leg in ('kernel flash', 'kernel conv_bn', 'bert dp=1 tp=1',
+                'bert dp=4 tp=1', 'bert dp=2 tp=2', 'session c0',
+                'session dense (PartitionedPS', 'loose mode'):
+        assert any(l.startswith(leg) for l in lines), (leg, out.stdout)
+    # a dry run never prints a device figure
+    assert 'observation' not in out.stdout
+
+
+def test_refuses_to_run_without_a_tpu():
+    out = _run_smoke()
+    assert out.returncode != 0
+    assert out.stdout.strip() == ''          # no result line
+    assert "jax.default_backend() is 'cpu'" in out.stderr
+    assert len(out.stderr.strip().splitlines()) == 1, out.stderr
+
+
+def test_no_phase_failure_can_be_swallowed():
+    """``main`` wraps no phase in ``try``; the handlers that exist
+    elsewhere name one exception type and re-raise what they do not
+    mean to step around."""
+    tree = ast.parse(open(SMOKE).read())
+    main = next(n for n in tree.body
+                if isinstance(n, ast.FunctionDef) and n.name == 'main')
+    tries = [n for n in ast.walk(main) if isinstance(n, ast.Try)]
+    # the one try in main is the optional libtpu version import
+    assert all(isinstance(h.type, ast.Name) and h.type.id == 'ImportError'
+               for t in tries for h in t.handlers), ast.dump(tries[0])
+    for handler in (n for n in ast.walk(tree)
+                    if isinstance(n, ast.ExceptHandler)):
+        assert handler.type is not None, 'bare except'
+        name = ast.unparse(handler.type)
+        assert name not in ('Exception', 'BaseException'), name
+        assert name == 'ImportError' or any(
+            isinstance(n, ast.Raise) for n in ast.walk(handler)), name
+
+
+def test_compile_cache_dir_is_left_alone_when_placed(monkeypatch):
+    import jax
+    from autodist_tpu.utils import jax_env
+    calls = []
+    monkeypatch.setattr(jax.config, 'update',
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/some/dir')
+    assert jax_env.setup_compile_cache() == '/some/dir'
+    assert calls == []                       # JAX reads the variable
+
+
+def test_compile_cache_default_is_fixed_inside_the_checkout(
+        monkeypatch, tmp_path):
+    import jax
+    from autodist_tpu.utils import jax_env
+    calls = []
+    monkeypatch.setattr(jax.config, 'update',
+                        lambda *a: calls.append(a))
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    expected = os.path.join(REPO, '.jax_cache')
+    for cwd in (tmp_path, REPO):             # the same from any cwd
+        monkeypatch.chdir(cwd)
+        assert jax_env.setup_compile_cache() == expected
+    assert calls == [('jax_compilation_cache_dir', expected)] * 2
+
+
+# -- one process per chip ---------------------------------------------------
+
+def _spec(*tpus):
+    """Nodes that are all THIS host, one per entry of ``tpus``."""
+    addresses = ['localhost', '127.0.0.1', '127.0.0.2', '127.0.0.3']
+    return ResourceSpec(resource_info={'nodes': [
+        dict({'address': a, 'chief': i == 0, 'network_bandwidth': 100},
+             **({'tpus': t} if t is not None else {}))
+        for i, (a, t) in enumerate(zip(addresses, tpus))]})
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    monkeypatch.setattr(coordinator, 'children_take_chips', lambda: True)
+
+
+def test_same_host_children_get_one_chip_each(on_tpu):
+    spec = _spec([0], [1], [2], [3])
+    envs = coordinator.same_host_chip_env(spec, list(spec.nodes))
+    assert [envs[a]['TPU_VISIBLE_CHIPS'] for a in spec.nodes] == \
+        ['0', '1', '2', '3']
+    for env in envs.values():
+        assert env['TPU_CHIPS_PER_PROCESS_BOUNDS'] == '1,1,1'
+        assert env['TPU_PROCESS_BOUNDS'] == '1,1,1'
+        assert env['ALLOW_MULTIPLE_LIBTPU_LOAD'] == '1'
+
+
+@pytest.mark.parametrize('tpus,complaint', [
+    ((None, None), 'declares tpus: no list'),
+    (('auto', [1]), 'declares tpus: no list'),
+    (([0], [0]), 'chip 0 is given to both'),
+    # multi-chip blocks are not handed out: ICI neighbourhood differs
+    # from host to host
+    (([0, 1], [2, 3]), 'needs exactly one explicit chip'),
+])
+def test_same_host_children_without_own_chip_are_refused(
+        on_tpu, tpus, complaint):
+    spec = _spec(*tpus)
+    with pytest.raises(ValueError, match=complaint) as exc:
+        coordinator.same_host_chip_env(spec, list(spec.nodes))
+    assert 'JAX_PLATFORMS=cpu' in str(exc.value)   # says what to change
+
+
+def test_no_assignment_needed(on_tpu, monkeypatch):
+    one_local = ResourceSpec(resource_info={'nodes': [
+        {'address': 'localhost', 'chief': True, 'network_bandwidth': 100},
+        {'address': '10.9.8.7', 'network_bandwidth': 100}]})
+    assert coordinator.same_host_chip_env(
+        one_local, list(one_local.nodes)) == {}
+    # children pinned to the CPU by name own no chips
+    monkeypatch.setattr(coordinator, 'children_take_chips', lambda: False)
+    spec = _spec(None, None)
+    assert coordinator.same_host_chip_env(spec, list(spec.nodes)) == {}
+
+
+def test_coordinator_refuses_a_worker_on_the_chiefs_host(on_tpu):
+    from autodist_tpu.strategy.base import Strategy
+    spec = _spec([0], [1])
+    c = coordinator.Coordinator(Strategy(), spec)
+    with pytest.raises(RuntimeError, match="chief's own host"):
+        c.launch_clients()
+    assert not c.supervisors
+
+
+def test_launcher_parent_initializes_no_backend(tmp_path):
+    """``launch_cli`` reads a spec (even a ``tpus: auto`` one), starts
+    its children and waits, without ever creating a JAX backend — on a
+    TPU host that would take the chips the children need. And with the
+    children not pinned to the CPU it refuses to start two of them on
+    one host's chips."""
+    script = tmp_path / 'child.py'
+    script.write_text('print("child ran")\n')
+    spec = tmp_path / 'spec.yml'
+    spec.write_text(textwrap.dedent('''
+        nodes:
+          - address: localhost
+            chief: true
+            tpus: auto
+            network_bandwidth: 100
+          - address: 127.0.0.1
+            tpus: auto
+            network_bandwidth: 100
+    '''))
+    driver = textwrap.dedent('''
+        import sys
+        from autodist_tpu.runtime.coordinator import launch_cli
+        rc = launch_cli(['--spec', %r, %r])
+        from jax._src import xla_bridge
+        assert not xla_bridge._backends, 'launcher touched a backend'
+        sys.exit(rc)
+    ''') % (str(spec), str(script))
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS='cpu',
+               AUTODIST_COORD_SERVICE_ADDR='127.0.0.1:%d' % _free_port())
+    ok = subprocess.run([sys.executable, '-c', driver], env=env,
+                        capture_output=True, text=True, timeout=120)
+    assert ok.returncode == 0, ok.stderr[-2000:]
+    assert ok.stdout.count('child ran') == 2
+    env.pop('JAX_PLATFORMS')
+    refused = subprocess.run([sys.executable, '-c', driver], env=env,
+                             capture_output=True, text=True, timeout=120)
+    assert refused.returncode == 2, refused.stderr[-2000:]
+    assert 'declares tpus: no list' in refused.stderr
+    assert 'child ran' not in refused.stdout
+
+
+def _free_port():
+    import socket
+    with socket.socket() as s:
+        s.bind(('127.0.0.1', 0))
+        return s.getsockname()[1]

@@ -21,7 +21,7 @@ import autodist_tpu as ad
 from autodist_tpu import autodist as ad_mod
 from autodist_tpu.const import AXIS_DATA
 from autodist_tpu.frontend import graph as fe
-from autodist_tpu.parallel.axes import shard_map_compat
+from autodist_tpu.parallel.axes import shard_map
 from autodist_tpu.parallel.plan import (ExecutionPlan, ShardedGrad,
                                         UpdateShard,
                                         hierarchical_all_gather,
@@ -440,7 +440,7 @@ def test_wus_static_matches_traced():
              if isinstance(sh, UpdateShard)})
         return tuple(gathered[s.name] for s in sources)
 
-    f = shard_map_compat(sync, mesh, tuple(P() for _ in grads),
+    f = shard_map(sync, mesh, tuple(P() for _ in grads),
                          tuple(P() for _ in grads))
     jax.eval_shape(f, *grads)
     traced = [e for e in plan.last_bucket_stats if e.get('wus')]
@@ -521,8 +521,8 @@ def test_hierarchical_halves_bit_identical_and_pinned(monkeypatch):
                                  tiled=True)
         return s, jax.lax.all_gather(s, AXIS_DATA, tiled=True)
 
-    fh = shard_map_compat(two_level, mesh, (P(),), (P(AXIS_DATA), P()))
-    ff = shard_map_compat(flat, mesh, (P(),), (P(AXIS_DATA), P()))
+    fh = shard_map(two_level, mesh, (P(),), (P(AXIS_DATA), P()))
+    ff = shard_map(flat, mesh, (P(),), (P(AXIS_DATA), P()))
     sh, ah = fh(x)
     sf, af = ff(x)
     assert jnp.array_equal(sh, sf)   # same ownership, same values
@@ -548,7 +548,7 @@ def test_hierarchical_halves_bit_identical_and_pinned(monkeypatch):
         return tuple(o.gather() if isinstance(o, ShardedGrad) else o
                      for o in out)
 
-    f = shard_map_compat(sync, mesh, tuple(P() for _ in grads),
+    f = shard_map(sync, mesh, tuple(P() for _ in grads),
                          tuple(P() for _ in grads))
     outs = f(*grads)
     traced = [(e['kind'], e['bytes'], e.get('hier'))

@@ -1,7 +1,7 @@
 """Diff two BENCH records per stable key — the machine-readable half
 of the bench trajectory.
 
-    python tools/bench_compare.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_compare.py old_record.json new_record.json
     python tools/bench_compare.py OLD.json NEW.json --threshold 0.15
     python tools/bench_compare.py OLD.json NEW.json --json
 

@@ -1,4 +1,4 @@
-"""BERT-large phase-2 (seq 512) sweep on the chip (VERDICT r4 item 2).
+"""BERT-large phase-2 (seq 512) sweep on the chip.
 
 Sweeps per-chip batch and the flash-attention kernel (force-on vs the
 auto XLA path — seq 512 sits at the kernel's measured 1.0x crossover)
@@ -17,8 +17,8 @@ import bench as B
 
 
 def main():
-    from autodist_tpu.utils.jax_env import apply_jax_env_overrides
-    apply_jax_env_overrides()
+    from autodist_tpu.utils.jax_env import setup_compile_cache
+    setup_compile_cache()
 
     import jax
     import jax.numpy as jnp

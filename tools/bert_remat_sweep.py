@@ -19,8 +19,8 @@ import bench as B
 
 
 def main():
-    from autodist_tpu.utils.jax_env import apply_jax_env_overrides
-    apply_jax_env_overrides()
+    from autodist_tpu.utils.jax_env import setup_compile_cache
+    setup_compile_cache()
 
     import jax
     import jax.numpy as jnp

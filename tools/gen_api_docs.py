@@ -19,10 +19,6 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from autodist_tpu.utils.jax_env import apply_jax_env_overrides  # noqa: E402
-
-apply_jax_env_overrides()
-
 import autodist_tpu  # noqa: E402
 
 OUT_DIR = os.path.join(REPO, 'docs', 'api')

@@ -1,4 +1,4 @@
-"""Measure the pipeline-schedule trade table (VERDICT r4 item 3).
+"""Measure the pipeline-schedule trade table.
 
 For pp in {2, 4}: GPipe vs legacy-1F1B vs fused-1F1B(remat) vs
 fused-1F1B(stash), all through the same Trainer/TransformerLM path on
@@ -24,9 +24,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from autodist_tpu.utils.jax_env import apply_jax_env_overrides
+from autodist_tpu.utils.jax_env import setup_compile_cache
 
-apply_jax_env_overrides()
+setup_compile_cache()
 
 import dataclasses
 

@@ -2,7 +2,7 @@
 per-entry collective drift table from records or traces.
 
     # a BENCH record (driver wrapper or bench.py's raw line)
-    python tools/roofline.py BENCH_r06.json
+    python tools/roofline.py bench_record.json
 
     # a raw roofline block (bench extra.roofline, or your own)
     python tools/roofline.py roofline.json --json

@@ -1,6 +1,6 @@
 """A/B the space-to-depth stem transform on the CNN family (TPU).
 
-VERDICT r4 item 1: measure AUTODIST_S2D_STEM=0 vs 1 train steps for
+Measure AUTODIST_S2D_STEM=0 vs 1 train steps for
 ResNet-101 / DenseNet-121 / InceptionV3 at their bench batch sizes.
 Uses bench.run_workload (median of 3 fenced blocks).
 """
@@ -48,8 +48,8 @@ def run(name, steps=10):
 
 
 def main():
-    from autodist_tpu.utils.jax_env import apply_jax_env_overrides
-    apply_jax_env_overrides()
+    from autodist_tpu.utils.jax_env import setup_compile_cache
+    setup_compile_cache()
     names = sys.argv[1:] or ['resnet101', 'densenet121', 'inceptionv3']
     for name in names:
         print(name, json.dumps(run(name)), flush=True)

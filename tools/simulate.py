@@ -3,11 +3,11 @@
 Prints the simulator's ranked table (predicted step time, per-device
 peak bytes, collective count per candidate builder) WITHOUT running a
 single training step: only ``jax.eval_shape`` touches the model, so
-this works on a TPU-less host.
+this works on a host with no accelerator.
 
-Runs under the CPU fallback::
+Runs on the host CPU unless ``JAX_PLATFORMS`` says otherwise::
 
-    JAX_PLATFORMS=cpu python tools/simulate.py --model ncf
+    python tools/simulate.py --model ncf
     python tools/simulate.py --model lstm --resource-spec cluster.yml \
         --budget-gb 8 --json
 
@@ -24,14 +24,9 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# CPU fallback BEFORE any jax import: 8 virtual devices (jax_env is
-# jax-import-free at module level, so this is safe to import first)
-from autodist_tpu.utils.jax_env import (  # noqa: E402
-    apply_jax_env_overrides, force_cpu_host_devices)
-
-force_cpu_host_devices(8)
+# Offline pricing never needs (and must never take) an accelerator:
+# unless told otherwise, keep jax on the host CPU. Set before jax loads.
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-apply_jax_env_overrides()
 
 
 def build_model(name):
