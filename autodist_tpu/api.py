@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_DATA, AXIS_PIPELINE, AXIS_SEQUENCE
 from autodist_tpu.parallel.axes import (ParallelSpec, sharding_ctx,
                                         shardings_for_tree, spec_for_axes)
@@ -77,6 +78,9 @@ class Trainer:
         self.param_shardings = shardings_for_tree(
             self._axes_tree, self.rules, self.mesh)
         self._step_cache = {}
+        # calls of step() so far: the number every loop span and
+        # event of this trainer is tagged with (telemetry.loop_span)
+        self._steps_run = 0
         # model state (BatchNorm running stats): non-trainable leaves
         # advance via recorded updates, not the optimizer
         self._has_state = getattr(model, 'has_state', lambda: False)()
@@ -216,23 +220,25 @@ class Trainer:
     # -- init --------------------------------------------------------------
     def init(self, rng, params=None):
         """Materialize sharded TrainState (params + optimizer slots)."""
-        if params is None:
-            with sharding_ctx(self.mesh, self.rules):
-                shapes = jax.eval_shape(self.model.init, rng)
-                shardings = self._param_sharding_tree(shapes)
-                init_fn = jax.jit(self.model.init,
-                                  out_shardings=shardings)
-                params = init_fn(rng)
-        else:
-            params = jax.tree.map(
-                lambda x, s: jax.device_put(jnp.asarray(x), s),
-                params, self._param_sharding_tree(params))
-        opt_state = jax.jit(self.optimizer.init)(params)
-        opt_shardings = self._opt_sharding(opt_state, params,
-                                           self._param_sharding_tree(params))
-        opt_state = jax.tree.map(
-            lambda x, s: jax.device_put(x, s), opt_state, opt_shardings)
-        return TrainState.create(params, opt_state)
+        with telemetry.get().loop_span('trainer.init'):
+            if params is None:
+                with sharding_ctx(self.mesh, self.rules):
+                    shapes = jax.eval_shape(self.model.init, rng)
+                    shardings = self._param_sharding_tree(shapes)
+                    init_fn = jax.jit(self.model.init,
+                                      out_shardings=shardings)
+                    params = init_fn(rng)
+            else:
+                params = jax.tree.map(
+                    lambda x, s: jax.device_put(jnp.asarray(x), s),
+                    params, self._param_sharding_tree(params))
+            opt_state = jax.jit(self.optimizer.init)(params)
+            opt_shardings = self._opt_sharding(
+                opt_state, params, self._param_sharding_tree(params))
+            opt_state = jax.tree.map(
+                lambda x, s: jax.device_put(x, s), opt_state,
+                opt_shardings)
+            return TrainState.create(params, opt_state)
 
     # -- the compiled step -------------------------------------------------
     @property
@@ -388,10 +394,11 @@ class Trainer:
                 grads = jax.tree.map(lambda g: g / accum, grads)
             else:
                 loss, grads, state_updates = grads_of(state.params, batch)
-            updates, new_opt = self.optimizer.update(
-                grads, state.opt_state, state.params)
-            new_params = apply_updates(state.params, updates,
-                                       state_updates)
+            with jax.named_scope('optimizer'):
+                updates, new_opt = self.optimizer.update(
+                    grads, state.opt_state, state.params)
+                new_params = apply_updates(state.params, updates,
+                                           state_updates)
             return TrainState(params=new_params, opt_state=new_opt,
                               step=state.step + 1), {'loss': loss}
 
@@ -420,6 +427,10 @@ class Trainer:
 
     def _ensure_step(self, key, state, batch):
         if key not in self._step_cache:
+            # which step met a batch signature with no compiled step yet
+            telemetry.get().loop_event(
+                'trainer.new_step_signature', step=self._steps_run + 1,
+                shapes=str(key[1]))
             step_fn = self._build_step(jax.tree.structure(batch))
             param_sh = self._param_sharding_tree(state.params)
             opt_sh = self._opt_sharding(state.opt_state, state.params,
@@ -438,20 +449,26 @@ class Trainer:
         subsequent ``step`` calls with the same signature reuse the same
         executable. Returns the ``jax.stages.Compiled`` (which exposes
         ``cost_analysis()`` — used by bench.py for FLOP cross-checks)."""
-        key = self._step_key(batch)
-        fn = self._ensure_step(key, state, batch)
-        if isinstance(fn, jax.stages.Compiled):
-            return fn
-        compiled = fn.lower(state, self.shard_batch(batch)).compile()
-        self._step_cache[key] = compiled
-        return compiled
+        with telemetry.get().loop_span('trainer.compile_step',
+                                       step=self._steps_run + 1):
+            key = self._step_key(batch)
+            fn = self._ensure_step(key, state, batch)
+            if isinstance(fn, jax.stages.Compiled):
+                return fn
+            compiled = fn.lower(state, self.shard_batch(batch)).compile()
+            self._step_cache[key] = compiled
+            return compiled
 
     def step(self, state, batch):
         """One optimizer step; returns (new_state, metrics)."""
-        key = self._step_key(batch)
-        fn = self._ensure_step(key, state, batch)
-        batch = self.shard_batch(batch)
-        return fn(state, batch)
+        with telemetry.get().loop_span('trainer.step',
+                                       step=self._steps_run + 1):
+            key = self._step_key(batch)
+            fn = self._ensure_step(key, state, batch)
+            batch = self.shard_batch(batch)
+            out = fn(state, batch)
+        self._steps_run += 1
+        return out
 
     # -- fit/evaluate conveniences (reference case c7's Model.fit role) ----
     def fit(self, state, data, steps=None, eval_data=None, eval_every=0,
@@ -483,38 +500,60 @@ class Trainer:
             entry per step) and, when evaluating, 'eval_loss' entries of
             (step, loss).
         """
+        tel = telemetry.get()
         history = {'loss': []}
         if eval_data is not None:
             history['eval_loss'] = []
-        if prefetch:
-            from autodist_tpu.data.prefetch import prefetch_to_device
-            data = prefetch_to_device(data, self.shard_batch,
-                                      size=prefetch)
-        it = iter(data)
         n = 0
-        for batch in it:
-            state, metrics = self.step(state, batch)
-            history['loss'].append(float(metrics['loss']))
-            n += 1
-            if eval_data is not None and eval_every and \
-                    n % eval_every == 0:
+
+        # every span is tagged with the number of the step it belongs
+        # to: calls of step() so far, plus one before the call
+        def evaluate():
+            with tel.loop_span('trainer.eval', step=self._steps_run):
                 history['eval_loss'].append(
                     (n, self.evaluate(state, eval_data)))
-            if checkpoint_manager is not None and save_every and \
-                    n % save_every == 0:
+
+        def save():
+            with tel.loop_span('trainer.save', step=self._steps_run):
                 self.save_state(checkpoint_manager, state)
-            if steps is not None and n >= steps:
-                break
-        if eval_data is not None and (not eval_every or
-                                      n % eval_every):
-            history['eval_loss'].append((n, self.evaluate(state,
-                                                          eval_data)))
-        if checkpoint_manager is not None and (not save_every or
-                                               n % save_every):
-            self.save_state(checkpoint_manager, state)
-        if checkpoint_manager is not None and \
-                hasattr(checkpoint_manager, 'wait_until_finished'):
-            checkpoint_manager.wait_until_finished()   # drain async save
+
+        with tel.loop_span('trainer.fit', step=self._steps_run + 1,
+                           steps=steps, prefetch=prefetch):
+            if prefetch:
+                from autodist_tpu.data.prefetch import prefetch_to_device
+                data = prefetch_to_device(data, self.shard_batch,
+                                          size=prefetch,
+                                          first_step=self._steps_run + 1)
+            it = iter(data)
+            done = object()
+            while True:
+                with tel.loop_span('trainer.input',
+                                   step=self._steps_run + 1):
+                    batch = next(it, done)
+                if batch is done:
+                    break
+                state, metrics = self.step(state, batch)
+                with tel.loop_span('trainer.loss_readback',
+                                   step=self._steps_run):
+                    history['loss'].append(float(metrics['loss']))
+                n += 1
+                if eval_data is not None and eval_every and \
+                        n % eval_every == 0:
+                    evaluate()
+                if checkpoint_manager is not None and save_every and \
+                        n % save_every == 0:
+                    save()
+                if steps is not None and n >= steps:
+                    break
+            if eval_data is not None and (not eval_every or
+                                          n % eval_every):
+                evaluate()
+            if checkpoint_manager is not None and (not save_every or
+                                                   n % save_every):
+                save()
+            if checkpoint_manager is not None and \
+                    hasattr(checkpoint_manager, 'wait_until_finished'):
+                checkpoint_manager.wait_until_finished()  # drain async save
         return state, history
 
     def evaluate(self, state, batches, metrics_fn=None):
@@ -622,21 +661,29 @@ class Trainer:
         of the session's ``RunOptions(trace_level=...)`` (reference
         chrome-trace timelines, runner.py:64-75). Returns ``trace_dir``;
         the traced steps' state updates are DISCARDED (profiling must
-        not perturb training)."""
+        not perturb training).
+
+        The steps go through :meth:`step`, so the trace carries the
+        ``trainer.step`` spans on its host plane and the model's named
+        scopes in its device operations; the Python tracer is off (it
+        records every call of the host loop, which slows the loop and
+        makes the trace hundreds of megabytes)."""
         import os
-        fn = self.compile_step(state, batch)
+        self.compile_step(state, batch)
         placed = self.shard_batch(batch)
         # profile a COPY when the step donates its input state (the
         # default): donating the caller's state would invalidate their
         # buffers. Without donation the copy would only waste HBM.
         s = jax.tree.map(jnp.copy, state) if self._donate else state
-        s, m = fn(s, placed)           # warmup outside the trace
+        s, m = self.step(s, placed)    # warmup outside the trace
         jax.block_until_ready(m['loss'])
         os.makedirs(trace_dir, exist_ok=True)
-        jax.profiler.start_trace(trace_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
         try:
             for _ in range(steps):
-                s, m = fn(s, placed)
+                s, m = self.step(s, placed)
             jax.block_until_ready(m['loss'])
         finally:
             jax.profiler.stop_trace()

@@ -8,9 +8,12 @@ the transfer of batch N+1 rides along while the step on batch N runs —
 the jax idiom replacing tf.data's ``prefetch_to_device``.
 """
 import collections
+import itertools
+
+from autodist_tpu import telemetry
 
 
-def prefetch_to_device(iterator, place_fn, size=2):
+def prefetch_to_device(iterator, place_fn, size=2, first_step=1):
     """Yield device-placed batches with ``size`` batches in flight.
 
     Args:
@@ -18,6 +21,11 @@ def prefetch_to_device(iterator, place_fn, size=2):
         place_fn: host batch -> device arrays (e.g.
             ``Trainer.shard_batch`` — async; must not block).
         size: number of placed batches to keep in flight (>= 1).
+        first_step: number of the training step that consumes the
+            first batch; batch ``i`` is fetched under a
+            ``trainer.source`` and placed under a ``trainer.place``
+            loop span (:meth:`Telemetry.loop_span`) tagged
+            ``first_step + i``.
 
     Yields:
         placed batches, in order.
@@ -27,12 +35,18 @@ def prefetch_to_device(iterator, place_fn, size=2):
     buf = collections.deque()
     it = iter(iterator)
     pending = []   # a source/placement error, deferred until buf drains
+    tel = telemetry.get()
+    steps = itertools.count(first_step)
 
     def fill():
         if pending:
             return False
+        step = next(steps)
         try:
-            buf.append(place_fn(next(it)))
+            with tel.loop_span('trainer.source', step=step):
+                batch = next(it)
+            with tel.loop_span('trainer.place', step=step):
+                buf.append(place_fn(batch))
         except StopIteration:
             return False
         except Exception as e:   # noqa: BLE001 - re-raised after drain

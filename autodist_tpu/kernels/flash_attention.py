@@ -169,6 +169,7 @@ def _fwd(q, k, v, causal, sm_scale, bq, bk, interpret):
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
+        name='flash_fwd',
     )(q, k, v)
     return o, lse
 
@@ -293,6 +294,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk, interpret):
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
+        name='flash_dq',
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid iterates q-blocks innermost for each kv-block
@@ -323,6 +325,7 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk, interpret):
             dimension_semantics=('parallel', 'parallel', 'parallel',
                                  'arbitrary')),
         interpret=interpret,
+        name='flash_dkv',
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -104,17 +104,20 @@ class Block(Module):
         return {'ln1': self.ln1, 'attn': self.attn,
                 'ln2': self.ln2, 'mlp': self.mlp}
 
+    @jax.named_scope('block')
     def apply(self, params, x):
-        x = x + self.attn.apply(params['attn'],
-                                self.ln1.apply(params['ln1'], x))
+        with jax.named_scope('attention'):
+            x = x + self.attn.apply(params['attn'],
+                                    self.ln1.apply(params['ln1'], x))
         # named so remat='save_attn' can keep it while recomputing the rest
         x = checkpoint_name(x, 'attn_out')
-        h = self.mlp.apply(params['mlp'],
-                           self.ln2.apply(params['ln2'], x))
-        aux = jnp.zeros((), jnp.float32)
-        if self.cfg.moe_experts:
-            h, aux = h
-        x = x + h
+        with jax.named_scope('mlp'):
+            h = self.mlp.apply(params['mlp'],
+                               self.ln2.apply(params['ln2'], x))
+            aux = jnp.zeros((), jnp.float32)
+            if self.cfg.moe_experts:
+                h, aux = h
+            x = x + h
         return constrain(x, ('batch', 'seq', 'embed')), aux
 
 
@@ -159,9 +162,10 @@ class TransformerLM(Module):
         """Returns (logits, aux) where aux is the summed MoE router
         load-balance loss (0.0 for dense configs)."""
         x, aux_total = self.hidden_with_aux(params, tokens)
-        logits = self._head_logits(params, x)
-        return constrain(logits.astype(jnp.float32),
-                         ('batch', 'seq', 'vocab')), aux_total
+        with jax.named_scope('head_loss'):
+            logits = self._head_logits(params, x)
+            return constrain(logits.astype(jnp.float32),
+                             ('batch', 'seq', 'vocab')), aux_total
 
     def _head_logits(self, params, x):
         """LM-head logits (model dtype) for hidden states of any
@@ -170,6 +174,7 @@ class TransformerLM(Module):
             return self.embed.attend(params['embed'], x)
         return self.lm_head.apply(params['lm_head'], x)
 
+    @jax.named_scope('embed')
     def _embedded(self, params, tokens):
         """Embedding + positions (the pipeline prologue)."""
         _, s = tokens.shape
@@ -245,7 +250,8 @@ class TransformerLM(Module):
             for i in range(cfg.n_layers):
                 x, a = block_fn(params['block_%03d' % i], x)
                 aux_total = aux_total + a
-        x = self.ln_f.apply(params['ln_f'], x)
+        with jax.named_scope('head_loss'):
+            x = self.ln_f.apply(params['ln_f'], x)
         return x, aux_total
 
     def per_token_loss(self, params, batch):
@@ -272,21 +278,24 @@ class TransformerLM(Module):
         x, aux = self.hidden_with_aux(params, batch['tokens'])
         b, s = targets.shape
         n = self._ce_chunks(s, b * s)
-        if n > 1:
-            # Chunked CE: scan over sequence chunks; jax.checkpoint means
-            # backward recomputes each chunk's logits instead of saving
-            # an [b, s, vocab] residual. Chunking the SEQ dim (not
-            # flattened rows) keeps the batch dim intact, so DP sharding
-            # propagates through the reshape without communication.
-            d = x.shape[-1]
-            xs = x.reshape(b, n, s // n, d).swapaxes(0, 1)
-            ts = targets.reshape(b, n, s // n).swapaxes(0, 1)
-            ckpt = jax.checkpoint(self._chunk_nll)
-            _, nll = jax.lax.scan(
-                lambda c, inp: (c, ckpt(params, *inp)), None, (xs, ts))
-            nll = nll.swapaxes(0, 1).reshape(b, s)
-        else:
-            nll = self._chunk_nll(params, x, targets)
+        with jax.named_scope('head_loss'):
+            if n > 1:
+                # Chunked CE: scan over sequence chunks; jax.checkpoint
+                # means backward recomputes each chunk's logits instead
+                # of saving an [b, s, vocab] residual. Chunking the SEQ
+                # dim (not flattened rows) keeps the batch dim intact, so
+                # DP sharding propagates through the reshape without
+                # communication.
+                d = x.shape[-1]
+                xs = x.reshape(b, n, s // n, d).swapaxes(0, 1)
+                ts = targets.reshape(b, n, s // n).swapaxes(0, 1)
+                ckpt = jax.checkpoint(self._chunk_nll)
+                _, nll = jax.lax.scan(
+                    lambda c, inp: (c, ckpt(params, *inp)), None,
+                    (xs, ts))
+                nll = nll.swapaxes(0, 1).reshape(b, s)
+            else:
+                nll = self._chunk_nll(params, x, targets)
         return nll, aux
 
     def _loss_1f1b(self, params, batch, pipe_axis):
@@ -309,6 +318,7 @@ class TransformerLM(Module):
         def head(p, tok_mb):
             return self._embedded(p, tok_mb)
 
+        @jax.named_scope('head_loss')
         def tail(p, h, tgt):
             h = self.ln_f.apply(p['ln_f'], h)
             return self._chunk_nll(p, h, tgt)
@@ -356,10 +366,11 @@ class TransformerLM(Module):
         """Mean token cross-entropy (+ MoE balance loss), optional mask."""
         nll, aux = self.per_token_loss_with_aux(params, batch)
         mask = batch.get('mask')
-        if mask is not None:
-            ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-        else:
-            ce = jnp.mean(nll)
+        with jax.named_scope('head_loss'):
+            if mask is not None:
+                ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+            else:
+                ce = jnp.mean(nll)
         return ce + self.cfg.moe_aux_coef * aux
 
 

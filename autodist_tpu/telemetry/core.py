@@ -34,12 +34,27 @@ once drained batches stop being pushed.
 Thread safety: recording calls take a small lock (the session's
 pipeline/heartbeat threads and ``TransferPool`` workers all record);
 the lock is only reached when telemetry is enabled.
+
+The one exception to the gate is the **loop ring**
+(:meth:`Telemetry.loop_span`, :meth:`Telemetry.loop_event`): the
+``Trainer``'s handful of loop spans a step are recorded whether or not
+``AUTODIST_TELEMETRY`` is set, into a ring of :data:`LOOP_RING`
+records, because whoever profiles a run (the benchmark's traced run, an
+operator's ``Trainer.profile``) cannot switch anything on in a process
+that is already training. Each also opens a
+``jax.profiler.TraceAnnotation`` of the same name, which costs next to
+nothing while no profiler runs and puts the span on the host plane of
+the trace, beside the runtime's own events, when one does.
 """
 import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from autodist_tpu.const import ENV
+
+LOOP_RING = 1024   # records; five a Trainer step
 
 
 class _NullSpan:
@@ -82,6 +97,32 @@ class _Span:
         return False
 
 
+class _LoopSpan:
+    """One live loop span: a ``TraceAnnotation`` for the profiler and,
+    on exit, one record in the always-on loop ring."""
+
+    __slots__ = ('_tel', 'name', 'step', 'tags', '_annotation', '_t0')
+
+    def __init__(self, tel, name, step, tags):
+        self._tel = tel
+        self.name = name
+        self.step = step
+        self.tags = tags
+        self._annotation = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        self._tel._record_loop(self.name, self._t0, dur, self.step,
+                               self.tags)
+        return False
+
+
 class Telemetry:
     """The per-process telemetry registry.
 
@@ -105,6 +146,8 @@ class Telemetry:
         self._anchor_perf = time.perf_counter()
         self._spans = deque(maxlen=cap)
         self._events = deque(maxlen=cap)
+        # always on, fixed bound: see loop_span
+        self._loop = deque(maxlen=LOOP_RING)
         # cumulative per-span-name aggregates: survive both the ring
         # bound and drain_spans (the periodic batch push), like the
         # series' count/total — the snapshot must describe the whole
@@ -144,6 +187,40 @@ class Telemetry:
                 name, {'count': 0, 'total_s': 0.0})
             agg['count'] += 1
             agg['total_s'] += dur
+
+    def loop_span(self, name, step=None, **tags):
+        """A timed context manager for the training loop's own spans,
+        recorded whether or not telemetry is enabled: a
+        ``jax.profiler.TraceAnnotation`` named ``name`` and a record
+        ``{'name', 't0', 'dur', 'step'}`` (``t0`` a ``perf_counter``
+        reading, ``dur`` seconds, plus ``tags`` if any) in a ring of
+        :data:`LOOP_RING` records (:meth:`loop_records`). With
+        telemetry enabled the span also lands in the span buffer like
+        any other."""
+        return _LoopSpan(self, name, step, tags)
+
+    def loop_event(self, name, step=None, **tags):
+        """A point event in the loop ring (``dur`` is ``None``); with
+        telemetry enabled also an :meth:`event`."""
+        self._record_loop(name, time.perf_counter(), None, step, tags)
+
+    def _record_loop(self, name, t0, dur, step, tags):
+        rec = {'name': name, 't0': t0, 'dur': dur, 'step': step}
+        if tags:
+            rec['tags'] = tags
+        with self._lock:
+            self._loop.append(rec)
+        if self.enabled:
+            tags = dict(tags, step=step)
+            if dur is None:
+                self.event(name, **tags)
+            else:
+                self._record_span(name, t0, dur, tags)
+
+    def loop_records(self):
+        """The loop ring, oldest first (at most :data:`LOOP_RING`)."""
+        with self._lock:
+            return list(self._loop)
 
     def event(self, name, **tags):
         """A point (instant) event."""

@@ -1,0 +1,206 @@
+"""What the training step says about itself (PR 24): the named scopes and
+kernel names in the compiled step, and the ``Trainer``'s loop spans in
+the always-on ring of ``telemetry``. All on the CPU mesh, no subprocess."""
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from autodist_tpu import telemetry
+from autodist_tpu.api import Trainer
+from autodist_tpu.models.transformer import TransformerConfig, TransformerLM
+from autodist_tpu.parallel.axes import ParallelSpec
+from autodist_tpu.telemetry import core
+
+
+def batch_of(seq, rows=4, seed=0):
+    rng = np.random.RandomState(seed)
+    return {'tokens': rng.randint(0, 256, (rows, seq), dtype=np.int32),
+            'targets': rng.randint(0, 256, (rows, seq), dtype=np.int32)}
+
+
+def tiny_trainer(**cfg):
+    model = TransformerLM(TransformerConfig.tiny(max_len=32, **cfg))
+    return Trainer(model, optax.adamw(1e-3), spec=ParallelSpec(dp=1))
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """A fresh registry with ``AUTODIST_TELEMETRY`` unset."""
+    monkeypatch.delenv('AUTODIST_TELEMETRY', raising=False)
+    telemetry.reset()
+    yield telemetry.get()
+    telemetry.reset()
+
+
+def names(records):
+    return [(r['name'], r['step']) for r in records]
+
+
+# -- the device: scopes and kernel names -----------------------------------
+
+def test_compiled_step_carries_every_scope_name():
+    tr = tiny_trainer(remat=True)
+    state = tr.init(jax.random.PRNGKey(0))
+    text = tr.compile_step(state, batch_of(32)).as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for wanted in ('jit(step_fn)/optimizer/',
+                   'jit(step_fn)/jvp(embed)/',
+                   'jit(step_fn)/transpose(jvp(embed))/',
+                   'jit(step_fn)/jvp(head_loss)/',
+                   'jit(step_fn)/transpose(jvp(head_loss))/',
+                   'jvp()/while/body/closed_call/block/attention/',
+                   'jvp()/while/body/closed_call/block/mlp/',
+                   'transpose(jvp())/while/body/closed_call/checkpoint/'
+                   'block/mlp/',
+                   'transpose(jvp())/while/body/closed_call/checkpoint/'
+                   'rematted_computation/block/attention/'):
+        assert any(wanted in name for name in op_names), wanted
+
+
+def test_kernel_names_reach_the_tpu_lowering(monkeypatch):
+    """``pallas_call(name=...)`` is what tells dq from dkv in the
+    compiled step; interpret mode on the CPU drops it, so look at the
+    step lowered for the TPU (as ``test_tpu_bringup.py`` does)."""
+    from autodist_tpu.kernels import flash_attention as fa
+    monkeypatch.setattr(fa, '_interpret_default', lambda: False)
+    cfg = TransformerConfig.tiny(dtype=jnp.bfloat16, n_layers=1, max_len=512,
+                                 remat=True)
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1), spec=ParallelSpec(dp=1))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = batch_of(512)
+    step = tr._ensure_step(tr._step_key(batch), state, batch)
+    module = jax.export.export(step, platforms=['tpu'])(
+        state, tr.shard_batch(batch)).mlir_module()
+    calls = [line for line in module.splitlines() if 'tpu_custom_call' in line]
+    assert len(calls) == 4          # forward, forward again, dq, dkv
+    for kernel, count in (('flash_fwd', 2), ('flash_dq', 1), ('flash_dkv', 1)):
+        assert sum('kernel_name = "%s"' % kernel in line
+                   for line in calls) == count, kernel
+
+
+# -- the host: the loop ring -----------------------------------------------
+
+def test_fit_leaves_its_spans_in_the_ring_with_telemetry_off(ring):
+    assert not ring.enabled
+    tr = tiny_trainer()
+    state = tr.init(jax.random.PRNGKey(0))
+    state, history = tr.fit(state, iter([batch_of(32, seed=i)
+                                         for i in range(5)]),
+                            steps=3, prefetch=2)
+    assert len(history['loss']) == 3
+    records = ring.loop_records()
+    assert names(records) == [
+        ('trainer.init', None),
+        # the prefetcher fills two batches before the first is handed out
+        ('trainer.source', 1), ('trainer.place', 1),
+        ('trainer.source', 2), ('trainer.place', 2),
+        ('trainer.source', 3), ('trainer.place', 3), ('trainer.input', 1),
+        ('trainer.new_step_signature', 1), ('trainer.step', 1),
+        ('trainer.loss_readback', 1),
+        ('trainer.source', 4), ('trainer.place', 4), ('trainer.input', 2),
+        ('trainer.step', 2), ('trainer.loss_readback', 2),
+        ('trainer.source', 5), ('trainer.place', 5), ('trainer.input', 3),
+        ('trainer.step', 3), ('trainer.loss_readback', 3),
+        ('trainer.fit', 1)]
+    fit = records[-1]
+    assert fit['tags'] == {'steps': 3, 'prefetch': 2}
+    for r in records:
+        if r['name'] == 'trainer.new_step_signature':
+            assert r['dur'] is None and '(4, 32)' in r['tags']['shapes']
+        else:       # inside fit's span, on perf_counter's clock
+            assert r['dur'] >= 0
+            if r['name'] != 'trainer.init':
+                assert fit['t0'] <= r['t0'] <= fit['t0'] + fit['dur']
+    # nothing went to the gated buffers
+    snapshot = ring.metrics_snapshot()
+    assert snapshot['buffered_spans'] == 0 and snapshot['spans'] == {}
+    # steps are numbered over the trainer's life, not per call of fit
+    tr.fit(state, [batch_of(32)], prefetch=0)
+    assert names(ring.loop_records())[-5:] == [
+        ('trainer.input', 4), ('trainer.step', 4),
+        ('trainer.loss_readback', 4), ('trainer.input', 5),
+        ('trainer.fit', 4)]
+
+
+def test_no_span_takes_a_name_the_benchmark_reads(ring):
+    """``benchmark/trace_reduce.py`` counts the host spans named
+    ``fit.step`` as traced steps and keeps ``data.next``: a program span
+    of either name would halve every per-step metric."""
+    tr = tiny_trainer()
+    state = tr.init(jax.random.PRNGKey(0))
+    tr.compile_step(state, batch_of(32))
+    tr.fit(state, [batch_of(32)] * 2, eval_data=[batch_of(32)], prefetch=1)
+    seen = {r['name'] for r in ring.loop_records()}
+    assert seen == {'trainer.init', 'trainer.compile_step',
+                    'trainer.new_step_signature', 'trainer.fit',
+                    'trainer.input', 'trainer.source', 'trainer.place',
+                    'trainer.step', 'trainer.loss_readback', 'trainer.eval'}
+    import autodist_tpu
+    for path in glob.glob(autodist_tpu.__path__[0] + '/**/*.py',
+                          recursive=True):
+        with open(path) as f:
+            text = f.read()
+        assert "'fit.step'" not in text and "'data.next'" not in text, path
+
+
+def test_the_ring_stays_bounded(ring):
+    for i in range(core.LOOP_RING + 100):
+        with ring.loop_span('trainer.step', step=i):
+            pass
+    records = ring.loop_records()
+    assert len(records) == core.LOOP_RING
+    assert records[0]['step'] == 100 and records[-1]['step'] == \
+        core.LOOP_RING + 99
+
+
+def test_a_new_batch_shape_says_which_step_recompiled(ring):
+    tr = tiny_trainer()
+    state = tr.init(jax.random.PRNGKey(0))
+    for seq in (32, 32, 16, 16, 32):
+        state, _ = tr.step(state, batch_of(seq))
+    events = [r for r in ring.loop_records()
+              if r['name'] == 'trainer.new_step_signature']
+    assert [e['step'] for e in events] == [1, 3]
+    assert '(4, 32)' in events[0]['tags']['shapes']
+    assert '(4, 16)' in events[1]['tags']['shapes']
+
+
+def test_with_telemetry_on_the_loop_spans_are_exported_too(monkeypatch):
+    monkeypatch.setenv('AUTODIST_TELEMETRY', '1')
+    telemetry.reset()
+    try:
+        tel = telemetry.get()
+        with tel.loop_span('trainer.step', step=7):
+            pass
+        tel.loop_event('trainer.new_step_signature', step=7, shapes='x')
+        assert len(tel.loop_records()) == 2
+        span, event = tel.drain_spans()
+        assert span['name'] == 'trainer.step' and span['tags'] == {'step': 7}
+        assert event['name'] == 'trainer.new_step_signature'
+        assert event['tags'] == {'shapes': 'x', 'step': 7}
+    finally:
+        telemetry.reset()
+
+
+def test_profile_goes_through_step_with_the_python_tracer_off(ring, tmp_path):
+    from jax.profiler import ProfileData
+    tr = tiny_trainer()
+    state = tr.init(jax.random.PRNGKey(0))
+    out = tr.profile(state, batch_of(32), str(tmp_path / 'trace'), steps=2)
+    assert [n for n in names(ring.loop_records())
+            if n[0] == 'trainer.step'] == [('trainer.step', 1),
+                                           ('trainer.step', 2),
+                                           ('trainer.step', 3)]
+    found = glob.glob(out + '/**/*.xplane.pb', recursive=True)
+    assert len(found) == 1
+    host = [e.name for plane in ProfileData.from_file(found[0]).planes
+            if plane.name == '/host:CPU'
+            for line in plane.lines for e in line.events]
+    # the two traced steps' spans are on the trace; no Python frames
+    assert host.count('trainer.step') == 2
+    assert not any(name.startswith('$') for name in host)
