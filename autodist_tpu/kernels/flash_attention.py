@@ -4,30 +4,71 @@ The reference has no attention kernels at all (its models are TF graphs;
 SURVEY.md §2.3 lists no TP/SP) — this is TPU-native greenfield, the block
 primitive promised by parallel/ring_attention.py. Algorithm is the public
 flash-attention-2 recipe: the score matrix is never materialized in HBM;
-each (Q-block × KV-block) tile runs on the MXU with an online-softmax
-accumulator held in VMEM scratch, and the backward pass recomputes P from
-the saved logsumexp instead of storing it.
+each (Q-block × KV-block) tile runs on the MXU, softmax statistics stay
+in f32, and the backward pass recomputes P from the saved logsumexp
+instead of storing it.
 
-Layout: q/k/v are [batch, heads, seq, head_dim]; the grid is
-(batch, heads, q-blocks, kv-blocks) with the kv dimension innermost and
-sequential ("arbitrary") so the VMEM accumulators carry across kv steps;
-batch/heads/q-blocks are parallel. Causal masking is by global position,
-and fully-masked tiles are skipped with predication (the classic ~2x
-saving on causal attention).
+Layout: q/k/v are [batch, heads, seq, head_dim]. Three ``pallas_call``s
+(``flash_fwd``, ``flash_dq``, ``flash_dkv``; the benchmark reads them by
+these names) share one grid shape, (batch, heads / G, outer blocks,
+inner blocks): a grid step holds G heads of one tile and walks them in
+an unrolled loop, so the fixed cost of a step is paid once for G tiles
+and the scheduler can fill one head's softmax with the next one's
+matmuls. The inner dimension is sequential ("arbitrary") and carries
+the VMEM accumulators; the rest are parallel.
+
+What a step computes is chosen by Python ``if``s on the static plan
+(:func:`_plan`: block sizes and G per kernel, from ``seq``, ``causal``
+and the local head count), one body per kernel:
+
+* **One inner block** (``nk == 1`` for fwd/dq, ``nq == 1`` for dkv):
+  the step owns a whole row of tiles, so a plain max-subtracted softmax
+  writes ``o`` and ``lse`` (or the gradients) straight from it: no
+  running max, no rescale, no scratch. Under a causal mask a step
+  computes only the key (for dkv: query) ranges that hold an unmasked
+  position: the square the diagonal crosses, masked, and what lies
+  below it, with no mask (:func:`_for_the_live_row`).
+* **Several inner blocks**: online softmax / accumulation in VMEM
+  scratch. Causal tiles come in three kinds, told from ``qi``, ``ki``
+  and the block sizes: dead (above the diagonal: skipped, its index map
+  clamped to the nearest live block so nothing is fetched), crossed by
+  the diagonal (masked) and below it (no mask at all)
+  (:func:`_for_each_tile_kind`).
+* The row statistics ``lse`` and ``delta`` live in HBM as
+  ``[b, h, 1, s]``, the sequence along the lanes: as ``[b, h, s, 1]``
+  XLA lays them out ``T(8, 128)``, 128-fold padded (403 MB for
+  ``[96, 16, 512, 1]`` f32). ``flash_dkv`` computes the TRANSPOSED tile
+  ``k q^T`` ([bk, bq]), where they broadcast down the sublanes and
+  ``dv = p^T do``, ``dk = ds^T q`` are plain matmuls; ``flash_fwd`` and
+  ``flash_dq`` turn the row to a column once a head and step.
+* A softmax scale that is a power of two (head_dim 16, 64, 256) is
+  folded into ``q`` ([bq, d]) instead of multiplying every [bq, bk]
+  tile, which is exact in any binary float format; any other scale
+  stays on the tile.
+
+Where the time goes at head_dim 64 on a v5e (PERF.md §6, PR 25): QK^T
+contracts over 64 and PV yields 64 columns, so every matmul fills half
+of the 128 x 128 MXU, and q/k/v/o are lane-padded to 128 in HBM. The
+backward pair runs within 5% of that half-filled-MXU bound at seq 512,
+the forward within 20% of its (padded) HBM traffic.
 
 On the CPU backend the same kernels run in Pallas interpret mode, so the
 CPU test mesh exercises the identical code path (tests/test_flash_attention.py);
 every other backend compiles them.
 """
+import collections
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from autodist_tpu import telemetry
+
 NEG_INF = -1e30   # same masking constant as parallel/ring_attention.py
-_LANES = 128      # TPU lane width: m/l scratch replicate across lanes
+_LANES = 128      # TPU lane width
 
 
 def _pick_block(seq, target):
@@ -37,14 +78,64 @@ def _pick_block(seq, target):
     return None
 
 
-def _default_blocks(seq):
-    """Measured-on-v5e block heuristic: small tiles pay grid overhead at
-    long seq, so scale tile size with the sequence (q-block, kv-block)."""
-    if seq <= 256:
-        return 128, 128
-    if seq <= 1024:
-        return 256, 512
-    return 512, 1024
+def _block_targets(seq, causal):
+    """(q-block, kv-block) targets of each kernel; a sequence no longer
+    than a target is one block. From sweeps on a v5e at head_dim 64
+    (my chip runs, PR 25; PERF.md §6). A causal row of up to 1024 keys
+    is one inner block, so that each kernel computes only the live
+    ranges of its outer block; the outer block trades masked work
+    (smaller is less) against steps and branches (larger is fewer), and
+    each kernel settles elsewhere. Otherwise the forward is fastest
+    with 1024 x 1024 tiles (the whole row as far as that: the online
+    softmax costs more than the tiles it would skip), the backward pair
+    at 512 x 512."""
+    if causal and seq <= 1024:
+        return {'fwd': (512, 1024), 'dq': (256, 1024), 'dkv': (1024, 512)}
+    return {'fwd': (1024, 1024), 'dq': (512, 512), 'dkv': (512, 512)}
+
+
+# Score-tile elements (G x bq x bk) one grid step may hold; its f32
+# temporaries (s, p, dp, ds) are 4 bytes each of that, and the unrolled
+# head loop is compiled G times. 8 heads a step ran 1-2% faster than 4
+# at 512 x 512 and took twice as long to compile (my chip runs, PR 25).
+_STEP_TILE_ELEMS = 1 << 20
+_MAX_HEADS_PER_STEP = 8
+# The default scoped VMEM (16 MiB) is short of a 1024 x 1024 f32 tile
+# with its exp and bf16 copy; a v5e core has 128 MiB.
+_VMEM_LIMIT_BYTES = 64 << 20
+
+
+def _heads_per_step(heads, bq, bk):
+    """Largest divisor of the (local) head count, at most
+    ``_MAX_HEADS_PER_STEP``, whose tiles stay inside the step budget."""
+    target = max(1, min(_MAX_HEADS_PER_STEP, _STEP_TILE_ELEMS // (bq * bk)))
+    return max(g for g in range(1, target + 1) if heads % g == 0)
+
+
+Blocks = collections.namedtuple('Blocks', 'block_q block_k heads_per_step')
+Plan = collections.namedtuple('Plan', 'fwd dq dkv')   # a Blocks each
+
+
+def _tile_live(qi, ki, bq, bk):
+    """False only for tiles strictly above the causal diagonal
+    (fully masked -> safe to skip)."""
+    return qi * bq + bq - 1 >= ki * bk
+
+
+def _tile_crossed(qi, ki, bq, bk):
+    """True where some position of the tile lies above the diagonal."""
+    return ki * bk + bk - 1 > qi * bq
+
+
+def _tile_counts(seq, bq, bk, causal):
+    """(tiles, live, masked) of one (batch, head): tiles in the grid,
+    those that hold an unmasked position, and those of the live ones the
+    causal diagonal crosses (which need the mask)."""
+    grid = [(qi, ki) for qi in range(seq // bq) for ki in range(seq // bk)]
+    if not causal:
+        return len(grid), len(grid), 0
+    live = [t for t in grid if _tile_live(*t, bq, bk)]
+    return len(grid), len(live), sum(_tile_crossed(*t, bq, bk) for t in live)
 
 
 def supports(shape, block=128):
@@ -54,48 +145,160 @@ def supports(shape, block=128):
     return _pick_block(s, block) is not None
 
 
-# Measured crossover vs XLA's fused attention on v5e: at short seq the
-# whole score matrix fits on-chip and XLA's fusion wins; the kernel wins
-# once [S, S] spills to HBM (isolated fwd+bwd bf16: 1.2x at 2k, 28x at
-# 8k). Round-5 END-TO-END check on bert_large (remat, scanned layers)
-# moved the threshold from 1024 to 512: full-model tokens/s at seq 512
-# is ~10% HIGHER with the kernel (34.3k vs 31.0k at B=96) while seq
-# 128/256 strongly favor XLA (45.8k vs 32.6k; 40.2k vs 26.6k) — under
-# remat the attention recompute doubles the [S,S] traffic, which the
-# kernel avoids earlier than the isolated crossover suggested.
+# Crossover with XLA's fused attention, on the model path
+# (``MultiHeadAttention``: ``preferred``). Not re-measured since the
+# kernels got faster (PERF.md §7): on the ledger BERT-large reaches
+# 53.4% MFU at seq 128 under XLA's attention and 46% at seq 512 under
+# these kernels (38.5% before PR 25); no cell sits between.
 MIN_KERNEL_SEQ = 512
 
 
 def preferred(shape):
     """True when the Pallas kernel is expected to beat XLA's fused
-    attention for this [B, H, S, D] shape."""
-    return shape[2] >= MIN_KERNEL_SEQ and supports(shape)
+    attention for this [B, H, S, D] shape. The compiled kernels keep
+    ``lse`` with the sequence along the lanes, so every block has to be
+    lane-wide or the whole sequence (which it is up to the smallest
+    block target, 256); a longer sequence that only splits into slivers
+    is XLA's to win anyway."""
+    s = shape[2]
+    return (s >= MIN_KERNEL_SEQ and (s % _LANES == 0 or s <= 256)
+            and supports(shape))
 
 
 def _interpret_default():
     return jax.default_backend() == 'cpu'
 
 
-def _causal_mask(s, qi, ki, bq, bk):
-    """Apply the global-position causal mask to one [bq, bk] score tile."""
-    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(qpos >= kpos, s, NEG_INF)
+def _causal_mask(qi, ki, bq, bk, transposed=False):
+    """Boolean tile of the global-position causal mask: [bq, bk], or
+    [bk, bq] for the transposed tile of ``flash_dkv``."""
+    shape = (bk, bq) if transposed else (bq, bk)
+    q_dim = 1 if transposed else 0
+    qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
+    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    return qpos >= kpos
 
 
-def _tile_live(qi, ki, bq, bk):
-    """False only for tiles strictly above the causal diagonal
-    (fully masked -> safe to skip)."""
-    return qi * bq + bq - 1 >= ki * bk
+def _for_each_tile_kind(tile, qi, ki, bq, bk, seq, causal,
+                        transposed=False):
+    """Run ``tile(mask)`` for the kind of tile (qi, ki) is: not at all
+    for a dead one, with the causal mask where the diagonal crosses it,
+    with ``None`` otherwise. A kind the static grid does not contain is
+    not emitted."""
+    if not causal:
+        tile(None)
+        return
+    tiles, live, masked = _tile_counts(seq, bq, bk, True)
+    if masked == tiles:
+        tile(_causal_mask(qi, ki, bq, bk, transposed))
+        return
+    is_live = _tile_live(qi, ki, bq, bk)
+    crossed = _tile_crossed(qi, ki, bq, bk)
+    if masked:
+        when = crossed if live == tiles else jnp.logical_and(is_live,
+                                                             crossed)
+
+        @pl.when(when)
+        def _():
+            tile(_causal_mask(qi, ki, bq, bk, transposed))
+    if live > masked:
+        @pl.when(jnp.logical_and(is_live, jnp.logical_not(crossed)))
+        def _():
+            tile(None)
+
+
+def _for_the_live_row(rows, outer, n_outer, size, seq, causal,
+                      transposed=False):
+    """One-pass dispatch: the inner block is the whole sequence, so a
+    step owns a row of tiles. Run ``rows(parts)`` with the ``(lo, hi,
+    mask)`` ranges of the inner sequence that hold an unmasked position.
+    Not causal: the whole row. Causal: the square the diagonal crosses
+    (masked, ``size`` wide) and what lies below it (no mask): keys
+    before the q-block, or for the transposed tiles of ``flash_dkv``
+    queries after the kv-block. The ranges depend on the outer block, so
+    there is one static branch per outer block."""
+    if not causal:
+        rows([(0, seq, None)])
+        return
+    diagonal = _causal_mask(0, 0, size, size, transposed)
+    for i in range(n_outer):
+        lo, hi = i * size, (i + 1) * size
+        below = (hi, seq) if transposed else (0, lo)
+        parts = [(lo, hi, diagonal)]
+        if below[0] < below[1]:
+            parts.append(below + (None,))
+
+        if n_outer == 1:
+            rows(parts)
+        else:
+            pl.when(outer == i)(functools.partial(rows, parts))
+
+
+def _is_pow2(x):
+    return math.frexp(x)[0] == 0.5
+
+
+def _to_row(col):
+    """[n, 1] -> [1, n] (sublanes to lanes)."""
+    n = col.shape[0]
+    return jnp.transpose(jnp.broadcast_to(col, (n, _LANES)))[:1]
+
+
+def _to_col(row):
+    """[1, n] -> [n, 1] (lanes to sublanes)."""
+    n = row.shape[1]
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, n)))[:, :1]
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _scores(a, b, sm_scale, fold, mask):
+    """Masked, scaled ``a b^T`` in f32; a folded scale is already in q."""
+    s = _dot(a, b, _NT)
+    if not fold:
+        s = s * sm_scale
+    return s if mask is None else jnp.where(mask, s, NEG_INF)
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_scr, m_scr, l_scr,
-                *, sm_scale, causal, bq, bk, nk):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                sm_scale, fold, causal, bq, bk, nq, nk, g):
     qi, ki = pl.program_id(2), pl.program_id(3)
+
+    def query(h):
+        q = q_ref[0, h]                                       # [bq, D]
+        return q * sm_scale if fold else q
+
+    def rows(parts):
+        # plain softmax over the live keys of the row, by ranges
+        for h in range(g):
+            q = query(h)
+            ss = [_scores(q, k_ref[0, h, lo:hi], sm_scale, fold, mask)
+                  for lo, hi, mask in parts]
+            m = functools.reduce(jnp.maximum, [
+                jnp.max(s, axis=1, keepdims=True) for s in ss])
+            ps = [jnp.exp(s - m) for s in ss]
+            l = sum(jnp.sum(p, axis=1, keepdims=True) for p in ps)
+            acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, h, lo:hi], _NN)
+                      for p, (lo, hi, _) in zip(ps, parts))
+            o_ref[0, h] = (acc / l).astype(o_ref.dtype)
+            lse_ref[0, h] = _to_row(m + jnp.log(l))
+
+    if nk == 1:
+        _for_the_live_row(rows, qi, nq, bq, bk, causal)
+        return
+
+    acc_scr, m_scr, l_scr = scratch
 
     @pl.when(ki == 0)
     def _init():
@@ -103,71 +306,82 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_scr, m_scr, l_scr,
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    def tile():
-        q = q_ref[0, 0]                       # [bq, D]
-        k = k_ref[0, 0]                       # [bk, D]
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [bq, bk]
-        if causal:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        m_prev = m_scr[:, :1]                                 # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_blk = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new)                                # [bq, bk]
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    def tile(mask):
+        # online softmax: every row meets an unmasked key in its first
+        # live tile (key 0), so the running max is real from then on
+        for h in range(g):
+            v = v_ref[0, h]
+            s = _scores(query(h), k_ref[0, h], sm_scale, fold, mask)
+            m_prev = m_scr[h]                                 # [bq, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)                            # [bq, bk]
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + _dot(p.astype(v.dtype), v,
+                                                   _NN)
+            m_scr[h] = m_new
 
-    if causal:
-        @pl.when(_tile_live(qi, ki, bq, bk))
-        def _():
-            tile()
-    else:
-        tile()
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal)
 
     @pl.when(ki == nk - 1)
     def _emit():
-        l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[:, :1] + jnp.log(l))
+        for h in range(g):
+            l = l_scr[h]
+            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
+            lse_ref[0, h] = _to_row(m_scr[h] + jnp.log(l))
 
 
-def _fwd(q, k, v, causal, sm_scale, bq, bk, interpret):
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=('parallel', 'parallel', 'parallel', 'arbitrary'),
+    vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _static(kernel, s, causal, sm_scale, blocks):
+    """``kernel`` with what a call fixes at trace time."""
+    bq, bk, g = blocks
+    return functools.partial(
+        kernel, sm_scale=sm_scale, fold=_is_pow2(sm_scale), causal=causal,
+        bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g)
+
+
+def _kv_index(causal, bq, bk):
+    """Index map of a K/V block on a (b, h, qi, ki) grid. A dead causal
+    tile asks for the last live block of its row again, which the
+    pipeline already holds, so it fetches nothing."""
+    if not causal:
+        return lambda b, h, i, j: (b, h, j, 0)
+    return lambda b, h, i, j: (
+        b, h, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
+
+
+def _fwd(q, k, v, causal, sm_scale, blocks, interpret):
     b, h, s, d = q.shape
+    bq, bk, g = blocks
     nq, nk = s // bq, s // bk
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale,
-                               causal=causal, bq=bq, bk=bk, nk=nk)
+    kv_index = _kv_index(causal, bq, bk)
+    scratch = [] if nk == 1 else [
+        pltpu.VMEM((g, bq, d), jnp.float32),
+        pltpu.VMEM((g, bq, 1), jnp.float32),
+        pltpu.VMEM((g, bq, 1), jnp.float32),
+    ]
     o, lse = pl.pallas_call(
-        kernel,
-        grid=(b, h, nq, nk),
+        _static(_fwd_kernel, s, causal, sm_scale, blocks),
+        grid=(b, h // g, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, g, bk, d), kv_index),
+            pl.BlockSpec((1, g, bk, d), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, g, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'parallel',
-                                 'arbitrary')),
+        scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name='flash_fwd',
     )(q, k, v)
@@ -179,178 +393,250 @@ def _fwd(q, k, v, causal, sm_scale, bq, bk, interpret):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, sm_scale, causal, bq, bk, nk):
+               *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g):
     qi, ki = pl.program_id(2), pl.program_id(3)
+
+    def grad(h, parts):
+        """dq of head ``h`` from the key ranges ``parts`` of the block."""
+        q = q_ref[0, h]
+        if fold:
+            q = q * sm_scale
+        do = do_ref[0, h]
+        lse = _to_col(lse_ref[0, h])                          # [bq, 1]
+        delta = _to_col(delta_ref[0, h])
+        dq = 0.
+        for lo, hi, mask in parts:
+            k = k_ref[0, h, lo:hi]
+            s = _scores(q, k, sm_scale, fold, mask)
+            p = jnp.exp(s - lse)                              # [bq, keys]
+            dp = _dot(do, v_ref[0, h, lo:hi], _NT)
+            ds = p * (dp - delta)
+            if not fold:
+                ds = ds * sm_scale
+            dq = dq + _dot(ds.astype(k.dtype), k, _NN)
+        return dq
+
+    def finish(dq):
+        # a folded scale multiplied q, not the tile: give dq its factor
+        return (dq * sm_scale if fold else dq).astype(dq_ref.dtype)
+
+    def rows(parts):
+        for h in range(g):
+            dq_ref[0, h] = finish(grad(h, parts))
+
+    if nk == 1:
+        _for_the_live_row(rows, qi, nq, bq, bk, causal)
+        return
+
+    dq_scr, = scratch
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def tile():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                                   # [bq, 1]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        p = jnp.exp(s - lse)                                  # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, bk]
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(mask):
+        for h in range(g):
+            dq_scr[h] = dq_scr[h] + grad(h, [(0, bk, mask)])
 
-    if causal:
-        @pl.when(_tile_live(qi, ki, bq, bk))
-        def _():
-            tile()
-    else:
-        tile()
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal)
 
     @pl.when(ki == nk - 1)
     def _emit():
-        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+        for h in range(g):
+            dq_ref[0, h] = finish(dq_scr[h])
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, sm_scale, causal, bq, bk, nq):
+                dk_ref, dv_ref, *scratch,
+                sm_scale, fold, causal, bq, bk, nq, nk, g):
     ki, qi = pl.program_id(2), pl.program_id(3)
+
+    def grads(h, parts):
+        """(dk, dv) of head ``h`` from the query ranges ``parts`` of the
+        block, on TRANSPOSED tiles [bk, queries]: lse and delta are rows
+        of them, and both gradients plain matmuls."""
+        k = k_ref[0, h]
+        v = v_ref[0, h]
+        dk = dv = 0.
+        for lo, hi, mask in parts:
+            q = q_ref[0, h, lo:hi]
+            if fold:
+                q = q * sm_scale       # dk = ds^T (q * scale) as well
+            do = do_ref[0, h, lo:hi]
+            s = _scores(k, q, sm_scale, fold, mask)           # [bk, queries]
+            p = jnp.exp(s - lse_ref[0, h, :, lo:hi])
+            dv = dv + _dot(p.astype(do.dtype), do, _NN)       # [bk, D]
+            dp = _dot(v, do, _NT)
+            ds = p * (dp - delta_ref[0, h, :, lo:hi])
+            if not fold:
+                ds = ds * sm_scale
+            dk = dk + _dot(ds.astype(q.dtype), q, _NN)
+        return dk, dv
+
+    def rows(parts):
+        for h in range(g):
+            dk, dv = grads(h, parts)
+            dk_ref[0, h] = dk.astype(dk_ref.dtype)
+            dv_ref[0, h] = dv.astype(dv_ref.dtype)
+
+    if nq == 1:
+        _for_the_live_row(rows, ki, nk, bk, bq, causal, transposed=True)
+        return
+
+    dk_scr, dv_scr = scratch
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def tile():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        p = jnp.exp(s - lse)                                  # [bq, bk]
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def tile(mask):
+        for h in range(g):
+            dk, dv = grads(h, [(0, bq, mask)])
+            dk_scr[h] = dk_scr[h] + dk
+            dv_scr[h] = dv_scr[h] + dv
 
-    if causal:
-        @pl.when(_tile_live(qi, ki, bq, bk))
-        def _():
-            tile()
-    else:
-        tile()
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
+                        transposed=True)
 
     @pl.when(qi == nq - 1)
     def _emit():
-        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+        for h in range(g):
+            dk_ref[0, h] = dk_scr[h].astype(dk_ref.dtype)
+            dv_ref[0, h] = dv_scr[h].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk, interpret):
+def _dq(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
     b, h, s, d = q.shape
+    bq, bk, g = blocks
     nq, nk = s // bq, s // bk
-    # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                   # [B, H, S, 1]
-
-    qkv_spec = [
-        pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda b, h, i, j: (b, h, j, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
-    ]
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          bq=bq, bk=bk, nk=nk),
-        grid=(b, h, nq, nk),
-        in_specs=qkv_spec,
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b, h, i, j: (b, h, i, 0)),
+    q_spec = pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((1, g, bk, d), _kv_index(causal, bq, bk))
+    row_spec = pl.BlockSpec((1, g, 1, bq), lambda b, h, i, j: (b, h, 0, i))
+    return pl.pallas_call(
+        _static(_dq_kernel, s, causal, sm_scale, blocks),
+        grid=(b, h // g, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'parallel',
-                                 'arbitrary')),
+        scratch_shapes=[] if nk == 1 else [
+            pltpu.VMEM((g, bq, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name='flash_dq',
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid iterates q-blocks innermost for each kv-block
-    kv_first_spec = [
-        pl.BlockSpec((1, 1, bq, d), lambda b, h, j, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
-        pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
-        pl.BlockSpec((1, 1, bq, d), lambda b, h, j, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0)),
-    ]
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          bq=bq, bk=bk, nq=nq),
-        grid=(b, h, nk, nq),
-        in_specs=kv_first_spec,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, i: (b, h, j, 0)),
-        ],
+
+def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
+    b, h, s, d = q.shape
+    bq, bk, g = blocks
+    nq, nk = s // bq, s // bk
+    # the grid iterates q-blocks innermost for each kv-block; the dead
+    # causal tiles come first there, and ask for the first live q-block
+    if causal:
+        def q_row(j, i):
+            return jnp.maximum(i, (j * bk) // bq)
+    else:
+        def q_row(j, i):
+            return i
+    q_spec = pl.BlockSpec(
+        (1, g, bq, d), lambda b, h, j, i: (b, h, q_row(j, i), 0))
+    kv_spec = pl.BlockSpec((1, g, bk, d), lambda b, h, j, i: (b, h, j, 0))
+    row_spec = pl.BlockSpec(
+        (1, g, 1, bq), lambda b, h, j, i: (b, h, 0, q_row(j, i)))
+    return pl.pallas_call(
+        _static(_dkv_kernel, s, causal, sm_scale, blocks),
+        grid=(b, h // g, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, d), k.dtype),
             jax.ShapeDtypeStruct((b, h, s, d), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'parallel',
-                                 'arbitrary')),
+        scratch_shapes=[] if nq == 1 else [
+            pltpu.VMEM((g, bk, d), jnp.float32),
+            pltpu.VMEM((g, bk, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name='flash_dkv',
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+
+
+def _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret):
+    # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]                   # [B, H, 1, S]
+    args = (q, k, v, do, lse, delta, causal, sm_scale)
+    dk, dv = _dkv(*args, plan.dkv, interpret)
+    return _dq(*args, plan.dq, interpret), dk, dv
 
 
 # ---------------------------------------------------------------------------
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, bq, bk, interpret):
-    o, _ = _fwd(q, k, v, causal, sm_scale, bq, bk, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, sm_scale, plan, interpret):
+    o, _ = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret)
     return o
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, bq, bk, interpret):
-    o, lse = _fwd(q, k, v, causal, sm_scale, bq, bk, interpret)
+def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret):
+    o, lse = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, sm_scale, bq, bk, interpret, res, do):
+def _flash_bwd(causal, sm_scale, plan, interpret, res, do):
     q, k, v, o, lse = res
-    return _bwd(q, k, v, o, lse, do, causal, sm_scale, bq, bk, interpret)
+    return _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _blocks(heads, seq, targets, block_q, block_k):
+    sizes = []
+    for asked, target in zip((block_q, block_k), targets):
+        size = seq if not asked and seq <= target else \
+            _pick_block(seq, asked or target)
+        if size is None:
+            raise ValueError('flash_attention: seq %d not blockable; check '
+                             'supports() first' % seq)
+        sizes.append(size)
+    return Blocks(*sizes, _heads_per_step(heads, *sizes))
+
+
+def _plan(shape, causal, block_q=None, block_k=None):
+    """The static plan of a call on [b, h, s, d] operands: for each
+    kernel the block sizes (the arguments, else the kernel's targets,
+    cut to divisors of ``s``) and the heads a grid step holds."""
+    _, h, s, _ = shape
+    return Plan(**{kernel: _blocks(h, s, targets, block_q, block_k)
+                   for kernel, targets in _block_targets(s, causal).items()})
+
+
+def _plan_tags(plan, seq, causal):
+    """The plan as the ``flash.plan`` event records it. Per kernel
+    (the forward's keys have no prefix): blocks, heads a step, whether
+    one inner block is the row, and per (batch, head) the tiles it
+    computes on (``tile_q`` x ``tile_k``: the block, or under the causal
+    one-pass the squares of the live row), those that hold an unmasked
+    position and those the causal diagonal crosses."""
+    tags = {}
+    for prefix, (bq, bk, g) in zip(('', 'dq_', 'dkv_'), plan):
+        transposed = prefix == 'dkv_'
+        one_pass = (bq if transposed else bk) == seq
+        tq, tk = bq, bk
+        if causal and one_pass:
+            tq = tk = bk if transposed else bq
+        tiles, live, masked = _tile_counts(seq, tq, tk, causal)
+        tags.update({prefix + 'block_q': bq, prefix + 'block_k': bk,
+                     prefix + 'heads_per_step': g,
+                     prefix + 'one_pass': one_pass,
+                     prefix + 'tile_q': tq, prefix + 'tile_k': tk,
+                     prefix + 'tiles': tiles, prefix + 'live_tiles': live,
+                     prefix + 'masked_tiles': masked})
+    return tags
 
 
 def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
@@ -359,19 +645,23 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
 
     Differentiable (custom VJP, flash backward). Requires ``seq`` to
     split into uniform blocks (``supports()``); callers fall back to the
-    jnp path otherwise. Block sizes default to a measured seq-dependent
-    heuristic. ``interpret`` defaults to True on the CPU backend only
-    (so the same kernel code runs on the CPU test mesh); any other
-    backend compiles the kernel or fails.
+    jnp path otherwise. Block sizes default to measured per-kernel
+    targets (``_block_targets``). ``interpret`` defaults to True on the
+    CPU backend only (so the same kernel code runs on the CPU test
+    mesh); any other backend compiles the kernel or fails.
+
+    Each trace leaves one ``flash.plan`` point event in the loop ring
+    (``telemetry.get().loop_records()``): the static plan of the call
+    (``_plan_tags``).
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    dq_blk, dk_blk = _default_blocks(q.shape[2])
-    bq = _pick_block(q.shape[2], block_q or dq_blk)
-    bk = _pick_block(q.shape[2], block_k or dk_blk)
-    if bq is None or bk is None:
-        raise ValueError('flash_attention: seq %d not blockable; check '
-                         'supports() first' % q.shape[2])
+    sm_scale = float(sm_scale)
+    plan = _plan(q.shape, causal, block_q, block_k)
     if interpret is None:
         interpret = _interpret_default()
-    return _flash(q, k, v, causal, float(sm_scale), bq, bk, interpret)
+    telemetry.get().loop_event(
+        'flash.plan', seq=q.shape[2], head_dim=q.shape[3],
+        causal=bool(causal), fold_scale=_is_pow2(sm_scale),
+        **_plan_tags(plan, q.shape[2], causal))
+    return _flash(q, k, v, causal, sm_scale, plan, interpret)

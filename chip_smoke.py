@@ -185,9 +185,9 @@ def _check_flash(shape, causal, interpret):
                           grads_of(reference)(q, k, v)):
         ratios.append(_check_close('flash %s %s' % (name, shape), g, r,
                                    KERNEL_RTOL))
-    say('kernel flash %s causal=%s blocks=%s: fwd+bwd match plain jnp '
+    say('kernel flash %s causal=%s %s: fwd+bwd match plain jnp '
         '(worst error/scale %.2g, tolerance %.2g)'
-        % (shape, causal, fa._default_blocks(shape[2]), max(ratios),
+        % (shape, causal, fa._plan(shape, causal), max(ratios),
            KERNEL_RTOL))
     return elapsed
 

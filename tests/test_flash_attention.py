@@ -66,7 +66,7 @@ def test_long_seq_asymmetric_blocks():
     """The production regime: seq >= MIN_KERNEL_SEQ picks asymmetric
     default blocks (bq=512, bk=1024) — partial causal tiles span
     multiple q-blocks per kv-block, a code shape short-seq tests miss."""
-    assert fa._default_blocks(2048) == (512, 1024)
+    assert fa._plan((1, 1, 2048, 16), True).fwd[:2] == (1024, 1024)
     rng = np.random.RandomState(4)
     q, k, v = _rand_qkv(rng, (1, 1, 2048, 16))
     got = fa.flash_attention(q, k, v, causal=True)
@@ -75,12 +75,127 @@ def test_long_seq_asymmetric_blocks():
                                atol=5e-5, rtol=5e-5)
 
 
+# Every branch the static plan can take, one case each: (heads, seq,
+# head_dim, causal, block_q, block_k, heads a step). head_dim 16 and 64
+# have a power-of-two softmax scale (folded into q), 32 does not.
+_PLAN_CASES = {
+    # nk == 1, one head a step, folded scale
+    'one_pass-g1-d64': (2, 64, 64, False, 32, 64, 1),
+    # nk == 1, the whole head count a step, scale on the tile
+    'one_pass-gall-d32': (2, 64, 32, False, 32, 64, 2),
+    # nk > 1: online softmax, scratch accumulators, 3 heads (no even G)
+    'online-g3-d16': (3, 96, 16, False, 32, 32, 3),
+    # nq == 1: flash_dkv writes straight from the tile, fwd/dq do not
+    'dkv_one_pass-g2-d32': (2, 64, 32, False, 64, 32, 2),
+    # causal, nq = 3: dead, diagonal-crossed and unmasked tiles in one call
+    'causal-kinds-g2-d32': (4, 96, 32, True, 32, 32, 2),
+    # causal, kv-block wider than the q-block, 12 heads at G = 6
+    'causal-wide_k-g6-d64': (12, 128, 64, True, 32, 64, 6),
+    # causal, q-block taller than the kv-block
+    'causal-tall_q-g1-d64': (2, 128, 64, True, 64, 32, 1),
+    # causal and nk == 1: every tile is live and crossed (static mask)
+    'causal-one_pass-g2-d32': (2, 128, 32, True, 32, 128, 2),
+    # causal and nq == 1: flash_dkv's live row (queries after the block)
+    'causal-dkv_one_pass-g2-d32': (2, 128, 32, True, 128, 32, 2),
+    # causal, one tile in all
+    'causal-one_tile-g1-d16': (1, 64, 16, True, 64, 64, 1),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_PLAN_CASES))
+def test_plan_branch_parity(case):
+    """Forward and all three gradients of the kernels, run with an
+    explicit plan, against the plain f32 attention."""
+    h, s, d, causal, bq, bk, g = _PLAN_CASES[case]
+    rng = np.random.RandomState(7)
+    q, k, v = _rand_qkv(rng, (2, h, s, d))
+    w = jnp.asarray(rng.randn(2, h, s, d), jnp.float32)
+    scale = d ** -0.5
+    assert fa._is_pow2(scale) == (d in (16, 64))
+    blocks = fa.Blocks(bq, bk, g)
+    plan = fa.Plan(blocks, blocks, blocks)
+
+    def kernel(q, k, v):
+        return fa._flash(q, k, v, causal, scale, plan, True)
+
+    def plain(q, k, v):
+        return local_flash_attention(q, k, v, causal=causal)
+
+    def grads(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * w), argnums=(0, 1, 2))
+
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(plain(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+    (got_l, got), (want_l, want) = grads(kernel)(q, k, v), grads(plain)(q, k, v)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-4)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+
+
+@pytest.mark.parametrize('heads,want', [(16, 8), (8, 8), (12, 6), (3, 3),
+                                        (2, 2), (7, 7), (11, 1)])
+def test_heads_per_step_divides_the_head_count(heads, want):
+    assert fa._heads_per_step(heads, 32, 32) == want
+    # a step never holds more score-tile elements than the budget
+    g = fa._heads_per_step(heads, 512, 1024)
+    assert heads % g == 0
+    assert g == 1 or g * 512 * 1024 <= fa._STEP_TILE_ELEMS
+
+
+@pytest.mark.parametrize('seq,bq,bk', [(1024, 256, 512), (1024, 256, 256),
+                                       (1024, 512, 1024), (96, 32, 32),
+                                       (128, 64, 32), (4096, 512, 1024)])
+def test_tile_counts_match_brute_force(seq, bq, bk):
+    """Live = holds a position at or below the diagonal; masked = live
+    and holds one above it. Counted position by position here."""
+    rows, cols = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    allowed = cols <= rows
+    live = masked = 0
+    for qi in range(seq // bq):
+        for ki in range(seq // bk):
+            t = allowed[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            live += bool(t.any())
+            masked += bool(t.any() and not t.all())
+    tiles = (seq // bq) * (seq // bk)
+    assert fa._tile_counts(seq, bq, bk, True) == (tiles, live, masked)
+    assert fa._tile_counts(seq, bq, bk, False) == (tiles, tiles, 0)
+
+
+@pytest.mark.parametrize('causal', [True, False])
+def test_default_plan_parity_at_the_crossover(causal):
+    """seq 512 with the blocks ``_plan`` picks itself: one tile a head,
+    but for the causal ``flash_dq``, which walks the live key ranges of
+    two q-blocks."""
+    plan = fa._plan((1, 2, 512, 16), causal)
+    assert [b[:2] for b in plan] == (
+        [(512, 512), (256, 512), (512, 512)] if causal
+        else [(512, 512)] * 3)
+    rng = np.random.RandomState(5)
+    q, k, v = _rand_qkv(rng, (1, 2, 512, 16))
+
+    def grads(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v, causal=causal) ** 2),
+            argnums=(0, 1, 2))
+
+    (got_l, got), (want_l, want) = (grads(fa.flash_attention)(q, k, v),
+                                    grads(local_flash_attention)(q, k, v))
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-4)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-4, rtol=5e-4)
+
+
 def test_supports_and_preferred():
     assert fa.supports((1, 1, 128, 64))
     assert fa.supports((1, 1, 40, 64))      # divisible by 8
     assert not fa.supports((1, 1, 7, 64))   # not blockable
     assert not fa.preferred((1, 1, 128, 64))   # short seq: XLA wins
     assert fa.preferred((1, 1, 2048, 64))
+    assert not fa.preferred((1, 1, 520, 64))   # no lane-wide blocks
 
 
 def test_tp_mesh_dispatches_via_nested_manual(monkeypatch):

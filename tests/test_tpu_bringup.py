@@ -68,6 +68,72 @@ def test_kernel_regions_lower_for_tpu(monkeypatch, spec_kw, seq):
     assert 'tpu_custom_call' in exported.mlir_module()
 
 
+@pytest.mark.parametrize('local_shape,causal,dp', [
+    ((96, 16, 512, 64), False, 1),      # bert-large.s512.c1
+    ((32, 16, 1024, 64), True, 1),      # gpt2-medium.s1024.c1
+    ((96, 16, 512, 64), False, 4),      # bert-large.s512.dp4's shard_map
+], ids=['s512', 's1024_causal', 's512_dp4'])
+def test_flash_step_is_three_named_kernels(local_shape, causal, dp):
+    """The benchmark reads the kernels of a step by name
+    (``benchmark/scope_reduce.py``): forward and backward of
+    ``flash_attention`` at the cells' shapes are exactly three Mosaic
+    calls, ``flash_fwd``, ``flash_dq`` and ``flash_dkv``; and tracing
+    leaves the static plan in the loop ring, with tile counts that a
+    position-by-position count confirms."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from autodist_tpu import telemetry
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.parallel.axes import shard_map
+
+    def attend(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, interpret=False)
+
+    shape, sharding = local_shape, None
+    if dp > 1:
+        mesh = Mesh(np.array(jax.devices()[:dp]), ('data',))
+        attend = shard_map(attend, mesh, (P('data'),) * 3, P('data'))
+        shape = (dp * shape[0],) + shape[1:]
+        sharding = NamedSharding(mesh, P('data'))
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    n_before = len(telemetry.get().loop_records())
+    exported = jax.export.export(
+        jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2))), platforms=['tpu'])(x, x, x)
+    text = exported.mlir_module()
+    assert text.count('@tpu_custom_call') == 3
+    assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
+        'flash_dkv', 'flash_dq', 'flash_fwd']
+
+    plans = [r for r in telemetry.get().loop_records()[n_before:]
+             if r['name'] == 'flash.plan']
+    assert plans and plans[-1]['dur'] is None
+    tags = plans[-1]['tags']
+    seq = tags['seq']
+    assert (seq, tags['head_dim'], tags['causal']) == (
+        local_shape[2], local_shape[3], causal)
+    allowed = np.tril(np.ones((seq, seq), bool)) if causal else \
+        np.ones((seq, seq), bool)
+    for kernel in ('', 'dq_', 'dkv_'):
+        bq, bk = tags[kernel + 'tile_q'], tags[kernel + 'tile_k']
+        assert local_shape[1] % tags[kernel + 'heads_per_step'] == 0
+        assert tags[kernel + 'one_pass'] == (
+            tags[kernel + ('block_q' if kernel == 'dkv_' else 'block_k')]
+            == seq)
+        tiles = [allowed[i:i + bq, j:j + bk]
+                 for i in range(0, seq, bq) for j in range(0, seq, bk)]
+        assert tags[kernel + 'tiles'] == len(tiles)
+        assert tags[kernel + 'live_tiles'] == sum(t.any() for t in tiles)
+        assert tags[kernel + 'masked_tiles'] == sum(
+            t.any() and not t.all() for t in tiles)
+
+
 # -- chip_smoke.py ---------------------------------------------------------
 
 def _run_smoke(*args, devices=1):
