@@ -41,6 +41,18 @@ and the local head count), one body per kernel:
   ``k q^T`` ([bk, bq]), where they broadcast down the sublanes and
   ``dv = p^T do``, ``dk = ds^T q`` are plain matmuls; ``flash_fwd`` and
   ``flash_dq`` turn the row to a column once a head and step.
+* A **band call** (``window = (left, right)``: query i sees keys
+  i - left .. i + right; ModernBERT's window layers) is planned from
+  the band and not from the sequence: blocks as wide as the band
+  reaches to one side (:func:`_band_targets`), an inner grid dimension
+  as long as the longest run of inner blocks an outer block's band
+  reaches (:func:`_band_inner_blocks`; three at 128 x 128 blocks and
+  64 keys each side), index maps that walk that run and clamp the steps outside
+  the sequence onto a block the pipeline already holds
+  (:func:`_band_fetch`), and the band's mask only on tiles an edge of
+  the band crosses. The three calls are then named ``flash_fwd_band``,
+  ``flash_dq_band``, ``flash_dkv_band``. With ``window=None`` nothing
+  of this is on the path.
 * A softmax scale that is a power of two (head_dim 16, 64, 256) is
   folded into ``q`` ([bq, d]) instead of multiplying every [bq, bk]
   tile, which is exact in any binary float format; any other scale
@@ -78,7 +90,7 @@ def _pick_block(seq, target):
     return None
 
 
-def _block_targets(seq, causal):
+def _block_targets(seq, causal, window=None):
     """(q-block, kv-block) targets of each kernel; a sequence no longer
     than a target is one block. From sweeps on a v5e at head_dim 64
     (my chip runs, PR 25; PERF.md §6). A causal row of up to 1024 keys
@@ -88,10 +100,36 @@ def _block_targets(seq, causal):
     each kernel settles elsewhere. Otherwise the forward is fastest
     with 1024 x 1024 tiles (the whole row as far as that: the online
     softmax costs more than the tiles it would skip), the backward pair
-    at 512 x 512."""
+    at 512 x 512. A band call (``window``) is sized to the band and not
+    to the sequence (:func:`_band_targets`)."""
+    if window is not None:
+        return _band_targets(window)
     if causal and seq <= 1024:
         return {'fwd': (512, 1024), 'dq': (256, 1024), 'dkv': (1024, 512)}
     return {'fwd': (1024, 1024), 'dq': (512, 512), 'dkv': (512, 512)}
+
+
+# Targets of a band call at ModernBERT's reach (64 keys each side), from
+# a sweep of {128, 256, 512}^2 at [4, 16, 8192, 64] on a v5e (my chip
+# runs, PR 26; PERF.md §6): ms a call, forward / dq / dkv, 3.81 / 3.09 /
+# 2.51 at 128 x 128 for all three against 3.54 / 2.39 / 2.35 here. A
+# tile costs about 0.19 us a head whatever its size, and an element of
+# it 7.6 ps, so tiles larger than the band pay in elements what they
+# save in steps; each kernel settles elsewhere.
+_BAND_TARGETS = {'fwd': (128, 512), 'dq': (256, 256), 'dkv': (128, 256)}
+
+
+def _band_targets(window):
+    """Block targets of a band call: the measured targets for a band
+    that reaches up to a lane-wide block (128) to either side, scaled up
+    by powers of two for a wider one, so that an outer block meets a
+    few inner blocks and the tiles stay close to the band's width."""
+    reach = max(window)
+    scale = 1
+    while scale * _LANES < reach:
+        scale *= 2
+    return {kernel: (scale * bq, scale * bk)
+            for kernel, (bq, bk) in _BAND_TARGETS.items()}
 
 
 # Score-tile elements (G x bq x bk) one grid step may hold; its f32
@@ -127,10 +165,71 @@ def _tile_crossed(qi, ki, bq, bk):
     return ki * bk + bk - 1 > qi * bq
 
 
-def _tile_counts(seq, bq, bk, causal):
+# A band call (``window = (left, right)``: query i sees keys i - left ..
+# i + right) walks, for each outer block, only the inner blocks the band
+# reaches: grid step ``j`` of the inner dimension is inner block
+# ``_band_first + j``, and the inner dimension is as long as the longest
+# such run. ``back`` and ``ahead`` are the band's reach from the outer
+# block towards lower and higher positions: (left, right) where queries
+# are the outer blocks, (right, left) for ``flash_dkv``.
+
+def _band_reach(window, transposed):
+    left, right = window
+    return (right, left) if transposed else (left, right)
+
+
+def _band_first(outer, size, inner_size, back):
+    """First inner block the band reaches from outer block ``outer``;
+    negative where the band starts before the sequence does."""
+    return (outer * size - back) // inner_size
+
+
+def _band_last(outer, size, inner_size, ahead):
+    return (outer * size + size - 1 + ahead) // inner_size
+
+
+def _band_inner_blocks(seq, size, inner_size, window, transposed):
+    """Length of the inner grid dimension of a band call."""
+    back, ahead = _band_reach(window, transposed)
+    return max(_band_last(o, size, inner_size, ahead)
+               - _band_first(o, size, inner_size, back) + 1
+               for o in range(seq // size))
+
+
+def _band_tile_live(qi, ki, bq, bk, seq, window):
+    """Whether tile (qi, ki) is inside the sequence and holds a pair of
+    the band."""
+    left, right = window
+    return ((ki >= 0) & (ki < seq // bk) & (qi >= 0) & (qi < seq // bq)
+            & (ki * bk + bk - 1 >= qi * bq - left)
+            & (ki * bk <= qi * bq + bq - 1 + right))
+
+
+def _band_tile_crossed(qi, ki, bq, bk, window):
+    """True where some pair of the tile lies outside the band."""
+    left, right = window
+    return ((ki * bk < qi * bq + bq - 1 - left)
+            | (ki * bk + bk - 1 > qi * bq + right))
+
+
+def _tile_counts(seq, bq, bk, causal, window=None, transposed=False):
     """(tiles, live, masked) of one (batch, head): tiles in the grid,
     those that hold an unmasked position, and those of the live ones the
-    causal diagonal crosses (which need the mask)."""
+    causal diagonal, or an edge of the band, crosses (which need the
+    mask). The grid of a band call holds the band's tiles only."""
+    if window is not None:
+        size, inner = (bk, bq) if transposed else (bq, bk)
+        back, _ = _band_reach(window, transposed)
+        grid = [(o, _band_first(o, size, inner, back) + j)
+                for o in range(seq // size) for j in range(
+                    _band_inner_blocks(seq, size, inner, window,
+                                       transposed))]
+        if transposed:
+            grid = [(qi, ki) for ki, qi in grid]
+        live = [t for t in grid
+                if _band_tile_live(*t, bq, bk, seq, window)]
+        return len(grid), len(live), sum(
+            bool(_band_tile_crossed(*t, bq, bk, window)) for t in live)
     grid = [(qi, ki) for qi in range(seq // bq) for ki in range(seq // bk)]
     if not causal:
         return len(grid), len(grid), 0
@@ -138,69 +237,113 @@ def _tile_counts(seq, bq, bk, causal):
     return len(grid), len(live), sum(_tile_crossed(*t, bq, bk) for t in live)
 
 
-def supports(shape, block=128):
+def check_window(window, causal=False):
+    """``window`` as a pair of ints, or None; a band under a causal mask
+    is refused, not guessed at."""
+    if window is None:
+        return None
+    left, right = (int(w) for w in window)
+    if left < 0 or right < 0:
+        raise ValueError('flash_attention: window %r must be (left, right) '
+                         'with neither negative' % (window,))
+    if causal:
+        raise ValueError('flash_attention: a window under a causal mask is '
+                         'not supported; pass causal=False, or window='
+                         '(left, 0) for a causal band')
+    return left, right
+
+
+def supports(shape, block=128, window=None):
     """Whether flash_attention can run for [B, H, S, D] (S divisible
-    into >=8-row blocks)."""
+    into >=8-row blocks), with or without a ``window``."""
+    check_window(window)
     s = shape[2]
     return _pick_block(s, block) is not None
 
 
 # Crossover with XLA's fused attention, on the model path
 # (``MultiHeadAttention``: ``preferred``). Not re-measured since the
-# kernels got faster (PERF.md §7): on the ledger BERT-large reaches
-# 53.4% MFU at seq 128 under XLA's attention and 46% at seq 512 under
-# these kernels (38.5% before PR 25); no cell sits between.
+# kernels got faster (PERF.md §7): on the ledger (PR 25) BERT-large
+# reaches 53.4% MFU at seq 128 under XLA's attention and 46.1% at seq
+# 512 under these kernels (38.5% before PR 25); no cell sits between.
 MIN_KERNEL_SEQ = 512
 
 
-def preferred(shape):
+def preferred(shape, window=None):
     """True when the Pallas kernel is expected to beat XLA's fused
-    attention for this [B, H, S, D] shape. The compiled kernels keep
+    attention for this [B, H, S, D] shape; the same sequences for a
+    band call (``window``) as for a full one, where XLA's side is the
+    blocked band of ``local_flash_attention``. The compiled kernels keep
     ``lse`` with the sequence along the lanes, so every block has to be
     lane-wide or the whole sequence (which it is up to the smallest
     block target, 256); a longer sequence that only splits into slivers
     is XLA's to win anyway."""
     s = shape[2]
     return (s >= MIN_KERNEL_SEQ and (s % _LANES == 0 or s <= 256)
-            and supports(shape))
+            and supports(shape, window=window))
 
 
 def _interpret_default():
     return jax.default_backend() == 'cpu'
 
 
-def _causal_mask(qi, ki, bq, bk, transposed=False):
-    """Boolean tile of the global-position causal mask: [bq, bk], or
+def _tile_positions(qi, ki, bq, bk, transposed):
+    """Global (query, key) positions over tile (qi, ki): [bq, bk], or
     [bk, bq] for the transposed tile of ``flash_dkv``."""
     shape = (bk, bq) if transposed else (bq, bk)
     q_dim = 1 if transposed else 0
     qpos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_dim)
     kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_dim)
+    return qpos, kpos
+
+
+def _causal_mask(qi, ki, bq, bk, transposed=False):
+    """Boolean tile of the global-position causal mask."""
+    qpos, kpos = _tile_positions(qi, ki, bq, bk, transposed)
     return qpos >= kpos
 
 
+def _window_mask(qi, ki, bq, bk, window, transposed=False):
+    """Boolean tile of a band: true where the key lies ``window[0]``
+    before to ``window[1]`` after the query."""
+    left, right = window
+    qpos, kpos = _tile_positions(qi, ki, bq, bk, transposed)
+    return jnp.logical_and(kpos >= qpos - left, kpos <= qpos + right)
+
+
 def _for_each_tile_kind(tile, qi, ki, bq, bk, seq, causal,
-                        transposed=False):
+                        transposed=False, window=None):
     """Run ``tile(mask)`` for the kind of tile (qi, ki) is: not at all
-    for a dead one, with the causal mask where the diagonal crosses it,
-    with ``None`` otherwise. A kind the static grid does not contain is
-    not emitted."""
-    if not causal:
+    for a dead one, with the causal (or the band's) mask where the
+    diagonal (an edge of the band) crosses it, with ``None`` otherwise.
+    A kind the static grid does not contain is not emitted."""
+    if not causal and window is None:
         tile(None)
         return
-    tiles, live, masked = _tile_counts(seq, bq, bk, True)
+    tiles, live, masked = _tile_counts(seq, bq, bk, causal, window,
+                                       transposed)
+    if window is not None:
+        def mask():
+            return _window_mask(qi, ki, bq, bk, window, transposed)
+    else:
+        def mask():
+            return _causal_mask(qi, ki, bq, bk, transposed)
     if masked == tiles:
-        tile(_causal_mask(qi, ki, bq, bk, transposed))
+        tile(mask())
         return
-    is_live = _tile_live(qi, ki, bq, bk)
-    crossed = _tile_crossed(qi, ki, bq, bk)
+    if window is not None:
+        is_live = _band_tile_live(qi, ki, bq, bk, seq, window)
+        crossed = _band_tile_crossed(qi, ki, bq, bk, window)
+    else:
+        is_live = _tile_live(qi, ki, bq, bk)
+        crossed = _tile_crossed(qi, ki, bq, bk)
     if masked:
         when = crossed if live == tiles else jnp.logical_and(is_live,
                                                              crossed)
 
         @pl.when(when)
         def _():
-            tile(_causal_mask(qi, ki, bq, bk, transposed))
+            tile(mask())
     if live > masked:
         @pl.when(jnp.logical_and(is_live, jnp.logical_not(crossed)))
         def _():
@@ -208,15 +351,21 @@ def _for_each_tile_kind(tile, qi, ki, bq, bk, seq, causal,
 
 
 def _for_the_live_row(rows, outer, n_outer, size, seq, causal,
-                      transposed=False):
+                      transposed=False, window=None):
     """One-pass dispatch: the inner block is the whole sequence, so a
     step owns a row of tiles. Run ``rows(parts)`` with the ``(lo, hi,
     mask)`` ranges of the inner sequence that hold an unmasked position.
-    Not causal: the whole row. Causal: the square the diagonal crosses
-    (masked, ``size`` wide) and what lies below it (no mask): keys
-    before the q-block, or for the transposed tiles of ``flash_dkv``
-    queries after the kv-block. The ranges depend on the outer block, so
-    there is one static branch per outer block."""
+    Not causal: the whole row (under a band's mask if there is a
+    ``window``: a sequence that is one block is short). Causal: the
+    square the diagonal crosses (masked, ``size`` wide) and what lies
+    below it (no mask): keys before the q-block, or for the transposed
+    tiles of ``flash_dkv`` queries after the kv-block. The ranges depend
+    on the outer block, so there is one static branch per outer block."""
+    if window is not None:
+        qi, ki, bq, bk = (0, outer, seq, size) if transposed else \
+            (outer, 0, size, seq)
+        rows([(0, seq, _window_mask(qi, ki, bq, bk, window, transposed))])
+        return
     if not causal:
         rows([(0, seq, None)])
         return
@@ -271,9 +420,20 @@ def _scores(a, b, sm_scale, fold, mask):
 # forward
 # ---------------------------------------------------------------------------
 
+def _inner_block(outer, j, size, inner_size, window, transposed=False):
+    """The inner block of grid step ``j``: ``j`` itself, or for a band
+    call the ``j``-th block the band reaches from ``outer`` (which may
+    lie outside the sequence: a dead tile)."""
+    if window is None:
+        return j
+    back, _ = _band_reach(window, transposed)
+    return _band_first(outer, size, inner_size, back) + j
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                sm_scale, fold, causal, bq, bk, nq, nk, g):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+                sm_scale, fold, causal, bq, bk, nq, nk, g, window, n_inner):
+    qi, j = pl.program_id(2), pl.program_id(3)
+    ki = _inner_block(qi, j, bq, bk, window)
 
     def query(h):
         q = q_ref[0, h]                                       # [bq, D]
@@ -295,20 +455,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             lse_ref[0, h] = _to_row(m + jnp.log(l))
 
     if nk == 1:
-        _for_the_live_row(rows, qi, nq, bq, bk, causal)
+        _for_the_live_row(rows, qi, nq, bq, bk, causal, window=window)
         return
 
     acc_scr, m_scr, l_scr = scratch
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
     def tile(mask):
-        # online softmax: every row meets an unmasked key in its first
-        # live tile (key 0), so the running max is real from then on
+        # online softmax. Under a causal mask every row meets an
+        # unmasked key in its first live tile (key 0), so the running
+        # max is real from then on. In a band call a row may meet none
+        # until a later tile: its max stays NEG_INF, p is exp(0) for
+        # every key and l and acc hold finite rubbish, which the first
+        # real max wipes out (alpha = exp(NEG_INF - m) = 0).
         for h in range(g):
             v = v_ref[0, h]
             s = _scores(query(h), k_ref[0, h], sm_scale, fold, mask)
@@ -321,9 +485,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                                                    _NN)
             m_scr[h] = m_new
 
-    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal)
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
+                        window=window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == n_inner - 1)
     def _emit():
         for h in range(g):
             l = l_scr[h]
@@ -336,37 +501,70 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
-def _static(kernel, s, causal, sm_scale, blocks):
+def _inner_blocks(s, blocks, window, transposed=False):
+    """Length of the inner (sequential) grid dimension: every block of
+    the inner operand, or the longest run of them a band reaches."""
+    bq, bk, _ = blocks
+    size, inner = (bk, bq) if transposed else (bq, bk)
+    if window is None:
+        return s // inner
+    return _band_inner_blocks(s, size, inner, window, transposed)
+
+
+def _static(kernel, s, causal, sm_scale, blocks, window, transposed=False):
     """``kernel`` with what a call fixes at trace time."""
     bq, bk, g = blocks
     return functools.partial(
         kernel, sm_scale=sm_scale, fold=_is_pow2(sm_scale), causal=causal,
-        bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g)
+        bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g, window=window,
+        n_inner=_inner_blocks(s, blocks, window, transposed))
 
 
-def _kv_index(causal, bq, bk):
+def _name(kernel, window):
+    """The ``pallas_call`` name: a band call is told from a full one in
+    a trace (``flash_fwd_band``), and the benchmark reads both."""
+    return kernel if window is None else kernel + '_band'
+
+
+def _band_fetch(outer, j, size, inner_size, seq, window, transposed=False):
+    """The inner block a band call fetches at grid step ``j``: the
+    ``j``-th the band reaches from ``outer``, and for a dead tile (before
+    the sequence's start, after its end, or past the band's last block)
+    the nearest live one, which the pipeline holds already."""
+    back, ahead = _band_reach(window, transposed)
+    first = _band_first(outer, size, inner_size, back)
+    last = _band_last(outer, size, inner_size, ahead)
+    return jnp.clip(first + j, jnp.maximum(first, 0),
+                    jnp.minimum(last, seq // inner_size - 1))
+
+
+def _kv_index(causal, bq, bk, window=None, seq=None):
     """Index map of a K/V block on a (b, h, qi, ki) grid. A dead causal
     tile asks for the last live block of its row again, which the
-    pipeline already holds, so it fetches nothing."""
+    pipeline already holds, so it fetches nothing; a band call walks the
+    band's blocks only (:func:`_band_fetch`)."""
+    if window is not None:
+        return lambda b, h, i, j: (
+            b, h, _band_fetch(i, j, bq, bk, seq, window), 0)
     if not causal:
         return lambda b, h, i, j: (b, h, j, 0)
     return lambda b, h, i, j: (
         b, h, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
 
 
-def _fwd(q, k, v, causal, sm_scale, blocks, interpret):
+def _fwd(q, k, v, causal, sm_scale, blocks, interpret, window=None):
     b, h, s, d = q.shape
     bq, bk, g = blocks
     nq, nk = s // bq, s // bk
-    kv_index = _kv_index(causal, bq, bk)
+    kv_index = _kv_index(causal, bq, bk, window, s)
     scratch = [] if nk == 1 else [
         pltpu.VMEM((g, bq, d), jnp.float32),
         pltpu.VMEM((g, bq, 1), jnp.float32),
         pltpu.VMEM((g, bq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
-        _static(_fwd_kernel, s, causal, sm_scale, blocks),
-        grid=(b, h // g, nq, nk),
+        _static(_fwd_kernel, s, causal, sm_scale, blocks, window),
+        grid=(b, h // g, nq, _inner_blocks(s, blocks, window)),
         in_specs=[
             pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, g, bk, d), kv_index),
@@ -383,7 +581,7 @@ def _fwd(q, k, v, causal, sm_scale, blocks, interpret):
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name='flash_fwd',
+        name=_name('flash_fwd', window),
     )(q, k, v)
     return o, lse
 
@@ -393,8 +591,10 @@ def _fwd(q, k, v, causal, sm_scale, blocks, interpret):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g):
-    qi, ki = pl.program_id(2), pl.program_id(3)
+               *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g, window,
+               n_inner):
+    qi, j = pl.program_id(2), pl.program_id(3)
+    ki = _inner_block(qi, j, bq, bk, window)
 
     def grad(h, parts):
         """dq of head ``h`` from the key ranges ``parts`` of the block."""
@@ -425,12 +625,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             dq_ref[0, h] = finish(grad(h, parts))
 
     if nk == 1:
-        _for_the_live_row(rows, qi, nq, bq, bk, causal)
+        _for_the_live_row(rows, qi, nq, bq, bk, causal, window=window)
         return
 
     dq_scr, = scratch
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -438,9 +638,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         for h in range(g):
             dq_scr[h] = dq_scr[h] + grad(h, [(0, bk, mask)])
 
-    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal)
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
+                        window=window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == n_inner - 1)
     def _emit():
         for h in range(g):
             dq_ref[0, h] = finish(dq_scr[h])
@@ -448,8 +649,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *scratch,
-                sm_scale, fold, causal, bq, bk, nq, nk, g):
-    ki, qi = pl.program_id(2), pl.program_id(3)
+                sm_scale, fold, causal, bq, bk, nq, nk, g, window, n_inner):
+    ki, j = pl.program_id(2), pl.program_id(3)
+    qi = _inner_block(ki, j, bk, bq, window, transposed=True)
 
     def grads(h, parts):
         """(dk, dv) of head ``h`` from the query ranges ``parts`` of the
@@ -480,12 +682,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_ref[0, h] = dv.astype(dv_ref.dtype)
 
     if nq == 1:
-        _for_the_live_row(rows, ki, nk, bk, bq, causal, transposed=True)
+        _for_the_live_row(rows, ki, nk, bk, bq, causal, transposed=True,
+                          window=window)
         return
 
     dk_scr, dv_scr = scratch
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -497,25 +700,27 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_scr[h] = dv_scr[h] + dv
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
-                        transposed=True)
+                        transposed=True, window=window)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(j == n_inner - 1)
     def _emit():
         for h in range(g):
             dk_ref[0, h] = dk_scr[h].astype(dk_ref.dtype)
             dv_ref[0, h] = dv_scr[h].astype(dv_ref.dtype)
 
 
-def _dq(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
+def _dq(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret,
+        window=None):
     b, h, s, d = q.shape
     bq, bk, g = blocks
     nq, nk = s // bq, s // bk
     q_spec = pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, g, bk, d), _kv_index(causal, bq, bk))
+    kv_spec = pl.BlockSpec((1, g, bk, d),
+                           _kv_index(causal, bq, bk, window, s))
     row_spec = pl.BlockSpec((1, g, 1, bq), lambda b, h, i, j: (b, h, 0, i))
     return pl.pallas_call(
-        _static(_dq_kernel, s, causal, sm_scale, blocks),
-        grid=(b, h // g, nq, nk),
+        _static(_dq_kernel, s, causal, sm_scale, blocks, window),
+        grid=(b, h // g, nq, _inner_blocks(s, blocks, window)),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
@@ -523,17 +728,22 @@ def _dq(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
             pltpu.VMEM((g, bq, d), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name='flash_dq',
+        name=_name('flash_dq', window),
     )(q, k, v, do, lse, delta)
 
 
-def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
+def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret,
+         window=None):
     b, h, s, d = q.shape
     bq, bk, g = blocks
     nq, nk = s // bq, s // bk
     # the grid iterates q-blocks innermost for each kv-block; the dead
-    # causal tiles come first there, and ask for the first live q-block
-    if causal:
+    # causal tiles come first there, and ask for the first live q-block;
+    # a band call walks the q-blocks its kv-block is seen from
+    if window is not None:
+        def q_row(j, i):
+            return _band_fetch(j, i, bk, bq, s, window, transposed=True)
+    elif causal:
         def q_row(j, i):
             return jnp.maximum(i, (j * bk) // bq)
     else:
@@ -545,8 +755,10 @@ def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
     row_spec = pl.BlockSpec(
         (1, g, 1, bq), lambda b, h, j, i: (b, h, 0, q_row(j, i)))
     return pl.pallas_call(
-        _static(_dkv_kernel, s, causal, sm_scale, blocks),
-        grid=(b, h // g, nk, nq),
+        _static(_dkv_kernel, s, causal, sm_scale, blocks, window,
+                transposed=True),
+        grid=(b, h // g, nk, _inner_blocks(s, blocks, window,
+                                           transposed=True)),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[
@@ -558,37 +770,38 @@ def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret):
             pltpu.VMEM((g, bk, d), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name='flash_dkv',
+        name=_name('flash_dkv', window),
     )(q, k, v, do, lse, delta)
 
 
-def _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret):
+def _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret, window):
     # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None, :]                   # [B, H, 1, S]
     args = (q, k, v, do, lse, delta, causal, sm_scale)
-    dk, dv = _dkv(*args, plan.dkv, interpret)
-    return _dq(*args, plan.dq, interpret), dk, dv
+    dk, dv = _dkv(*args, plan.dkv, interpret, window)
+    return _dq(*args, plan.dq, interpret, window), dk, dv
 
 
 # ---------------------------------------------------------------------------
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, plan, interpret):
-    o, _ = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, sm_scale, plan, interpret, window=None):
+    o, _ = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret, window)
     return o
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret):
-    o, lse = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret)
+def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret, window):
+    o, lse = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, sm_scale, plan, interpret, res, do):
+def _flash_bwd(causal, sm_scale, plan, interpret, window, res, do):
     q, k, v, o, lse = res
-    return _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret)
+    return _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret,
+                window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -606,22 +819,24 @@ def _blocks(heads, seq, targets, block_q, block_k):
     return Blocks(*sizes, _heads_per_step(heads, *sizes))
 
 
-def _plan(shape, causal, block_q=None, block_k=None):
+def _plan(shape, causal, block_q=None, block_k=None, window=None):
     """The static plan of a call on [b, h, s, d] operands: for each
     kernel the block sizes (the arguments, else the kernel's targets,
     cut to divisors of ``s``) and the heads a grid step holds."""
     _, h, s, _ = shape
     return Plan(**{kernel: _blocks(h, s, targets, block_q, block_k)
-                   for kernel, targets in _block_targets(s, causal).items()})
+                   for kernel, targets in
+                   _block_targets(s, causal, window).items()})
 
 
-def _plan_tags(plan, seq, causal):
+def _plan_tags(plan, seq, causal, window=None):
     """The plan as the ``flash.plan`` event records it. Per kernel
     (the forward's keys have no prefix): blocks, heads a step, whether
     one inner block is the row, and per (batch, head) the tiles it
     computes on (``tile_q`` x ``tile_k``: the block, or under the causal
     one-pass the squares of the live row), those that hold an unmasked
-    position and those the causal diagonal crosses."""
+    position and those the causal diagonal crosses. For a band call
+    the tiles are the band's: the grid walks no others."""
     tags = {}
     for prefix, (bq, bk, g) in zip(('', 'dq_', 'dkv_'), plan):
         transposed = prefix == 'dkv_'
@@ -629,7 +844,8 @@ def _plan_tags(plan, seq, causal):
         tq, tk = bq, bk
         if causal and one_pass:
             tq = tk = bk if transposed else bq
-        tiles, live, masked = _tile_counts(seq, tq, tk, causal)
+        tiles, live, masked = _tile_counts(seq, tq, tk, causal, window,
+                                           transposed)
         tags.update({prefix + 'block_q': bq, prefix + 'block_k': bk,
                      prefix + 'heads_per_step': g,
                      prefix + 'one_pass': one_pass,
@@ -640,7 +856,7 @@ def _plan_tags(plan, seq, causal):
 
 
 def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
-                    block_k=None, interpret=None):
+                    block_k=None, interpret=None, window=None):
     """Exact attention over [batch, heads, seq, head_dim] tensors.
 
     Differentiable (custom VJP, flash backward). Requires ``seq`` to
@@ -650,18 +866,26 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     CPU backend only (so the same kernel code runs on the CPU test
     mesh); any other backend compiles the kernel or fails.
 
+    ``window = (left, right)`` (static) keeps, for query ``i``, the keys
+    ``i - left .. i + right``: a band. The kernels then walk the band's
+    tiles only and are named ``flash_fwd_band``, ``flash_dq_band`` and
+    ``flash_dkv_band``; with ``window=None`` the call is what it is
+    without the argument. A window under ``causal=True`` is an error.
+
     Each trace leaves one ``flash.plan`` point event in the loop ring
     (``telemetry.get().loop_records()``): the static plan of the call
     (``_plan_tags``).
     """
+    window = check_window(window, causal)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     sm_scale = float(sm_scale)
-    plan = _plan(q.shape, causal, block_q, block_k)
+    plan = _plan(q.shape, causal, block_q, block_k, window)
     if interpret is None:
         interpret = _interpret_default()
     telemetry.get().loop_event(
         'flash.plan', seq=q.shape[2], head_dim=q.shape[3],
         causal=bool(causal), fold_scale=_is_pow2(sm_scale),
-        **_plan_tags(plan, q.shape[2], causal))
-    return _flash(q, k, v, causal, sm_scale, plan, interpret)
+        window=None if window is None else list(window),
+        **_plan_tags(plan, q.shape[2], causal, window))
+    return _flash(q, k, v, causal, sm_scale, plan, interpret, window)

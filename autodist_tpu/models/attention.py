@@ -22,16 +22,54 @@ from autodist_tpu.parallel.ring_attention import (local_flash_attention,
 from autodist_tpu.parallel.ulysses import ulysses_attention
 
 
+@jax.named_scope('rotary')
+def rotary(x, positions, theta):
+    """Rotary position embedding over all of the head dim of
+    ``x [b, h, s, d]``, rotate-half convention (``x1, x2`` the two
+    halves: ``x * cos + cat(-x2, x1) * sin`` with ``inv_freq_j =
+    theta ** (-2j / d)``), computed in f32 at positions ``[s]``.
+
+    ``cat(-x2, x1)`` is taken as ``x @ R`` with R the signed permutation
+    that moves each half onto the other: on the MXU, exact in any float
+    format (one term a column). Written with split, negate and
+    concatenate on a head dim of 64 it costs XLA a dozen lane-shuffling
+    passes over f32 copies of q and k, a quarter of a ModernBERT step at
+    seq 8192 (PERF.md §6, PR 26)."""
+    d = x.shape[-1]
+    half = d // 2
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)      # [s, d]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    at = jnp.arange(d)
+    turn = (jnp.where(at[:, None] == at[None, :] - half, 1.0, 0.0)
+            - jnp.where(at[:, None] == at[None, :] + half, 1.0, 0.0))
+    turned = jnp.einsum('bhsd,de->bhse', x, turn.astype(x.dtype),
+                        precision=jax.lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * cos
+            + turned.astype(jnp.float32) * sin).astype(x.dtype)
+
+
 class MultiHeadAttention(Module):
-    """Causal (or full) self-attention; [batch, seq, embed] in/out."""
+    """Causal (or full) self-attention; [batch, seq, embed] in/out.
+
+    ``rope_theta`` (a base) puts rotary positions on q and k; ``window``
+    (keys each side, or ``(left, right)``) keeps a band of the scores
+    and is handed to every attention path that takes one: the flash
+    kernels, their nested-manual route under dp/tp, and the XLA path.
+    The sequence-parallel paths take none and raise."""
 
     def __init__(self, dim, num_heads, head_dim=None, causal=True,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, rope_theta=None, window=None):
         self.dim = dim
         self.num_heads = num_heads
         self.head_dim = head_dim or dim // num_heads
         self.causal = causal
         self.dtype = dtype
+        self.rope_theta = rope_theta
+        if isinstance(window, int):
+            window = (window, window)
+        self.window = fa.check_window(window, causal)
         inner = self.num_heads * self.head_dim
         # qkv fused: column-parallel over heads; out: row-parallel back.
         self.wqkv = Dense(dim, 3 * inner, 'embed', 'heads',
@@ -52,16 +90,31 @@ class MultiHeadAttention(Module):
         v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
 
         seq_axis = manual_axis(AXIS_SEQUENCE)
+        if self.rope_theta is not None:
+            # global positions, as the position table's (transformer.py)
+            pos = jnp.arange(s)
+            if seq_axis is not None:
+                pos = pos + jax.lax.axis_index(seq_axis) * s
+            q = rotary(q, pos, self.rope_theta)
+            k = rotary(k, pos, self.rope_theta)
+        window = self.window
         if seq_axis is not None:
+            if window is not None:
+                raise ValueError(
+                    'attention window %r under sequence parallelism: '
+                    'ring_attention and ulysses_attention take no window '
+                    'yet; use sp=1 for a model with window layers'
+                    % (window,))
             if ctx_option('sp_mode', 'ring') == 'ulysses':
                 o = ulysses_attention(q, k, v, seq_axis,
                                       causal=self.causal)
             else:
                 o = ring_attention(q, k, v, seq_axis, causal=self.causal)
-        elif unsharded_execution() and fa.preferred(q.shape):
+        elif unsharded_execution() and fa.preferred(q.shape, window):
             # device-local long-seq data: the Pallas flash kernel (never
             # materializes the [s, s] score matrix in HBM)
-            o = fa.flash_attention(q, k, v, causal=self.causal)
+            o = fa.flash_attention(q, k, v, causal=self.causal,
+                                   window=window)
         elif self._tp_manual_shape(q.shape) is not None:
             # dp/tp GSPMD mesh at long seq: attention is independent per
             # (batch, head), so hop into a nested manual region and run
@@ -69,7 +122,8 @@ class MultiHeadAttention(Module):
             # partition an opaque pallas_call.
             o = self._tp_manual_flash(q, k, v)
         else:
-            o = local_flash_attention(q, k, v, causal=self.causal)
+            o = local_flash_attention(q, k, v, causal=self.causal,
+                                      window=window)
             o = constrain(o, ('batch', 'heads', 'seq', 'kv'))
         o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
         return self.wo.apply(params['out'], o)
@@ -95,7 +149,7 @@ class MultiHeadAttention(Module):
         if dp * tp <= 1 or shape[0] % dp or shape[1] % tp:
             return None
         local = (shape[0] // dp, shape[1] // tp, shape[2], shape[3])
-        return local if fa.preferred(local) else None
+        return local if fa.preferred(local, self.window) else None
 
     def _tp_manual_flash(self, q, k, v):
         """Flash kernel on local (batch, head) shards. The region is
@@ -111,6 +165,7 @@ class MultiHeadAttention(Module):
         from autodist_tpu.parallel.axes import shard_map
         fn = shard_map(
             lambda q, k, v: fa.flash_attention(q, k, v,
-                                               causal=self.causal),
+                                               causal=self.causal,
+                                               window=self.window),
             mesh, (spec,) * 3, spec)
         return fn(q, k, v)

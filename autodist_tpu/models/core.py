@@ -307,30 +307,50 @@ class Embedding(Module):
 
 
 class LayerNorm(Module):
+    """LayerNorm in f32 over the last dim; ``use_bias=False`` has a
+    scale and no bias (ModernBERT's ``norm_bias: false``)."""
+
     def __init__(self, dim, axis_name='embed', eps=1e-6,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, use_bias=True):
         self.dim, self.axis_name, self.eps = dim, axis_name, eps
         self.dtype = dtype
+        self.use_bias = use_bias
 
     def param_defs(self):
-        return {'scale': ParamDef((self.dim,), (self.axis_name,), 'ones'),
-                'bias': ParamDef((self.dim,), (self.axis_name,), 'zeros')}
+        d = {'scale': ParamDef((self.dim,), (self.axis_name,), 'ones')}
+        if self.use_bias:
+            d['bias'] = ParamDef((self.dim,), (self.axis_name,), 'zeros')
+        return d
 
     def apply(self, params, x):
         x32 = x.astype(jnp.float32)
         mu = jnp.mean(x32, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x32 - mu), axis=-1, keepdims=True)
         y = (x32 - mu) * jax.lax.rsqrt(var + self.eps)
-        y = y * params['scale'] + params['bias']
+        y = y * params['scale']
+        if self.use_bias:
+            y = y + params['bias']
         return y.astype(self.dtype)
 
 
-class Mlp(Module):
-    """Transformer MLP: Megatron column- then row-parallel pair."""
+def gelu_exact(x):
+    """The erf GELU (BERT's, ModernBERT's ``"gelu"``); ``jax.nn.gelu``
+    alone is the tanh approximation (GPT-2's ``gelu_new``)."""
+    return jax.nn.gelu(x, approximate=False)
 
-    def __init__(self, dim, hidden, dtype=jnp.float32, act=jax.nn.gelu):
-        self.up = Dense(dim, hidden, 'embed', 'mlp', dtype=dtype)
-        self.down = Dense(hidden, dim, 'mlp', 'embed', dtype=dtype)
+
+class Mlp(Module):
+    """Transformer MLP: Megatron column- then row-parallel pair. The
+    default ``act`` is the TANH-approximated GELU (``jax.nn.gelu``'s
+    default), which is GPT-2's and not BERT's; pass :func:`gelu_exact`
+    for the erf form."""
+
+    def __init__(self, dim, hidden, dtype=jnp.float32, act=jax.nn.gelu,
+                 use_bias=True):
+        self.up = Dense(dim, hidden, 'embed', 'mlp', use_bias=use_bias,
+                        dtype=dtype)
+        self.down = Dense(hidden, dim, 'mlp', 'embed', use_bias=use_bias,
+                          dtype=dtype)
         self.act = act
 
     def param_defs(self):
@@ -340,3 +360,51 @@ class Mlp(Module):
         h = self.act(self.up.apply(params['up'], x))
         h = constrain(h, ('batch', 'seq', 'mlp'))
         return self.down.apply(params['down'], h)
+
+
+class GatedMlp(Module):
+    """Gated MLP (GeGLU with a GELU ``act``): ``down(act(input) * gate)``
+    where ``input, gate = split(x @ up)``. The up kernel is kept as
+    ``[dim, 2, hidden]`` with the ``mlp`` logical axis on ``hidden``, so
+    tensor parallelism cuts inside each half and a shard holds the same
+    columns of input and gate (a fused ``[dim, 2 * hidden]`` kernel cut
+    in two would put all of input on one shard and all of gate on the
+    other). ``kernel.reshape(dim, 2 * hidden)`` is the published fused
+    matrix, input first."""
+
+    def __init__(self, dim, hidden, dtype=jnp.float32, act=gelu_exact,
+                 use_bias=False):
+        self.dim, self.hidden = dim, hidden
+        self.dtype = dtype
+        self.act = act
+        self.use_bias = use_bias
+        self.down = Dense(hidden, dim, 'mlp', 'embed', use_bias=use_bias,
+                          dtype=dtype)
+
+    def param_defs(self):
+        # the fan-in of either half is `dim`
+        up = {'kernel': ParamDef((self.dim, 2, self.hidden),
+                                 ('embed', None, 'mlp'), 'normal',
+                                 self.dim ** -0.5)}
+        if self.use_bias:
+            up['bias'] = ParamDef((2, self.hidden), (None, 'mlp'), 'zeros')
+        return {'up': _Leaves(up), 'down': self.down}
+
+    def apply(self, params, x):
+        w = params['up']['kernel'].astype(self.dtype)
+        u = jnp.einsum('...d,dgh->...gh', x.astype(self.dtype), w)
+        if self.use_bias:
+            u = u + params['up']['bias'].astype(self.dtype)
+        h = self.act(u[..., 0, :]) * u[..., 1, :]
+        h = constrain(h, ('batch', 'seq', 'mlp'))
+        return self.down.apply(params['down'], h)
+
+
+class _Leaves(Module):
+    """A level of plain ``ParamDef`` leaves."""
+
+    def __init__(self, defs):
+        self._defs = defs
+
+    def param_defs(self):
+        return self._defs

@@ -12,10 +12,12 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_PIPELINE, AXIS_SEQUENCE
 from autodist_tpu.models.attention import MultiHeadAttention
-from autodist_tpu.models.core import (Dense, Embedding, LayerNorm, Mlp,
-                                      Module, ParamDef, constrain)
+from autodist_tpu.models.core import (Dense, Embedding, GatedMlp, LayerNorm,
+                                      Mlp, Module, ParamDef, constrain,
+                                      gelu_exact)
 from autodist_tpu.parallel.axes import ctx_option, manual_axis
 
 
@@ -33,14 +35,11 @@ class TransformerConfig:
     # remat: False = none; True = checkpoint each block (recompute the
     # whole block in backward); 'save_attn' = checkpoint each block but
     # SAVE the post-attention residual, so backward recomputes only the
-    # LN2+MLP half at one extra [b,s,d] save per layer. On v5e BERT
-    # bench shapes the two are perf-equal (step time is dominated
-    # elsewhere); 'save_attn' matters when attention is the expensive
-    # recompute (long sequences without the flash kernel). Also
-    # 'dots' (save every matmul output — recompute only elementwise
-    # work; highest-memory selective tier, exceeds a 16 GB chip for
-    # bert_large from batch 128) and 'dots_no_batch' (save only
-    # batch-free dots — effectively full remat here). See _block_fn.
+    # LN2+MLP half at one extra [b,s,d] save per layer (it matters when
+    # attention is the expensive recompute); 'dots' = save every matmul
+    # output and recompute only elementwise work (the highest-memory
+    # tier); 'dots_no_batch' = save only batch-free dots, which in a
+    # transformer block is full remat. See _block_fn.
     remat: object = False
     scan_layers: bool = True     # stack blocks + lax.scan (1 compile/block)
     # Chunked cross-entropy: target rows (batch*seq positions) per chunk
@@ -55,6 +54,70 @@ class TransformerConfig:
     moe_experts: int = 0         # >0: MoE MLP with this many experts
     moe_top_k: int = 2
     moe_aux_coef: float = 0.01   # load-balance loss weight
+    # -- the block's variants (docs/usage/layer-patterns.md). Each field
+    # describes the architecture; the defaults are GPT-2's block.
+    positions: str = 'learned'   # 'learned': a table of max_len rows
+    #                              added to the embedding; 'rotary': none,
+    #                              q and k are rotated in every layer
+    rope_theta: float = 10000.0  # rotary base of the global layers
+    window: object = None        # keys EACH SIDE a window layer attends
+    #                              to (ModernBERT's local_attention 128
+    #                              is 64); None: every layer is global
+    global_every: int = 1        # with a window: layer i is global iff
+    #                              i % global_every == 0, else a window
+    #                              layer
+    window_rope_theta: object = None   # rotary base of the window
+    #                              layers; None: rope_theta
+    mlp_dim: object = None       # MLP width; None: dim * mlp_ratio
+    gated_mlp: bool = False      # GeGLU: down(act(input) * gate)
+    gelu: str = 'tanh'           # 'tanh' (GPT-2's gelu_new) | 'erf'
+    norm_eps: float = 1e-6
+    norm_bias: bool = True       # LayerNorms carry a bias
+    mlp_bias: bool = True
+    embed_norm: bool = False     # LayerNorm after the embedding; the
+    #                              first layer's attention norm is then
+    #                              the identity (ModernBERT)
+    head_transform: bool = False  # prediction head: dense, act, norm
+    #                              before the decoder (BERT's MLM head)
+    decoder_bias: bool = False
+
+    def __post_init__(self):
+        if self.positions not in ('learned', 'rotary'):
+            raise ValueError("positions must be 'learned' or 'rotary', "
+                             'not %r' % (self.positions,))
+        if self.gelu not in ('tanh', 'erf'):
+            raise ValueError("gelu must be 'tanh' or 'erf', not %r"
+                             % (self.gelu,))
+        if self.window is not None and self.global_every < 2:
+            raise ValueError(
+                'window=%r needs global_every >= 2 (layer i is global iff '
+                'i %% global_every == 0); with global_every=%d no layer '
+                'would use the window' % (self.window, self.global_every))
+        if self.window is not None and self.causal:
+            raise ValueError('window layers under a causal mask are not '
+                             'supported')
+        if self.moe_experts and self.gated_mlp:
+            raise ValueError('gated_mlp with moe_experts is not supported')
+
+    def layer_kinds(self):
+        """'global' or 'window' for each layer."""
+        return ['global' if self.window is None or i % self.global_every == 0
+                else 'window' for i in range(self.n_layers)]
+
+    @classmethod
+    def modernbert_large(cls, **kw):
+        """ModernBERT-large (answerdotai/ModernBERT-large, arXiv:
+        2412.13663): 28 x 1024 x 16 heads, GeGLU 2624, rotary positions,
+        every third layer global, the others in a window of 64 each
+        side."""
+        d = dict(vocab=50368, dim=1024, n_layers=28, n_heads=16,
+                 max_len=8192, causal=False, positions='rotary',
+                 rope_theta=160000.0, window_rope_theta=10000.0, window=64,
+                 global_every=3, mlp_dim=2624, gated_mlp=True, gelu='erf',
+                 norm_eps=1e-5, norm_bias=False, mlp_bias=False,
+                 embed_norm=True, head_transform=True, decoder_bias=True)
+        d.update(kw)
+        return cls(**d)
 
     @classmethod
     def bert_large(cls, **kw):
@@ -79,36 +142,62 @@ class TransformerConfig:
         return cls(**d)
 
 
+def _norm(cfg):
+    return LayerNorm(cfg.dim, eps=cfg.norm_eps, dtype=cfg.dtype,
+                     use_bias=cfg.norm_bias)
+
+
+def _act(cfg):
+    return gelu_exact if cfg.gelu == 'erf' else jax.nn.gelu
+
+
 class Block(Module):
     """Pre-LN transformer block; MoE MLP when cfg.moe_experts > 0.
+
+    ``kind`` is 'global' or 'window' (``cfg.layer_kinds()``): a window
+    layer attends inside ``cfg.window`` keys each side, with its own
+    rotary base. ``attn_norm=False`` makes the attention norm the
+    identity (the first layer after an embedding norm).
 
     ``apply`` returns ``(x, aux)`` where aux is the router load-balance
     loss contribution (0.0 for dense blocks)."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, kind='global', attn_norm=True):
         self.cfg = cfg
-        self.ln1 = LayerNorm(cfg.dim, dtype=cfg.dtype)
-        self.attn = MultiHeadAttention(cfg.dim, cfg.n_heads,
-                                       causal=cfg.causal, dtype=cfg.dtype)
-        self.ln2 = LayerNorm(cfg.dim, dtype=cfg.dtype)
+        windowed = kind == 'window'
+        theta = None
+        if cfg.positions == 'rotary':
+            theta = cfg.window_rope_theta if windowed and \
+                cfg.window_rope_theta is not None else cfg.rope_theta
+        self.ln1 = _norm(cfg) if attn_norm else None
+        self.attn = MultiHeadAttention(
+            cfg.dim, cfg.n_heads, causal=cfg.causal, dtype=cfg.dtype,
+            rope_theta=theta, window=cfg.window if windowed else None)
+        self.ln2 = _norm(cfg)
+        hidden = cfg.mlp_dim or cfg.dim * cfg.mlp_ratio
         if cfg.moe_experts:
             from autodist_tpu.models.moe import MoeMlp
-            self.mlp = MoeMlp(cfg.dim, cfg.dim * cfg.mlp_ratio,
+            self.mlp = MoeMlp(cfg.dim, hidden,
                               cfg.moe_experts, top_k=cfg.moe_top_k,
                               dtype=cfg.dtype)
+        elif cfg.gated_mlp:
+            self.mlp = GatedMlp(cfg.dim, hidden, dtype=cfg.dtype,
+                                act=_act(cfg), use_bias=cfg.mlp_bias)
         else:
-            self.mlp = Mlp(cfg.dim, cfg.dim * cfg.mlp_ratio,
-                           dtype=cfg.dtype)
+            self.mlp = Mlp(cfg.dim, hidden, dtype=cfg.dtype, act=_act(cfg),
+                           use_bias=cfg.mlp_bias)
 
     def param_defs(self):
-        return {'ln1': self.ln1, 'attn': self.attn,
-                'ln2': self.ln2, 'mlp': self.mlp}
+        d = {'attn': self.attn, 'ln2': self.ln2, 'mlp': self.mlp}
+        if self.ln1 is not None:
+            d['ln1'] = self.ln1
+        return d
 
     @jax.named_scope('block')
     def apply(self, params, x):
         with jax.named_scope('attention'):
-            x = x + self.attn.apply(params['attn'],
-                                    self.ln1.apply(params['ln1'], x))
+            a = x if self.ln1 is None else self.ln1.apply(params['ln1'], x)
+            x = x + self.attn.apply(params['attn'], a)
         # named so remat='save_attn' can keep it while recomputing the rest
         x = checkpoint_name(x, 'attn_out')
         with jax.named_scope('mlp'):
@@ -128,6 +217,15 @@ class TransformerLM(Module):
     ``stage`` logical axis and the forward is a ``lax.scan`` — one
     compiled block regardless of depth, and the natural substrate for
     pipeline parallelism (the ``stage`` axis shards over ``pipe``).
+
+    Layers of two kinds (``cfg.window``, ``cfg.global_every``) scan over
+    PERIODS of the pattern: the parameters of a kind are stacked under
+    ``blocks[kind]`` (``stage`` leading, in depth order) and one scan
+    step runs a period's layers, each under the remat policy. The
+    layers that fill no period come first, unrolled, under their own
+    names ``block_000``...: ModernBERT's 28 are layer 0 (global, and
+    the one whose attention norm is the identity) and 9 periods of
+    (window, window, global). ``_layers`` has the arithmetic.
     """
 
     def __init__(self, cfg):
@@ -138,21 +236,69 @@ class TransformerLM(Module):
         self.pos_embed = Embedding(cfg.max_len, cfg.dim,
                                    vocab_axis='pos', dtype=cfg.dtype)
         self.block = Block(cfg)
-        self.ln_f = LayerNorm(cfg.dim, dtype=cfg.dtype)
+        self.ln_f = _norm(cfg)
         if not cfg.tied_embeddings:
             self.lm_head = Dense(cfg.dim, cfg.vocab, 'embed', 'vocab',
                                  use_bias=False, dtype=cfg.dtype)
+        self.ln_embed = _norm(cfg)
+        self.head = _HeadTransform(cfg)
+        self._lead, self._period, self._periods = self._layers()
+        kinds = cfg.layer_kinds()
+        # the unrolled layers' blocks, by depth; the scanned ones by kind
+        self._lead_blocks = [
+            Block(cfg, kinds[i], attn_norm=not (cfg.embed_norm and i == 0))
+            for i in range(cfg.n_layers if not cfg.scan_layers
+                           else self._lead)]
+        self._kind_blocks = {kind: Block(cfg, kind)
+                             for kind in sorted(set(self._period))} \
+            if self.patterned else {}
+
+    def _layers(self):
+        """``(lead, period, periods)`` of the layer stack: the first
+        ``lead`` layers run unrolled, then ``periods`` scan steps run
+        the kinds ``period`` each. A stack of one kind whose first layer
+        is like the others is one period a layer and no lead (the plain
+        model); otherwise the lead is what fills no period, and a whole
+        period where the first layer (no attention norm after an
+        embedding norm) would else fall inside the scan."""
+        cfg = self.cfg
+        kinds = cfg.layer_kinds()
+        size = cfg.global_every if cfg.window is not None else 1
+        lead = cfg.n_layers % size
+        if cfg.embed_norm and lead == 0:
+            lead = min(size, cfg.n_layers)
+        return lead, tuple(kinds[lead:lead + size]), \
+            (cfg.n_layers - lead) // size
+
+    @property
+    def patterned(self):
+        """Whether the scanned stack is by kind (``blocks[kind]``) with
+        unrolled lead layers, and not the plain ``blocks``."""
+        return bool(self._lead) or len(self._period) > 1
 
     def param_defs(self):
-        d = {'embed': self.embed, 'pos_embed': self.pos_embed,
-             'ln_f': self.ln_f}
-        if not self.cfg.tied_embeddings:
+        cfg = self.cfg
+        d = {'embed': self.embed, 'ln_f': self.ln_f}
+        if cfg.positions == 'learned':
+            d['pos_embed'] = self.pos_embed
+        if cfg.embed_norm:
+            d['ln_embed'] = self.ln_embed
+        if cfg.head_transform or cfg.decoder_bias:
+            d['head'] = self.head
+        if not cfg.tied_embeddings:
             d['lm_head'] = self.lm_head
-        if self.cfg.scan_layers:
-            d['blocks'] = _Stacked(self.block, self.cfg.n_layers)
+        if not cfg.scan_layers:
+            for i in range(cfg.n_layers):
+                d['block_%03d' % i] = self._lead_blocks[i]
+        elif not self.patterned:
+            d['blocks'] = _Stacked(self.block, cfg.n_layers)
         else:
-            for i in range(self.cfg.n_layers):
-                d['block_%03d' % i] = self.block
+            for i in range(self._lead):
+                d['block_%03d' % i] = self._lead_blocks[i]
+            d['blocks'] = _Kinds({
+                kind: _Stacked(block,
+                               self._periods * self._period.count(kind))
+                for kind, block in self._kind_blocks.items()})
         return d
 
     def apply(self, params, tokens):
@@ -169,27 +315,40 @@ class TransformerLM(Module):
 
     def _head_logits(self, params, x):
         """LM-head logits (model dtype) for hidden states of any
-        leading shape (..., dim)."""
-        if self.cfg.tied_embeddings:
-            return self.embed.attend(params['embed'], x)
-        return self.lm_head.apply(params['lm_head'], x)
+        leading shape (..., dim): the prediction head's transform where
+        the configuration has one, the decoder, its bias."""
+        cfg = self.cfg
+        if cfg.head_transform:
+            x = self.head.apply(params['head'], x)
+        if cfg.tied_embeddings:
+            logits = self.embed.attend(params['embed'], x)
+        else:
+            logits = self.lm_head.apply(params['lm_head'], x)
+        if cfg.decoder_bias:
+            logits = logits + params['head']['decoder_bias'].astype(
+                logits.dtype)
+        return logits
 
     @jax.named_scope('embed')
     def _embedded(self, params, tokens):
         """Embedding + positions (the pipeline prologue)."""
         _, s = tokens.shape
         x = self.embed.apply(params['embed'], tokens)
-        # global positions: offset by the manual seq-shard index when the
-        # sequence axis runs inside shard_map (ring attention mode)
-        seq_axis = manual_axis(AXIS_SEQUENCE)
-        pos = jnp.arange(s)
-        if seq_axis is not None:
-            pos = pos + jax.lax.axis_index(seq_axis) * s
-        x = x + self.pos_embed.apply(params['pos_embed'], pos)[None]
+        if self.cfg.positions == 'learned':
+            # global positions: offset by the manual seq-shard index when
+            # the sequence axis runs inside shard_map (ring attention mode)
+            seq_axis = manual_axis(AXIS_SEQUENCE)
+            pos = jnp.arange(s)
+            if seq_axis is not None:
+                pos = pos + jax.lax.axis_index(seq_axis) * s
+            x = x + self.pos_embed.apply(params['pos_embed'], pos)[None]
+        if self.cfg.embed_norm:
+            x = self.ln_embed.apply(params['ln_embed'], x)
         return constrain(x, ('batch', 'seq', 'embed'))
 
-    def _block_fn(self):
-        """Single-block apply with the remat policy applied.
+    def _block_fn(self, block=None):
+        """Single-block apply (``block``: the plain model's by default)
+        with the remat policy applied.
 
         ``cfg.remat``: False (no remat), True (full — recompute the
         whole block in the backward), or a named selective policy:
@@ -200,7 +359,7 @@ class TransformerLM(Module):
         full remat, kept for completeness).
         """
         cfg = self.cfg
-        block_fn = self.block.apply
+        block_fn = (block or self.block).apply
         if isinstance(cfg.remat, str):
             policies = {
                 'save_attn':
@@ -228,18 +387,16 @@ class TransformerLM(Module):
         block_fn = self._block_fn()
         aux_total = jnp.zeros((), jnp.float32)
         pipe_axis = manual_axis(AXIS_PIPELINE)
+        self._note_layers()
         if pipe_axis is not None:
-            if not cfg.scan_layers:
-                raise ValueError(
-                    'pipeline parallelism requires scan_layers=True '
-                    '(blocks must be stage-stacked to shard over pipe)')
+            self._check_pipelined()
             from autodist_tpu.parallel.pipeline import gpipe, one_f_one_b
             pipe_fn = one_f_one_b \
                 if ctx_option('pp_schedule', 'gpipe') == '1f1b' else gpipe
             x, aux_pipe = pipe_fn(block_fn, params['blocks'], x, pipe_axis,
                                   ctx_option('microbatches', 1))
             aux_total = aux_total + aux_pipe
-        elif cfg.scan_layers:
+        elif cfg.scan_layers and not self.patterned:
             def body(carry, layer_params):
                 h, aux = carry
                 h, a = block_fn(layer_params, h)
@@ -247,12 +404,69 @@ class TransformerLM(Module):
             (x, aux_total), _ = jax.lax.scan(
                 body, (x, aux_total), params['blocks'])
         else:
-            for i in range(cfg.n_layers):
-                x, a = block_fn(params['block_%03d' % i], x)
+            for i, block in enumerate(self._lead_blocks):
+                x, a = self._block_fn(block)(params['block_%03d' % i], x)
                 aux_total = aux_total + a
+            if cfg.scan_layers:
+                x, aux_total = self._scan_periods(params['blocks'], x,
+                                                  aux_total)
         with jax.named_scope('head_loss'):
             x = self.ln_f.apply(params['ln_f'], x)
         return x, aux_total
+
+    def _scan_periods(self, stacks, x, aux_total):
+        """``periods`` scan steps over ``stacks[kind]``, each running one
+        period's layers in order, each under the remat policy: a kind's
+        stack ``[periods * c, ...]`` is seen as ``[periods, c, ...]``
+        and the period's ``c`` layers of that kind index the second."""
+        fns = {kind: self._block_fn(block)
+               for kind, block in self._kind_blocks.items()}
+        per_period = {
+            kind: jax.tree.map(
+                lambda a, c=self._period.count(kind): a.reshape(
+                    (self._periods, c) + a.shape[1:]), stack)
+            for kind, stack in stacks.items()}
+
+        def body(carry, period_params):
+            h, aux = carry
+            seen = dict.fromkeys(period_params, 0)
+            for kind in self._period:
+                layer = jax.tree.map(lambda a, i=seen[kind]: a[i],
+                                     period_params[kind])
+                seen[kind] += 1
+                h, a = fns[kind](layer, h)
+                aux = aux + a
+            return (h, aux), None
+        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), per_period)
+        return x, aux_total
+
+    def _check_pipelined(self):
+        cfg = self.cfg
+        if not cfg.scan_layers:
+            raise ValueError(
+                'pipeline parallelism requires scan_layers=True '
+                '(blocks must be stage-stacked to shard over pipe)')
+        if self.patterned:
+            raise ValueError(
+                'pipeline parallelism needs layers of one kind: this '
+                'stack is %d unrolled layer(s) and %d periods of %s, and '
+                'stages are not cut across a layer pattern; use pp=1'
+                % (self._lead, self._periods, '/'.join(self._period)))
+
+    def _note_layers(self):
+        """One ``transformer.layers`` point event a trace of a patterned
+        stack: how it is run (docs/design/observability.md). The plain
+        model, one scan step a layer, leaves none."""
+        if not self.patterned:
+            return
+        kinds = self.cfg.layer_kinds()
+        telemetry.get().loop_event(
+            'transformer.layers', n_layers=len(kinds),
+            period=len(self._period), periods=self._periods,
+            remainder=self._lead, pattern='/'.join(self._period),
+            scanned=bool(self.cfg.scan_layers),
+            global_layers=kinds.count('global'),
+            window_layers=kinds.count('window'))
 
     def per_token_loss(self, params, batch):
         return self.per_token_loss_with_aux(params, batch)[0]
@@ -309,10 +523,7 @@ class TransformerLM(Module):
         working set, independent of the microbatch count).
         ``loss_chunk`` is subsumed — each microbatch IS a head chunk."""
         cfg = self.cfg
-        if not cfg.scan_layers:
-            raise ValueError(
-                'pipeline parallelism requires scan_layers=True '
-                '(blocks must be stage-stacked to shard over pipe)')
+        self._check_pipelined()
         from autodist_tpu.parallel.pipeline import one_f_one_b
 
         def head(p, tok_mb):
@@ -327,11 +538,14 @@ class TransformerLM(Module):
         # backward carries + psums a zeros-like of these trees, so
         # handing it the full params dict would add two block-stack-
         # sized gradient buffers for nothing.
-        head_params = {k: params[k] for k in ('embed', 'pos_embed')}
+        head_params = {k: params[k]
+                       for k in ('embed', 'pos_embed', 'ln_embed')
+                       if k in params}
         tail_params = {
             k: params[k]
-            for k in ('ln_f',
-                      'embed' if cfg.tied_embeddings else 'lm_head')}
+            for k in ('ln_f', 'head',
+                      'embed' if cfg.tied_embeddings else 'lm_head')
+            if k in params}
         return one_f_one_b(self._block_fn(), params['blocks'],
                            batch['tokens'], pipe_axis,
                            ctx_option('microbatches', 1),
@@ -395,3 +609,40 @@ class _Stacked(Module):
 
     def param_defs(self):  # pragma: no cover - init/axes overridden
         return {'inner': self.inner}
+
+
+class _Kinds(Module):
+    """The scanned stacks of a patterned model, one per layer kind."""
+
+    def __init__(self, stacks):
+        self._stacks = stacks
+
+    def param_defs(self):
+        return self._stacks
+
+
+class _HeadTransform(Module):
+    """The prediction head before the decoder: ``norm(act(dense(x)))``
+    (``cfg.head_transform``), and the decoder's bias
+    (``cfg.decoder_bias``), which ``_head_logits`` adds."""
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.dense = Dense(cfg.dim, cfg.dim, 'embed', 'head_out',
+                           use_bias=False, dtype=cfg.dtype)
+        self.norm = _norm(cfg)
+        self.act = _act(cfg)
+
+    def param_defs(self):
+        d = {}
+        if self.cfg.head_transform:
+            d.update(dense=self.dense, norm=self.norm)
+        if self.cfg.decoder_bias:
+            d['decoder_bias'] = ParamDef((self.cfg.vocab,), ('vocab',),
+                                         'zeros')
+        return d
+
+    def apply(self, params, x):
+        return self.norm.apply(params['norm'],
+                               self.act(self.dense.apply(params['dense'],
+                                                         x)))

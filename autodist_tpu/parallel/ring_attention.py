@@ -96,16 +96,69 @@ def ring_attention(q, k, v, axis_name, causal=True, sm_scale=None):
     return out.astype(q.dtype)
 
 
-def local_flash_attention(q, k, v, causal=True, sm_scale=None):
+def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None):
     """Single-device exact attention with the same accumulation; used as
-    the non-SP fallback so numerics match ring_attention bit-for-bit-ish."""
+    the non-SP fallback so numerics match ring_attention bit-for-bit-ish.
+
+    ``window = (left, right)`` keeps keys ``i - left .. i + right`` for
+    query ``i`` (not with ``causal``). A sequence several bands long is
+    computed in query blocks against the keys each block's band reaches
+    (:func:`_band_attention`), so no ``[s, s]`` score matrix exists; a
+    short one under a dense mask."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if window is not None:
+        if causal:
+            raise ValueError('local_flash_attention: a window under a '
+                             'causal mask is not supported')
+        block = _band_block(q.shape[2], window)
+        if block:
+            return _band_attention(q, k, v, window, sm_scale, block)
     s = jnp.einsum('bhqd,bhkd->bhqk', q, k,
                    preferred_element_type=jnp.float32) * sm_scale
+    sq, sk = s.shape[-2], s.shape[-1]
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
         s = jnp.where(mask, s, -1e30)
+    elif window is not None:
+        ahead = jnp.arange(sk)[None, :] - jnp.arange(sq)[:, None]
+        s = jnp.where((ahead >= -window[0]) & (ahead <= window[1]), s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum('bhqk,bhkd->bhqd', p.astype(v.dtype), v)
+
+
+def _band_block(seq, window):
+    """Query-block size of the blocked band, or None where the dense
+    mask is as cheap: the sequence is under four bands long, or splits
+    into no block of 8 rows or more."""
+    left, right = window
+    if seq <= 4 * (left + right + 1):
+        return None
+    block = 8
+    while block < max(128, left, right) and seq % (2 * block) == 0:
+        block *= 2
+    return block if seq % block == 0 else None
+
+
+def _band_attention(q, k, v, window, sm_scale, block):
+    """Band attention in query blocks of ``block``: block ``n`` meets
+    keys ``n * block - left .. (n + 1) * block + right`` (gathered from
+    zero-padded k and v), so the scores are ``[b, h, s / block, block,
+    block + left + right]``."""
+    b, h, s, d = q.shape
+    left, right = window
+    n, width = s // block, block + left + right
+    pad = ((0, 0), (0, 0), (left, right), (0, 0))
+    at = jnp.arange(n)[:, None] * block + jnp.arange(width)[None, :]
+    kb = jnp.pad(k, pad)[:, :, at]                    # [b, h, n, width, d]
+    vb = jnp.pad(v, pad)[:, :, at]
+    sc = jnp.einsum('bhnqd,bhnkd->bhnqk', q.reshape(b, h, n, block, d), kb,
+                    preferred_element_type=jnp.float32) * sm_scale
+    kpos = at[:, None, :] - left                      # [n, 1, width]
+    qpos = (jnp.arange(n)[:, None] * block
+            + jnp.arange(block)[None, :])[:, :, None]  # [n, block, 1]
+    mask = ((kpos >= 0) & (kpos < s) & (kpos >= qpos - left)
+            & (kpos <= qpos + right))
+    p = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
+    o = jnp.einsum('bhnqk,bhnkd->bhnqd', p.astype(v.dtype), vb)
+    return o.reshape(b, h, s, d)

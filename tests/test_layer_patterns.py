@@ -1,0 +1,222 @@
+"""Layers of two kinds in one ``TransformerLM`` (PR 26): the scan over
+periods against the unrolled stack, the parallel layouts that take a
+window and a layer pattern and those that refuse them, the XLA band
+path, rotary positions and the gated MLP's sharding."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from autodist_tpu import telemetry
+from autodist_tpu.api import Trainer
+from autodist_tpu.models.attention import rotary
+from autodist_tpu.models.core import GatedMlp
+from autodist_tpu.models.transformer import TransformerConfig, TransformerLM
+from autodist_tpu.parallel.axes import ParallelSpec
+from autodist_tpu.parallel.ring_attention import (_band_block,
+                                                  local_flash_attention)
+
+
+def tiny(**kw):
+    """ModernBERT's structure at a size the CPU runs in a second: 7
+    layers = layer 0 + 2 periods of (window, window, global), 4 heads of
+    16, a window of 8 keys each side, seq 64."""
+    d = dict(vocab=256, dim=64, n_layers=7, n_heads=4, max_len=64,
+             window=8, mlp_dim=96, dtype=jnp.float32, remat=True)
+    d.update(kw)
+    return TransformerConfig.modernbert_large(**d)
+
+
+def batch(n=4, seq=64, seed=0):
+    rng = np.random.RandomState(seed)
+    return {'tokens': rng.randint(0, 256, (n, seq), dtype=np.int32),
+            'targets': rng.randint(0, 256, (n, seq), dtype=np.int32)}
+
+
+def unrolled_from_scanned(model, params):
+    """The scanned tree's layers under the unrolled model's names."""
+    kinds = model.cfg.layer_kinds()
+    out = {k: v for k, v in params.items() if k != 'blocks'}
+    seen = dict.fromkeys(params['blocks'], 0)
+    for i in range(model._lead, len(kinds)):
+        row = seen[kinds[i]]
+        out['block_%03d' % i] = jax.tree.map(lambda a, r=row: a[r],
+                                             params['blocks'][kinds[i]])
+        seen[kinds[i]] += 1
+    return out
+
+
+@pytest.mark.parametrize('n_layers,want', [
+    (28, (1, ('window', 'window', 'global'), 9)),   # ModernBERT-large
+    (22, (1, ('window', 'window', 'global'), 7)),   # ModernBERT-base
+    (7, (1, ('window', 'window', 'global'), 2)),
+    (8, (2, ('window', 'global', 'window'), 2)),
+    (6, (3, ('global', 'window', 'window'), 1)),    # layer 0 kept out
+])
+def test_layer_arithmetic(n_layers, want):
+    model = TransformerLM(tiny(n_layers=n_layers))
+    assert model._layers() == want and model.patterned
+    kinds = model.cfg.layer_kinds()
+    lead, period, periods = want
+    assert kinds[:lead] + list(period) * periods == kinds
+    assert kinds.count('global') == len(range(0, n_layers, 3))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    for kind in set(period):
+        assert shapes['blocks'][kind]['ln2']['scale'].shape[0] == \
+            periods * period.count(kind)
+    assert 'ln1' not in shapes['block_000'] and 'pos_embed' not in shapes
+    assert model.axes()['blocks']['window']['mlp']['up']['kernel'] == (
+        'stage', 'embed', None, 'mlp')
+
+
+def test_plain_models_keep_their_tree_and_stack():
+    """BERT and GPT-2: one kind, no lead, ``blocks`` stacked directly."""
+    for cfg in (TransformerConfig.tiny(), TransformerConfig.tiny(
+            causal=False)):
+        model = TransformerLM(cfg)
+        assert model._layers() == (0, ('global',), cfg.n_layers)
+        assert not model.patterned
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        assert sorted(shapes) == ['blocks', 'embed', 'ln_f', 'pos_embed']
+        assert sorted(shapes['blocks']) == ['attn', 'ln1', 'ln2', 'mlp']
+        assert sorted(shapes['blocks']['ln1']) == ['bias', 'scale']
+
+
+@pytest.mark.parametrize('loss_chunk', [0, 64])
+def test_scan_over_periods_equals_the_unrolled_stack(loss_chunk):
+    scanned = TransformerLM(tiny(loss_chunk=loss_chunk))
+    plain = TransformerLM(tiny(scan_layers=False, remat=False))
+    params = scanned.init(jax.random.PRNGKey(0))
+    n_before = len(telemetry.get().loop_records())
+    got, got_g = jax.value_and_grad(scanned.loss)(params, batch())
+    want, want_g = jax.value_and_grad(plain.loss)(
+        unrolled_from_scanned(scanned, params), batch())
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    got_g = unrolled_from_scanned(scanned, got_g)
+    assert jax.tree.structure(got_g) == jax.tree.structure(want_g)
+    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-6, rtol=1e-4)
+    events = [r['tags'] for r in telemetry.get().loop_records()[n_before:]
+              if r['name'] == 'transformer.layers']
+    assert events[0] == dict(
+        n_layers=7, period=3, periods=2, remainder=1,
+        pattern='window/window/global', scanned=True, global_layers=3,
+        window_layers=4)
+
+
+def test_dp2_tp2_equals_one_device():
+    """The window, the rotary bases and the gated MLP's halves under
+    GSPMD: a dp=2 x tp=2 step moves the parameters as a dp=1 step."""
+    model = TransformerLM(tiny())
+    after = {}
+    for name, spec in (('one', ParallelSpec(dp=1)),
+                       ('mesh', ParallelSpec(dp=2, tp=2))):
+        tr = Trainer(model, optax.sgd(0.1), spec=spec)
+        state = tr.init(jax.random.PRNGKey(0))
+        if name == 'mesh':
+            up = state.params['blocks']['window']['mlp']['up']['kernel']
+            # tp cuts inside each half: a shard holds half of input AND gate
+            assert {s.data.shape for s in up.addressable_shards} == {
+                (4, 64, 2, 48)}
+        state, metrics = tr.step(state, batch())
+        after[name] = (float(metrics['loss']),
+                       jax.tree.map(np.asarray, state.params))
+    np.testing.assert_allclose(after['mesh'][0], after['one'][0], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(after['mesh'][1]),
+                    jax.tree.leaves(after['one'][1])):
+        np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
+
+
+@pytest.mark.parametrize('spec_kw,complaint', [
+    (dict(dp=1, sp=2), 'window .* under sequence parallelism'),
+    (dict(dp=1, sp=2, sp_mode='ulysses'),
+     'window .* under sequence parallelism'),
+    (dict(dp=1, pp=2), 'pipeline parallelism needs layers of one kind'),
+    (dict(dp=1, pp=2, pp_schedule='1f1b'),
+     'pipeline parallelism needs layers of one kind'),
+])
+def test_layouts_that_take_no_pattern_say_so(spec_kw, complaint):
+    tr = Trainer(TransformerLM(tiny()), optax.sgd(0.1),
+                 spec=ParallelSpec(**spec_kw))
+    with pytest.raises(ValueError, match=complaint):
+        state = tr.init(jax.random.PRNGKey(0))
+        tr.step(state, batch())
+
+
+def test_config_refuses_what_it_cannot_mean():
+    with pytest.raises(ValueError, match='global_every >= 2'):
+        TransformerConfig.tiny(causal=False, window=8)
+    with pytest.raises(ValueError, match='causal'):
+        TransformerConfig.tiny(window=8, global_every=2)
+    with pytest.raises(ValueError, match="'learned' or 'rotary'"):
+        TransformerConfig.tiny(positions='alibi')
+
+
+@pytest.mark.parametrize('seq,window', [(64, (8, 8)), (640, (8, 24)),
+                                        (1024, (64, 64)), (520, (3, 0))])
+def test_xla_band_path_matches_a_dense_mask(seq, window):
+    """``local_flash_attention(window=...)``: dense under a mask for a
+    short sequence, in query blocks (no [s, s] tensor) for a long one."""
+    rng = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rng.randn(1, 2, seq, 16), jnp.float32)
+               for _ in range(3))
+    ahead = np.arange(seq)[None, :] - np.arange(seq)[:, None]
+    keep = (ahead >= -window[0]) & (ahead <= window[1])
+    scores = jnp.einsum('bhqd,bhkd->bhqk', q, k) / 4.0
+    want = jnp.einsum('bhqk,bhkd->bhqd', jax.nn.softmax(
+        jnp.where(keep, scores, -jnp.inf), axis=-1), v)
+    blocked = _band_block(seq, window) is not None
+    assert blocked == (seq > 4 * (sum(window) + 1))
+    text = jax.jit(lambda q, k, v: local_flash_attention(
+        q, k, v, causal=False, window=window)).lower(q, k, v).as_text()
+    assert ('x%dx%dxf32' % (seq, seq) in text) == (not blocked)
+    got = local_flash_attention(q, k, v, causal=False, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_rotary_is_a_rotation_by_relative_position():
+    """Scores of rotated q and k depend on the distance only, and the
+    base sets the lowest frequency."""
+    rng = np.random.RandomState(0)
+    q, k = (jnp.asarray(rng.randn(1, 1, 1, 16), jnp.float32)
+            for _ in range(2))
+
+    def score(i, j, theta=10000.0):
+        return float(jnp.sum(rotary(q, jnp.asarray([i]), theta)
+                             * rotary(k, jnp.asarray([j]), theta)))
+    assert score(5, 3) == pytest.approx(score(40, 38), rel=1e-5)
+    assert score(5, 3) != pytest.approx(score(5, 4), rel=1e-3)
+    assert score(0, 0) == pytest.approx(float(jnp.sum(q * k)), rel=1e-6)
+    assert score(900, 0) != pytest.approx(score(900, 0, 160000.0), rel=1e-3)
+    # rotate-half: dims j and j + d/2 turn together at theta ** (-2j/d)
+    x = jnp.zeros((1, 1, 1, 16)).at[..., 1].set(1.0)
+    turned = np.asarray(rotary(x, jnp.asarray([1]), 100.0))[0, 0, 0]
+    angle = 100.0 ** (-2 / 16)
+    assert turned[1] == pytest.approx(np.cos(angle), rel=1e-6)
+    assert turned[9] == pytest.approx(np.sin(angle), rel=1e-6)
+
+
+def test_gated_mlp_is_the_published_fused_matrix():
+    mlp = GatedMlp(8, 12)
+    params = mlp.init(jax.random.PRNGKey(0))
+    x = jnp.asarray(np.random.RandomState(0).randn(3, 8), jnp.float32)
+    fused = params['up']['kernel'].reshape(8, 24)
+    inp, gate = jnp.split(x @ fused, 2, axis=-1)
+    want = (jax.nn.gelu(inp, approximate=False) * gate) \
+        @ params['down']['kernel']
+    np.testing.assert_allclose(np.asarray(mlp.apply(params, x)),
+                               np.asarray(want), rtol=1e-5, atol=1e-6)
+    assert 'bias' not in params['up'] and 'bias' not in params['down']
+    # with biases (zero at init, so moved off it): one for each half
+    biased = GatedMlp(8, 12, use_bias=True)
+    p2 = jax.tree.map(lambda a: a + 0.1, biased.init(jax.random.PRNGKey(0)))
+    assert p2['up']['bias'].shape == (2, 12)
+    inp, gate = jnp.split(x @ p2['up']['kernel'].reshape(8, 24)
+                          + p2['up']['bias'].reshape(24), 2, axis=-1)
+    want = (jax.nn.gelu(inp, approximate=False) * gate) \
+        @ p2['down']['kernel'] + p2['down']['bias']
+    np.testing.assert_allclose(np.asarray(biased.apply(p2, x)),
+                               np.asarray(want), rtol=1e-5, atol=1e-6)

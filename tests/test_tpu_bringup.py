@@ -68,18 +68,25 @@ def test_kernel_regions_lower_for_tpu(monkeypatch, spec_kw, seq):
     assert 'tpu_custom_call' in exported.mlir_module()
 
 
-@pytest.mark.parametrize('local_shape,causal,dp', [
-    ((96, 16, 512, 64), False, 1),      # bert-large.s512.c1
-    ((32, 16, 1024, 64), True, 1),      # gpt2-medium.s1024.c1
-    ((96, 16, 512, 64), False, 4),      # bert-large.s512.dp4's shard_map
-], ids=['s512', 's1024_causal', 's512_dp4'])
-def test_flash_step_is_three_named_kernels(local_shape, causal, dp):
+@pytest.mark.parametrize('local_shape,causal,dp,window', [
+    ((96, 16, 512, 64), False, 1, None),    # bert-large.s512.c1
+    ((32, 16, 1024, 64), True, 1, None),    # gpt2-medium.s1024.c1
+    ((96, 16, 512, 64), False, 4, None),    # bert-large.s512.dp4's shard_map
+    # modernbert-large.s8192.c1: a global layer's calls (the multi-block
+    # path), a window layer's (the band path), and the band under dp=4
+    ((4, 16, 8192, 64), False, 1, None),
+    ((4, 16, 8192, 64), False, 1, (64, 64)),
+    ((4, 16, 8192, 64), False, 4, (64, 64)),
+], ids=['s512', 's1024_causal', 's512_dp4', 's8192_global', 's8192_band',
+        's8192_band_dp4'])
+def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     """The benchmark reads the kernels of a step by name
-    (``benchmark/scope_reduce.py``): forward and backward of
-    ``flash_attention`` at the cells' shapes are exactly three Mosaic
-    calls, ``flash_fwd``, ``flash_dq`` and ``flash_dkv``; and tracing
-    leaves the static plan in the loop ring, with tile counts that a
-    position-by-position count confirms."""
+    (``benchmark/scope_reduce.py``, ``benchmark/flash_kinds.py``):
+    forward and backward of ``flash_attention`` at the cells' shapes are
+    exactly three Mosaic calls, ``flash_fwd``, ``flash_dq`` and
+    ``flash_dkv``, with ``_band`` behind each name for a band call; and
+    tracing leaves the static plan in the loop ring, with tile counts
+    that a position-by-position count confirms."""
     import re
 
     import jax
@@ -92,7 +99,8 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp):
     from autodist_tpu.parallel.axes import shard_map
 
     def attend(q, k, v):
-        return fa.flash_attention(q, k, v, causal=causal, interpret=False)
+        return fa.flash_attention(q, k, v, causal=causal, interpret=False,
+                                  window=window)
 
     shape, sharding = local_shape, None
     if dp > 1:
@@ -108,18 +116,23 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp):
             argnums=(0, 1, 2))), platforms=['tpu'])(x, x, x)
     text = exported.mlir_module()
     assert text.count('@tpu_custom_call') == 3
+    band = '_band' if window else ''
     assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
-        'flash_dkv', 'flash_dq', 'flash_fwd']
+        'flash_dkv' + band, 'flash_dq' + band, 'flash_fwd' + band]
 
     plans = [r for r in telemetry.get().loop_records()[n_before:]
              if r['name'] == 'flash.plan']
     assert plans and plans[-1]['dur'] is None
     tags = plans[-1]['tags']
     seq = tags['seq']
-    assert (seq, tags['head_dim'], tags['causal']) == (
-        local_shape[2], local_shape[3], causal)
+    assert (seq, tags['head_dim'], tags['causal'], tags['window']) == (
+        local_shape[2], local_shape[3], causal,
+        list(window) if window else None)
     allowed = np.tril(np.ones((seq, seq), bool)) if causal else \
         np.ones((seq, seq), bool)
+    if window:
+        ahead = np.arange(seq)[None, :] - np.arange(seq)[:, None]
+        allowed = (ahead >= -window[0]) & (ahead <= window[1])
     for kernel in ('', 'dq_', 'dkv_'):
         bq, bk = tags[kernel + 'tile_q'], tags[kernel + 'tile_k']
         assert local_shape[1] % tags[kernel + 'heads_per_step'] == 0
@@ -128,10 +141,70 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp):
             == seq)
         tiles = [allowed[i:i + bq, j:j + bk]
                  for i in range(0, seq, bq) for j in range(0, seq, bk)]
-        assert tags[kernel + 'tiles'] == len(tiles)
+        if window:
+            # the grid of a band call walks the band: 2 to 4 tiles for
+            # each outer block here, of 16 to 64 in a row of the square
+            outer = seq // (bk if kernel == 'dkv_' else bq)
+            assert tags[kernel + 'tiles'] in (2 * outer, 3 * outer,
+                                              4 * outer)
+            assert tags[kernel + 'tiles'] < len(tiles)
+        else:
+            assert tags[kernel + 'tiles'] == len(tiles)
         assert tags[kernel + 'live_tiles'] == sum(t.any() for t in tiles)
         assert tags[kernel + 'masked_tiles'] == sum(
             t.any() and not t.all() for t in tiles)
+
+
+# The Mosaic modules of the existing flash cells' kernels with their
+# source locations stripped (the serialized module carries the file's
+# path and line numbers, so the bytes differ from checkout to checkout).
+# A PR that means to leave these cells' kernels alone (PR 26 added the
+# band path beside them) leaves these as they are; one that changes the
+# kernels replaces them, on purpose. jax 0.9.0's lowering.
+_KERNEL_MODULES = {
+    's512': ((96, 16, 512, 64), False, {
+        'flash_fwd': 'adfad1b9b9f7d42d', 'flash_dq': '6f8368d491dd60bd',
+        'flash_dkv': '7deaeb00cc4045f0'}),
+    's1024_causal': ((32, 16, 1024, 64), True, {
+        'flash_fwd': 'def267ce84890dae', 'flash_dq': '4663e617ae66821d',
+        'flash_dkv': '4a5957399e7a1ef5'}),
+    # chip_smoke.py's shape: the multi-block causal path
+    's4096_causal': ((2, 12, 4096, 64), True, {
+        'flash_fwd': '77c1c8692a72f861', 'flash_dq': 'fd32b0dd300c76b7',
+        'flash_dkv': '15bd8df0d5f02377'}),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_KERNEL_MODULES))
+def test_full_call_kernels_are_op_for_op_what_they_were(case):
+    import base64
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    from autodist_tpu.kernels import flash_attention as fa
+
+    shape, causal, want = _KERNEL_MODULES[case]
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    text = jax.export.export(jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=causal, interpret=False).astype(jnp.float32)),
+        argnums=(0, 1, 2))), platforms=['tpu'])(x, x, x).mlir_module()
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    got = {}
+    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)', text):
+        with context:
+            module = ir.Module.parse(
+                base64.b64decode(body + '=' * (-len(body) % 4)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        got[re.search(r'module @(\w+)', asm).group(1)] = hashlib.sha256(
+            asm.encode()).hexdigest()[:16]
+    assert got == want
 
 
 # -- chip_smoke.py ---------------------------------------------------------
