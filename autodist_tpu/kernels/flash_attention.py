@@ -74,6 +74,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -774,10 +775,7 @@ def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret,
     )(q, k, v, do, lse, delta)
 
 
-def _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret, window):
-    # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, :, None, :]                   # [B, H, 1, S]
+def _bwd(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, window):
     args = (q, k, v, do, lse, delta, causal, sm_scale)
     dk, dv = _dkv(*args, plan.dkv, interpret, window)
     return _dq(*args, plan.dq, interpret, window), dk, dv
@@ -787,21 +785,69 @@ def _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret, window):
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, plan, interpret, window=None):
+# What the forward rule of a ``merged`` call names for a checkpoint
+# policy (``jax.checkpoint_policies.save_only_these_names``): its output
+# and its row statistics, all the backward needs besides q, k and v.
+CHECKPOINT_NAMES = ('flash_o', 'flash_lse')
+
+
+def _merge_heads(o):
+    b, h, s, d = o.shape
+    return jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, sm_scale, plan, interpret, window=None,
+           merged=False):
     o, _ = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret, window)
-    return o
+    return _merge_heads(o) if merged else o
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret, window):
+def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret, window, merged):
     o, lse = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret, window)
-    return o, (q, k, v, o, lse)
+    if not merged:
+        return o, (q, k, v, o, lse)
+    # o is named in the layout it is kept in, heads x head_dim along the
+    # lanes: [b, h, s, 64] is lane-padded to twice its bytes in HBM. lse
+    # stays as the kernel writes it (XLA tiles the stack of [b, h, 1, s]
+    # T(1, 128): the unit dimension pads nothing). o is named as its
+    # BITS: on a floating-point residual that the forward pass uses too,
+    # jax.checkpoint puts a reduce_precision, which XLA runs right after
+    # the custom call as a pass of its own over the padded o (0.6 ms a
+    # layer at [96, 16, 512, 64]: PERF.md §6, PR 27). The value is the
+    # kernel's own rounding already, what the caller gets is a bitcast
+    # of what is kept, and the barrier keeps that bitcast behind the
+    # layout copy, where it fuses into the write to the stack.
+    bits = jnp.dtype('uint%d' % (8 * o.dtype.itemsize))
+    o = checkpoint_name(
+        jax.lax.bitcast_convert_type(
+            jax.lax.optimization_barrier(_merge_heads(o)), bits),
+        CHECKPOINT_NAMES[0])
+    lse = checkpoint_name(lse, CHECKPOINT_NAMES[1])
+    return jax.lax.bitcast_convert_type(o, q.dtype), (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, sm_scale, plan, interpret, window, res, do):
+def _flash_bwd(causal, sm_scale, plan, interpret, window, merged, res, do):
     q, k, v, o, lse = res
-    return _bwd(q, k, v, o, lse, do, causal, sm_scale, plan, interpret,
-                window)
+    # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
+    if merged:
+        b, h, s, d = q.shape
+        do = do.reshape(b, s, h, d)
+        # the barrier pins the reshape to the slice of the stack, a free
+        # view: XLA else sinks it below the converts, and o in f32 becomes
+        # a tensor in HBM between them and the reduce (0.6 ms a layer at
+        # [96, 16, 512, 64])
+        o = jax.lax.bitcast_convert_type(
+            jax.lax.optimization_barrier(o.reshape(b, s, h, d)), q.dtype)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1)
+        delta = jnp.transpose(delta, (0, 2, 1))               # [B, H, S]
+        do = jnp.transpose(do, (0, 2, 1, 3))
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1)
+    return _bwd(q, k, v, do, lse, delta[:, :, None, :], causal, sm_scale,
+                plan, interpret, window)                      # [B, H, 1, S]
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -876,6 +922,36 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     (``telemetry.get().loop_records()``): the static plan of the call
     (``_plan_tags``).
     """
+    return _planned(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                    window, merged=False)
+
+
+def flash_attention_merged(q, k, v, causal=True, sm_scale=None,
+                           interpret=None, window=None):
+    """:func:`flash_attention` for a model's block: the same call on
+    ``[batch, heads, seq, head_dim]`` operands, with the output as
+    ``[batch, seq, heads * head_dim]``, what the output projection
+    takes, and the forward rule's residuals named for a checkpoint
+    policy (``CHECKPOINT_NAMES``): that output, and ``lse``. Under ``jax.checkpoint(block,
+    policy=save_only_these_names(*CHECKPOINT_NAMES))`` the backward
+    pass then recomputes q, k and v but runs no forward kernel again;
+    the backward rule takes ``do`` in the output's layout and computes
+    ``delta`` from the two merged tensors. :func:`saved_bytes` is what a
+    call keeps. Without such a policy the names mean nothing."""
+    return _planned(q, k, v, causal, sm_scale, None, None, interpret, window,
+                    merged=True)
+
+
+def saved_bytes(shape, dtype):
+    """Bytes that a :func:`flash_attention_merged` call on
+    ``[b, h, s, d]`` operands of ``dtype`` names for the checkpoint:
+    ``o`` and the f32 ``lse``."""
+    b, h, s, d = shape
+    return b * h * s * (d * jnp.dtype(dtype).itemsize + 4)
+
+
+def _planned(q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
+             merged):
     window = check_window(window, causal)
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -888,4 +964,4 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
         causal=bool(causal), fold_scale=_is_pow2(sm_scale),
         window=None if window is None else list(window),
         **_plan_tags(plan, q.shape[2], causal, window))
-    return _flash(q, k, v, causal, sm_scale, plan, interpret, window)
+    return _flash(q, k, v, causal, sm_scale, plan, interpret, window, merged)

@@ -98,6 +98,7 @@ class MultiHeadAttention(Module):
             q = rotary(q, pos, self.rope_theta)
             k = rotary(k, pos, self.rope_theta)
         window = self.window
+        local = self.kernel_shape(q.shape)
         if seq_axis is not None:
             if window is not None:
                 raise ValueError(
@@ -110,12 +111,14 @@ class MultiHeadAttention(Module):
                                       causal=self.causal)
             else:
                 o = ring_attention(q, k, v, seq_axis, causal=self.causal)
-        elif unsharded_execution() and fa.preferred(q.shape, window):
+        elif local == q.shape:
             # device-local long-seq data: the Pallas flash kernel (never
-            # materializes the [s, s] score matrix in HBM)
-            o = fa.flash_attention(q, k, v, causal=self.causal,
-                                   window=window)
-        elif self._tp_manual_shape(q.shape) is not None:
+            # materializes the [s, s] score matrix in HBM); its output
+            # is [b, s, h * d] already and named, with lse, for the
+            # block's checkpoint policy
+            o = fa.flash_attention_merged(q, k, v, causal=self.causal,
+                                          window=window)
+        elif local is not None:
             # dp/tp GSPMD mesh at long seq: attention is independent per
             # (batch, head), so hop into a nested manual region and run
             # the flash kernel on local shards — GSPMD alone cannot
@@ -125,8 +128,22 @@ class MultiHeadAttention(Module):
             o = local_flash_attention(q, k, v, causal=self.causal,
                                       window=window)
             o = constrain(o, ('batch', 'heads', 'seq', 'kv'))
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
+        if local is None:
+            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
         return self.wo.apply(params['out'], o)
+
+    def kernel_shape(self, shape):
+        """The per-device ``[b, h, s, d]`` that the flash kernels run on
+        for q, k, v of ``shape`` in the current trace, or None where
+        attention takes another path: sequence-parallel (ring, Ulysses:
+        they own their kernel calls), or XLA's below the kernels'
+        crossover. A block whose attention returns a shape here keeps
+        ``fa.saved_bytes`` of it through its checkpoint."""
+        if manual_axis(AXIS_SEQUENCE) is not None:
+            return None
+        if unsharded_execution():
+            return shape if fa.preferred(shape, self.window) else None
+        return self._tp_manual_shape(shape)
 
     # -- nested-manual flash under dp/tp GSPMD -----------------------------
     def _tp_manual_shape(self, shape):
@@ -152,7 +169,8 @@ class MultiHeadAttention(Module):
         return local if fa.preferred(local, self.window) else None
 
     def _tp_manual_flash(self, q, k, v):
-        """Flash kernel on local (batch, head) shards. The region is
+        """Flash kernel on local (batch, head) shards, ``[b, s, h * d]``
+        out (a shard's heads are a contiguous run of it). The region is
         manual over EVERY mesh axis, size-1 ones included: Mosaic
         refuses to lower a kernel while any axis of the mesh is still
         automatic (found on the first four-chip run — interpret mode on
@@ -160,12 +178,11 @@ class MultiHeadAttention(Module):
         not name see replicated operands, which is what attention
         inputs are over pipe/seq/expert."""
         mesh = current_mesh()
-        spec = P(AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None,
-                 live_mesh_axis('heads'))
+        data = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
+        heads = live_mesh_axis('heads')
         from autodist_tpu.parallel.axes import shard_map
         fn = shard_map(
-            lambda q, k, v: fa.flash_attention(q, k, v,
-                                               causal=self.causal,
-                                               window=self.window),
-            mesh, (spec,) * 3, spec)
+            lambda q, k, v: fa.flash_attention_merged(
+                q, k, v, causal=self.causal, window=self.window),
+            mesh, (P(data, heads),) * 3, P(data, None, heads))
         return fn(q, k, v)

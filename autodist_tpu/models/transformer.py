@@ -14,6 +14,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_PIPELINE, AXIS_SEQUENCE
+from autodist_tpu.kernels import flash_attention as fa
 from autodist_tpu.models.attention import MultiHeadAttention
 from autodist_tpu.models.core import (Dense, Embedding, GatedMlp, LayerNorm,
                                       Mlp, Module, ParamDef, constrain,
@@ -32,8 +33,12 @@ class TransformerConfig:
     causal: bool = True
     tied_embeddings: bool = True
     dtype: object = jnp.bfloat16
-    # remat: False = none; True = checkpoint each block (recompute the
-    # whole block in backward); 'save_attn' = checkpoint each block but
+    # remat: False = none; True = checkpoint each block: the backward
+    # recomputes the block, all but the flash kernel's forward call
+    # where the block makes one, whose output ([b, s, dim], the input of
+    # the output projection) and row statistics are kept: b x s x (dim
+    # x itemsize + 4 x heads) bytes a layer (docs/design/kernels.md);
+    # 'save_attn' = checkpoint each block but
     # SAVE the post-attention residual, so backward recomputes only the
     # LN2+MLP half at one extra [b,s,d] save per layer (it matters when
     # attention is the expensive recompute); 'dots' = save every matmul
@@ -350,8 +355,12 @@ class TransformerLM(Module):
         """Single-block apply (``block``: the plain model's by default)
         with the remat policy applied.
 
-        ``cfg.remat``: False (no remat), True (full — recompute the
-        whole block in the backward), or a named selective policy:
+        ``cfg.remat``: False (no remat), True (recompute the block in
+        the backward, all but the flash kernel's forward call: the
+        policy keeps what ``fa.flash_attention_merged`` names, its
+        output and ``lse``; a block on another attention path names
+        nothing and is recomputed whole, as without a policy), or a
+        named selective policy:
         'save_attn' (keep attention outputs), 'dots' (keep every
         matmul output — recompute only elementwise/norm work; the
         highest-memory selective tier), 'dots_no_batch' (keep only
@@ -376,7 +385,10 @@ class TransformerLM(Module):
                     'one of %s)' % (cfg.remat, sorted(policies)))
             return jax.checkpoint(block_fn, policy=policies[cfg.remat])
         if cfg.remat:
-            return jax.checkpoint(block_fn)
+            return jax.checkpoint(
+                block_fn,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *fa.CHECKPOINT_NAMES))
         return block_fn
 
     def hidden_with_aux(self, params, tokens):
@@ -388,6 +400,7 @@ class TransformerLM(Module):
         aux_total = jnp.zeros((), jnp.float32)
         pipe_axis = manual_axis(AXIS_PIPELINE)
         self._note_layers()
+        self._note_remat(x)
         if pipe_axis is not None:
             self._check_pipelined()
             from autodist_tpu.parallel.pipeline import gpipe, one_f_one_b
@@ -467,6 +480,34 @@ class TransformerLM(Module):
             scanned=bool(self.cfg.scan_layers),
             global_layers=kinds.count('global'),
             window_layers=kinds.count('window'))
+
+    def _note_remat(self, x):
+        """One ``transformer.remat`` point event a trace under
+        ``remat=True``: what the blocks' checkpoint keeps of block input
+        ``x [b, s, dim]``. ``layers`` of the stack call the flash kernel
+        here (``MultiHeadAttention.kernel_shape``) and keep
+        ``saved_bytes_per_layer`` each on a device, for as long as the
+        layer inputs live; 0 layers, on any other attention path, is
+        the checkpoint without a policy."""
+        cfg = self.cfg
+        if cfg.remat is not True:
+            return
+        b, s, _ = x.shape
+        if not cfg.scan_layers:
+            blocks = self._lead_blocks
+        elif self.patterned:
+            blocks = self._lead_blocks + self._periods * [
+                self._kind_blocks[kind] for kind in self._period]
+        else:
+            blocks = [self.block] * cfg.n_layers
+        shapes = [block.attn.kernel_shape(
+            (b, cfg.n_heads, s, block.attn.head_dim)) for block in blocks]
+        kept = [fa.saved_bytes(shape, cfg.dtype) for shape in shapes
+                if shape is not None]
+        telemetry.get().loop_event(
+            'transformer.remat', policy='save_only_these_names',
+            saved=list(fa.CHECKPOINT_NAMES), layers=len(kept),
+            saved_bytes_per_layer=max(kept, default=0))
 
     def per_token_loss(self, params, batch):
         return self.per_token_loss_with_aux(params, batch)[0]

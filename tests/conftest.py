@@ -35,3 +35,31 @@ def _fresh_process_state():
     ad_mod._DEFAULT_AUTODIST.clear()
     if hasattr(fe._GRAPH_STACK, 'stack'):
         fe._GRAPH_STACK.stack.clear()
+
+
+def _kernel_calls(jaxpr, times=1, counts=None):
+    """``{kernel name: calls}`` of the ``pallas_call``s a jaxpr makes
+    when run, those of nested jaxprs included (a scan's body counts
+    ``length`` times)."""
+    import collections
+
+    from jax._src import core
+    counts = collections.Counter() if counts is None else counts
+    for eqn in getattr(jaxpr, 'jaxpr', jaxpr).eqns:
+        if eqn.primitive.name == 'pallas_call':
+            counts[eqn.params['name']] += times
+            continue
+        inner = times * eqn.params['length'] \
+            if eqn.primitive.name == 'scan' else times
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                if isinstance(sub, (core.Jaxpr, core.ClosedJaxpr)):
+                    _kernel_calls(sub, inner, counts)
+    return dict(counts)
+
+
+@pytest.fixture
+def kernel_calls():
+    """Counts the Pallas kernel calls of a jaxpr by name; interpret mode
+    keeps the name that the compiled call carries."""
+    return _kernel_calls

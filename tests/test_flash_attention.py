@@ -314,6 +314,75 @@ def test_window_under_a_causal_mask_is_refused():
     assert not fa.preferred((1, 1, 128, 64), window=(8, 8))
 
 
+# (causal, window) of the three kinds of call a cell makes
+_CALL_KINDS = {'global': (False, None), 'causal': (True, None),
+               'band': (False, (8, 8))}
+
+
+@pytest.mark.parametrize('kind', sorted(_CALL_KINDS))
+def test_merged_call_is_the_public_one_with_the_heads_merged(kind):
+    causal, window = _CALL_KINDS[kind]
+    rng = np.random.RandomState(5)
+    q, k, v = _rand_qkv(rng, (2, 4, 64, 16))
+    w = jnp.asarray(rng.randn(2, 64, 64), jnp.float32)
+
+    def merge(o):
+        return jnp.transpose(o, (0, 2, 1, 3)).reshape(2, 64, 64)
+
+    def public(q, k, v):
+        return merge(fa.flash_attention(q, k, v, causal=causal,
+                                        window=window))
+
+    def merged(q, k, v):
+        return fa.flash_attention_merged(q, k, v, causal=causal,
+                                         window=window)
+    np.testing.assert_array_equal(np.asarray(merged(q, k, v)),
+                                  np.asarray(public(q, k, v)))
+    got = jax.grad(lambda *a: jnp.sum(merged(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(public(*a) * w), (0, 1, 2))(q, k, v)
+    for g, x in zip(got, want):
+        # delta is summed from another view of the same products
+        np.testing.assert_allclose(np.asarray(g), np.asarray(x),
+                                   rtol=1e-5, atol=1e-6)
+    assert fa.saved_bytes(q.shape, q.dtype) == 2 * 4 * 64 * (16 * 4 + 4)
+
+
+@pytest.mark.parametrize('kind', sorted(_CALL_KINDS))
+def test_checkpoint_policy_keeps_the_forward_kernel_out_of_the_backward(
+        kind, kernel_calls):
+    """Three scanned blocks (projection, kernel, projection) under
+    ``jax.checkpoint``: with the policy that saves what the merged call
+    names, the gradient runs the forward kernel once a layer, without
+    it twice, and gives the same bits either way."""
+    causal, window = _CALL_KINDS[kind]
+    rng = np.random.RandomState(6)
+    x = jnp.asarray(rng.randn(2, 64, 64), jnp.float32)
+    ws = jnp.asarray(rng.randn(3, 64, 4 * 64) * 0.1, jnp.float32)
+
+    def block(h, w):
+        q, k, v = (jnp.transpose((h @ w[:, i * 64:(i + 1) * 64]).reshape(
+            2, 64, 4, 16), (0, 2, 1, 3)) for i in range(3))
+        o = fa.flash_attention_merged(q, k, v, causal=causal, window=window)
+        return h + o @ w[:, 192:], None
+
+    def loss(policy):
+        fn = jax.checkpoint(block, policy=policy)
+        return lambda x, ws: jnp.sum(jax.lax.scan(fn, x, ws)[0] ** 2)
+
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *fa.CHECKPOINT_NAMES)
+    band = '_band' if window else ''
+    names = ['flash_fwd' + band, 'flash_dq' + band, 'flash_dkv' + band]
+    calls = {policy: kernel_calls(jax.make_jaxpr(jax.grad(
+        loss(policy), (0, 1)))(x, ws)) for policy in (keep, None)}
+    assert calls[keep] == dict.fromkeys(names, 3)
+    assert calls[None] == dict(dict.fromkeys(names, 3),
+                               **{names[0]: 6})
+    for got, want in zip(jax.grad(loss(keep), (0, 1))(x, ws),
+                         jax.grad(loss(None), (0, 1))(x, ws)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_tp_mesh_dispatches_via_nested_manual(monkeypatch):
     """Under a dp/tp GSPMD mesh the module hops into a nested shard_map
     so the kernel runs on local shards — and the numbers still match the
@@ -327,13 +396,13 @@ def test_tp_mesh_dispatches_via_nested_manual(monkeypatch):
     from autodist_tpu.parallel.axes import ParallelSpec
 
     calls = {'n': 0}
-    real = fa.flash_attention
+    real = fa.flash_attention_merged
 
     def spy(*a, **kw):
         calls['n'] += 1
         return real(*a, **kw)
 
-    monkeypatch.setattr(attn_mod.fa, 'flash_attention', spy)
+    monkeypatch.setattr(attn_mod.fa, 'flash_attention_merged', spy)
     monkeypatch.setattr(attn_mod.fa, 'MIN_KERNEL_SEQ', 16)
 
     cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=2)
@@ -371,13 +440,13 @@ def test_flash_parity_on_dp8_gspmd_mesh_long_seq(monkeypatch):
     from autodist_tpu.parallel.axes import ParallelSpec
 
     calls = {'n': 0}
-    real = fa.flash_attention
+    real = fa.flash_attention_merged
 
     def spy(*a, **kw):
         calls['n'] += 1
         return real(*a, **kw)
 
-    monkeypatch.setattr(attn_mod.fa, 'flash_attention', spy)
+    monkeypatch.setattr(attn_mod.fa, 'flash_attention_merged', spy)
     cfg = TransformerConfig(vocab=64, dim=32, n_layers=1, n_heads=2,
                             max_len=2048, dtype=jnp.float32,
                             scan_layers=False)
@@ -413,13 +482,13 @@ def test_flash_dispatch_with_extra_live_mesh_axes(monkeypatch):
     from autodist_tpu.parallel.axes import ParallelSpec
 
     calls = {'n': 0}
-    real = fa.flash_attention
+    real = fa.flash_attention_merged
 
     def spy(*a, **kw):
         calls['n'] += 1
         return real(*a, **kw)
 
-    monkeypatch.setattr(attn_mod.fa, 'flash_attention', spy)
+    monkeypatch.setattr(attn_mod.fa, 'flash_attention_merged', spy)
     monkeypatch.setattr(attn_mod.fa, 'MIN_KERNEL_SEQ', 16)
 
     cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=2)
@@ -451,14 +520,14 @@ def test_module_dispatches_to_kernel(monkeypatch):
     from autodist_tpu.models.attention import MultiHeadAttention
 
     calls = {}
-    real = fa.flash_attention
+    real = fa.flash_attention_merged
 
     def spy(*a, **kw):
         calls['hit'] = True
         return real(*a, **kw)
 
     import autodist_tpu.models.attention as attn_mod
-    monkeypatch.setattr(attn_mod.fa, 'flash_attention', spy)
+    monkeypatch.setattr(attn_mod.fa, 'flash_attention_merged', spy)
     monkeypatch.setattr(attn_mod.fa, 'MIN_KERNEL_SEQ', 16)
 
     mha = MultiHeadAttention(32, 2)

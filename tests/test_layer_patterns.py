@@ -220,3 +220,119 @@ def test_gated_mlp_is_the_published_fused_matrix():
         @ p2['down']['kernel'] + p2['down']['bias']
     np.testing.assert_allclose(np.asarray(biased.apply(p2, x)),
                                np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+# -- remat=True keeps the flash kernel's o and lse (PR 27) ------------------
+
+def without_the_policy(monkeypatch):
+    """``remat=True`` as ``jax.checkpoint(block)`` with no policy."""
+    monkeypatch.setattr(jax.checkpoint_policies, 'save_only_these_names',
+                        lambda *names: None)
+
+
+def remat_events(n_before):
+    return [r['tags'] for r in telemetry.get().loop_records()[n_before:]
+            if r['name'] == 'transformer.remat']
+
+
+# (cfg, kernel calls of the gradient with the policy: forward, dq and
+# dkv once a layer). seq 64, with the crossover lowered to it.
+_REMAT_STACKS = {
+    'bert_scanned': (lambda: TransformerConfig.tiny(
+        causal=False, max_len=64, dtype=jnp.float32, remat=True),
+        {'flash_fwd': 2, 'flash_dq': 2, 'flash_dkv': 2}),
+    # layer 0 unrolled, then one period of (window, window, global)
+    'modernbert': (lambda: tiny(n_layers=4), {
+        'flash_fwd': 2, 'flash_dq': 2, 'flash_dkv': 2,
+        'flash_fwd_band': 2, 'flash_dq_band': 2, 'flash_dkv_band': 2}),
+}
+
+
+@pytest.mark.parametrize('stack', sorted(_REMAT_STACKS))
+def test_remat_runs_the_forward_kernel_once_a_layer(stack, monkeypatch,
+                                                    kernel_calls):
+    from autodist_tpu.kernels import flash_attention as fa
+    monkeypatch.setattr(fa, 'MIN_KERNEL_SEQ', 64)
+    make_cfg, want = _REMAT_STACKS[stack]
+    model = TransformerLM(make_cfg())
+    params = model.init(jax.random.PRNGKey(0))
+    data = batch(n=2)
+
+    def traced():     # a new function each time: nothing traced is reused
+        return jax.jit(jax.grad(lambda p, b: model.loss(p, b))).trace(
+            params, data)
+
+    n_before = len(telemetry.get().loop_records())
+    kept = traced()
+    assert kernel_calls(kept.jaxpr) == want
+    assert remat_events(n_before) == [dict(
+        policy='save_only_these_names', saved=['flash_o', 'flash_lse'],
+        layers=model.cfg.n_layers,
+        saved_bytes_per_layer=2 * 4 * 64 * (16 * 4 + 4))]
+    without_the_policy(monkeypatch)
+    whole = traced()
+    assert kernel_calls(whole.jaxpr) == dict(
+        want, **{name: 2 * want[name] for name in want if 'fwd' in name})
+    for a, b in zip(*(jax.tree.leaves(t.lower().compile()(params, data))
+                      for t in (kept, whole))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_remat_keeps_the_forward_kernel_under_dp2_tp2(monkeypatch,
+                                                      kernel_calls):
+    """Through ``_tp_manual_flash``: the names are given inside the
+    nested manual region and the policy outside it still finds them."""
+    from autodist_tpu.kernels import flash_attention as fa
+    monkeypatch.setattr(fa, 'MIN_KERNEL_SEQ', 64)
+    model = TransformerLM(tiny(n_layers=4))
+
+    def step(forward_calls):
+        tr = Trainer(model, optax.sgd(0.1), spec=ParallelSpec(dp=2, tp=2))
+        state = tr.init(jax.random.PRNGKey(0))
+        fn = tr._ensure_step(tr._step_key(batch()), state, batch())
+        calls = kernel_calls(jax.make_jaxpr(fn)(
+            state, tr.shard_batch(batch())))
+        assert calls['flash_fwd'] + calls['flash_fwd_band'] == forward_calls
+        assert calls['flash_dq'] + calls['flash_dq_band'] == 4
+        state, _ = tr.step(state, batch())
+        return jax.tree.map(np.asarray, state.params)
+
+    n_before = len(telemetry.get().loop_records())
+    kept = step(4)
+    # a device's shard: batch 2 of 4, heads 2 of 4
+    assert remat_events(n_before)[0]['saved_bytes_per_layer'] == \
+        2 * 2 * 64 * (16 * 4 + 4)
+    without_the_policy(monkeypatch)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(step(8))):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_block_off_the_kernel_is_the_checkpoint_without_a_policy(
+        monkeypatch):
+    """Below the crossover (seq 128: ``bert-large.s128.c1``) no block
+    names anything, the policy keeps nothing, and the step lowers to the
+    same StableHLO as under ``jax.checkpoint(block)``."""
+    model = TransformerLM(TransformerConfig.tiny(
+        causal=False, n_layers=3, max_len=128, remat=True))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    data = jax.eval_shape(lambda: batch(seq=128))
+
+    def lowered():
+        return jax.jit(jax.value_and_grad(
+            lambda p, b: model.loss(p, b))).lower(shapes, data).as_text()
+
+    n_before = len(telemetry.get().loop_records())
+    with_policy = lowered()
+    assert remat_events(n_before) == [dict(
+        policy='save_only_these_names', saved=['flash_o', 'flash_lse'],
+        layers=0, saved_bytes_per_layer=0)]
+    without_the_policy(monkeypatch)
+    assert lowered() == with_policy
+    # unrolled layers count one by one; no tier but True leaves the event
+    n_before = len(telemetry.get().loop_records())
+    for kw in (dict(remat=True, scan_layers=False), dict(remat='save_attn'),
+               dict()):
+        other = TransformerLM(TransformerConfig.tiny(**kw))
+        jax.eval_shape(other.loss, jax.eval_shape(
+            other.init, jax.random.PRNGKey(0)), batch(seq=64))
+    assert [e['layers'] for e in remat_events(n_before)] == [0]

@@ -155,6 +155,71 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
             t.any() and not t.all() for t in tiles)
 
 
+# One layer of each cell's model under remat=True with its gradient, as it
+# lowers for the TPU: (config, batch a chip, seq, dp, kernel calls). Since
+# PR 27 the block's checkpoint keeps the forward kernel's o and lse, so the
+# gradient holds each forward kernel once, not twice.
+_REMAT_BLOCKS = {
+    's512': (dict(causal=False), 96, 512, 1,
+             {'flash_fwd': 1, 'flash_dq': 1, 'flash_dkv': 1}),
+    's1024_causal': (dict(causal=True), 32, 1024, 1,
+                     {'flash_fwd': 1, 'flash_dq': 1, 'flash_dkv': 1}),
+    's512_dp4': (dict(causal=False), 96, 512, 4,
+                 {'flash_fwd': 1, 'flash_dq': 1, 'flash_dkv': 1}),
+    # ModernBERT's layer 0 (global, unrolled) and one period of the scan
+    # (window, window, global)
+    's8192_global_and_band': (
+        dict(causal=False, positions='rotary', window=64, global_every=3,
+             n_layers=4, embed_norm=True), 4, 8192, 1,
+        {'flash_fwd': 2, 'flash_dq': 2, 'flash_dkv': 2,
+         'flash_fwd_band': 2, 'flash_dq_band': 2, 'flash_dkv_band': 2}),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_REMAT_BLOCKS))
+def test_remat_block_lowers_with_one_forward_kernel(monkeypatch, case):
+    import collections
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu import telemetry
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    monkeypatch.setattr(fa, '_interpret_default', lambda: False)
+    kw, per_chip, seq, dp, want = _REMAT_BLOCKS[case]
+    cfg = TransformerConfig(**dict(dict(
+        vocab=256, dim=1024, n_layers=1, n_heads=16, max_len=seq,
+        dtype=jnp.bfloat16, remat=True), **kw))
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(dp=dp))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = {name: np.zeros((dp * per_chip, seq), np.int32)
+             for name in ('tokens', 'targets')}
+    n_before = len(telemetry.get().loop_records())
+    step = tr._ensure_step(tr._step_key(batch), state, batch)
+    shapes = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        batch, tr.batch_sharding(batch))
+    text = jax.export.export(step, platforms=['tpu'])(
+        state, shapes).mlir_module()
+    assert collections.Counter(
+        re.findall(r'kernel_name = "(\w+)"', text)) == want
+    event = [r['tags'] for r in telemetry.get().loop_records()[n_before:]
+             if r['name'] == 'transformer.remat'][0]
+    # o in bf16 and lse in f32, of a chip's share of the batch
+    assert event['layers'] == cfg.n_layers
+    assert event['saved_bytes_per_layer'] == per_chip * seq * (
+        1024 * 2 + 16 * 4)
+
+
 # The Mosaic modules of the existing flash cells' kernels with their
 # source locations stripped (the serialized module carries the file's
 # path and line numbers, so the bytes differ from checkout to checkout).
