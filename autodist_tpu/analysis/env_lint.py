@@ -37,7 +37,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 #: Scanned roots, relative to the repo.
-SCAN_ROOTS = ('autodist_tpu', 'tools', 'tests', 'examples', 'bench.py',
+SCAN_ROOTS = ('autodist_tpu', 'tools', 'tests', 'examples',
               '__graft_entry__.py')
 
 #: Undeclared raw reads allowed, with the reason. Empty on HEAD: every

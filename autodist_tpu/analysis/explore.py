@@ -277,7 +277,7 @@ SEEDED_BUGS = (
 #: Exploration statistics of the last :func:`analyze` run (or any
 #: model-checker pass using :func:`run_suite`): per-scenario and total
 #: states explored, so ``tools/analyze.py --json`` can report model
-#: cost and ``bench_compare`` can flag state-space blowup.
+#: cost.
 LAST_STATS = {}
 
 
@@ -304,8 +304,8 @@ def run_suite(head_cfg, scenarios_fn, seeded, label, stats=None,
         result = explore(sc, max_states=max_states)
         # unique stats key per seeded exploration: two seeds sharing a
         # scenario+kind (e.g. both pipeline floor bugs) must both show
-        # up, or a state-space blowup in the second is invisible to
-        # the bench_compare gate these counts feed
+        # up, or a state-space blowup in the second is invisible in
+        # the report these counts feed
         key = '%s[%s]' % (scen_name, kind)
         while key in per_scenario:
             key += "'"

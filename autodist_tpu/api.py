@@ -447,8 +447,9 @@ class Trainer:
     def compile_step(self, state, batch):
         """AOT-compile the step for this batch signature, ONCE, and make
         subsequent ``step`` calls with the same signature reuse the same
-        executable. Returns the ``jax.stages.Compiled`` (which exposes
-        ``cost_analysis()`` — used by bench.py for FLOP cross-checks)."""
+        executable. Returns the ``jax.stages.Compiled`` (whose
+        ``as_text()`` the benchmark's engine and ``chip_smoke.py``
+        read)."""
         with telemetry.get().loop_span('trainer.compile_step',
                                        step=self._steps_run + 1):
             key = self._step_key(batch)

@@ -304,8 +304,8 @@ class ENV(Enum):
                             lo=0),)
     # deterministic fault-injection plan (utils/faultline.py): inline
     # JSON, or @/path/to/plan.json. Empty = no faults. Only honored
-    # when the process explicitly installs a FaultLine (chaos tests,
-    # bench recovery A/B) — production sessions never read it.
+    # when the process explicitly installs a FaultLine (the chaos
+    # tests) — production sessions never read it.
     AUTODIST_FAULT_PLAN = (lambda v: v if v else '',)
     # Block size (elements) for block-quantized int8 wire formats: the
     # Int8RingCompressor's bucket/ring quantization and the PS data

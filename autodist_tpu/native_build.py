@@ -9,6 +9,7 @@ the source changes. Uses plain g++ (present in the supported images); a
 import hashlib
 import os
 import subprocess
+import tempfile
 
 from autodist_tpu.const import DEFAULT_WORKING_DIR
 from autodist_tpu.utils import logging
@@ -50,7 +51,15 @@ def build(source_name, output_name=None, shared=False, extra_flags=()):
     if os.path.exists(out):
         return out
     os.makedirs(out_dir, exist_ok=True)
-    cmd = cmd + [src, '-o', out]
-    logging.info('Building native component: %s', ' '.join(cmd))
-    subprocess.run(cmd, check=True)
+    # Compile under a directory of this call's own and rename onto
+    # ``out``: callers that race (several workers on a cold host) each
+    # link their own file, so whatever exists at ``out`` is whole and
+    # never open for writing (executing a file the linker still holds
+    # fails with ETXTBSY); a failed compile leaves nothing there.
+    with tempfile.TemporaryDirectory(dir=out_dir) as tmp_dir:
+        tmp = os.path.join(tmp_dir, out_name)
+        cmd = cmd + [src, '-o', tmp]
+        logging.info('Building native component: %s', ' '.join(cmd))
+        subprocess.run(cmd, check=True)
+        os.replace(tmp, out)
     return out

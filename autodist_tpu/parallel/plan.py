@@ -342,8 +342,8 @@ def static_collective_schedule(strategy, graph_item, num_replicas,
     the roofline drift table join on.
     ``bytes``
     are RAW tensor bytes; anything REPORTING traffic must route them
-    through ``simulator.cost_model.wire_bytes`` (as the cost model,
-    ``profiling.bucket_report`` and ``bench.py`` do) — under a
+    through ``simulator.cost_model.wire_bytes`` (as the cost model
+    and ``profiling.bucket_report`` do) — under a
     compressed wire the raw figure overstates by 2-4x. Sparse
     (embedding) vars
     assume ``sparse_lookups_per_replica`` looked-up rows per step, the
@@ -814,8 +814,8 @@ class ExecutionPlan:
         self._pure_sparse_cache = {}
         # per-bucket accounting from the most recent sync_gradients
         # trace: [{'kind', 'group', 'compressor', 'dtype', 'spec',
-        # 'vars', 'bytes'}] — 'bytes' are RAW tensor bytes; bench.py
-        # and utils/profiling.bucket_report attach the wire figure via
+        # 'vars', 'bytes'}] — 'bytes' are RAW tensor bytes;
+        # utils/profiling.bucket_report attaches the wire figure via
         # simulator.cost_model.wire_bytes so the bucket layout (and the
         # overlap + compression it enables) is auditable without
         # reading HLO. Each record carries the schedule 'entry_id'

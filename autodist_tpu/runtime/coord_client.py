@@ -355,7 +355,9 @@ def ensure_service(port=DEFAULT_COORD_PORT, wait_s=10.0, bind='127.0.0.1'):
                             stdout=subprocess.DEVNULL,
                             stderr=subprocess.DEVNULL, env=env)
     deadline = time.time() + wait_s
-    while time.time() < deadline:
+    # a child that has exited (it could not bind the port) will never
+    # answer: stop waiting for it
+    while time.time() < deadline and proc.poll() is None:
         try:
             CoordClient(('127.0.0.1', port), timeout=0.5).ping()
             logging.info('coord_service started on :%d (pid %d)',
@@ -455,7 +457,7 @@ class CoordClient:
     """Blocking line-protocol client."""
 
     # Fault-injection hook (utils/faultline.py): when set (class-wide,
-    # chaos tests / bench recovery only), called as
+    # chaos tests only), called as
     # ``hook(client, line, payload)`` before every request frame hits
     # the wire. The hook may raise (drop/close faults), sleep (delay
     # faults) or return a replacement ``(line, payload)`` (torn-frame

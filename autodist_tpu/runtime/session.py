@@ -119,8 +119,8 @@ def admit_worker(coord, ns, max_workers=None, wait_init_s=120.0,
     PR 4 made workers *leaving* survivable; this makes joining possible).
 
     One protocol, one place: :class:`Session` joins through it when
-    ``AUTODIST_ELASTIC_JOIN`` is set, and chaos tests / ``bench.py``'s
-    elastic A/B drive it with a raw client — the handshake must not be
+    ``AUTODIST_ELASTIC_JOIN`` is set, and the chaos tests drive it
+    with a raw client — the handshake must not be
     re-implemented per caller or the fault-injection coverage
     (``faultline``'s ``join_*`` kinds) stops meaning anything.
 
@@ -2061,8 +2061,8 @@ class Session:
     def step_wall_series(self):
         """The uniform per-step wall series: ``run()``'s wall seconds
         for every executed train step, EVERY mode (loose or SPMD,
-        pipelined or serial) — the series ``bench.py`` and the
-        telemetry snapshot read. Bounded ring
+        pipelined or serial) — the series the telemetry snapshot
+        reads. Bounded ring
         (``AUTODIST_TELEMETRY_MAX_SPANS``), oldest first."""
         return list(self._step_walls)
 
@@ -3260,8 +3260,8 @@ class Session:
             self._ps_seconds += push_s
             self._ps_bytes += wire_bytes
             # direction split: the proxy refresh is READ traffic even
-            # though it rides the push phase, so the quantized-push
-            # A/B (bench_quantized) can compare pure push bytes
+            # though it rides the push phase, so a quantized-push
+            # A/B (tests/test_quantized_wire.py) compares pure push bytes
             self._ps_push_bytes += push_only_bytes
             self._ps_pull_bytes += refresh_bytes
             self._ps_phase['push_s'] += push_s

@@ -162,7 +162,7 @@ class ServingFleet:
 
     def stats(self):
         """Aggregated fleet stats for ``profiling.health_report``'s
-        ``serving`` section (and the bench's serving block)."""
+        ``serving`` section."""
         per = [r.serve_stats() for r in self.replicas]
         samples = []
         for r in self.replicas:

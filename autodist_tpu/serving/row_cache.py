@@ -12,7 +12,7 @@ sparse flavor of a mixed-version read).
 
 Accounting is part of the contract, not a debugging afterthought:
 ``hits``/``misses``/``evictions``/``expirations``/``invalidations``
-feed ``serve_stats`` -> ``profiling.health_report`` -> bench.
+feed ``serve_stats`` -> ``profiling.health_report``.
 """
 import collections
 import time

@@ -21,11 +21,9 @@ Cost contract (the tentpole's overhead budget):
   after a single attribute check;
 - **enabled**: one ``perf_counter`` pair + one bounded-deque append
   per span (~3 us measured); batch pushes ride the session's
-  dedicated background lane, never the step's critical path. ≤ 2%
-  step time on the CPU smoke, measured by ``bench.bench_telemetry``'s
-  per-record decomposition (records/step x measured record cost +
-  the on-path drain share of a push — the raw on-vs-off wall delta
-  is recorded as context but is scheduler noise at ms-scale steps).
+  dedicated background lane, never the step's critical path. What
+  the always-on loop spans cost a training step on the chip is in
+  ``PERF.md`` (PR 24's entry).
 
 Buffers are bounded (``AUTODIST_TELEMETRY_MAX_SPANS``): telemetry must
 never grow without bound on a long run — old spans fall off the front
@@ -282,9 +280,7 @@ class Telemetry:
     def metrics_snapshot(self):
         """One JSON-serializable snapshot of the whole registry:
         counters, gauges, per-series stats and per-span-name
-        aggregates. Embedded in every BENCH record
-        (``bench.bench_telemetry``) and in the chief's cohort
-        timeline."""
+        aggregates. Embedded in the chief's cohort timeline."""
         with self._lock:
             by_name = {}
             for name, agg in self._span_agg.items():

@@ -42,11 +42,9 @@ Detection is OBSERVABILITY, never actuation: the
 surface in ``health_report`` with an ``exclude_candidate`` flag); the
 PR 4 peer-failure policy machinery remains the sole actuator.
 
-Surfacing: ``tools/monitor.py`` (live/offline CLI), the
-``health_report`` perf section, and ``bench.bench_monitor`` (the
-detection-latency / false-positive / overhead A/B in every BENCH
-record). ``tools/trace_view.py --json`` renders per-phase columns
-through the SAME :func:`phase_splits` implementation, pinned by a
+Surfacing: ``tools/monitor.py`` (live/offline CLI) and the
+``health_report`` perf section. ``tools/trace_view.py --json``
+renders per-phase columns through the SAME :func:`phase_splits` implementation, pinned by a
 shared test, so the CLI and the verdicts cannot drift.
 """
 import statistics

@@ -38,11 +38,10 @@ device-plane twin:
 Everything degrades explicitly, never silently: a CPU-fallback host
 gets ``mfu: None`` with a named reason (no meaningful peak), a
 trace with no device timeline gets ``achieved_s: None`` rows, and the
-whole module never raises mid-bench for a missing backend feature.
+whole module never raises mid-run for a missing backend feature.
 
 Surfacing: ``tools/roofline.py`` (offline record/trace input,
-``--json``), the ``roofline`` block in every BENCH record
-(``bench.bench_roofline``), and the session's per-step series under
+``--json``) and the session's per-step series under
 ``AUTODIST_ROOFLINE`` / ``AUTODIST_ROOFLINE_EVERY``.
 """
 import math
@@ -147,8 +146,9 @@ def classify_regime(flops, bytes_accessed, wall_s, peak_flops,
                     peak_hbm_bps, comms_s=None):
     """One step's roofline record.
 
-    ``mfu`` = flops / peak_flops / wall (the model-FLOPs-utilization
-    definition bench.py's headline uses); ``hbm_frac`` the analogous
+    ``mfu`` = flops / peak_flops / wall (model FLOPs utilization, from
+    the compiler's own FLOP count; the benchmark's ``mfu_pct`` counts
+    them from shapes instead); ``hbm_frac`` the analogous
     bytes-accessed / peak-HBM fraction; ``comms_frac`` = exposed comms
     seconds / wall when the caller measured them. ``roofline_regime``
     is the largest of the computable fractions — the bound the step is

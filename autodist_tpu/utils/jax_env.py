@@ -1,8 +1,9 @@
 """JAX process-environment helpers for entry points.
 
 Nothing here runs at package import: entry points (``chip_smoke.py``,
-``bench.py``, ``examples/_common.py``, the ``tools/`` drivers that
-compile real models) call :func:`setup_compile_cache` themselves, and
+``benchmark/harness.py``, ``examples/_common.py``,
+``tools/pp_schedule_table.py``) call :func:`setup_compile_cache`
+themselves, and
 the session arms the XLA overlap flags when its plan needs them.
 """
 import os

@@ -3,12 +3,12 @@
 Loose mode is a multi-process mode; driving its PS data plane from ONE
 process needs a subtle env dance: the strategy build must see 2
 processes (the mode decision) while the session sees 1 (no peers to
-barrier with) — the same data plane either way. bench.py's ps-pipeline
-A/B and tests/test_async_ps.py both ride this helper so the dance
-lives in exactly one place.
+barrier with) — the same data plane either way. ``chip_smoke.py``'s
+loose-mode leg and the loose-mode tests (tests/test_async_ps.py and its
+siblings) all ride this helper so the dance lives in exactly one place.
 
 This module also hosts :func:`ack_staged_swaps`, the swap-handshake
-half of a SIMULATED peer: tests and benches that fake a cohort member
+half of a SIMULATED peer: tests that fake a cohort member
 with a bare coord client (publish step, heartbeat, release) must also
 speak the epoch-swap ack protocol or the chief's ack quorum would
 never fill.  One helper, called from every simulated-peer loop, keeps

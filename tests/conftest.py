@@ -9,7 +9,11 @@ pinned here, before jax's backend initializes, so a test run never takes
 an accelerator whatever the caller's environment says. ``XLA_FLAGS`` also
 carries the device count so subprocesses the tests start inherit it.
 """
+import contextlib
 import os
+import shutil
+import socket
+import subprocess
 
 os.environ.setdefault('AUTODIST_IS_TESTING', 'True')
 if 'xla_force_host_platform_device_count' not in \
@@ -35,6 +39,88 @@ def _fresh_process_state():
     ad_mod._DEFAULT_AUTODIST.clear()
     if hasattr(fe._GRAPH_STACK, 'stack'):
         fe._GRAPH_STACK.stack.clear()
+
+
+def free_port():
+    """A loopback port that was free a moment ago. Nothing holds it
+    once this returns, so whoever binds it next must be ready to lose
+    it to another process in the gap."""
+    with socket.socket() as s:
+        s.bind(('127.0.0.1', 0))
+        return s.getsockname()[1]
+
+
+def shutdown_service(addr):
+    """Shut down the coord service at ``host:port`` if one is there: a
+    test that plays launcher owns the lifetime of the service its
+    processes started (launch_cli parity)."""
+    from autodist_tpu.runtime.coord_client import CoordClient
+    host, port = addr.rsplit(':', 1)
+    try:
+        CoordClient((host, int(port)), timeout=2.0).shutdown()
+    except OSError:
+        pass
+
+
+@contextlib.contextmanager
+def coord_service(port=None, attempts=5):
+    """A native coord service of the caller's own: yields its port and
+    shuts it down on exit (kills it if a test already took it down).
+
+    Starts on ``port`` (a free one by default); when the start fails
+    there (the port was taken in the gap, or the start deadline was
+    missed under load) or something already answers on it (another
+    module's service, which is not ours to share or to shut down),
+    tries another free port, ``attempts`` starts in all."""
+    from autodist_tpu.runtime.coord_client import (CoordClient,
+                                                   ensure_service)
+    if shutil.which('g++') is None:
+        pytest.skip('g++ unavailable')
+    failure = None
+    for _ in range(attempts):
+        port = port or free_port()
+        try:
+            proc = ensure_service(port=port)
+        except RuntimeError as e:
+            proc, failure = None, e
+        # None: the answer came from a service that was there before;
+        # a child that is gone lost the port to whoever answered
+        if proc is not None and proc.poll() is None:
+            break
+        port = None
+    else:
+        raise RuntimeError('no coord service of our own after %d starts'
+                           % attempts) from failure
+    try:
+        yield port
+    finally:
+        try:
+            CoordClient(('127.0.0.1', port)).shutdown()
+            proc.wait(timeout=5)
+        except (OSError, subprocess.TimeoutExpired):
+            proc.kill()
+            proc.wait(timeout=5)
+
+
+@pytest.fixture(scope='module')
+def coord_port():
+    """The port of a coord service that lives as long as the module."""
+    with coord_service() as port:
+        yield port
+
+
+@pytest.fixture(scope='module')
+def coord(coord_port):
+    """``coord(**kw)``: a new client of the module's coord service."""
+    from autodist_tpu.runtime.coord_client import CoordClient
+    return lambda **kw: CoordClient(('127.0.0.1', coord_port), **kw)
+
+
+@pytest.fixture
+def service():
+    """The port of a coord service that lives as long as one test."""
+    with coord_service() as port:
+        yield port
 
 
 def _kernel_calls(jaxpr, times=1, counts=None):

@@ -14,7 +14,6 @@ The deterministic single-process subset lives in
 tests/test_chaos_recovery.py."""
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -23,27 +22,12 @@ import time
 import numpy as np
 import pytest
 
+from conftest import free_port, shutdown_service
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 pytestmark = [pytest.mark.integration, pytest.mark.chaos]
-
-
-def free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _shutdown_service(addr):
-    from autodist_tpu.runtime.coord_client import CoordClient
-    host, port = addr.rsplit(':', 1)
-    try:
-        CoordClient((host, int(port)), timeout=2.0).shutdown()
-    except OSError:
-        pass
 
 
 COMMON_PRELUDE = textwrap.dedent("""
@@ -175,7 +159,7 @@ def test_exclude_kill_1_of_4_survivors_finish(tmp_path):
             q.kill()
         raise
     finally:
-        _shutdown_service(coord_service)
+        shutdown_service(coord_service)
     results = {}
     for rc, out, err in outs:
         assert rc == 0, 'rc=%s\nstdout:%s\nstderr:%s' % (rc, out,
@@ -332,7 +316,7 @@ def test_elastic_scale_up_2_4_3(tmp_path):
             q.kill()
         raise
     finally:
-        _shutdown_service(coord_service)
+        shutdown_service(coord_service)
 
     def parse(tag, out):
         lines = [ln for ln in out.splitlines() if ln.startswith(tag)]
@@ -502,7 +486,7 @@ def test_restart_supervised_worker_process_rejoins(tmp_path):
     finally:
         sup.join(timeout=60.0)
         sup.terminate()
-        _shutdown_service(coord_service)
+        shutdown_service(coord_service)
     assert chief.returncode == 0, 'chief rc=%s\n%s\n%s' \
         % (chief.returncode, out, err[-4000:])
     assert not gave_up, 'supervisor gave up: %s' % gave_up

@@ -16,7 +16,6 @@ Mirrors the reference's 2-machine distributed tests
 """
 import json
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -24,30 +23,13 @@ import textwrap
 import numpy as np
 import pytest
 
+from conftest import free_port, shutdown_service
+
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 # reference c0 per-role gradient ground truth (cases/c0.py:92-120)
 GRAD_CHIEF, GRAD_WORKER = 4.17503, 4.05530
-
-
-def free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-def _shutdown_service(addr):
-    """The launcher owns the coord service's lifetime (launch_cli parity);
-    here the test plays launcher."""
-    from autodist_tpu.runtime.coord_client import CoordClient
-    host, port = addr.rsplit(':', 1)
-    try:
-        CoordClient((host, int(port)), timeout=2.0).shutdown()
-    except OSError:
-        pass
 
 
 COMMON_PRELUDE = textwrap.dedent("""
@@ -117,7 +99,7 @@ def launch_procs(tmp_path, script_body, nprocs, timeout=300,
                 raise
             outs.append((p.returncode, out, err))
     finally:
-        _shutdown_service(coord_service)
+        shutdown_service(coord_service)
     results = []
     for required, (rc, out, err) in zip(require_result, outs):
         if required:
@@ -404,7 +386,7 @@ def test_partitioned_var_shards_span_endpoints(tmp_path):
                        'AUTODIST_PS_CHUNK_BYTES': str(16 << 20)})
     finally:
         for p in ep_ports:
-            _shutdown_service('127.0.0.1:%d' % p)
+            shutdown_service('127.0.0.1:%d' % p)
     for r in results:
         # ONE variable, TWO endpoints: the shards really span them
         assert sorted(r['shard_eps']) == [0, 1], r
@@ -474,7 +456,7 @@ def test_loose_mode_carries_100mb_model_multi_endpoint(tmp_path):
             extra_env={'AUTODIST_PS_ENDPOINTS': eps})
     finally:
         for p in ep_ports:
-            _shutdown_service('127.0.0.1:%d' % p)
+            shutdown_service('127.0.0.1:%d' % p)
     # wire bytes halve under AUTODIST_PS_WIRE_DTYPE=bf16
     scale = 0.5 if os.environ.get('AUTODIST_PS_WIRE_DTYPE') == 'bf16' \
         else 1.0
@@ -725,7 +707,7 @@ def test_four_worker_loose_100mb_two_endpoints(tmp_path):
             extra_env={'AUTODIST_PS_ENDPOINTS': eps})
     finally:
         for p in ep_ports:
-            _shutdown_service('127.0.0.1:%d' % p)
+            shutdown_service('127.0.0.1:%d' % p)
     agg_mb = sum(r['ps_mb'] for r in results)
     agg_s = max(r['ps_s'] for r in results)
     # wire bytes halve under AUTODIST_PS_WIRE_DTYPE=bf16

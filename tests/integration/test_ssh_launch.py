@@ -22,13 +22,14 @@ as a check that everything a worker needs rides the remote command.
 import json
 import os
 import shutil
-import socket
 import subprocess
 import sys
 import textwrap
 import time
 
 import pytest
+
+from conftest import free_port, shutdown_service
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -94,14 +95,6 @@ PROG = textwrap.dedent("""
 """)
 
 
-def free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
 def _write_shims(tmp_path):
     bindir = tmp_path / 'bin'
     bindir.mkdir()
@@ -148,8 +141,13 @@ def _run_chief(tmp_path, worker_hook='pass', ssh_section=None,
     prog.write_text(PROG % {'repo': REPO, 'worker_hook': worker_hook})
     env = _chief_env(tmp_path, _resource_file(tmp_path, ssh_section),
                      _write_shims(tmp_path) if with_shims else None)
-    return subprocess.run([sys.executable, str(prog)], env=env,
-                          capture_output=True, text=True, timeout=timeout)
+    try:
+        return subprocess.run([sys.executable, str(prog)], env=env,
+                              capture_output=True, text=True,
+                              timeout=timeout)
+    finally:
+        # a chief that its fail-fast monitor hard-exits shuts nothing down
+        shutdown_service(env['AUTODIST_COORD_SERVICE_ADDR'])
 
 
 def _results(out):

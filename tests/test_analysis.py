@@ -718,8 +718,7 @@ def test_schedule_lint_reshard_preconditions():
 def test_analyze_cli_all_json():
     """`tools/analyze.py --all` exits 0 on HEAD with zero findings and
     the --json report carries schema_version, per-analyzer wall time
-    and (for the model checkers) states-explored counts — the shape
-    bench.py stores under the stable 'analysis' BENCH key."""
+    and (for the model checkers) states-explored counts."""
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, 'tools', 'analyze.py'),
          '--all', '--json'],

@@ -7,8 +7,8 @@ Tier-1 safe on CPU: everything runs single-process against a live
 coord_service on a private port (skipped without g++, like
 test_native.py).
 """
+import select
 import shutil
-import socket
 import threading
 import time
 from contextlib import contextmanager
@@ -19,26 +19,6 @@ import pytest
 HAVE_GXX = shutil.which('g++') is not None
 
 pytestmark = pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-
-
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture(scope='module')
-def coord():
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield lambda **kw: CoordClient(('127.0.0.1', port), **kw)
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
 
 
 # -- pipelined multi-tensor RPCs ----------------------------------------------
@@ -127,14 +107,16 @@ def test_vmget_retries_version_skew_between_chunks(coord, monkeypatch):
     from autodist_tpu.runtime.coord_client import CoordClient
     real_send = CoordClient._send_frame
     seen = []
-    fired = []
 
     def send_with_one_push(self, line, payload=None):
-        # one whole push lands between the FIRST attempt's two chunks
+        # one whole push lands between the FIRST attempt's two chunks:
+        # requests are pipelined, so chunk two's send says nothing of
+        # chunk one. Its reply on the socket does: the service has read
+        # chunk one, at the old version, and has not seen chunk two.
         if self is c and line.startswith('BGET skew/k'):
             seen.append(line)
-            if len(seen) == 2 and not fired:
-                fired.append(True)
+            if len(seen) == 2:
+                assert select.select([c._sock], [], [], 60.0)[0]
                 pusher.vadd('skew/k', np.ones(10, np.float32))
         return real_send(self, line, payload)
 
@@ -301,8 +283,7 @@ def _loose_session(monkeypatch, coord_port, depth, staleness=2,
                    dim=48, seed=0):
     """Single-process loose-mode session harness: the build-sees-2/
     session-sees-1 env dance lives in
-    ``utils.loose_harness.single_process_loose_env`` (shared with
-    bench.py's ps-pipeline A/B). Yields
+    ``utils.loose_harness.single_process_loose_env``. Yields
     (sess, train_op, x placeholder, W0, feed)."""
     del monkeypatch   # env handled (and restored) by the shared harness
     import autodist_tpu as ad
@@ -433,9 +414,10 @@ def test_depth2_push_precedes_publish_and_next_pull(coord, monkeypatch):
 
 
 def test_depth2_records_overlap(coord, monkeypatch):
-    """With a host tail between steps, depth 2 hides wire time: the
-    session's measured overlap_frac is > 0 and the profiling report
-    attributes hidden vs exposed wire seconds."""
+    """With a host tail between steps that outlasts the background
+    push, depth 2 hides wire time: the session's measured overlap_frac
+    is > 0 and the profiling report attributes hidden vs exposed wire
+    seconds."""
     from autodist_tpu.utils.profiling import (format_ps_overlap,
                                               ps_overlap_report)
     host, port = coord().address
@@ -443,7 +425,9 @@ def test_depth2_records_overlap(coord, monkeypatch):
             sess, train_op, x, W0, feed):
         sess.run(train_op, {x: feed})          # compile + warmup
         for _ in range(4):
-            time.sleep(0.05)                   # input-pipeline interval
+            # the input pipeline's interval: as long as the push and
+            # the pull-ahead take, whatever else the machine is doing
+            sess._inflight.result(timeout=60.0)
             sess.run(train_op, {x: feed})
         sess.get_variable_value('W')           # drain the last push
         stats = sess.ps_stats

@@ -10,7 +10,6 @@ tests/integration/test_chaos.py.
 
 Tier-1 safe on CPU (skipped without g++, like test_native.py)."""
 import shutil
-import socket
 import threading
 import time
 
@@ -22,30 +21,6 @@ pytestmark = [
     pytest.mark.skipif(shutil.which('g++') is None,
                        reason='g++ unavailable'),
 ]
-
-
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture()
-def service():
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield port
-    try:
-        CoordClient(('127.0.0.1', port)).shutdown()
-        if proc is not None:
-            proc.wait(timeout=5)
-    except OSError:
-        if proc is not None:
-            proc.kill()
 
 
 @pytest.fixture(autouse=True)

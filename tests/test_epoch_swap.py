@@ -14,7 +14,6 @@ executed re-keying migration) lives in tests/test_chaos_recovery.py
 and tests/test_reshard.py.
 """
 import shutil
-import socket
 
 import pytest
 
@@ -140,32 +139,13 @@ class TestSwapConformance:
 
 # -- generation hygiene against a live coord service ----------------------
 
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
 @pytest.mark.skipif(shutil.which('g++') is None,
                     reason='g++ unavailable')
 class TestGenerationHygiene:
     @pytest.fixture()
-    def client(self):
-        from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                       ensure_service)
-        port = _free_port()
-        proc = ensure_service(port=port)
-        c = CoordClient(('127.0.0.1', port))
-        yield c
-        try:
-            c.shutdown()
-            if proc is not None:
-                proc.wait(timeout=5)
-        except OSError:
-            if proc is not None:
-                proc.kill()
+    def client(self, service):
+        from autodist_tpu.runtime.coord_client import CoordClient
+        return CoordClient(('127.0.0.1', service))
 
     def test_stage_purges_previous_generation(self, client):
         ns = 'nsswap'

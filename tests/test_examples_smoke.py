@@ -12,6 +12,8 @@ import re
 import subprocess
 import sys
 
+import numpy as np
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -59,3 +61,13 @@ def test_sentiment_classifier_dsl_example():
     assert len(losses) >= 2, out
     assert losses[-1] < losses[0], losses
     assert 'emb table: shape (10000, 16)' in out
+
+
+def test_graft_entry_forward():
+    import jax
+
+    import __graft_entry__ as g
+    fn, (params, tokens) = g.entry()
+    logits = jax.jit(fn)(params, tokens)
+    assert logits.shape[0] == tokens.shape[0]
+    assert np.isfinite(np.asarray(logits)).all()

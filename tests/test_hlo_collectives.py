@@ -8,6 +8,8 @@ StableHLO of a compiled training step must contain exactly ONE
 all-reduce per gradient group — group fusion is a property of OUR
 emission, not of XLA's (size-bounded) all-reduce combiner pass.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -15,6 +17,41 @@ import jax
 
 import autodist_tpu as ad
 from autodist_tpu.strategy import AllReduce, PartitionedPS
+
+_DTYPE_BYTES = {'pred': 1, 's8': 1, 'u8': 1, 's16': 2, 'u16': 2,
+                'bf16': 2, 'f16': 2, 's32': 4, 'u32': 4, 'f32': 4,
+                's64': 8, 'u64': 8, 'f64': 8}
+# Sync collectives and the '-done' halves of async pairs: both carry
+# exactly the OUTPUT buffer in their result. '-start' ops are skipped:
+# their result tuples also include the input operand buffer, which
+# would double-count the wire bytes.
+_COLLECTIVE_RE = re.compile(
+    r'(all-reduce|all-gather|reduce-scatter|collective-permute|'
+    r'all-to-all)(?:-done)?\(')
+_SHAPE_RE = re.compile(r'(\w+)\[([\d,]*)\]')
+
+
+def _collective_bytes(hlo):
+    """Per-step communication volume, from optimised HLO text: result
+    bytes of every collective, keyed by collective kind (variadic
+    tuple-result collectives, the program-level gradient-group fusion,
+    sum their elements). A collective inside a ``while`` body counts
+    once, not once per iteration; the dp gradient all-reduces this is
+    used for sit outside any scan."""
+    out = {}
+    for line in hlo.splitlines():
+        m = _COLLECTIVE_RE.search(line)
+        eq = line.find(' = ')
+        if not m or eq < 0 or m.start() < eq:
+            continue
+        total = 0
+        for dtype, dims in _SHAPE_RE.findall(line[eq + 3:m.start()]):
+            size = _DTYPE_BYTES[dtype]
+            for d in filter(None, dims.split(',')):
+                size *= int(d)
+            total += size
+        out[m.group(1)] = out.get(m.group(1), 0) + total
+    return out
 
 
 def _compiled_step_text(strategy_builder, n_vars=4, dim=4):
@@ -79,10 +116,7 @@ def test_collective_bytes_conserved_at_realistic_size():
     At 4 x 4 MB gradients (16.8 MB total), whatever XLA's combiner
     does downstream, the COMPILED program's total all-reduce result
     bytes must equal the gradient bytes exactly — wire-volume
-    conservation is merge-agnostic (accounting via
-    bench.collective_bytes, the same parser the scaling bench
-    reports)."""
-    import bench as B
+    conservation is merge-agnostic."""
     dim, n_vars = 1024, 4
     want = n_vars * dim * dim * 4   # f32 gradients
 
@@ -90,12 +124,7 @@ def test_collective_bytes_conserved_at_realistic_size():
         text, opt = _compiled_step_text(AllReduce(chunk_size=chunk_size),
                                         n_vars=n_vars, dim=dim)
         assert text.count('stablehlo.all_reduce') == emitted
-
-        class _C:   # adapt raw text to collective_bytes' interface
-            def as_text(self):
-                return opt
-
-        got = B.collective_bytes(_C()).get('all-reduce', 0)
+        got = _collective_bytes(opt).get('all-reduce', 0)
         assert got == want, (chunk_size, got, want)
 
 
@@ -106,7 +135,6 @@ def test_forced_ring_wire_is_bandwidth_optimal():
     ≈1.9·|T| at n=8 — where the naive whole-tensor ring this replaced
     shipped (n-1)·|T| = 7·|T|. The compiled HLO's collective result
     bytes pin the bound."""
-    import bench as B
     dim, n_vars = 64, 4
     grad_bytes = n_vars * dim * dim * 4   # f32, one fused flat bucket
     text, opt = _compiled_step_text(
@@ -114,12 +142,7 @@ def test_forced_ring_wire_is_bandwidth_optimal():
         n_vars=n_vars, dim=dim)
     # forced ring: the program must carry NO plain all-reduce
     assert text.count('stablehlo.all_reduce') == 0
-
-    class _C:   # adapt raw text to collective_bytes' interface
-        def as_text(self):
-            return opt
-
-    by_kind = B.collective_bytes(_C())
+    by_kind = _collective_bytes(opt)
     wire = by_kind.get('collective-permute', 0) + \
         by_kind.get('all-gather', 0)
     assert wire > 0, by_kind

@@ -8,7 +8,6 @@ the FENCE protocol end-to-end at the client/service level.
 
 Tier-1 safe on CPU (skipped without g++, like test_native.py)."""
 import shutil
-import socket
 import threading
 import time
 
@@ -17,26 +16,6 @@ import pytest
 
 pytestmark = pytest.mark.skipif(shutil.which('g++') is None,
                                 reason='g++ unavailable')
-
-
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture(scope='module')
-def coord():
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield lambda **kw: CoordClient(('127.0.0.1', port), **kw)
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
 
 
 # -- dead_workers edges ------------------------------------------------------

@@ -8,7 +8,6 @@ The session tests run single-process against a live coord_service on
 a private port (skipped without g++, like tests/test_async_ps.py).
 """
 import shutil
-import socket
 from contextlib import contextmanager
 
 import numpy as np
@@ -190,28 +189,6 @@ def test_lazy_rows_bit_stable_across_window(opt_name):
 
 # -- loose-mode session window machinery ----------------------------------
 
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture(scope='module')
-def coord():
-    if not HAVE_GXX:
-        pytest.skip('g++ unavailable')
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield port
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
-
-
 @contextmanager
 def _loose_session(coord_port, h, depth=1, dim=48, seed=0):
     """Single-process loose-mode session at window length ``h`` (the
@@ -258,12 +235,12 @@ def _serial_ground_truth(W0, feed, steps, lr=0.1):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_h1_sync_rounds_equal_train_steps(coord):
+def test_h1_sync_rounds_equal_train_steps(coord_port):
     """The H=1 equivalence pin (satellite 3): with no window every
     train step IS a sync round, so ps_stats' per-round pull/push
     divides are bit-for-bit the legacy per-step ones, and the math
     tracks the serial trajectory unchanged."""
-    with _loose_session(coord, h=1) as (sess, train_op, x, W0, feed):
+    with _loose_session(coord_port, h=1) as (sess, train_op, x, W0, feed):
         for _ in range(5):
             sess.run(train_op, {x: feed})
         got = sess.get_variable_value('W')
@@ -277,12 +254,12 @@ def test_h1_sync_rounds_equal_train_steps(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_window_round_accounting(coord):
+def test_window_round_accounting(coord_port):
     """At H=4 the wire phases happen once per SYNC ROUND: 8 train
     steps = 2 rounds of pull/push, and the pipeline stats divide by
     rounds (dividing by train steps would understate per-round
     averages 4x — the satellite-3 fix)."""
-    with _loose_session(coord, h=4) as (sess, train_op, x, W0, feed):
+    with _loose_session(coord_port, h=4) as (sess, train_op, x, W0, feed):
         for _ in range(8):
             sess.run(train_op, {x: feed})
         stats = sess.ps_stats
@@ -294,14 +271,14 @@ def test_window_round_accounting(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_window_delta_telescopes_to_serial(coord):
+def test_window_delta_telescopes_to_serial(coord_port):
     """One worker's window delta (state-after-H-local-steps minus the
     round's pulled base) telescopes to the sequential trajectory: the
     H=4 final state matches H=1 (and the analytic serial path) up to
     float reassociation noise."""
     finals = {}
     for h in (1, 4):
-        with _loose_session(coord, h=h, seed=7) as (
+        with _loose_session(coord_port, h=h, seed=7) as (
                 sess, train_op, x, W0, feed):
             for _ in range(8):
                 sess.run(train_op, {x: feed})
@@ -314,11 +291,11 @@ def test_window_delta_telescopes_to_serial(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_partial_window_is_dropped_at_close(coord):
+def test_partial_window_is_dropped_at_close(coord_port):
     """The round is the atomic unit: 6 train steps at H=4 complete
     one sync round, and the 2-step tail never reaches the PS — the
     authoritative read serves the round-1 state (4 serial steps)."""
-    with _loose_session(coord, h=4) as (sess, train_op, x, W0, feed):
+    with _loose_session(coord_port, h=4) as (sess, train_op, x, W0, feed):
         for _ in range(6):
             sess.run(train_op, {x: feed})
         got = sess.get_variable_value('W')
@@ -329,12 +306,12 @@ def test_partial_window_is_dropped_at_close(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_env_window_overrides_strategy(coord, monkeypatch):
+def test_env_window_overrides_strategy(coord_port, monkeypatch):
     """AUTODIST_LOCAL_STEPS > 0 overrides the strategy's window (the
     operator's weak-link dial, forwarded to every worker so the
     round-scoped gates agree)."""
     monkeypatch.setenv('AUTODIST_LOCAL_STEPS', '2')
-    with _loose_session(coord, h=1) as (sess, train_op, x, W0, feed):
+    with _loose_session(coord_port, h=1) as (sess, train_op, x, W0, feed):
         assert sess._local_steps == 2
         for _ in range(4):
             sess.run(train_op, {x: feed})

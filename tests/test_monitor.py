@@ -8,7 +8,6 @@ incremental batch collection cursor, and the telemetry-namespace
 purge across back-to-back sessions on one service."""
 import json
 import os
-import socket
 import subprocess
 import sys
 import threading
@@ -23,30 +22,6 @@ from autodist_tpu.analysis import conformance  # noqa: E402
 from autodist_tpu.telemetry.monitor import (CohortMonitor,  # noqa: E402
                                             format_snapshot,
                                             phase_medians, phase_splits)
-
-
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture()
-def service():
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield port
-    try:
-        CoordClient(('127.0.0.1', port)).shutdown()
-        if proc is not None:
-            proc.wait(timeout=5)
-    except OSError:
-        if proc is not None:
-            proc.kill()
 
 
 @pytest.fixture()

@@ -10,23 +10,14 @@ import threading
 import numpy as np
 import pytest
 
+from conftest import coord_service
+
 from autodist_tpu.data import DataLoader, write_records
 
 HAVE_GXX = shutil.which('g++') is not None
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 pytestmark = pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-
-
-@pytest.fixture(scope='module')
-def coord():
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = 14851
-    proc = ensure_service(port=port)
-    yield lambda **kw: CoordClient(('127.0.0.1', port), **kw)
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
 
 
 def test_coord_kv_and_counters(coord):
@@ -470,15 +461,9 @@ def test_coord_service_auth_handshake(monkeypatch, tmp_path):
     token-file transport (how the ssh coordinator ships the secret)
     resolves too."""
     import socket as _socket
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    s0 = _socket.socket()
-    s0.bind(('127.0.0.1', 0))
-    port = s0.getsockname()[1]
-    s0.close()
+    from autodist_tpu.runtime.coord_client import CoordClient
     monkeypatch.setenv('AUTODIST_COORD_TOKEN', 'sekrit-token')
-    proc = ensure_service(port=port)
-    try:
+    with coord_service() as port:
         c = CoordClient(('127.0.0.1', port), timeout=5)
         c.set('authed', 'yes')
         assert c.get('authed') == 'yes'
@@ -508,14 +493,8 @@ def test_coord_service_auth_handshake(monkeypatch, tmp_path):
         s.close()
         # the authed connection still works
         assert c.get('authed') == 'yes'
-    finally:
+        # the helper's shutdown needs the secret too
         monkeypatch.setenv('AUTODIST_COORD_TOKEN', 'sekrit-token')
-        try:
-            CoordClient(('127.0.0.1', port), timeout=5).shutdown()
-        except OSError:
-            pass
-        if proc is not None:
-            proc.wait(timeout=5)
 
 
 @pytest.mark.parametrize('builder_name,rows,shard_sizes', [
@@ -709,3 +688,79 @@ def test_prefetch_defers_source_error_until_drained():
         for b in prefetch_to_device(source(), lambda x: x * 10, size=3):
             got.append(b)
     assert got == [10, 20]
+
+
+# -- the build and the service fixture themselves ---------------------------
+
+_BUILD_AND_RUN = '''
+import subprocess, sys
+from autodist_tpu import native_build
+native_build.NATIVE_CACHE_DIR = sys.argv[1]
+print('ready', flush=True)
+sys.stdin.read()                      # the starting gun
+out = native_build.build('coord_service.cc')
+# 192.0.2.1 (TEST-NET-1) is no address of this host: the service execs,
+# cannot bind and exits 1
+rc = subprocess.run([out, '0', '192.0.2.1'], stderr=subprocess.DEVNULL,
+                    timeout=60).returncode
+sys.exit(0 if rc == 1 else 10 + rc)
+'''
+
+
+def test_build_is_whole_under_concurrent_callers(tmp_path):
+    """Several processes that reach ``build`` together on a cold cache
+    each get an artifact they can execute at once: the file at the
+    final path is never one a linker still holds open (ETXTBSY)."""
+    import contextlib
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONPATH=REPO)
+    with contextlib.ExitStack() as stack:
+        procs = [stack.enter_context(subprocess.Popen(
+            [sys.executable, '-c', _BUILD_AND_RUN, str(tmp_path)],
+            env=env, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE, text=True)) for _ in range(6)]
+        stack.callback(lambda: [p.kill() for p in procs])   # before the waits
+        for p in procs:     # all are past their imports before any builds
+            assert p.stdout.readline() == 'ready\n', p.stderr.read()
+        for p in procs:
+            p.stdin.close()
+        codes = [p.wait(timeout=300) for p in procs]
+        assert codes == [0] * 6, [p.stderr.read() for p in procs]
+    (digest,) = os.listdir(tmp_path)
+    assert os.listdir(tmp_path / digest) == ['coord_service']
+
+
+def test_failed_compile_leaves_no_artifact(tmp_path, monkeypatch):
+    """A compile that fails leaves nothing at the artifact's path (an
+    ``exists`` check would otherwise hand out the wreck for ever), and
+    the next call builds."""
+    import subprocess
+
+    from autodist_tpu import native_build
+    monkeypatch.setattr(native_build, 'NATIVE_CACHE_DIR', str(tmp_path))
+    with pytest.raises(subprocess.CalledProcessError):
+        native_build.build('dataloader.cc', shared=True,
+                           extra_flags=('-l:no-such-library',))
+    assert [os.listdir(tmp_path / d) for d in os.listdir(tmp_path)] == [[]]
+    out = native_build.build('dataloader.cc', shared=True)
+    assert os.path.getsize(out) > 0
+
+
+def test_service_fixture_survives_a_taken_port():
+    """Handed a port that something else holds, the shared helper ends
+    with a live service on another one."""
+    import socket
+
+    from autodist_tpu.runtime.coord_client import CoordClient
+    with socket.socket() as held:
+        held.bind(('127.0.0.1', 0))
+        held.listen(1)
+        taken = held.getsockname()[1]
+        with coord_service(port=taken) as port:
+            assert port != taken
+            c = CoordClient(('127.0.0.1', port))
+            c.set('k', 'v')
+            assert c.get('k') == 'v'
+    with pytest.raises(OSError):        # and it is shut down on exit
+        CoordClient(('127.0.0.1', port), timeout=0.5).ping()

@@ -150,24 +150,6 @@ def test_pull_wire_downgrades_i8_to_f32():
 
 # -- golden frames through the native service ----------------------------
 
-@pytest.fixture(scope='module')
-def coord():
-    if not HAVE_GXX:
-        pytest.skip('g++ unavailable')
-    import socket
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    proc = ensure_service(port=port)
-    yield lambda **kw: CoordClient(('127.0.0.1', port), **kw)
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
-
-
 def _raw_bget(client, key, wire):
     """BGET at an explicit wire dtype, bypassing the client's
     pull-direction downgrade — exercises the service's encode_wire."""
@@ -333,21 +315,15 @@ def _loose_sgd_run(port, wire, steps=5, dim=48, probe=None):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_loose_mode_i8_bounded_divergence_and_exact_residual(coord):
+def test_loose_mode_i8_bounded_divergence_and_exact_residual(service):
     """End-to-end loose mode on the i8 push wire: (a) the PS state
     after the first push equals W0 + the delta's exact block
     round-trip, and the session's carried residual is exactly the mass
     the wire dropped; (b) after several steps the divergence vs the
     f32 wire stays bounded (error feedback), while pushes moved ~4x
     fewer bytes."""
-    import socket
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    proc = ensure_service(port=port)
+    from autodist_tpu.runtime.coord_client import CoordClient
+    port = service
     carried = {}
 
     def probe(sess, ns, W0):
@@ -365,17 +341,8 @@ def test_loose_mode_i8_bounded_divergence_and_exact_residual(coord):
         c.close()
         carried['ok'] = True
 
-    try:
-        w8, s8 = _loose_sgd_run(port, 'i8', probe=probe)
-        w32, s32 = _loose_sgd_run(port, 'f32')
-    finally:
-        try:
-            CoordClient(('127.0.0.1', port)).shutdown()
-            if proc is not None:
-                proc.wait(timeout=5)
-        except Exception:   # noqa: BLE001 - teardown only
-            if proc is not None:
-                proc.kill()
+    w8, s8 = _loose_sgd_run(port, 'i8', probe=probe)
+    w32, s32 = _loose_sgd_run(port, 'f32')
     assert carried.get('ok')
     assert float(np.abs(w32 - w8).max()) < 0.01
     assert s32['push_bytes'] / s8['push_bytes'] >= 3.0

@@ -4,7 +4,6 @@ property across every op kind, optimizer-slot co-movement, and the
 executed elastic re-plan (AUTODIST_EXECUTE_REPLAN) migrating a live
 loose-mode session with exact state."""
 import shutil
-import socket
 import threading
 import time
 
@@ -162,16 +161,8 @@ def test_mismatched_meshes_refused():
 HAVE_GXX = shutil.which('g++') is not None
 
 
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_executed_replan_migrates_live_session(monkeypatch):
+def test_executed_replan_migrates_live_session(monkeypatch, service):
     """AUTODIST_EXECUTE_REPLAN: a live 2->3 worker re-plan runs the
     epoch-swap handshake (stage -> peer ack quorum -> armed boundary)
     and migrates the chief's session through the reshard path at the
@@ -181,14 +172,12 @@ def test_executed_replan_migrates_live_session(monkeypatch):
     never migrated but trained the same number of steps (values are
     moved, never recomputed)."""
     import autodist_tpu as ad
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
+    from autodist_tpu.runtime.coord_client import CoordClient
     from autodist_tpu.runtime.session import admit_worker
     from autodist_tpu.utils.loose_harness import (ack_staged_swaps,
                                                   single_process_loose_env)
 
-    port = _free_port()
-    proc = ensure_service(port=port)
+    port = service
     monkeypatch.setenv('AUTODIST_PEER_FAILURE_POLICY', 'exclude')
     monkeypatch.setenv('AUTODIST_HEARTBEAT_TIMEOUT', '5.0')
 
@@ -297,18 +286,8 @@ def test_executed_replan_migrates_live_session(monkeypatch):
                     t.join(timeout=25.0)
         return np.asarray(w), stats, trained
 
-    try:
-        w_mig, stats_mig, n_mig = run_once(True)
-        w_plain, stats_plain, n_plain = run_once(False,
-                                                 train_total=n_mig)
-    finally:
-        try:
-            CoordClient(('127.0.0.1', port)).shutdown()
-            if proc is not None:
-                proc.wait(timeout=5)
-        except Exception:   # noqa: BLE001 - results already in hand
-            if proc is not None:
-                proc.kill()
+    w_mig, stats_mig, n_mig = run_once(True)
+    w_plain, stats_plain, n_plain = run_once(False, train_total=n_mig)
 
     plain_replans = stats_plain.get('replans', [])
     mig_replans = stats_mig.get('replans', [])

@@ -7,8 +7,7 @@ traced emission and the static schedule, the per-entry drift join,
 the entry-labeled calibration fit the old unlabeled classification
 gets wrong (pinned), the tracker's MFU-regression flight events, the
 monitor's compute/memory-bound verdict refinement, the
-silent-empty-timeline mismatch logging, the roofline CLI smoke, and
-bench_compare's higher-direction failure-sentinel rule.
+silent-empty-timeline mismatch logging, and the roofline CLI smoke.
 """
 import json
 import math
@@ -537,7 +536,7 @@ def test_calibrate_from_trace_threads_expected_count(tmp_path,
     assert not params.calibrated
 
 
-# -- CLI + bench_compare ---------------------------------------------------
+# -- CLI -------------------------------------------------------------------
 
 def test_roofline_cli_json_smoke(tmp_path):
     block = {
@@ -569,34 +568,6 @@ def test_roofline_cli_json_smoke(tmp_path):
     assert out.returncode == 0, out.stderr[-2000:]
     assert 'MFU: null' in out.stdout
     assert 'round-trip' in out.stdout
-
-
-def test_bench_compare_higher_direction_failure_sentinel(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, 'tools'))
-    try:
-        import bench_compare
-    finally:
-        sys.path.pop(0)
-
-    def rec(mfu):
-        return {'metric': 'm', 'value': 1.0,
-                'extra': {'platform': 'cpu',
-                          'roofline': {'mfu': mfu}}}
-
-    # new-side sentinel = regression even though -1 < old numerically
-    report = bench_compare.compare(rec(0.5), rec(-1.0))
-    rows = {r['metric']: r for r in report['rows']}
-    row = rows['extra.roofline.mfu']
-    assert row['status'] == 'regression'
-    assert 'sentinel' in row['note']
-    # old-side sentinel: any measured value is an improvement
-    report = bench_compare.compare(rec(-1.0), rec(0.4))
-    row = {r['metric']: r for r in report['rows']}['extra.roofline.mfu']
-    assert row['status'] == 'ok'
-    # json-null (CPU fallback) skips rather than gates
-    report = bench_compare.compare(rec(None), rec(None))
-    row = {r['metric']: r for r in report['rows']}['extra.roofline.mfu']
-    assert row['status'] == 'skipped'
 
 
 # -- session integration ---------------------------------------------------

@@ -9,7 +9,6 @@ is emulated with raw clients driving exactly the session's publish
 path — seqlock round open, pushes, publish_step, round close.
 """
 import shutil
-import socket
 import threading
 
 import numpy as np
@@ -188,28 +187,6 @@ def test_health_report_serving_section():
 
 # -- live coord service ----------------------------------------------------
 
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture(scope='module')
-def coord():
-    if not HAVE_GXX:
-        pytest.skip('g++ unavailable')
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield port
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
-
-
 class _Trainer:
     """Raw-client emulation of the loose session's publish path: the
     seqlock round (``Session._snap_round_open/_close``) around pushes
@@ -264,15 +241,15 @@ class _Trainer:
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_read_only_client_blocks_every_mutating_verb(coord):
+def test_read_only_client_blocks_every_mutating_verb(coord_port):
     """Satellite 1: each mutating command raises ReadOnlyViolation
     LOCALLY (no wire round trip to find out), delta-0 INCR (the
     plane's counter read, fence-exempt in the service for the same
     reason) and all reads pass."""
     from autodist_tpu.runtime.coord_client import (CoordClient,
                                                    ReadOnlyViolation)
-    w = CoordClient(('127.0.0.1', coord))
-    ro = CoordClient(('127.0.0.1', coord), read_only=True)
+    w = CoordClient(('127.0.0.1', coord_port))
+    ro = CoordClient(('127.0.0.1', coord_port), read_only=True)
     try:
         w.vset('rotest/var/v', np.arange(6, dtype=np.float32))
         w.set('rotest/k', 'x')
@@ -308,7 +285,7 @@ def test_read_only_client_blocks_every_mutating_verb(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_admit_reader_is_invisible_to_membership(coord):
+def test_admit_reader_is_invisible_to_membership(coord_port):
     """Readers claim serve/world ordinals and heartbeat on the serve
     prefix — live_members_on_plane (the quorum/exclusion definition)
     must not move by one bit."""
@@ -316,8 +293,8 @@ def test_admit_reader_is_invisible_to_membership(coord):
     from autodist_tpu.runtime.session import (admit_reader,
                                               live_members_on_plane)
     ns = 'adminv'
-    tr = _Trainer(coord, ns)
-    ctl = CoordClient(('127.0.0.1', coord))
+    tr = _Trainer(coord_port, ns)
+    ctl = CoordClient(('127.0.0.1', coord_port))
     try:
         tr.init_plane({'w': np.ones(3, np.float32)})
         before = live_members_on_plane(tr.c, ns)
@@ -341,19 +318,19 @@ def _mk_replica(port, ns, **kw):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_snapshot_pull_is_epoch_consistent_and_bit_exact(coord):
+def test_snapshot_pull_is_epoch_consistent_and_bit_exact(coord_port):
     """The seqlock protocol end to end: the replica pulls the
     published state bit-exactly, refuses to pull mid-round (odd
     parity), and never regresses to an older floor."""
     ns = 'snapbit'
-    tr = _Trainer(coord, ns)
+    tr = _Trainer(coord_port, ns)
     w1 = np.random.RandomState(0).randn(8, 3).astype(np.float32)
     w2 = np.random.RandomState(1).randn(5).astype(np.float32)
     replica = None
     try:
         tr.init_plane({'a': w1, 'b': w2})
         tr.round(dense={'a': w1, 'b': w2})          # publish step 1
-        replica = _mk_replica(coord, ns,
+        replica = _mk_replica(coord_port, ns,
                               dense_vars={'a': w1.shape, 'b': w2.shape},
                               poll_s=0.01, snapshot_retries=3)
         assert replica.refresh() is True
@@ -393,19 +370,19 @@ def test_snapshot_pull_is_epoch_consistent_and_bit_exact(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_crashed_writer_grows_staleness_never_blocks(coord):
+def test_crashed_writer_grows_staleness_never_blocks(coord_port):
     """A writer dying mid-round leaves its parity odd: the replica
     keeps serving the previous snapshot and GRADES itself against the
     staleness bound (the documented trade — a reader never blocks
     training, training's failure handling bounds reader staleness)."""
     ns = 'snapstale'
-    tr = _Trainer(coord, ns)
+    tr = _Trainer(coord_port, ns)
     w = np.ones(4, np.float32)
     replica = None
     try:
         tr.init_plane({'w': w})
         tr.round(dense={'w': w})                     # step 1
-        replica = _mk_replica(coord, ns, dense_vars={'w': w.shape},
+        replica = _mk_replica(coord_port, ns, dense_vars={'w': w.shape},
                               snapshot_retries=2, staleness_bound=0)
         assert replica.refresh() is True
         # the writer opens round 2, publishes step 2, then "crashes"
@@ -429,19 +406,19 @@ def test_crashed_writer_grows_staleness_never_blocks(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_row_lookup_bit_exact_after_sparse_push_and_bump(coord):
+def test_row_lookup_bit_exact_after_sparse_push_and_bump(coord_port):
     """Satellite 3's live half: hot rows served from cache are
     bit-exact against a direct vmgetrows after a concurrent sparse
     push, because the dense snapshot bump flushes the cache."""
     ns = 'rowbit'
-    tr = _Trainer(coord, ns)
+    tr = _Trainer(coord_port, ns)
     table = np.arange(32, dtype=np.float32).reshape(16, 2)
     dense = np.float32([1.0])
     replica = None
     try:
         tr.init_plane({'d': dense}, sparse={'emb': table})
         tr.round()                                   # publish step 1
-        replica = _mk_replica(coord, ns, dense_vars={'d': dense.shape},
+        replica = _mk_replica(coord_port, ns, dense_vars={'d': dense.shape},
                               sparse_vars={'emb': table.shape},
                               poll_s=0.01)
         replica.refresh()
@@ -477,7 +454,7 @@ def test_row_lookup_bit_exact_after_sparse_push_and_bump(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_fleet_serves_while_training_and_reader_death_is_free(coord):
+def test_fleet_serves_while_training_and_reader_death_is_free(coord_port):
     """The acceptance shape in miniature: a trainer keeps publishing
     while a 2-replica fleet refreshes and answers; killing one
     replica mid-service neither stalls the trainer nor dents
@@ -486,13 +463,13 @@ def test_fleet_serves_while_training_and_reader_death_is_free(coord):
     from autodist_tpu.serving import ServingFleet
     from autodist_tpu.utils import profiling
     ns = 'fleetns'
-    tr = _Trainer(coord, ns)
+    tr = _Trainer(coord_port, ns)
     table = np.arange(24, dtype=np.float32).reshape(12, 2)
     w = np.zeros(6, np.float32)
     try:
         tr.init_plane({'w': w}, sparse={'emb': table})
         tr.round(dense={'w': w + 1})
-        with ServingFleet(ns, address=('127.0.0.1', coord),
+        with ServingFleet(ns, address=('127.0.0.1', coord_port),
                           dense_vars={'w': w.shape},
                           sparse_vars={'emb': table.shape},
                           poll_s=0.01) as fleet:
@@ -544,16 +521,16 @@ def test_fleet_serves_while_training_and_reader_death_is_free(coord):
 
 
 @pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-def test_fleet_scale_up_via_autoscale_contract(coord):
+def test_fleet_scale_up_via_autoscale_contract(coord_port):
     """ServingFleet.scale_up honors the AutoscaleController contract:
     returns the list actually started, and live_replicas resyncs."""
     from autodist_tpu.serving import ServingFleet
     ns = 'fleetgrow'
-    tr = _Trainer(coord, ns)
+    tr = _Trainer(coord_port, ns)
     try:
         tr.init_plane({'w': np.zeros(2, np.float32)})
         tr.round()
-        with ServingFleet(ns, address=('127.0.0.1', coord),
+        with ServingFleet(ns, address=('127.0.0.1', coord_port),
                           dense_vars={'w': (2,)}, poll_s=0.01) as fleet:
             started = fleet.scale_up(2)
             assert len(started) == 2

@@ -8,7 +8,6 @@ async-PS suite uses.
 """
 import os
 import shutil
-import socket
 import subprocess
 import sys
 import time
@@ -22,29 +21,6 @@ HAVE_GXX = shutil.which('g++') is not None
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 needs_gxx = pytest.mark.skipif(not HAVE_GXX, reason='g++ unavailable')
-
-
-@pytest.fixture(scope='module')
-def coord_port():
-    if not HAVE_GXX:
-        pytest.skip('g++ unavailable')
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    proc = ensure_service(port=port)
-    yield port
-    CoordClient(('127.0.0.1', port)).shutdown()
-    if proc is not None:
-        proc.wait(timeout=5)
-
-
-@pytest.fixture()
-def coord(coord_port):
-    from autodist_tpu.runtime.coord_client import CoordClient
-    return lambda **kw: CoordClient(('127.0.0.1', coord_port), **kw)
 
 
 # -- protocol: BSADD / BGETROWS ------------------------------------------

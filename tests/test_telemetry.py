@@ -9,7 +9,6 @@ the coord service is g++-gated like the other native-plane suites.
 import json
 import os
 import shutil
-import socket
 import subprocess
 import sys
 import threading
@@ -36,30 +35,6 @@ def telem(monkeypatch, tmp_path):
     yield telemetry
     telemetry.reset()
     telemetry.reset_recorder()
-
-
-def _free_port():
-    s = socket.socket()
-    s.bind(('127.0.0.1', 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
-
-
-@pytest.fixture()
-def service():
-    from autodist_tpu.runtime.coord_client import (CoordClient,
-                                                   ensure_service)
-    port = _free_port()
-    proc = ensure_service(port=port)
-    yield port
-    try:
-        CoordClient(('127.0.0.1', port)).shutdown()
-        if proc is not None:
-            proc.wait(timeout=5)
-    except OSError:
-        if proc is not None:
-            proc.kill()
 
 
 # -- registry --------------------------------------------------------------

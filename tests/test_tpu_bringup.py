@@ -21,6 +21,8 @@ import textwrap
 
 import pytest
 
+from conftest import free_port
+
 from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.runtime import coordinator
 
@@ -445,7 +447,7 @@ def test_launcher_parent_initializes_no_backend(tmp_path):
         sys.exit(rc)
     ''') % (str(spec), str(script))
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS='cpu',
-               AUTODIST_COORD_SERVICE_ADDR='127.0.0.1:%d' % _free_port())
+               AUTODIST_COORD_SERVICE_ADDR='127.0.0.1:%d' % free_port())
     ok = subprocess.run([sys.executable, '-c', driver], env=env,
                         capture_output=True, text=True, timeout=120)
     assert ok.returncode == 0, ok.stderr[-2000:]
@@ -456,10 +458,3 @@ def test_launcher_parent_initializes_no_backend(tmp_path):
     assert refused.returncode == 2, refused.stderr[-2000:]
     assert 'declares tpus: no list' in refused.stderr
     assert 'child ran' not in refused.stdout
-
-
-def _free_port():
-    import socket
-    with socket.socket() as s:
-        s.bind(('127.0.0.1', 0))
-        return s.getsockname()[1]

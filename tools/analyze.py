@@ -47,10 +47,8 @@ protocol (analysis/conformance.py), so chaos runs can assert the live
 system conforms.
 
 Fast, no devices, no processes: wired into tier-1 via
-tests/test_analysis.py. CI/bench records attach the --json report
-(``bench.py`` stores it under the stable ``analysis`` BENCH key, and
-``tools/bench_compare.py`` flags analyzer-cost / state-space blowup
-regressions across records). The report carries ``schema_version``
+tests/test_analysis.py, the --json report's only reader today. The
+report carries ``schema_version``
 (bumped on shape changes), per-pass wall time, and — for the model
 checkers — states-explored counts.
 """
@@ -68,9 +66,9 @@ sys.path.insert(0, REPO)
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
 
 #: Version of the --json report shape. Bump when a field is renamed,
-#: removed, or changes meaning — bench_compare keys off dotted paths
-#: into this report, and a silent shape change would read as metrics
-#: vanishing rather than as an incompatibility.
+#: removed, or changes meaning — a reader keyed on dotted paths into
+#: this report would take a silent shape change for metrics vanishing
+#: rather than for an incompatibility.
 SCHEMA_VERSION = 2
 
 ANALYZER_NAMES = ('protocol', 'data-plane', 'epoch-swap',

@@ -1,10 +1,12 @@
 """Roofline observatory CLI — render MFU/regime, HBM drift and the
 per-entry collective drift table from records or traces.
 
-    # a BENCH record (driver wrapper or bench.py's raw line)
-    python tools/roofline.py bench_record.json
+    # a record carrying an ``extra.roofline`` block (nothing in the
+    # repository writes one since the host-side benchmark was retired:
+    # ROADMAP.md queue 3 item 7)
+    python tools/roofline.py record.json
 
-    # a raw roofline block (bench extra.roofline, or your own)
+    # a raw roofline block (``RooflineTracker.snapshot()``, or your own)
     python tools/roofline.py roofline.json --json
 
     # offline join: a profiler trace dir + the static schedule it ran
@@ -13,11 +15,11 @@ per-entry collective drift table from records or traces.
         --replicas 8
 
 Inputs are sniffed per path: a JSON file carrying a ``roofline`` block
-(BENCH record, wrapped or raw) or BEING one (a dict with ``drift`` /
+(wrapped under ``parsed`` or raw) or BEING one (a dict with ``drift`` /
 ``mfu`` keys) renders directly; a directory is treated as a captured
 profiler trace whose collective timeline is joined against
 ``--schedule`` through the SAME ``telemetry.roofline.drift_table``
-join the bench uses. ``--json`` prints the machine-readable summary
+join the session's tracker uses. ``--json`` prints the machine-readable summary
 (the tier-1 subprocess smoke's contract).
 """
 import argparse
@@ -117,7 +119,7 @@ def main(argv=None):
         description='render roofline records / join a trace against '
                     'its static collective schedule')
     ap.add_argument('paths', nargs='+',
-                    help='BENCH records, roofline blocks, or a '
+                    help='records, roofline blocks, or a '
                          'profiler trace dir (with --schedule)')
     ap.add_argument('--schedule',
                     help='static_collective_schedule entries (JSON '
