@@ -8,14 +8,38 @@ each (Q-block × KV-block) tile runs on the MXU, softmax statistics stay
 in f32, and the backward pass recomputes P from the saved logsumexp
 instead of storing it.
 
-Layout: q/k/v are [batch, heads, seq, head_dim]. Three ``pallas_call``s
-(``flash_fwd``, ``flash_dq``, ``flash_dkv``; the benchmark reads them by
-these names) share one grid shape, (batch, heads / G, outer blocks,
-inner blocks): a grid step holds G heads of one tile and walks them in
-an unrolled loop, so the fixed cost of a step is paid once for G tiles
-and the scheduler can fill one head's softmax with the next one's
-matmuls. The inner dimension is sequential ("arbitrary") and carries
-the VMEM accumulators; the rest are parallel.
+Layout: q, k, v, ``do`` are read from and ``o``, dq, dk, dv written to
+``[batch, seq, heads * head_dim]``, the layout the qkv projection writes
+and the output projection reads: lane-dense, unpadded, and nothing is
+transposed or copied between a projection and a kernel. q, k and v may
+be three arrays or the three thirds of the projection's one
+``[b, s, 3 * h * d]`` (an index map each over the same array), and dq,
+dk, dv then land in one such array: ``flash_dq`` writes its first
+third, ``flash_dkv`` takes that array in and writes dk into the second
+in place (``input_output_aliases``), and dv goes over the last.
+Three ``pallas_call``s (``flash_fwd``, ``flash_dq``, ``flash_dkv``; the
+benchmark reads them by these names) share one grid shape, (batch,
+heads / G, outer blocks, inner blocks): a grid step holds the ``G * d``
+lanes of G heads of one tile and walks them in an unrolled loop, so the
+fixed cost of a step is paid once for G tiles and the scheduler can
+fill one head's softmax with the next one's matmuls. The inner
+dimension is sequential ("arbitrary") and carries the VMEM
+accumulators; the rest are parallel.
+
+Heads in the lanes (:func:`_lane_block`): a step's lanes are whole LANE
+BLOCKS of ``max(128, head_dim)`` lanes: one head of 128 lanes or more,
+two heads at head_dim 64, four at 32. A head's tile is taken out of its
+block with no lane slice (a [., 64] slice at lane 64 is a lane rotate
+for every operand and a masked store for every result: 15-25% slower
+on a v5e, PERF.md §6, PR 29). Instead the OTHER heads' lanes of one
+operand of each contraction over the lanes are zeroed with a ``where``
+on a lane iota (q for ``q k^T``, ``do`` for ``do v^T``; k and v for the
+transposed tiles of ``flash_dkv``), and the contraction runs over all
+128 lanes: the same passes of a 128-deep MXU as over 64. A matmul
+against the block's lanes (``p v``, ``ds k``, ``p^T do``, ``ds^T q``)
+yields [., 128] of which the head's own 64 columns mean something; the
+heads' results are ``where``d together and stored once, lane-dense.
+The behaviour follows ``head_dim``, which the code sees in its input.
 
 What a step computes is chosen by Python ``if``s on the static plan
 (:func:`_plan`: block sizes and G per kernel, from ``seq``, ``causal``
@@ -41,6 +65,12 @@ and the local head count), one body per kernel:
   ``k q^T`` ([bk, bq]), where they broadcast down the sublanes and
   ``dv = p^T do``, ``dk = ds^T q`` are plain matmuls; ``flash_fwd`` and
   ``flash_dq`` turn the row to a column once a head and step.
+* ``delta = rowsum(dO * O)`` of each head is made by ``flash_dq``, from
+  the blocks of ``do`` and ``o`` it holds anyway (``o`` is one operand
+  more), and handed to ``flash_dkv`` as ``[b, h, 1, s]``. With ``do``
+  and ``o`` in the kernels' layout XLA has no cheap way to it: a
+  reduce over 64-lane groups whose result has ``s`` along the lanes
+  cost it an f32 copy of the whole product (PERF.md §6, PR 29).
 * A **band call** (``window = (left, right)``: query i sees keys
   i - left .. i + right; ModernBERT's window layers) is planned from
   the band and not from the sequence: blocks as wide as the band
@@ -58,11 +88,13 @@ and the local head count), one body per kernel:
   tile, which is exact in any binary float format; any other scale
   stays on the tile.
 
-Where the time goes at head_dim 64 on a v5e (PERF.md §6, PR 25): QK^T
-contracts over 64 and PV yields 64 columns, so every matmul fills half
-of the 128 x 128 MXU, and q/k/v/o are lane-padded to 128 in HBM. The
-backward pair runs within 5% of that half-filled-MXU bound at seq 512,
-the forward within 20% of its (padded) HBM traffic.
+Where the time goes at head_dim 64 on a v5e (PERF.md §6, PR 25 and 29):
+QK^T contracts over one head's 64 lanes and PV yields one head's 64
+columns whatever the layout (``p`` differs by head), so every matmul
+fills half of the 128 x 128 MXU. The backward pair runs within 5% of
+that half-filled-MXU bound at seq 512; the forward is bound by neither
+the MXU nor its DMAs (halving its bytes did not move it) but by the
+softmax's elementwise work.
 
 On the CPU backend the same kernels run in Pallas interpret mode, so the
 CPU test mesh exercises the identical code path (tests/test_flash_attention.py);
@@ -144,11 +176,27 @@ _MAX_HEADS_PER_STEP = 8
 _VMEM_LIMIT_BYTES = 64 << 20
 
 
-def _heads_per_step(heads, bq, bk):
-    """Largest divisor of the (local) head count, at most
-    ``_MAX_HEADS_PER_STEP``, whose tiles stay inside the step budget."""
+def _lane_block(heads, head_dim):
+    """Lanes of a block of the ``[b, s, heads * head_dim]`` layout: one
+    head of 128 lanes or more, else the heads that fill 128 lanes (two
+    at head_dim 64, four at 32). Heads that do not tile the lanes that
+    way (``supports``: a model of under 128 lanes in all, an odd head
+    count at head_dim 64) are one block, the whole minor dimension."""
+    if head_dim % _LANES == 0:
+        return head_dim
+    if _LANES % head_dim == 0 and heads * head_dim % _LANES == 0:
+        return _LANES
+    return heads * head_dim
+
+
+def _heads_per_step(heads, bq, bk, per_block=1):
+    """Heads a grid step holds: whole lane blocks (``per_block`` heads
+    each) that divide the (local) head count, as many as stay inside the
+    step budget and ``_MAX_HEADS_PER_STEP``, and one block at least."""
     target = max(1, min(_MAX_HEADS_PER_STEP, _STEP_TILE_ELEMS // (bq * bk)))
-    return max(g for g in range(1, target + 1) if heads % g == 0)
+    blocks = heads // per_block
+    return per_block * max(c for c in range(1, blocks + 1) if blocks % c == 0
+                           and c * per_block <= max(target, per_block))
 
 
 Blocks = collections.namedtuple('Blocks', 'block_q block_k heads_per_step')
@@ -255,11 +303,15 @@ def check_window(window, causal=False):
 
 
 def supports(shape, block=128, window=None):
-    """Whether flash_attention can run for [B, H, S, D] (S divisible
-    into >=8-row blocks), with or without a ``window``."""
+    """Whether flash_attention can run for [B, H, S, D], with or without
+    a ``window``: S divisible into >=8-row blocks, and heads that tile
+    the lanes of ``[B, S, H * D]`` (:func:`_lane_block`: H * D a
+    multiple of 128 in heads of 128 / n or 128 n lanes, or all of it no
+    more than one lane block)."""
     check_window(window)
-    s = shape[2]
-    return _pick_block(s, block) is not None
+    _, h, s, d = shape
+    return _pick_block(s, block) is not None and (
+        _lane_block(h, d) == max(_LANES, d) or h * d <= _LANES)
 
 
 # Crossover with XLA's fused attention, on the model path
@@ -418,6 +470,45 @@ def _scores(a, b, sm_scale, fold, mask):
 
 
 # ---------------------------------------------------------------------------
+# heads in the lanes of a block
+# ---------------------------------------------------------------------------
+
+def _head_lanes(lanes, d, i):
+    """Which lanes of a ``lanes``-wide block are those of its ``i``-th
+    head: a [1, lanes] mask, or None where the block is one head."""
+    if lanes == d:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return jnp.logical_and(lane >= i * d, lane < (i + 1) * d)
+
+
+def _lane_blocks(g, d, lanes):
+    """The lane blocks of a grid step that holds ``g`` heads: for each
+    its columns of the step's ``g * d`` and its heads, as ``(head of
+    the step, mask of its lanes)``."""
+    per = lanes // d
+    return [(slice(c * lanes, (c + 1) * lanes),
+             [(c * per + i, _head_lanes(lanes, d, i)) for i in range(per)])
+            for c in range(g // per)]
+
+
+def _only(x, keep):
+    """``x`` with the lanes of the block's other heads zeroed: contracted
+    over all the lanes of the block it is contracted over this head's
+    (in as many passes of a 128-deep MXU as over 64 lanes alone)."""
+    return x if keep is None else jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _place(rest, x, keep):
+    """``x`` on this head's lanes and ``rest`` (what the block's heads
+    so far left) on the others. A matmul against all the lanes of a
+    block yields every head's columns and this head's are the ones that
+    mean something; once each head of the block has placed its own,
+    the block is whole and stored lane-dense."""
+    return x if keep is None or rest is None else jnp.where(keep, x, rest)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -432,28 +523,33 @@ def _inner_block(outer, j, size, inner_size, window, transposed=False):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                sm_scale, fold, causal, bq, bk, nq, nk, g, window, n_inner):
+                sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
+                n_inner):
     qi, j = pl.program_id(2), pl.program_id(3)
     ki = _inner_block(qi, j, bq, bk, window)
+    blocks = _lane_blocks(g, d, lanes)
 
-    def query(h):
-        q = q_ref[0, h]                                       # [bq, D]
-        return q * sm_scale if fold else q
+    def query(cols, keep):
+        q = q_ref[0, :, cols]                                 # [bq, lanes]
+        return _only(q * sm_scale if fold else q, keep)
 
     def rows(parts):
         # plain softmax over the live keys of the row, by ranges
-        for h in range(g):
-            q = query(h)
-            ss = [_scores(q, k_ref[0, h, lo:hi], sm_scale, fold, mask)
-                  for lo, hi, mask in parts]
-            m = functools.reduce(jnp.maximum, [
-                jnp.max(s, axis=1, keepdims=True) for s in ss])
-            ps = [jnp.exp(s - m) for s in ss]
-            l = sum(jnp.sum(p, axis=1, keepdims=True) for p in ps)
-            acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, h, lo:hi], _NN)
-                      for p, (lo, hi, _) in zip(ps, parts))
-            o_ref[0, h] = (acc / l).astype(o_ref.dtype)
-            lse_ref[0, h] = _to_row(m + jnp.log(l))
+        for cols, heads in blocks:
+            o = None
+            for h, keep in heads:
+                q = query(cols, keep)
+                ss = [_scores(q, k_ref[0, lo:hi, cols], sm_scale, fold, mask)
+                      for lo, hi, mask in parts]
+                m = functools.reduce(jnp.maximum, [
+                    jnp.max(s, axis=1, keepdims=True) for s in ss])
+                ps = [jnp.exp(s - m) for s in ss]
+                l = sum(jnp.sum(p, axis=1, keepdims=True) for p in ps)
+                acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, lo:hi, cols],
+                               _NN) for p, (lo, hi, _) in zip(ps, parts))
+                o = _place(o, acc / l, keep)
+                lse_ref[0, h] = _to_row(m + jnp.log(l))
+            o_ref[0, :, cols] = o.astype(o_ref.dtype)
 
     if nk == 1:
         _for_the_live_row(rows, qi, nq, bq, bk, causal, window=window)
@@ -474,27 +570,36 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         # until a later tile: its max stays NEG_INF, p is exp(0) for
         # every key and l and acc hold finite rubbish, which the first
         # real max wipes out (alpha = exp(NEG_INF - m) = 0).
-        for h in range(g):
-            v = v_ref[0, h]
-            s = _scores(query(h), k_ref[0, h], sm_scale, fold, mask)
-            m_prev = m_scr[h]                                 # [bq, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)                            # [bq, bk]
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[h] = acc_scr[h] * alpha + _dot(p.astype(v.dtype), v,
-                                                   _NN)
-            m_scr[h] = m_new
+        for c, (cols, heads) in enumerate(blocks):
+            v = v_ref[0, :, cols]
+            alphas = pv = None
+            for h, keep in heads:
+                s = _scores(query(cols, keep), k_ref[0, :, cols], sm_scale,
+                            fold, mask)
+                m_prev = m_scr[h]                             # [bq, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)                        # [bq, bk]
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+                m_scr[h] = m_new
+                alphas = _place(alphas, alpha, keep)
+                pv = _place(pv, _dot(p.astype(v.dtype), v, _NN), keep)
+            acc_scr[c] = acc_scr[c] * alphas + pv
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
                         window=window)
 
     @pl.when(j == n_inner - 1)
     def _emit():
-        for h in range(g):
-            l = l_scr[h]
-            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
-            lse_ref[0, h] = _to_row(m_scr[h] + jnp.log(l))
+        for c, (cols, heads) in enumerate(blocks):
+            ls = None
+            for h, keep in heads:
+                l = l_scr[h]
+                ls = _place(ls, l, keep)
+                lse_ref[0, h] = _to_row(m_scr[h] + jnp.log(l))
+            o_ref[0, :, cols] = (acc_scr[c] / ls).astype(o_ref.dtype)
 
 
 _COMPILER_PARAMS = pltpu.CompilerParams(
@@ -512,12 +617,14 @@ def _inner_blocks(s, blocks, window, transposed=False):
     return _band_inner_blocks(s, size, inner, window, transposed)
 
 
-def _static(kernel, s, causal, sm_scale, blocks, window, transposed=False):
+def _static(kernel, s, heads, d, causal, sm_scale, blocks, window,
+            transposed=False):
     """``kernel`` with what a call fixes at trace time."""
     bq, bk, g = blocks
     return functools.partial(
         kernel, sm_scale=sm_scale, fold=_is_pow2(sm_scale), causal=causal,
-        bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g, window=window,
+        bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g, d=d,
+        lanes=_lane_block(heads, d), window=window,
         n_inner=_inner_blocks(s, blocks, window, transposed))
 
 
@@ -539,45 +646,86 @@ def _band_fetch(outer, j, size, inner_size, seq, window, transposed=False):
                     jnp.minimum(last, seq // inner_size - 1))
 
 
-def _kv_index(causal, bq, bk, window=None, seq=None):
-    """Index map of a K/V block on a (b, h, qi, ki) grid. A dead causal
-    tile asks for the last live block of its row again, which the
+# An operand of a call is ``(array, start)``: the ``heads * d`` columns
+# from ``start`` of a ``[b, s, columns]`` array. q, k and v may be three
+# arrays or three runs of columns of one (the qkv projection's output),
+# which the index maps tell apart and nothing copies.
+
+def _head_dim(qkv, heads):
+    """``qkv``: the three arrays, or one that holds them side by side."""
+    return qkv[0].shape[-1] // (heads * (3 if len(qkv) == 1 else 1))
+
+
+def _operands(qkv, heads, g):
+    """What the three calls lay out alike, from what a caller hands over
+    and the heads ``g`` of a grid step: q, k, v as ``(array, start)``,
+    the head dim, the lanes of a step and those of a lane block."""
+    d = _head_dim(qkv, heads)
+    if len(qkv) == 3:
+        operands = [(x, 0) for x in qkv]
+    else:
+        operands = [(qkv[0], i * heads * d) for i in range(3)]
+    return operands, d, g * d, _lane_block(heads, d)
+
+
+def _rows_spec(rows, width, row_of, start=0):
+    """Blocks ``(1, rows, width)`` of a ``[b, s, columns]`` operand on a
+    (b, head group, outer, inner) grid: row block ``row_of(outer,
+    inner)``, and the head group's ``width`` lanes, counted from column
+    ``start`` (a multiple of ``width``: a head group divides the heads)."""
+    first = start // width
+    return pl.BlockSpec((1, rows, width),
+                        lambda b, h, i, j: (b, row_of(i, j), first + h))
+
+
+def _stat_spec(g, rows, row_of):
+    """Blocks of a row statistic (``lse``, ``delta``: ``[b, h, 1, s]``)."""
+    return pl.BlockSpec((1, g, 1, rows),
+                        lambda b, h, i, j: (b, h, 0, row_of(i, j)))
+
+
+def _outer(i, j):
+    return i
+
+
+def _kv_row(causal, bq, bk, window=None, seq=None):
+    """Row block of K/V at step (i, j) of a (b, h, qi, ki) grid. A dead
+    causal tile asks for the last live block of its row again, which the
     pipeline already holds, so it fetches nothing; a band call walks the
     band's blocks only (:func:`_band_fetch`)."""
     if window is not None:
-        return lambda b, h, i, j: (
-            b, h, _band_fetch(i, j, bq, bk, seq, window), 0)
+        return lambda i, j: _band_fetch(i, j, bq, bk, seq, window)
     if not causal:
-        return lambda b, h, i, j: (b, h, j, 0)
-    return lambda b, h, i, j: (
-        b, h, jnp.minimum(j, ((i + 1) * bq - 1) // bk), 0)
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
 
 
-def _fwd(q, k, v, causal, sm_scale, blocks, interpret, window=None):
-    b, h, s, d = q.shape
+def _fwd(qkv, heads, causal, sm_scale, blocks, interpret, window=None):
     bq, bk, g = blocks
-    nq, nk = s // bq, s // bk
-    kv_index = _kv_index(causal, bq, bk, window, s)
+    ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
+    b, s, _ = q.shape
+    nk = s // bk
+    kv_row = _kv_row(causal, bq, bk, window, s)
     scratch = [] if nk == 1 else [
-        pltpu.VMEM((g, bq, d), jnp.float32),
+        pltpu.VMEM((width // lanes, bq, lanes), jnp.float32),
         pltpu.VMEM((g, bq, 1), jnp.float32),
         pltpu.VMEM((g, bq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
-        _static(_fwd_kernel, s, causal, sm_scale, blocks, window),
-        grid=(b, h // g, nq, _inner_blocks(s, blocks, window)),
+        _static(_fwd_kernel, s, heads, d, causal, sm_scale, blocks, window),
+        grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
         in_specs=[
-            pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, g, bk, d), kv_index),
-            pl.BlockSpec((1, g, bk, d), kv_index),
+            _rows_spec(bq, width, _outer, q0),
+            _rows_spec(bk, width, kv_row, k0),
+            _rows_spec(bk, width, kv_row, v0),
         ],
         out_specs=[
-            pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, g, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
+            _rows_spec(bq, width, _outer),
+            _stat_spec(g, bq, _outer),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, heads * d), q.dtype),
+            jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32),
         ],
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
@@ -591,96 +739,119 @@ def _fwd(q, k, v, causal, sm_scale, blocks, interpret, window=None):
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g, window,
-               n_inner):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
+               *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes,
+               window, n_inner):
     qi, j = pl.program_id(2), pl.program_id(3)
     ki = _inner_block(qi, j, bq, bk, window)
+    blocks = _lane_blocks(g, d, lanes)
 
-    def grad(h, parts):
-        """dq of head ``h`` from the key ranges ``parts`` of the block."""
-        q = q_ref[0, h]
-        if fold:
-            q = q * sm_scale
-        do = do_ref[0, h]
-        lse = _to_col(lse_ref[0, h])                          # [bq, 1]
-        delta = _to_col(delta_ref[0, h])
-        dq = 0.
-        for lo, hi, mask in parts:
-            k = k_ref[0, h, lo:hi]
-            s = _scores(q, k, sm_scale, fold, mask)
-            p = jnp.exp(s - lse)                              # [bq, keys]
-            dp = _dot(do, v_ref[0, h, lo:hi], _NT)
-            ds = p * (dp - delta)
-            if not fold:
-                ds = ds * sm_scale
-            dq = dq + _dot(ds.astype(k.dtype), k, _NN)
-        return dq
+    def delta_of(cols, h, keep):
+        """``rowsum(dO * O)`` over head ``h``'s lanes, [bq, 1] in f32;
+        leaves it in ``delta_ref`` as a row, for ``flash_dkv``."""
+        products = (do_ref[0, :, cols].astype(jnp.float32)
+                    * o_ref[0, :, cols].astype(jnp.float32))
+        delta = jnp.sum(_only(products, keep), axis=1, keepdims=True)
+        delta_ref[0, h] = _to_row(delta)
+        return delta
+
+    def grad(cols, heads, parts, delta_of=delta_of):
+        """dq of a lane block's heads from the key ranges ``parts``."""
+        dqs = None
+        for h, keep in heads:
+            q = q_ref[0, :, cols]
+            q = _only(q * sm_scale if fold else q, keep)
+            do = _only(do_ref[0, :, cols], keep)
+            lse = _to_col(lse_ref[0, h])                      # [bq, 1]
+            delta = delta_of(cols, h, keep)
+            dq = 0.
+            for lo, hi, mask in parts:
+                k = k_ref[0, lo:hi, cols]
+                s = _scores(q, k, sm_scale, fold, mask)
+                p = jnp.exp(s - lse)                          # [bq, keys]
+                dp = _dot(do, v_ref[0, lo:hi, cols], _NT)
+                ds = p * (dp - delta)
+                if not fold:
+                    ds = ds * sm_scale
+                dq = dq + _dot(ds.astype(k.dtype), k, _NN)    # [bq, lanes]
+            dqs = _place(dqs, dq, keep)
+        return dqs
 
     def finish(dq):
         # a folded scale multiplied q, not the tile: give dq its factor
         return (dq * sm_scale if fold else dq).astype(dq_ref.dtype)
 
     def rows(parts):
-        for h in range(g):
-            dq_ref[0, h] = finish(grad(h, parts))
+        for cols, heads in blocks:
+            dq_ref[0, :, cols] = finish(grad(cols, heads, parts))
 
     if nk == 1:
         _for_the_live_row(rows, qi, nq, bq, bk, causal, window=window)
         return
 
-    dq_scr, = scratch
+    # delta is the same at every inner step: made at the first and kept
+    dq_scr, delta_scr = scratch
 
     @pl.when(j == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        for cols, heads in blocks:
+            for h, keep in heads:
+                delta_scr[h] = delta_of(cols, h, keep)
 
     def tile(mask):
-        for h in range(g):
-            dq_scr[h] = dq_scr[h] + grad(h, [(0, bk, mask)])
+        for c, (cols, heads) in enumerate(blocks):
+            dq_scr[c] = dq_scr[c] + grad(
+                cols, heads, [(0, bk, mask)],
+                lambda cols, h, keep: delta_scr[h])
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
                         window=window)
 
     @pl.when(j == n_inner - 1)
     def _emit():
-        for h in range(g):
-            dq_ref[0, h] = finish(dq_scr[h])
+        for c, (cols, _) in enumerate(blocks):
+            dq_ref[0, :, cols] = finish(dq_scr[c])
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *scratch,
-                sm_scale, fold, causal, bq, bk, nq, nk, g, window, n_inner):
+                sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
+                n_inner):
     ki, j = pl.program_id(2), pl.program_id(3)
     qi = _inner_block(ki, j, bk, bq, window, transposed=True)
+    blocks = _lane_blocks(g, d, lanes)
 
-    def grads(h, parts):
-        """(dk, dv) of head ``h`` from the query ranges ``parts`` of the
-        block, on TRANSPOSED tiles [bk, queries]: lse and delta are rows
-        of them, and both gradients plain matmuls."""
-        k = k_ref[0, h]
-        v = v_ref[0, h]
-        dk = dv = 0.
-        for lo, hi, mask in parts:
-            q = q_ref[0, h, lo:hi]
-            if fold:
-                q = q * sm_scale       # dk = ds^T (q * scale) as well
-            do = do_ref[0, h, lo:hi]
-            s = _scores(k, q, sm_scale, fold, mask)           # [bk, queries]
-            p = jnp.exp(s - lse_ref[0, h, :, lo:hi])
-            dv = dv + _dot(p.astype(do.dtype), do, _NN)       # [bk, D]
-            dp = _dot(v, do, _NT)
-            ds = p * (dp - delta_ref[0, h, :, lo:hi])
-            if not fold:
-                ds = ds * sm_scale
-            dk = dk + _dot(ds.astype(q.dtype), q, _NN)
-        return dk, dv
+    def grads(cols, heads, parts):
+        """(dk, dv) of a lane block's heads from the query ranges
+        ``parts``, on TRANSPOSED tiles [bk, queries]: lse and delta are
+        rows of them, and both gradients plain matmuls."""
+        dks = dvs = None
+        for h, keep in heads:
+            k = _only(k_ref[0, :, cols], keep)
+            v = _only(v_ref[0, :, cols], keep)
+            dk = dv = 0.
+            for lo, hi, mask in parts:
+                q = q_ref[0, lo:hi, cols]
+                if fold:
+                    q = q * sm_scale   # dk = ds^T (q * scale) as well
+                do = do_ref[0, lo:hi, cols]
+                s = _scores(k, q, sm_scale, fold, mask)       # [bk, queries]
+                p = jnp.exp(s - lse_ref[0, h, :, lo:hi])
+                dv = dv + _dot(p.astype(do.dtype), do, _NN)   # [bk, lanes]
+                dp = _dot(v, do, _NT)
+                ds = p * (dp - delta_ref[0, h, :, lo:hi])
+                if not fold:
+                    ds = ds * sm_scale
+                dk = dk + _dot(ds.astype(q.dtype), q, _NN)
+            dks, dvs = _place(dks, dk, keep), _place(dvs, dv, keep)
+        return dks, dvs
 
     def rows(parts):
-        for h in range(g):
-            dk, dv = grads(h, parts)
-            dk_ref[0, h] = dk.astype(dk_ref.dtype)
-            dv_ref[0, h] = dv.astype(dv_ref.dtype)
+        for cols, heads in blocks:
+            dk, dv = grads(cols, heads, parts)
+            dk_ref[0, :, cols] = dk.astype(dk_ref.dtype)
+            dv_ref[0, :, cols] = dv.astype(dv_ref.dtype)
 
     if nq == 1:
         _for_the_live_row(rows, ki, nk, bk, bq, causal, transposed=True,
@@ -695,49 +866,60 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def tile(mask):
-        for h in range(g):
-            dk, dv = grads(h, [(0, bq, mask)])
-            dk_scr[h] = dk_scr[h] + dk
-            dv_scr[h] = dv_scr[h] + dv
+        for c, (cols, heads) in enumerate(blocks):
+            dk, dv = grads(cols, heads, [(0, bq, mask)])
+            dk_scr[c] = dk_scr[c] + dk
+            dv_scr[c] = dv_scr[c] + dv
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
                         transposed=True, window=window)
 
     @pl.when(j == n_inner - 1)
     def _emit():
-        for h in range(g):
-            dk_ref[0, h] = dk_scr[h].astype(dk_ref.dtype)
-            dv_ref[0, h] = dv_scr[h].astype(dv_ref.dtype)
+        for c, (cols, _) in enumerate(blocks):
+            dk_ref[0, :, cols] = dk_scr[c].astype(dk_ref.dtype)
+            dv_ref[0, :, cols] = dv_scr[c].astype(dv_ref.dtype)
 
 
-def _dq(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret,
+def _dq(qkv, do, o, lse, heads, causal, sm_scale, blocks, interpret,
         window=None):
-    b, h, s, d = q.shape
+    """``(dq, delta)``: ``delta = rowsum(dO * O)`` of each head is
+    computed here, from the two merged tensors a block at a time, and
+    left as ``[b, h, 1, s]`` for ``flash_dkv``."""
     bq, bk, g = blocks
-    nq, nk = s // bq, s // bk
-    q_spec = pl.BlockSpec((1, g, bq, d), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, g, bk, d),
-                           _kv_index(causal, bq, bk, window, s))
-    row_spec = pl.BlockSpec((1, g, 1, bq), lambda b, h, i, j: (b, h, 0, i))
+    ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
+    b, s, _ = do.shape
+    kv_row = _kv_row(causal, bq, bk, window, s)
+    q_spec, row_spec = _rows_spec(bq, width, _outer), _stat_spec(g, bq, _outer)
     return pl.pallas_call(
-        _static(_dq_kernel, s, causal, sm_scale, blocks, window),
-        grid=(b, h // g, nq, _inner_blocks(s, blocks, window)),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((g, bq, d), jnp.float32)],
+        _static(_dq_kernel, s, heads, d, causal, sm_scale, blocks, window),
+        grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
+        in_specs=[_rows_spec(bq, width, _outer, q0),
+                  _rows_spec(bk, width, kv_row, k0),
+                  _rows_spec(bk, width, kv_row, v0),
+                  q_spec, q_spec, row_spec],
+        # dq as q is held: an array of its own, or the first third of
+        # one (whose other columns this call leaves unwritten)
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[] if s // bk == 1 else [
+            pltpu.VMEM((width // lanes, bq, lanes), jnp.float32),
+            pltpu.VMEM((g, bq, 1), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=_name('flash_dq', window),
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, o, lse)
 
 
-def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret,
-         window=None):
-    b, h, s, d = q.shape
+def _dkv(qkv, do, lse, delta, heads, causal, sm_scale, blocks, interpret,
+         window=None, dqkv=None):
+    """``(dk, dv)``; or with ``dqkv``, the ``[b, s, 3 * heads * d]``
+    array whose first third ``flash_dq`` wrote, ``(dqkv, dv)``: dk goes
+    into the second third of that array, in place."""
     bq, bk, g = blocks
-    nq, nk = s // bq, s // bk
+    ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
+    b, s, hd = do.shape
     # the grid iterates q-blocks innermost for each kv-block; the dead
     # causal tiles come first there, and ask for the first live q-block;
     # a band call walks the q-blocks its kv-block is seen from
@@ -750,110 +932,99 @@ def _dkv(q, k, v, do, lse, delta, causal, sm_scale, blocks, interpret,
     else:
         def q_row(j, i):
             return i
-    q_spec = pl.BlockSpec(
-        (1, g, bq, d), lambda b, h, j, i: (b, h, q_row(j, i), 0))
-    kv_spec = pl.BlockSpec((1, g, bk, d), lambda b, h, j, i: (b, h, j, 0))
-    row_spec = pl.BlockSpec(
-        (1, g, 1, bq), lambda b, h, j, i: (b, h, 0, q_row(j, i)))
+    row_spec = _stat_spec(g, bq, q_row)
+    acc = pltpu.VMEM((width // lanes, bk, lanes), jnp.float32)
+    kernel = _static(_dkv_kernel, s, heads, d, causal, sm_scale, blocks,
+                     window, transposed=True)
+    in_specs = [_rows_spec(bq, width, q_row, q0),
+                _rows_spec(bk, width, _outer, k0),
+                _rows_spec(bk, width, _outer, v0),
+                _rows_spec(bq, width, q_row), row_spec, row_spec]
+    operands = (q, k, v, do, lse, delta)
+    if dqkv is None:
+        dk, aliases = jax.ShapeDtypeStruct((b, s, hd), k.dtype), {}
+    else:
+        # the array comes in where it lies and goes out as the first
+        # result: the kernel sees it as that result only
+        dk, aliases = dqkv, {len(operands): 0}
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        operands += (dqkv,)
+        kernel = functools.partial(_without, kernel, len(operands) - 1)
     return pl.pallas_call(
-        _static(_dkv_kernel, s, causal, sm_scale, blocks, window,
-                transposed=True),
-        grid=(b, h // g, nk, _inner_blocks(s, blocks, window,
-                                           transposed=True)),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, s, d), v.dtype),
-        ],
-        scratch_shapes=[] if nq == 1 else [
-            pltpu.VMEM((g, bk, d), jnp.float32),
-            pltpu.VMEM((g, bk, d), jnp.float32)],
+        kernel,
+        grid=(b, heads // g, s // bk, _inner_blocks(s, blocks, window,
+                                                    transposed=True)),
+        in_specs=in_specs,
+        out_specs=[_rows_spec(bk, width, _outer, 0 if dqkv is None else hd),
+                   _rows_spec(bk, width, _outer)],
+        out_shape=[jax.ShapeDtypeStruct(dk.shape, dk.dtype),
+                   jax.ShapeDtypeStruct((b, s, hd), v.dtype)],
+        scratch_shapes=[] if s // bq == 1 else [acc, acc],
+        input_output_aliases=aliases,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=_name('flash_dkv', window),
-    )(q, k, v, do, lse, delta)
+    )(*operands)
 
 
-def _bwd(q, k, v, do, lse, delta, causal, sm_scale, plan, interpret, window):
-    args = (q, k, v, do, lse, delta, causal, sm_scale)
-    dk, dv = _dkv(*args, plan.dkv, interpret, window)
-    return _dq(*args, plan.dq, interpret, window), dk, dv
+def _without(kernel, i, *refs):
+    """``kernel`` on all its refs but the ``i``-th."""
+    return kernel(*refs[:i], *refs[i + 1:])
 
 
 # ---------------------------------------------------------------------------
 # custom-vjp wrapper
 # ---------------------------------------------------------------------------
 
-# What the forward rule of a ``merged`` call names for a checkpoint
-# policy (``jax.checkpoint_policies.save_only_these_names``): its output
-# and its row statistics, all the backward needs besides q, k and v.
+# What the forward rule of a ``flash_attention_merged`` call names for a
+# checkpoint policy (``jax.checkpoint_policies.save_only_these_names``):
+# its output and its row statistics, all the backward needs besides q, k
+# and v.
 CHECKPOINT_NAMES = ('flash_o', 'flash_lse')
 
 
-def _merge_heads(o):
-    b, h, s, d = o.shape
-    return jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+def _flash(qkv, heads, causal, sm_scale, plan, interpret, window=None,
+           named=False):
+    """``qkv``: a tuple of q, k, v as ``[b, s, heads * d]`` each, or of
+    the one ``[b, s, 3 * heads * d]`` that holds them side by side;
+    ``o [b, s, heads * d]``."""
+    return _fwd(qkv, heads, causal, sm_scale, plan.fwd, interpret, window)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, sm_scale, plan, interpret, window=None,
-           merged=False):
-    o, _ = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret, window)
-    return _merge_heads(o) if merged else o
+def _flash_fwd(qkv, heads, causal, sm_scale, plan, interpret, window, named):
+    o, lse = _fwd(qkv, heads, causal, sm_scale, plan.fwd, interpret, window)
+    if named:
+        # both as the kernel writes them: o lane-dense, what the output
+        # projection reads; lse [b, h, 1, s], which XLA tiles T(1, 128)
+        # in a stack of them (the unit dimension pads nothing)
+        o = checkpoint_name(o, CHECKPOINT_NAMES[0])
+        lse = checkpoint_name(lse, CHECKPOINT_NAMES[1])
+    return o, (qkv, o, lse)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, plan, interpret, window, merged):
-    o, lse = _fwd(q, k, v, causal, sm_scale, plan.fwd, interpret, window)
-    if not merged:
-        return o, (q, k, v, o, lse)
-    # o is named in the layout it is kept in, heads x head_dim along the
-    # lanes: [b, h, s, 64] is lane-padded to twice its bytes in HBM. lse
-    # stays as the kernel writes it (XLA tiles the stack of [b, h, 1, s]
-    # T(1, 128): the unit dimension pads nothing). o is named as its
-    # BITS: on a floating-point residual that the forward pass uses too,
-    # jax.checkpoint puts a reduce_precision, which XLA runs right after
-    # the custom call as a pass of its own over the padded o (0.6 ms a
-    # layer at [96, 16, 512, 64]: PERF.md §6, PR 27). The value is the
-    # kernel's own rounding already, what the caller gets is a bitcast
-    # of what is kept, and the barrier keeps that bitcast behind the
-    # layout copy, where it fuses into the write to the stack.
-    bits = jnp.dtype('uint%d' % (8 * o.dtype.itemsize))
-    o = checkpoint_name(
-        jax.lax.bitcast_convert_type(
-            jax.lax.optimization_barrier(_merge_heads(o)), bits),
-        CHECKPOINT_NAMES[0])
-    lse = checkpoint_name(lse, CHECKPOINT_NAMES[1])
-    return jax.lax.bitcast_convert_type(o, q.dtype), (q, k, v, o, lse)
-
-
-def _flash_bwd(causal, sm_scale, plan, interpret, window, merged, res, do):
-    q, k, v, o, lse = res
-    # delta = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
-    if merged:
-        b, h, s, d = q.shape
-        do = do.reshape(b, s, h, d)
-        # the barrier pins the reshape to the slice of the stack, a free
-        # view: XLA else sinks it below the converts, and o in f32 becomes
-        # a tensor in HBM between them and the reduce (0.6 ms a layer at
-        # [96, 16, 512, 64])
-        o = jax.lax.bitcast_convert_type(
-            jax.lax.optimization_barrier(o.reshape(b, s, h, d)), q.dtype)
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)
-        delta = jnp.transpose(delta, (0, 2, 1))               # [B, H, S]
-        do = jnp.transpose(do, (0, 2, 1, 3))
-    else:
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)
-    return _bwd(q, k, v, do, lse, delta[:, :, None, :], causal, sm_scale,
-                plan, interpret, window)                      # [B, H, 1, S]
+def _flash_bwd(heads, causal, sm_scale, plan, interpret, window, named, res,
+               do):
+    qkv, o, lse = res
+    dq, delta = _dq(qkv, do, o, lse, heads, causal, sm_scale, plan.dq,
+                    interpret, window)
+    if len(qkv) == 3:
+        dk, dv = _dkv(qkv, do, lse, delta, heads, causal, sm_scale, plan.dkv,
+                      interpret, window)
+        return ((dq, dk, dv),)
+    # the cotangent of one array that holds q, k and v is one array: dq
+    # is its first third as flash_dq returns it, flash_dkv writes dk into
+    # the second in place, and dv is written over the last
+    dqkv, dv = _dkv(qkv, do, lse, delta, heads, causal, sm_scale, plan.dkv,
+                    interpret, window, dqkv=dq)
+    return ((jax.lax.dynamic_update_slice_in_dim(
+        dqkv, dv, 2 * dv.shape[-1], axis=2),),)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _blocks(heads, seq, targets, block_q, block_k):
+def _blocks(heads, head_dim, seq, targets, block_q, block_k):
     sizes = []
     for asked, target in zip((block_q, block_k), targets):
         size = seq if not asked and seq <= target else \
@@ -862,15 +1033,17 @@ def _blocks(heads, seq, targets, block_q, block_k):
             raise ValueError('flash_attention: seq %d not blockable; check '
                              'supports() first' % seq)
         sizes.append(size)
-    return Blocks(*sizes, _heads_per_step(heads, *sizes))
+    per_block = _lane_block(heads, head_dim) // head_dim
+    return Blocks(*sizes, _heads_per_step(heads, *sizes, per_block))
 
 
 def _plan(shape, causal, block_q=None, block_k=None, window=None):
-    """The static plan of a call on [b, h, s, d] operands: for each
-    kernel the block sizes (the arguments, else the kernel's targets,
-    cut to divisors of ``s``) and the heads a grid step holds."""
-    _, h, s, _ = shape
-    return Plan(**{kernel: _blocks(h, s, targets, block_q, block_k)
+    """The static plan of a call on ``h`` heads of ``d`` over ``s``
+    positions (``shape``: [b, h, s, d]): for each kernel the block sizes
+    (the arguments, else the kernel's targets, cut to divisors of ``s``)
+    and the heads a grid step holds, in whole lane blocks."""
+    _, h, s, d = shape
+    return Plan(**{kernel: _blocks(h, d, s, targets, block_q, block_k)
                    for kernel, targets in
                    _block_targets(s, causal, window).items()})
 
@@ -918,50 +1091,73 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     ``flash_dkv_band``; with ``window=None`` the call is what it is
     without the argument. A window under ``causal=True`` is an error.
 
+    The kernels work on ``[batch, seq, heads * head_dim]``
+    (:func:`flash_attention_merged`); this is that call between the
+    transposes into its layout and out of it, for callers that hold
+    ``[b, h, s, d]`` (ring and Ulysses attention, ``chip_smoke.py``).
+
     Each trace leaves one ``flash.plan`` point event in the loop ring
     (``telemetry.get().loop_records()``): the static plan of the call
     (``_plan_tags``).
     """
-    return _planned(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-                    window, merged=False)
+    b, h, s, d = q.shape
+    o = _planned(tuple(jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, h * d)
+                       for x in (q, k, v)), h, causal, sm_scale, block_q,
+                 block_k, interpret, window, named=False)
+    return jnp.transpose(o.reshape(b, s, h, d), (0, 2, 1, 3))
 
 
-def flash_attention_merged(q, k, v, causal=True, sm_scale=None,
+def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
                            interpret=None, window=None):
-    """:func:`flash_attention` for a model's block: the same call on
-    ``[batch, heads, seq, head_dim]`` operands, with the output as
+    """:func:`flash_attention` in the kernels' own layout, which is the
+    model's: ``qkv`` is the qkv projection's output
+    ``[batch, seq, 3 * heads * head_dim]`` (q, k and v side by side,
+    read where they lie) or a tuple of the three as
+    ``[batch, seq, heads * head_dim]`` each, and the output is
     ``[batch, seq, heads * head_dim]``, what the output projection
-    takes, and the forward rule's residuals named for a checkpoint
-    policy (``CHECKPOINT_NAMES``): that output, and ``lse``. Under ``jax.checkpoint(block,
-    policy=save_only_these_names(*CHECKPOINT_NAMES))`` the backward
-    pass then recomputes q, k and v but runs no forward kernel again;
-    the backward rule takes ``do`` in the output's layout and computes
-    ``delta`` from the two merged tensors. :func:`saved_bytes` is what a
-    call keeps. Without such a policy the names mean nothing."""
-    return _planned(q, k, v, causal, sm_scale, None, None, interpret, window,
-                    merged=True)
+    takes. Nothing is transposed, copied or padded on the way in or out.
+
+    The forward rule's residuals are named for a checkpoint policy
+    (``CHECKPOINT_NAMES``): that output, and ``lse``. Under
+    ``jax.checkpoint(block, policy=save_only_these_names(
+    *CHECKPOINT_NAMES))`` the backward pass then recomputes q, k and v
+    but runs no forward kernel again. :func:`saved_bytes` is what a call
+    keeps. Without such a policy the names mean nothing."""
+    if not isinstance(qkv, (tuple, list)):
+        qkv = (qkv,)
+    return _planned(tuple(qkv), heads, causal, sm_scale, None, None,
+                    interpret, window, named=True)
 
 
 def saved_bytes(shape, dtype):
-    """Bytes that a :func:`flash_attention_merged` call on
-    ``[b, h, s, d]`` operands of ``dtype`` names for the checkpoint:
-    ``o`` and the f32 ``lse``."""
+    """Bytes that a :func:`flash_attention_merged` call on ``h`` heads
+    of ``d`` (``shape``: [b, h, s, d]) in ``dtype`` names for the
+    checkpoint: ``o`` and the f32 ``lse``."""
     b, h, s, d = shape
     return b * h * s * (d * jnp.dtype(dtype).itemsize + 4)
 
 
-def _planned(q, k, v, causal, sm_scale, block_q, block_k, interpret, window,
-             merged):
+def _planned(qkv, heads, causal, sm_scale, block_q, block_k, interpret,
+             window, named):
     window = check_window(window, causal)
+    b, s, _ = qkv[0].shape
+    d = _head_dim(qkv, heads)
+    lanes = _lane_block(heads, d)
+    if len(qkv) == 1 and lanes % _LANES:
+        # a run of columns narrower than the lanes cannot be blocked out
+        # of a wider array (models of under 128 lanes in all)
+        qkv = tuple(jnp.split(qkv[0], 3, axis=-1))
     if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
+        sm_scale = d ** -0.5
     sm_scale = float(sm_scale)
-    plan = _plan(q.shape, causal, block_q, block_k, window)
+    plan = _plan((b, heads, s, d), causal, block_q, block_k, window)
     if interpret is None:
         interpret = _interpret_default()
     telemetry.get().loop_event(
-        'flash.plan', seq=q.shape[2], head_dim=q.shape[3],
-        causal=bool(causal), fold_scale=_is_pow2(sm_scale),
+        'flash.plan', seq=s, head_dim=d, causal=bool(causal),
+        fold_scale=_is_pow2(sm_scale),
         window=None if window is None else list(window),
-        **_plan_tags(plan, q.shape[2], causal, window))
-    return _flash(q, k, v, causal, sm_scale, plan, interpret, window, merged)
+        layout='bsd', lane_block=lanes, heads_per_lane_block=lanes // d,
+        **_plan_tags(plan, s, causal, window))
+    return _flash(qkv, heads, causal, sm_scale, plan, interpret, window,
+                  named)

@@ -16,17 +16,19 @@ from autodist_tpu.kernels import flash_attention as fa
 from autodist_tpu.models.core import Dense, Module, constrain
 from autodist_tpu.parallel.axes import (active_manual_axes, ctx_option,
                                         current_mesh, live_mesh_axis,
-                                        manual_axis, unsharded_execution)
+                                        manual_axis, shard_map,
+                                        unsharded_execution)
 from autodist_tpu.parallel.ring_attention import (local_flash_attention,
                                                   ring_attention)
 from autodist_tpu.parallel.ulysses import ulysses_attention
 
 
 @jax.named_scope('rotary')
-def rotary(x, positions, theta):
+def rotary(x, positions, theta, heads=None):
     """Rotary position embedding over all of the head dim of
-    ``x [b, h, s, d]``, rotate-half convention (``x1, x2`` the two
-    halves: ``x * cos + cat(-x2, x1) * sin`` with ``inv_freq_j =
+    ``x [b, h, s, d]``, or with ``heads`` given of each head of the
+    merged ``x [b, s, heads * d]``; rotate-half convention (``x1, x2``
+    the two halves: ``x * cos + cat(-x2, x1) * sin`` with ``inv_freq_j =
     theta ** (-2j / d)``), computed in f32 at positions ``[s]``.
 
     ``cat(-x2, x1)`` is taken as ``x @ R`` with R the signed permutation
@@ -34,8 +36,10 @@ def rotary(x, positions, theta):
     format (one term a column). Written with split, negate and
     concatenate on a head dim of 64 it costs XLA a dozen lane-shuffling
     passes over f32 copies of q and k, a quarter of a ModernBERT step at
-    seq 8192 (PERF.md §6, PR 26)."""
-    d = x.shape[-1]
+    seq 8192 (PERF.md §6, PR 26). On the merged layout R is that
+    permutation once a head down the diagonal and ``cos``, ``sin``
+    repeat a head: nothing reshapes the lanes into heads."""
+    d = x.shape[-1] // (heads or 1)
     half = d // 2
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
@@ -44,7 +48,10 @@ def rotary(x, positions, theta):
     at = jnp.arange(d)
     turn = (jnp.where(at[:, None] == at[None, :] - half, 1.0, 0.0)
             - jnp.where(at[:, None] == at[None, :] + half, 1.0, 0.0))
-    turned = jnp.einsum('bhsd,de->bhse', x, turn.astype(x.dtype),
+    if heads:
+        cos, sin = jnp.tile(cos, (1, heads)), jnp.tile(sin, (1, heads))
+        turn = jnp.kron(jnp.eye(heads), turn)
+    turned = jnp.einsum('...d,de->...e', x, turn.astype(x.dtype),
                         precision=jax.lax.Precision.HIGHEST)
     return (x.astype(jnp.float32) * cos
             + turned.astype(jnp.float32) * sin).astype(x.dtype)
@@ -84,6 +91,13 @@ class MultiHeadAttention(Module):
         b, s, _ = x.shape
         h, d = self.num_heads, self.head_dim
         qkv = self.wqkv.apply(params['qkv'], x)          # [b, s, 3hd]
+        local = self.kernel_shape((b, h, s, d))
+        if local is not None:
+            # long device-local sequences: the Pallas flash kernels
+            # (the [s, s] score matrix never reaches HBM), in the layout
+            # the two projections have
+            return self.wo.apply(params['out'],
+                                 self._kernel_attention(qkv, local[1]))
         qkv = qkv.reshape(b, s, 3, h, d)
         q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))     # [b, h, s, d]
         k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
@@ -98,7 +112,6 @@ class MultiHeadAttention(Module):
             q = rotary(q, pos, self.rope_theta)
             k = rotary(k, pos, self.rope_theta)
         window = self.window
-        local = self.kernel_shape(q.shape)
         if seq_axis is not None:
             if window is not None:
                 raise ValueError(
@@ -111,26 +124,54 @@ class MultiHeadAttention(Module):
                                       causal=self.causal)
             else:
                 o = ring_attention(q, k, v, seq_axis, causal=self.causal)
-        elif local == q.shape:
-            # device-local long-seq data: the Pallas flash kernel (never
-            # materializes the [s, s] score matrix in HBM); its output
-            # is [b, s, h * d] already and named, with lse, for the
-            # block's checkpoint policy
-            o = fa.flash_attention_merged(q, k, v, causal=self.causal,
-                                          window=window)
-        elif local is not None:
-            # dp/tp GSPMD mesh at long seq: attention is independent per
-            # (batch, head), so hop into a nested manual region and run
-            # the flash kernel on local shards — GSPMD alone cannot
-            # partition an opaque pallas_call.
-            o = self._tp_manual_flash(q, k, v)
         else:
             o = local_flash_attention(q, k, v, causal=self.causal,
                                       window=window)
             o = constrain(o, ('batch', 'heads', 'seq', 'kv'))
-        if local is None:
-            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
+        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
         return self.wo.apply(params['out'], o)
+
+    def _kernel_attention(self, qkv, local_heads):
+        """``flash_attention_merged`` on the projection's output
+        ``qkv [b, s, 3 * h * d]`` (``local_heads`` of the heads on a
+        device), ``[b, s, h * d]`` out, named with
+        ``lse`` for the block's checkpoint policy. The kernels read q, k
+        and v where the projection wrote them; they are taken apart
+        only where something stands between the two: rotary positions,
+        or heads sharded over a mesh axis (a shard's heads are a
+        contiguous run of each of the three, not of ``qkv``).
+
+        Under a dp/tp GSPMD mesh the call is made on local (batch, head)
+        shards, in a nested manual region: GSPMD alone cannot partition
+        an opaque pallas_call. The region is manual over EVERY mesh
+        axis, size-1 ones included: Mosaic refuses to lower a kernel
+        while any axis of the mesh is still automatic (found on the
+        first four-chip run: interpret mode on the CPU mesh never goes
+        through that check). Axes the spec does not name see replicated
+        operands, which is what attention inputs are over
+        pipe/seq/expert."""
+        h = self.num_heads
+        mesh = None if unsharded_execution() else current_mesh()
+        heads_axis = live_mesh_axis('heads') if mesh is not None else None
+        operands = (qkv,)
+        if self.rope_theta is not None or heads_axis:
+            operands = q, k, v = tuple(jnp.split(qkv, 3, axis=-1))
+        if self.rope_theta is not None:
+            pos = jnp.arange(qkv.shape[1])
+            operands = (rotary(q, pos, self.rope_theta, heads=h),
+                        rotary(k, pos, self.rope_theta, heads=h), v)
+
+        def attend(operands):
+            return fa.flash_attention_merged(
+                operands, local_heads, causal=self.causal,
+                window=self.window)
+
+        if mesh is None:
+            return attend(operands)
+        data = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
+        spec = P(data, None, heads_axis)
+        return shard_map(attend, mesh, ((spec,) * len(operands),),
+                         spec)(operands)
 
     def kernel_shape(self, shape):
         """The per-device ``[b, h, s, d]`` that the flash kernels run on
@@ -167,22 +208,3 @@ class MultiHeadAttention(Module):
             return None
         local = (shape[0] // dp, shape[1] // tp, shape[2], shape[3])
         return local if fa.preferred(local, self.window) else None
-
-    def _tp_manual_flash(self, q, k, v):
-        """Flash kernel on local (batch, head) shards, ``[b, s, h * d]``
-        out (a shard's heads are a contiguous run of it). The region is
-        manual over EVERY mesh axis, size-1 ones included: Mosaic
-        refuses to lower a kernel while any axis of the mesh is still
-        automatic (found on the first four-chip run — interpret mode on
-        the CPU mesh never goes through that check). Axes the spec does
-        not name see replicated operands, which is what attention
-        inputs are over pipe/seq/expert."""
-        mesh = current_mesh()
-        data = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
-        heads = live_mesh_axis('heads')
-        from autodist_tpu.parallel.axes import shard_map
-        fn = shard_map(
-            lambda q, k, v: fa.flash_attention_merged(
-                q, k, v, causal=self.causal, window=self.window),
-            mesh, (P(data, heads),) * 3, P(data, None, heads))
-        return fn(q, k, v)

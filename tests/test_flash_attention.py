@@ -77,46 +77,77 @@ def test_long_seq_asymmetric_blocks():
 
 # Every branch the static plan can take, one case each: (heads, seq,
 # head_dim, causal, block_q, block_k, heads a step). head_dim 16 and 64
-# have a power-of-two softmax scale (folded into q), 32 does not.
+# have a power-of-two softmax scale (folded into q), 32 and 128 do not
+# (128 ** -0.5 is no power of two). A step holds whole lane blocks of
+# ``[b, s, heads * head_dim]``: two heads at 64, four at 32, one at 128,
+# and every head where they are under 128 lanes in all.
 _PLAN_CASES = {
-    # nk == 1, one head a step, folded scale
-    'one_pass-g1-d64': (2, 64, 64, False, 32, 64, 1),
+    # nk == 1, one lane block (a pair of heads) a step, folded scale
+    'one_pass-g2-d64': (2, 64, 64, False, 32, 64, 2),
     # nk == 1, the whole head count a step, scale on the tile
     'one_pass-gall-d32': (2, 64, 32, False, 32, 64, 2),
-    # nk > 1: online softmax, scratch accumulators, 3 heads (no even G)
+    # nk > 1: online softmax, scratch accumulators, 3 heads in 48 lanes
     'online-g3-d16': (3, 96, 16, False, 32, 32, 3),
     # nq == 1: flash_dkv writes straight from the tile, fwd/dq do not
     'dkv_one_pass-g2-d32': (2, 64, 32, False, 64, 32, 2),
-    # causal, nq = 3: dead, diagonal-crossed and unmasked tiles in one call
-    'causal-kinds-g2-d32': (4, 96, 32, True, 32, 32, 2),
+    # causal, nq = 3: dead, diagonal-crossed and unmasked tiles in one
+    # call; four heads of 32 in the 128 lanes of one block
+    'causal-kinds-g4-d32': (4, 96, 32, True, 32, 32, 4),
     # causal, kv-block wider than the q-block, 12 heads at G = 6
     'causal-wide_k-g6-d64': (12, 128, 64, True, 32, 64, 6),
     # causal, q-block taller than the kv-block
-    'causal-tall_q-g1-d64': (2, 128, 64, True, 64, 32, 1),
+    'causal-tall_q-g2-d64': (2, 128, 64, True, 64, 32, 2),
     # causal and nk == 1: every tile is live and crossed (static mask)
     'causal-one_pass-g2-d32': (2, 128, 32, True, 32, 128, 2),
     # causal and nq == 1: flash_dkv's live row (queries after the block)
     'causal-dkv_one_pass-g2-d32': (2, 128, 32, True, 128, 32, 2),
     # causal, one tile in all
     'causal-one_tile-g1-d16': (1, 64, 16, True, 64, 64, 1),
+    # two and four lane blocks a step (G = 4, 8 at head_dim 64), one
+    # pass and several inner blocks
+    'one_pass-g4-d64': (4, 64, 64, False, 32, 64, 4),
+    'online-g8-d64': (8, 64, 64, False, 32, 32, 8),
+    'causal-online-g4-d64': (8, 96, 64, True, 32, 32, 4),
+    # eight heads of 32 in two lane blocks, four of them a step
+    'online-g4-d32': (8, 64, 32, False, 32, 32, 4),
+    'causal-one_pass-g8-d32': (8, 64, 32, True, 32, 64, 8),
+    # a head is a lane block: no head shares its lanes
+    'one_pass-g1-d128': (2, 64, 128, False, 32, 64, 1),
+    'causal-online-g2-d128': (2, 96, 128, True, 32, 32, 2),
+    # an odd head count at head_dim 64 tiles no lane block: every head a
+    # step, in one block as wide as the minor dimension
+    'online-g3-d64': (3, 64, 64, False, 32, 32, 3),
 }
+
+
+def _merge(x):
+    b, h, s, d = x.shape
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, h * d)
+
+
+def _split(x, h):
+    b, s, hd = x.shape
+    return jnp.transpose(x.reshape(b, s, h, hd // h), (0, 2, 1, 3))
 
 
 @pytest.mark.parametrize('case', sorted(_PLAN_CASES))
 def test_plan_branch_parity(case):
     """Forward and all three gradients of the kernels, run with an
-    explicit plan, against the plain f32 attention."""
+    explicit plan on ``[b, s, heads * head_dim]`` operands, against the
+    plain f32 attention."""
     h, s, d, causal, bq, bk, g = _PLAN_CASES[case]
     rng = np.random.RandomState(7)
     q, k, v = _rand_qkv(rng, (2, h, s, d))
     w = jnp.asarray(rng.randn(2, h, s, d), jnp.float32)
     scale = d ** -0.5
     assert fa._is_pow2(scale) == (d in (16, 64))
+    assert g % (fa._lane_block(h, d) // d) == 0
     blocks = fa.Blocks(bq, bk, g)
     plan = fa.Plan(blocks, blocks, blocks)
 
     def kernel(q, k, v):
-        return fa._flash(q, k, v, causal, scale, plan, True)
+        return _split(fa._flash((_merge(q), _merge(k), _merge(v)), h, causal,
+                                scale, plan, True), h)
 
     def plain(q, k, v):
         return local_flash_attention(q, k, v, causal=causal)
@@ -143,6 +174,21 @@ def test_heads_per_step_divides_the_head_count(heads, want):
     g = fa._heads_per_step(heads, 512, 1024)
     assert heads % g == 0
     assert g == 1 or g * 512 * 1024 <= fa._STEP_TILE_ELEMS
+
+
+@pytest.mark.parametrize('heads,d,lanes,small,large', [
+    (16, 64, 128, 8, 2),     # pairs: never half a lane block
+    (12, 64, 128, 6, 2), (16, 32, 128, 8, 4), (8, 32, 128, 8, 4),
+    (16, 128, 128, 8, 1), (8, 256, 256, 8, 1),
+    (4, 16, 64, 4, 4),       # under 128 lanes in all: one block
+    (3, 64, 192, 3, 3),      # heads that tile no lane block: one block
+])
+def test_a_step_holds_whole_lane_blocks(heads, d, lanes, small, large):
+    assert fa._lane_block(heads, d) == lanes
+    per_block = lanes // d
+    assert fa._heads_per_step(heads, 32, 32, per_block) == small
+    # one lane block at least, whatever the tile budget says
+    assert fa._heads_per_step(heads, 1024, 1024, per_block) == large
 
 
 @pytest.mark.parametrize('seq,bq,bk', [(1024, 256, 512), (1024, 256, 256),
@@ -196,6 +242,16 @@ def test_supports_and_preferred():
     assert not fa.preferred((1, 1, 128, 64))   # short seq: XLA wins
     assert fa.preferred((1, 1, 2048, 64))
     assert not fa.preferred((1, 1, 520, 64))   # no lane-wide blocks
+    # heads that tile the lanes of [b, s, h * d] ...
+    assert fa.supports((1, 16, 512, 64)) and fa.supports((1, 16, 512, 32))
+    assert fa.supports((1, 8, 512, 128)) and fa.supports((1, 4, 512, 256))
+    # ... or fit in one lane block
+    assert fa.supports((1, 4, 512, 16)) and fa.supports((1, 1, 512, 64))
+    # an odd head count at head_dim 64, a head_dim that divides no lane
+    # block: XLA's
+    assert not fa.supports((1, 3, 512, 64))
+    assert not fa.preferred((1, 15, 2048, 64))
+    assert not fa.supports((1, 4, 512, 96))
 
 
 def _dense_band(q, k, v, window):
@@ -293,8 +349,9 @@ def test_window_none_is_todays_plan_for_the_cells():
         B(512, 512, 4), B(512, 512, 4), B(512, 512, 4))
     assert fa._plan((32, 16, 1024, 64), True) == fa.Plan(
         B(512, 1024, 2), B(256, 1024, 4), B(1024, 512, 2))
+    # the forward's step holds a pair of heads since PR 29: a lane block
     assert fa._plan((4, 16, 8192, 64), False) == fa.Plan(
-        B(1024, 1024, 1), B(512, 512, 4), B(512, 512, 4))
+        B(1024, 1024, 2), B(512, 512, 4), B(512, 512, 4))
     assert fa._plan((4, 16, 8192, 64), False, window=(64, 64)) == fa.Plan(
         B(128, 512, 8), B(256, 256, 8), B(128, 256, 8))
     assert fa._plan((4, 16, 8192, 64), False, window=(300, 10)).dq[:2] \
@@ -321,30 +378,86 @@ _CALL_KINDS = {'global': (False, None), 'causal': (True, None),
 
 @pytest.mark.parametrize('kind', sorted(_CALL_KINDS))
 def test_merged_call_is_the_public_one_with_the_heads_merged(kind):
+    """``flash_attention([b, h, s, d])`` is the merged call between two
+    transposes: the same bits, forward and gradients."""
     causal, window = _CALL_KINDS[kind]
     rng = np.random.RandomState(5)
     q, k, v = _rand_qkv(rng, (2, 4, 64, 16))
     w = jnp.asarray(rng.randn(2, 64, 64), jnp.float32)
 
-    def merge(o):
-        return jnp.transpose(o, (0, 2, 1, 3)).reshape(2, 64, 64)
-
     def public(q, k, v):
-        return merge(fa.flash_attention(q, k, v, causal=causal,
-                                        window=window))
+        return _merge(fa.flash_attention(q, k, v, causal=causal,
+                                         window=window))
 
     def merged(q, k, v):
-        return fa.flash_attention_merged(q, k, v, causal=causal,
-                                         window=window)
+        return fa.flash_attention_merged(
+            (_merge(q), _merge(k), _merge(v)), 4, causal=causal,
+            window=window)
     np.testing.assert_array_equal(np.asarray(merged(q, k, v)),
                                   np.asarray(public(q, k, v)))
     got = jax.grad(lambda *a: jnp.sum(merged(*a) * w), (0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(public(*a) * w), (0, 1, 2))(q, k, v)
     for g, x in zip(got, want):
-        # delta is summed from another view of the same products
-        np.testing.assert_allclose(np.asarray(g), np.asarray(x),
-                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
     assert fa.saved_bytes(q.shape, q.dtype) == 2 * 4 * 64 * (16 * 4 + 4)
+
+
+# (heads, head_dim): pairs and fours of heads in a lane block, a head
+# that is one; with 128 lanes or more in all, q, k and v can be read
+# out of one array
+_PACKED = {'d64': (4, 64), 'd32': (8, 32), 'd128': (2, 128)}
+
+
+@pytest.mark.parametrize('kind', sorted(_CALL_KINDS))
+@pytest.mark.parametrize('heads', sorted(_PACKED))
+def test_qkv_read_from_one_array_is_three_separate_operands(heads, kind):
+    """The projection's output as ONE operand, q, k and v three runs of
+    its columns, against the three as arrays of their own: the same
+    bits, and the cotangent is the three gradients side by side."""
+    h, d = _PACKED[heads]
+    causal, window = _CALL_KINDS[kind]
+    rng = np.random.RandomState(9)
+    qkv = jnp.asarray(rng.randn(2, 64, 3 * h * d), jnp.float32)
+    w = jnp.asarray(rng.randn(2, 64, h * d), jnp.float32)
+
+    def packed(qkv):
+        return fa.flash_attention_merged(qkv, h, causal=causal,
+                                         window=window)
+
+    def separate(qkv):
+        return fa.flash_attention_merged(
+            tuple(jnp.split(qkv, 3, axis=-1)), h, causal=causal,
+            window=window)
+    np.testing.assert_array_equal(np.asarray(packed(qkv)),
+                                  np.asarray(separate(qkv)))
+    np.testing.assert_array_equal(
+        np.asarray(jax.grad(lambda x: jnp.sum(packed(x) * w))(qkv)),
+        np.asarray(jax.grad(lambda x: jnp.sum(separate(x) * w))(qkv)))
+    # and they are the plain attention's
+    q, k, v = (_split(x, h) for x in jnp.split(qkv, 3, axis=-1))
+    want = local_flash_attention(q, k, v, causal=causal, window=window) \
+        if window is None else _dense_band(q, k, v, window)
+    np.testing.assert_allclose(np.asarray(_split(packed(qkv), h)),
+                               np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+def test_heads_that_tile_no_lane_block_take_the_xla_path(monkeypatch):
+    """Three heads of 64: ``supports`` is False, and the module never
+    reaches the kernels, whatever the sequence."""
+    import autodist_tpu.models.attention as attn_mod
+    from autodist_tpu.models.attention import MultiHeadAttention
+
+    def never(*a, **kw):
+        raise AssertionError('kernel path taken')
+
+    monkeypatch.setattr(attn_mod.fa, 'flash_attention_merged', never)
+    monkeypatch.setattr(attn_mod.fa, 'MIN_KERNEL_SEQ', 16)
+    mha = MultiHeadAttention(192, 3, causal=False)
+    assert mha.kernel_shape((2, 3, 32, 64)) is None
+    assert MultiHeadAttention(256, 4).kernel_shape((2, 4, 32, 64)) \
+        == (2, 4, 32, 64)
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 32, 192), jnp.float32)
+    assert mha.apply(mha.init(jax.random.PRNGKey(0)), x).shape == x.shape
 
 
 @pytest.mark.parametrize('kind', sorted(_CALL_KINDS))
@@ -360,9 +473,8 @@ def test_checkpoint_policy_keeps_the_forward_kernel_out_of_the_backward(
     ws = jnp.asarray(rng.randn(3, 64, 4 * 64) * 0.1, jnp.float32)
 
     def block(h, w):
-        q, k, v = (jnp.transpose((h @ w[:, i * 64:(i + 1) * 64]).reshape(
-            2, 64, 4, 16), (0, 2, 1, 3)) for i in range(3))
-        o = fa.flash_attention_merged(q, k, v, causal=causal, window=window)
+        o = fa.flash_attention_merged(h @ w[:, :192], 4, causal=causal,
+                                      window=window)
         return h + o @ w[:, 192:], None
 
     def loss(policy):

@@ -13,6 +13,7 @@ The subprocess tests here are the slow ones of this file's family, so
 the file sorts late in the suite on purpose.
 """
 import ast
+import functools
 import json
 import os
 import subprocess
@@ -84,11 +85,15 @@ def test_kernel_regions_lower_for_tpu(monkeypatch, spec_kw, seq):
 def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     """The benchmark reads the kernels of a step by name
     (``benchmark/scope_reduce.py``, ``benchmark/flash_kinds.py``):
-    forward and backward of ``flash_attention`` at the cells' shapes are
-    exactly three Mosaic calls, ``flash_fwd``, ``flash_dq`` and
-    ``flash_dkv``, with ``_band`` behind each name for a band call; and
-    tracing leaves the static plan in the loop ring, with tile counts
-    that a position-by-position count confirms."""
+    forward and backward of ``flash_attention_merged`` at the cells'
+    shapes are exactly three Mosaic calls, ``flash_fwd``, ``flash_dq``
+    and ``flash_dkv``, with ``_band`` behind each name for a band call;
+    and tracing leaves the static plan in the loop ring, with tile
+    counts that a position-by-position count confirms. The operands are
+    the model's (PR 29): the projection's ``[b, s, 3 * h * d]`` read
+    where it lies, or for ModernBERT (rotary stands between) q, k, v as
+    ``[b, s, h * d]`` each; no operand or result of a call has a head's
+    64 lanes as its minor dimension."""
     import re
 
     import jax
@@ -100,27 +105,44 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     from autodist_tpu.kernels import flash_attention as fa
     from autodist_tpu.parallel.axes import shard_map
 
-    def attend(q, k, v):
-        return fa.flash_attention(q, k, v, causal=causal, interpret=False,
-                                  window=window)
+    b, h, s, d = local_shape
+    packed = s < 8192
 
-    shape, sharding = local_shape, None
+    def attend(*qkv):
+        return fa.flash_attention_merged(
+            qkv[0] if packed else qkv, h, causal=causal, interpret=False,
+            window=window)
+
+    n = 1 if packed else 3
+    shape, sharding = (b, s, (3 if packed else 1) * h * d), None
     if dp > 1:
         mesh = Mesh(np.array(jax.devices()[:dp]), ('data',))
-        attend = shard_map(attend, mesh, (P('data'),) * 3, P('data'))
-        shape = (dp * shape[0],) + shape[1:]
+        attend = shard_map(attend, mesh, (P('data'),) * n, P('data'))
+        shape = (dp * b,) + shape[1:]
         sharding = NamedSharding(mesh, P('data'))
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
     n_before = len(telemetry.get().loop_records())
     exported = jax.export.export(
         jax.jit(jax.value_and_grad(
-            lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
-            argnums=(0, 1, 2))), platforms=['tpu'])(x, x, x)
+            lambda *qkv: jnp.sum(attend(*qkv).astype(jnp.float32)),
+            argnums=tuple(range(n)))), platforms=['tpu'])(*[x] * n)
     text = exported.mlir_module()
     assert text.count('@tpu_custom_call') == 3
     band = '_band' if window else ''
     assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
         'flash_dkv' + band, 'flash_dq' + band, 'flash_fwd' + band]
+    calls = [line for line in text.splitlines() if '@tpu_custom_call' in line]
+    shapes = {t for line in calls
+              for t in re.findall(r'tensor<([0-9x]+)x(?:bf16|f32)>', line)}
+    width = 'x%d' % (h * d)
+    assert shapes == {
+        '%dx%dx%d' % (b, s, 3 * h * d), '%dx%d%s' % (b, s, width),
+        '%dx%dx1x%d' % (b, h, s)} if packed else {
+        '%dx%d%s' % (b, s, width), '%dx%dx1x%d' % (b, h, s)}
+    # dq goes out as the first third of the array dk is then written
+    # into in place: the cotangent of the projection's output is never
+    # concatenated
+    assert sum('output_operand_aliases' in line for line in calls) == packed
 
     plans = [r for r in telemetry.get().loop_records()[n_before:]
              if r['name'] == 'flash.plan']
@@ -130,6 +152,8 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     assert (seq, tags['head_dim'], tags['causal'], tags['window']) == (
         local_shape[2], local_shape[3], causal,
         list(window) if window else None)
+    assert (tags['layout'], tags['lane_block'],
+            tags['heads_per_lane_block']) == ('bsd', 128, 2)
     allowed = np.tril(np.ones((seq, seq), bool)) if causal else \
         np.ones((seq, seq), bool)
     if window:
@@ -178,11 +202,11 @@ _REMAT_BLOCKS = {
 }
 
 
-@pytest.mark.parametrize('case', sorted(_REMAT_BLOCKS))
-def test_remat_block_lowers_with_one_forward_kernel(monkeypatch, case):
-    import collections
-    import re
-
+@functools.lru_cache(maxsize=None)
+def _export_remat_block(case):
+    """``(StableHLO of the step for the TPU, its transformer.remat
+    event, the model's config)`` of one of ``_REMAT_BLOCKS``; exported
+    once for the tests that read it."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -195,8 +219,7 @@ def test_remat_block_lowers_with_one_forward_kernel(monkeypatch, case):
                                                  TransformerLM)
     from autodist_tpu.parallel.axes import ParallelSpec
 
-    monkeypatch.setattr(fa, '_interpret_default', lambda: False)
-    kw, per_chip, seq, dp, want = _REMAT_BLOCKS[case]
+    kw, per_chip, seq, dp, _ = _REMAT_BLOCKS[case]
     cfg = TransformerConfig(**dict(dict(
         vocab=256, dim=1024, n_layers=1, n_heads=16, max_len=seq,
         dtype=jnp.bfloat16, remat=True), **kw))
@@ -206,20 +229,60 @@ def test_remat_block_lowers_with_one_forward_kernel(monkeypatch, case):
     batch = {name: np.zeros((dp * per_chip, seq), np.int32)
              for name in ('tokens', 'targets')}
     n_before = len(telemetry.get().loop_records())
-    step = tr._ensure_step(tr._step_key(batch), state, batch)
-    shapes = jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        batch, tr.batch_sharding(batch))
-    text = jax.export.export(step, platforms=['tpu'])(
-        state, shapes).mlir_module()
-    assert collections.Counter(
-        re.findall(r'kernel_name = "(\w+)"', text)) == want
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, '_interpret_default', lambda: False)
+        step = tr._ensure_step(tr._step_key(batch), state, batch)
+        shapes = jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            batch, tr.batch_sharding(batch))
+        text = jax.export.export(step, platforms=['tpu'])(
+            state, shapes).mlir_module()
     event = [r['tags'] for r in telemetry.get().loop_records()[n_before:]
              if r['name'] == 'transformer.remat'][0]
+    return text, event, cfg
+
+
+@pytest.mark.parametrize('case', sorted(_REMAT_BLOCKS))
+def test_remat_block_lowers_with_one_forward_kernel(case):
+    import collections
+    import re
+
+    _, per_chip, seq, _, want = _REMAT_BLOCKS[case]
+    text, event, cfg = _export_remat_block(case)
+    assert collections.Counter(
+        re.findall(r'kernel_name = "(\w+)"', text)) == want
     # o in bf16 and lse in f32, of a chip's share of the batch
     assert event['layers'] == cfg.n_layers
     assert event['saved_bytes_per_layer'] == per_chip * seq * (
         1024 * 2 + 16 * 4)
+
+
+@pytest.mark.parametrize('case', sorted(_REMAT_BLOCKS))
+def test_remat_block_holds_no_tensor_by_head(case):
+    """PR 29: the kernels take the projection's ``[b, s, 3 * h * d]`` and
+    give the output projection its ``[b, s, h * d]``, so a block under
+    remat, forward and backward, transposes no 4-D tensor (q, k, v, do
+    into ``[b, h, s, d]``, o, dq, dk, dv out of it) and holds no tensor
+    of the batch whose minor dimension is a head's 64 lanes, which HBM
+    pads to 128; rotary positions (ModernBERT) included, and ``delta``,
+    which ``flash_dq`` computes from the merged ``do`` and ``o``."""
+    import re
+
+    kw, per_chip, _, dp, _ = _REMAT_BLOCKS[case]
+    text, _, _ = _export_remat_block(case)
+    types = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|ui16|i1)>',
+                           text))
+    by_head = [t for t in types if t.endswith('x64')]
+    assert types and not [t for t in by_head if t.split('x')[0] in (
+        str(per_chip), str(dp * per_chip))], by_head
+    if kw.get('positions') != 'rotary':
+        # (the tables of rotary positions are made a head wide, [s, 64],
+        # and repeated along the lanes: nothing of the batch's size)
+        assert not by_head
+    transposed = [re.search(r'-> tensor<([0-9x]+)x', line).group(1)
+                  for line in text.splitlines()
+                  if 'stablehlo.transpose' in line]
+    assert not [t for t in transposed if t.count('x') >= 3], transposed
 
 
 # The Mosaic modules of the existing flash cells' kernels with their
@@ -228,17 +291,22 @@ def test_remat_block_lowers_with_one_forward_kernel(monkeypatch, case):
 # A PR that means to leave these cells' kernels alone (PR 26 added the
 # band path beside them) leaves these as they are; one that changes the
 # kernels replaces them, on purpose. jax 0.9.0's lowering.
+# PR 29 replaced all nine: the kernels work on [b, s, heads * head_dim]
+# now, two heads of 64 to a 128-lane block, flash_dq makes delta, and the
+# two cells' calls are the model's (q, k, v read out of the projection's
+# one [b, s, 3 * h * d], dk written into dq's array in place).
 _KERNEL_MODULES = {
     's512': ((96, 16, 512, 64), False, {
-        'flash_fwd': 'adfad1b9b9f7d42d', 'flash_dq': '6f8368d491dd60bd',
-        'flash_dkv': '7deaeb00cc4045f0'}),
+        'flash_fwd': 'ce728f40448169bd', 'flash_dq': 'b47e95b84700c249',
+        'flash_dkv': '3668fb4edb515cc6'}),
     's1024_causal': ((32, 16, 1024, 64), True, {
-        'flash_fwd': 'def267ce84890dae', 'flash_dq': '4663e617ae66821d',
-        'flash_dkv': '4a5957399e7a1ef5'}),
-    # chip_smoke.py's shape: the multi-block causal path
+        'flash_fwd': '1e3f526c24fc74e6', 'flash_dq': '7a86f7845564c57f',
+        'flash_dkv': '5db563625dce051b'}),
+    # chip_smoke.py's shape and call (flash_attention on [b, h, s, d]):
+    # the multi-block causal path
     's4096_causal': ((2, 12, 4096, 64), True, {
-        'flash_fwd': '77c1c8692a72f861', 'flash_dq': 'fd32b0dd300c76b7',
-        'flash_dkv': '15bd8df0d5f02377'}),
+        'flash_fwd': '3ab6d97b0522dfbf', 'flash_dq': '2727da750cfd69fe',
+        'flash_dkv': 'd0b2442aa14f6839'}),
 }
 
 
@@ -256,11 +324,21 @@ def test_full_call_kernels_are_op_for_op_what_they_were(case):
     from autodist_tpu.kernels import flash_attention as fa
 
     shape, causal, want = _KERNEL_MODULES[case]
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    b, h, s, d = shape
+    if case == 's4096_causal':
+        def attend(q, k, v):
+            return fa.flash_attention(q, k, v, causal=causal,
+                                      interpret=False)
+        args = [jax.ShapeDtypeStruct(shape, jnp.bfloat16)] * 3
+    else:
+        def attend(qkv):
+            return fa.flash_attention_merged(qkv, h, causal=causal,
+                                             interpret=False)
+        args = [jax.ShapeDtypeStruct((b, s, 3 * h * d), jnp.bfloat16)]
     text = jax.export.export(jax.jit(jax.value_and_grad(
-        lambda q, k, v: jnp.sum(fa.flash_attention(
-            q, k, v, causal=causal, interpret=False).astype(jnp.float32)),
-        argnums=(0, 1, 2))), platforms=['tpu'])(x, x, x).mlir_module()
+        lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
+        argnums=tuple(range(len(args))))), platforms=['tpu'])(
+            *args).mlir_module()
     context = jax_mlir.make_ir_context()
     context.allow_unregistered_dialects = True
     got = {}

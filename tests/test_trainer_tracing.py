@@ -73,9 +73,21 @@ def test_kernel_names_reach_the_tpu_lowering(monkeypatch):
     tr = Trainer(TransformerLM(cfg), optax.sgd(0.1), spec=ParallelSpec(dp=1))
     state = tr.init(jax.random.PRNGKey(0))
     batch = batch_of(512)
+    n_before = len(telemetry.get().loop_records())
     step = tr._ensure_step(tr._step_key(batch), state, batch)
     module = jax.export.export(step, platforms=['tpu'])(
         state, tr.shard_batch(batch)).mlir_module()
+    # each trace of a call leaves its static plan in the loop ring; since
+    # PR 29 with the layout the kernels work on, [b, s, heads * head_dim],
+    # and how the heads sit in its lanes (four heads of 16 are under 128
+    # lanes in all: one block of 64)
+    plans = [r['tags'] for r in telemetry.get().loop_records()[n_before:]
+             if r['name'] == 'flash.plan']
+    assert plans and all(
+        (t['layout'], t['lane_block'], t['heads_per_lane_block'],
+         t['head_dim'], t['seq']) == ('bsd', 64, 4, 16, 512) for t in plans)
+    assert all(t[k + 'heads_per_step'] == 4
+               for t in plans for k in ('', 'dq_', 'dkv_'))
     calls = [line for line in module.splitlines() if 'tpu_custom_call' in line]
     # forward, dq, dkv: 4 until PR 27, when the block's checkpoint began
     # to keep the forward kernel's o and lse and the backward stopped
