@@ -87,6 +87,31 @@ and the local head count), one body per kernel:
   folded into ``q`` ([bq, d]) instead of multiplying every [bq, bk]
   tile, which is exact in any binary float format; any other scale
   stays on the tile.
+* **Rotary positions** (``flash_attention_merged(..., rotary=(cos,
+  sin))``; ModernBERT's layers): the tables of :func:`rotary_tables`,
+  ``[seq, lanes of a lane block]`` f32 (a head's ``[seq, d]`` repeated
+  over the block's heads; 4 MB each at seq 8192), are operands of each
+  of the six calls, each twice: blocked ``(rows, lanes)`` by the row
+  index maps of the q and of the k operand (``_outer``, ``_kv_row``,
+  ``_band_fetch``, whatever the batch and the head group). Every body
+  rotates the q and k blocks it loads, on the tile in VMEM and before
+  anything else touches them (:func:`_reads`): ``x cos + turn(x) sin``
+  in f32, rounded ONCE to the operands' dtype, then the scale's fold
+  and the other heads' mask as without tables. ``turn`` is the
+  rotate-half of each head's ``d`` lanes inside the lane block
+  (:func:`_turn`: two ``pltpu.roll`` along the lanes, a select on
+  ``lane % d < d / 2``, the sign; no lane slice). ``flash_fwd``
+  rotates q and k; ``flash_dq`` rotates q and k, accumulates the
+  gradient w.r.t. the ROTATED q and turns it back (``dq cos - turn(dq)
+  sin``, the rotation's transpose) from the f32 accumulator before its
+  one store; ``flash_dkv`` rotates k and q and turns dk back the same
+  way; v, ``do``, ``o``, dv, ``lse`` and ``delta`` never meet the
+  tables. So the model hands over the projection's ``[b, s, 3 * h *
+  d]`` as it is, rotary or not, the cotangent is one array written in
+  place, and the step holds no rotated copy of q and k. Chosen by a
+  Python ``if`` on whether tables were handed in: without them nothing
+  of it is on the path (the table-less calls' Mosaic modules are
+  pinned op for op in ``tests/test_tpu_bringup.py``).
 
 Where the time goes at head_dim 64 on a v5e (PERF.md §6, PR 25 and 29):
 QK^T contracts over one head's 64 lanes and PV yields one head's 64
@@ -509,6 +534,78 @@ def _place(rest, x, keep):
 
 
 # ---------------------------------------------------------------------------
+# rotary positions on the tile
+# ---------------------------------------------------------------------------
+
+def _turn(x, d):
+    """The rotate-half of each head's ``d`` lanes of ``x [rows, lanes]``,
+    ``cat(-x2, x1)`` with ``x1, x2`` the head's halves: two rolls along
+    the lanes (a lower half takes what lies ``d / 2`` above it, an upper
+    half what lies below), a select on the lane and the sign. No lane
+    slice, so the heads of a lane block turn together."""
+    lanes, half = x.shape[-1], d // 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return jnp.where(lane % d < half, -pltpu.roll(x, lanes - half, 1),
+                     pltpu.roll(x, half, 1))
+
+
+def _rotate(x, cos, sin, d, back=False):
+    """``x [rows, lanes]`` turned by the angles of the position tables'
+    blocks ``cos``, ``sin [rows, lanes]``: ``x cos + turn(x) sin`` in
+    f32, which the caller rounds once. ``back`` turns by the negative
+    angle, ``x cos - turn(x) sin``: the transpose of the rotation
+    (``turn^T = -turn``, and the tables repeat across a head's halves),
+    which takes the gradient w.r.t. a rotated q or k to that of q or k."""
+    x = x.astype(jnp.float32)
+    turned = _turn(x, d) * sin
+    return x * cos - turned if back else x * cos + turned
+
+
+def _reads(ref, tables, d):
+    """``read(rows, cols)`` of the block ``ref[0, rows, cols]`` of q or
+    k: as it lies, or with position ``tables`` (the ``(cos, sin)`` refs
+    blocked by the operand's rows) rotated on the tile, in f32, rounded
+    once to the operand's dtype, before anything else touches it (the
+    scale's fold, the other heads' mask). A lane block's heads read the
+    same block: it is rotated once for the life of this ``read``, which
+    a caller makes inside the branch that uses it."""
+    if tables is None:
+        return lambda rows, cols: ref[0, rows, cols]
+    cos_ref, sin_ref = tables
+    seen = {}
+
+    def read(rows, cols):
+        at = (rows.start, rows.stop, cols.start)
+        if at not in seen:
+            x = ref[0, rows, cols]
+            seen[at] = _rotate(x, cos_ref[rows, :], sin_ref[rows, :],
+                               d).astype(x.dtype)
+        return seen[at]
+    return read
+
+
+def _reads_qk(q_ref, k_ref, tables, d):
+    """:func:`_reads` of q and of k; ``tables``: None, or the table refs
+    blocked by q's rows and those blocked by k's."""
+    q_tables, k_tables = tables or (None, None)
+    return _reads(q_ref, q_tables, d), _reads(k_ref, k_tables, d)
+
+
+def _turned_back(dx, tables, d):
+    """The finished f32 gradient w.r.t. a rotated q or k block turned
+    back to that of q or k, before the one rounding of its store;
+    ``tables``: None (nothing was rotated), or the ``(cos, sin)`` refs
+    blocked by the block's rows."""
+    if tables is None:
+        return dx
+    cos_ref, sin_ref = tables
+    return _rotate(dx, cos_ref[...], sin_ref[...], d, back=True)
+
+
+_ALL = slice(None)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -524,22 +621,24 @@ def _inner_block(outer, j, size, inner_size, window, transposed=False):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
-                n_inner):
+                n_inner, tables=None):
     qi, j = pl.program_id(2), pl.program_id(3)
     ki = _inner_block(qi, j, bq, bk, window)
     blocks = _lane_blocks(g, d, lanes)
 
-    def query(cols, keep):
-        q = q_ref[0, :, cols]                                 # [bq, lanes]
+    def query(read_q, cols, keep):
+        q = read_q(_ALL, cols)                                # [bq, lanes]
         return _only(q * sm_scale if fold else q, keep)
 
     def rows(parts):
         # plain softmax over the live keys of the row, by ranges
         for cols, heads in blocks:
+            read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
             o = None
             for h, keep in heads:
-                q = query(cols, keep)
-                ss = [_scores(q, k_ref[0, lo:hi, cols], sm_scale, fold, mask)
+                q = query(read_q, cols, keep)
+                ss = [_scores(q, read_k(slice(lo, hi), cols), sm_scale, fold,
+                              mask)
                       for lo, hi, mask in parts]
                 m = functools.reduce(jnp.maximum, [
                     jnp.max(s, axis=1, keepdims=True) for s in ss])
@@ -571,11 +670,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         # every key and l and acc hold finite rubbish, which the first
         # real max wipes out (alpha = exp(NEG_INF - m) = 0).
         for c, (cols, heads) in enumerate(blocks):
+            read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
             v = v_ref[0, :, cols]
             alphas = pv = None
             for h, keep in heads:
-                s = _scores(query(cols, keep), k_ref[0, :, cols], sm_scale,
-                            fold, mask)
+                s = _scores(query(read_q, cols, keep), read_k(_ALL, cols),
+                            sm_scale, fold, mask)
                 m_prev = m_scr[h]                             # [bq, 1]
                 m_new = jnp.maximum(m_prev,
                                     jnp.max(s, axis=1, keepdims=True))
@@ -688,6 +788,25 @@ def _outer(i, j):
     return i
 
 
+# Rotary position tables (``cos``, ``sin``: ``[s, lanes of a lane block]``
+# f32, a head's table repeated over the block's heads) are operands of a
+# call that is given them: each twice, blocked by the row index maps of
+# the q and of the k operand, whatever the batch and the head group.
+
+def _table_specs(lanes, *blockings):
+    """Specs of ``cos`` and ``sin`` for each ``(rows, row_of)``."""
+    return [pl.BlockSpec((rows, lanes),
+                         lambda b, h, i, j, row_of=row_of: (row_of(i, j), 0))
+            for rows, row_of in blockings for _ in range(2)]
+
+
+def _tabled(kernel, at, *refs):
+    """``kernel`` with the four table refs that start at ``at`` (cos and
+    sin by q's rows, cos and sin by k's) as its ``tables``."""
+    return kernel(*refs[:at], *refs[at + 4:],
+                  tables=(refs[at:at + 2], refs[at + 2:at + 4]))
+
+
 def _kv_row(causal, bq, bk, window=None, seq=None):
     """Row block of K/V at step (i, j) of a (b, h, qi, ki) grid. A dead
     causal tile asks for the last live block of its row again, which the
@@ -700,25 +819,32 @@ def _kv_row(causal, bq, bk, window=None, seq=None):
     return lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
 
 
-def _fwd(qkv, heads, causal, sm_scale, blocks, interpret, window=None):
+def _fwd(qkv, tables, heads, causal, sm_scale, blocks, interpret,
+         window=None):
     bq, bk, g = blocks
     ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
     b, s, _ = q.shape
     nk = s // bk
     kv_row = _kv_row(causal, bq, bk, window, s)
+    kernel = _static(_fwd_kernel, s, heads, d, causal, sm_scale, blocks,
+                     window)
+    in_specs = [_rows_spec(bq, width, _outer, q0),
+                _rows_spec(bk, width, kv_row, k0),
+                _rows_spec(bk, width, kv_row, v0)]
+    operands = (q, k, v)
+    if tables is not None:
+        kernel = functools.partial(_tabled, kernel, len(operands))
+        in_specs += _table_specs(lanes, (bq, _outer), (bk, kv_row))
+        operands += tuple(tables) * 2
     scratch = [] if nk == 1 else [
         pltpu.VMEM((width // lanes, bq, lanes), jnp.float32),
         pltpu.VMEM((g, bq, 1), jnp.float32),
         pltpu.VMEM((g, bq, 1), jnp.float32),
     ]
     o, lse = pl.pallas_call(
-        _static(_fwd_kernel, s, heads, d, causal, sm_scale, blocks, window),
+        kernel,
         grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
-        in_specs=[
-            _rows_spec(bq, width, _outer, q0),
-            _rows_spec(bk, width, kv_row, k0),
-            _rows_spec(bk, width, kv_row, v0),
-        ],
+        in_specs=in_specs,
         out_specs=[
             _rows_spec(bq, width, _outer),
             _stat_spec(g, bq, _outer),
@@ -731,7 +857,7 @@ def _fwd(qkv, heads, causal, sm_scale, blocks, interpret, window=None):
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=_name('flash_fwd', window),
-    )(q, k, v)
+    )(*operands)
     return o, lse
 
 
@@ -741,7 +867,7 @@ def _fwd(qkv, heads, causal, sm_scale, blocks, interpret, window=None):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
                *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes,
-               window, n_inner):
+               window, n_inner, tables=None):
     qi, j = pl.program_id(2), pl.program_id(3)
     ki = _inner_block(qi, j, bq, bk, window)
     blocks = _lane_blocks(g, d, lanes)
@@ -756,17 +882,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
         return delta
 
     def grad(cols, heads, parts, delta_of=delta_of):
-        """dq of a lane block's heads from the key ranges ``parts``."""
+        """dq of a lane block's heads from the key ranges ``parts``;
+        with position tables, w.r.t. the rotated q."""
+        read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
         dqs = None
         for h, keep in heads:
-            q = q_ref[0, :, cols]
+            q = read_q(_ALL, cols)
             q = _only(q * sm_scale if fold else q, keep)
             do = _only(do_ref[0, :, cols], keep)
             lse = _to_col(lse_ref[0, h])                      # [bq, 1]
             delta = delta_of(cols, h, keep)
             dq = 0.
             for lo, hi, mask in parts:
-                k = k_ref[0, lo:hi, cols]
+                k = read_k(slice(lo, hi), cols)
                 s = _scores(q, k, sm_scale, fold, mask)
                 p = jnp.exp(s - lse)                          # [bq, keys]
                 dp = _dot(do, v_ref[0, lo:hi, cols], _NT)
@@ -778,8 +906,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
         return dqs
 
     def finish(dq):
-        # a folded scale multiplied q, not the tile: give dq its factor
-        return (dq * sm_scale if fold else dq).astype(dq_ref.dtype)
+        # a folded scale multiplied q, not the tile: give dq its factor;
+        # and the gradient w.r.t. a rotated q is turned back, from the
+        # f32 accumulator, before the one rounding of the store
+        dq = dq * sm_scale if fold else dq
+        return _turned_back(dq, tables and tables[0], d).astype(dq_ref.dtype)
 
     def rows(parts):
         for cols, heads in blocks:
@@ -817,7 +948,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *scratch,
                 sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
-                n_inner):
+                n_inner, tables=None):
     ki, j = pl.program_id(2), pl.program_id(3)
     qi = _inner_block(ki, j, bk, bq, window, transposed=True)
     blocks = _lane_blocks(g, d, lanes)
@@ -825,14 +956,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def grads(cols, heads, parts):
         """(dk, dv) of a lane block's heads from the query ranges
         ``parts``, on TRANSPOSED tiles [bk, queries]: lse and delta are
-        rows of them, and both gradients plain matmuls."""
+        rows of them, and both gradients plain matmuls. With position
+        tables dk is w.r.t. the rotated k."""
+        read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
         dks = dvs = None
         for h, keep in heads:
-            k = _only(k_ref[0, :, cols], keep)
+            k = _only(read_k(_ALL, cols), keep)
             v = _only(v_ref[0, :, cols], keep)
             dk = dv = 0.
             for lo, hi, mask in parts:
-                q = q_ref[0, lo:hi, cols]
+                q = read_q(slice(lo, hi), cols)
                 if fold:
                     q = q * sm_scale   # dk = ds^T (q * scale) as well
                 do = do_ref[0, lo:hi, cols]
@@ -847,10 +980,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dks, dvs = _place(dks, dk, keep), _place(dvs, dv, keep)
         return dks, dvs
 
+    def finish(dk):
+        return _turned_back(dk, tables and tables[1], d).astype(dk_ref.dtype)
+
     def rows(parts):
         for cols, heads in blocks:
             dk, dv = grads(cols, heads, parts)
-            dk_ref[0, :, cols] = dk.astype(dk_ref.dtype)
+            dk_ref[0, :, cols] = finish(dk)
             dv_ref[0, :, cols] = dv.astype(dv_ref.dtype)
 
     if nq == 1:
@@ -877,11 +1013,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(j == n_inner - 1)
     def _emit():
         for c, (cols, _) in enumerate(blocks):
-            dk_ref[0, :, cols] = dk_scr[c].astype(dk_ref.dtype)
+            dk_ref[0, :, cols] = finish(dk_scr[c])
             dv_ref[0, :, cols] = dv_scr[c].astype(dv_ref.dtype)
 
 
-def _dq(qkv, do, o, lse, heads, causal, sm_scale, blocks, interpret,
+def _dq(qkv, tables, do, o, lse, heads, causal, sm_scale, blocks, interpret,
         window=None):
     """``(dq, delta)``: ``delta = rowsum(dO * O)`` of each head is
     computed here, from the two merged tensors a block at a time, and
@@ -891,13 +1027,20 @@ def _dq(qkv, do, o, lse, heads, causal, sm_scale, blocks, interpret,
     b, s, _ = do.shape
     kv_row = _kv_row(causal, bq, bk, window, s)
     q_spec, row_spec = _rows_spec(bq, width, _outer), _stat_spec(g, bq, _outer)
+    kernel = _static(_dq_kernel, s, heads, d, causal, sm_scale, blocks, window)
+    in_specs = [_rows_spec(bq, width, _outer, q0),
+                _rows_spec(bk, width, kv_row, k0),
+                _rows_spec(bk, width, kv_row, v0),
+                q_spec, q_spec, row_spec]
+    operands = (q, k, v, do, o, lse)
+    if tables is not None:
+        kernel = functools.partial(_tabled, kernel, len(operands))
+        in_specs += _table_specs(lanes, (bq, _outer), (bk, kv_row))
+        operands += tuple(tables) * 2
     return pl.pallas_call(
-        _static(_dq_kernel, s, heads, d, causal, sm_scale, blocks, window),
+        kernel,
         grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
-        in_specs=[_rows_spec(bq, width, _outer, q0),
-                  _rows_spec(bk, width, kv_row, k0),
-                  _rows_spec(bk, width, kv_row, v0),
-                  q_spec, q_spec, row_spec],
+        in_specs=in_specs,
         # dq as q is held: an array of its own, or the first third of
         # one (whose other columns this call leaves unwritten)
         out_specs=[q_spec, row_spec],
@@ -909,11 +1052,11 @@ def _dq(qkv, do, o, lse, heads, causal, sm_scale, blocks, interpret,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name=_name('flash_dq', window),
-    )(q, k, v, do, o, lse)
+    )(*operands)
 
 
-def _dkv(qkv, do, lse, delta, heads, causal, sm_scale, blocks, interpret,
-         window=None, dqkv=None):
+def _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale, blocks,
+         interpret, window=None, dqkv=None):
     """``(dk, dv)``; or with ``dqkv``, the ``[b, s, 3 * heads * d]``
     array whose first third ``flash_dq`` wrote, ``(dqkv, dv)``: dk goes
     into the second third of that array, in place."""
@@ -941,6 +1084,10 @@ def _dkv(qkv, do, lse, delta, heads, causal, sm_scale, blocks, interpret,
                 _rows_spec(bk, width, _outer, v0),
                 _rows_spec(bq, width, q_row), row_spec, row_spec]
     operands = (q, k, v, do, lse, delta)
+    if tables is not None:
+        kernel = functools.partial(_tabled, kernel, len(operands))
+        in_specs += _table_specs(lanes, (bq, q_row), (bk, _outer))
+        operands += tuple(tables) * 2
     if dqkv is None:
         dk, aliases = jax.ShapeDtypeStruct((b, s, hd), k.dtype), {}
     else:
@@ -983,42 +1130,48 @@ def _without(kernel, i, *refs):
 CHECKPOINT_NAMES = ('flash_o', 'flash_lse')
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
-def _flash(qkv, heads, causal, sm_scale, plan, interpret, window=None,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7, 8))
+def _flash(qkv, tables, heads, causal, sm_scale, plan, interpret, window=None,
            named=False):
     """``qkv``: a tuple of q, k, v as ``[b, s, heads * d]`` each, or of
     the one ``[b, s, 3 * heads * d]`` that holds them side by side;
+    ``tables``: None, or the rotary positions' ``(cos, sin)``;
     ``o [b, s, heads * d]``."""
-    return _fwd(qkv, heads, causal, sm_scale, plan.fwd, interpret, window)[0]
+    return _fwd(qkv, tables, heads, causal, sm_scale, plan.fwd, interpret,
+                window)[0]
 
 
-def _flash_fwd(qkv, heads, causal, sm_scale, plan, interpret, window, named):
-    o, lse = _fwd(qkv, heads, causal, sm_scale, plan.fwd, interpret, window)
+def _flash_fwd(qkv, tables, heads, causal, sm_scale, plan, interpret, window,
+               named):
+    o, lse = _fwd(qkv, tables, heads, causal, sm_scale, plan.fwd, interpret,
+                  window)
     if named:
         # both as the kernel writes them: o lane-dense, what the output
         # projection reads; lse [b, h, 1, s], which XLA tiles T(1, 128)
         # in a stack of them (the unit dimension pads nothing)
         o = checkpoint_name(o, CHECKPOINT_NAMES[0])
         lse = checkpoint_name(lse, CHECKPOINT_NAMES[1])
-    return o, (qkv, o, lse)
+    return o, (qkv, tables, o, lse)
 
 
 def _flash_bwd(heads, causal, sm_scale, plan, interpret, window, named, res,
                do):
-    qkv, o, lse = res
-    dq, delta = _dq(qkv, do, o, lse, heads, causal, sm_scale, plan.dq,
+    qkv, tables, o, lse = res
+    # (the position tables are constants: the None beside the cotangent
+    # of qkv is theirs)
+    dq, delta = _dq(qkv, tables, do, o, lse, heads, causal, sm_scale, plan.dq,
                     interpret, window)
     if len(qkv) == 3:
-        dk, dv = _dkv(qkv, do, lse, delta, heads, causal, sm_scale, plan.dkv,
-                      interpret, window)
-        return ((dq, dk, dv),)
+        dk, dv = _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale,
+                      plan.dkv, interpret, window)
+        return (dq, dk, dv), None
     # the cotangent of one array that holds q, k and v is one array: dq
     # is its first third as flash_dq returns it, flash_dkv writes dk into
     # the second in place, and dv is written over the last
-    dqkv, dv = _dkv(qkv, do, lse, delta, heads, causal, sm_scale, plan.dkv,
-                    interpret, window, dqkv=dq)
-    return ((jax.lax.dynamic_update_slice_in_dim(
-        dqkv, dv, 2 * dv.shape[-1], axis=2),),)
+    dqkv, dv = _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale,
+                    plan.dkv, interpret, window, dqkv=dq)
+    return (jax.lax.dynamic_update_slice_in_dim(
+        dqkv, dv, 2 * dv.shape[-1], axis=2),), None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1102,13 +1255,13 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     """
     b, h, s, d = q.shape
     o = _planned(tuple(jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, h * d)
-                       for x in (q, k, v)), h, causal, sm_scale, block_q,
-                 block_k, interpret, window, named=False)
+                       for x in (q, k, v)), None, h, causal, sm_scale,
+                 block_q, block_k, interpret, window, named=False)
     return jnp.transpose(o.reshape(b, s, h, d), (0, 2, 1, 3))
 
 
 def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
-                           interpret=None, window=None):
+                           interpret=None, window=None, rotary=None):
     """:func:`flash_attention` in the kernels' own layout, which is the
     model's: ``qkv`` is the qkv projection's output
     ``[batch, seq, 3 * heads * head_dim]`` (q, k and v side by side,
@@ -1116,6 +1269,15 @@ def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
     ``[batch, seq, heads * head_dim]`` each, and the output is
     ``[batch, seq, heads * head_dim]``, what the output projection
     takes. Nothing is transposed, copied or padded on the way in or out.
+
+    ``rotary = (cos, sin)`` puts rotary positions on q and k inside the
+    kernels: the tables of :func:`rotary_tables`, ``[seq, lane block]``
+    in f32. Every kernel then rotates the q and k blocks it loads, on
+    the tile, as ``x cos + turn(x) sin`` in f32 rounded once to the
+    operands' dtype, ``flash_dq`` and ``flash_dkv`` turn dq and dk back,
+    and the cotangent is that of the unrotated ``qkv``: the call is
+    ``flash_attention_merged`` of the rotated q and k, without their
+    copies. With ``rotary=None`` nothing of it is on the path.
 
     The forward rule's residuals are named for a checkpoint policy
     (``CHECKPOINT_NAMES``): that output, and ``lse``. Under
@@ -1125,8 +1287,22 @@ def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
     keeps. Without such a policy the names mean nothing."""
     if not isinstance(qkv, (tuple, list)):
         qkv = (qkv,)
-    return _planned(tuple(qkv), heads, causal, sm_scale, None, None,
+    return _planned(tuple(qkv), rotary, heads, causal, sm_scale, None, None,
                     interpret, window, named=True)
+
+
+def rotary_tables(positions, theta, heads, head_dim):
+    """``(cos, sin)`` of rotary positions for :func:`flash_attention_merged`
+    on ``heads`` (local) heads of ``head_dim``: ``[len(positions), lanes
+    of a lane block]`` in f32, the head's table (``inv_freq_j = theta **
+    (-2j / head_dim)``, the rotate-half convention: both halves of a head
+    carry the same angles) repeated over the heads of a lane block."""
+    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                         / head_dim)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    repeats = 2 * _lane_block(heads, head_dim) // head_dim
+    return (jnp.concatenate([jnp.cos(angle)] * repeats, axis=-1),
+            jnp.concatenate([jnp.sin(angle)] * repeats, axis=-1))
 
 
 def saved_bytes(shape, dtype):
@@ -1137,12 +1313,20 @@ def saved_bytes(shape, dtype):
     return b * h * s * (d * jnp.dtype(dtype).itemsize + 4)
 
 
-def _planned(qkv, heads, causal, sm_scale, block_q, block_k, interpret,
-             window, named):
+def _planned(qkv, tables, heads, causal, sm_scale, block_q, block_k,
+             interpret, window, named):
     window = check_window(window, causal)
     b, s, _ = qkv[0].shape
     d = _head_dim(qkv, heads)
     lanes = _lane_block(heads, d)
+    if tables is not None:
+        tables = tuple(tables)
+        if [(t.shape, t.dtype) for t in tables] != [((s, lanes),
+                                                     jnp.float32)] * 2:
+            raise ValueError(
+                'flash_attention: rotary=(cos, sin) must be two f32 [%d, %d] '
+                '(seq, lanes of a lane block: rotary_tables); got %s'
+                % (s, lanes, [(t.shape, str(t.dtype)) for t in tables]))
     if len(qkv) == 1 and lanes % _LANES:
         # a run of columns narrower than the lanes cannot be blocked out
         # of a wider array (models of under 128 lanes in all)
@@ -1158,6 +1342,6 @@ def _planned(qkv, heads, causal, sm_scale, block_q, block_k, interpret,
         fold_scale=_is_pow2(sm_scale),
         window=None if window is None else list(window),
         layout='bsd', lane_block=lanes, heads_per_lane_block=lanes // d,
-        **_plan_tags(plan, s, causal, window))
-    return _flash(qkv, heads, causal, sm_scale, plan, interpret, window,
-                  named)
+        rotary=tables is not None, **_plan_tags(plan, s, causal, window))
+    return _flash(qkv, tables, heads, causal, sm_scale, plan, interpret,
+                  window, named)

@@ -60,11 +60,14 @@ def rotary(x, positions, theta, heads=None):
 class MultiHeadAttention(Module):
     """Causal (or full) self-attention; [batch, seq, embed] in/out.
 
-    ``rope_theta`` (a base) puts rotary positions on q and k; ``window``
-    (keys each side, or ``(left, right)``) keeps a band of the scores
-    and is handed to every attention path that takes one: the flash
-    kernels, their nested-manual route under dp/tp, and the XLA path.
-    The sequence-parallel paths take none and raise."""
+    ``rope_theta`` (a base) puts rotary positions on q and k: inside
+    the flash kernels where they run (``position_tables``: ``cos`` and
+    ``sin`` as two more operands, q and k rotated on the tile), by
+    :func:`rotary` on every other path. ``window`` (keys each side, or
+    ``(left, right)``) keeps a band of the scores and is handed to every
+    attention path that takes one: the flash kernels, their
+    nested-manual route under dp/tp, and the XLA path. The
+    sequence-parallel paths take none and raise."""
 
     def __init__(self, dim, num_heads, head_dim=None, causal=True,
                  dtype=jnp.float32, rope_theta=None, window=None):
@@ -87,7 +90,9 @@ class MultiHeadAttention(Module):
     def param_defs(self):
         return {'qkv': self.wqkv, 'out': self.wo}
 
-    def apply(self, params, x):
+    def apply(self, params, x, tables=None):
+        """``tables``: what ``position_tables`` gives for ``x``, where a
+        caller made it once for many layers; made here otherwise."""
         b, s, _ = x.shape
         h, d = self.num_heads, self.head_dim
         qkv = self.wqkv.apply(params['qkv'], x)          # [b, s, 3hd]
@@ -96,8 +101,10 @@ class MultiHeadAttention(Module):
             # long device-local sequences: the Pallas flash kernels
             # (the [s, s] score matrix never reaches HBM), in the layout
             # the two projections have
-            return self.wo.apply(params['out'],
-                                 self._kernel_attention(qkv, local[1]))
+            if tables is None:
+                tables = self.position_tables((b, h, s, d))
+            return self.wo.apply(
+                params['out'], self._kernel_attention(qkv, local[1], tables))
         qkv = qkv.reshape(b, s, 3, h, d)
         q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))     # [b, h, s, d]
         k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
@@ -131,15 +138,31 @@ class MultiHeadAttention(Module):
         o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
         return self.wo.apply(params['out'], o)
 
-    def _kernel_attention(self, qkv, local_heads):
+    def position_tables(self, shape):
+        """The rotary positions' ``(cos, sin)`` as the flash kernels take
+        them (``fa.rotary_tables``), for q, k, v of ``[b, h, s, d]``
+        ``shape`` in the current trace; None without rotary positions
+        or where attention takes another path than the kernels. They
+        depend on no parameter and no layer: a model makes them once a
+        step for each rotary base and hands them to its layers."""
+        local = self.kernel_shape(shape) if self.rope_theta is not None \
+            else None
+        if local is None:
+            return None
+        with jax.named_scope('rotary'):
+            return fa.rotary_tables(jnp.arange(shape[2]), self.rope_theta,
+                                    local[1], self.head_dim)
+
+    def _kernel_attention(self, qkv, local_heads, tables):
         """``flash_attention_merged`` on the projection's output
         ``qkv [b, s, 3 * h * d]`` (``local_heads`` of the heads on a
-        device), ``[b, s, h * d]`` out, named with
-        ``lse`` for the block's checkpoint policy. The kernels read q, k
-        and v where the projection wrote them; they are taken apart
-        only where something stands between the two: rotary positions,
-        or heads sharded over a mesh axis (a shard's heads are a
-        contiguous run of each of the three, not of ``qkv``).
+        device) and the rotary positions' ``tables`` (or None),
+        ``[b, s, h * d]`` out, named with ``lse`` for the block's
+        checkpoint policy. The kernels read q, k and v where the
+        projection wrote them, and rotate q and k on the tile; the three
+        are taken apart only where heads are sharded over a mesh axis (a
+        shard's heads are a contiguous run of each of the three, not of
+        ``qkv``).
 
         Under a dp/tp GSPMD mesh the call is made on local (batch, head)
         shards, in a nested manual region: GSPMD alone cannot partition
@@ -150,28 +173,23 @@ class MultiHeadAttention(Module):
         through that check). Axes the spec does not name see replicated
         operands, which is what attention inputs are over
         pipe/seq/expert."""
-        h = self.num_heads
         mesh = None if unsharded_execution() else current_mesh()
         heads_axis = live_mesh_axis('heads') if mesh is not None else None
-        operands = (qkv,)
-        if self.rope_theta is not None or heads_axis:
-            operands = q, k, v = tuple(jnp.split(qkv, 3, axis=-1))
-        if self.rope_theta is not None:
-            pos = jnp.arange(qkv.shape[1])
-            operands = (rotary(q, pos, self.rope_theta, heads=h),
-                        rotary(k, pos, self.rope_theta, heads=h), v)
+        operands = tuple(jnp.split(qkv, 3, axis=-1)) if heads_axis else (qkv,)
 
-        def attend(operands):
+        def attend(operands, tables):
             return fa.flash_attention_merged(
                 operands, local_heads, causal=self.causal,
-                window=self.window)
+                window=self.window, rotary=tables)
 
         if mesh is None:
-            return attend(operands)
+            return attend(operands, tables)
         data = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
         spec = P(data, None, heads_axis)
-        return shard_map(attend, mesh, ((spec,) * len(operands),),
-                         spec)(operands)
+        # every shard sees the whole tables: positions are not sharded
+        return shard_map(attend, mesh, ((spec,) * len(operands),
+                                        tables and (P(), P())),
+                         spec)(operands, tables)
 
     def kernel_shape(self, shape):
         """The per-device ``[b, h, s, d]`` that the flash kernels run on

@@ -6,6 +6,7 @@ built TPU-first: bfloat16 matmuls on the MXU, logical-axis sharding for
 DP/TP/SP/EP, ring attention for long context, remat-friendly block
 structure (scan-over-layers so XLA compiles one block).
 """
+import functools
 from dataclasses import dataclass, field
 
 import jax
@@ -199,10 +200,12 @@ class Block(Module):
         return d
 
     @jax.named_scope('block')
-    def apply(self, params, x):
+    def apply(self, params, x, tables=None):
+        """``tables``: the attention's ``position_tables`` for ``x``,
+        where the model made them once for its layers."""
         with jax.named_scope('attention'):
             a = x if self.ln1 is None else self.ln1.apply(params['ln1'], x)
-            x = x + self.attn.apply(params['attn'], a)
+            x = x + self.attn.apply(params['attn'], a, tables)
         # named so remat='save_attn' can keep it while recomputing the rest
         x = checkpoint_name(x, 'attn_out')
         with jax.named_scope('mlp'):
@@ -351,9 +354,31 @@ class TransformerLM(Module):
             x = self.ln_embed.apply(params['ln_embed'], x)
         return constrain(x, ('batch', 'seq', 'embed'))
 
-    def _block_fn(self, block=None):
-        """Single-block apply (``block``: the plain model's by default)
-        with the remat policy applied.
+    def _position_tables(self, x):
+        """``tables(block)``: the rotary positions' ``(cos, sin)`` that
+        ``block``'s attention takes for block input ``x [b, s, dim]``
+        (``MultiHeadAttention.position_tables``; None where it takes
+        none). Called once a trace, outside the layer scan and the
+        blocks' checkpoints, it makes one pair for each rotary base, 4 MB
+        a table at seq 8192, and the layers' functions close over
+        theirs: inside a block the tables would be made, and under remat
+        made again, in every layer."""
+        b, s, _ = x.shape
+        made = {}
+
+        def tables(block):
+            attn = block.attn
+            shape = (b, attn.num_heads, s, attn.head_dim)
+            kind = (attn.rope_theta, attn.kernel_shape(shape))
+            if kind not in made:
+                made[kind] = attn.position_tables(shape)
+            return made[kind]
+        return tables
+
+    def _block_fn(self, block=None, tables=None):
+        """Single-block apply (``block``: the plain model's by default;
+        ``tables``: its ``_position_tables``, which it closes over) with
+        the remat policy applied.
 
         ``cfg.remat``: False (no remat), True (recompute the block in
         the backward, all but the flash kernel's forward call: the
@@ -369,6 +394,8 @@ class TransformerLM(Module):
         """
         cfg = self.cfg
         block_fn = (block or self.block).apply
+        if tables is not None:
+            block_fn = functools.partial(block_fn, tables=tables)
         if isinstance(cfg.remat, str):
             policies = {
                 'save_attn':
@@ -396,7 +423,7 @@ class TransformerLM(Module):
         everything except the lm-head, so losses can chunk the head."""
         cfg = self.cfg
         x = self._embedded(params, tokens)
-        block_fn = self._block_fn()
+        tables = self._position_tables(x)
         aux_total = jnp.zeros((), jnp.float32)
         pipe_axis = manual_axis(AXIS_PIPELINE)
         self._note_layers()
@@ -406,10 +433,14 @@ class TransformerLM(Module):
             from autodist_tpu.parallel.pipeline import gpipe, one_f_one_b
             pipe_fn = one_f_one_b \
                 if ctx_option('pp_schedule', 'gpipe') == '1f1b' else gpipe
-            x, aux_pipe = pipe_fn(block_fn, params['blocks'], x, pipe_axis,
-                                  ctx_option('microbatches', 1))
+            # (a stage's layers make their own tables: the schedules
+            # differentiate the block themselves, and see microbatches)
+            x, aux_pipe = pipe_fn(self._block_fn(), params['blocks'], x,
+                                  pipe_axis, ctx_option('microbatches', 1))
             aux_total = aux_total + aux_pipe
         elif cfg.scan_layers and not self.patterned:
+            block_fn = self._block_fn(tables=tables(self.block))
+
             def body(carry, layer_params):
                 h, aux = carry
                 h, a = block_fn(layer_params, h)
@@ -418,21 +449,23 @@ class TransformerLM(Module):
                 body, (x, aux_total), params['blocks'])
         else:
             for i, block in enumerate(self._lead_blocks):
-                x, a = self._block_fn(block)(params['block_%03d' % i], x)
+                x, a = self._block_fn(block, tables(block))(
+                    params['block_%03d' % i], x)
                 aux_total = aux_total + a
             if cfg.scan_layers:
                 x, aux_total = self._scan_periods(params['blocks'], x,
-                                                  aux_total)
+                                                  aux_total, tables)
         with jax.named_scope('head_loss'):
             x = self.ln_f.apply(params['ln_f'], x)
         return x, aux_total
 
-    def _scan_periods(self, stacks, x, aux_total):
+    def _scan_periods(self, stacks, x, aux_total, tables):
         """``periods`` scan steps over ``stacks[kind]``, each running one
         period's layers in order, each under the remat policy: a kind's
         stack ``[periods * c, ...]`` is seen as ``[periods, c, ...]``
-        and the period's ``c`` layers of that kind index the second."""
-        fns = {kind: self._block_fn(block)
+        and the period's ``c`` layers of that kind index the second.
+        ``tables``: ``_position_tables``."""
+        fns = {kind: self._block_fn(block, tables(block))
                for kind, block in self._kind_blocks.items()}
         per_period = {
             kind: jax.tree.map(

@@ -129,6 +129,39 @@ def test_dp2_tp2_equals_one_device():
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
 
 
+@pytest.mark.parametrize('spec_kw', [dict(dp=1), dict(dp=2),
+                                     dict(dp=2, tp=2)],
+                         ids=['one_device', 'dp2', 'dp2_tp2'])
+def test_rotary_inside_the_kernels_is_rotary_outside_them(monkeypatch,
+                                                          spec_kw):
+    """PR 32: where the flash kernels run, q and k are rotated on the
+    tile from ``cos`` / ``sin`` tables made once a step; everywhere else
+    by ``rotary()``. One step of a patterned model with the kernels on
+    every layer (on one device, on dp shards, and on (batch, head)
+    shards, which see the whole tables and q, k, v apart) moves the
+    parameters as the step that runs XLA's attention does."""
+    from autodist_tpu.kernels import flash_attention as fa
+
+    model = TransformerLM(tiny(n_layers=4))
+    after = {}
+    for crossover in (16, 10 ** 9):
+        monkeypatch.setattr(fa, 'MIN_KERNEL_SEQ', crossover)
+        tr = Trainer(model, optax.sgd(0.1), spec=ParallelSpec(**spec_kw))
+        state = tr.init(jax.random.PRNGKey(0))
+        n_before = len(telemetry.get().loop_records())
+        state, metrics = tr.step(state, batch())
+        plans = [r['tags']['rotary']
+                 for r in telemetry.get().loop_records()[n_before:]
+                 if r['name'] == 'flash.plan']
+        assert (plans and all(plans)) if crossover == 16 else not plans
+        after[crossover] = (float(metrics['loss']),
+                            jax.tree.map(np.asarray, state.params))
+    np.testing.assert_allclose(after[16][0], after[10 ** 9][0], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(after[16][1]),
+                    jax.tree.leaves(after[10 ** 9][1])):
+        np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-4)
+
+
 @pytest.mark.parametrize('spec_kw,complaint', [
     (dict(dp=1, sp=2), 'window .* under sequence parallelism'),
     (dict(dp=1, sp=2, sp_mode='ulysses'),

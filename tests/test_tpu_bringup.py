@@ -91,9 +91,10 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     and tracing leaves the static plan in the loop ring, with tile
     counts that a position-by-position count confirms. The operands are
     the model's (PR 29): the projection's ``[b, s, 3 * h * d]`` read
-    where it lies, or for ModernBERT (rotary stands between) q, k, v as
-    ``[b, s, h * d]`` each; no operand or result of a call has a head's
-    64 lanes as its minor dimension."""
+    where it lies, and for ModernBERT (PR 32) the rotary positions'
+    ``cos`` and ``sin [s, 128]`` beside it, which every shard of a dp
+    mesh sees whole; no operand or result of a call has a head's 64
+    lanes as its minor dimension."""
     import re
 
     import jax
@@ -106,26 +107,30 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     from autodist_tpu.parallel.axes import shard_map
 
     b, h, s, d = local_shape
-    packed = s < 8192
+    rotary = s == 8192
 
-    def attend(*qkv):
+    def attend(qkv, *tables):
         return fa.flash_attention_merged(
-            qkv[0] if packed else qkv, h, causal=causal, interpret=False,
-            window=window)
+            qkv, h, causal=causal, interpret=False, window=window,
+            rotary=tables or None)
 
-    n = 1 if packed else 3
-    shape, sharding = (b, s, (3 if packed else 1) * h * d), None
+    shape, sharding, whole = (b, s, 3 * h * d), None, None
     if dp > 1:
         mesh = Mesh(np.array(jax.devices()[:dp]), ('data',))
-        attend = shard_map(attend, mesh, (P('data'),) * n, P('data'))
+        attend = shard_map(attend, mesh, (P('data'),) + 2 * rotary * (P(),),
+                           P('data'))
         shape = (dp * b,) + shape[1:]
-        sharding = NamedSharding(mesh, P('data'))
+        sharding, whole = (NamedSharding(mesh, spec)
+                           for spec in (P('data'), P()))
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    tables = 2 * rotary * (
+        jax.ShapeDtypeStruct((s, 128), jnp.float32, sharding=whole),)
     n_before = len(telemetry.get().loop_records())
     exported = jax.export.export(
         jax.jit(jax.value_and_grad(
-            lambda *qkv: jnp.sum(attend(*qkv).astype(jnp.float32)),
-            argnums=tuple(range(n)))), platforms=['tpu'])(*[x] * n)
+            lambda qkv, *tables: jnp.sum(
+                attend(qkv, *tables).astype(jnp.float32)))),
+        platforms=['tpu'])(x, *tables)
     text = exported.mlir_module()
     assert text.count('@tpu_custom_call') == 3
     band = '_band' if window else ''
@@ -134,15 +139,13 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     calls = [line for line in text.splitlines() if '@tpu_custom_call' in line]
     shapes = {t for line in calls
               for t in re.findall(r'tensor<([0-9x]+)x(?:bf16|f32)>', line)}
-    width = 'x%d' % (h * d)
     assert shapes == {
-        '%dx%dx%d' % (b, s, 3 * h * d), '%dx%d%s' % (b, s, width),
-        '%dx%dx1x%d' % (b, h, s)} if packed else {
-        '%dx%d%s' % (b, s, width), '%dx%dx1x%d' % (b, h, s)}
+        '%dx%dx%d' % (b, s, 3 * h * d), '%dx%dx%d' % (b, s, h * d),
+        '%dx%dx1x%d' % (b, h, s)} | ({'%dx128' % s} if rotary else set())
     # dq goes out as the first third of the array dk is then written
     # into in place: the cotangent of the projection's output is never
     # concatenated
-    assert sum('output_operand_aliases' in line for line in calls) == packed
+    assert sum('output_operand_aliases' in line for line in calls) == 1
 
     plans = [r for r in telemetry.get().loop_records()[n_before:]
              if r['name'] == 'flash.plan']
@@ -153,7 +156,8 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
         local_shape[2], local_shape[3], causal,
         list(window) if window else None)
     assert (tags['layout'], tags['lane_block'],
-            tags['heads_per_lane_block']) == ('bsd', 128, 2)
+            tags['heads_per_lane_block'], tags['rotary']) == (
+                'bsd', 128, 2, rotary)
     allowed = np.tril(np.ones((seq, seq), bool)) if causal else \
         np.ones((seq, seq), bool)
     if window:
@@ -196,7 +200,8 @@ _REMAT_BLOCKS = {
     # (window, window, global)
     's8192_global_and_band': (
         dict(causal=False, positions='rotary', window=64, global_every=3,
-             n_layers=4, embed_norm=True), 4, 8192, 1,
+             rope_theta=160000.0, window_rope_theta=10000.0, n_layers=4,
+             embed_norm=True), 4, 8192, 1,
         {'flash_fwd': 2, 'flash_dq': 2, 'flash_dkv': 2,
          'flash_fwd_band': 2, 'flash_dq_band': 2, 'flash_dkv_band': 2}),
 }
@@ -263,26 +268,51 @@ def test_remat_block_holds_no_tensor_by_head(case):
     give the output projection its ``[b, s, h * d]``, so a block under
     remat, forward and backward, transposes no 4-D tensor (q, k, v, do
     into ``[b, h, s, d]``, o, dq, dk, dv out of it) and holds no tensor
-    of the batch whose minor dimension is a head's 64 lanes, which HBM
-    pads to 128; rotary positions (ModernBERT) included, and ``delta``,
-    which ``flash_dq`` computes from the merged ``do`` and ``o``."""
+    whose minor dimension is a head's 64 lanes, which HBM pads to 128;
+    ``delta`` included, which ``flash_dq`` computes from the merged
+    ``do`` and ``o``.
+
+    PR 32: rotary positions (ModernBERT) are inside the kernels, so
+    every cell's calls are alike: the projection's ``[b, s, 3 * h * d]``
+    goes into the kernels as it is (no slice of q, k or v out of it)
+    and its cotangent comes back as one array that ``flash_dkv`` writes
+    in place (no concatenate of dq, dk, dv). What is left under the
+    ``rotary`` scope is the making of the ``cos`` / ``sin`` tables
+    ``[s, 128]``, outside the layers: no matmul (``rotary()`` turned
+    q and k by a ``[1024, 1024]`` signed permutation, 168 times a step)
+    and nothing ``[1024, 1024]``."""
     import re
 
-    kw, per_chip, _, dp, _ = _REMAT_BLOCKS[case]
-    text, _, _ = _export_remat_block(case)
+    kw, per_chip, seq, dp, _ = _REMAT_BLOCKS[case]
+    text, _, cfg = _export_remat_block(case)
     types = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|ui16|i1)>',
                            text))
-    by_head = [t for t in types if t.endswith('x64')]
-    assert types and not [t for t in by_head if t.split('x')[0] in (
-        str(per_chip), str(dp * per_chip))], by_head
-    if kw.get('positions') != 'rotary':
-        # (the tables of rotary positions are made a head wide, [s, 64],
-        # and repeated along the lanes: nothing of the batch's size)
-        assert not by_head
+    assert types and not [t for t in types if t.endswith('x64')]
     transposed = [re.search(r'-> tensor<([0-9x]+)x', line).group(1)
                   for line in text.splitlines()
                   if 'stablehlo.transpose' in line]
     assert not [t for t in transposed if t.count('x') >= 3], transposed
+
+    qkv = '%dx%dx%d' % (dp * per_chip, seq, 3 * cfg.dim)
+    lines = text.splitlines()
+    assert not [line for line in lines if qkv in line and (
+        'stablehlo.slice' in line or 'stablehlo.concatenate' in line)]
+    calls = [line for line in lines if '@tpu_custom_call' in line]
+    kernels = len(re.findall(r'kernel_name = "flash_dkv', text))
+    assert sum('output_operand_aliases' in line for line in calls) == kernels
+    # operations by the name their location gives them
+    named = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    rotary = [line for line in lines
+              for ref in re.findall(r'loc\((#loc\d+)\)\s*$', line)
+              if re.search(r'\brotary\b', named.get(ref, ''))]
+    assert bool(rotary) == (kw.get('positions') == 'rotary')
+    assert not [line for line in rotary if 'dot_general' in line
+                or '1024x1024' in line]
+    tables = [line for line in rotary if '-> tensor<%dx128xf32>' % seq in line]
+    # one cos and one sin a rotary base, whatever the number of layers
+    bases = {cfg.rope_theta, cfg.window_rope_theta or cfg.rope_theta}
+    assert len([line for line in tables if 'concatenate' in line]) == (
+        2 * len(bases) if rotary else 0)
 
 
 # The Mosaic modules of the existing flash cells' kernels with their
