@@ -323,22 +323,21 @@ class Trainer:
         accum = max(1, int(getattr(self.spec, 'grad_accum', 1)))
 
         def grads_of(params, batch):
-            """(loss, grads, state_updates) — updates is {} for
-            stateless models."""
+            """(loss, grads, state_updates, counters) — updates is {} for
+            stateless models, counters what the model counted in this
+            step (``core.record_counter``; mostly {})."""
             from autodist_tpu.models.core import model_mode
 
             def loss_fn(p):
                 with sharding_ctx(self.mesh, self.rules):
-                    if not self._has_state:
-                        return self.loss_for(p, batch), {}
                     with model_mode(training=True) as mm:
                         loss = self.loss_for(p, batch)
-                    return loss, dict(mm.updates)
+                    return loss, (dict(mm.updates), dict(mm.counters))
             if self.spec.remat == 'full':
                 loss_fn = jax.checkpoint(loss_fn)
-            (loss, updates), grads = jax.value_and_grad(
+            (loss, (updates, counters)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            return loss, grads, updates
+            return loss, grads, updates, counters
 
         def apply_updates(params, opt_updates, state_updates):
             from autodist_tpu.models.core import apply_tree_updates
@@ -371,7 +370,7 @@ class Trainer:
                 chunked = jax.tree.map(_chunk, batch)
 
                 def body(acc, chunk):
-                    loss_c, grads_c, upd_c = grads_of(state.params, chunk)
+                    loss_c, grads_c, upd_c, _ = grads_of(state.params, chunk)
                     acc_loss, acc_grads, _ = acc
                     # state (BN EMA) keeps the LAST chunk's update: each
                     # chunk computes its EMA from the pre-step state, so
@@ -392,15 +391,17 @@ class Trainer:
                     body, zero, chunked)
                 loss = loss / accum
                 grads = jax.tree.map(lambda g: g / accum, grads)
+                counters = {}   # (a chunk's cannot leave the scan)
             else:
-                loss, grads, state_updates = grads_of(state.params, batch)
+                loss, grads, state_updates, counters = grads_of(
+                    state.params, batch)
             with jax.named_scope('optimizer'):
                 updates, new_opt = self.optimizer.update(
                     grads, state.opt_state, state.params)
                 new_params = apply_updates(state.params, updates,
                                            state_updates)
             return TrainState(params=new_params, opt_state=new_opt,
-                              step=state.step + 1), {'loss': loss}
+                              step=state.step + 1), dict(counters, loss=loss)
 
         return step_fn
 
@@ -537,6 +538,12 @@ class Trainer:
                 with tel.loop_span('trainer.loss_readback',
                                    step=self._steps_run):
                     history['loss'].append(float(metrics['loss']))
+                if len(metrics) > 1:
+                    # what the model counted in the step, read back with
+                    # the loss (docs/design/observability.md)
+                    tel.loop_event('trainer.counters', step=self._steps_run,
+                                   **{k: float(v) for k, v in metrics.items()
+                                      if k != 'loss'})
                 n += 1
                 if eval_data is not None and eval_every and \
                         n % eval_every == 0:

@@ -177,16 +177,31 @@ def _block_targets(seq, causal, window=None):
 _BAND_TARGETS = {'fwd': (128, 512), 'dq': (256, 256), 'dkv': (128, 256)}
 
 
+# No tile of a wide band's call grows past its kernel's cap here.
+# Measured on a v5e at Mellum2's band (a causal window of 1024 keys: a
+# reach of 1023; 32 query heads over 4 kv heads of 128, 4 x 8192
+# positions, rotary inside; my chip runs, PR 33; PERF.md §6), ms a call
+# with every kernel's tiles capped at 256 / 512 / 1024 a side: the
+# forward 11.37 (256 x 512) / 12.11 / 9.58, the backward pair 16.53 /
+# 17.06 / 23.72. The forward is bound by its softmax's elementwise work
+# and gains from fewer, larger steps though a 1024 x 1024 tile on the
+# band's edge is half dead; the backward kernels pay for every dead
+# element with matmuls and settle at the smallest tile.
+_BAND_MAX_BLOCK = {'fwd': 1024, 'dq': 256, 'dkv': 256}
+
+
 def _band_targets(window):
     """Block targets of a band call: the measured targets for a band
     that reaches up to a lane-wide block (128) to either side, scaled up
-    by powers of two for a wider one, so that an outer block meets a
-    few inner blocks and the tiles stay close to the band's width."""
+    by powers of two for a wider one as far as the kernel's
+    ``_BAND_MAX_BLOCK``, so that an outer block meets a few inner blocks
+    and the tiles stay close to the band's width."""
     reach = max(window)
     scale = 1
     while scale * _LANES < reach:
         scale *= 2
-    return {kernel: (scale * bq, scale * bk)
+    return {kernel: (min(scale * bq, max(bq, _BAND_MAX_BLOCK[kernel])),
+                     min(scale * bk, max(bk, _BAND_MAX_BLOCK[kernel])))
             for kernel, (bq, bk) in _BAND_TARGETS.items()}
 
 
@@ -312,29 +327,32 @@ def _tile_counts(seq, bq, bk, causal, window=None, transposed=False):
 
 
 def check_window(window, causal=False):
-    """``window`` as a pair of ints, or None; a band under a causal mask
-    is refused, not guessed at."""
+    """``window`` as a pair of ints, or None. Under a causal mask the
+    band is ``(left, 0)``: query i sees keys i - left .. i, and the band
+    holds the mask (a caller then runs the band call with no causal
+    flag beside it)."""
     if window is None:
         return None
     left, right = (int(w) for w in window)
     if left < 0 or right < 0:
         raise ValueError('flash_attention: window %r must be (left, right) '
                          'with neither negative' % (window,))
-    if causal:
-        raise ValueError('flash_attention: a window under a causal mask is '
-                         'not supported; pass causal=False, or window='
-                         '(left, 0) for a causal band')
-    return left, right
+    return (left, 0) if causal else (left, right)
 
 
-def supports(shape, block=128, window=None):
+def supports(shape, block=128, window=None, kv_heads=None):
     """Whether flash_attention can run for [B, H, S, D], with or without
     a ``window``: S divisible into >=8-row blocks, and heads that tile
     the lanes of ``[B, S, H * D]`` (:func:`_lane_block`: H * D a
     multiple of 128 in heads of 128 / n or 128 n lanes, or all of it no
-    more than one lane block)."""
+    more than one lane block). Grouped kv heads (``kv_heads`` fewer than
+    H, dividing it) need a head to be its own lane block (D a multiple
+    of 128): a kv head's block is then read for its group's query heads
+    with no lane moved."""
     check_window(window)
     _, h, s, d = shape
+    if kv_heads not in (None, h) and (h % kv_heads or d % _LANES):
+        return False
     return _pick_block(s, block) is not None and (
         _lane_block(h, d) == max(_LANES, d) or h * d <= _LANES)
 
@@ -347,7 +365,7 @@ def supports(shape, block=128, window=None):
 MIN_KERNEL_SEQ = 512
 
 
-def preferred(shape, window=None):
+def preferred(shape, window=None, kv_heads=None):
     """True when the Pallas kernel is expected to beat XLA's fused
     attention for this [B, H, S, D] shape; the same sequences for a
     band call (``window``) as for a full one, where XLA's side is the
@@ -358,7 +376,7 @@ def preferred(shape, window=None):
     is XLA's to win anyway."""
     s = shape[2]
     return (s >= MIN_KERNEL_SEQ and (s % _LANES == 0 or s <= 256)
-            and supports(shape, window=window))
+            and supports(shape, window=window, kv_heads=kv_heads))
 
 
 def _interpret_default():
@@ -605,6 +623,27 @@ def _turned_back(dx, tables, d):
 _ALL = slice(None)
 
 
+# Grouped kv heads (``group`` query heads to a kv head, ``group > 1``): a
+# head is its own lane block, a grid step holds ``g`` query heads of ONE
+# group (``g`` divides ``group``) and the group's one block of k and of
+# v, which every head of the step reads where it lies.
+
+def _kv_cols(cols, group, lanes):
+    """The step's columns of k and v for the query lane block ``cols``:
+    the same columns, or the one kv head a grouped step holds."""
+    return cols if group == 1 else slice(0, lanes)
+
+
+def _readers(q_ref, k_ref, tables, d, group):
+    """``readers()``: :func:`_reads_qk` for one lane block of a step; the
+    heads of a grouped step share one, so that their k block is rotated
+    once."""
+    if group == 1:
+        return lambda: _reads_qk(q_ref, k_ref, tables, d)
+    shared = _reads_qk(q_ref, k_ref, tables, d)
+    return lambda: shared
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -621,7 +660,7 @@ def _inner_block(outer, j, size, inner_size, window, transposed=False):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
-                n_inner, tables=None):
+                n_inner, group, tables=None):
     qi, j = pl.program_id(2), pl.program_id(3)
     ki = _inner_block(qi, j, bq, bk, window)
     blocks = _lane_blocks(g, d, lanes)
@@ -632,19 +671,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
     def rows(parts):
         # plain softmax over the live keys of the row, by ranges
+        readers = _readers(q_ref, k_ref, tables, d, group)
         for cols, heads in blocks:
-            read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
+            read_q, read_k = readers()
+            kcols = _kv_cols(cols, group, lanes)
             o = None
             for h, keep in heads:
                 q = query(read_q, cols, keep)
-                ss = [_scores(q, read_k(slice(lo, hi), cols), sm_scale, fold,
-                              mask)
+                ss = [_scores(q, read_k(slice(lo, hi), kcols), sm_scale,
+                              fold, mask)
                       for lo, hi, mask in parts]
                 m = functools.reduce(jnp.maximum, [
                     jnp.max(s, axis=1, keepdims=True) for s in ss])
                 ps = [jnp.exp(s - m) for s in ss]
                 l = sum(jnp.sum(p, axis=1, keepdims=True) for p in ps)
-                acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, lo:hi, cols],
+                acc = sum(_dot(p.astype(v_ref.dtype), v_ref[0, lo:hi, kcols],
                                _NN) for p, (lo, hi, _) in zip(ps, parts))
                 o = _place(o, acc / l, keep)
                 lse_ref[0, h] = _to_row(m + jnp.log(l))
@@ -669,12 +710,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         # until a later tile: its max stays NEG_INF, p is exp(0) for
         # every key and l and acc hold finite rubbish, which the first
         # real max wipes out (alpha = exp(NEG_INF - m) = 0).
+        readers = _readers(q_ref, k_ref, tables, d, group)
         for c, (cols, heads) in enumerate(blocks):
-            read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
-            v = v_ref[0, :, cols]
+            read_q, read_k = readers()
+            kcols = _kv_cols(cols, group, lanes)
+            v = v_ref[0, :, kcols]
             alphas = pv = None
             for h, keep in heads:
-                s = _scores(query(read_q, cols, keep), read_k(_ALL, cols),
+                s = _scores(query(read_q, cols, keep), read_k(_ALL, kcols),
                             sm_scale, fold, mask)
                 m_prev = m_scr[h]                             # [bq, 1]
                 m_new = jnp.maximum(m_prev,
@@ -717,7 +760,7 @@ def _inner_blocks(s, blocks, window, transposed=False):
     return _band_inner_blocks(s, size, inner, window, transposed)
 
 
-def _static(kernel, s, heads, d, causal, sm_scale, blocks, window,
+def _static(kernel, s, heads, kv_heads, d, causal, sm_scale, blocks, window,
             transposed=False):
     """``kernel`` with what a call fixes at trace time."""
     bq, bk, g = blocks
@@ -725,7 +768,8 @@ def _static(kernel, s, heads, d, causal, sm_scale, blocks, window,
         kernel, sm_scale=sm_scale, fold=_is_pow2(sm_scale), causal=causal,
         bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g, d=d,
         lanes=_lane_block(heads, d), window=window,
-        n_inner=_inner_blocks(s, blocks, window, transposed))
+        n_inner=_inner_blocks(s, blocks, window, transposed),
+        group=heads // kv_heads)
 
 
 def _name(kernel, window):
@@ -746,42 +790,64 @@ def _band_fetch(outer, j, size, inner_size, seq, window, transposed=False):
                     jnp.minimum(last, seq // inner_size - 1))
 
 
-# An operand of a call is ``(array, start)``: the ``heads * d`` columns
-# from ``start`` of a ``[b, s, columns]`` array. q, k and v may be three
-# arrays or three runs of columns of one (the qkv projection's output),
-# which the index maps tell apart and nothing copies.
+# An operand of a call is ``(array, start)``: the ``heads * d`` (for k
+# and v: ``kv_heads * d``) columns from ``start`` of a ``[b, s, columns]``
+# array. q, k and v may be three arrays or three runs of columns of one
+# (the qkv projection's output), which the index maps tell apart and
+# nothing copies.
 
-def _head_dim(qkv, heads):
+def _head_dim(qkv, heads, kv_heads):
     """``qkv``: the three arrays, or one that holds them side by side."""
-    return qkv[0].shape[-1] // (heads * (3 if len(qkv) == 1 else 1))
+    return qkv[0].shape[-1] // (heads + 2 * kv_heads if len(qkv) == 1
+                                else heads)
 
 
-def _operands(qkv, heads, g):
+def _operands(qkv, heads, kv_heads, g):
     """What the three calls lay out alike, from what a caller hands over
-    and the heads ``g`` of a grid step: q, k, v as ``(array, start)``,
-    the head dim, the lanes of a step and those of a lane block."""
-    d = _head_dim(qkv, heads)
+    and the query heads ``g`` of a grid step: q, k, v as ``(array,
+    start)``, the head dim, the lanes of q and of k and v in a step,
+    and those of a lane block."""
+    d = _head_dim(qkv, heads, kv_heads)
     if len(qkv) == 3:
         operands = [(x, 0) for x in qkv]
     else:
-        operands = [(qkv[0], i * heads * d) for i in range(3)]
-    return operands, d, g * d, _lane_block(heads, d)
+        operands = [(qkv[0], first * d)
+                    for first in (0, heads, heads + kv_heads)]
+    return operands, d, g * d, (g * d if kv_heads == heads else d), \
+        _lane_block(heads, d)
 
 
-def _rows_spec(rows, width, row_of, start=0):
+def _group_of(h, j):
+    return h
+
+
+def _rows_spec(rows, width, row_of, start=0, group_of=_group_of):
     """Blocks ``(1, rows, width)`` of a ``[b, s, columns]`` operand on a
     (b, head group, outer, inner) grid: row block ``row_of(outer,
-    inner)``, and the head group's ``width`` lanes, counted from column
-    ``start`` (a multiple of ``width``: a head group divides the heads)."""
+    inner)``, and the ``width`` lanes of head group ``group_of(head
+    group, inner)``, counted from column ``start`` (a multiple of
+    ``width``: a head group divides the heads)."""
     first = start // width
-    return pl.BlockSpec((1, rows, width),
-                        lambda b, h, i, j: (b, row_of(i, j), first + h))
+    return pl.BlockSpec(
+        (1, rows, width),
+        lambda b, h, i, j: (b, row_of(i, j), first + group_of(h, j)))
 
 
-def _stat_spec(g, rows, row_of):
+def _stat_spec(g, rows, row_of, group_of=_group_of):
     """Blocks of a row statistic (``lse``, ``delta``: ``[b, h, 1, s]``)."""
-    return pl.BlockSpec((1, g, 1, rows),
-                        lambda b, h, i, j: (b, h, 0, row_of(i, j)))
+    return pl.BlockSpec(
+        (1, g, 1, rows),
+        lambda b, h, i, j: (b, group_of(h, j), 0, row_of(i, j)))
+
+
+def _kv_group_of(heads, kv_heads, g):
+    """The kv block of a (b, query head group, ., .) grid's step: the
+    step's own columns, or with grouped kv heads the kv head that the
+    step's ``g`` query heads share."""
+    if kv_heads == heads:
+        return _group_of
+    steps = heads // kv_heads // g
+    return lambda h, j: h // steps
 
 
 def _outer(i, j):
@@ -819,18 +885,20 @@ def _kv_row(causal, bq, bk, window=None, seq=None):
     return lambda i, j: jnp.minimum(j, ((i + 1) * bq - 1) // bk)
 
 
-def _fwd(qkv, tables, heads, causal, sm_scale, blocks, interpret,
+def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
          window=None):
     bq, bk, g = blocks
-    ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
+    ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
+        qkv, heads, kv_heads, g)
     b, s, _ = q.shape
     nk = s // bk
     kv_row = _kv_row(causal, bq, bk, window, s)
-    kernel = _static(_fwd_kernel, s, heads, d, causal, sm_scale, blocks,
-                     window)
+    kv_of = _kv_group_of(heads, kv_heads, g)
+    kernel = _static(_fwd_kernel, s, heads, kv_heads, d, causal, sm_scale,
+                     blocks, window)
     in_specs = [_rows_spec(bq, width, _outer, q0),
-                _rows_spec(bk, width, kv_row, k0),
-                _rows_spec(bk, width, kv_row, v0)]
+                _rows_spec(bk, kv_width, kv_row, k0, kv_of),
+                _rows_spec(bk, kv_width, kv_row, v0, kv_of)]
     operands = (q, k, v)
     if tables is not None:
         kernel = functools.partial(_tabled, kernel, len(operands))
@@ -867,7 +935,7 @@ def _fwd(qkv, tables, heads, causal, sm_scale, blocks, interpret,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
                *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes,
-               window, n_inner, tables=None):
+               window, n_inner, group, tables=None):
     qi, j = pl.program_id(2), pl.program_id(3)
     ki = _inner_block(qi, j, bq, bk, window)
     blocks = _lane_blocks(g, d, lanes)
@@ -881,10 +949,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
         delta_ref[0, h] = _to_row(delta)
         return delta
 
-    def grad(cols, heads, parts, delta_of=delta_of):
+    def grad(readers, cols, heads, parts, delta_of=delta_of):
         """dq of a lane block's heads from the key ranges ``parts``;
         with position tables, w.r.t. the rotated q."""
-        read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
+        read_q, read_k = readers()
+        kcols = _kv_cols(cols, group, lanes)
         dqs = None
         for h, keep in heads:
             q = read_q(_ALL, cols)
@@ -894,10 +963,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
             delta = delta_of(cols, h, keep)
             dq = 0.
             for lo, hi, mask in parts:
-                k = read_k(slice(lo, hi), cols)
+                k = read_k(slice(lo, hi), kcols)
                 s = _scores(q, k, sm_scale, fold, mask)
                 p = jnp.exp(s - lse)                          # [bq, keys]
-                dp = _dot(do, v_ref[0, lo:hi, cols], _NT)
+                dp = _dot(do, v_ref[0, lo:hi, kcols], _NT)
                 ds = p * (dp - delta)
                 if not fold:
                     ds = ds * sm_scale
@@ -913,8 +982,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
         return _turned_back(dq, tables and tables[0], d).astype(dq_ref.dtype)
 
     def rows(parts):
+        readers = _readers(q_ref, k_ref, tables, d, group)
         for cols, heads in blocks:
-            dq_ref[0, :, cols] = finish(grad(cols, heads, parts))
+            dq_ref[0, :, cols] = finish(grad(readers, cols, heads, parts))
 
     if nk == 1:
         _for_the_live_row(rows, qi, nq, bq, bk, causal, window=window)
@@ -931,9 +1001,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
                 delta_scr[h] = delta_of(cols, h, keep)
 
     def tile(mask):
+        readers = _readers(q_ref, k_ref, tables, d, group)
         for c, (cols, heads) in enumerate(blocks):
             dq_scr[c] = dq_scr[c] + grad(
-                cols, heads, [(0, bk, mask)],
+                readers, cols, heads, [(0, bk, mask)],
                 lambda cols, h, keep: delta_scr[h])
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
@@ -948,21 +1019,28 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *scratch,
                 sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
-                n_inner, tables=None):
-    ki, j = pl.program_id(2), pl.program_id(3)
+                n_inner, group, tables=None):
+    # With grouped kv heads the head dimension of the grid walks the kv
+    # heads, and the inner dimension the group's query heads, ``g`` at a
+    # step, each over the q-blocks: dk and dv of the kv head add up over
+    # all of them in the scratch.
+    steps = group // g if group > 1 else 1
+    ki, step = pl.program_id(2), pl.program_id(3)
+    j = step % n_inner if steps > 1 else step
     qi = _inner_block(ki, j, bk, bq, window, transposed=True)
     blocks = _lane_blocks(g, d, lanes)
 
-    def grads(cols, heads, parts):
+    def grads(readers, cols, heads, parts):
         """(dk, dv) of a lane block's heads from the query ranges
         ``parts``, on TRANSPOSED tiles [bk, queries]: lse and delta are
         rows of them, and both gradients plain matmuls. With position
         tables dk is w.r.t. the rotated k."""
-        read_q, read_k = _reads_qk(q_ref, k_ref, tables, d)
+        read_q, read_k = readers()
+        kcols = _kv_cols(cols, group, lanes)
         dks = dvs = None
         for h, keep in heads:
-            k = _only(read_k(_ALL, cols), keep)
-            v = _only(v_ref[0, :, cols], keep)
+            k = _only(read_k(_ALL, kcols), keep)
+            v = _only(v_ref[0, :, kcols], keep)
             dk = dv = 0.
             for lo, hi, mask in parts:
                 q = read_q(slice(lo, hi), cols)
@@ -984,53 +1062,64 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return _turned_back(dk, tables and tables[1], d).astype(dk_ref.dtype)
 
     def rows(parts):
+        readers = _readers(q_ref, k_ref, tables, d, group)
         for cols, heads in blocks:
-            dk, dv = grads(cols, heads, parts)
+            dk, dv = grads(readers, cols, heads, parts)
             dk_ref[0, :, cols] = finish(dk)
             dv_ref[0, :, cols] = dv.astype(dv_ref.dtype)
 
-    if nq == 1:
+    if nq == 1 and group == 1:
         _for_the_live_row(rows, ki, nk, bk, bq, causal, transposed=True,
                           window=window)
         return
 
     dk_scr, dv_scr = scratch
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def tile(mask):
+    def add(parts):
+        readers = _readers(q_ref, k_ref, tables, d, group)
         for c, (cols, heads) in enumerate(blocks):
-            dk, dv = grads(cols, heads, [(0, bq, mask)])
+            dk, dv = grads(readers, cols, heads, parts)
+            c = c if group == 1 else 0    # a group's heads: one kv block
             dk_scr[c] = dk_scr[c] + dk
             dv_scr[c] = dv_scr[c] + dv
 
-    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
-                        transposed=True, window=window)
+    if nq == 1:
+        _for_the_live_row(add, ki, nk, bk, bq, causal, transposed=True,
+                          window=window)
+    else:
+        _for_each_tile_kind(lambda mask: add([(0, bq, mask)]), qi, ki, bq, bk,
+                            nq * bq, causal, transposed=True, window=window)
 
-    @pl.when(j == n_inner - 1)
+    @pl.when(step == steps * n_inner - 1)
     def _emit():
-        for c, (cols, _) in enumerate(blocks):
+        for c in range(dk_scr.shape[0]):
+            cols = slice(c * lanes, (c + 1) * lanes)
             dk_ref[0, :, cols] = finish(dk_scr[c])
             dv_ref[0, :, cols] = dv_scr[c].astype(dv_ref.dtype)
 
 
-def _dq(qkv, tables, do, o, lse, heads, causal, sm_scale, blocks, interpret,
-        window=None):
+def _dq(qkv, tables, do, o, lse, heads, kv_heads, causal, sm_scale, blocks,
+        interpret, window=None):
     """``(dq, delta)``: ``delta = rowsum(dO * O)`` of each head is
     computed here, from the two merged tensors a block at a time, and
     left as ``[b, h, 1, s]`` for ``flash_dkv``."""
     bq, bk, g = blocks
-    ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
+    ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
+        qkv, heads, kv_heads, g)
     b, s, _ = do.shape
     kv_row = _kv_row(causal, bq, bk, window, s)
+    kv_of = _kv_group_of(heads, kv_heads, g)
     q_spec, row_spec = _rows_spec(bq, width, _outer), _stat_spec(g, bq, _outer)
-    kernel = _static(_dq_kernel, s, heads, d, causal, sm_scale, blocks, window)
+    kernel = _static(_dq_kernel, s, heads, kv_heads, d, causal, sm_scale,
+                     blocks, window)
     in_specs = [_rows_spec(bq, width, _outer, q0),
-                _rows_spec(bk, width, kv_row, k0),
-                _rows_spec(bk, width, kv_row, v0),
+                _rows_spec(bk, kv_width, kv_row, k0, kv_of),
+                _rows_spec(bk, kv_width, kv_row, v0, kv_of),
                 q_spec, q_spec, row_spec]
     operands = (q, k, v, do, o, lse)
     if tables is not None:
@@ -1041,7 +1130,7 @@ def _dq(qkv, tables, do, o, lse, heads, causal, sm_scale, blocks, interpret,
         kernel,
         grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
         in_specs=in_specs,
-        # dq as q is held: an array of its own, or the first third of
+        # dq as q is held: an array of its own, or the first run of
         # one (whose other columns this call leaves unwritten)
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -1055,41 +1144,58 @@ def _dq(qkv, tables, do, o, lse, heads, causal, sm_scale, blocks, interpret,
     )(*operands)
 
 
-def _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale, blocks,
-         interpret, window=None, dqkv=None):
-    """``(dk, dv)``; or with ``dqkv``, the ``[b, s, 3 * heads * d]``
-    array whose first third ``flash_dq`` wrote, ``(dqkv, dv)``: dk goes
-    into the second third of that array, in place."""
+def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
+         blocks, interpret, window=None, dqkv=None):
+    """``(dk, dv)``; or with ``dqkv``, the ``[b, s, (heads + 2 kv_heads)
+    * d]`` array whose first run ``flash_dq`` wrote, ``(dqkv, dv)``: dk
+    goes into the second run of that array, in place. With grouped kv
+    heads the grid's head dimension walks the kv heads and its inner
+    dimension the group's query heads, ``g`` at a step, each over the
+    q-blocks (``_dkv_kernel``)."""
     bq, bk, g = blocks
-    ((q, q0), (k, k0), (v, v0)), d, width, lanes = _operands(qkv, heads, g)
-    b, s, hd = do.shape
+    ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
+        qkv, heads, kv_heads, g)
+    b, s, _ = do.shape
+    n_inner = _inner_blocks(s, blocks, window, transposed=True)
+    grouped = kv_heads != heads
+    steps = heads // kv_heads // g if grouped else 1
+
+    def inner(i):
+        return i % n_inner if steps > 1 else i
     # the grid iterates q-blocks innermost for each kv-block; the dead
     # causal tiles come first there, and ask for the first live q-block;
     # a band call walks the q-blocks its kv-block is seen from
     if window is not None:
         def q_row(j, i):
-            return _band_fetch(j, i, bk, bq, s, window, transposed=True)
+            return _band_fetch(j, inner(i), bk, bq, s, window,
+                               transposed=True)
     elif causal:
         def q_row(j, i):
-            return jnp.maximum(i, (j * bk) // bq)
+            return jnp.maximum(inner(i), (j * bk) // bq)
     else:
         def q_row(j, i):
-            return i
-    row_spec = _stat_spec(g, bq, q_row)
-    acc = pltpu.VMEM((width // lanes, bk, lanes), jnp.float32)
-    kernel = _static(_dkv_kernel, s, heads, d, causal, sm_scale, blocks,
-                     window, transposed=True)
-    in_specs = [_rows_spec(bq, width, q_row, q0),
-                _rows_spec(bk, width, _outer, k0),
-                _rows_spec(bk, width, _outer, v0),
-                _rows_spec(bq, width, q_row), row_spec, row_spec]
+            return inner(i)
+    # the step's query heads: the grid's own head group, or the
+    # ``i // n_inner``-th ``g`` heads of kv head ``h``'s group
+    q_of = _group_of if not grouped else \
+        (lambda h, i: h * steps + i // n_inner)
+    row_spec = _stat_spec(g, bq, q_row, q_of)
+    acc = pltpu.VMEM((kv_width // lanes, bk, lanes), jnp.float32)
+    kernel = _static(_dkv_kernel, s, heads, kv_heads, d, causal, sm_scale,
+                     blocks, window, transposed=True)
+    in_specs = [_rows_spec(bq, width, q_row, q0, q_of),
+                _rows_spec(bk, kv_width, _outer, k0),
+                _rows_spec(bk, kv_width, _outer, v0),
+                _rows_spec(bq, width, q_row, group_of=q_of),
+                row_spec, row_spec]
     operands = (q, k, v, do, lse, delta)
     if tables is not None:
         kernel = functools.partial(_tabled, kernel, len(operands))
         in_specs += _table_specs(lanes, (bq, q_row), (bk, _outer))
         operands += tuple(tables) * 2
+    kv_shape = (b, s, kv_heads * d)
     if dqkv is None:
-        dk, aliases = jax.ShapeDtypeStruct((b, s, hd), k.dtype), {}
+        dk, aliases = jax.ShapeDtypeStruct(kv_shape, k.dtype), {}
     else:
         # the array comes in where it lies and goes out as the first
         # result: the kernel sees it as that result only
@@ -1099,14 +1205,15 @@ def _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale, blocks,
         kernel = functools.partial(_without, kernel, len(operands) - 1)
     return pl.pallas_call(
         kernel,
-        grid=(b, heads // g, s // bk, _inner_blocks(s, blocks, window,
-                                                    transposed=True)),
+        grid=(b, kv_heads if grouped else heads // g, s // bk,
+              steps * n_inner),
         in_specs=in_specs,
-        out_specs=[_rows_spec(bk, width, _outer, 0 if dqkv is None else hd),
-                   _rows_spec(bk, width, _outer)],
+        out_specs=[_rows_spec(bk, kv_width, _outer,
+                              0 if dqkv is None else heads * d),
+                   _rows_spec(bk, kv_width, _outer)],
         out_shape=[jax.ShapeDtypeStruct(dk.shape, dk.dtype),
-                   jax.ShapeDtypeStruct((b, s, hd), v.dtype)],
-        scratch_shapes=[] if s // bq == 1 else [acc, acc],
+                   jax.ShapeDtypeStruct(kv_shape, v.dtype)],
+        scratch_shapes=[] if s // bq == 1 and not grouped else [acc, acc],
         input_output_aliases=aliases,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
@@ -1130,21 +1237,22 @@ def _without(kernel, i, *refs):
 CHECKPOINT_NAMES = ('flash_o', 'flash_lse')
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7, 8))
-def _flash(qkv, tables, heads, causal, sm_scale, plan, interpret, window=None,
-           named=False):
-    """``qkv``: a tuple of q, k, v as ``[b, s, heads * d]`` each, or of
-    the one ``[b, s, 3 * heads * d]`` that holds them side by side;
-    ``tables``: None, or the rotary positions' ``(cos, sin)``;
-    ``o [b, s, heads * d]``."""
-    return _fwd(qkv, tables, heads, causal, sm_scale, plan.fwd, interpret,
-                window)[0]
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9))
+def _flash(qkv, tables, heads, kv_heads, causal, sm_scale, plan, interpret,
+           window=None, named=False):
+    """``qkv``: a tuple of q ``[b, s, heads * d]``, k and v ``[b, s,
+    kv_heads * d]``, or of the one ``[b, s, (heads + 2 kv_heads) * d]``
+    that holds them side by side; ``tables``: None, or the rotary
+    positions' ``(cos, sin)``; ``o [b, s, heads * d]``."""
+    return _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan.fwd,
+                interpret, window)[0]
 
 
-def _flash_fwd(qkv, tables, heads, causal, sm_scale, plan, interpret, window,
-               named):
-    o, lse = _fwd(qkv, tables, heads, causal, sm_scale, plan.fwd, interpret,
-                  window)
+def _flash_fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan,
+               interpret, window, named):
+    o, lse = _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan.fwd,
+                  interpret, window)
     if named:
         # both as the kernel writes them: o lane-dense, what the output
         # projection reads; lse [b, h, 1, s], which XLA tiles T(1, 128)
@@ -1154,30 +1262,30 @@ def _flash_fwd(qkv, tables, heads, causal, sm_scale, plan, interpret, window,
     return o, (qkv, tables, o, lse)
 
 
-def _flash_bwd(heads, causal, sm_scale, plan, interpret, window, named, res,
-               do):
+def _flash_bwd(heads, kv_heads, causal, sm_scale, plan, interpret, window,
+               named, res, do):
     qkv, tables, o, lse = res
     # (the position tables are constants: the None beside the cotangent
     # of qkv is theirs)
-    dq, delta = _dq(qkv, tables, do, o, lse, heads, causal, sm_scale, plan.dq,
-                    interpret, window)
+    dq, delta = _dq(qkv, tables, do, o, lse, heads, kv_heads, causal,
+                    sm_scale, plan.dq, interpret, window)
     if len(qkv) == 3:
-        dk, dv = _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale,
-                      plan.dkv, interpret, window)
+        dk, dv = _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal,
+                      sm_scale, plan.dkv, interpret, window)
         return (dq, dk, dv), None
     # the cotangent of one array that holds q, k and v is one array: dq
-    # is its first third as flash_dq returns it, flash_dkv writes dk into
+    # is its first run as flash_dq returns it, flash_dkv writes dk into
     # the second in place, and dv is written over the last
-    dqkv, dv = _dkv(qkv, tables, do, lse, delta, heads, causal, sm_scale,
-                    plan.dkv, interpret, window, dqkv=dq)
+    dqkv, dv = _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal,
+                    sm_scale, plan.dkv, interpret, window, dqkv=dq)
     return (jax.lax.dynamic_update_slice_in_dim(
-        dqkv, dv, 2 * dv.shape[-1], axis=2),), None
+        dqkv, dv, dqkv.shape[-1] - dv.shape[-1], axis=2),), None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _blocks(heads, head_dim, seq, targets, block_q, block_k):
+def _blocks(heads, head_dim, seq, targets, block_q, block_k, group=1):
     sizes = []
     for asked, target in zip((block_q, block_k), targets):
         size = seq if not asked and seq <= target else \
@@ -1187,16 +1295,21 @@ def _blocks(heads, head_dim, seq, targets, block_q, block_k):
                              'supports() first' % seq)
         sizes.append(size)
     per_block = _lane_block(heads, head_dim) // head_dim
-    return Blocks(*sizes, _heads_per_step(heads, *sizes, per_block))
+    # (grouped kv heads: a step's query heads share one kv head)
+    return Blocks(*sizes, _heads_per_step(heads if group == 1 else group,
+                                          *sizes, per_block))
 
 
-def _plan(shape, causal, block_q=None, block_k=None, window=None):
+def _plan(shape, causal, block_q=None, block_k=None, window=None,
+          kv_heads=None):
     """The static plan of a call on ``h`` heads of ``d`` over ``s``
     positions (``shape``: [b, h, s, d]): for each kernel the block sizes
     (the arguments, else the kernel's targets, cut to divisors of ``s``)
-    and the heads a grid step holds, in whole lane blocks."""
+    and the heads a grid step holds, in whole lane blocks (with
+    ``kv_heads`` fewer than ``h``: query heads of one group)."""
     _, h, s, d = shape
-    return Plan(**{kernel: _blocks(h, d, s, targets, block_q, block_k)
+    return Plan(**{kernel: _blocks(h, d, s, targets, block_q, block_k,
+                                   h // (kv_heads or h))
                    for kernel, targets in
                    _block_targets(s, causal, window).items()})
 
@@ -1242,7 +1355,10 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     ``i - left .. i + right``: a band. The kernels then walk the band's
     tiles only and are named ``flash_fwd_band``, ``flash_dq_band`` and
     ``flash_dkv_band``; with ``window=None`` the call is what it is
-    without the argument. A window under ``causal=True`` is an error.
+    without the argument. Under ``causal=True`` the band is ``(left,
+    0)`` and holds the mask. k and v may have fewer heads than q, a
+    divisor of its count (grouped kv heads; ``supports``): query head
+    ``i`` attends kv head ``i // group``.
 
     The kernels work on ``[batch, seq, heads * head_dim]``
     (:func:`flash_attention_merged`); this is that call between the
@@ -1254,21 +1370,27 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     (``_plan_tags``).
     """
     b, h, s, d = q.shape
-    o = _planned(tuple(jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, h * d)
-                       for x in (q, k, v)), None, h, causal, sm_scale,
-                 block_q, block_k, interpret, window, named=False)
+    o = _planned(tuple(jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, -1)
+                       for x in (q, k, v)), None, h, k.shape[1], causal,
+                 sm_scale, block_q, block_k, interpret, window, named=False)
     return jnp.transpose(o.reshape(b, s, h, d), (0, 2, 1, 3))
 
 
 def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
-                           interpret=None, window=None, rotary=None):
+                           interpret=None, window=None, rotary=None,
+                           kv_heads=None):
     """:func:`flash_attention` in the kernels' own layout, which is the
     model's: ``qkv`` is the qkv projection's output
-    ``[batch, seq, 3 * heads * head_dim]`` (q, k and v side by side,
-    read where they lie) or a tuple of the three as
-    ``[batch, seq, heads * head_dim]`` each, and the output is
-    ``[batch, seq, heads * head_dim]``, what the output projection
-    takes. Nothing is transposed, copied or padded on the way in or out.
+    ``[batch, seq, (heads + 2 * kv_heads) * head_dim]`` (q, k and v side
+    by side, read where they lie) or a tuple of the three as
+    ``[batch, seq, heads * head_dim]`` (k and v: ``kv_heads``) each, and
+    the output is ``[batch, seq, heads * head_dim]``, what the output
+    projection takes. Nothing is transposed, copied or padded on the way
+    in or out. ``kv_heads`` defaults to ``heads``; with fewer (grouped
+    kv heads) a grid step holds query heads of one group and reads the
+    group's k and v block once for all of them, and ``flash_dkv`` adds
+    a kv head's dk and dv up over its group inside the kernel: no copy
+    of k or v repeated to the query heads exists.
 
     ``rotary = (cos, sin)`` puts rotary positions on q and k inside the
     kernels: the tables of :func:`rotary_tables`, ``[seq, lane block]``
@@ -1287,22 +1409,38 @@ def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
     keeps. Without such a policy the names mean nothing."""
     if not isinstance(qkv, (tuple, list)):
         qkv = (qkv,)
-    return _planned(tuple(qkv), rotary, heads, causal, sm_scale, None, None,
-                    interpret, window, named=True)
+    return _planned(tuple(qkv), rotary, heads, kv_heads or heads, causal,
+                    sm_scale, None, None, interpret, window, named=True)
+
+
+def rotary_angles(positions, theta, head_dim):
+    """``(cos, sin)`` of rotary positions, ``[len(positions), head_dim /
+    2]`` in f32. ``theta`` is a base (``inv_freq_j = theta ** (-2j /
+    head_dim)``) or a pair ``(inv_freq, factor)``: the ``head_dim / 2``
+    frequencies themselves and a factor on ``cos`` and ``sin`` (YaRN:
+    ``models/attention.rope_frequencies``)."""
+    factor = None
+    if isinstance(theta, tuple):
+        inv_freq, factor = theta
+        inv_freq = jnp.asarray(inv_freq, jnp.float32)
+    else:
+        inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return (cos, sin) if factor is None else (cos * factor, sin * factor)
 
 
 def rotary_tables(positions, theta, heads, head_dim):
     """``(cos, sin)`` of rotary positions for :func:`flash_attention_merged`
     on ``heads`` (local) heads of ``head_dim``: ``[len(positions), lanes
-    of a lane block]`` in f32, the head's table (``inv_freq_j = theta **
-    (-2j / head_dim)``, the rotate-half convention: both halves of a head
-    carry the same angles) repeated over the heads of a lane block."""
-    inv_freq = theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                         / head_dim)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    of a lane block]`` in f32, the head's table (:func:`rotary_angles` of
+    ``theta``; the rotate-half convention: both halves of a head carry
+    the same angles) repeated over the heads of a lane block."""
+    cos, sin = rotary_angles(positions, theta, head_dim)
     repeats = 2 * _lane_block(heads, head_dim) // head_dim
-    return (jnp.concatenate([jnp.cos(angle)] * repeats, axis=-1),
-            jnp.concatenate([jnp.sin(angle)] * repeats, axis=-1))
+    return (jnp.concatenate([cos] * repeats, axis=-1),
+            jnp.concatenate([sin] * repeats, axis=-1))
 
 
 def saved_bytes(shape, dtype):
@@ -1313,12 +1451,18 @@ def saved_bytes(shape, dtype):
     return b * h * s * (d * jnp.dtype(dtype).itemsize + 4)
 
 
-def _planned(qkv, tables, heads, causal, sm_scale, block_q, block_k,
+def _planned(qkv, tables, heads, kv_heads, causal, sm_scale, block_q, block_k,
              interpret, window, named):
     window = check_window(window, causal)
+    causal = causal and window is None     # a causal band holds the mask
     b, s, _ = qkv[0].shape
-    d = _head_dim(qkv, heads)
+    d = _head_dim(qkv, heads, kv_heads)
     lanes = _lane_block(heads, d)
+    if kv_heads != heads and (heads % kv_heads or lanes != d):
+        raise ValueError('flash_attention: %d query heads over %d kv heads '
+                         'of %d lanes is not supported (grouped kv heads '
+                         'need a head to be a lane block); check supports() '
+                         'first' % (heads, kv_heads, d))
     if tables is not None:
         tables = tuple(tables)
         if [(t.shape, t.dtype) for t in tables] != [((s, lanes),
@@ -1334,7 +1478,8 @@ def _planned(qkv, tables, heads, causal, sm_scale, block_q, block_k,
     if sm_scale is None:
         sm_scale = d ** -0.5
     sm_scale = float(sm_scale)
-    plan = _plan((b, heads, s, d), causal, block_q, block_k, window)
+    plan = _plan((b, heads, s, d), causal, block_q, block_k, window,
+                 kv_heads)
     if interpret is None:
         interpret = _interpret_default()
     telemetry.get().loop_event(
@@ -1342,6 +1487,7 @@ def _planned(qkv, tables, heads, causal, sm_scale, block_q, block_k,
         fold_scale=_is_pow2(sm_scale),
         window=None if window is None else list(window),
         layout='bsd', lane_block=lanes, heads_per_lane_block=lanes // d,
-        rotary=tables is not None, **_plan_tags(plan, s, causal, window))
-    return _flash(qkv, tables, heads, causal, sm_scale, plan, interpret,
-                  window, named)
+        rotary=tables is not None, kv_heads=kv_heads,
+        **_plan_tags(plan, s, causal, window))
+    return _flash(qkv, tables, heads, kv_heads, causal, sm_scale, plan,
+                  interpret, window, named)

@@ -7,8 +7,11 @@ switches to ring attention (parallel/ring_attention.py). The reference
 has neither TP nor SP (SURVEY.md §2.3) — these are the TPU-native
 extension axes of the strategy space.
 """
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
@@ -23,13 +26,46 @@ from autodist_tpu.parallel.ring_attention import (local_flash_attention,
 from autodist_tpu.parallel.ulysses import ulysses_attention
 
 
+def rope_frequencies(theta, head_dim, yarn=None):
+    """What a layer kind's rotary positions are made from: a base
+    ``theta`` alone, or with ``yarn`` (a mapping with ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
+    and ``attention_factor``) the pair ``(inv_freq, factor)`` of YaRN as
+    the public ``transformers`` ``rope_type: yarn`` computes it: the
+    base's frequencies, divided by ``factor`` where a frequency turns
+    fewer than ``beta_slow`` times in the original length, left as they
+    are where it turns more than ``beta_fast`` times, a linear ramp over
+    the pair index between, at every length; ``cos`` and ``sin`` are
+    multiplied by ``attention_factor``. Either is what
+    ``fa.rotary_tables`` and :func:`rotary` take."""
+    if yarn is None:
+        return theta
+    pairs = np.arange(head_dim // 2, dtype=np.float64)
+    extra = theta ** (-2.0 * pairs / head_dim)
+    inter = extra / yarn['factor']
+    original = yarn['original_max_position_embeddings']
+
+    def pair_of(turns):
+        return head_dim * math.log(original / (2 * math.pi * turns)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair_of(yarn['beta_fast'])), 0)
+    high = min(math.ceil(pair_of(yarn['beta_slow'])), head_dim - 1)
+    if low == high:
+        high += 0.001    # as transformers: no division by zero
+    ramp = np.clip((pairs - low) / (high - low), 0.0, 1.0)
+    inv_freq = inter * ramp + extra * (1.0 - ramp)
+    return (tuple(float(f) for f in inv_freq.astype(np.float32)),
+            float(yarn['attention_factor']))
+
+
 @jax.named_scope('rotary')
 def rotary(x, positions, theta, heads=None):
     """Rotary position embedding over all of the head dim of
     ``x [b, h, s, d]``, or with ``heads`` given of each head of the
     merged ``x [b, s, heads * d]``; rotate-half convention (``x1, x2``
     the two halves: ``x * cos + cat(-x2, x1) * sin`` with ``inv_freq_j =
-    theta ** (-2j / d)``), computed in f32 at positions ``[s]``.
+    theta ** (-2j / d)``, or the frequencies and factor of
+    :func:`rope_frequencies`), computed in f32 at positions ``[s]``.
 
     ``cat(-x2, x1)`` is taken as ``x @ R`` with R the signed permutation
     that moves each half onto the other: on the MXU, exact in any float
@@ -41,10 +77,9 @@ def rotary(x, positions, theta, heads=None):
     repeat a head: nothing reshapes the lanes into heads."""
     d = x.shape[-1] // (heads or 1)
     half = d // 2
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)      # [s, d]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)
+    cos, sin = fa.rotary_angles(positions, theta, d)
+    cos = jnp.concatenate([cos] * 2, axis=-1)                 # [s, d]
+    sin = jnp.concatenate([sin] * 2, axis=-1)
     at = jnp.arange(d)
     turn = (jnp.where(at[:, None] == at[None, :] - half, 1.0, 0.0)
             - jnp.where(at[:, None] == at[None, :] + half, 1.0, 0.0))
@@ -60,31 +95,51 @@ def rotary(x, positions, theta, heads=None):
 class MultiHeadAttention(Module):
     """Causal (or full) self-attention; [batch, seq, embed] in/out.
 
-    ``rope_theta`` (a base) puts rotary positions on q and k: inside
-    the flash kernels where they run (``position_tables``: ``cos`` and
-    ``sin`` as two more operands, q and k rotated on the tile), by
-    :func:`rotary` on every other path. ``window`` (keys each side, or
-    ``(left, right)``) keeps a band of the scores and is handed to every
+    ``num_kv_heads`` (grouped kv heads): query head ``i`` attends kv
+    head ``i // (num_heads // num_kv_heads)``. The projection writes
+    ``[b, s, (num_heads + 2 * num_kv_heads) * head_dim]``, q, k and v
+    side by side, and the flash kernels read the three runs where they
+    lie: no copy of k or v repeated to the query heads exists on that
+    path (the XLA and sequence-parallel paths, for short sequences and
+    tiny models, repeat them).
+
+    ``rope_theta`` (a base; with ``rope_yarn`` YaRN's frequencies and
+    factor, :func:`rope_frequencies`) puts rotary positions on q and k:
+    inside the flash kernels where they run (``position_tables``:
+    ``cos`` and ``sin`` as two more operands, q and k rotated on the
+    tile), by :func:`rotary` on every other path. ``window`` (keys each
+    side, or ``(left, right)``) keeps a band of the scores; under
+    ``causal`` the band is ``(left, 0)``. It is handed to every
     attention path that takes one: the flash kernels, their
     nested-manual route under dp/tp, and the XLA path. The
     sequence-parallel paths take none and raise."""
 
     def __init__(self, dim, num_heads, head_dim=None, causal=True,
-                 dtype=jnp.float32, rope_theta=None, window=None):
+                 dtype=jnp.float32, rope_theta=None, window=None,
+                 num_kv_heads=None, rope_yarn=None):
         self.dim = dim
         self.num_heads = num_heads
+        self.num_kv_heads = num_kv_heads or num_heads
+        if num_heads % self.num_kv_heads:
+            raise ValueError('%d query heads do not divide over %d kv heads'
+                             % (num_heads, self.num_kv_heads))
         self.head_dim = head_dim or dim // num_heads
         self.causal = causal
         self.dtype = dtype
-        self.rope_theta = rope_theta
+        # what the position tables are made from, hashable: a model makes
+        # one pair of tables for the layers that share it
+        self.rope = None if rope_theta is None else rope_frequencies(
+            rope_theta, self.head_dim, rope_yarn)
         if isinstance(window, int):
             window = (window, window)
         self.window = fa.check_window(window, causal)
-        inner = self.num_heads * self.head_dim
+        h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
+        # the runs of q, k and v in the projection's output
+        self._runs = (h * d, (h + kv) * d)
         # qkv fused: column-parallel over heads; out: row-parallel back.
-        self.wqkv = Dense(dim, 3 * inner, 'embed', 'heads',
+        self.wqkv = Dense(dim, (h + 2 * kv) * d, 'embed', 'heads',
                           use_bias=False, dtype=dtype)
-        self.wo = Dense(inner, dim, 'heads', 'embed',
+        self.wo = Dense(h * d, dim, 'heads', 'embed',
                         use_bias=False, dtype=dtype)
 
     def param_defs(self):
@@ -94,8 +149,8 @@ class MultiHeadAttention(Module):
         """``tables``: what ``position_tables`` gives for ``x``, where a
         caller made it once for many layers; made here otherwise."""
         b, s, _ = x.shape
-        h, d = self.num_heads, self.head_dim
-        qkv = self.wqkv.apply(params['qkv'], x)          # [b, s, 3hd]
+        h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
+        qkv = self.wqkv.apply(params['qkv'], x)     # [b, s, (h + 2 kv) d]
         local = self.kernel_shape((b, h, s, d))
         if local is not None:
             # long device-local sequences: the Pallas flash kernels
@@ -105,20 +160,33 @@ class MultiHeadAttention(Module):
                 tables = self.position_tables((b, h, s, d))
             return self.wo.apply(
                 params['out'], self._kernel_attention(qkv, local[1], tables))
-        qkv = qkv.reshape(b, s, 3, h, d)
-        q = jnp.transpose(qkv[:, :, 0], (0, 2, 1, 3))     # [b, h, s, d]
-        k = jnp.transpose(qkv[:, :, 1], (0, 2, 1, 3))
-        v = jnp.transpose(qkv[:, :, 2], (0, 2, 1, 3))
+        if kv == h:
+            # (three equal runs as one reshape, as before grouped kv
+            # heads: GSPMD carries a `heads` sharding of the fused columns
+            # through it, where a split at the runs' bounds made XLA:CPU
+            # abort under pp x tp)
+            qkv = qkv.reshape(b, s, 3, h, d)
+            q, k, v = (jnp.transpose(qkv[:, :, i], (0, 2, 1, 3))
+                       for i in range(3))                 # [b, h, s, d]
+        else:
+            q, k, v = (jnp.transpose(t.reshape(b, s, -1, d), (0, 2, 1, 3))
+                       for t in jnp.split(qkv, self._runs, axis=-1))
 
         seq_axis = manual_axis(AXIS_SEQUENCE)
-        if self.rope_theta is not None:
+        if self.rope is not None:
             # global positions, as the position table's (transformer.py)
             pos = jnp.arange(s)
             if seq_axis is not None:
                 pos = pos + jax.lax.axis_index(seq_axis) * s
-            q = rotary(q, pos, self.rope_theta)
-            k = rotary(k, pos, self.rope_theta)
+            q = rotary(q, pos, self.rope)
+            k = rotary(k, pos, self.rope)
+        if kv != h:
+            # the paths below know one head count (short sequences, tiny
+            # models): each kv head once for every query head of its group
+            k, v = (jnp.repeat(t, h // kv, axis=1) for t in (k, v))
         window = self.window
+        # a band under a causal mask holds the mask: (left, 0)
+        causal = self.causal and window is None
         if seq_axis is not None:
             if window is not None:
                 raise ValueError(
@@ -127,13 +195,11 @@ class MultiHeadAttention(Module):
                     'yet; use sp=1 for a model with window layers'
                     % (window,))
             if ctx_option('sp_mode', 'ring') == 'ulysses':
-                o = ulysses_attention(q, k, v, seq_axis,
-                                      causal=self.causal)
+                o = ulysses_attention(q, k, v, seq_axis, causal=causal)
             else:
-                o = ring_attention(q, k, v, seq_axis, causal=self.causal)
+                o = ring_attention(q, k, v, seq_axis, causal=causal)
         else:
-            o = local_flash_attention(q, k, v, causal=self.causal,
-                                      window=window)
+            o = local_flash_attention(q, k, v, causal=causal, window=window)
             o = constrain(o, ('batch', 'heads', 'seq', 'kv'))
         o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
         return self.wo.apply(params['out'], o)
@@ -145,18 +211,17 @@ class MultiHeadAttention(Module):
         or where attention takes another path than the kernels. They
         depend on no parameter and no layer: a model makes them once a
         step for each rotary base and hands them to its layers."""
-        local = self.kernel_shape(shape) if self.rope_theta is not None \
-            else None
+        local = self.kernel_shape(shape) if self.rope is not None else None
         if local is None:
             return None
         with jax.named_scope('rotary'):
-            return fa.rotary_tables(jnp.arange(shape[2]), self.rope_theta,
+            return fa.rotary_tables(jnp.arange(shape[2]), self.rope,
                                     local[1], self.head_dim)
 
     def _kernel_attention(self, qkv, local_heads, tables):
         """``flash_attention_merged`` on the projection's output
-        ``qkv [b, s, 3 * h * d]`` (``local_heads`` of the heads on a
-        device) and the rotary positions' ``tables`` (or None),
+        ``qkv [b, s, (h + 2 kv) * d]`` (``local_heads`` of the query
+        heads on a device) and the rotary positions' ``tables`` (or None),
         ``[b, s, h * d]`` out, named with ``lse`` for the block's
         checkpoint policy. The kernels read q, k and v where the
         projection wrote them, and rotate q and k on the tile; the three
@@ -175,12 +240,14 @@ class MultiHeadAttention(Module):
         pipe/seq/expert."""
         mesh = None if unsharded_execution() else current_mesh()
         heads_axis = live_mesh_axis('heads') if mesh is not None else None
-        operands = tuple(jnp.split(qkv, 3, axis=-1)) if heads_axis else (qkv,)
+        operands = tuple(jnp.split(qkv, self._runs, axis=-1)) \
+            if heads_axis else (qkv,)
+        kv_heads = local_heads * self.num_kv_heads // self.num_heads
 
         def attend(operands, tables):
             return fa.flash_attention_merged(
                 operands, local_heads, causal=self.causal,
-                window=self.window, rotary=tables)
+                window=self.window, rotary=tables, kv_heads=kv_heads)
 
         if mesh is None:
             return attend(operands, tables)
@@ -201,8 +268,14 @@ class MultiHeadAttention(Module):
         if manual_axis(AXIS_SEQUENCE) is not None:
             return None
         if unsharded_execution():
-            return shape if fa.preferred(shape, self.window) else None
+            return shape if self._kernel_preferred(shape) else None
         return self._tp_manual_shape(shape)
+
+    def _kernel_preferred(self, local):
+        """``fa.preferred`` for a device's ``[b, h, s, d]`` of q, with
+        its share of the kv heads."""
+        kv_heads, rest = divmod(local[1] * self.num_kv_heads, self.num_heads)
+        return not rest and fa.preferred(local, self.window, kv_heads)
 
     # -- nested-manual flash under dp/tp GSPMD -----------------------------
     def _tp_manual_shape(self, shape):
@@ -225,4 +298,4 @@ class MultiHeadAttention(Module):
         if dp * tp <= 1 or shape[0] % dp or shape[1] % tp:
             return None
         local = (shape[0] // dp, shape[1] // tp, shape[2], shape[3])
-        return local if fa.preferred(local, self.window) else None
+        return local if self._kernel_preferred(local) else None

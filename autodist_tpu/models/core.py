@@ -127,6 +127,7 @@ class _StateCollector:
     def __init__(self, training):
         self.training = training
         self.updates = {}    # path tuple -> new value (tracer ok)
+        self.counters = {}   # name -> scalar of this step (tracer ok)
 
 
 class model_mode:
@@ -139,6 +140,10 @@ class model_mode:
     @property
     def updates(self):
         return self._col.updates
+
+    @property
+    def counters(self):
+        return self._col.counters
 
     def __enter__(self):
         stack = getattr(_MODEL_CTX, 'stack', None)
@@ -175,6 +180,18 @@ def record_state_update(module, name, value):
             'Trainer (assign_state_paths) to track running statistics'
             % type(module).__name__)
     col.updates[path + (name,)] = value
+
+
+def record_counter(name, value):
+    """Record a scalar the model counts in this step (the rows an expert
+    layer holds, ...), for the trainer to return beside the loss. Like a
+    state update it leaves through the trace-time collector, so it has
+    to be a value of the loss function's own trace (not of a scan body
+    or a manual region inside it); a no-op when no collector is
+    active."""
+    col = _collector()
+    if col is not None:
+        col.counters[name] = value
 
 
 def assign_state_paths(module, prefix=(), _seen=None):
@@ -278,15 +295,16 @@ class Embedding(Module):
     partitioned embeddings, partitioner.py:576-602)."""
 
     def __init__(self, vocab, dim, vocab_axis='vocab', dim_axis='embed',
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, init_scale=0.02):
         self.vocab, self.dim = vocab, dim
         self.vocab_axis, self.dim_axis = vocab_axis, dim_axis
         self.dtype = dtype
+        self.init_scale = init_scale   # std of a row's elements at init
 
     def param_defs(self):
         return {'table': ParamDef((self.vocab, self.dim),
                                   (self.vocab_axis, self.dim_axis),
-                                  'normal', 0.02)}
+                                  'normal', self.init_scale)}
 
     def apply(self, params, ids):
         table = params['table'].astype(self.dtype)
@@ -333,10 +351,39 @@ class LayerNorm(Module):
         return y.astype(self.dtype)
 
 
+class RMSNorm(Module):
+    """``x / sqrt(mean(x^2) + eps) * scale`` in f32 over the last dim:
+    no mean taken out, no bias."""
+
+    def __init__(self, dim, axis_name='embed', eps=1e-6, dtype=jnp.float32):
+        self.dim, self.axis_name, self.eps = dim, axis_name, eps
+        self.dtype = dtype
+
+    def param_defs(self):
+        return {'scale': ParamDef((self.dim,), (self.axis_name,), 'ones')}
+
+    def apply(self, params, x):
+        x32 = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(ms + self.eps)
+                * params['scale']).astype(self.dtype)
+
+
 def gelu_exact(x):
     """The erf GELU (BERT's, ModernBERT's ``"gelu"``); ``jax.nn.gelu``
     alone is the tanh approximation (GPT-2's ``gelu_new``)."""
     return jax.nn.gelu(x, approximate=False)
+
+
+# An MLP's activation by the name a configuration gives it.
+ACTIVATIONS = {'tanh': jax.nn.gelu, 'erf': gelu_exact, 'silu': jax.nn.silu}
+
+
+def activation(name):
+    if name not in ACTIVATIONS:
+        raise ValueError('activation must be one of %s, not %r'
+                         % (sorted(ACTIVATIONS), name))
+    return ACTIVATIONS[name]
 
 
 class Mlp(Module):

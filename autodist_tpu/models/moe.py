@@ -1,88 +1,358 @@
-"""Mixture-of-experts MLP with expert parallelism.
+"""Mixture-of-experts MLP: top-k routing over all of a layer's experts,
+computed for the experts this program HOLDS, with no token dropped.
 
-The reference's closest feature is sparse-variable partitioning
-("EP-lite", SURVEY.md §2.3); real expert parallelism is a TPU-native
-extension axis. Design is the Switch/GShard dense-dispatch formulation:
-top-k routing builds a dispatch tensor contracted with einsums, so expert
-compute stays static-shaped (MXU/XLA-friendly, no ragged scatter) and
-sharding the expert dim over the ``expert`` mesh axis makes GSPMD insert
-the all-to-alls. Overflowed tokens beyond per-expert capacity are dropped
-(standard Switch behavior); an auxiliary load-balancing loss is returned
-via a side channel.
+The layer is told the router's width (``n_experts``), the experts a
+token takes (``top_k``) and which experts it holds (``held = (first,
+count)``; all by default). It routes over all of them, softmax then the
+``top_k`` largest, their weights renormalised to sum to one, and
+computes the part of the result its own experts give: ``sum over the
+chosen e that are held of w_e * expert_e(x)``. What the experts held
+elsewhere would add is left out. That is the layer each rank of an
+expert-parallel job runs between its two exchanges; this module has no
+exchange, and nothing that stands in for absent chips (one chip's share
+of a deployment: the ``model-configs`` guide, §4). The parts of all the
+shares add up to the whole layer (``tests/test_moe.py``).
+
+How the held part is computed (the reference's dense-dispatch einsums
+over a one-hot ``[b, s, e, capacity]`` tensor, which dropped what
+overflowed a capacity, are gone):
+
+* **route** (scope ``moe_route``): router, top-k, and the ORDER of the
+  (token, choice) pairs whose expert is held: by expert, and inside an
+  expert by token. Each expert's rows start on a tile of
+  ``grouped_matmul.TILE_ROWS`` rows and are padded to whole tiles, so a
+  tile is one expert's; a pair's row is ``start of its expert + its
+  rank there`` (the rank is a running count over the tokens: no sort).
+  The row buffer is sized for the worst case, every pair held: ``tokens
+  x min(top_k, held)`` rows and a tile of padding an expert. NO ROW IS
+  DROPPED at any routing.
+* **experts** (``moe_experts``) between **dispatch** (``moe_dispatch``):
+  the rows are walked in CHUNKS of ``CHUNK_TILES`` tiles by a
+  ``while_loop`` that stops after the last live tile, so the time
+  follows the rows that are live and the memory one chunk, not the
+  buffer: gather the chunk's token rows, one grouped product with the
+  experts' ``[dim, hidden]`` (gate and up side by side for a gated
+  expert), the activation, a second with ``[hidden, dim]``, and the
+  rows scattered back onto their tokens with their weights, added up in
+  f32. The backward pass is written out (``jax.custom_vjp``): it walks
+  the chunks again, computes each chunk's forward again, and adds the
+  experts' weight gradients up in place, a group at a time
+  (``grouped_matmul.gmm_dw``); nothing a row long is kept between the
+  passes.
+
+The grouped products are the Pallas kernels of
+``kernels/grouped_matmul.py`` (``moe_gmm``, ``moe_gmm_dx``,
+``moe_gmm_dw``), always: under a mesh that shards the tokens or the
+experts the layer runs on each device's shard in a manual region
+(:meth:`MoeMlp._on_shards`).
+
+An auxiliary load-balancing loss (Switch eq. 4, over all the router's
+experts) is returned beside the output, and the step's load as
+``stats``: the rows held here and the largest load of a held expert.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from autodist_tpu.models.core import Dense, Module, ParamDef, constrain
+from autodist_tpu.kernels import grouped_matmul as gm
+from autodist_tpu.models.core import Dense, Module, ParamDef
+from autodist_tpu.parallel.axes import (AXIS_DATA, active_manual_axes,
+                                        current_mesh, live_mesh_axis,
+                                        shard_map, unsharded_execution)
+
+# Tiles a step of the chunk loop walks: 4096 rows, an expert's share of
+# 32,768 tokens at 8 of 64. Measured on a v5e in Mellum2's cell (PERF.md
+# §6, PR 33): with chunks of 8192 rows XLA's scatter-add takes nearly
+# twice as long a step (`moe_dispatch_ms_per_step` 330 against 180).
+CHUNK_TILES = 16
 
 
 class MoeMlp(Module):
-    """Top-k routed expert MLP. Input/output: [batch, seq, dim]."""
+    """Top-k routed expert MLP. Input/output: [batch, seq, dim];
+    ``apply`` returns ``(y, aux, stats)``."""
 
-    def __init__(self, dim, hidden, n_experts, top_k=2,
-                 capacity_factor=2.0, dtype=jnp.float32,
-                 act=jax.nn.gelu):
+    def __init__(self, dim, hidden, n_experts, top_k=2, held=None,
+                 dtype=jnp.float32, act=jax.nn.gelu, gated=False):
         self.dim, self.hidden = dim, hidden
         self.n_experts = n_experts
         self.top_k = top_k
-        self.capacity_factor = capacity_factor
+        self.first, self.held = held or (0, n_experts)
+        if not 0 <= self.first <= self.first + self.held <= n_experts \
+                or self.held < 1:
+            raise ValueError('held=%r is no run of the %d experts'
+                             % (held, n_experts))
         self.dtype = dtype
         self.act = act
+        self.gated = gated
         self.router = Dense(dim, n_experts, 'embed', None,
                             use_bias=False, dtype=jnp.float32)
 
     def param_defs(self):
+        # an expert's fan-in is its own dim or hidden, not the stack's;
+        # a gated expert's gate (index 0: the activated half) and up
+        # (index 1) lie side by side, as GatedMlp's
+        up = (self.held, self.dim) + ((2,) if self.gated else ()) \
+            + (self.hidden,)
         return {
             'router': self.router,
-            'up': ParamDef((self.n_experts, self.dim, self.hidden),
-                           ('expert', 'embed', 'mlp'), 'fan_in'),
-            'down': ParamDef((self.n_experts, self.hidden, self.dim),
-                             ('expert', 'mlp', 'embed'), 'fan_in'),
+            'up': ParamDef(up, ('expert', 'embed')
+                           + ((None,) if self.gated else ()) + ('mlp',),
+                           'normal', self.dim ** -0.5),
+            'down': ParamDef((self.held, self.hidden, self.dim),
+                             ('expert', 'mlp', 'embed'), 'normal',
+                             self.hidden ** -0.5),
         }
 
     def apply(self, params, x):
+        mesh = None if unsharded_execution() else current_mesh()
+        if mesh is None:
+            y, f, p, sizes = self._held_part(
+                x, params['router'], params['up'], params['down'],
+                self.first)
+            total, largest = jnp.sum(sizes), jnp.max(sizes)
+        else:
+            y, f, p, total, largest = self._on_shards(mesh, params, x)
+        # load-balance aux loss (Switch eq. 4): e * sum_e f_e * P_e, f
+        # the share of the tokens whose first choice is e and P the mean
+        # of its probability
+        n = x.shape[0] * x.shape[1]
+        aux = self.n_experts * jnp.sum(f * p) / (n * n)
+        stats = jnp.stack([total, largest]).astype(jnp.float32)
+        return y, aux, stats
+
+    def _held_part(self, x, router, up, down, first):
+        """What the experts ``first ..`` of ``up`` / ``down`` (all their
+        hidden units or a run of them) add for the tokens of ``x [b, s,
+        d]``, on device-local data: ``(y, first choices counted by
+        expert [e], probabilities summed by expert [e], rows of each of
+        these experts)``."""
         b, s, d = x.shape
-        e = self.n_experts
-        cap = max(1, int(self.capacity_factor * s * self.top_k / e))
+        held, hidden = down.shape[0], down.shape[1]
+        tokens = x.reshape(b * s, d)
+        with jax.named_scope('moe_route'):
+            probs, weights, idx = self._route(router, tokens)
+            order = _order(idx - first, weights, held)
+        y = _experts(functools.partial(_hidden, self.act, self.gated, hidden),
+                     tokens.astype(self.dtype), up.reshape(held, d, -1), down,
+                     order['token'], order['weight'], order['tile_group'],
+                     order['live'])
+        f = jnp.sum(jax.nn.one_hot(idx[:, 0], self.n_experts,
+                                   dtype=jnp.float32), axis=0)
+        return y.reshape(b, s, d), f, jnp.sum(probs, axis=0), order['sizes']
 
-        logits = self.router.apply(params['router'],
-                                   x.astype(jnp.float32))   # [b,s,e]
+    def _on_shards(self, mesh, params, x):
+        """:meth:`_held_part` on each device's shard, in a manual region
+        (GSPMD cannot partition the kernels' opaque calls; as
+        ``MultiHeadAttention._kernel_attention``): the tokens by their
+        batch over the data axis, the experts over the axis of
+        ``'expert'`` and their hidden units over that of ``'mlp'``. A
+        device computes what ITS experts and hidden units add for ITS
+        tokens; the parts are added up over the axes that divide the
+        experts (the tokens are whole on each of them: this is an
+        all-reduce of partial sums, not an exchange of tokens), the
+        counts over all. The region is manual over every axis that is
+        not manual already (inside a pipeline stage, over the rest)."""
+        manual = active_manual_axes()
+        live = [a for a, n in mesh.shape.items() if n > 1 and a not in manual]
+        data = AXIS_DATA if AXIS_DATA in live else None
+        by_expert, by_hidden = (
+            a if a in live else None
+            for a in (live_mesh_axis('expert'), live_mesh_axis('mlp')))
+        if data and x.shape[0] % mesh.shape[data] \
+                or by_expert and self.held % mesh.shape[by_expert] \
+                or by_hidden and self.hidden % mesh.shape[by_hidden]:
+            raise ValueError(
+                'MoeMlp: batch %d, %d held experts and %d hidden units do '
+                'not divide over the mesh %s' % (x.shape[0], self.held,
+                                                 self.hidden, dict(mesh.shape)))
+        over_experts = tuple(a for a in (by_expert, by_hidden) if a)
+
+        def part(x, router, up, down):
+            first = self.first
+            if by_expert:
+                first = first + jax.lax.axis_index(by_expert) * down.shape[0]
+            y, f, p, sizes = self._held_part(x, router, up, down, first)
+            if over_experts:
+                y = jax.lax.psum(y, over_experts)
+            if data:
+                f, p, sizes = jax.lax.psum((f, p, sizes), data)
+            total, largest = jnp.sum(sizes), jnp.max(sizes)
+            if by_expert:
+                total = jax.lax.psum(total, by_expert)
+                largest = jax.lax.pmax(largest, by_expert)
+            return y, f, p, total, largest
+
+        tokens = P(data, None, None)
+        gate = (None,) if self.gated else ()
+        return shard_map(
+            part, None if manual else mesh,
+            (tokens, P(), P(by_expert, None, *gate, by_hidden),
+             P(by_expert, by_hidden, None)),
+            (tokens, P(), P(), P(), P()),
+            axis_names=set(live) if manual else None)(
+                x, params['router'], params['up'], params['down'])
+
+    def _route(self, router, tokens):
+        """``(probs [t, e], weights [t, k], idx [t, k])``: the softmax
+        over all the experts in f32, and of its ``top_k`` largest the
+        weights, renormalised, and the experts."""
+        logits = self.router.apply(router, tokens.astype(jnp.float32))
         probs = jax.nn.softmax(logits, axis=-1)
+        vals, idx = jax.lax.top_k(probs, self.top_k)
+        return probs, vals / jnp.maximum(
+            jnp.sum(vals, -1, keepdims=True), 1e-9), idx
 
-        # top-k expert choice per token
-        gate_vals, gate_idx = jax.lax.top_k(probs, self.top_k)  # [b,s,k]
-        gate_vals = gate_vals / jnp.maximum(
-            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
-        # position of each (token, choice) in its expert's buffer via
-        # cumulative count over the flattened (s*k) routing sequence
-        choice_oh = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)  # [b,s,k,e]
-        flat = choice_oh.reshape(b, s * self.top_k, e)
-        pos = jnp.cumsum(flat, axis=1) - flat                 # [b,sk,e]
-        pos = jnp.sum(pos * flat, axis=-1).reshape(b, s, self.top_k)
-        in_cap = pos < cap
+def _hidden(act, gated, f, u):
+    """``h`` of a chunk's first product ``u`` (gate and up side by side
+    for a gated expert), in f32."""
+    return act(u[:, :f]) * u[:, f:] if gated else act(u)
 
-        # dispatch/combine tensors [b, s, k, e, cap] -> summed over k
-        pos_oh = jax.nn.one_hot(pos, cap, dtype=self.dtype)   # [b,s,k,cap]
-        disp = (choice_oh.astype(self.dtype)[..., None] *
-                pos_oh[..., None, :] *
-                in_cap[..., None, None].astype(self.dtype))   # [b,s,k,e,cap]
-        combine = disp * gate_vals[..., None, None].astype(self.dtype)
-        disp = jnp.sum(disp, axis=2)                          # [b,s,e,cap]
-        combine = jnp.sum(combine, axis=2)                    # [b,s,e,cap]
 
-        xe = jnp.einsum('bsec,bsd->becd', disp, x.astype(self.dtype))
-        xe = constrain(xe, ('batch', 'expert', None, 'embed'))
-        h = self.act(jnp.einsum('becd,edh->bech', xe,
-                                params['up'].astype(self.dtype)))
-        h = constrain(h, ('batch', 'expert', None, 'mlp'))
-        ye = jnp.einsum('bech,ehd->becd', h,
-                        params['down'].astype(self.dtype))
-        y = jnp.einsum('bsec,becd->bsd', combine, ye)
+def buffer_rows(tokens, top_k, held):
+    """Rows of the buffer the held pairs are laid out in, at the worst
+    case (every pair of every token held, as far as ``top_k`` and
+    ``held`` allow; a tile of padding an expert), in whole chunks."""
+    chunk = CHUNK_TILES * gm.TILE_ROWS
+    rows = tokens * min(top_k, held) + held * gm.TILE_ROWS
+    return -(-rows // chunk) * chunk
 
-        # load-balance aux loss (Switch eq. 4): e * sum_e f_e * P_e
-        f = jnp.mean(jnp.sum(choice_oh[:, :, 0], axis=1).astype(
-            jnp.float32) / s, axis=0)                         # [e]
-        p = jnp.mean(probs, axis=(0, 1))
-        self_aux = e * jnp.sum(f * p)
-        return y, self_aux
+
+def _order(local, weights, held):
+    """The layout of the pairs ``(token, choice)`` whose expert
+    ``local[t, j]`` (counted from the first held) is held, ``0 <= local <
+    held``: rows by expert, each expert from a tile's start, inside an
+    expert by token. Returns ``token`` and ``weight`` of every row of
+    the buffer (``[buffer_rows]``; a padding row is token 0 at weight
+    0), each tile's expert ``tile_group``, the number of ``live`` tiles
+    (``[1]``) and the experts' ``sizes`` in rows."""
+    t, k = local.shape
+    tile = gm.TILE_ROWS
+    rows = buffer_rows(t, k, held)
+    is_held = jnp.logical_and(local >= 0, local < held)
+    chosen = jnp.any(jnp.logical_and(
+        local[:, :, None] == jnp.arange(held)[None, None, :],
+        is_held[:, :, None]), axis=1).astype(jnp.int32)        # [t, held]
+    sizes = jnp.sum(chosen, axis=0)
+    rank = jnp.cumsum(chosen, axis=0) - chosen                 # [t, held]
+    tiles = -(-sizes // tile)
+    tile_end = jnp.cumsum(tiles)
+    start = (tile_end - tiles) * tile                          # [held]
+    at = jnp.clip(local, 0, held - 1)
+    row = jnp.where(is_held, start[at] + jnp.take_along_axis(rank, at, 1),
+                    rows)                                      # [t, k]
+    token = jnp.zeros((rows,), jnp.int32).at[row.ravel()].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode='drop',
+        unique_indices=True)
+    weight = jnp.zeros((rows,), jnp.float32).at[row.ravel()].set(
+        weights.astype(jnp.float32).ravel(), mode='drop',
+        unique_indices=True)
+    tile_group = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(rows // tile), side='right'),
+        held - 1).astype(jnp.int32)
+    return {'token': token, 'weight': weight, 'tile_group': tile_group,
+            'live': tile_end[-1:].astype(jnp.int32), 'sizes': sizes}
+
+
+# ---------------------------------------------------------------------------
+# the held experts over the ordered rows
+# ---------------------------------------------------------------------------
+
+def _chunk(i, token, weight, tile_group, live):
+    """Chunk ``i`` of the rows: its tokens, weights, tiles' experts, live
+    tiles (``[1]``), and which of its rows lie in a live tile."""
+    rows = CHUNK_TILES * gm.TILE_ROWS
+    at = jax.lax.dynamic_slice_in_dim
+    here = jnp.clip(live - i * CHUNK_TILES, 0, CHUNK_TILES)
+    valid = (jnp.arange(rows) < here[0] * gm.TILE_ROWS)[:, None]
+    return (at(token, i * rows, rows), at(weight, i * rows, rows),
+            at(tile_group, i * CHUNK_TILES, CHUNK_TILES), here, valid)
+
+
+def _chunks(live):
+    return -(-live[0] // CHUNK_TILES)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts(hidden, x, w_up, w_down, token, weight, tile_group, live):
+    """``out[t] = sum over the rows r of token t of weight[r] * (hidden(
+    x[t] @ w_up[e_r]) @ w_down[e_r])`` for ``x [tokens, dim]``, the held
+    experts' ``w_up [held, dim, n * hidden]`` and ``w_down [held, hidden,
+    dim]`` (f32; multiplied in x's dtype) and the rows' layout
+    (:func:`_order`); ``[tokens, dim]`` in x's dtype."""
+    return _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group,
+                        live)[0]
+
+
+def _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group, live):
+    up, down = w_up.astype(x.dtype), w_down.astype(x.dtype)
+
+    def step(carry):
+        i, out = carry
+        tok, wt, groups, here, valid = _chunk(i, token, weight, tile_group,
+                                              live)
+        with jax.named_scope('moe_dispatch'):
+            xs = jnp.take(x, tok, axis=0)
+        with jax.named_scope('moe_experts'):
+            u = gm.gmm(xs, up, groups, here)
+            h = jnp.where(valid, hidden(u.astype(jnp.float32)), 0.0)
+            y = gm.gmm(h.astype(x.dtype), down, groups, here)
+        with jax.named_scope('moe_dispatch'):
+            y = jnp.where(valid, y.astype(jnp.float32) * wt[:, None], 0.0)
+            out = out.at[tok].add(y)
+        return i + 1, out
+
+    _, out = jax.lax.while_loop(
+        lambda carry: carry[0] < _chunks(live), step,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
+    return out.astype(x.dtype), (x, w_up, w_down, token, weight, tile_group,
+                                 live)
+
+
+def _experts_bwd(hidden, res, dout):
+    x, w_up, w_down, token, weight, tile_group, live = res
+    up, down = w_up.astype(x.dtype), w_down.astype(x.dtype)
+
+    def step(carry):
+        i, dx, d_up, d_down, d_weight = carry
+        tok, wt, groups, here, valid = _chunk(i, token, weight, tile_group,
+                                              live)
+        with jax.named_scope('moe_dispatch'):
+            xs = jnp.take(x, tok, axis=0)
+            dy = jnp.where(valid, jnp.take(dout, tok, axis=0), 0)
+        with jax.named_scope('moe_experts'):
+            u = gm.gmm(xs, up, groups, here)
+            h, h_vjp = jax.vjp(hidden, u.astype(jnp.float32))
+            h = jnp.where(valid, h, 0.0)
+            # d(weight) and d(h) from the unweighted dy @ down^T
+            dh = jnp.where(valid, gm.gmm(dy, down, groups, here,
+                                         transposed=True).astype(jnp.float32),
+                           0.0)
+            d_wt = jnp.sum(h * dh, axis=-1)
+            du = h_vjp(dh * wt[:, None])[0].astype(x.dtype)
+            d_down = gm.gmm_dw(h.astype(x.dtype),
+                            (dy.astype(jnp.float32)
+                             * wt[:, None]).astype(x.dtype),
+                            groups, here, d_down)
+            d_up = gm.gmm_dw(xs, du, groups, here, d_up)
+            dxs = gm.gmm(du, up, groups, here, transposed=True)
+        with jax.named_scope('moe_dispatch'):
+            dx = dx.at[tok].add(jnp.where(valid, dxs.astype(jnp.float32),
+                                          0.0))
+            d_weight = jax.lax.dynamic_update_slice_in_dim(
+                d_weight, d_wt, i * d_wt.shape[0], axis=0)
+        return i + 1, dx, d_up, d_down, d_weight
+
+    _, dx, d_up, d_down, d_weight = jax.lax.while_loop(
+        lambda carry: carry[0] < _chunks(live), step,
+        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(w_up.shape, jnp.float32),
+         jnp.zeros(w_down.shape, jnp.float32),
+         jnp.zeros(weight.shape, jnp.float32)))
+    return (dx.astype(x.dtype), d_up.astype(w_up.dtype),
+            d_down.astype(w_down.dtype), None, d_weight, None, None)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)

@@ -18,9 +18,10 @@ from autodist_tpu.const import AXIS_PIPELINE, AXIS_SEQUENCE
 from autodist_tpu.kernels import flash_attention as fa
 from autodist_tpu.models.attention import MultiHeadAttention
 from autodist_tpu.models.core import (Dense, Embedding, GatedMlp, LayerNorm,
-                                      Mlp, Module, ParamDef, constrain,
-                                      gelu_exact)
-from autodist_tpu.parallel.axes import ctx_option, manual_axis
+                                      Mlp, Module, ParamDef, RMSNorm,
+                                      activation, constrain, record_counter)
+from autodist_tpu.parallel.axes import (active_manual_axes, ctx_option,
+                                        manual_axis)
 
 
 @dataclass
@@ -57,9 +58,15 @@ class TransformerConfig:
     # ~8 GB (batch 768 compiles where 640 OOMed before) at unchanged
     # tokens/s; it is what makes big-vocab / long-seq losses fit.
     loss_chunk: int = 0
-    moe_experts: int = 0         # >0: MoE MLP with this many experts
+    moe_experts: int = 0         # >0: MoE MLP; the router's width
     moe_top_k: int = 2
     moe_aux_coef: float = 0.01   # load-balance loss weight
+    moe_held: object = None      # the experts this program holds of each
+    #                              layer's moe_experts: a count (from
+    #                              expert 0) or (first, count); None: all.
+    #                              The router keeps its width and its
+    #                              top_k; the layer computes what its own
+    #                              experts add (models/moe.py)
     # -- the block's variants (docs/usage/layer-patterns.md). Each field
     # describes the architecture; the defaults are GPT-2's block.
     positions: str = 'learned'   # 'learned': a table of max_len rows
@@ -69,14 +76,30 @@ class TransformerConfig:
     window: object = None        # keys EACH SIDE a window layer attends
     #                              to (ModernBERT's local_attention 128
     #                              is 64); None: every layer is global
+    #                              (under `causal`: keys before it, so
+    #                              1023 is a causal window of 1024)
     global_every: int = 1        # with a window: layer i is global iff
-    #                              i % global_every == 0, else a window
-    #                              layer
+    #                              i % global_every == global_at, else a
+    #                              window layer
+    global_at: int = 0           # the global layer's place in a period:
+    #                              ModernBERT's first (0), Mellum2's last
+    #                              (global_every - 1)
     window_rope_theta: object = None   # rotary base of the window
     #                              layers; None: rope_theta
-    mlp_dim: object = None       # MLP width; None: dim * mlp_ratio
-    gated_mlp: bool = False      # GeGLU: down(act(input) * gate)
-    gelu: str = 'tanh'           # 'tanh' (GPT-2's gelu_new) | 'erf'
+    rope_yarn: object = None     # YaRN on the GLOBAL layers' rotary
+    #                              frequencies: a mapping with factor,
+    #                              original_max_position_embeddings,
+    #                              beta_fast, beta_slow, attention_factor
+    #                              (models/attention.rope_frequencies)
+    n_kv_heads: object = None    # grouped kv heads; None: n_heads
+    head_dim: object = None      # None: dim // n_heads
+    mlp_dim: object = None       # MLP (or expert) width; None: dim *
+    #                              mlp_ratio
+    gated_mlp: bool = False      # down(act(input) * gate): GeGLU, SwiGLU
+    gelu: str = 'tanh'           # the MLP's activation by name: 'tanh'
+    #                              (GPT-2's gelu_new) | 'erf' | 'silu'
+    norm: str = 'layer'          # 'layer' (LayerNorm) | 'rms' (RMSNorm:
+    #                              no mean, no bias)
     norm_eps: float = 1e-6
     norm_bias: bool = True       # LayerNorms carry a bias
     mlp_bias: bool = True
@@ -86,29 +109,39 @@ class TransformerConfig:
     head_transform: bool = False  # prediction head: dense, act, norm
     #                              before the decoder (BERT's MLM head)
     decoder_bias: bool = False
+    embed_init_scale: float = 0.02   # std of an embedding row's elements
+    #                              as drawn at init
 
     def __post_init__(self):
         if self.positions not in ('learned', 'rotary'):
             raise ValueError("positions must be 'learned' or 'rotary', "
                              'not %r' % (self.positions,))
-        if self.gelu not in ('tanh', 'erf'):
-            raise ValueError("gelu must be 'tanh' or 'erf', not %r"
-                             % (self.gelu,))
+        activation(self.gelu)
+        if self.norm not in ('layer', 'rms'):
+            raise ValueError("norm must be 'layer' or 'rms', not %r"
+                             % (self.norm,))
         if self.window is not None and self.global_every < 2:
             raise ValueError(
                 'window=%r needs global_every >= 2 (layer i is global iff '
-                'i %% global_every == 0); with global_every=%d no layer '
-                'would use the window' % (self.window, self.global_every))
-        if self.window is not None and self.causal:
-            raise ValueError('window layers under a causal mask are not '
-                             'supported')
-        if self.moe_experts and self.gated_mlp:
-            raise ValueError('gated_mlp with moe_experts is not supported')
+                'i %% global_every == global_at); with global_every=%d no '
+                'layer would use the window'
+                % (self.window, self.global_every))
+        if not 0 <= self.global_at < self.global_every:
+            raise ValueError('global_at=%d is no place in a period of %d '
+                             'layers' % (self.global_at, self.global_every))
 
     def layer_kinds(self):
         """'global' or 'window' for each layer."""
-        return ['global' if self.window is None or i % self.global_every == 0
+        return ['global' if self.window is None
+                or i % self.global_every == self.global_at
                 else 'window' for i in range(self.n_layers)]
+
+    def held_experts(self):
+        """``(first, count)`` of the experts this program holds."""
+        held = self.moe_held
+        if held is None:
+            return 0, self.moe_experts
+        return (0, held) if isinstance(held, int) else tuple(held)
 
     @classmethod
     def modernbert_large(cls, **kw):
@@ -148,13 +181,20 @@ class TransformerConfig:
         return cls(**d)
 
 
+def _add(a, b):
+    """Sum of two ``aux`` values: scalars, or ``(aux, stats)`` pairs."""
+    return jax.tree.map(jnp.add, a, b)
+
+
 def _norm(cfg):
+    if cfg.norm == 'rms':
+        return RMSNorm(cfg.dim, eps=cfg.norm_eps, dtype=cfg.dtype)
     return LayerNorm(cfg.dim, eps=cfg.norm_eps, dtype=cfg.dtype,
                      use_bias=cfg.norm_bias)
 
 
 def _act(cfg):
-    return gelu_exact if cfg.gelu == 'erf' else jax.nn.gelu
+    return activation(cfg.gelu)
 
 
 class Block(Module):
@@ -166,7 +206,8 @@ class Block(Module):
     identity (the first layer after an embedding norm).
 
     ``apply`` returns ``(x, aux)`` where aux is the router load-balance
-    loss contribution (0.0 for dense blocks)."""
+    loss contribution (0.0 for dense blocks); with ``stats`` ``(x, (aux,
+    stats))``, the expert layer's load beside it (``MoeMlp.apply``)."""
 
     def __init__(self, cfg, kind='global', attn_norm=True):
         self.cfg = cfg
@@ -177,15 +218,19 @@ class Block(Module):
                 cfg.window_rope_theta is not None else cfg.rope_theta
         self.ln1 = _norm(cfg) if attn_norm else None
         self.attn = MultiHeadAttention(
-            cfg.dim, cfg.n_heads, causal=cfg.causal, dtype=cfg.dtype,
-            rope_theta=theta, window=cfg.window if windowed else None)
+            cfg.dim, cfg.n_heads, head_dim=cfg.head_dim, causal=cfg.causal,
+            dtype=cfg.dtype, rope_theta=theta,
+            window=cfg.window if windowed else None,
+            num_kv_heads=cfg.n_kv_heads,
+            rope_yarn=None if windowed else cfg.rope_yarn)
         self.ln2 = _norm(cfg)
         hidden = cfg.mlp_dim or cfg.dim * cfg.mlp_ratio
         if cfg.moe_experts:
             from autodist_tpu.models.moe import MoeMlp
             self.mlp = MoeMlp(cfg.dim, hidden,
                               cfg.moe_experts, top_k=cfg.moe_top_k,
-                              dtype=cfg.dtype)
+                              held=cfg.held_experts(), dtype=cfg.dtype,
+                              act=_act(cfg), gated=cfg.gated_mlp)
         elif cfg.gated_mlp:
             self.mlp = GatedMlp(cfg.dim, hidden, dtype=cfg.dtype,
                                 act=_act(cfg), use_bias=cfg.mlp_bias)
@@ -200,7 +245,7 @@ class Block(Module):
         return d
 
     @jax.named_scope('block')
-    def apply(self, params, x, tables=None):
+    def apply(self, params, x, tables=None, stats=False):
         """``tables``: the attention's ``position_tables`` for ``x``,
         where the model made them once for its layers."""
         with jax.named_scope('attention'):
@@ -213,7 +258,9 @@ class Block(Module):
                                self.ln2.apply(params['ln2'], x))
             aux = jnp.zeros((), jnp.float32)
             if self.cfg.moe_experts:
-                h, aux = h
+                h, aux, load = h
+                if stats:
+                    aux = (aux, load)
             x = x + h
         return constrain(x, ('batch', 'seq', 'embed')), aux
 
@@ -238,7 +285,8 @@ class TransformerLM(Module):
 
     def __init__(self, cfg):
         self.cfg = cfg
-        self.embed = Embedding(cfg.vocab, cfg.dim, dtype=cfg.dtype)
+        self.embed = Embedding(cfg.vocab, cfg.dim, dtype=cfg.dtype,
+                               init_scale=cfg.embed_init_scale)
         # 'pos' is deliberately unmapped (replicated): in sequence-parallel
         # mode every shard looks up its own global positions locally.
         self.pos_embed = Embedding(cfg.max_len, cfg.dim,
@@ -369,16 +417,17 @@ class TransformerLM(Module):
         def tables(block):
             attn = block.attn
             shape = (b, attn.num_heads, s, attn.head_dim)
-            kind = (attn.rope_theta, attn.kernel_shape(shape))
+            kind = (attn.rope, attn.kernel_shape(shape))
             if kind not in made:
                 made[kind] = attn.position_tables(shape)
             return made[kind]
         return tables
 
-    def _block_fn(self, block=None, tables=None):
+    def _block_fn(self, block=None, tables=None, stats=False):
         """Single-block apply (``block``: the plain model's by default;
-        ``tables``: its ``_position_tables``, which it closes over) with
-        the remat policy applied.
+        ``tables``: its ``_position_tables``, which it closes over;
+        ``stats``: the expert layers' load beside ``aux``) with the
+        remat policy applied.
 
         ``cfg.remat``: False (no remat), True (recompute the block in
         the backward, all but the flash kernel's forward call: the
@@ -396,6 +445,8 @@ class TransformerLM(Module):
         block_fn = (block or self.block).apply
         if tables is not None:
             block_fn = functools.partial(block_fn, tables=tables)
+        if stats:
+            block_fn = functools.partial(block_fn, stats=True)
         if isinstance(cfg.remat, str):
             policies = {
                 'save_attn':
@@ -426,6 +477,11 @@ class TransformerLM(Module):
         tables = self._position_tables(x)
         aux_total = jnp.zeros((), jnp.float32)
         pipe_axis = manual_axis(AXIS_PIPELINE)
+        # the expert layers' load rides beside aux through the layer
+        # loops (not through the pipeline schedules, whose aux is a scalar)
+        stats = bool(cfg.moe_experts) and pipe_axis is None
+        if stats:
+            aux_total = (aux_total, jnp.zeros((2,), jnp.float32))
         self._note_layers()
         self._note_remat(x)
         if pipe_axis is not None:
@@ -439,33 +495,52 @@ class TransformerLM(Module):
                                   pipe_axis, ctx_option('microbatches', 1))
             aux_total = aux_total + aux_pipe
         elif cfg.scan_layers and not self.patterned:
-            block_fn = self._block_fn(tables=tables(self.block))
+            block_fn = self._block_fn(tables=tables(self.block), stats=stats)
 
             def body(carry, layer_params):
                 h, aux = carry
                 h, a = block_fn(layer_params, h)
-                return (h, aux + a), None
+                return (h, _add(aux, a)), None
             (x, aux_total), _ = jax.lax.scan(
                 body, (x, aux_total), params['blocks'])
         else:
             for i, block in enumerate(self._lead_blocks):
-                x, a = self._block_fn(block, tables(block))(
+                x, a = self._block_fn(block, tables(block), stats)(
                     params['block_%03d' % i], x)
-                aux_total = aux_total + a
+                aux_total = _add(aux_total, a)
             if cfg.scan_layers:
                 x, aux_total = self._scan_periods(params['blocks'], x,
-                                                  aux_total, tables)
+                                                  aux_total, tables, stats)
+        if stats:
+            aux_total, load = aux_total
+            self._count_load(load)
         with jax.named_scope('head_loss'):
             x = self.ln_f.apply(params['ln_f'], x)
         return x, aux_total
 
-    def _scan_periods(self, stacks, x, aux_total, tables):
+    def _count_load(self, load):
+        """The step's counters of the expert layers (``load``: rows held
+        here and the largest load of a held expert, summed over the
+        layers), for the trainer to read back with the loss
+        (``core.record_counter``): the mean over the layers of the rows
+        held here, of the largest and of the mean load of a held
+        expert. Not inside a manual region, whose values cannot leave
+        it this way."""
+        if active_manual_axes():
+            return
+        cfg = self.cfg
+        rows, largest = load[0] / cfg.n_layers, load[1] / cfg.n_layers
+        record_counter('moe_rows_here', rows)
+        record_counter('moe_load_max', largest)
+        record_counter('moe_load_mean', rows / cfg.held_experts()[1])
+
+    def _scan_periods(self, stacks, x, aux_total, tables, stats=False):
         """``periods`` scan steps over ``stacks[kind]``, each running one
         period's layers in order, each under the remat policy: a kind's
         stack ``[periods * c, ...]`` is seen as ``[periods, c, ...]``
         and the period's ``c`` layers of that kind index the second.
         ``tables``: ``_position_tables``."""
-        fns = {kind: self._block_fn(block, tables(block))
+        fns = {kind: self._block_fn(block, tables(block), stats)
                for kind, block in self._kind_blocks.items()}
         per_period = {
             kind: jax.tree.map(
@@ -481,7 +556,7 @@ class TransformerLM(Module):
                                      period_params[kind])
                 seen[kind] += 1
                 h, a = fns[kind](layer, h)
-                aux = aux + a
+                aux = _add(aux, a)
             return (h, aux), None
         (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), per_period)
         return x, aux_total

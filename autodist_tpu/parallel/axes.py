@@ -232,8 +232,14 @@ def shard_map(f, mesh, in_specs, out_specs, axis_names=None):
     which is a no-op for the math: Mosaic refuses to lower a Pallas
     kernel while ANY mesh axis is still automatic, so a region whose
     named axes are all the size>1 ones must not leave the idle ones
-    auto."""
+    auto. ``mesh=None`` nests a region, manual over ``axis_names``,
+    inside a manual one."""
     names = set(axis_names or ())
+    if mesh is None:
+        # a region nested in a manual one: under the mesh of the trace,
+        # whose size-1 axes the outer region has taken already
+        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                             axis_names=names, check_vma=False)
     if names:
         names |= {a for a, n in mesh.shape.items() if n == 1}
     return jax.shard_map(f, mesh=mesh, in_specs=in_specs,

@@ -101,7 +101,8 @@ def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None):
     the non-SP fallback so numerics match ring_attention bit-for-bit-ish.
 
     ``window = (left, right)`` keeps keys ``i - left .. i + right`` for
-    query ``i`` (not with ``causal``). A sequence several bands long is
+    query ``i``; under ``causal`` the band is ``(left, 0)`` and holds
+    the mask. A sequence several bands long is
     computed in query blocks against the keys each block's band reaches
     (:func:`_band_attention`), so no ``[s, s]`` score matrix exists; a
     short one under a dense mask."""
@@ -109,8 +110,7 @@ def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None):
         sm_scale = q.shape[-1] ** -0.5
     if window is not None:
         if causal:
-            raise ValueError('local_flash_attention: a window under a '
-                             'causal mask is not supported')
+            window, causal = (window[0], 0), False
         block = _band_block(q.shape[2], window)
         if block:
             return _band_attention(q, k, v, window, sm_scale, block)

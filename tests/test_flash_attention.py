@@ -147,7 +147,7 @@ def test_plan_branch_parity(case):
 
     def kernel(q, k, v):
         return _split(fa._flash((_merge(q), _merge(k), _merge(v)), None, h,
-                                causal, scale, plan, True), h)
+                                h, causal, scale, plan, True), h)
 
     def plain(q, k, v):
         return local_flash_attention(q, k, v, causal=causal)
@@ -354,18 +354,32 @@ def test_window_none_is_todays_plan_for_the_cells():
         B(1024, 1024, 2), B(512, 512, 4), B(512, 512, 4))
     assert fa._plan((4, 16, 8192, 64), False, window=(64, 64)) == fa.Plan(
         B(128, 512, 8), B(256, 256, 8), B(128, 256, 8))
+    # a wide band's tiles stop at their kernel's cap, swept on the chip
+    # at Mellum2's causal window of 1024 keys (PR 33): the forward's at
+    # 1024 a side, the backward kernels' at 256
     assert fa._plan((4, 16, 8192, 64), False, window=(300, 10)).dq[:2] \
-        == (1024, 1024)
+        == (256, 256)
+    assert fa._plan((4, 32, 8192, 128), False, window=(1023, 0),
+                    kv_heads=4) == fa.Plan(
+        B(1024, 1024, 1), B(256, 256, 8), B(256, 256, 8))
+    # grouped kv heads: a step's query heads divide a group of 8
+    assert fa._plan((4, 32, 8192, 128), True, kv_heads=4) == fa.Plan(
+        B(1024, 1024, 1), B(512, 512, 4), B(512, 512, 4))
 
 
-def test_window_under_a_causal_mask_is_refused():
-    q = jnp.zeros((1, 1, 64, 16))
-    with pytest.raises(ValueError, match='window under a causal mask'):
-        fa.flash_attention(q, q, q, causal=True, window=(8, 8))
+def test_window_under_a_causal_mask_is_the_causal_band():
+    """``window=(left, right)`` under ``causal=True`` is the band
+    ``(left, 0)``, in the kernels and on the XLA path."""
+    assert fa.check_window((8, 8), causal=True) == (8, 0)
+    rng = np.random.RandomState(5)
+    q, k, v = _rand_qkv(rng, (1, 2, 64, 16))
+    want = local_flash_attention(q, k, v, causal=False, window=(8, 0))
+    for got in (fa.flash_attention(q, k, v, causal=True, window=(8, 8)),
+                local_flash_attention(q, k, v, causal=True, window=(8, 8))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
     with pytest.raises(ValueError, match='neither negative'):
         fa.flash_attention(q, q, q, causal=False, window=(-1, 8))
-    with pytest.raises(ValueError, match='window under a causal mask'):
-        local_flash_attention(q, q, q, causal=True, window=(8, 8))
     assert fa.supports((1, 1, 128, 64), window=(8, 8))
     assert fa.preferred((4, 16, 8192, 64), window=(64, 64))
     assert not fa.preferred((1, 1, 128, 64), window=(8, 8))
@@ -502,8 +516,8 @@ def test_rotary_on_the_tile_is_rotary_before_the_call(case):
 
     # 1. bf16 operands and exact products: the bits of o and lse
     def forward(operands, tables):
-        return fa._fwd(operands, tables, h, False, d ** -0.5, plan.fwd, True,
-                       window)
+        return fa._fwd(operands, tables, h, h, False, d ** -0.5, plan.fwd,
+                       True, window)
     coarse = tuple(t.astype(jnp.bfloat16).astype(jnp.float32) for t in tables)
     head = tuple(t[:, :d] for t in coarse)
     q, k, v = jnp.split(qkv.astype(jnp.bfloat16), 3, axis=-1)
@@ -518,8 +532,8 @@ def test_rotary_on_the_tile_is_rotary_before_the_call(case):
     # 2. f32, the real tables, rotary() before the call
     def o_and_grad(operands_of, tables):
         def loss(qkv):
-            o = fa._planned(operands_of(qkv), tables, h, False, None, block_q,
-                            block_k, True, window, named=True)
+            o = fa._planned(operands_of(qkv), tables, h, h, False, None,
+                            block_q, block_k, True, window, named=True)
             return jnp.sum(o * w), o
         (_, o), g = jax.value_and_grad(loss, has_aux=True)(qkv)
         return o, g
@@ -793,3 +807,98 @@ def test_module_dispatches_to_kernel(monkeypatch):
     out = mha.apply(params, x)
     assert out.shape == (2, 32, 32)
     assert calls.get('hit'), 'kernel path not taken for local execution'
+
+
+# ---------------------------------------------------------------------------
+# grouped kv heads and the causal band (PR 33)
+# ---------------------------------------------------------------------------
+
+def _repeated_head_form(q, k, v, h, kv, causal, window, theta=None):
+    """Plain attention on ``[b, s, heads * d]`` operands with each kv
+    head repeated for the query heads of its group."""
+    from autodist_tpu.models.attention import rotary
+    b, s, _ = q.shape
+    d = q.shape[-1] // h
+    qh, kh, vh = (_split(x, n) for x, n in ((q, h), (k, kv), (v, kv)))
+    if theta is not None:
+        qh, kh = (rotary(x, jnp.arange(s), theta) for x in (qh, kh))
+    kh, vh = (jnp.repeat(x, h // kv, axis=1) for x in (kh, vh))
+    return _merge(local_flash_attention(qh, kh, vh, causal=causal,
+                                        window=window))
+
+
+# (heads, kv heads, seq, head_dim, causal, window, rotary, packed): the
+# one-pass and the multi-block causal paths, a step that holds a whole
+# group and one that holds part of it, the causal band (w - 1, 0) on one
+# block and on several, one array or three
+_GQA_CASES = {
+    'one_pass_causal': (4, 2, 256, 128, True, None, False, False),
+    'one_pass_rotary_packed': (4, 2, 256, 128, True, None, True, True),
+    'multi_block_causal': (8, 2, 2048, 128, True, None, True, True),
+    'band_1_kv_head': (4, 1, 1024, 128, True, (255, 0), True, True),
+    'band_wide': (4, 2, 1024, 128, True, (1023, 7), False, False),
+    'not_causal_d256': (2, 1, 512, 256, False, None, False, True),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_GQA_CASES))
+def test_grouped_kv_heads_match_the_repeated_head_form(case):
+    """Forward and the gradients of q, k and v: ``flash_dkv`` adds a kv
+    head's dk and dv up over its group inside the kernel, where
+    ``jax.grad`` of the repeated-head form sums the copies."""
+    from autodist_tpu.models.attention import rope_frequencies
+    h, kv, s, d, causal, window, rot, packed = _GQA_CASES[case]
+    rng = np.random.RandomState(0)
+    q, k, v, w = (jnp.asarray(rng.randn(1, s, n * d), jnp.float32)
+                  for n in (h, kv, kv, h))
+    theta = rope_frequencies(500000.0, d, dict(
+        factor=16.0, original_max_position_embeddings=64, beta_fast=32.0,
+        beta_slow=1.0, attention_factor=1.25)) if rot else None
+    tables = fa.rotary_tables(jnp.arange(s), theta, h, d) if rot else None
+
+    def kernel(q, k, v):
+        operands = (jnp.concatenate([q, k, v], -1),) if packed else (q, k, v)
+        o = fa.flash_attention_merged(operands, h, causal=causal,
+                                      window=window, rotary=tables,
+                                      kv_heads=kv, interpret=True)
+        return jnp.sum(o * w), o
+
+    def plain(q, k, v):
+        o = _repeated_head_form(q, k, v, h, kv, causal, window, theta)
+        return jnp.sum(o * w), o
+    (_, got_o), got = jax.value_and_grad(kernel, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    (_, want_o), want = jax.value_and_grad(plain, (0, 1, 2), has_aux=True)(
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-4)
+
+
+@pytest.mark.parametrize('w', [64, 256, 1024])
+def test_causal_band_matches_a_masked_softmax(w):
+    """The band ``(w - 1, 0)``: query i sees keys j with ``0 <= i - j <
+    w``, against a softmax under that mask written out."""
+    rng = np.random.RandomState(1)
+    q, k, v = _rand_qkv(rng, (1, 2, 1024, 64))
+    back = np.arange(1024)[:, None] - np.arange(1024)[None, :]
+    scores = jnp.einsum('bhqd,bhkd->bhqk', q, k) / 8.0
+    scores = jnp.where((back >= 0) & (back < w), scores, -jnp.inf)
+    want = jnp.einsum('bhqk,bhkd->bhqd', jax.nn.softmax(scores, -1), v)
+    got = fa.flash_attention(q, k, v, causal=True, window=(w - 1, w - 1))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_grouped_kv_heads_need_a_head_to_be_a_lane_block():
+    assert fa.supports((4, 32, 8192, 128), kv_heads=4)
+    assert fa.preferred((4, 32, 8192, 128), (1023, 0), kv_heads=4)
+    assert not fa.supports((4, 16, 8192, 64), kv_heads=4)
+    assert not fa.supports((4, 6, 8192, 128), kv_heads=4)
+    assert fa.supports((4, 16, 8192, 64), kv_heads=16)
+    q = jnp.zeros((1, 64, 4 * 64))
+    kv = jnp.zeros((1, 64, 2 * 64))
+    with pytest.raises(ValueError, match='need a head to be a lane block'):
+        fa.flash_attention_merged((q, kv, kv), 4, kv_heads=2)

@@ -181,8 +181,16 @@ def test_layouts_that_take_no_pattern_say_so(spec_kw, complaint):
 def test_config_refuses_what_it_cannot_mean():
     with pytest.raises(ValueError, match='global_every >= 2'):
         TransformerConfig.tiny(causal=False, window=8)
-    with pytest.raises(ValueError, match='causal'):
-        TransformerConfig.tiny(window=8, global_every=2)
+    with pytest.raises(ValueError, match='no place in a period'):
+        TransformerConfig.tiny(causal=False, window=8, global_every=2,
+                               global_at=2)
+    with pytest.raises(ValueError, match="'layer' or 'rms'"):
+        TransformerConfig.tiny(norm='batch')
+    with pytest.raises(ValueError, match='activation must be one of'):
+        TransformerConfig.tiny(gelu='relu')
+    # a window under a causal mask is the causal band since PR 33
+    assert TransformerConfig.tiny(window=8, global_every=2).layer_kinds() \
+        == ['global', 'window']
     with pytest.raises(ValueError, match="'learned' or 'rotary'"):
         TransformerConfig.tiny(positions='alibi')
 

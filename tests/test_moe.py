@@ -1,0 +1,279 @@
+"""The expert layer (``models/moe.py``) and its grouped products
+(``kernels/grouped_matmul.py``), PR 33: the kernels in interpret mode
+against a loop over the groups, the layer against a loop over the
+experts (loss and every gradient), the share of a deployment (the parts
+that all the shares give add up to the uncut layer), and that no row is
+dropped at the worst routing the buffers are sized for."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from autodist_tpu import telemetry
+from autodist_tpu.api import Trainer
+from autodist_tpu.kernels import grouped_matmul as gm
+from autodist_tpu.models import moe
+from autodist_tpu.models.moe import MoeMlp
+from autodist_tpu.models.transformer import TransformerConfig, TransformerLM
+
+TILE = gm.TILE_ROWS
+
+
+def layout(sizes, spare_tiles=1):
+    """Rows by group, each group from a tile's start: ``(tile_group,
+    live, rows of each group)`` as ``moe._order`` lays them out, with
+    ``spare_tiles`` dead tiles after the live ones."""
+    tiles = [-(-n // TILE) for n in sizes]
+    tile_group = np.repeat(np.arange(len(sizes)), tiles)
+    live = len(tile_group)
+    tile_group = np.concatenate(
+        [tile_group, np.full(spare_tiles, len(sizes) - 1)]).astype(np.int32)
+    starts = np.cumsum([0] + tiles[:-1]) * TILE
+    return (jnp.asarray(tile_group), jnp.asarray([live], jnp.int32),
+            [np.arange(s, s + n) for s, n in zip(starts, sizes)])
+
+
+# an empty group (twice: first and in the middle), a group of one row,
+# every row in one group, groups that end inside a tile and on its edge
+_GROUPS = {
+    'an_empty_group': [0, 300, 0, 256],
+    'a_group_of_one_row': [1, 257, 40],
+    'every_row_in_one_group': [0, 0, 700],
+    'whole_tiles': [256, 512],
+}
+
+
+@pytest.mark.parametrize('case', sorted(_GROUPS))
+def test_grouped_products_match_a_loop_over_the_groups(case):
+    sizes = _GROUPS[case]
+    tile_group, live, rows = layout(sizes)
+    m, k, n, g = TILE * len(tile_group), 24, 40, len(sizes)
+    rng = np.random.RandomState(3)
+    lhs = rng.randn(m, k).astype('f4')
+    rhs = rng.randn(g, k, n).astype('f4')
+    dy = rng.randn(m, n).astype('f4')
+    acc = rng.randn(g, k, n).astype('f4')
+    args = (tile_group, live)
+    out = np.asarray(gm.gmm(jnp.asarray(lhs), jnp.asarray(rhs), *args))
+    dx = np.asarray(gm.gmm(jnp.asarray(dy), jnp.asarray(rhs), *args,
+                           transposed=True))
+    dw = np.asarray(gm.gmm_dw(jnp.asarray(lhs), jnp.asarray(dy), *args,
+                              jnp.asarray(acc)))
+    for e, at in enumerate(rows):
+        np.testing.assert_allclose(out[at], lhs[at] @ rhs[e], rtol=1e-5,
+                                   atol=1e-5)
+        np.testing.assert_allclose(dx[at], dy[at] @ rhs[e].T, rtol=1e-5,
+                                   atol=1e-5)
+        # a group's padding rows count too: they are its tile's rows
+        tile_rows = np.flatnonzero(np.repeat(np.asarray(tile_group), TILE)
+                                   [:TILE * int(live[0])] == e)
+        np.testing.assert_allclose(
+            dw[e], acc[e] + lhs[tile_rows].T @ dy[tile_rows], rtol=1e-4,
+            atol=1e-4)
+    # the same through jax.lax.ragged_dot, the tests' second opinion
+    live_rows = TILE * int(live[0])
+    np.testing.assert_allclose(
+        np.asarray(gm.reference(jnp.asarray(lhs), jnp.asarray(rhs),
+                                *args))[:live_rows], out[:live_rows],
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(gm.reference_dw(jnp.asarray(lhs), jnp.asarray(dy), *args,
+                                   jnp.asarray(acc))), dw, rtol=1e-4,
+        atol=1e-4)
+
+
+def test_tiles_past_the_live_ones_are_skipped():
+    """With no live tile a call computes nothing: ``gmm_dw`` hands its
+    accumulator back as it came; with one live tile of three only that
+    group's matrix moves."""
+    tile_group = jnp.asarray([0, 1, 2], jnp.int32)
+    rng = np.random.RandomState(0)
+    lhs = jnp.asarray(rng.randn(3 * TILE, 8), jnp.float32)
+    rhs = jnp.asarray(rng.randn(3 * TILE, 16), jnp.float32)
+    acc = jnp.asarray(rng.randn(3, 8, 16), jnp.float32)
+    none = gm.gmm_dw(lhs, rhs, tile_group, jnp.asarray([0], jnp.int32), acc)
+    np.testing.assert_array_equal(np.asarray(none), np.asarray(acc))
+    one = np.asarray(gm.gmm_dw(lhs, rhs, tile_group,
+                               jnp.asarray([1], jnp.int32), acc))
+    np.testing.assert_array_equal(one[1:], np.asarray(acc)[1:])
+    np.testing.assert_allclose(
+        one[0], np.asarray(acc[0] + lhs[:TILE].T @ rhs[:TILE]), rtol=1e-5,
+        atol=1e-5)
+
+
+def whole_layer(p, x, first, held, top_k, gated, act):
+    """The plain form: every held expert for every token, weighted."""
+    t = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(t @ p['router']['kernel'], -1)
+    vals, idx = jax.lax.top_k(probs, top_k)
+    w = vals / vals.sum(-1, keepdims=True)
+    out = 0
+    for e in range(first, first + held):
+        we = jnp.sum(jnp.where(idx == e, w, 0), -1)
+        up = p['up'][e - first]
+        h = act(t @ up[:, 0]) * (t @ up[:, 1]) if gated else act(t @ up)
+        out = out + we[:, None] * (h @ p['down'][e - first])
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize('gated,held', [(True, (2, 4)), (False, (0, 8)),
+                                        (True, (7, 1))])
+def test_layer_matches_a_loop_over_its_experts(gated, held):
+    act = jax.nn.silu if gated else jax.nn.gelu
+    layer = MoeMlp(32, 16, 8, top_k=2, held=held, act=act, gated=gated)
+    p = layer.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32))
+
+    def plain(p, x):
+        return whole_layer(p, x, *held, 2, gated, act)
+    y, _, stats = layer.apply(p, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(plain(p, x)),
+                               atol=2e-5)
+    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(layer.apply(p, x)[0])),
+                   argnums=(0, 1))(p, x)
+    want = jax.grad(lambda p, x: jnp.sum(jnp.sin(plain(p, x))),
+                    argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+    # the counters: rows held here, and the largest load of a held expert
+    _, idx = jax.lax.top_k(jax.nn.softmax(
+        x.reshape(-1, 32) @ p['router']['kernel'], -1), 2)
+    local = np.asarray(idx) - held[0]
+    loads = np.bincount(local[(local >= 0) & (local < held[1])],
+                        minlength=held[1])
+    np.testing.assert_array_equal(np.asarray(stats),
+                                  [loads.sum(), loads.max()])
+
+
+def test_the_shares_of_a_deployment_add_up_to_the_whole_layer():
+    """The ``model-configs`` guide's §4: experts ``0..3`` and ``4..7`` of
+    the same weights, each share computing its own part, add up to what
+    the uncut layer gives; so do eight shares of one expert."""
+    whole = MoeMlp(32, 16, 8, top_k=3, act=jax.nn.silu, gated=True)
+    p = whole.init(jax.random.PRNGKey(4))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, 32))
+    want = whole_layer(p, x, 0, 8, 3, True, jax.nn.silu)
+    np.testing.assert_allclose(np.asarray(whole.apply(p, x)[0]),
+                               np.asarray(want), atol=2e-5)
+    for count in (4, 1):
+        total, rows = 0, 0
+        for first in range(0, 8, count):
+            share = MoeMlp(32, 16, 8, top_k=3, held=(first, count),
+                           act=jax.nn.silu, gated=True)
+            ps = dict(p, up=p['up'][first:first + count],
+                      down=p['down'][first:first + count])
+            y, _, stats = share.apply(ps, x)
+            total, rows = total + y, rows + float(stats[0])
+        np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                                   atol=3e-5)
+        assert rows == 2 * 24 * 3     # every pair is some share's row
+
+
+@pytest.mark.parametrize('parallel', [
+    dict(dp=8), dict(dp=2, ep=2, tp=2), dict(dp=1, ep=4, tp=1)],
+    ids=['dp8', 'dp2_ep2_tp2', 'ep4'])
+def test_layer_on_shards_matches_the_layer_on_one_device(parallel):
+    """Under a mesh that shards the tokens (data), the experts (expert)
+    or their hidden units (model) the layer runs its kernels on each
+    device's shard in a manual region and adds the parts up: output,
+    every gradient, the auxiliary loss and the counters are those of
+    the layer on one device."""
+    from autodist_tpu.parallel.axes import ParallelSpec, sharding_ctx
+    layer = MoeMlp(32, 16, 8, top_k=3, held=(2, 4), act=jax.nn.silu,
+                   gated=True)
+    p = layer.init(jax.random.PRNGKey(6))
+    x = jax.random.normal(jax.random.PRNGKey(7), (8, 12, 32))
+
+    def run(p, x):
+        y, aux, stats = layer.apply(p, x)
+        return jnp.sum(jnp.sin(y)) + 3.0 * aux, (y, aux, stats)
+    want, want_grads = jax.value_and_grad(run, argnums=(0, 1),
+                                          has_aux=True)(p, x)
+    spec = ParallelSpec(**parallel)
+    mesh = spec.build_mesh(devices=jax.devices()[:8])
+    with sharding_ctx(mesh, spec.rules):
+        got, got_grads = jax.jit(jax.value_and_grad(
+            run, argnums=(0, 1), has_aux=True))(p, x)
+    for a, b in zip(jax.tree.leaves((got, got_grads)),
+                    jax.tree.leaves((want, want_grads))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def test_a_mesh_that_does_not_divide_the_experts_is_refused():
+    from autodist_tpu.parallel.axes import ParallelSpec, sharding_ctx
+    layer = MoeMlp(32, 16, 8, top_k=2, held=(0, 3))
+    p = layer.init(jax.random.PRNGKey(0))
+    spec = ParallelSpec(dp=4, ep=2)
+    mesh = spec.build_mesh(devices=jax.devices()[:8])
+    with sharding_ctx(mesh, spec.rules), \
+            pytest.raises(ValueError, match='do not divide'):
+        jax.jit(lambda p, x: layer.apply(p, x)[0])(p, jnp.zeros((4, 8, 32)))
+
+
+@pytest.mark.parametrize('held,top_k', [((0, 2), 2), ((4, 4), 4),
+                                        ((0, 3), 5)])
+def test_no_row_is_dropped_at_the_worst_routing(held, top_k):
+    """A router that sends every token to the held experts: the buffer
+    is sized for ``tokens x min(top_k, held)`` rows and a tile of
+    padding an expert, every one of them is live, and the layer still
+    equals the plain form. (The capacity tensor this replaces dropped
+    what overflowed ``capacity_factor x tokens x top_k / experts``.)"""
+    first, count = held
+    layer = MoeMlp(32, 16, 8, top_k=top_k, held=held, act=jax.nn.silu,
+                   gated=True)
+    p = layer.init(jax.random.PRNGKey(2))
+    bias = jnp.where((jnp.arange(8) >= first)
+                     & (jnp.arange(8) < first + count), 50.0, 0.0)
+    # every token's logits favour the held experts by 50
+    p['router']['kernel'] = 0.1 * p['router']['kernel'] + bias[None, :] \
+        * jnp.ones((32, 1)) / 32
+    x = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(3), (3, 200, 32))
+    tokens = 600
+    y, _, stats = layer.apply(p, x)
+    assert float(stats[0]) == tokens * min(top_k, count)
+    assert moe.buffer_rows(tokens, top_k, count) >= \
+        tokens * min(top_k, count) + count * (TILE - 1)
+    np.testing.assert_allclose(
+        np.asarray(y),
+        np.asarray(whole_layer(p, x, first, count, top_k, True,
+                               jax.nn.silu)), atol=5e-5)
+    # and one expert takes all of them
+    assert float(stats[1]) == tokens
+
+
+def test_buffer_is_whole_chunks_at_the_worst_case():
+    chunk = moe.CHUNK_TILES * TILE
+    assert moe.buffer_rows(32768, 8, 16) == 262144 + 4096 == 65 * chunk
+    assert moe.buffer_rows(600, 4, 2) == chunk      # 1200 + 512 rows
+    assert moe.buffer_rows(10, 8, 64) % chunk == 0
+
+
+def test_counters_are_read_back_with_the_loss():
+    """The step returns the expert layers' counters beside the loss, and
+    ``fit`` leaves them in the loop ring as ``trainer.counters``."""
+    cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=2,
+                                 moe_experts=4, moe_held=2, moe_top_k=2)
+    trainer = Trainer(TransformerLM(cfg), optax.sgd(0.1))
+    state = trainer.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(0)
+    batch = {'tokens': rng.randint(0, 256, (8, 32)),
+             'targets': rng.randint(0, 256, (8, 32))}
+    state, metrics = trainer.step(state, batch)
+    assert set(metrics) == {'loss', 'moe_rows_here', 'moe_load_max',
+                            'moe_load_mean'}
+    rows = float(metrics['moe_rows_here'])
+    assert 0 < rows <= 8 * 32 * 2
+    assert float(metrics['moe_load_mean']) == pytest.approx(rows / 2)
+    assert float(metrics['moe_load_max']) >= rows / 2
+    state, history = trainer.fit(state, [batch, batch], steps=2)
+    events = [r for r in telemetry.get().loop_records()
+              if r['name'] == 'trainer.counters']
+    assert len(events) >= 2 and events[-1]['step'] == 3
+    assert set(events[-1]['tags']) == {'moe_rows_here', 'moe_load_max',
+                                       'moe_load_mean'}
+    # a dense model's step returns the loss alone, as before
+    dense = Trainer(TransformerLM(TransformerConfig.tiny(
+        dtype=jnp.float32, n_layers=1)), optax.sgd(0.1))
+    _, metrics = dense.step(dense.init(jax.random.PRNGKey(0)), batch)
+    assert set(metrics) == {'loss'}

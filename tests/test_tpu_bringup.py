@@ -71,6 +71,48 @@ def test_kernel_regions_lower_for_tpu(monkeypatch, spec_kw, seq):
     assert 'tpu_custom_call' in exported.mlir_module()
 
 
+@pytest.mark.parametrize('spec_kw', [
+    dict(dp=2, ep=2, tp=2), dict(dp=4), dict(dp=1, ep=4)],
+    ids=['dp2_ep2_tp2', 'dp4', 'ep4'])
+def test_expert_layer_lowers_for_tpu_on_a_sharded_mesh(monkeypatch, spec_kw):
+    """A step with the expert layer under a mesh that shards the
+    tokens, the experts or their hidden units LOWERS for the TPU with
+    the grouped kernels in it (PR 33): the layer runs them on each
+    device's shard in a manual region, as attention does its own, and
+    no path falls back to ``jax.lax.ragged_dot``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.kernels import grouped_matmul as gm
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    monkeypatch.setattr(fa, '_interpret_default', lambda: False)
+    monkeypatch.setattr(gm, '_interpret_default', lambda: False)
+    cfg = TransformerConfig.tiny(
+        dtype=jnp.bfloat16, n_layers=1, max_len=512, dim=256, n_heads=2,
+        mlp_dim=256, gated_mlp=True, gelu='silu', moe_experts=8,
+        moe_top_k=2, moe_held=4, moe_aux_coef=0.01)
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(**spec_kw))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = {name: np.zeros((4, 512), np.int32)
+             for name in ('tokens', 'targets')}
+    step = tr._ensure_step(tr._step_key(batch), state, batch)
+    text = jax.export.export(step, platforms=['tpu'])(
+        state, tr.shard_batch(batch)).mlir_module()
+    assert {'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw'} <= set(
+        re.findall(r'kernel_name = "(\w+)"', text))
+    assert 'ragged_dot' not in text
+
+
 @pytest.mark.parametrize('local_shape,causal,dp,window', [
     ((96, 16, 512, 64), False, 1, None),    # bert-large.s512.c1
     ((32, 16, 1024, 64), True, 1, None),    # gpt2-medium.s1024.c1
@@ -183,6 +225,77 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
         assert tags[kernel + 'live_tiles'] == sum(t.any() for t in tiles)
         assert tags[kernel + 'masked_tiles'] == sum(
             t.any() and not t.all() for t in tiles)
+
+
+def test_mellum2_period_lowers_with_its_kernels_and_no_repeated_kv():
+    """One period of Mellum2 at its published widths (three causal-window
+    layers and the full causal YaRN layer, 32 query heads over 4 kv heads
+    of 128, top-8 of 64 experts of width 896, two of them held to keep
+    the test light) under remat=True with its gradient, as it lowers for
+    the TPU (PR 33): the flash calls of both kinds and the three grouped
+    products are there by name; every flash call reads the projection's
+    ``[b, s, (32 + 4 + 4) x 128]`` where it lies, and no tensor of the
+    step is k or v repeated to 32 heads (``[b, s, 3 x 4096]``, or
+    ``[b, 32, s, 128]`` beside q) or a one-hot dispatch tensor ``[b, s,
+    experts, capacity]``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.kernels import grouped_matmul as gm
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    b, s = 1, 8192
+    cfg = TransformerConfig(
+        vocab=256, dim=2304, n_layers=4, n_heads=32, n_kv_heads=4,
+        head_dim=128, max_len=s, causal=True, tied_embeddings=False,
+        dtype=jnp.bfloat16, remat=True, positions='rotary',
+        rope_theta=500000.0, window=1023, global_every=4, global_at=3,
+        rope_yarn=dict(factor=16, original_max_position_embeddings=8192,
+                       beta_fast=32, beta_slow=1,
+                       attention_factor=1.2772588722239782),
+        mlp_dim=896, gated_mlp=True, gelu='silu', norm='rms',
+        moe_experts=64, moe_top_k=8, moe_held=2, moe_aux_coef=0.0)
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(dp=1))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = {name: np.zeros((b, s), np.int32)
+             for name in ('tokens', 'targets')}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, '_interpret_default', lambda: False)
+        patch.setattr(gm, '_interpret_default', lambda: False)
+        step = tr._ensure_step(tr._step_key(batch), state, batch)
+        shapes = jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sh),
+            batch, tr.batch_sharding(batch))
+        text = jax.export.export(step, platforms=['tpu'])(
+            state, shapes).mlir_module()
+    names = set(re.findall(r'kernel_name = "(\w+)"', text))
+    assert names == {'flash_fwd', 'flash_dq', 'flash_dkv', 'flash_fwd_band',
+                     'flash_dq_band', 'flash_dkv_band', 'moe_gmm',
+                     'moe_gmm_dx', 'moe_gmm_dw'}
+    flash_calls = [line for line in text.splitlines()
+                   if '@tpu_custom_call' in line and 'flash_' in line]
+    assert flash_calls and all('%dx%dx5120xbf16' % (b, s) in line
+                               for line in flash_calls)
+    tensors = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|i1)>', text))
+    assert '%dx%dx12288' % (b, s) not in tensors         # 3 x 32 heads
+    assert not any(t.startswith('%dx32x%dx128' % (b, s)) for t in tensors)
+    assert not any(t.startswith('%dx%dx64x' % (b, s)) for t in tensors)
+    # the rows buffer's worst case, tokens x min(8, 2) + a tile an expert,
+    # is walked a chunk at a time: no tensor has its rows x a width
+    rows = 8192 * 2 + 2 * gm.TILE_ROWS
+    assert not any(t.startswith('%dx' % (-(-rows // 4096) * 4096))
+                   and t.endswith(('x2304', 'x1792', 'x896'))
+                   for t in tensors)
 
 
 # One layer of each cell's model under remat=True with its gradient, as it
