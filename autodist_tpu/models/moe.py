@@ -29,22 +29,33 @@ overflowed a capacity, are gone):
 * **experts** (``moe_experts``) between **dispatch** (``moe_dispatch``):
   the rows are walked in CHUNKS of ``CHUNK_TILES`` tiles by a
   ``while_loop`` that stops after the last live tile, so the time
-  follows the rows that are live and the memory one chunk, not the
-  buffer: gather the chunk's token rows, one grouped product with the
-  experts' ``[dim, hidden]`` (gate and up side by side for a gated
-  expert), the activation, a second with ``[hidden, dim]``, and the
-  rows scattered back onto their tokens with their weights, added up in
-  f32. The backward pass is written out (``jax.custom_vjp``): it walks
-  the chunks again, computes each chunk's forward again, and adds the
-  experts' weight gradients up in place, a group at a time
-  (``grouped_matmul.gmm_dw``); nothing a row long is kept between the
-  passes.
+  follows the rows that are live and the temporaries one chunk, not the
+  buffer: gather the chunk's token rows (``jnp.take``), one grouped
+  product with the experts' ``[dim, hidden]`` (gate and up side by side
+  for a gated expert), the activation, a second with ``[hidden, dim]``.
+  The chunks' output rows are put side by side in the buffer of a PASS
+  (``PASS_CHUNKS`` chunks, in x's dtype), and a pass's rows go back
+  onto their tokens with their weights, added up in f32, by the kernel
+  ``moe_combine`` (``kernels/moe_combine.py``), which uses the order
+  above: the rows of one expert for a block of 128 tokens are one
+  contiguous run, so a block's sum is built in VMEM from one fixed
+  window an expert and written once; no scatter-add. Live rows beyond
+  a pass take a further pass, onto the first one's sum. The backward
+  pass is written out (``jax.custom_vjp``): it walks the chunks again,
+  computes each chunk's forward again, adds the experts' weight
+  gradients up in place, a group at a time (``grouped_matmul.gmm_dw``),
+  and sends the rows' input gradients through the same passes of the
+  same kernel, at weights of one; nothing a row long is kept between
+  the forward and the backward.
 
 The grouped products are the Pallas kernels of
 ``kernels/grouped_matmul.py`` (``moe_gmm``, ``moe_gmm_dx``,
-``moe_gmm_dw``), always: under a mesh that shards the tokens or the
-experts the layer runs on each device's shard in a manual region
-(:meth:`MoeMlp._on_shards`).
+``moe_gmm_dw``) and the combine that of ``kernels/moe_combine.py``,
+always: under a mesh that shards the tokens or the experts the layer
+runs on each device's shard in a manual region
+(:meth:`MoeMlp._on_shards`). Each trace of the layer leaves its plan in
+the loop ring as the point event ``moe.plan``
+(``docs/design/observability.md``).
 
 An auxiliary load-balancing loss (Switch eq. 4, over all the router's
 experts) is returned beside the output, and the step's load as
@@ -56,17 +67,25 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from autodist_tpu import telemetry
 from autodist_tpu.kernels import grouped_matmul as gm
+from autodist_tpu.kernels import moe_combine as mc
 from autodist_tpu.models.core import Dense, Module, ParamDef
 from autodist_tpu.parallel.axes import (AXIS_DATA, active_manual_axes,
                                         current_mesh, live_mesh_axis,
                                         shard_map, unsharded_execution)
 
 # Tiles a step of the chunk loop walks: 4096 rows, an expert's share of
-# 32,768 tokens at 8 of 64. Measured on a v5e in Mellum2's cell (PERF.md
-# §6, PR 33): with chunks of 8192 rows XLA's scatter-add takes nearly
-# twice as long a step (`moe_dispatch_ms_per_step` 330 against 180).
+# 32,768 tokens at 8 of 64. XLA's gather and the activation run on
+# every row of a chunk, live or not, so a chunk is what a step wastes at
+# most; the temporaries of a step are a chunk's.
 CHUNK_TILES = 16
+# Chunks whose rows one pass of the combine holds side by side: 98,304
+# rows, 453 MB of bf16 at Mellum2's width, a third more than the cell's
+# 16 experts hold of 32,768 tokens at an even load (its worst case is
+# 266,240). A layer with more live rows takes a further pass, onto the
+# first one's sum.
+PASS_CHUNKS = 24
 
 
 class MoeMlp(Module):
@@ -134,10 +153,11 @@ class MoeMlp(Module):
         with jax.named_scope('moe_route'):
             probs, weights, idx = self._route(router, tokens)
             order = _order(idx - first, weights, held)
+        _note_plan(order['token'].shape[0], d, self.dtype)
         y = _experts(functools.partial(_hidden, self.act, self.gated, hidden),
                      tokens.astype(self.dtype), up.reshape(held, d, -1), down,
                      order['token'], order['weight'], order['tile_group'],
-                     order['live'])
+                     order['live'], order['row_of'], order['token_weight'])
         f = jnp.sum(jax.nn.one_hot(idx[:, 0], self.n_experts,
                                    dtype=jnp.float32), axis=0)
         return y.reshape(b, s, d), f, jnp.sum(probs, axis=0), order['sizes']
@@ -227,19 +247,26 @@ def _order(local, weights, held):
     expert by token. Returns ``token`` and ``weight`` of every row of
     the buffer (``[buffer_rows]``; a padding row is token 0 at weight
     0), each tile's expert ``tile_group``, the number of ``live`` tiles
-    (``[1]``) and the experts' ``sizes`` in rows."""
+    (``[1]``) and the experts' ``sizes`` in rows; and the same layout by
+    token, for the combine: ``row_of [t, held]``, the row of the pair
+    ``(t, expert)`` or -1, and ``token_weight [t, held]``, its weight
+    (no gradient goes through it: the rows' ``weight`` carries it)."""
     t, k = local.shape
     tile = gm.TILE_ROWS
     rows = buffer_rows(t, k, held)
     is_held = jnp.logical_and(local >= 0, local < held)
-    chosen = jnp.any(jnp.logical_and(
+    pair = jnp.logical_and(
         local[:, :, None] == jnp.arange(held)[None, None, :],
-        is_held[:, :, None]), axis=1).astype(jnp.int32)        # [t, held]
+        is_held[:, :, None])                                   # [t, k, held]
+    chosen = jnp.any(pair, axis=1).astype(jnp.int32)           # [t, held]
+    token_weight = jnp.sum(jnp.where(
+        pair, weights.astype(jnp.float32)[:, :, None], 0.0), axis=1)
     sizes = jnp.sum(chosen, axis=0)
     rank = jnp.cumsum(chosen, axis=0) - chosen                 # [t, held]
     tiles = -(-sizes // tile)
     tile_end = jnp.cumsum(tiles)
     start = (tile_end - tiles) * tile                          # [held]
+    row_of = jnp.where(chosen > 0, start[None, :] + rank, -1)
     at = jnp.clip(local, 0, held - 1)
     row = jnp.where(is_held, start[at] + jnp.take_along_axis(rank, at, 1),
                     rows)                                      # [t, k]
@@ -253,7 +280,9 @@ def _order(local, weights, held):
         tile_end, jnp.arange(rows // tile), side='right'),
         held - 1).astype(jnp.int32)
     return {'token': token, 'weight': weight, 'tile_group': tile_group,
-            'live': tile_end[-1:].astype(jnp.int32), 'sizes': sizes}
+            'live': tile_end[-1:].astype(jnp.int32), 'sizes': sizes,
+            'row_of': row_of.astype(jnp.int32),
+            'token_weight': jax.lax.stop_gradient(token_weight)}
 
 
 # ---------------------------------------------------------------------------
@@ -275,48 +304,102 @@ def _chunks(live):
     return -(-live[0] // CHUNK_TILES)
 
 
+def pass_chunks(rows):
+    """Chunks a pass of the combine takes, of a buffer of ``rows``."""
+    return min(PASS_CHUNKS, rows // (CHUNK_TILES * gm.TILE_ROWS))
+
+
+def _walk(live, row_of, token_weight, rows, like, step, state):
+    """The live chunks walked a PASS at a time: ``step(chunk, state)``
+    gives ``(the chunk's rows for the combine [chunk rows, dim], which of
+    them lie in a live tile, state)``; a pass puts its chunks' rows side
+    by side in one buffer (``pass_chunks`` chunks of ``like``'s dtype)
+    and adds them up onto their tokens (``kernels/moe_combine.py``), the
+    first pass over nothing, each further one onto the sum so far.
+    Returns ``(sum [tokens, dim] f32, state)``."""
+    chunk_rows = CHUNK_TILES * gm.TILE_ROWS
+    per_pass = pass_chunks(rows)
+    chunks = _chunks(live)
+
+    def one_pass(carry):
+        j, total, state = carry
+        first = j * per_pass
+        here = jnp.clip(chunks - first, 0, per_pass)
+
+        def chunk(carry):
+            i, buffer, state = carry
+            out, valid, state = step(first + i, state)
+            with jax.named_scope('moe_dispatch'):
+                # rows of no live tile as zeros: the kernels leave them
+                # unwritten, and a window of the combine may hold them
+                buffer = jax.lax.dynamic_update_slice_in_dim(
+                    buffer, jnp.where(valid, out, 0).astype(buffer.dtype),
+                    i * chunk_rows, axis=0)
+            return i + 1, buffer, state
+
+        with jax.named_scope('moe_dispatch'):
+            buffer = mc.unwritten(per_pass * chunk_rows, like.shape[1],
+                                  like.dtype)
+        _, buffer, state = jax.lax.while_loop(
+            lambda carry: carry[0] < here, chunk,
+            (jnp.int32(0), buffer, state))
+        with jax.named_scope('moe_dispatch'):
+            at = row_of - first * chunk_rows
+            at = jnp.where(jnp.logical_and(at >= 0, at < here * chunk_rows),
+                           at, -1)
+            total = mc.combine(buffer, at, token_weight,
+                               limit=here * chunk_rows, onto=total,
+                               fresh=j == 0)
+        return j + 1, total, state
+
+    with jax.named_scope('moe_dispatch'):
+        total = mc.unwritten(like.shape[0], like.shape[1], jnp.float32)
+    # one pass at least: with no live row it writes the zeros
+    _, total, state = jax.lax.while_loop(
+        lambda carry: jnp.logical_or(carry[0] == 0,
+                                     carry[0] * per_pass < chunks),
+        one_pass, (jnp.int32(0), total, state))
+    return total, state
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts(hidden, x, w_up, w_down, token, weight, tile_group, live):
+def _experts(hidden, x, w_up, w_down, token, weight, tile_group, live,
+             row_of, token_weight):
     """``out[t] = sum over the rows r of token t of weight[r] * (hidden(
     x[t] @ w_up[e_r]) @ w_down[e_r])`` for ``x [tokens, dim]``, the held
     experts' ``w_up [held, dim, n * hidden]`` and ``w_down [held, hidden,
     dim]`` (f32; multiplied in x's dtype) and the rows' layout
     (:func:`_order`); ``[tokens, dim]`` in x's dtype."""
     return _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group,
-                        live)[0]
+                        live, row_of, token_weight)[0]
 
 
-def _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group, live):
+def _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group, live,
+                 row_of, token_weight):
     up, down = w_up.astype(x.dtype), w_down.astype(x.dtype)
 
-    def step(carry):
-        i, out = carry
-        tok, wt, groups, here, valid = _chunk(i, token, weight, tile_group,
-                                              live)
+    def step(i, state):
+        tok, _, groups, here, valid = _chunk(i, token, weight, tile_group,
+                                             live)
         with jax.named_scope('moe_dispatch'):
             xs = jnp.take(x, tok, axis=0)
         with jax.named_scope('moe_experts'):
             u = gm.gmm(xs, up, groups, here)
             h = jnp.where(valid, hidden(u.astype(jnp.float32)), 0.0)
             y = gm.gmm(h.astype(x.dtype), down, groups, here)
-        with jax.named_scope('moe_dispatch'):
-            y = jnp.where(valid, y.astype(jnp.float32) * wt[:, None], 0.0)
-            out = out.at[tok].add(y)
-        return i + 1, out
+        return y, valid, state
 
-    _, out = jax.lax.while_loop(
-        lambda carry: carry[0] < _chunks(live), step,
-        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))
+    out, _ = _walk(live, row_of, token_weight, token.shape[0], x, step, ())
     return out.astype(x.dtype), (x, w_up, w_down, token, weight, tile_group,
-                                 live)
+                                 live, row_of)
 
 
 def _experts_bwd(hidden, res, dout):
-    x, w_up, w_down, token, weight, tile_group, live = res
+    x, w_up, w_down, token, weight, tile_group, live, row_of = res
     up, down = w_up.astype(x.dtype), w_down.astype(x.dtype)
 
-    def step(carry):
-        i, dx, d_up, d_down, d_weight = carry
+    def step(i, state):
+        d_up, d_down, d_weight = state
         tok, wt, groups, here, valid = _chunk(i, token, weight, tile_group,
                                               live)
         with jax.named_scope('moe_dispatch'):
@@ -339,20 +422,37 @@ def _experts_bwd(hidden, res, dout):
             d_up = gm.gmm_dw(xs, du, groups, here, d_up)
             dxs = gm.gmm(du, up, groups, here, transposed=True)
         with jax.named_scope('moe_dispatch'):
-            dx = dx.at[tok].add(jnp.where(valid, dxs.astype(jnp.float32),
-                                          0.0))
             d_weight = jax.lax.dynamic_update_slice_in_dim(
                 d_weight, d_wt, i * d_wt.shape[0], axis=0)
-        return i + 1, dx, d_up, d_down, d_weight
+        return dxs, valid, (d_up, d_down, d_weight)
 
-    _, dx, d_up, d_down, d_weight = jax.lax.while_loop(
-        lambda carry: carry[0] < _chunks(live), step,
-        (jnp.int32(0), jnp.zeros(x.shape, jnp.float32),
-         jnp.zeros(w_up.shape, jnp.float32),
+    dx, (d_up, d_down, d_weight) = _walk(
+        live, row_of, None, token.shape[0], x, step,
+        (jnp.zeros(w_up.shape, jnp.float32),
          jnp.zeros(w_down.shape, jnp.float32),
          jnp.zeros(weight.shape, jnp.float32)))
+    # Rows of no live tile were never written: the select changes no
+    # value. It gives the gather that transposes `_order`'s scatter an
+    # operand XLA keeps in fast memory; straight out of the loop it is
+    # gathered from HBM, at a third of the rate (PERF.md §6, PR 34).
+    d_weight = jnp.where(
+        jnp.arange(d_weight.shape[0]) < live[0] * gm.TILE_ROWS, d_weight, 0.0)
     return (dx.astype(x.dtype), d_up.astype(w_up.dtype),
-            d_down.astype(w_down.dtype), None, d_weight, None, None)
+            d_down.astype(w_down.dtype), None, d_weight, None, None, None,
+            None)
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def _note_plan(rows, dim, dtype):
+    """One ``moe.plan`` point event a trace of the layer: how its rows
+    move between the tokens' order and the experts' (``rows`` and
+    ``buffer_bytes``: of the buffer a pass of the combine holds)."""
+    chunks = pass_chunks(rows)
+    held = chunks * CHUNK_TILES * gm.TILE_ROWS
+    telemetry.get().loop_event(
+        'moe.plan', rows=held, chunk_tiles=CHUNK_TILES, pass_chunks=chunks,
+        token_block=mc.TOKEN_BLOCK, window_rows=mc.WINDOW_ROWS,
+        combine='pallas', gather='xla',
+        buffer_bytes=held * dim * jnp.dtype(dtype).itemsize)

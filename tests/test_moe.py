@@ -1,9 +1,11 @@
-"""The expert layer (``models/moe.py``) and its grouped products
-(``kernels/grouped_matmul.py``), PR 33: the kernels in interpret mode
-against a loop over the groups, the layer against a loop over the
-experts (loss and every gradient), the share of a deployment (the parts
-that all the shares give add up to the uncut layer), and that no row is
-dropped at the worst routing the buffers are sized for."""
+"""The expert layer (``models/moe.py``), its grouped products
+(``kernels/grouped_matmul.py``, PR 33) and its combine
+(``kernels/moe_combine.py``, PR 34): the kernels in interpret mode
+against a loop over the groups and against XLA's scatter-add, the layer
+against a loop over the experts (loss and every gradient), the share of
+a deployment (the parts that all the shares give add up to the uncut
+layer), and that no row is dropped at the worst routing the buffers are
+sized for."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,7 @@ import pytest
 from autodist_tpu import telemetry
 from autodist_tpu.api import Trainer
 from autodist_tpu.kernels import grouped_matmul as gm
+from autodist_tpu.kernels import moe_combine as mc
 from autodist_tpu.models import moe
 from autodist_tpu.models.moe import MoeMlp
 from autodist_tpu.models.transformer import TransformerConfig, TransformerLM
@@ -100,6 +103,135 @@ def test_tiles_past_the_live_ones_are_skipped():
     np.testing.assert_allclose(
         one[0], np.asarray(acc[0] + lhs[:TILE].T @ rhs[:TILE]), rtol=1e-5,
         atol=1e-5)
+
+
+def ordered(chosen):
+    """``(row_of [tokens, groups], live rows)`` of the pairs ``chosen
+    [tokens, groups]`` as ``moe._order`` lays them out: rows by group,
+    each group from a tile's start, inside a group by token."""
+    sizes = chosen.sum(0)
+    tiles = -(-sizes // TILE)
+    start = (np.cumsum(tiles) - tiles) * TILE
+    rank = np.cumsum(chosen, 0) - chosen
+    return (np.where(chosen, start[None] + rank, -1).astype(np.int32),
+            int(tiles.sum()) * TILE)
+
+
+def _combine_cases():
+    rng = np.random.RandomState(5)
+    even = rng.rand(300, 4) < 0.4
+    # tokens 128..255 all take group 1, after five of the first block
+    # do: a run of 128 rows that starts off a tile of the buffer
+    one_block = rng.rand(256, 3) < 0.3
+    one_block[:, 1] = False
+    one_block[[3, 40, 41, 90, 127], 1] = True
+    one_block[128:, 1] = True
+    empty_group = rng.rand(300, 4) < 0.5
+    empty_group[:, 2] = False
+    # 540 rows of one group: token blocks whose runs cross the 256-row
+    # tiles, and (in two calls of 512 rows, one onto the other) a chunk
+    heavy = rng.rand(600, 2) < 0.9
+    return {
+        'even_routing': dict(chosen=even),
+        'a_block_of_tokens_to_one_expert': dict(chosen=one_block),
+        'an_expert_with_no_row': dict(chosen=empty_group),
+        'no_live_row_at_all': dict(chosen=np.zeros((200, 3), bool)),
+        'runs_straddle_a_tile_and_a_chunk': dict(chosen=heavy, calls=512),
+        'weights_bf16_cannot_hold': dict(chosen=even, dtype=jnp.bfloat16,
+                                         dim=128, fine_weights=True),
+        'dim_no_multiple_of_128': dict(chosen=even, dim=40),
+    }
+
+
+@pytest.mark.parametrize('case', sorted(_combine_cases()))
+def test_combine_matches_the_scatter_add(case):
+    """``moe_combine`` against ``out.at[token].add(weight * row)``, with
+    the layer's weights and with weights of one (the backward's), the
+    rows past the live ones NaN: no window reaches them."""
+    spec = _combine_cases()[case]
+    chosen, dim = spec['chosen'], spec.get('dim', 32)
+    dtype = spec.get('dtype', jnp.float32)
+    row_of, live = ordered(chosen)
+    rng = np.random.RandomState(7)
+    rows = rng.randn(live + 2 * TILE, dim).astype('f4')
+    rows[live:] = np.nan
+    rows = jnp.asarray(rows, dtype)
+    weight = rng.rand(*chosen.shape).astype('f4')
+    if spec.get('fine_weights'):
+        # 24 significant bits, of which bf16 keeps 8
+        weight = (1.0 / 3.0 + weight * 2.0 ** -12).astype('f4')
+        assert np.any(weight != np.asarray(
+            jnp.asarray(weight, jnp.bfloat16), 'f4'))
+    for w in (jnp.asarray(weight), None):
+        want = np.asarray(mc.reference(rows, jnp.asarray(row_of), w,
+                                       out_dtype=jnp.float32))
+        # in calls of `calls` rows, the first over a sum nobody wrote
+        calls = spec.get('calls', live)
+        got = jnp.full(want.shape, np.nan, jnp.float32)
+        for first in range(0, max(live, 1), max(calls, 1)):
+            at = row_of - first
+            at = np.where((at >= 0) & (at < calls), at, -1)
+            got = mc.combine(rows[first:], jnp.asarray(at), w,
+                             limit=jnp.int32(min(calls, live - first)),
+                             onto=got, fresh=first == 0)
+        assert got.shape == want.shape and got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                                   atol=1e-6)
+        if calls == live:       # and in one call that carries no sum
+            alone = mc.combine(rows, jnp.asarray(row_of), w,
+                               limit=jnp.int32(live), out_dtype=jnp.float32)
+            np.testing.assert_array_equal(np.asarray(alone), np.asarray(got))
+    if not chosen.any():
+        assert not np.asarray(got).any()
+
+
+def test_layer_walks_its_chunks_in_passes(monkeypatch):
+    """With chunks of one tile and passes of two chunks the layer's rows
+    take several passes of the combine, each onto the sum so far, and a
+    token block's run lies across two of them: output and every
+    gradient are still those of the loop over the experts."""
+    monkeypatch.setattr(moe, 'CHUNK_TILES', 1)
+    monkeypatch.setattr(moe, 'PASS_CHUNKS', 2)
+    layer = MoeMlp(32, 16, 8, top_k=3, held=(2, 4), act=jax.nn.silu,
+                   gated=True)
+    p = layer.init(jax.random.PRNGKey(2))
+    x = jax.random.normal(jax.random.PRNGKey(3), (3, 300, 32))
+
+    def plain(p, x):
+        return whole_layer(p, x, 2, 4, 3, True, jax.nn.silu)
+    y, _, stats = layer.apply(p, x)
+    assert float(stats[0]) > 2 * 2 * TILE      # more than two passes
+    np.testing.assert_allclose(np.asarray(y), np.asarray(plain(p, x)),
+                               atol=2e-5)
+    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(layer.apply(p, x)[0])),
+                   argnums=(0, 1))(p, x)
+    want = jax.grad(lambda p, x: jnp.sum(jnp.sin(plain(p, x))),
+                    argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def test_the_layer_leaves_its_plan_in_the_ring():
+    """One ``moe.plan`` point event a trace of the layer, none for a
+    call that is not traced again: the buffer a pass of the combine
+    holds, and which of the two movements is a kernel."""
+    layer = MoeMlp(32, 16, 8, top_k=2, held=(0, 4))
+    p = layer.init(jax.random.PRNGKey(0))
+    x = jnp.ones((2, 40, 32))
+
+    def plans():
+        return [r for r in telemetry.get().loop_records()
+                if r['name'] == 'moe.plan']
+    before = len(plans())
+    run = jax.jit(lambda p, x: layer.apply(p, x)[0])
+    run(p, x)
+    run(p, x)
+    assert len(plans()) == before + 1
+    rows = moe.buffer_rows(80, 2, 4)
+    assert plans()[-1]['tags'] == dict(
+        rows=rows, chunk_tiles=moe.CHUNK_TILES, pass_chunks=1,
+        token_block=mc.TOKEN_BLOCK, window_rows=mc.WINDOW_ROWS,
+        combine='pallas', gather='xla', buffer_bytes=rows * 32 * 4)
 
 
 def whole_layer(p, x, first, held, top_k, gated, act):

@@ -108,7 +108,7 @@ def test_expert_layer_lowers_for_tpu_on_a_sharded_mesh(monkeypatch, spec_kw):
     step = tr._ensure_step(tr._step_key(batch), state, batch)
     text = jax.export.export(step, platforms=['tpu'])(
         state, tr.shard_batch(batch)).mlir_module()
-    assert {'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw'} <= set(
+    assert {'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw', 'moe_combine'} <= set(
         re.findall(r'kernel_name = "(\w+)"', text))
     assert 'ragged_dot' not in text
 
@@ -232,12 +232,14 @@ def test_mellum2_period_lowers_with_its_kernels_and_no_repeated_kv():
     layers and the full causal YaRN layer, 32 query heads over 4 kv heads
     of 128, top-8 of 64 experts of width 896, two of them held to keep
     the test light) under remat=True with its gradient, as it lowers for
-    the TPU (PR 33): the flash calls of both kinds and the three grouped
-    products are there by name; every flash call reads the projection's
-    ``[b, s, (32 + 4 + 4) x 128]`` where it lies, and no tensor of the
-    step is k or v repeated to 32 heads (``[b, s, 3 x 4096]``, or
-    ``[b, 32, s, 128]`` beside q) or a one-hot dispatch tensor ``[b, s,
-    experts, capacity]``."""
+    the TPU (PR 33): the flash calls of both kinds, the three grouped
+    products and the combine (PR 34) are there by name; every flash call
+    reads the projection's ``[b, s, (32 + 4 + 4) x 128]`` where it lies,
+    and no tensor of the step is k or v repeated to 32 heads (``[b, s, 3
+    x 4096]``, or ``[b, 32, s, 128]`` beside q) or a one-hot dispatch
+    tensor ``[b, s, experts, capacity]``. Of the rows buffer's length
+    there is the one bf16 buffer a pass of the combine holds, 2304 wide,
+    and the rows' sums are added up by no scatter."""
     import re
 
     import jax
@@ -281,7 +283,8 @@ def test_mellum2_period_lowers_with_its_kernels_and_no_repeated_kv():
     names = set(re.findall(r'kernel_name = "(\w+)"', text))
     assert names == {'flash_fwd', 'flash_dq', 'flash_dkv', 'flash_fwd_band',
                      'flash_dq_band', 'flash_dkv_band', 'moe_gmm',
-                     'moe_gmm_dx', 'moe_gmm_dw'}
+                     'moe_gmm_dx', 'moe_gmm_dw', 'moe_combine',
+                     'moe_rows_buffer'}
     flash_calls = [line for line in text.splitlines()
                    if '@tpu_custom_call' in line and 'flash_' in line]
     assert flash_calls and all('%dx%dx5120xbf16' % (b, s) in line
@@ -291,11 +294,55 @@ def test_mellum2_period_lowers_with_its_kernels_and_no_repeated_kv():
     assert not any(t.startswith('%dx32x%dx128' % (b, s)) for t in tensors)
     assert not any(t.startswith('%dx%dx64x' % (b, s)) for t in tensors)
     # the rows buffer's worst case, tokens x min(8, 2) + a tile an expert,
-    # is walked a chunk at a time: no tensor has its rows x a width
-    rows = 8192 * 2 + 2 * gm.TILE_ROWS
-    assert not any(t.startswith('%dx' % (-(-rows // 4096) * 4096))
-                   and t.endswith(('x2304', 'x1792', 'x896'))
-                   for t in tensors)
+    # is walked a chunk at a time: of its length there is only what a
+    # pass of the combine holds, the experts' outputs (forward) or the
+    # gradients w.r.t. their inputs (backward) in bf16; nothing of the
+    # experts' inner widths, nothing in f32
+    from autodist_tpu.models import moe
+    rows = moe.buffer_rows(8192, 8, 2)
+    assert rows == 8192 * 2 + 4096 and moe.pass_chunks(rows) == rows // 4096
+    long = set(re.findall(r'tensor<%dx([0-9]+)x(\w+)>' % rows, text))
+    assert long == {('2304', 'bf16')}
+    # and the sums onto the tokens are the kernel's: the scatters left are
+    # the route's (one number a row, the top-k's gradient) and the
+    # embedding's
+    scatters = set(re.findall(
+        r'"stablehlo.scatter"\(.*?\n\s*\}\) : \([^)]*\) -> '
+        r'tensor<([0-9x]+)x\w+>', text, re.S))
+    assert scatters == {str(rows), '%dx64' % s, '256x2304'}
+
+
+@pytest.mark.parametrize('carried', [False, True],
+                         ids=['alone', 'onto_the_sum'])
+def test_moe_combine_lowers_for_tpu_at_the_cells_shape(carried):
+    """The combine of Mellum2's cell (PR 34) LOWERS for the TPU as one
+    Mosaic call: a pass's buffer of 98,304 rows of 2304 in bf16, 32,768
+    tokens over 16 held experts, with the weights by token; the layer's
+    passes add onto the f32 sum in place."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.kernels import moe_combine as mc
+
+    rows, tokens, held, dim = 98304, 32768, 16, 2304
+
+    def call(buffer, row_of, weight, limit, total):
+        return mc.combine(buffer, row_of, weight, limit=limit,
+                          onto=total if carried else None, fresh=limit > 0,
+                          out_dtype=jnp.float32, interpret=False)
+    text = jax.export.export(jax.jit(call), platforms=['tpu'])(
+        jax.ShapeDtypeStruct((rows, dim), jnp.bfloat16),
+        jax.ShapeDtypeStruct((tokens, held), jnp.int32),
+        jax.ShapeDtypeStruct((tokens, held), jnp.float32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((tokens, dim), jnp.float32)).mlir_module()
+    calls = [line for line in text.splitlines()
+             if '@tpu_custom_call' in line]
+    assert len(calls) == 1 and 'kernel_name = "moe_combine"' in calls[0]
+    assert ('output_operand_aliases = [#stablehlo.output_operand_alias'
+            in calls[0]) == carried
+    assert '-> tensor<%dx%dxf32>' % (tokens, dim) in calls[0]
+    assert 'stablehlo.scatter' not in text
 
 
 # One layer of each cell's model under remat=True with its gradient, as it
