@@ -21,6 +21,7 @@ functional models (strategy → ParallelSpec adapter in
 :mod:`autodist_tpu.strategy.adapter`).
 """
 import functools
+import itertools
 from dataclasses import dataclass
 from typing import Any
 
@@ -65,18 +66,25 @@ class Trainer:
         mesh: optional prebuilt mesh (else ``spec.build_mesh()``).
     """
 
+    _built = itertools.count()   # Trainers constructed in this process
+
     def __init__(self, model, optimizer, spec=None, loss_fn=None,
                  mesh=None, rules=None, donate=True):
-        self.model = model
-        self.optimizer = optimizer
-        self.spec = spec or ParallelSpec()
-        self.mesh = mesh if mesh is not None else self.spec.build_mesh()
-        self.rules = rules if rules is not None else self.spec.rules
-        self._loss_fn = loss_fn
-        self._donate = donate
-        self._axes_tree = model.axes()
-        self.param_shardings = shardings_for_tree(
-            self._axes_tree, self.rules, self.mesh)
+        # the tag `trainer` of every loop record this Trainer makes:
+        # tells the one that trains from, say, a benchmark's probe
+        self._tag = next(Trainer._built)
+        with self._span('trainer.new', setup=True):
+            self.model = model
+            self.optimizer = optimizer
+            self.spec = spec or ParallelSpec()
+            self.mesh = (mesh if mesh is not None
+                         else self.spec.build_mesh())
+            self.rules = rules if rules is not None else self.spec.rules
+            self._loss_fn = loss_fn
+            self._donate = donate
+            self._axes_tree = model.axes()
+            self.param_shardings = shardings_for_tree(
+                self._axes_tree, self.rules, self.mesh)
         self._step_cache = {}
         # calls of step() so far: the number every loop span and
         # event of this trainer is tagged with (telemetry.loop_span)
@@ -94,6 +102,11 @@ class Trainer:
                     self._trainable_mask)[0] if not leaf]
         logging.info('Trainer mesh: %s, zero=%d, sp=%d',
                      dict(self.mesh.shape), self.spec.zero, self.spec.sp)
+
+    def _span(self, name, step=None, **tags):
+        """A loop span of this Trainer (``telemetry.loop_span``)."""
+        return telemetry.get().loop_span(name, step=step,
+                                         trainer=self._tag, **tags)
 
     # -- sharding helpers --------------------------------------------------
     def _zero_extend(self, sharding, shape):
@@ -220,24 +233,30 @@ class Trainer:
     # -- init --------------------------------------------------------------
     def init(self, rng, params=None):
         """Materialize sharded TrainState (params + optimizer slots)."""
-        with telemetry.get().loop_span('trainer.init'):
-            if params is None:
-                with sharding_ctx(self.mesh, self.rules):
-                    shapes = jax.eval_shape(self.model.init, rng)
-                    shardings = self._param_sharding_tree(shapes)
-                    init_fn = jax.jit(self.model.init,
-                                      out_shardings=shardings)
-                    params = init_fn(rng)
-            else:
-                params = jax.tree.map(
-                    lambda x, s: jax.device_put(jnp.asarray(x), s),
-                    params, self._param_sharding_tree(params))
-            opt_state = jax.jit(self.optimizer.init)(params)
-            opt_shardings = self._opt_sharding(
-                opt_state, params, self._param_sharding_tree(params))
-            opt_state = jax.tree.map(
-                lambda x, s: jax.device_put(x, s), opt_state,
-                opt_shardings)
+        # every call here dispatches and returns: what the device still
+        # has in flight when a span closes is not in it
+        with self._span('trainer.init', setup=True):
+            with self._span('trainer.init.params'):
+                if params is None:
+                    with sharding_ctx(self.mesh, self.rules):
+                        shapes = jax.eval_shape(self.model.init, rng)
+                        shardings = self._param_sharding_tree(shapes)
+                        init_fn = jax.jit(self.model.init,
+                                          out_shardings=shardings)
+                        params = init_fn(rng)
+                else:
+                    params = jax.tree.map(
+                        lambda x, s: jax.device_put(jnp.asarray(x), s),
+                        params, self._param_sharding_tree(params))
+            with self._span('trainer.init.opt_state'):
+                opt_state = jax.jit(self.optimizer.init)(params)
+                opt_shardings = self._opt_sharding(
+                    opt_state, params, self._param_sharding_tree(params))
+            with self._span('trainer.init.place',
+                            leaves=len(jax.tree.leaves(opt_state))):
+                opt_state = jax.tree.map(
+                    lambda x, s: jax.device_put(x, s), opt_state,
+                    opt_shardings)
             return TrainState.create(params, opt_state)
 
     # -- the compiled step -------------------------------------------------
@@ -431,7 +450,7 @@ class Trainer:
             # which step met a batch signature with no compiled step yet
             telemetry.get().loop_event(
                 'trainer.new_step_signature', step=self._steps_run + 1,
-                shapes=str(key[1]))
+                trainer=self._tag, shapes=str(key[1]))
             step_fn = self._build_step(jax.tree.structure(batch))
             param_sh = self._param_sharding_tree(state.params)
             opt_sh = self._opt_sharding(state.opt_state, state.params,
@@ -451,20 +470,29 @@ class Trainer:
         executable. Returns the ``jax.stages.Compiled`` (whose
         ``as_text()`` the benchmark's engine and ``chip_smoke.py``
         read)."""
-        with telemetry.get().loop_span('trainer.compile_step',
-                                       step=self._steps_run + 1):
-            key = self._step_key(batch)
-            fn = self._ensure_step(key, state, batch)
+        with self._span('trainer.compile_step', step=self._steps_run + 1,
+                        setup=True):
+            with self._span('trainer.compile_step.build'):
+                key = self._step_key(batch)
+                fn = self._ensure_step(key, state, batch)
             if isinstance(fn, jax.stages.Compiled):
                 return fn
-            compiled = fn.lower(state, self.shard_batch(batch)).compile()
+            with self._span('trainer.compile_step.place'):
+                batch = self.shard_batch(batch)
+            # Python tracing and lowering to StableHLO, the Mosaic
+            # payloads with it
+            with self._span('trainer.compile_step.lower'):
+                lowered = fn.lower(state, batch)
+            # XLA's compile, or the persistent cache's look-up, read
+            # and load
+            with self._span('trainer.compile_step.compile'):
+                compiled = lowered.compile()
             self._step_cache[key] = compiled
             return compiled
 
     def step(self, state, batch):
         """One optimizer step; returns (new_state, metrics)."""
-        with telemetry.get().loop_span('trainer.step',
-                                       step=self._steps_run + 1):
+        with self._span('trainer.step', step=self._steps_run + 1):
             key = self._step_key(batch)
             fn = self._ensure_step(key, state, batch)
             batch = self.shard_batch(batch)
@@ -502,7 +530,6 @@ class Trainer:
             entry per step) and, when evaluating, 'eval_loss' entries of
             (step, loss).
         """
-        tel = telemetry.get()
         history = {'loss': []}
         if eval_data is not None:
             history['eval_loss'] = []
@@ -511,39 +538,43 @@ class Trainer:
         # every span is tagged with the number of the step it belongs
         # to: calls of step() so far, plus one before the call
         def evaluate():
-            with tel.loop_span('trainer.eval', step=self._steps_run):
+            with self._span('trainer.eval', step=self._steps_run):
                 history['eval_loss'].append(
                     (n, self.evaluate(state, eval_data)))
 
         def save():
-            with tel.loop_span('trainer.save', step=self._steps_run):
+            with self._span('trainer.save', step=self._steps_run):
                 self.save_state(checkpoint_manager, state)
 
-        with tel.loop_span('trainer.fit', step=self._steps_run + 1,
-                           steps=steps, prefetch=prefetch):
+        with self._span('trainer.fit', step=self._steps_run + 1,
+                        steps=steps, prefetch=prefetch):
             if prefetch:
                 from autodist_tpu.data.prefetch import prefetch_to_device
                 data = prefetch_to_device(data, self.shard_batch,
                                           size=prefetch,
-                                          first_step=self._steps_run + 1)
+                                          first_step=self._steps_run + 1,
+                                          trainer=self._tag)
             it = iter(data)
             done = object()
             while True:
-                with tel.loop_span('trainer.input',
-                                   step=self._steps_run + 1):
+                with self._span('trainer.input', step=self._steps_run + 1):
                     batch = next(it, done)
                 if batch is done:
                     break
                 state, metrics = self.step(state, batch)
-                with tel.loop_span('trainer.loss_readback',
-                                   step=self._steps_run):
+                with self._span('trainer.loss_readback',
+                                step=self._steps_run):
                     history['loss'].append(float(metrics['loss']))
                 if len(metrics) > 1:
-                    # what the model counted in the step, read back with
-                    # the loss (docs/design/observability.md)
-                    tel.loop_event('trainer.counters', step=self._steps_run,
-                                   **{k: float(v) for k, v in metrics.items()
-                                      if k != 'loss'})
+                    # what the model counted in the step, read back
+                    # after the loss (docs/design/observability.md)
+                    with self._span('trainer.counters_readback',
+                                    step=self._steps_run):
+                        counted = {k: float(v) for k, v in metrics.items()
+                                   if k != 'loss'}
+                    telemetry.get().loop_event(
+                        'trainer.counters', step=self._steps_run,
+                        trainer=self._tag, **counted)
                 n += 1
                 if eval_data is not None and eval_every and \
                         n % eval_every == 0:

@@ -13,7 +13,7 @@ import itertools
 from autodist_tpu import telemetry
 
 
-def prefetch_to_device(iterator, place_fn, size=2, first_step=1):
+def prefetch_to_device(iterator, place_fn, size=2, first_step=1, **tags):
     """Yield device-placed batches with ``size`` batches in flight.
 
     Args:
@@ -26,6 +26,8 @@ def prefetch_to_device(iterator, place_fn, size=2, first_step=1):
             ``trainer.source`` and placed under a ``trainer.place``
             loop span (:meth:`Telemetry.loop_span`) tagged
             ``first_step + i``.
+        **tags: further tags of those spans (``Trainer.fit`` gives its
+            ``trainer``).
 
     Yields:
         placed batches, in order.
@@ -43,9 +45,9 @@ def prefetch_to_device(iterator, place_fn, size=2, first_step=1):
             return False
         step = next(steps)
         try:
-            with tel.loop_span('trainer.source', step=step):
+            with tel.loop_span('trainer.source', step=step, **tags):
                 batch = next(it)
-            with tel.loop_span('trainer.place', step=step):
+            with tel.loop_span('trainer.place', step=step, **tags):
                 buf.append(place_fn(batch))
         except StopIteration:
             return False

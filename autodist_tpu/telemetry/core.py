@@ -43,16 +43,57 @@ that is already training. Each also opens a
 ``jax.profiler.TraceAnnotation`` of the same name, which costs next to
 nothing while no profiler runs and puts the span on the host plane of
 the trace, beside the runtime's own events, when one does.
+
+A ring record says who caused it: ``id`` counts the records of the
+process and ``parent`` is the ``id`` of the loop span that was open on
+the recording thread, so a span's self time is its duration less what
+its children cover. A span opened with ``setup=True`` (the ``Trainer``'s
+``new`` / ``init`` / ``compile_step``) and everything recorded under it
+go to a list of :data:`LOOP_SETUP` records that nothing turns over: a
+long ``fit`` fills the ring in 200 steps, and how the job started is
+asked after that. JAX's own compile durations (:data:`JAX_DURATIONS`)
+land in the same place, under the span that caused them.
 """
+import itertools
 import threading
 import time
 from collections import deque
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from autodist_tpu.const import ENV
 
-LOOP_RING = 1024   # records; five a Trainer step
+LOOP_RING = 1024   # records; six a Trainer step
+LOOP_SETUP = 1024  # records of set-up kept for the life of the process
+
+# jax 0.9.0's duration events (jax/_src/dispatch.py, compiler.py) and
+# the ring record each becomes. ``jax.backend_compile`` is the whole
+# compile request, so on a persistent-cache hit it holds a
+# ``jax.cache_retrieval``.
+JAX_DURATIONS = {
+    '/jax/core/compile/jaxpr_trace_duration': 'jax.trace',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'jax.lower',
+    '/jax/core/compile/backend_compile_duration': 'jax.backend_compile',
+    '/jax/compilation_cache/cache_retrieval_time_sec':
+        'jax.cache_retrieval',
+}
+
+# JAX reports the trace of every jitted function it meets INSIDE another
+# trace as an event of its own (each ``jnp.add`` of a step: hundreds,
+# 10 us apiece, all within the outer event): those are not recorded
+MIN_TRACE_S = 1e-3
+
+_IDS = itertools.count()      # per process: unique across reset()
+_OPEN = threading.local()     # .spans: this thread's open _LoopSpans
+
+
+def _open_spans():
+    try:
+        return _OPEN.spans
+    except AttributeError:
+        spans = _OPEN.spans = []
+        return spans
 
 
 class _NullSpan:
@@ -97,27 +138,40 @@ class _Span:
 
 class _LoopSpan:
     """One live loop span: a ``TraceAnnotation`` for the profiler and,
-    on exit, one record in the always-on loop ring."""
+    on exit, one record in the always-on loop ring. While it is open it
+    is the top of its thread's stack, so what is recorded meanwhile
+    names it as ``parent``."""
 
-    __slots__ = ('_tel', 'name', 'step', 'tags', '_annotation', '_t0')
+    __slots__ = ('_tel', 'name', 'step', 'tags', 'setup', 'id', 'parent',
+                 '_annotation', '_t0')
 
-    def __init__(self, tel, name, step, tags):
+    def __init__(self, tel, name, step, setup, tags):
         self._tel = tel
         self.name = name
         self.step = step
+        self.setup = setup
         self.tags = tags
         self._annotation = TraceAnnotation(name)
 
     def __enter__(self):
         self._annotation.__enter__()
+        spans = _open_spans()
+        self.id = next(_IDS)
+        if spans:
+            self.parent = spans[-1].id
+            self.setup = self.setup or spans[-1].setup
+        else:
+            self.parent = None
+        spans.append(self)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
+        _open_spans().pop()
         self._annotation.__exit__(*exc)
         self._tel._record_loop(self.name, self._t0, dur, self.step,
-                               self.tags)
+                               self.tags, self.id, self.parent, self.setup)
         return False
 
 
@@ -144,8 +198,9 @@ class Telemetry:
         self._anchor_perf = time.perf_counter()
         self._spans = deque(maxlen=cap)
         self._events = deque(maxlen=cap)
-        # always on, fixed bound: see loop_span
+        # always on, fixed bounds: see loop_span
         self._loop = deque(maxlen=LOOP_RING)
+        self._loop_setup = []
         # cumulative per-span-name aggregates: survive both the ring
         # bound and drain_spans (the periodic batch push), like the
         # series' count/total — the snapshot must describe the whole
@@ -186,28 +241,45 @@ class Telemetry:
             agg['count'] += 1
             agg['total_s'] += dur
 
-    def loop_span(self, name, step=None, **tags):
+    def loop_span(self, name, step=None, setup=False, **tags):
         """A timed context manager for the training loop's own spans,
         recorded whether or not telemetry is enabled: a
         ``jax.profiler.TraceAnnotation`` named ``name`` and a record
-        ``{'name', 't0', 'dur', 'step'}`` (``t0`` a ``perf_counter``
-        reading, ``dur`` seconds, plus ``tags`` if any) in a ring of
+        ``{'name', 't0', 'dur', 'step', 'id', 'parent'}`` (``t0`` a
+        ``perf_counter`` reading, ``dur`` seconds, ``parent`` the
+        ``id`` of the loop span open on this thread as this one opened
+        or ``None``, plus ``tags`` if any) in a ring of
         :data:`LOOP_RING` records (:meth:`loop_records`). With
-        telemetry enabled the span also lands in the span buffer like
-        any other."""
-        return _LoopSpan(self, name, step, tags)
+        ``setup`` the record, and every record made on this thread
+        while the span is open, is kept outside the ring, in a list of
+        at most :data:`LOOP_SETUP`. With telemetry enabled the span
+        also lands in the span buffer like any other."""
+        return _LoopSpan(self, name, step, setup, tags)
 
     def loop_event(self, name, step=None, **tags):
-        """A point event in the loop ring (``dur`` is ``None``); with
-        telemetry enabled also an :meth:`event`."""
-        self._record_loop(name, time.perf_counter(), None, step, tags)
+        """A point event in the loop ring (``dur`` is ``None``), a
+        child of the loop span open on this thread; with telemetry
+        enabled also an :meth:`event`."""
+        self._record_under_open(name, time.perf_counter(), None, step,
+                                tags)
 
-    def _record_loop(self, name, t0, dur, step, tags):
-        rec = {'name': name, 't0': t0, 'dur': dur, 'step': step}
+    def _record_under_open(self, name, t0, dur, step, tags):
+        spans = _open_spans()
+        parent, setup = ((spans[-1].id, spans[-1].setup) if spans
+                         else (None, False))
+        self._record_loop(name, t0, dur, step, tags, next(_IDS), parent,
+                          setup)
+
+    def _record_loop(self, name, t0, dur, step, tags, id_, parent, setup):
+        rec = {'name': name, 't0': t0, 'dur': dur, 'step': step,
+               'id': id_, 'parent': parent}
         if tags:
             rec['tags'] = tags
         with self._lock:
-            self._loop.append(rec)
+            if setup and len(self._loop_setup) < LOOP_SETUP:
+                self._loop_setup.append(rec)
+            else:
+                self._loop.append(rec)
         if self.enabled:
             tags = dict(tags, step=step)
             if dur is None:
@@ -216,9 +288,14 @@ class Telemetry:
                 self._record_span(name, t0, dur, tags)
 
     def loop_records(self):
-        """The loop ring, oldest first (at most :data:`LOOP_RING`)."""
+        """The kept set-up records (at most :data:`LOOP_SETUP`) and the
+        loop ring (at most :data:`LOOP_RING`) as one list, in the order
+        they were recorded: a span as it closed, so after its
+        children."""
         with self._lock:
-            return list(self._loop)
+            records = self._loop_setup + list(self._loop)
+        records.sort(key=lambda r: r['t0'] + (r['dur'] or 0.0))
+        return records
 
     def event(self, name, **tags):
         """A point (instant) event."""
@@ -330,3 +407,23 @@ def reset():
     global _SINGLETON
     with _SINGLETON_LOCK:
         _SINGLETON = None
+
+
+def _on_jax_duration(event, duration, **kwargs):
+    """One timed ring record for each of :data:`JAX_DURATIONS`, ending
+    now, under the loop span open on the compiling thread. JAX reports
+    these only when something is traced, lowered or compiled: never in
+    a steady step."""
+    name = JAX_DURATIONS.get(event)
+    if name is None or (name == 'jax.trace' and duration < MIN_TRACE_S):
+        return
+    tags = {}
+    if 'fun_name' in kwargs:
+        tags['fun_name'] = kwargs['fun_name']
+    get()._record_under_open(name, time.perf_counter() - duration,
+                             duration, None, tags)
+
+
+# once per process and handed to whichever registry is current: a
+# listener cannot be taken off, so reset() must not add another
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)

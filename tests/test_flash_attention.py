@@ -5,6 +5,8 @@ the kernel must match the straightforward jnp attention — forward and
 gradients — for causal/full, odd block splits, and through the
 MultiHeadAttention module's dispatch.
 """
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -581,11 +583,11 @@ def test_flash_plan_says_whether_the_kernels_rotate(monkeypatch):
             vocab=64, dim=128, n_layers=7, n_heads=2, max_len=128,
             causal=False, remat=True, **kw))
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-        n_before = len(telemetry.get().loop_records())
+        t_before = time.perf_counter()
         del made[:]
         jax.eval_shape(jax.grad(model.loss), params, batch)
-        return [r['tags'] for r in telemetry.get().loop_records()[n_before:]
-                if r['name'] == 'flash.plan']
+        return [r['tags'] for r in telemetry.get().loop_records()
+                if r['t0'] >= t_before and r['name'] == 'flash.plan']
 
     patterned = plans(positions='rotary', window=16, global_every=3,
                       embed_norm=True, rope_theta=160000.0,

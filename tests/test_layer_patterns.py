@@ -2,6 +2,8 @@
 periods against the unrolled stack, the parallel layouts that take a
 window and a layer pattern and those that refuse them, the XLA band
 path, rotary positions and the gated MLP's sharding."""
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -88,7 +90,7 @@ def test_scan_over_periods_equals_the_unrolled_stack(loss_chunk):
     scanned = TransformerLM(tiny(loss_chunk=loss_chunk))
     plain = TransformerLM(tiny(scan_layers=False, remat=False))
     params = scanned.init(jax.random.PRNGKey(0))
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     got, got_g = jax.value_and_grad(scanned.loss)(params, batch())
     want, want_g = jax.value_and_grad(plain.loss)(
         unrolled_from_scanned(scanned, params), batch())
@@ -98,8 +100,8 @@ def test_scan_over_periods_equals_the_unrolled_stack(loss_chunk):
     for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-6, rtol=1e-4)
-    events = [r['tags'] for r in telemetry.get().loop_records()[n_before:]
-              if r['name'] == 'transformer.layers']
+    events = [r['tags'] for r in telemetry.get().loop_records()
+              if r['t0'] >= t_before and r['name'] == 'transformer.layers']
     assert events[0] == dict(
         n_layers=7, period=3, periods=2, remainder=1,
         pattern='window/window/global', scanned=True, global_layers=3,
@@ -148,11 +150,11 @@ def test_rotary_inside_the_kernels_is_rotary_outside_them(monkeypatch,
         monkeypatch.setattr(fa, 'MIN_KERNEL_SEQ', crossover)
         tr = Trainer(model, optax.sgd(0.1), spec=ParallelSpec(**spec_kw))
         state = tr.init(jax.random.PRNGKey(0))
-        n_before = len(telemetry.get().loop_records())
+        t_before = time.perf_counter()
         state, metrics = tr.step(state, batch())
         plans = [r['tags']['rotary']
-                 for r in telemetry.get().loop_records()[n_before:]
-                 if r['name'] == 'flash.plan']
+                 for r in telemetry.get().loop_records()
+                 if r['t0'] >= t_before and r['name'] == 'flash.plan']
         assert (plans and all(plans)) if crossover == 16 else not plans
         after[crossover] = (float(metrics['loss']),
                             jax.tree.map(np.asarray, state.params))
@@ -271,9 +273,9 @@ def without_the_policy(monkeypatch):
                         lambda *names: None)
 
 
-def remat_events(n_before):
-    return [r['tags'] for r in telemetry.get().loop_records()[n_before:]
-            if r['name'] == 'transformer.remat']
+def remat_events(t_before):
+    return [r['tags'] for r in telemetry.get().loop_records()
+            if r['t0'] >= t_before and r['name'] == 'transformer.remat']
 
 
 # (cfg, kernel calls of the gradient with the policy: forward, dq and
@@ -303,10 +305,10 @@ def test_remat_runs_the_forward_kernel_once_a_layer(stack, monkeypatch,
         return jax.jit(jax.grad(lambda p, b: model.loss(p, b))).trace(
             params, data)
 
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     kept = traced()
     assert kernel_calls(kept.jaxpr) == want
-    assert remat_events(n_before) == [dict(
+    assert remat_events(t_before) == [dict(
         policy='save_only_these_names', saved=['flash_o', 'flash_lse'],
         layers=model.cfg.n_layers,
         saved_bytes_per_layer=2 * 4 * 64 * (16 * 4 + 4))]
@@ -338,10 +340,10 @@ def test_remat_keeps_the_forward_kernel_under_dp2_tp2(monkeypatch,
         state, _ = tr.step(state, batch())
         return jax.tree.map(np.asarray, state.params)
 
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     kept = step(4)
     # a device's shard: batch 2 of 4, heads 2 of 4
-    assert remat_events(n_before)[0]['saved_bytes_per_layer'] == \
+    assert remat_events(t_before)[0]['saved_bytes_per_layer'] == \
         2 * 2 * 64 * (16 * 4 + 4)
     without_the_policy(monkeypatch)
     for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(step(8))):
@@ -362,18 +364,18 @@ def test_a_block_off_the_kernel_is_the_checkpoint_without_a_policy(
         return jax.jit(jax.value_and_grad(
             lambda p, b: model.loss(p, b))).lower(shapes, data).as_text()
 
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     with_policy = lowered()
-    assert remat_events(n_before) == [dict(
+    assert remat_events(t_before) == [dict(
         policy='save_only_these_names', saved=['flash_o', 'flash_lse'],
         layers=0, saved_bytes_per_layer=0)]
     without_the_policy(monkeypatch)
     assert lowered() == with_policy
     # unrolled layers count one by one; no tier but True leaves the event
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     for kw in (dict(remat=True, scan_layers=False), dict(remat='save_attn'),
                dict()):
         other = TransformerLM(TransformerConfig.tiny(**kw))
         jax.eval_shape(other.loss, jax.eval_shape(
             other.init, jax.random.PRNGKey(0)), batch(seq=64))
-    assert [e['layers'] for e in remat_events(n_before)] == [0]
+    assert [e['layers'] for e in remat_events(t_before)] == [0]

@@ -403,7 +403,13 @@ def test_counters_are_read_back_with_the_loss():
               if r['name'] == 'trainer.counters']
     assert len(events) >= 2 and events[-1]['step'] == 3
     assert set(events[-1]['tags']) == {'moe_rows_here', 'moe_load_max',
-                                       'moe_load_mean'}
+                                       'moe_load_mean', 'trainer'}
+    # and the copies back are under a span of their own, after the loss's
+    spans = [r for r in telemetry.get().loop_records()
+             if r['name'] == 'trainer.counters_readback'
+             and r['tags']['trainer'] == events[-1]['tags']['trainer']]
+    assert [r['step'] for r in spans] == [2, 3]
+    assert spans[-1]['t0'] + spans[-1]['dur'] <= events[-1]['t0']
     # a dense model's step returns the loss alone, as before
     dense = Trainer(TransformerLM(TransformerConfig.tiny(
         dtype=jnp.float32, n_layers=1)), optax.sgd(0.1))

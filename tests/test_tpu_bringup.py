@@ -19,6 +19,7 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
 
 import pytest
 
@@ -167,7 +168,7 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
     tables = 2 * rotary * (
         jax.ShapeDtypeStruct((s, 128), jnp.float32, sharding=whole),)
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     exported = jax.export.export(
         jax.jit(jax.value_and_grad(
             lambda qkv, *tables: jnp.sum(
@@ -189,8 +190,8 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     # concatenated
     assert sum('output_operand_aliases' in line for line in calls) == 1
 
-    plans = [r for r in telemetry.get().loop_records()[n_before:]
-             if r['name'] == 'flash.plan']
+    plans = [r for r in telemetry.get().loop_records()
+             if r['t0'] >= t_before and r['name'] == 'flash.plan']
     assert plans and plans[-1]['dur'] is None
     tags = plans[-1]['tags']
     seq = tags['seq']
@@ -393,7 +394,7 @@ def _export_remat_block(case):
     state = tr.init(jax.random.PRNGKey(0))
     batch = {name: np.zeros((dp * per_chip, seq), np.int32)
              for name in ('tokens', 'targets')}
-    n_before = len(telemetry.get().loop_records())
+    t_before = time.perf_counter()
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(fa, '_interpret_default', lambda: False)
         step = tr._ensure_step(tr._step_key(batch), state, batch)
@@ -402,8 +403,8 @@ def _export_remat_block(case):
             batch, tr.batch_sharding(batch))
         text = jax.export.export(step, platforms=['tpu'])(
             state, shapes).mlir_module()
-    event = [r['tags'] for r in telemetry.get().loop_records()[n_before:]
-             if r['name'] == 'transformer.remat'][0]
+    event = [r['tags'] for r in telemetry.get().loop_records()
+             if r['t0'] >= t_before and r['name'] == 'transformer.remat'][0]
     return text, event, cfg
 
 
