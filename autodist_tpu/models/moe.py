@@ -21,11 +21,28 @@ overflowed a capacity, are gone):
   (token, choice) pairs whose expert is held: by expert, and inside an
   expert by token. Each expert's rows start on a tile of
   ``grouped_matmul.TILE_ROWS`` rows and are padded to whole tiles, so a
-  tile is one expert's; a pair's row is ``start of its expert + its
-  rank there`` (the rank is a running count over the tokens: no sort).
-  The row buffer is sized for the worst case, every pair held: ``tokens
-  x min(top_k, held)`` rows and a tile of padding an expert. NO ROW IS
-  DROPPED at any routing.
+  tile is one expert's. The rows' token and weight come by ONE stable
+  sort of the pairs by expert and a copy of each expert's run to its
+  tiles (``_runs``); the same layout by token, ``row_of [t, held]`` for
+  the combine, is ``start of the expert + the pair's rank there`` (a
+  running count over the tokens). In the backward pass the rows'
+  gradient goes back to the pairs the same way: the runs copied back
+  and the sort undone by a sort. No single number is moved by an
+  index: XLA does that at 4.5 ns apiece on a v5e (1.2 ms a scatter of
+  262,144 pairs, 1.9 the gather that transposes it, 2.2 a
+  ``take_along_axis``, all of which this replaced) where a sort of the
+  pairs is 0.3 ms. The row buffer is sized for the worst case, every
+  pair held: ``tokens x min(top_k, held)`` rows and a tile of padding an
+  expert. NO ROW IS DROPPED at any routing. The order is made ONCE a
+  step: what the backward pass reads of it goes by ``CHECKPOINT_NAMES``,
+  which ``TransformerLM``'s ``remat=True`` keeps (``_block_fn``), so the
+  block run again in the backward makes none of it; it still runs the
+  router, the softmax and ``top_k``, whose own backward reads them (0.4
+  ms a layer). Kept: the sorted ``pairs [t * k]``, ``token`` and
+  ``weight`` by row, ``row_of [t, held]``, a tile's expert, the live
+  tiles and the experts' sizes: integers and one f32 vector, ``4 x (t x
+  k + 2 x rows + t x held)`` bytes, 5.3 MB a layer at 32,768 tokens, 8
+  of 64, 16 held, beside the 453 MB a pass of the combine holds.
 * **experts** (``moe_experts``) between **dispatch** (``moe_dispatch``):
   the rows are walked in CHUNKS of ``CHUNK_TILES`` tiles by a
   ``while_loop`` that stops after the last live tile, so the time
@@ -65,6 +82,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from autodist_tpu import telemetry
@@ -80,6 +98,12 @@ from autodist_tpu.parallel.axes import (AXIS_DATA, active_manual_axes,
 # every row of a chunk, live or not, so a chunk is what a step wastes at
 # most; the temporaries of a step are a chunk's.
 CHUNK_TILES = 16
+# What the backward pass reads of the order (`_order`), by the names a
+# block's checkpoint keeps it under (models/transformer.py `_block_fn`),
+# so that the recomputed block makes none of it again: integers and one
+# f32 vector, 5.3 MB a layer at Mellum2's shape.
+CHECKPOINT_NAMES = ('moe_pairs', 'moe_token', 'moe_weight', 'moe_tile_group',
+                    'moe_live', 'moe_row_of', 'moe_sizes')
 # Chunks whose rows one pass of the combine holds side by side: 98,304
 # rows, 453 MB of bf16 at Mellum2's width, a third more than the cell's
 # 16 experts hold of 32,768 tokens at an even load (its worst case is
@@ -153,11 +177,12 @@ class MoeMlp(Module):
         with jax.named_scope('moe_route'):
             probs, weights, idx = self._route(router, tokens)
             order = _order(idx - first, weights, held)
-        _note_plan(order['token'].shape[0], d, self.dtype)
+        _note_plan(order, d, self.dtype)
         y = _experts(functools.partial(_hidden, self.act, self.gated, hidden),
                      tokens.astype(self.dtype), up.reshape(held, d, -1), down,
                      order['token'], order['weight'], order['tile_group'],
-                     order['live'], order['row_of'], order['token_weight'])
+                     order['live'], order['row_of'], order['token_weight'],
+                     order['pairs'], order['sizes'], weights)
         f = jnp.sum(jax.nn.one_hot(idx[:, 0], self.n_experts,
                                    dtype=jnp.float32), axis=0)
         return y.reshape(b, s, d), f, jnp.sum(probs, axis=0), order['sizes']
@@ -249,8 +274,11 @@ def _order(local, weights, held):
     0), each tile's expert ``tile_group``, the number of ``live`` tiles
     (``[1]``) and the experts' ``sizes`` in rows; and the same layout by
     token, for the combine: ``row_of [t, held]``, the row of the pair
-    ``(t, expert)`` or -1, and ``token_weight [t, held]``, its weight
-    (no gradient goes through it: the rows' ``weight`` carries it)."""
+    ``(t, expert)`` or -1, and ``token_weight [t, held]``, its weight;
+    and ``pairs [t * k]``, the pairs ``t * k + j`` as the sort left them
+    (the held ones first, in the rows' order), by which the backward
+    pass takes the rows' gradient to ``weights`` (:func:`_experts`): no
+    gradient goes through anything returned here."""
     t, k = local.shape
     tile = gm.TILE_ROWS
     rows = buffer_rows(t, k, held)
@@ -263,26 +291,57 @@ def _order(local, weights, held):
         pair, weights.astype(jnp.float32)[:, :, None], 0.0), axis=1)
     sizes = jnp.sum(chosen, axis=0)
     rank = jnp.cumsum(chosen, axis=0) - chosen                 # [t, held]
-    tiles = -(-sizes // tile)
-    tile_end = jnp.cumsum(tiles)
-    start = (tile_end - tiles) * tile                          # [held]
+    tile_end = jnp.cumsum(-(-sizes // tile))
+    start, first = _run_starts(sizes)                          # [held]
     row_of = jnp.where(chosen > 0, start[None, :] + rank, -1)
-    at = jnp.clip(local, 0, held - 1)
-    row = jnp.where(is_held, start[at] + jnp.take_along_axis(rank, at, 1),
-                    rows)                                      # [t, k]
-    token = jnp.zeros((rows,), jnp.int32).at[row.ravel()].set(
-        jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode='drop',
-        unique_indices=True)
-    weight = jnp.zeros((rows,), jnp.float32).at[row.ravel()].set(
-        weights.astype(jnp.float32).ravel(), mode='drop',
-        unique_indices=True)
     tile_group = jnp.minimum(jnp.searchsorted(
         tile_end, jnp.arange(rows // tile), side='right'),
         held - 1).astype(jnp.int32)
-    return {'token': token, 'weight': weight, 'tile_group': tile_group,
-            'live': tile_end[-1:].astype(jnp.int32), 'sizes': sizes,
-            'row_of': row_of.astype(jnp.int32),
-            'token_weight': jax.lax.stop_gradient(token_weight)}
+    # token and weight of every row by ONE stable sort of the pairs by
+    # expert (they come by token) and a copy of each expert's run to its
+    # tiles: no scatter by row
+    _, pairs, weight = jax.lax.sort(
+        (jnp.where(is_held, local, held).ravel(),
+         jnp.arange(t * k, dtype=jnp.int32),
+         jax.lax.stop_gradient(weights).astype(jnp.float32).ravel()),
+        num_keys=1, is_stable=True)
+    in_run = (jnp.arange(rows).reshape(-1, tile)
+              < (start + sizes)[tile_group][:, None]).ravel()
+
+    def by_row(sorted_pairs):
+        return jnp.where(in_run, _runs(sorted_pairs, first, start, t, rows),
+                         0)
+    order = {'pairs': pairs, 'token': by_row(pairs // k),
+             'weight': by_row(weight), 'tile_group': tile_group,
+             'live': tile_end[-1:].astype(jnp.int32),
+             'row_of': row_of.astype(jnp.int32), 'sizes': sizes,
+             'token_weight': jax.lax.stop_gradient(token_weight)}
+    return {key: checkpoint_name(x, 'moe_' + key)
+            if 'moe_' + key in CHECKPOINT_NAMES else x
+            for key, x in order.items()}
+
+
+def _run_starts(sizes):
+    """Where each expert's run starts among the rows (on a tile) and
+    among the sorted pairs, for the experts' ``sizes``."""
+    tiles = -(-sizes // gm.TILE_ROWS)
+    return ((jnp.cumsum(tiles) - tiles) * gm.TILE_ROWS,
+            jnp.cumsum(sizes) - sizes)
+
+
+def _runs(x, source, target, length, size):
+    """``size`` zeros with ``x [source[e]:source[e] + length]`` copied to
+    ``target[e]`` for each expert ``e`` in turn: a run in another place;
+    what it carries past its own end the next one overwrites, and the
+    caller masks the rest."""
+    x = jnp.pad(x, (0, length))
+
+    def run(e, out):
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jax.lax.dynamic_slice_in_dim(x, source[e], length),
+            target[e], 0)
+    return jax.lax.fori_loop(0, source.shape[0], run, jnp.zeros(
+        (size + length,), x.dtype))[:size]
 
 
 # ---------------------------------------------------------------------------
@@ -364,18 +423,20 @@ def _walk(live, row_of, token_weight, rows, like, step, state):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _experts(hidden, x, w_up, w_down, token, weight, tile_group, live,
-             row_of, token_weight):
+             row_of, token_weight, pairs, sizes, weights):
     """``out[t] = sum over the rows r of token t of weight[r] * (hidden(
     x[t] @ w_up[e_r]) @ w_down[e_r])`` for ``x [tokens, dim]``, the held
     experts' ``w_up [held, dim, n * hidden]`` and ``w_down [held, hidden,
     dim]`` (f32; multiplied in x's dtype) and the rows' layout
-    (:func:`_order`); ``[tokens, dim]`` in x's dtype."""
+    (:func:`_order`); ``[tokens, dim]`` in x's dtype. The rows' weights
+    take their gradient as the pairs' ``weights [t, k]``, through
+    ``pairs`` and ``sizes``."""
     return _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group,
-                        live, row_of, token_weight)[0]
+                        live, row_of, token_weight, pairs, sizes, weights)[0]
 
 
 def _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group, live,
-                 row_of, token_weight):
+                 row_of, token_weight, pairs, sizes, weights):
     up, down = w_up.astype(x.dtype), w_down.astype(x.dtype)
 
     def step(i, state):
@@ -391,11 +452,12 @@ def _experts_fwd(hidden, x, w_up, w_down, token, weight, tile_group, live,
 
     out, _ = _walk(live, row_of, token_weight, token.shape[0], x, step, ())
     return out.astype(x.dtype), (x, w_up, w_down, token, weight, tile_group,
-                                 live, row_of)
+                                 live, row_of, pairs, sizes)
 
 
 def _experts_bwd(hidden, res, dout):
-    x, w_up, w_down, token, weight, tile_group, live, row_of = res
+    (x, w_up, w_down, token, weight, tile_group, live, row_of, pairs,
+     sizes) = res
     up, down = w_up.astype(x.dtype), w_down.astype(x.dtype)
 
     def step(i, state):
@@ -431,28 +493,37 @@ def _experts_bwd(hidden, res, dout):
         (jnp.zeros(w_up.shape, jnp.float32),
          jnp.zeros(w_down.shape, jnp.float32),
          jnp.zeros(weight.shape, jnp.float32)))
-    # Rows of no live tile were never written: the select changes no
-    # value. It gives the gather that transposes `_order`'s scatter an
-    # operand XLA keeps in fast memory; straight out of the loop it is
-    # gathered from HBM, at a third of the rate (PERF.md §6, PR 34).
-    d_weight = jnp.where(
-        jnp.arange(d_weight.shape[0]) < live[0] * gm.TILE_ROWS, d_weight, 0.0)
+    with jax.named_scope('moe_route'):
+        # the rows' gradient back to the pairs: each expert's run to
+        # where the sort had it, then the sort undone by one of its own
+        n = pairs.shape[0]
+        by_pairs = _runs(d_weight, *_run_starts(sizes), x.shape[0], n)
+        _, d_weights = jax.lax.sort(
+            (pairs, jnp.where(jnp.arange(n) < jnp.sum(sizes), by_pairs, 0.0)),
+            num_keys=1)
+        d_weights = d_weights.reshape(x.shape[0], -1)
     return (dx.astype(x.dtype), d_up.astype(w_up.dtype),
-            d_down.astype(w_down.dtype), None, d_weight, None, None, None,
-            None)
+            d_down.astype(w_down.dtype), None, None, None, None, None, None,
+            None, None, d_weights)
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def _note_plan(rows, dim, dtype):
+def _note_plan(order, dim, dtype):
     """One ``moe.plan`` point event a trace of the layer: how its rows
     move between the tokens' order and the experts' (``rows`` and
-    ``buffer_bytes``: of the buffer a pass of the combine holds)."""
-    chunks = pass_chunks(rows)
+    ``buffer_bytes``: of the buffer a pass of the combine holds) and how
+    that order is made and kept (``order_scatters``: single numbers a
+    trace of the layer moves by an index, by row; ``order_saved_bytes``:
+    of what ``CHECKPOINT_NAMES`` name)."""
+    chunks = pass_chunks(order['token'].shape[0])
     held = chunks * CHUNK_TILES * gm.TILE_ROWS
+    kept = [order[name[len('moe_'):]] for name in CHECKPOINT_NAMES]
     telemetry.get().loop_event(
         'moe.plan', rows=held, chunk_tiles=CHUNK_TILES, pass_chunks=chunks,
         token_block=mc.TOKEN_BLOCK, window_rows=mc.WINDOW_ROWS,
         combine='pallas', gather='xla',
-        buffer_bytes=held * dim * jnp.dtype(dtype).itemsize)
+        buffer_bytes=held * dim * jnp.dtype(dtype).itemsize,
+        order='sort', order_scatters=0,
+        order_saved_bytes=sum(x.size * x.dtype.itemsize for x in kept))

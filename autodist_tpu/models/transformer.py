@@ -39,7 +39,8 @@ class TransformerConfig:
     # recomputes the block, all but the flash kernel's forward call
     # where the block makes one, whose output ([b, s, dim], the input of
     # the output projection) and row statistics are kept: b x s x (dim
-    # x itemsize + 4 x heads) bytes a layer (docs/design/kernels.md);
+    # x itemsize + 4 x heads) bytes a layer (docs/design/kernels.md),
+    # and an expert layer's order of its rows (models/moe.py);
     # 'save_attn' = checkpoint each block but
     # SAVE the post-attention residual, so backward recomputes only the
     # LN2+MLP half at one extra [b,s,d] save per layer (it matters when
@@ -281,6 +282,14 @@ class TransformerLM(Module):
     names ``block_000``...: ModernBERT's 28 are layer 0 (global, and
     the one whose attention norm is the identity) and 9 periods of
     (window, window, global). ``_layers`` has the arithmetic.
+
+    ``remat=True`` runs a block again in the backward, all but what
+    its checkpoint keeps by name (``_block_fn``, ``_saved_names``): the
+    flash kernel's output and ``lse`` (``fa.CHECKPOINT_NAMES``) and, in
+    a model with expert layers, the order of their rows
+    (``models/moe.CHECKPOINT_NAMES``: integers, a few MB a layer), so
+    that neither the kernel's forward call nor the sort that lays the
+    rows out runs twice a step.
     """
 
     def __init__(self, cfg):
@@ -432,8 +441,10 @@ class TransformerLM(Module):
         ``cfg.remat``: False (no remat), True (recompute the block in
         the backward, all but the flash kernel's forward call: the
         policy keeps what ``fa.flash_attention_merged`` names, its
-        output and ``lse``; a block on another attention path names
-        nothing and is recomputed whole, as without a policy), or a
+        output and ``lse``, and what an expert layer names of the order
+        of its rows, ``_saved_names``; a block on another attention
+        path and without experts names nothing and is recomputed
+        whole, as without a policy), or a
         named selective policy:
         'save_attn' (keep attention outputs), 'dots' (keep every
         matmul output — recompute only elementwise/norm work; the
@@ -466,8 +477,17 @@ class TransformerLM(Module):
             return jax.checkpoint(
                 block_fn,
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    *fa.CHECKPOINT_NAMES))
+                    *self._saved_names()))
         return block_fn
+
+    def _saved_names(self):
+        """What ``remat=True`` keeps of a block: what the flash kernel's
+        forward names and, with expert layers, what they name of the
+        order of their rows (``models/moe.py``)."""
+        if not self.cfg.moe_experts:
+            return fa.CHECKPOINT_NAMES
+        from autodist_tpu.models import moe
+        return fa.CHECKPOINT_NAMES + moe.CHECKPOINT_NAMES
 
     def hidden_with_aux(self, params, tokens):
         """Final hidden states (post ln_f) and the MoE aux loss —
@@ -614,7 +634,7 @@ class TransformerLM(Module):
                 if shape is not None]
         telemetry.get().loop_event(
             'transformer.remat', policy='save_only_these_names',
-            saved=list(fa.CHECKPOINT_NAMES), layers=len(kept),
+            saved=list(self._saved_names()), layers=len(kept),
             saved_bytes_per_layer=max(kept, default=0))
 
     def per_token_loss(self, params, batch):

@@ -1,11 +1,16 @@
 """The expert layer (``models/moe.py``), its grouped products
 (``kernels/grouped_matmul.py``, PR 33) and its combine
-(``kernels/moe_combine.py``, PR 34): the kernels in interpret mode
+(``kernels/moe_combine.py``, PR 34): the order of the held pairs against
+a stable sort and what a block's checkpoint keeps of it (PR 38), the
+kernels in interpret mode
 against a loop over the groups and against XLA's scatter-add, the layer
 against a loop over the experts (loss and every gradient), the share of
 a deployment (the parts that all the shares give add up to the uncut
 layer), and that no row is dropped at the worst routing the buffers are
 sized for."""
+import re
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +19,7 @@ import pytest
 
 from autodist_tpu import telemetry
 from autodist_tpu.api import Trainer
+from autodist_tpu.kernels import flash_attention as fa
 from autodist_tpu.kernels import grouped_matmul as gm
 from autodist_tpu.kernels import moe_combine as mc
 from autodist_tpu.models import moe
@@ -228,10 +234,187 @@ def test_the_layer_leaves_its_plan_in_the_ring():
     run(p, x)
     assert len(plans()) == before + 1
     rows = moe.buffer_rows(80, 2, 4)
+    # of the order the checkpoint keeps the sorted pairs [t * k], token
+    # and weight by row, a tile's expert, the live tiles, row_of [t,
+    # held] and the held experts' sizes
     assert plans()[-1]['tags'] == dict(
         rows=rows, chunk_tiles=moe.CHUNK_TILES, pass_chunks=1,
         token_block=mc.TOKEN_BLOCK, window_rows=mc.WINDOW_ROWS,
-        combine='pallas', gather='xla', buffer_bytes=rows * 32 * 4)
+        combine='pallas', gather='xla', buffer_bytes=rows * 32 * 4,
+        order='sort', order_scatters=0,
+        order_saved_bytes=4 * (80 * 2 + 2 * rows + rows // TILE + 1
+                               + 80 * 4 + 4))
+
+
+def plain_order(local, weights, held):
+    """``moe._order`` in numpy: a stable sort of the held pairs by
+    expert (so inside an expert by token), each expert's run from a
+    tile's start."""
+    t, k = local.shape
+    rows = moe.buffer_rows(t, k, held)
+    at_token, at_choice = np.divmod(np.arange(t * k), k)
+    is_held = ((local >= 0) & (local < held)).ravel()
+    at_token, at_choice = at_token[is_held], at_choice[is_held]
+    expert = local.ravel()[is_held]
+    by_expert = np.argsort(expert, kind='stable')
+    sizes = np.bincount(expert, minlength=held)
+    tiles = -(-sizes // TILE)
+    start = (np.cumsum(tiles) - tiles) * TILE
+    first = np.cumsum(sizes) - sizes
+    out = dict(
+        # the pairs as the sort leaves them: the held by expert, then
+        # the others as they came
+        pairs=np.concatenate([np.flatnonzero(is_held)[by_expert],
+                              np.flatnonzero(~is_held)]),
+        token=np.zeros(rows, int),
+        weight=np.zeros(rows, 'f4'), row_of=np.full((t, held), -1),
+        token_weight=np.zeros((t, held), 'f4'), sizes=sizes,
+        live=np.asarray([tiles.sum()]),
+        tile_group=np.concatenate([
+            np.repeat(np.arange(held), tiles),
+            np.full(rows // TILE - tiles.sum(), held - 1)]))
+    for place, pair in enumerate(by_expert):
+        e, i, j = expert[pair], at_token[pair], at_choice[pair]
+        row = start[e] + place - first[e]
+        out['row_of'][i, e] = row
+        out['token'][row] = i
+        out['weight'][row] = out['token_weight'][i, e] = weights[i, j]
+    return out
+
+
+def _order_cases():
+    rng = np.random.RandomState(11)
+
+    def choices(t, k, n, first):      # k distinct experts of n a token
+        return np.argsort(rng.rand(t, n), axis=1)[:, :k] - first
+    one = np.stack([np.full(600, 2), np.full(600, -3)], axis=1)
+    return {
+        'even_load': (choices(300, 2, 8, 2), 4),
+        # test_no_row_is_dropped_at_the_worst_routing's: one expert's run
+        # is every token, and every pair of every token is held
+        'every_token_to_one_held_expert': (one, 4),
+        'every_pair_held': (choices(600, 2, 2, 0), 2),
+        'no_pair_held': (np.where(choices(200, 2, 8, 0) < 4, -1, 7), 3),
+        'held_less_than_top_k': (choices(260, 4, 8, 3), 2),
+        'held_is_n_experts': (choices(256, 3, 8, 0), 8),
+        'tokens_no_multiple_of_128': (choices(333, 2, 8, 1), 5),
+    }
+
+
+@pytest.mark.parametrize('case', sorted(_order_cases()))
+def test_order_is_a_stable_sort_of_the_held_pairs(case):
+    local, held = _order_cases()[case]
+    weights = np.random.RandomState(13).rand(*local.shape).astype('f4')
+    want = plain_order(local, weights, held)
+    got = jax.jit(moe._order, static_argnums=2)(
+        jnp.asarray(local, jnp.int32), jnp.asarray(weights), held)
+    assert set(got) == set(want)
+    for name in sorted(want):
+        assert got[name].shape == want[name].shape, name
+        np.testing.assert_array_equal(np.asarray(got[name]), want[name],
+                                      err_msg=name)
+    assert {name for name, x in got.items() if x.dtype == jnp.float32} \
+        == {'weight', 'token_weight'}
+
+
+# -- what a block's checkpoint keeps of the order (PR 38) ------------------
+
+def nested(jaxpr, recomputed=False):
+    """``(equation, whether it lies in the body of a checkpoint
+    equation)`` of ``jaxpr`` and of every jaxpr its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn, recomputed
+        inside = recomputed or eqn.primitive.name == 'remat2'
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    yield from nested(sub, inside)
+
+
+def expert_model(**over):
+    cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=2,
+                                 moe_experts=8, moe_held=4, moe_top_k=2,
+                                 **over)
+    model = TransformerLM(cfg)
+    rng = np.random.RandomState(0)
+    batch = {'tokens': rng.randint(0, 256, (2, 32)),
+             'targets': rng.randint(0, 256, (2, 32))}
+    return model, model.init(jax.random.PRNGKey(0)), batch
+
+
+@pytest.mark.parametrize('scan', [False, True], ids=['unrolled', 'scanned'])
+def test_the_recomputed_block_makes_no_order(scan):
+    """Under ``remat=True`` the body of every checkpoint equation of
+    ``grad(loss)`` (the block run again, and its backward) holds none of
+    the order's making: no sort but the one that takes the rows'
+    gradient back to the pairs (and ``top_k``'s own), no scatter, no
+    gather of integers (what ``take_along_axis`` of the rank was) or
+    out of a vector (what transposed the rows' scatter): the checkpoint
+    keeps the order by ``moe.CHECKPOINT_NAMES``. The ``scatter-add``
+    there transposes ``top_k``'s gather. Nowhere is a number scattered
+    by row."""
+    model, params, batch = expert_model(remat=True, scan_layers=scan)
+    t_before = time.perf_counter()
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: model.loss(p, batch)))(params)
+    found = list(nested(jaxpr.jaxpr))
+    assert any(recomputed for _, recomputed in found)
+    bodies = [e for e, recomputed in found if recomputed]
+    pairs = 2 * 32 * model.cfg.moe_top_k
+    sorts = [[v.aval.shape for v in e.invars] for e in bodies
+             if e.primitive.name == 'sort' and e.invars[0].aval.size == pairs]
+    assert sorts == [[(pairs,), (pairs,)]] * (1 if scan else 2)
+    assert not [e for e in bodies if e.primitive.name == 'gather' and (
+        jnp.issubdtype(e.invars[0].aval.dtype, jnp.integer)
+        or e.invars[0].aval.ndim == 1)]
+    assert not [e for e, _ in found if e.primitive.name == 'scatter']
+    named = {e.params['name'] for e, _ in found
+             if e.primitive.name == 'name'}
+    assert {n for n in named if n.startswith('moe_')} \
+        == set(moe.CHECKPOINT_NAMES)
+    events = [r['tags'] for r in telemetry.get().loop_records()
+              if r['t0'] >= t_before and r['name'] == 'transformer.remat']
+    assert [e['saved'] for e in events] == [
+        list(fa.CHECKPOINT_NAMES + moe.CHECKPOINT_NAMES)]
+
+
+@pytest.mark.parametrize('scan', [False, True], ids=['unrolled', 'scanned'])
+def test_gradients_are_the_same_with_the_order_kept(scan):
+    """Every leaf, the routers' among them, under ``remat=True`` (the
+    order kept, the router run again) and under ``remat=False``."""
+    grads = {}
+    for remat in (True, False):
+        model, params, batch = expert_model(remat=remat, scan_layers=scan)
+        grads[remat] = jax.jit(jax.grad(
+            lambda p: model.loss(p, batch)))(params)
+    leaves = jax.tree_util.tree_leaves_with_path(grads[True])
+    assert any('router' in jax.tree_util.keystr(path) and np.any(leaf)
+               for path, leaf in leaves)
+    for (path, a), b in zip(leaves, jax.tree.leaves(grads[False])):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_a_dense_block_is_kept_as_it_was(monkeypatch):
+    """A block without an expert layer gives none of the order's names:
+    ``grad(loss)`` of a dense model under ``remat=True`` is the same
+    jaxpr whether or not the policy lists them."""
+    cfg = TransformerConfig.tiny(dtype=jnp.float32, n_layers=2, remat=True)
+    model = TransformerLM(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {'tokens': np.zeros((2, 32), np.int32),
+             'targets': np.ones((2, 32), np.int32)}
+    assert model._saved_names() == fa.CHECKPOINT_NAMES
+
+    def text():
+        return re.sub(r' at 0x[0-9a-f]+', '', str(jax.make_jaxpr(
+            jax.grad(lambda p: model.loss(p, batch)))(params)))
+    as_it_is = text()
+    monkeypatch.setattr(
+        TransformerLM, '_saved_names',
+        lambda self: fa.CHECKPOINT_NAMES + moe.CHECKPOINT_NAMES)
+    assert text() == as_it_is and 'remat2' in as_it_is
 
 
 def whole_layer(p, x, first, held, top_k, gated, act):
