@@ -121,6 +121,13 @@ that half-filled-MXU bound at seq 512; the forward is bound by neither
 the MXU nor its DMAs (halving its bytes did not move it) but by the
 softmax's elementwise work.
 
+* **Latent attention** (``flash_attention_latent``: a q/k head of a
+  128-lane part of its own beside a narrower rotary part whose key is
+  ONE for all heads, a v head of another width; ``flash_fwd_mla``,
+  ``flash_dq_mla``, ``flash_dkv_mla``) is a section of its own at the
+  end of the file, with bodies of its own over the helpers above: with
+  the other entry points nothing of it is on the path.
+
 On the CPU backend the same kernels run in Pallas interpret mode, so the
 CPU test mesh exercises the identical code path (tests/test_flash_attention.py);
 every other backend compiles them.
@@ -1443,12 +1450,14 @@ def rotary_tables(positions, theta, heads, head_dim):
             jnp.concatenate([sin] * repeats, axis=-1))
 
 
-def saved_bytes(shape, dtype):
+def saved_bytes(shape, dtype, v_dim=None):
     """Bytes that a :func:`flash_attention_merged` call on ``h`` heads
     of ``d`` (``shape``: [b, h, s, d]) in ``dtype`` names for the
-    checkpoint: ``o`` and the f32 ``lse``."""
+    checkpoint: ``o`` and the f32 ``lse``. ``v_dim``: the width of a v
+    head (and so of ``o``'s) where it is another than q's and k's
+    (:func:`flash_attention_latent`)."""
     b, h, s, d = shape
-    return b * h * s * (d * jnp.dtype(dtype).itemsize + 4)
+    return b * h * s * ((v_dim or d) * jnp.dtype(dtype).itemsize + 4)
 
 
 def _planned(qkv, tables, heads, kv_heads, causal, sm_scale, block_q, block_k,
@@ -1491,3 +1500,516 @@ def _planned(qkv, tables, heads, kv_heads, causal, sm_scale, block_q, block_k,
         **_plan_tags(plan, s, causal, window))
     return _flash(qkv, tables, heads, kv_heads, causal, sm_scale, plan,
                   interpret, window, named)
+
+
+# ---------------------------------------------------------------------------
+# latent attention: a q/k head in two parts, one rotary key for all heads
+# ---------------------------------------------------------------------------
+#
+# ``flash_attention_latent`` (multi-head latent attention as DeepSeek-V2
+# publishes it, on the training path: k and v are expanded from the
+# latent before the call). A q/k head is two parts: ``nope`` lanes of
+# its own (a multiple of 128: a lane block a head, for q and for k) and
+# ``rope`` lanes (128 / n: 64 is two heads to a lane block, the layout
+# the kernels have at head_dim 64) whose KEY is one for all heads, as a
+# kv head is for its group. The score is the sum of the two
+# contractions, the rotary part alone is rotated on the tile, v and the
+# output are ``v`` lanes a head (a multiple of 128). Operands, read where
+# the projections wrote them:
+#
+# * ``q [b, s, heads * (nope + rope)]``: for every ``per = 128 / rope``
+#   heads in turn their nope parts and then their rope parts, which are
+#   one lane block (:func:`latent_columns` has the order). A grid step
+#   holds whole such groups, so q is ONE operand and dq ONE result of
+#   its shape.
+# * ``kv [b, s, heads * (nope + v)]``: all the heads' k_nope, then all
+#   their v: two runs of columns of one array, an index map each, and
+#   the cotangent one such array (``flash_dkv_mla`` writes dk_nope into
+#   its first run, dv goes over the second).
+# * ``c [b, s, >= 128]``, whose first ``rope`` columns are the rotary
+#   key (the down-projection's output, the key first): the kernels fetch
+#   its first lane block and copy the key onto the block's other heads'
+#   lanes on the tile (:func:`_shared_key`); no copy of it repeated to
+#   the heads exists in HBM. ``flash_dkv_mla`` walks all the heads of a
+#   kv-block in its inner grid dimension and adds their dk_rope up in
+#   VMEM, as a kv head's is over its group.
+#
+# One body a kernel: the online-softmax / accumulating form at any
+# number of inner blocks (a row of one block is a walk of one step).
+
+Latent = collections.namedtuple('Latent', 'nope rope v')
+
+
+def _per_block(dims):
+    return _LANES // dims.rope
+
+
+def _group_lanes(dims):
+    """Lanes of one group of q: ``per`` heads' nope parts and their one
+    lane block of rope parts."""
+    return _per_block(dims) * dims.nope + _LANES
+
+
+def latent_group(heads, dims):
+    """Heads whose parts lie together in q: those whose rope parts fill
+    a lane block where the kernels can run (:func:`supports_latent`),
+    else all of them (a tiny model's, which runs under XLA)."""
+    dims = Latent(*dims)
+    return _per_block(dims) if supports_latent((1, heads, _LANES, 0), dims) \
+        else heads
+
+
+def latent_columns(heads, dims):
+    """For each column of the kernels' q ``[.., heads * (nope + rope)]``
+    the column of the head-major ``[heads, nope | rope]`` layout (the
+    published one) that it holds: ``q_kernel = q_published[...,
+    latent_columns(heads, dims)]``."""
+    dims = Latent(*dims)
+    per, width = latent_group(heads, dims), dims.nope + dims.rope
+    cols = []
+    for first in range(0, heads, per):
+        group = range(first, first + per)
+        cols += [h * width + i for h in group for i in range(dims.nope)]
+        cols += [h * width + dims.nope + i for h in group
+                 for i in range(dims.rope)]
+    return cols
+
+
+def supports_latent(shape, dims):
+    """Whether :func:`flash_attention_latent` can run for ``heads`` heads
+    over ``s`` positions (``shape``: [b, heads, s, .]) at the head's
+    parts ``dims = (nope, rope, v)``."""
+    dims = Latent(*dims)
+    _, h, s, _ = shape
+    return (_pick_block(s, 128) is not None and dims.nope % _LANES == 0
+            and dims.v % _LANES == 0 and 0 < dims.rope <= _LANES
+            and _LANES % dims.rope == 0 and dims.rope % 2 == 0
+            and h % _per_block(dims) == 0 and dims.nope % dims.v == 0)
+
+
+def preferred_latent(shape, dims):
+    """:func:`preferred` for a latent call: the same sequences."""
+    s = shape[2]
+    return (s >= MIN_KERNEL_SEQ and (s % _LANES == 0 or s <= 256)
+            and supports_latent(shape, dims))
+
+
+def _shared_key(kr_ref, tables, dims, rows=_ALL):
+    """The one rotary key of a k-block, rotated, on every head's lanes
+    of a lane block: ``[rows, 128]`` in the operand's dtype. The fetched
+    block holds the key in its first ``rope`` lanes (what lies beside it
+    is the latent's: overwritten here)."""
+    key = kr_ref[0, rows, :]
+    x = key.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    width = dims.rope
+    while width < _LANES:
+        x = jnp.where(lane < width, x, pltpu.roll(x, width, 1))
+        width *= 2
+    cos_ref, sin_ref = tables
+    return _rotate(x, cos_ref[rows, :], sin_ref[rows, :],
+                   dims.rope).astype(key.dtype)
+
+
+def _rope_parts(q_ref, cols, tables, dims):
+    """The rope parts of a lane block's heads (columns ``cols`` of the
+    step's q), rotated: ``[bq, 128]`` in the operand's dtype."""
+    x = q_ref[0, :, cols]
+    cos_ref, sin_ref = tables
+    return _rotate(x, cos_ref[...], sin_ref[...], dims.rope).astype(x.dtype)
+
+
+def _q_lanes(g, dims):
+    """Lanes of q that a step of ``g`` heads holds: whole groups."""
+    return g // _per_block(dims) * _group_lanes(dims)
+
+
+def _latent_heads(g, dims):
+    """The heads of a step that holds ``g``: for each group of q its
+    rope block's columns and its heads as ``(head of the step, columns
+    of its nope part in q, mask of its rope lanes)``."""
+    per, lanes, nope = _per_block(dims), _group_lanes(dims), dims.nope
+    return [(slice(c * lanes + per * nope, (c + 1) * lanes),
+             [(c * per + i,
+               slice(c * lanes + i * nope, c * lanes + (i + 1) * nope),
+               _head_lanes(_LANES, dims.rope, i)) for i in range(per)])
+            for c in range(g // per)]
+
+
+def _head_cols(h, width):
+    return slice(h * width, (h + 1) * width)
+
+
+def _latent_scores(qn, kn, qr, kr, sm_scale, mask, transposed=False):
+    """Masked, scaled sum of the two contractions in f32: ``[bq, bk]``,
+    or ``[bk, bq]`` for the transposed tile of ``flash_dkv_mla``. ``qr``
+    has the lanes of the block's other heads zeroed."""
+    if transposed:
+        s = _dot(kn, qn, _NT) + _dot(kr, qr, _NT)
+    else:
+        s = _dot(qn, kn, _NT) + _dot(qr, kr, _NT)
+    s = s * sm_scale
+    return s if mask is None else jnp.where(mask, s, NEG_INF)
+
+
+def _fwd_latent_kernel(q_ref, kn_ref, v_ref, kr_ref, cq_ref, sq_ref, ck_ref,
+                       sk_ref, o_ref, lse_ref, acc_scr, m_scr, l_scr, *,
+                       sm_scale, causal, bq, bk, nq, nk, g, dims):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    def tile(mask):
+        kr = _shared_key(kr_ref, (ck_ref, sk_ref), dims)
+        for rope_cols, heads in _latent_heads(g, dims):
+            qr = _rope_parts(q_ref, rope_cols, (cq_ref, sq_ref), dims)
+            for h, nope_cols, keep in heads:
+                s = _latent_scores(q_ref[0, :, nope_cols],
+                                   kn_ref[0, :, _head_cols(h, dims.nope)],
+                                   _only(qr, keep), kr, sm_scale, mask)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+                m_scr[h] = m_new
+                v = v_ref[0, :, _head_cols(h, dims.v)]
+                acc_scr[h] = acc_scr[h] * alpha + _dot(p.astype(v.dtype), v,
+                                                       _NN)
+
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal)
+
+    @pl.when(ki == nk - 1)
+    def _emit():
+        for h in range(g):
+            l = l_scr[h]
+            lse_ref[0, h] = _to_row(m_scr[h] + jnp.log(l))
+            o_ref[0, :, _head_cols(h, dims.v)] = (acc_scr[h] / l).astype(
+                o_ref.dtype)
+
+
+def _dq_latent_kernel(q_ref, kn_ref, v_ref, kr_ref, do_ref, o_ref, lse_ref,
+                      cq_ref, sq_ref, ck_ref, sk_ref, dq_ref, delta_ref,
+                      dq_scr, delta_scr, *, sm_scale, causal, bq, bk, nq, nk,
+                      g, dims):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+        for h in range(g):
+            cols = _head_cols(h, dims.v)
+            delta = jnp.sum(do_ref[0, :, cols].astype(jnp.float32)
+                            * o_ref[0, :, cols].astype(jnp.float32),
+                            axis=1, keepdims=True)
+            delta_scr[h] = delta
+            delta_ref[0, h] = _to_row(delta)
+
+    def tile(mask):
+        kr = _shared_key(kr_ref, (ck_ref, sk_ref), dims)
+        for rope_cols, heads in _latent_heads(g, dims):
+            qr = _rope_parts(q_ref, rope_cols, (cq_ref, sq_ref), dims)
+            dqr = None
+            for h, nope_cols, keep in heads:
+                kn = kn_ref[0, :, _head_cols(h, dims.nope)]
+                s = _latent_scores(q_ref[0, :, nope_cols], kn,
+                                   _only(qr, keep), kr, sm_scale, mask)
+                p = jnp.exp(s - _to_col(lse_ref[0, h]))
+                dp = _dot(do_ref[0, :, _head_cols(h, dims.v)],
+                          v_ref[0, :, _head_cols(h, dims.v)], _NT)
+                ds = (p * (dp - delta_scr[h]) * sm_scale).astype(kn.dtype)
+                dq_scr[:, nope_cols] = dq_scr[:, nope_cols] + _dot(ds, kn,
+                                                                   _NN)
+                dqr = _place(dqr, _dot(ds, kr, _NN), keep)
+            dq_scr[:, rope_cols] = dq_scr[:, rope_cols] + dqr
+
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal)
+
+    @pl.when(ki == nk - 1)
+    def _emit():
+        # the rope parts' gradient is w.r.t. the rotated q: turned back
+        # from the f32 accumulator, before the one rounding of the store
+        for rope_cols, heads in _latent_heads(g, dims):
+            for _, nope_cols, _ in heads:
+                dq_ref[0, :, nope_cols] = dq_scr[:, nope_cols].astype(
+                    dq_ref.dtype)
+            dq_ref[0, :, rope_cols] = _rotate(
+                dq_scr[:, rope_cols], cq_ref[...], sq_ref[...], dims.rope,
+                back=True).astype(dq_ref.dtype)
+
+
+def _dkv_latent_kernel(q_ref, kn_ref, v_ref, kr_ref, do_ref, lse_ref,
+                       delta_ref, cq_ref, sq_ref, ck_ref, sk_ref, dkn_ref,
+                       dv_ref, dkr_ref, dkn_scr, dv_scr, dkr_scr, *, sm_scale,
+                       causal, bq, bk, nq, nk, g, dims, head_steps):
+    # The inner grid dimension walks the head groups (``g`` heads a
+    # step) and under each the q-blocks: dk_nope and dv of a step's
+    # heads add up over the q-blocks and are written when its last one
+    # is done; dk_rope, one for all heads, adds up over every step of
+    # the kv-block.
+    ki, step = pl.program_id(2), pl.program_id(3)
+    qi = step % nq
+
+    @pl.when(step == 0)
+    def _init_shared():
+        dkr_scr[:] = jnp.zeros_like(dkr_scr)
+
+    @pl.when(qi == 0)
+    def _init():
+        dkn_scr[:] = jnp.zeros_like(dkn_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def tile(mask):
+        kr = _shared_key(kr_ref, (ck_ref, sk_ref), dims)
+        for rope_cols, heads in _latent_heads(g, dims):
+            qr = _rope_parts(q_ref, rope_cols, (cq_ref, sq_ref), dims)
+            for h, nope_cols, keep in heads:
+                qn, mine = q_ref[0, :, nope_cols], _only(qr, keep)
+                do = do_ref[0, :, _head_cols(h, dims.v)]
+                s = _latent_scores(qn, kn_ref[0, :, _head_cols(h, dims.nope)],
+                                   mine, kr, sm_scale, mask, transposed=True)
+                p = jnp.exp(s - lse_ref[0, h])                # [bk, bq]
+                cols = _head_cols(h, dims.v)
+                dv_scr[:, cols] = dv_scr[:, cols] + _dot(p.astype(do.dtype),
+                                                         do, _NN)
+                dp = _dot(v_ref[0, :, cols], do, _NT)
+                ds = (p * (dp - delta_ref[0, h]) * sm_scale).astype(qn.dtype)
+                cols = _head_cols(h, dims.nope)
+                dkn_scr[:, cols] = dkn_scr[:, cols] + _dot(ds, qn, _NN)
+                dkr_scr[:] = dkr_scr[:] + _dot(ds, mine, _NN)
+
+    _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
+                        transposed=True)
+
+    @pl.when(qi == nq - 1)
+    def _emit():
+        dkn_ref[0] = dkn_scr[:].astype(dkn_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(step == head_steps * nq - 1)
+    def _emit_shared():
+        # a head's part lies on its own lanes of the block: added up
+        # onto every head's lanes, then turned back as one key
+        total, width = dkr_scr[:], _LANES // 2
+        while width >= dims.rope:
+            total = total + pltpu.roll(total, width, 1)
+            width //= 2
+        dkr_ref[0] = _rotate(total, ck_ref[...], sk_ref[...], dims.rope,
+                             back=True).astype(dkr_ref.dtype)
+
+
+def _latent_static(kernel, s, causal, sm_scale, blocks, dims, **more):
+    bq, bk, g = blocks
+    return functools.partial(kernel, sm_scale=sm_scale, causal=causal, bq=bq,
+                             bk=bk, nq=s // bq, nk=s // bk, g=g, dims=dims,
+                             **more)
+
+
+def _latent_kv_specs(heads, dims, blocks, kv_row, group_of=_group_of):
+    """Specs of k_nope and v (two runs of ``kv``) and of the rotary
+    key's lane block of ``c``, by the row blocks ``kv_row``."""
+    _, bk, g = blocks
+    return [_rows_spec(bk, g * dims.nope, kv_row, 0, group_of),
+            _rows_spec(bk, g * dims.v, kv_row, heads * dims.nope, group_of),
+            pl.BlockSpec((1, bk, _LANES),
+                         lambda b, h, i, j: (b, kv_row(i, j), 0))]
+
+
+def _fwd_latent(q, kv, c, tables, heads, dims, causal, sm_scale, blocks,
+                interpret):
+    bq, bk, g = blocks
+    b, s, _ = q.shape
+    q_width = _q_lanes(g, dims)
+    kv_row = _kv_row(causal, bq, bk)
+    return pl.pallas_call(
+        _latent_static(_fwd_latent_kernel, s, causal, sm_scale, blocks, dims),
+        grid=(b, heads // g, s // bq, s // bk),
+        in_specs=[_rows_spec(bq, q_width, _outer)]
+        + _latent_kv_specs(heads, dims, blocks, kv_row)
+        + _table_specs(_LANES, (bq, _outer), (bk, kv_row)),
+        out_specs=[_rows_spec(bq, g * dims.v, _outer),
+                   _stat_spec(g, bq, _outer)],
+        out_shape=[jax.ShapeDtypeStruct((b, s, heads * dims.v), q.dtype),
+                   jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((g, bq, dims.v), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name='flash_fwd_mla',
+    )(q, kv, kv, c, *tables, *tables)
+
+
+def _dq_latent(q, kv, c, tables, do, o, lse, heads, dims, causal, sm_scale,
+               blocks, interpret):
+    """``(dq, delta)``, as :func:`_dq`."""
+    bq, bk, g = blocks
+    b, s, _ = q.shape
+    q_width = _q_lanes(g, dims)
+    kv_row = _kv_row(causal, bq, bk)
+    q_spec, o_spec = (_rows_spec(bq, q_width, _outer),
+                      _rows_spec(bq, g * dims.v, _outer))
+    row_spec = _stat_spec(g, bq, _outer)
+    return pl.pallas_call(
+        _latent_static(_dq_latent_kernel, s, causal, sm_scale, blocks, dims),
+        grid=(b, heads // g, s // bq, s // bk),
+        in_specs=[q_spec] + _latent_kv_specs(heads, dims, blocks, kv_row)
+        + [o_spec, o_spec, row_spec]
+        + _table_specs(_LANES, (bq, _outer), (bk, kv_row)),
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, q_width), jnp.float32),
+                        pltpu.VMEM((g, bq, 1), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name='flash_dq_mla',
+    )(q, kv, kv, c, do, o, lse, *tables, *tables)
+
+
+def _dkv_latent(q, kv, c, tables, do, lse, delta, heads, dims, causal,
+                sm_scale, blocks, interpret):
+    """``(dkv, dv, dk_rope)``: the array of ``kv``'s shape whose first
+    run (dk_nope) is written, dv ``[b, s, heads * v]`` and the rotary
+    key's gradient on every head's lanes of ``[b, s, 128]``."""
+    bq, bk, g = blocks
+    b, s, _ = q.shape
+    nq = s // bq
+    q_width = _q_lanes(g, dims)
+
+    # (the dead causal tiles come first among a head group's q-blocks,
+    # and ask for the first live one)
+    def q_row(j, i):
+        return jnp.maximum(i % nq, (j * bk) // bq) if causal else i % nq
+
+    def q_of(h, i):
+        return i // nq
+
+    def head_cols(width, start=0):
+        return pl.BlockSpec(
+            (1, bk, g * width),
+            lambda b, h, j, i: (b, j, start // (g * width) + i // nq))
+    row_spec = _stat_spec(g, bq, q_row, q_of)
+    shared = pl.BlockSpec((1, bk, _LANES), lambda b, h, j, i: (b, j, 0))
+    return pl.pallas_call(
+        _latent_static(_dkv_latent_kernel, s, causal, sm_scale, blocks, dims,
+                       head_steps=heads // g),
+        grid=(b, 1, s // bk, heads // g * nq),
+        in_specs=[_rows_spec(bq, q_width, q_row, group_of=q_of),
+                  head_cols(dims.nope), head_cols(dims.v, heads * dims.nope),
+                  shared,
+                  _rows_spec(bq, g * dims.v, q_row, group_of=q_of),
+                  row_spec, row_spec]
+        + _table_specs(_LANES, (bq, q_row), (bk, _outer)),
+        out_specs=[head_cols(dims.nope), head_cols(dims.v), shared],
+        out_shape=[jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+                   jax.ShapeDtypeStruct((b, s, heads * dims.v), kv.dtype),
+                   jax.ShapeDtypeStruct((b, s, _LANES), c.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, g * dims.nope), jnp.float32),
+                        pltpu.VMEM((bk, g * dims.v), jnp.float32),
+                        pltpu.VMEM((bk, _LANES), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+        name='flash_dkv_mla',
+    )(q, kv, kv, c, do, lse, delta, *tables, *tables)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_latent(q, kv, c, tables, heads, dims, causal, sm_scale, plan,
+                  interpret, named):
+    return _fwd_latent(q, kv, c, tables, heads, dims, causal, sm_scale,
+                       plan.fwd, interpret)[0]
+
+
+def _flash_latent_fwd(q, kv, c, tables, heads, dims, causal, sm_scale, plan,
+                      interpret, named):
+    o, lse = _fwd_latent(q, kv, c, tables, heads, dims, causal, sm_scale,
+                         plan.fwd, interpret)
+    if named:
+        o = checkpoint_name(o, CHECKPOINT_NAMES[0])
+        lse = checkpoint_name(lse, CHECKPOINT_NAMES[1])
+    return o, (q, kv, c, tables, o, lse)
+
+
+def _flash_latent_bwd(heads, dims, causal, sm_scale, plan, interpret, named,
+                      res, do):
+    q, kv, c, tables, o, lse = res
+    dq, delta = _dq_latent(q, kv, c, tables, do, o, lse, heads, dims, causal,
+                           sm_scale, plan.dq, interpret)
+    dkv, dv, dkr = _dkv_latent(q, kv, c, tables, do, lse, delta, heads, dims,
+                               causal, sm_scale, plan.dkv, interpret)
+    dkv = jax.lax.dynamic_update_slice_in_dim(dkv, dv, heads * dims.nope,
+                                              axis=2)
+    # (the kernels read the key's columns of c and no other)
+    dc = jnp.pad(dkr[..., :dims.rope],
+                 ((0, 0), (0, 0), (0, c.shape[-1] - dims.rope)))
+    return dq, dkv, dc, None
+
+
+_flash_latent.defvjp(_flash_latent_fwd, _flash_latent_bwd)
+
+
+def flash_attention_latent(q, kv, c, heads, dims, rotary, causal=True,
+                           block_q=None, block_k=None, interpret=None):
+    """Exact attention of ``heads`` heads whose q/k head is ``dims.nope``
+    lanes of its own beside ``dims.rope`` rotary lanes that share ONE
+    key, and whose v head is ``dims.v`` wide (``dims = (nope, rope,
+    v)``; :func:`supports_latent`), in the kernels' own layout (the
+    section's comment above): ``q [b, s, heads * (nope + rope)]`` in
+    :func:`latent_columns`' order, ``kv [b, s, heads * (nope + v)]``
+    (every head's k_nope, then every head's v), ``c [b, s, >= 128]``
+    whose first ``rope`` columns are the rotary key, ``rotary`` the
+    tables of ``rotary_tables(positions, theta, heads, rope)``. Returns
+    ``o [b, s, heads * v]``. The scale is ``(nope + rope) ** -0.5``. The rotary parts of q and the key are rotated on the tile
+    (half-split pairs), their gradients turned back; the cotangents are
+    those of ``q``, ``kv`` and ``c`` (zero beside the key's columns).
+    The three calls are ``flash_fwd_mla``, ``flash_dq_mla``,
+    ``flash_dkv_mla``; ``o`` and ``lse`` are named for a checkpoint
+    policy as :func:`flash_attention_merged`'s. One ``flash.plan`` event
+    a trace, with ``qk_dim``, ``v_dim``, ``rope_dim`` and
+    ``shared_rope_key`` beside the plan."""
+    dims = Latent(*dims)
+    b, s, _ = q.shape
+    shape = (b, heads, s, dims.nope + dims.rope)
+    if not supports_latent(shape, dims) or c.shape[-1] < _LANES \
+            or q.shape[-1] != heads * (dims.nope + dims.rope) \
+            or kv.shape[-1] != heads * (dims.nope + dims.v):
+        raise ValueError('flash_attention_latent: %d heads of %s over q %s, '
+                         'kv %s, c %s is not supported; check '
+                         'supports_latent() first'
+                         % (heads, dims, q.shape, kv.shape, c.shape))
+    tables = tuple(rotary)
+    if [(t.shape, t.dtype) for t in tables] != [((s, _LANES),
+                                                 jnp.float32)] * 2:
+        raise ValueError(
+            'flash_attention_latent: rotary=(cos, sin) must be two f32 [%d, '
+            '%d] (rotary_tables at the rotary part\'s width); got %s'
+            % (s, _LANES, [(t.shape, str(t.dtype)) for t in tables]))
+    sm_scale = float((dims.nope + dims.rope) ** -0.5)
+    per = _per_block(dims)
+    plan = Plan(**{
+        kernel: _latent_blocks(heads, s, targets, block_q, block_k, per)
+        for kernel, targets in _block_targets(s, causal).items()})
+    if interpret is None:
+        interpret = _interpret_default()
+    telemetry.get().loop_event(
+        'flash.plan', seq=s, head_dim=dims.nope + dims.rope,
+        causal=bool(causal), fold_scale=False, window=None, layout='bsd',
+        lane_block=_LANES, heads_per_lane_block=per, rotary=True,
+        kv_heads=heads, qk_dim=dims.nope + dims.rope, v_dim=dims.v,
+        rope_dim=dims.rope, shared_rope_key=True,
+        **_plan_tags(plan, s, causal))
+    return _flash_latent(q, kv, c, tables, heads, dims, bool(causal),
+                         sm_scale, plan, interpret, True)
+
+
+def _latent_blocks(heads, seq, targets, block_q, block_k, per):
+    sizes = [seq if not asked and seq <= target
+             else _pick_block(seq, asked or target)
+             for asked, target in zip((block_q, block_k), targets)]
+    return Blocks(*sizes, _heads_per_step(heads, *sizes, per))

@@ -7,6 +7,7 @@ switches to ring attention (parallel/ring_attention.py). The reference
 has neither TP nor SP (SURVEY.md §2.3) — these are the TPU-native
 extension axes of the strategy space.
 """
+import functools
 import math
 
 import jax
@@ -16,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
 from autodist_tpu.kernels import flash_attention as fa
-from autodist_tpu.models.core import Dense, Module, constrain
+from autodist_tpu.models.core import Dense, Module, RMSNorm, constrain
 from autodist_tpu.parallel.axes import (active_manual_axes, ctx_option,
                                         current_mesh, live_mesh_axis,
                                         manual_axis, shard_map,
@@ -299,3 +300,138 @@ class MultiHeadAttention(Module):
             return None
         local = (shape[0] // dp, shape[1] // tp, shape[2], shape[3])
         return local if self._kernel_preferred(local) else None
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention as DeepSeek-V2 publishes it (no q
+    down-projection), on the training path: [batch, seq, embed] in/out.
+
+    ``q = x W_q`` in ``num_heads`` heads of ``nope_dim + rope_dim``; ``c
+    = x W_kva`` is the rotary key (``rope_dim``: ONE for all heads) and
+    the latent (``rank``); ``RMSNorm(latent) W_kvb`` gives every head
+    its ``k_nope [nope_dim]`` and ``v [v_dim]``. Head n: ``softmax((
+    q_nope_n k_nope_n^T + rot(q_rope_n) rot(k_rope)^T) / sqrt(nope_dim
+    + rope_dim)) v_n``, then ``W_o [num_heads * v_dim, dim]``.
+
+    The columns of the three projections are laid out for the flash
+    kernels, which read their outputs where they lie
+    (``fa.flash_attention_latent``): ``W_q``'s in ``fa.latent_columns``'
+    order (for every lane block's heads their nope parts, then their
+    rope parts), ``W_kva``'s the rotary key first and the latent after
+    it, ``W_kvb``'s every head's k_nope and then every head's v. Rotary
+    pairs are the two halves of the rope part (rotate-half); a published
+    checkpoint's interleaved pairs and head-major columns are a fixed
+    permutation of these (``benchmark/models/kanana2.py:
+    to_reference_params``). Below the kernels' crossover (short
+    sequences, the CPU tests' tiny shapes) and under a mesh that shards
+    the heads the same equations run under XLA, scores ``[b, h, s, s]``
+    and all. The sequence-parallel paths take no latent attention and
+    raise."""
+
+    def __init__(self, dim, num_heads, rank, nope_dim, rope_dim, v_dim,
+                 causal=True, dtype=jnp.float32, rope_theta=10000.0,
+                 norm_eps=1e-6):
+        self.dim, self.num_heads = dim, num_heads
+        self.dims = fa.Latent(nope_dim, rope_dim, v_dim)
+        self.head_dim, self.v_dim = nope_dim + rope_dim, v_dim
+        self.causal, self.dtype = causal, dtype
+        self.rope = rope_theta
+        h = num_heads
+        self.wq = Dense(dim, h * self.head_dim, 'embed', 'heads',
+                        use_bias=False, dtype=dtype)
+        self.wkv_a = Dense(dim, rope_dim + rank, 'embed', None,
+                           use_bias=False, dtype=dtype)
+        self.kv_norm = RMSNorm(rank, axis_name=None, eps=norm_eps,
+                               dtype=dtype)
+        self.wkv_b = Dense(rank, h * (nope_dim + v_dim), None, 'heads',
+                           use_bias=False, dtype=dtype)
+        self.wo = Dense(h * v_dim, dim, 'heads', 'embed', use_bias=False,
+                        dtype=dtype)
+
+    def param_defs(self):
+        return {'q': self.wq, 'kv_a': self.wkv_a, 'kv_norm': self.kv_norm,
+                'kv_b': self.wkv_b, 'out': self.wo}
+
+    def apply(self, params, x, tables=None):
+        b, s, _ = x.shape
+        if manual_axis(AXIS_SEQUENCE) is not None:
+            raise ValueError('latent attention under sequence parallelism: '
+                             'ring_attention and ulysses_attention take one '
+                             'head width; use sp=1')
+        nope, rope, _ = self.dims
+        with jax.named_scope('mla_latent'):
+            q = self.wq.apply(params['q'], x)
+            c = self.wkv_a.apply(params['kv_a'], x)   # [k_rope | latent]
+            kv = self.wkv_b.apply(params['kv_b'], self.kv_norm.apply(
+                params['kv_norm'], c[..., rope:]))
+        shape = (b, self.num_heads, s, self.head_dim)
+        if self.kernel_shape(shape) is not None:
+            if tables is None:
+                tables = self.position_tables(shape)
+            o = self._kernel_attention(q, kv, c, tables)
+        else:
+            o = self._xla_attention(q, kv, c[..., :rope])
+        return self.wo.apply(params['out'], o)
+
+    def _kernel_attention(self, q, kv, c, tables):
+        """The flash kernels on the projections' outputs; under a
+        data-parallel mesh on each device's batch, in a manual region
+        (as ``MultiHeadAttention._kernel_attention``)."""
+        def attend(q, kv, c, tables):
+            return fa.flash_attention_latent(q, kv, c, self.num_heads,
+                                             self.dims, tables,
+                                             causal=self.causal)
+        mesh = None if unsharded_execution() else current_mesh()
+        if mesh is None:
+            return attend(q, kv, c, tables)
+        spec = P(AXIS_DATA, None, None)
+        return shard_map(attend, mesh, (spec, spec, spec, (P(), P())),
+                         spec)(q, kv, c, tables)
+
+    def _xla_attention(self, q, kv, k_rope):
+        """The same equations under XLA, from the kernels' layout."""
+        b, s, _ = q.shape
+        h, (nope, rope, v_dim) = self.num_heads, self.dims
+        per = fa.latent_group(h, self.dims)
+        q = q.reshape(b, s, h // per, per * (nope + rope))
+        q_nope = q[..., :per * nope].reshape(b, s, h, nope)
+        q_rope = q[..., per * nope:].reshape(b, s, h, rope)
+        k_nope = kv[..., :h * nope].reshape(b, s, h, nope)
+        v = kv[..., h * nope:].reshape(b, s, h, v_dim)
+        pos = jnp.arange(s)
+        by_head = functools.partial(jnp.transpose, axes=(0, 2, 1, 3))
+        q_rope = rotary(by_head(q_rope), pos, self.rope)
+        k_rope = rotary(k_rope[:, None], pos, self.rope)
+        # (the sum of the two contractions as one over nope + rope lanes,
+        # the one key repeated to the heads: short sequences, tiny models)
+        q = jnp.concatenate([by_head(q_nope), q_rope], -1)
+        k = jnp.concatenate(
+            [by_head(k_nope), jnp.broadcast_to(k_rope, q_rope.shape)], -1)
+        o = local_flash_attention(q, k, by_head(v), causal=self.causal)
+        return by_head(o).reshape(b, s, h * v_dim)
+
+    def position_tables(self, shape):
+        """The rotary positions' ``(cos, sin)`` as the kernels take them
+        for the rope part (``fa.rotary_tables`` at ``rope_dim``), or
+        None where attention takes the XLA path."""
+        if self.kernel_shape(shape) is None:
+            return None
+        with jax.named_scope('rotary'):
+            return fa.rotary_tables(jnp.arange(shape[2]), self.rope,
+                                    self.num_heads, self.dims.rope)
+
+    def kernel_shape(self, shape):
+        """The per-device ``[b, h, s, nope + rope]`` the flash kernels
+        run on, or None where attention runs under XLA: short
+        sequences, or a mesh that shards anything but the batch."""
+        if manual_axis(AXIS_SEQUENCE) is not None:
+            return None
+        if not unsharded_execution():
+            mesh = current_mesh()
+            dp = mesh.shape.get(AXIS_DATA, 1)
+            others = [a for a, n in mesh.shape.items()
+                      if n > 1 and a != AXIS_DATA]
+            if active_manual_axes() or others or shape[0] % dp:
+                return None
+            shape = (shape[0] // dp,) + tuple(shape[1:])
+        return shape if fa.preferred_latent(shape, self.dims) else None

@@ -77,6 +77,16 @@ the loop ring as the point event ``moe.plan``
 An auxiliary load-balancing loss (Switch eq. 4, over all the router's
 experts) is returned beside the output, and the step's load as
 ``stats``: the rows held here and the largest load of a held expert.
+
+The router's variants (``scoring``, ``select_bias``, ``scale``: DeepSeek-V3's
+``sigmoid`` / ``noaux_tc`` / ``routed_scaling_factor``): sigmoid scores
+over all the experts in f32, the ``top_k`` experts with the largest
+``score + bias`` (the bias SELECTS only, takes no gradient and is not
+updated here), weights ``scale x score / (sum of the chosen scores +
+1e-20)``; the Switch loss does not apply to such scores and is zero.
+``shared`` adds an always-on expert of that width for every token (scope
+``moe_shared``), whole on every holder of a share: the shares' routed
+parts and ONE shared expert add up to the layer.
 """
 import functools
 
@@ -88,7 +98,8 @@ from jax.sharding import PartitionSpec as P
 from autodist_tpu import telemetry
 from autodist_tpu.kernels import grouped_matmul as gm
 from autodist_tpu.kernels import moe_combine as mc
-from autodist_tpu.models.core import Dense, Module, ParamDef
+from autodist_tpu.models.core import (Dense, GatedMlp, Mlp, Module,
+                                      ParamDef)
 from autodist_tpu.parallel.axes import (AXIS_DATA, active_manual_axes,
                                         current_mesh, live_mesh_axis,
                                         shard_map, unsharded_execution)
@@ -117,8 +128,19 @@ class MoeMlp(Module):
     ``apply`` returns ``(y, aux, stats)``."""
 
     def __init__(self, dim, hidden, n_experts, top_k=2, held=None,
-                 dtype=jnp.float32, act=jax.nn.gelu, gated=False):
+                 dtype=jnp.float32, act=jax.nn.gelu, gated=False,
+                 scoring='softmax', select_bias=False, scale=1.0, shared=0):
+        if scoring not in ('softmax', 'sigmoid'):
+            raise ValueError("scoring must be 'softmax' or 'sigmoid', not %r"
+                             % (scoring,))
         self.dim, self.hidden = dim, hidden
+        self.scoring, self.select_bias, self.scale = (scoring, select_bias,
+                                                      float(scale))
+        self.shared = None
+        if shared:
+            self.shared = GatedMlp(dim, shared, dtype=dtype, act=act) \
+                if gated else Mlp(dim, shared, dtype=dtype, act=act,
+                                  use_bias=False)
         self.n_experts = n_experts
         self.top_k = top_k
         self.first, self.held = held or (0, n_experts)
@@ -138,7 +160,7 @@ class MoeMlp(Module):
         # (index 1) lie side by side, as GatedMlp's
         up = (self.held, self.dim) + ((2,) if self.gated else ()) \
             + (self.hidden,)
-        return {
+        defs = {
             'router': self.router,
             'up': ParamDef(up, ('expert', 'embed')
                            + ((None,) if self.gated else ()) + ('mlp',),
@@ -147,25 +169,35 @@ class MoeMlp(Module):
                              ('expert', 'mlp', 'embed'), 'normal',
                              self.hidden ** -0.5),
         }
+        if self.select_bias:
+            defs['select_bias'] = ParamDef((self.n_experts,), (None,),
+                                           'zeros')
+        if self.shared is not None:
+            defs['shared'] = self.shared
+        return defs
 
     def apply(self, params, x):
         mesh = None if unsharded_execution() else current_mesh()
         if mesh is None:
             y, f, p, sizes = self._held_part(
                 x, params['router'], params['up'], params['down'],
-                self.first)
+                self.first, params.get('select_bias'))
             total, largest = jnp.sum(sizes), jnp.max(sizes)
         else:
             y, f, p, total, largest = self._on_shards(mesh, params, x)
+        if self.shared is not None:
+            with jax.named_scope('moe_shared'):
+                y = y + self.shared.apply(params['shared'], x)
         # load-balance aux loss (Switch eq. 4): e * sum_e f_e * P_e, f
         # the share of the tokens whose first choice is e and P the mean
-        # of its probability
+        # of its probability; not a loss of sigmoid scores
         n = x.shape[0] * x.shape[1]
-        aux = self.n_experts * jnp.sum(f * p) / (n * n)
+        aux = self.n_experts * jnp.sum(f * p) / (n * n) \
+            if self.scoring == 'softmax' else jnp.zeros((), jnp.float32)
         stats = jnp.stack([total, largest]).astype(jnp.float32)
         return y, aux, stats
 
-    def _held_part(self, x, router, up, down, first):
+    def _held_part(self, x, router, up, down, first, bias=None):
         """What the experts ``first ..`` of ``up`` / ``down`` (all their
         hidden units or a run of them) add for the tokens of ``x [b, s,
         d]``, on device-local data: ``(y, first choices counted by
@@ -175,9 +207,11 @@ class MoeMlp(Module):
         held, hidden = down.shape[0], down.shape[1]
         tokens = x.reshape(b * s, d)
         with jax.named_scope('moe_route'):
-            probs, weights, idx = self._route(router, tokens)
+            probs, weights, idx = self._route(router, tokens, bias)
             order = _order(idx - first, weights, held)
-        _note_plan(order, d, self.dtype)
+        _note_plan(order, d, self.dtype, scoring=self.scoring,
+                   bias=bias is not None, scale=self.scale,
+                   shared=self.shared.hidden if self.shared else 0)
         y = _experts(functools.partial(_hidden, self.act, self.gated, hidden),
                      tokens.astype(self.dtype), up.reshape(held, d, -1), down,
                      order['token'], order['weight'], order['tile_group'],
@@ -214,11 +248,12 @@ class MoeMlp(Module):
                                                  self.hidden, dict(mesh.shape)))
         over_experts = tuple(a for a in (by_expert, by_hidden) if a)
 
-        def part(x, router, up, down):
+        def part(x, router, up, down, *bias):
             first = self.first
             if by_expert:
                 first = first + jax.lax.axis_index(by_expert) * down.shape[0]
-            y, f, p, sizes = self._held_part(x, router, up, down, first)
+            y, f, p, sizes = self._held_part(x, router, up, down, first,
+                                             *bias)
             if over_experts:
                 y = jax.lax.psum(y, over_experts)
             if data:
@@ -231,23 +266,38 @@ class MoeMlp(Module):
 
         tokens = P(data, None, None)
         gate = (None,) if self.gated else ()
+        bias = (params['select_bias'],) if self.select_bias else ()
         return shard_map(
             part, None if manual else mesh,
             (tokens, P(), P(by_expert, None, *gate, by_hidden),
-             P(by_expert, by_hidden, None)),
+             P(by_expert, by_hidden, None)) + (P(),) * len(bias),
             (tokens, P(), P(), P(), P()),
             axis_names=set(live) if manual else None)(
-                x, params['router'], params['up'], params['down'])
+                x, params['router'], params['up'], params['down'], *bias)
 
-    def _route(self, router, tokens):
+    def _route(self, router, tokens, bias=None):
         """``(probs [t, e], weights [t, k], idx [t, k])``: the softmax
         over all the experts in f32, and of its ``top_k`` largest the
-        weights, renormalised, and the experts."""
+        weights, renormalised, and the experts. With sigmoid scoring the
+        scores, the ``top_k`` experts by ``score + bias`` and their
+        weights from the scores alone, ``scale x score / sum``."""
         logits = self.router.apply(router, tokens.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        vals, idx = jax.lax.top_k(probs, self.top_k)
-        return probs, vals / jnp.maximum(
-            jnp.sum(vals, -1, keepdims=True), 1e-9), idx
+        if self.scoring == 'softmax':
+            probs = jax.nn.softmax(logits, axis=-1)
+            vals, idx = jax.lax.top_k(probs, self.top_k)
+            return probs, vals / jnp.maximum(
+                jnp.sum(vals, -1, keepdims=True), 1e-9), idx
+        probs = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            probs if bias is None else probs + jax.lax.stop_gradient(
+                bias.astype(jnp.float32)), self.top_k)
+        # the chosen experts' own scores, by a mask and not by an index
+        # (XLA moves single numbers by an index at 4.5 ns apiece)
+        vals = jnp.sum(jnp.where(
+            idx[:, :, None] == jnp.arange(self.n_experts)[None, None, :],
+            probs[:, None, :], 0.0), axis=-1)
+        return probs, self.scale * vals / (
+            jnp.sum(vals, -1, keepdims=True) + 1e-20), idx
 
 
 def _hidden(act, gated, f, u):
@@ -510,13 +560,14 @@ def _experts_bwd(hidden, res, dout):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def _note_plan(order, dim, dtype):
+def _note_plan(order, dim, dtype, **router):
     """One ``moe.plan`` point event a trace of the layer: how its rows
     move between the tokens' order and the experts' (``rows`` and
     ``buffer_bytes``: of the buffer a pass of the combine holds) and how
     that order is made and kept (``order_scatters``: single numbers a
     trace of the layer moves by an index, by row; ``order_saved_bytes``:
-    of what ``CHECKPOINT_NAMES`` name)."""
+    of what ``CHECKPOINT_NAMES`` name); ``router``: the router's variant
+    (``scoring``, ``bias``, ``scale``, ``shared``)."""
     chunks = pass_chunks(order['token'].shape[0])
     held = chunks * CHUNK_TILES * gm.TILE_ROWS
     kept = [order[name[len('moe_'):]] for name in CHECKPOINT_NAMES]
@@ -526,4 +577,5 @@ def _note_plan(order, dim, dtype):
         combine='pallas', gather='xla',
         buffer_bytes=held * dim * jnp.dtype(dtype).itemsize,
         order='sort', order_scatters=0,
-        order_saved_bytes=sum(x.size * x.dtype.itemsize for x in kept))
+        order_saved_bytes=sum(x.size * x.dtype.itemsize for x in kept),
+        **router)

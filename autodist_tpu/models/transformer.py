@@ -16,7 +16,8 @@ from jax.ad_checkpoint import checkpoint_name
 from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_PIPELINE, AXIS_SEQUENCE
 from autodist_tpu.kernels import flash_attention as fa
-from autodist_tpu.models.attention import MultiHeadAttention
+from autodist_tpu.models.attention import (LatentAttention,
+                                           MultiHeadAttention)
 from autodist_tpu.models.core import (Dense, Embedding, GatedMlp, LayerNorm,
                                       Mlp, Module, ParamDef, RMSNorm,
                                       activation, constrain, record_counter)
@@ -112,6 +113,27 @@ class TransformerConfig:
     decoder_bias: bool = False
     embed_init_scale: float = 0.02   # std of an embedding row's elements
     #                              as drawn at init
+    # -- latent attention (DeepSeek-V2's MLA, no q down-projection):
+    # with `latent_rank` every layer's attention is
+    # models/attention.LatentAttention, a q/k head of qk_nope_dim lanes
+    # of its own + qk_rope_dim rotary lanes whose key all heads share,
+    # a v head of v_head_dim; `head_dim`, `n_kv_heads` and `window` are
+    # then not used
+    latent_rank: object = None   # the kv latent's width
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    # -- MLPs of two kinds in one stack, and the router's variants
+    dense_lead: int = 0          # with moe_experts: the first layers
+    #                              whose MLP is dense, of dense_mlp_dim
+    dense_mlp_dim: object = None   # None: mlp_dim
+    moe_scoring: str = 'softmax'   # | 'sigmoid': scores of their own,
+    #                              the experts chosen by score + a
+    #                              selection bias (a parameter without a
+    #                              gradient), weighed by the score alone
+    moe_scale: float = 1.0       # factor on the routed experts' weights
+    moe_shared_dim: int = 0      # >0: an always-on expert of this width
+    #                              beside the routed ones
 
     def __post_init__(self):
         if self.positions not in ('learned', 'rotary'):
@@ -130,6 +152,16 @@ class TransformerConfig:
         if not 0 <= self.global_at < self.global_every:
             raise ValueError('global_at=%d is no place in a period of %d '
                              'layers' % (self.global_at, self.global_every))
+        if self.latent_rank and (self.positions != 'rotary'
+                                 or self.window is not None):
+            raise ValueError('latent attention takes rotary positions and '
+                             'no window')
+        if self.dense_lead and not (self.moe_experts and 0 < self.dense_lead
+                                    < self.n_layers):
+            raise ValueError('dense_lead=%d: the dense layers lead a stack '
+                             'of expert layers (moe_experts > 0, fewer than '
+                             'n_layers=%d)' % (self.dense_lead,
+                                               self.n_layers))
 
     def layer_kinds(self):
         """'global' or 'window' for each layer."""
@@ -210,7 +242,7 @@ class Block(Module):
     loss contribution (0.0 for dense blocks); with ``stats`` ``(x, (aux,
     stats))``, the expert layer's load beside it (``MoeMlp.apply``)."""
 
-    def __init__(self, cfg, kind='global', attn_norm=True):
+    def __init__(self, cfg, kind='global', attn_norm=True, dense=False):
         self.cfg = cfg
         windowed = kind == 'window'
         theta = None
@@ -218,20 +250,34 @@ class Block(Module):
             theta = cfg.window_rope_theta if windowed and \
                 cfg.window_rope_theta is not None else cfg.rope_theta
         self.ln1 = _norm(cfg) if attn_norm else None
-        self.attn = MultiHeadAttention(
-            cfg.dim, cfg.n_heads, head_dim=cfg.head_dim, causal=cfg.causal,
-            dtype=cfg.dtype, rope_theta=theta,
-            window=cfg.window if windowed else None,
-            num_kv_heads=cfg.n_kv_heads,
-            rope_yarn=None if windowed else cfg.rope_yarn)
+        if cfg.latent_rank:
+            self.attn = LatentAttention(
+                cfg.dim, cfg.n_heads, cfg.latent_rank, cfg.qk_nope_dim,
+                cfg.qk_rope_dim, cfg.v_head_dim, causal=cfg.causal,
+                dtype=cfg.dtype, rope_theta=theta, norm_eps=cfg.norm_eps)
+        else:
+            self.attn = MultiHeadAttention(
+                cfg.dim, cfg.n_heads, head_dim=cfg.head_dim,
+                causal=cfg.causal, dtype=cfg.dtype, rope_theta=theta,
+                window=cfg.window if windowed else None,
+                num_kv_heads=cfg.n_kv_heads,
+                rope_yarn=None if windowed else cfg.rope_yarn)
         self.ln2 = _norm(cfg)
         hidden = cfg.mlp_dim or cfg.dim * cfg.mlp_ratio
-        if cfg.moe_experts:
+        # (a leading dense layer of a stack of expert layers: `dense`)
+        self.sparse = bool(cfg.moe_experts) and not dense
+        if dense:
+            hidden = cfg.dense_mlp_dim or hidden
+        if self.sparse:
             from autodist_tpu.models.moe import MoeMlp
             self.mlp = MoeMlp(cfg.dim, hidden,
                               cfg.moe_experts, top_k=cfg.moe_top_k,
                               held=cfg.held_experts(), dtype=cfg.dtype,
-                              act=_act(cfg), gated=cfg.gated_mlp)
+                              act=_act(cfg), gated=cfg.gated_mlp,
+                              scoring=cfg.moe_scoring,
+                              select_bias=cfg.moe_scoring == 'sigmoid',
+                              scale=cfg.moe_scale,
+                              shared=cfg.moe_shared_dim)
         elif cfg.gated_mlp:
             self.mlp = GatedMlp(cfg.dim, hidden, dtype=cfg.dtype,
                                 act=_act(cfg), use_bias=cfg.mlp_bias)
@@ -258,10 +304,12 @@ class Block(Module):
             h = self.mlp.apply(params['mlp'],
                                self.ln2.apply(params['ln2'], x))
             aux = jnp.zeros((), jnp.float32)
-            if self.cfg.moe_experts:
+            if self.sparse:
                 h, aux, load = h
-                if stats:
-                    aux = (aux, load)
+            elif stats:
+                load = jnp.zeros((2,), jnp.float32)
+            if stats:
+                aux = (aux, load)
             x = x + h
         return constrain(x, ('batch', 'seq', 'embed')), aux
 
@@ -311,7 +359,8 @@ class TransformerLM(Module):
         kinds = cfg.layer_kinds()
         # the unrolled layers' blocks, by depth; the scanned ones by kind
         self._lead_blocks = [
-            Block(cfg, kinds[i], attn_norm=not (cfg.embed_norm and i == 0))
+            Block(cfg, kinds[i], attn_norm=not (cfg.embed_norm and i == 0),
+                  dense=i < cfg.dense_lead)
             for i in range(cfg.n_layers if not cfg.scan_layers
                            else self._lead)]
         self._kind_blocks = {kind: Block(cfg, kind)
@@ -332,6 +381,10 @@ class TransformerLM(Module):
         lead = cfg.n_layers % size
         if cfg.embed_norm and lead == 0:
             lead = min(size, cfg.n_layers)
+        # leading dense layers of a stack of expert layers run unrolled
+        # too, and whole periods with them
+        while lead < cfg.dense_lead:
+            lead += size
         return lead, tuple(kinds[lead:lead + size]), \
             (cfg.n_layers - lead) // size
 
@@ -549,7 +602,8 @@ class TransformerLM(Module):
         if active_manual_axes():
             return
         cfg = self.cfg
-        rows, largest = load[0] / cfg.n_layers, load[1] / cfg.n_layers
+        layers = cfg.n_layers - cfg.dense_lead      # the expert layers
+        rows, largest = load[0] / layers, load[1] / layers
         record_counter('moe_rows_here', rows)
         record_counter('moe_load_max', largest)
         record_counter('moe_load_mean', rows / cfg.held_experts()[1])
@@ -607,7 +661,10 @@ class TransformerLM(Module):
             remainder=self._lead, pattern='/'.join(self._period),
             scanned=bool(self.cfg.scan_layers),
             global_layers=kinds.count('global'),
-            window_layers=kinds.count('window'))
+            window_layers=kinds.count('window'),
+            dense_lead=self.cfg.dense_lead,
+            expert_layers=len(kinds) - self.cfg.dense_lead
+            if self.cfg.moe_experts else 0)
 
     def _note_remat(self, x):
         """One ``transformer.remat`` point event a trace under
@@ -630,8 +687,9 @@ class TransformerLM(Module):
             blocks = [self.block] * cfg.n_layers
         shapes = [block.attn.kernel_shape(
             (b, cfg.n_heads, s, block.attn.head_dim)) for block in blocks]
-        kept = [fa.saved_bytes(shape, cfg.dtype) for shape in shapes
-                if shape is not None]
+        kept = [fa.saved_bytes(shape, cfg.dtype, cfg.latent_rank
+                               and cfg.v_head_dim)
+                for shape in shapes if shape is not None]
         telemetry.get().loop_event(
             'transformer.remat', policy='save_only_these_names',
             saved=list(self._saved_names()), layers=len(kept),

@@ -1,0 +1,16 @@
+"""Share of its roofline the latent-attention forward call
+(``flash_fwd_mla``: QK^T at 192 lanes a head, PV at 128, over the causal
+half of the score square) reaches: the larger of its FLOPs over peak
+FLOP/s and its bytes over peak bytes/s, from shapes
+(``mla_kinds.call_cost``), over its time in the trace."""
+from benchmark import mla_kinds
+
+LAYER = 'kernels'
+UNIT = '%'
+BETTER = 'higher'
+SOURCE = 'device_trace'
+MOVES = 'tokens_per_s_per_chip'
+
+
+def reduce(trace, run):
+    return mla_kinds.roofline_pct(trace, run, 'flash_fwd_mla')

@@ -105,7 +105,7 @@ def test_scan_over_periods_equals_the_unrolled_stack(loss_chunk):
     assert events[0] == dict(
         n_layers=7, period=3, periods=2, remainder=1,
         pattern='window/window/global', scanned=True, global_layers=3,
-        window_layers=4)
+        window_layers=4, dense_lead=0, expert_layers=0)
 
 
 def test_dp2_tp2_equals_one_device():
@@ -195,6 +195,12 @@ def test_config_refuses_what_it_cannot_mean():
         == ['global', 'window']
     with pytest.raises(ValueError, match="'learned' or 'rotary'"):
         TransformerConfig.tiny(positions='alibi')
+    with pytest.raises(ValueError, match='rotary positions and no window'):
+        TransformerConfig.tiny(latent_rank=16)
+    with pytest.raises(ValueError, match='lead a stack of expert layers'):
+        TransformerConfig.tiny(dense_lead=1)
+    with pytest.raises(ValueError, match='lead a stack of expert layers'):
+        TransformerConfig.tiny(dense_lead=2, moe_experts=4)
 
 
 @pytest.mark.parametrize('seq,window', [(64, (8, 8)), (640, (8, 24)),
@@ -379,3 +385,115 @@ def test_a_block_off_the_kernel_is_the_checkpoint_without_a_policy(
         jax.eval_shape(other.loss, jax.eval_shape(
             other.init, jax.random.PRNGKey(0)), batch(seq=64))
     assert [e['layers'] for e in remat_events(t_before)] == [0]
+
+
+# -- a leading dense layer, then expert layers; latent attention -----------
+
+def latent_stack(**kw):
+    """kanana-2's structure at a size the CPU runs in a second: latent
+    attention (4 heads of 8 + 4 on one rotary key, v heads of 6, a latent
+    of 16), a dense layer of 48 that leads, then expert layers (2 of 4,
+    sigmoid scores with a selection bias, a scale, a shared expert)."""
+    d = dict(vocab=256, dim=32, n_layers=4, n_heads=4, max_len=64,
+             causal=True, tied_embeddings=False, dtype=jnp.float32,
+             remat=True, positions='rotary', rope_theta=1e6, latent_rank=16,
+             qk_nope_dim=8, qk_rope_dim=4, v_head_dim=6, mlp_dim=16,
+             gated_mlp=True, gelu='silu', norm='rms', mlp_bias=False,
+             moe_experts=4, moe_top_k=2, moe_aux_coef=0.0, dense_lead=1,
+             dense_mlp_dim=48, moe_scoring='sigmoid', moe_scale=2.448,
+             moe_shared_dim=24)
+    d.update(kw)
+    return TransformerConfig(**d)
+
+
+@pytest.mark.parametrize('n_layers,dense_lead', [(4, 1), (5, 2)])
+def test_dense_layers_lead_unrolled_and_the_expert_layers_scan(n_layers,
+                                                               dense_lead):
+    cfg = latent_stack(n_layers=n_layers, dense_lead=dense_lead)
+    scanned = TransformerLM(cfg)
+    assert scanned._layers() == (dense_lead, ('global',),
+                                 n_layers - dense_lead) and scanned.patterned
+    params = scanned.init(jax.random.PRNGKey(0))
+    for i in range(dense_lead):
+        mlp = params['block_%03d' % i]['mlp']
+        assert mlp['up']['kernel'].shape == (32, 2, 48) and 'router' not in mlp
+    stack = params['blocks']['global']
+    assert stack['mlp']['up'].shape == (n_layers - dense_lead, 4, 32, 2, 16)
+    assert stack['mlp']['select_bias'].shape == (n_layers - dense_lead, 4)
+    assert stack['mlp']['shared']['up']['kernel'].shape[1:] == (32, 2, 24)
+    assert sorted(stack['attn']) == ['kv_a', 'kv_b', 'kv_norm', 'out', 'q']
+    assert stack['attn']['kv_a']['kernel'].shape[1:] == (32, 4 + 16)
+    # the bias selects: moved off zero, it takes no gradient
+    stack['mlp']['select_bias'] = 0.3 * jax.random.normal(
+        jax.random.PRNGKey(1), stack['mlp']['select_bias'].shape)
+    plain = TransformerLM(latent_stack(n_layers=n_layers,
+                                       dense_lead=dense_lead,
+                                       scan_layers=False, remat=False))
+    t_before = time.perf_counter()
+    got, got_g = jax.jit(jax.value_and_grad(scanned.loss))(params,
+                                                           batch(seq=32))
+    want, want_g = jax.jit(jax.value_and_grad(plain.loss))(
+        unrolled_from_scanned(scanned, params), batch(seq=32))
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    assert not np.any(np.asarray(
+        got_g['blocks']['global']['mlp']['select_bias']))
+    got_g = unrolled_from_scanned(scanned, got_g)
+    assert jax.tree.structure(got_g) == jax.tree.structure(want_g)
+    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-6, rtol=1e-4)
+    events = [r['tags'] for r in telemetry.get().loop_records()
+              if r['t0'] >= t_before and r['name'] == 'transformer.layers'
+              and r['tags']['scanned']]
+    assert events[0] == dict(
+        n_layers=n_layers, period=1, periods=n_layers - dense_lead,
+        remainder=dense_lead, pattern='global', scanned=True,
+        global_layers=n_layers, window_layers=0, dense_lead=dense_lead,
+        expert_layers=n_layers - dense_lead)
+    plans = [r['tags'] for r in telemetry.get().loop_records()
+             if r['t0'] >= t_before and r['name'] == 'moe.plan']
+    assert plans and all(
+        (p['scoring'], p['bias'], p['scale'], p['shared'])
+        == ('sigmoid', True, 2.448, 24) for p in plans)
+
+
+def test_the_step_counters_count_the_expert_layers_only():
+    """With every expert held each pair is a row here: ``moe_rows_here``,
+    the mean over the EXPERT layers, is ``tokens x top_k`` whatever the
+    dense layers before them."""
+    trainer = Trainer(TransformerLM(latent_stack(n_layers=3)),
+                      optax.sgd(0.1))
+    _, metrics = trainer.step(trainer.init(jax.random.PRNGKey(0)),
+                              batch(n=8, seq=32))
+    assert float(metrics['moe_rows_here']) == 8 * 32 * 2
+    assert float(metrics['moe_load_mean']) == 8 * 32 * 2 / 4
+    assert float(metrics['moe_load_max']) >= 8 * 32 * 2 / 4
+
+
+def test_latent_attention_through_the_kernels_is_the_xla_path(monkeypatch):
+    """``LatentAttention`` past the kernels' crossover (interpret mode)
+    against the same module under XLA, output and every gradient, and
+    the scopes it leaves: ``mla_latent`` holds the three projections."""
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.models.attention import LatentAttention
+    attn = LatentAttention(64, 2, 64, 128, 64, 128, dtype=jnp.float32,
+                           rope_theta=1e6)
+    params = attn.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 512, 64))
+    shape = (1, 2, 512, 192)
+    assert attn.kernel_shape(shape) == shape and attn.v_dim == 128
+
+    def run(params, x):
+        return jnp.sum(jnp.sin(attn.apply(params, x)))
+    with jax.default_matmul_precision('highest'):
+        got = jax.value_and_grad(run, (0, 1))(params, x)
+        text = jax.jit(jax.grad(run)).lower(params, x).as_text(
+            debug_info=True)
+        monkeypatch.setattr(fa, 'MIN_KERNEL_SEQ', 10 ** 9)
+        assert attn.kernel_shape(shape) is None
+        assert attn.position_tables(shape) is None
+        want = jax.value_and_grad(run, (0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+    assert 'flash_dkv_mla' in text and 'mla_latent' in text

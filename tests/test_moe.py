@@ -243,7 +243,8 @@ def test_the_layer_leaves_its_plan_in_the_ring():
         combine='pallas', gather='xla', buffer_bytes=rows * 32 * 4,
         order='sort', order_scatters=0,
         order_saved_bytes=4 * (80 * 2 + 2 * rows + rows // TILE + 1
-                               + 80 * 4 + 4))
+                               + 80 * 4 + 4),
+        scoring='softmax', bias=False, scale=1.0, shared=0)
 
 
 def plain_order(local, weights, held):
@@ -417,43 +418,84 @@ def test_a_dense_block_is_kept_as_it_was(monkeypatch):
     assert text() == as_it_is and 'remat2' in as_it_is
 
 
-def whole_layer(p, x, first, held, top_k, gated, act):
+# The router's variants (DeepSeek-V3's): sigmoid scores, the experts
+# chosen by score + a bias that is NOT zero and weighed by the score
+# alone (so that choosing and weighing by one of the two is told apart),
+# a scale on the weights, a shared expert beside the routed ones.
+SIGMOID = dict(scoring='sigmoid', select_bias=True, scale=2.448, shared=24)
+
+
+def with_bias(p, key=7):
+    if 'select_bias' in p:
+        p = dict(p, select_bias=0.3 * jax.random.normal(
+            jax.random.PRNGKey(key), p['select_bias'].shape))
+    return p
+
+
+def whole_layer(p, x, first, held, top_k, gated, act, scoring='softmax',
+                select_bias=False, scale=1.0, shared=0, by_bias=True,
+                weigh_biased=False):
     """The plain form: every held expert for every token, weighted."""
     t = x.reshape(-1, x.shape[-1])
-    probs = jax.nn.softmax(t @ p['router']['kernel'], -1)
-    vals, idx = jax.lax.top_k(probs, top_k)
-    w = vals / vals.sum(-1, keepdims=True)
+    logits = t @ p['router']['kernel']
+    if scoring == 'softmax':
+        vals, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), top_k)
+        w = vals / vals.sum(-1, keepdims=True)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        biased = scores + p['select_bias']
+        _, idx = jax.lax.top_k(biased if by_bias else scores, top_k)
+        vals = jnp.take_along_axis(biased if weigh_biased else scores, idx,
+                                   -1)
+        w = scale * vals / (vals.sum(-1, keepdims=True) + 1e-20)
     out = 0
     for e in range(first, first + held):
         we = jnp.sum(jnp.where(idx == e, w, 0), -1)
         up = p['up'][e - first]
         h = act(t @ up[:, 0]) * (t @ up[:, 1]) if gated else act(t @ up)
         out = out + we[:, None] * (h @ p['down'][e - first])
+    if shared:
+        up = p['shared']['up']['kernel']
+        out = out + (act(t @ up[:, 0]) * (t @ up[:, 1])) \
+            @ p['shared']['down']['kernel']
     return out.reshape(x.shape)
 
 
-@pytest.mark.parametrize('gated,held', [(True, (2, 4)), (False, (0, 8)),
-                                        (True, (7, 1))])
-def test_layer_matches_a_loop_over_its_experts(gated, held):
+@pytest.mark.parametrize('gated,held,router', [
+    (True, (2, 4), {}), (False, (0, 8), {}), (True, (7, 1), {}),
+    (True, (2, 4), SIGMOID), (True, (0, 8), dict(SIGMOID, shared=0))],
+    ids=['gated_2_4', 'plain_all', 'gated_last', 'sigmoid_bias_shared',
+         'sigmoid_bias_all'])
+def test_layer_matches_a_loop_over_its_experts(gated, held, router):
     act = jax.nn.silu if gated else jax.nn.gelu
-    layer = MoeMlp(32, 16, 8, top_k=2, held=held, act=act, gated=gated)
-    p = layer.init(jax.random.PRNGKey(0))
+    layer = MoeMlp(32, 16, 8, top_k=2, held=held, act=act, gated=gated,
+                   **router)
+    p = with_bias(layer.init(jax.random.PRNGKey(0)))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32))
 
-    def plain(p, x):
-        return whole_layer(p, x, *held, 2, gated, act)
-    y, _, stats = layer.apply(p, x)
+    def plain(p, x, **how):
+        return whole_layer(p, x, *held, 2, gated, act, **router, **how)
+    y, aux, stats = layer.apply(p, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(plain(p, x)),
                                atol=2e-5)
     got = jax.grad(lambda p, x: jnp.sum(jnp.sin(layer.apply(p, x)[0])),
                    argnums=(0, 1))(p, x)
     want = jax.grad(lambda p, x: jnp.sum(jnp.sin(plain(p, x))),
                     argnums=(0, 1))(p, x)
+    if router:
+        # the bias selects and takes no gradient; the plain form's goes
+        # nowhere either (top_k's indices carry none)
+        assert not np.any(np.asarray(got[0]['select_bias']))
+        assert float(aux) == 0          # no Switch loss of sigmoid scores
+        # choosing without the bias, or weighing with it, is another layer
+        for other in (dict(by_bias=False), dict(weigh_biased=True)):
+            assert float(jnp.max(jnp.abs(y - plain(p, x, **other)))) > 1e-2
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
     # the counters: rows held here, and the largest load of a held expert
-    _, idx = jax.lax.top_k(jax.nn.softmax(
-        x.reshape(-1, 32) @ p['router']['kernel'], -1), 2)
+    logits = x.reshape(-1, 32) @ p['router']['kernel']
+    _, idx = jax.lax.top_k(jax.nn.sigmoid(logits) + p['select_bias']
+                           if router else jax.nn.softmax(logits, -1), 2)
     local = np.asarray(idx) - held[0]
     loads = np.bincount(local[(local >= 0) & (local < held[1])],
                         minlength=held[1])
@@ -461,25 +503,30 @@ def test_layer_matches_a_loop_over_its_experts(gated, held):
                                   [loads.sum(), loads.max()])
 
 
-def test_the_shares_of_a_deployment_add_up_to_the_whole_layer():
+@pytest.mark.parametrize('router', [{}, SIGMOID],
+                         ids=['softmax', 'sigmoid_bias_shared'])
+def test_the_shares_of_a_deployment_add_up_to_the_whole_layer(router):
     """The ``model-configs`` guide's §4: experts ``0..3`` and ``4..7`` of
     the same weights, each share computing its own part, add up to what
-    the uncut layer gives; so do eight shares of one expert."""
-    whole = MoeMlp(32, 16, 8, top_k=3, act=jax.nn.silu, gated=True)
-    p = whole.init(jax.random.PRNGKey(4))
+    the uncut layer gives; so do eight shares of one expert. A shared
+    expert is whole in every share: the shares' ROUTED parts and the
+    shared expert counted once are the layer."""
+    whole = MoeMlp(32, 16, 8, top_k=3, act=jax.nn.silu, gated=True, **router)
+    p = with_bias(whole.init(jax.random.PRNGKey(4)))
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, 32))
-    want = whole_layer(p, x, 0, 8, 3, True, jax.nn.silu)
+    want = whole_layer(p, x, 0, 8, 3, True, jax.nn.silu, **router)
     np.testing.assert_allclose(np.asarray(whole.apply(p, x)[0]),
                                np.asarray(want), atol=2e-5)
+    shared = whole.shared.apply(p['shared'], x) if router else 0
     for count in (4, 1):
-        total, rows = 0, 0
+        total, rows = shared, 0
         for first in range(0, 8, count):
             share = MoeMlp(32, 16, 8, top_k=3, held=(first, count),
-                           act=jax.nn.silu, gated=True)
+                           act=jax.nn.silu, gated=True, **router)
             ps = dict(p, up=p['up'][first:first + count],
                       down=p['down'][first:first + count])
             y, _, stats = share.apply(ps, x)
-            total, rows = total + y, rows + float(stats[0])
+            total, rows = total + (y - shared), rows + float(stats[0])
         np.testing.assert_allclose(np.asarray(total), np.asarray(want),
                                    atol=3e-5)
         assert rows == 2 * 24 * 3     # every pair is some share's row

@@ -313,6 +313,81 @@ def test_mellum2_period_lowers_with_its_kernels_and_no_repeated_kv():
     assert scatters == {'%dx64' % s, '256x2304'}
 
 
+def test_kanana2_stack_lowers_with_its_kernels_and_nothing_by_head():
+    """kanana-2's dense layer and one expert layer at the published
+    widths (latent attention: 32 heads of 128 + 64 on one rotary key, v
+    heads of 128, a latent of 512; a dense MLP of 6144; sigmoid 6 of 128
+    experts of width 768, two of them held to keep the test light, a
+    shared expert of 1536) under remat=True with its gradient, as it
+    lowers for the TPU (PR 39): the three latent flash calls, the grouped
+    products and the combine are there by name; every flash call reads
+    the projections' outputs where they lie (q ``[b, s, 6144]``, k_nope
+    and v as ``[b, s, 8192]``, the rotary key inside ``[b, s, 576]``);
+    and no tensor of the step is by head: no score square ``[., 32, s,
+    s]``, no copy of the rotary key repeated to the 32 heads, no q, k or
+    v padded to a common head width."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.kernels import grouped_matmul as gm
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    b, s = 1, 8192
+    cfg = TransformerConfig(
+        vocab=256, dim=2048, n_layers=2, n_heads=32, max_len=s, causal=True,
+        tied_embeddings=False, dtype=jnp.bfloat16, remat=True,
+        positions='rotary', rope_theta=1e6, latent_rank=512,
+        qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, mlp_dim=768,
+        gated_mlp=True, gelu='silu', norm='rms', mlp_bias=False,
+        moe_experts=128, moe_top_k=6, moe_held=2, moe_aux_coef=0.0,
+        dense_lead=1, dense_mlp_dim=6144, moe_scoring='sigmoid',
+        moe_scale=2.448, moe_shared_dim=1536)
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(dp=1))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = {name: np.zeros((b, s), np.int32)
+             for name in ('tokens', 'targets')}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, '_interpret_default', lambda: False)
+        patch.setattr(gm, '_interpret_default', lambda: False)
+        step = tr._ensure_step(tr._step_key(batch), state, batch)
+        shapes = jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sh),
+            batch, tr.batch_sharding(batch))
+        text = jax.export.export(step, platforms=['tpu'])(
+            state, shapes).mlir_module()
+    names = set(re.findall(r'kernel_name = "(\w+)"', text))
+    assert names == {'flash_fwd_mla', 'flash_dq_mla', 'flash_dkv_mla',
+                     'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw', 'moe_combine',
+                     'moe_rows_buffer'}
+    flash_calls = [line for line in text.splitlines()
+                   if '@tpu_custom_call' in line and 'flash_' in line]
+    # a forward call in each layer and again in neither's backward (the
+    # checkpoint keeps its output), a backward pair each
+    assert len(flash_calls) == 2 * 3
+    for line in flash_calls:
+        operands = line.split(' : (', 1)[1].split(') -> ')[0]
+        assert operands.startswith(
+            'tensor<%dx%dx6144xbf16>, tensor<%dx%dx8192xbf16>, '
+            'tensor<%dx%dx8192xbf16>, tensor<%dx%dx576xbf16>'
+            % ((b, s) * 4)), line
+    tensors = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|i1)>', text))
+    by_head = [t for t in tensors if re.search(
+        r'(^|x)(%dx32x|32x%dx)(64|128|192|256|%d)$' % (s, s, s), t)]
+    assert not by_head, by_head
+    assert not any(t.endswith('%dx%d' % (s, s)) and t != '%dx%dx%d'
+                   % (b, s, s) for t in tensors)
+
+
 @pytest.mark.parametrize('carried', [False, True],
                          ids=['alone', 'onto_the_sum'])
 def test_moe_combine_lowers_for_tpu_at_the_cells_shape(carried):
