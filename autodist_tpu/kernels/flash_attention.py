@@ -72,17 +72,35 @@ and the local head count), one body per kernel:
   reduce over 64-lane groups whose result has ``s`` along the lanes
   cost it an f32 copy of the whole product (PERF.md §6, PR 29).
 * A **band call** (``window = (left, right)``: query i sees keys
-  i - left .. i + right; ModernBERT's window layers) is planned from
-  the band and not from the sequence: blocks as wide as the band
-  reaches to one side (:func:`_band_targets`), an inner grid dimension
-  as long as the longest run of inner blocks an outer block's band
-  reaches (:func:`_band_inner_blocks`; three at 128 x 128 blocks and
-  64 keys each side), index maps that walk that run and clamp the steps outside
-  the sequence onto a block the pipeline already holds
-  (:func:`_band_fetch`), and the band's mask only on tiles an edge of
-  the band crosses. The three calls are then named ``flash_fwd_band``,
-  ``flash_dq_band``, ``flash_dkv_band``. With ``window=None`` nothing
-  of this is on the path.
+  i - left .. i + right) is planned from the band and not from the
+  sequence, in one of two forms that follow the band's reach
+  (:func:`_band_form`; the ``flash.plan`` tag ``band_form``). The three
+  calls are named ``flash_fwd_band``, ``flash_dq_band``,
+  ``flash_dkv_band`` in both; with ``window=None`` nothing of either is
+  on the path.
+
+  - ``'row'``, a band that reaches no further than a lane block to
+    either side (``max(window) <= 128``: ModernBERT's window layers, 64
+    keys each side): ONE pass over each row block's own keys. The keys
+    a block of rows can see are its own and a corner of each
+    neighbour, which a grid step holds as three operands of the same
+    array (the section "a narrow band" below): no inner grid
+    dimension, no scratch, no online softmax, one score block of
+    ``[sub, sub + 2 corner]`` a head and sub-block. The tiled walk
+    spent 2-3 times the elements there, each under the online
+    softmax's bookkeeping (PERF.md §6, PR 40).
+  - ``'tiles'``, a wider band (Mellum2's causal window of 1024 keys),
+    and a narrow one the row form has no body for (grouped kv heads, a
+    sequence that is no multiple of 128, block sizes asked for by
+    hand): the tiled walk under the online softmax with a shorter
+    inner grid dimension. Blocks as wide as the band reaches to one
+    side (:func:`_band_targets`), an inner dimension as long as the
+    longest run of inner blocks an outer block's band reaches
+    (:func:`_band_inner_blocks`), index maps that walk that run and
+    clamp the steps outside the sequence onto a block the pipeline
+    already holds (:func:`_band_fetch`), and the band's mask only on
+    tiles an edge of the band crosses. Its tiles are many times the
+    band's edge, and the row form has nothing to give it.
 * A softmax scale that is a power of two (head_dim 16, 64, 256) is
   folded into ``q`` ([bq, d]) instead of multiplying every [bq, bk]
   tile, which is exact in any binary float format; any other scale
@@ -174,13 +192,14 @@ def _block_targets(seq, causal, window=None):
     return {'fwd': (1024, 1024), 'dq': (512, 512), 'dkv': (512, 512)}
 
 
-# Targets of a band call at ModernBERT's reach (64 keys each side), from
-# a sweep of {128, 256, 512}^2 at [4, 16, 8192, 64] on a v5e (my chip
-# runs, PR 26; PERF.md §6): ms a call, forward / dq / dkv, 3.81 / 3.09 /
-# 2.51 at 128 x 128 for all three against 3.54 / 2.39 / 2.35 here. A
-# tile costs about 0.19 us a head whatever its size, and an element of
-# it 7.6 ps, so tiles larger than the band pay in elements what they
-# save in steps; each kernel settles elsewhere.
+# Base targets of the tiled walk of a band (:func:`_band_targets` scales
+# them up for a wide one), from a sweep of {128, 256, 512}^2 at
+# ModernBERT's reach, [4, 16, 8192, 64] on a v5e (my chip runs, PR 26),
+# when that band took this walk: 3.54 / 2.39 / 2.35 ms a call, forward
+# / dq / dkv; a tile cost about 0.19 us a head whatever its size and an
+# element of it 7.6 ps. A band of that reach takes the row form since
+# PR 40 (``_ROW_TARGETS`` below has its sweep); what is left to these
+# is a wide band's base, and a narrow band the row form has no body for.
 _BAND_TARGETS = {'fwd': (128, 512), 'dq': (256, 256), 'dkv': (128, 256)}
 
 
@@ -236,14 +255,21 @@ def _lane_block(heads, head_dim):
     return heads * head_dim
 
 
-def _heads_per_step(heads, bq, bk, per_block=1):
-    """Heads a grid step holds: whole lane blocks (``per_block`` heads
-    each) that divide the (local) head count, as many as stay inside the
-    step budget and ``_MAX_HEADS_PER_STEP``, and one block at least."""
-    target = max(1, min(_MAX_HEADS_PER_STEP, _STEP_TILE_ELEMS // (bq * bk)))
+def _whole_blocks(heads, per_block, target):
+    """The most heads, up to ``target``, that are whole lane blocks
+    (``per_block`` heads each) and divide the (local) head count; one
+    block at least."""
     blocks = heads // per_block
     return per_block * max(c for c in range(1, blocks + 1) if blocks % c == 0
                            and c * per_block <= max(target, per_block))
+
+
+def _heads_per_step(heads, bq, bk, per_block=1):
+    """Heads a grid step holds: whole lane blocks that divide the head
+    count, as many as stay inside the step budget and
+    ``_MAX_HEADS_PER_STEP``."""
+    return _whole_blocks(heads, per_block, max(1, min(
+        _MAX_HEADS_PER_STEP, _STEP_TILE_ELEMS // (bq * bk))))
 
 
 Blocks = collections.namedtuple('Blocks', 'block_q block_k heads_per_step')
@@ -616,15 +642,15 @@ def _reads_qk(q_ref, k_ref, tables, d):
     return _reads(q_ref, q_tables, d), _reads(k_ref, k_tables, d)
 
 
-def _turned_back(dx, tables, d):
+def _turned_back(dx, tables, d, rows=Ellipsis):
     """The finished f32 gradient w.r.t. a rotated q or k block turned
     back to that of q or k, before the one rounding of its store;
     ``tables``: None (nothing was rotated), or the ``(cos, sin)`` refs
-    blocked by the block's rows."""
+    blocked by the block's rows (``dx`` is that of their ``rows``)."""
     if tables is None:
         return dx
     cos_ref, sin_ref = tables
-    return _rotate(dx, cos_ref[...], sin_ref[...], d, back=True)
+    return _rotate(dx, cos_ref[rows], sin_ref[rows], d, back=True)
 
 
 _ALL = slice(None)
@@ -894,6 +920,9 @@ def _kv_row(causal, bq, bk, window=None, seq=None):
 
 def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
          window=None):
+    if isinstance(blocks, Rows):
+        return _fwd_row(qkv, tables, heads, sm_scale, blocks, interpret,
+                        window)
     bq, bk, g = blocks
     ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
         qkv, heads, kv_heads, g)
@@ -1115,6 +1144,9 @@ def _dq(qkv, tables, do, o, lse, heads, kv_heads, causal, sm_scale, blocks,
     """``(dq, delta)``: ``delta = rowsum(dO * O)`` of each head is
     computed here, from the two merged tensors a block at a time, and
     left as ``[b, h, 1, s]`` for ``flash_dkv``."""
+    if isinstance(blocks, Rows):
+        return _dq_row(qkv, tables, do, o, lse, heads, sm_scale, blocks,
+                       interpret, window)
     bq, bk, g = blocks
     ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
         qkv, heads, kv_heads, g)
@@ -1159,6 +1191,9 @@ def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
     heads the grid's head dimension walks the kv heads and its inner
     dimension the group's query heads, ``g`` at a step, each over the
     q-blocks (``_dkv_kernel``)."""
+    if isinstance(blocks, Rows):
+        return _dkv_row(qkv, tables, do, lse, delta, heads, sm_scale, blocks,
+                        interpret, window, dqkv)
     bq, bk, g = blocks
     ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
         qkv, heads, kv_heads, g)
@@ -1231,6 +1266,396 @@ def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
 def _without(kernel, i, *refs):
     """``kernel`` on all its refs but the ``i``-th."""
     return kernel(*refs[:i], *refs[i + 1:])
+
+
+# ---------------------------------------------------------------------------
+# a narrow band: one pass over each row block's own keys
+# ---------------------------------------------------------------------------
+#
+# A band that reaches no further than a lane block to either side
+# (``max(window) <= 128``: ModernBERT's 64 keys each side) has nothing
+# to walk: the keys a block of rows can see are the block's own and a
+# ``corner`` of each neighbour (64 rows where the reach is no more, else
+# 128). The three calls then have no inner grid dimension, no scratch
+# and no online softmax. Grid (batch, heads / G, seq / rows); a step
+# holds ``rows`` rows of its outer operand (q for ``flash_fwd_band`` and
+# ``flash_dq_band``, k and v for ``flash_dkv_band``) and of the inner
+# one THE RUN of ``corner + rows + corner`` rows round them, as three
+# operands of the same array: the neighbour's corner before, the block
+# itself, the corner after. The corners' index maps clamp at the
+# sequence's ends onto a block in range, and the mask, which knows every
+# position, kills what the clamp brought. On the tile the run's pieces
+# are rotated once each and laid end to end, and each ``sub`` rows of
+# the step (a static loop) meet the ``sub + 2 corner`` rows of the run
+# round them: one score block a head, the band's mask, a plain softmax
+# (or the backward's ``exp(s - lse)``), the products, one store.
+
+Rows = collections.namedtuple('Rows', 'rows sub corner heads_per_step')
+
+# (rows of a sub-block, sub-blocks a step, lanes a step) of each kernel
+# in the row form, from a sweep at ModernBERT's window layers, [4, 16,
+# 8192, 64] bf16, 64 keys each side, rotary on the tile, on a v5e (my
+# chip run, PR 40; ``tools/flash_band_bench.py --sweep``; each kernel
+# alone, ms a call by the host's clock round 20 calls in a row, which
+# holds their dispatch: inside the traced step the committed three take
+# 3.61 together where they add up to 3.77 here; dkv's column holds about
+# 0.7 ms of a copy that the step does not make). The tiled walk beside
+# them: 3.55 / 2.86 / 3.16.
+#
+#   sub x steps   heads: forward            dq                   dkv
+#                 2     4     8     16 | 2    4    8    16  | 2    4    8    16
+#   128 x 1       2.40  1.66  1.61  1.59 2.86 2.28 2.04 1.78  3.35 2.43 1.97 1.69
+#   128 x 2       1.65  1.33  1.23  1.23 2.29 1.99 1.75 1.66  2.46 1.95 1.73 1.58
+#   128 x 4       1.34  1.20  1.14  1.11 1.89 1.75 1.67 1.59  2.16 1.75 1.61 1.54
+#   128 x 8       1.21  1.01  1.02  1.04 1.73 1.67 1.58 1.56  2.04 1.66 1.56 1.53
+#   256 x 1       1.72  1.38  1.29  1.19 2.14 1.69 1.73 1.72  2.77 2.30 2.07 1.92
+#   256 x 2       1.42  1.24  1.19  1.19 1.66 1.93 1.77 1.76  2.30 2.07 1.96 1.89
+#   256 x 4       1.35  1.29  1.18  1.15 1.50 1.67 1.69 1.74  2.09 1.96 1.91 1.86
+#   256 x 8       1.23  1.27  1.15  -    1.51 1.65 1.70 -     2.01 1.91 1.88 -
+#
+# Fewer, larger steps win in every kernel (a step's fixed cost, and the
+# corners' fetch and rotation, are paid once for up to 1024 rows), and
+# sub-blocks of 128 rows nearly everywhere: a 256-row sub-block meets
+# 384 keys, 1.5 times the elements. ``flash_dq_band`` alone prefers
+# them, at one lane block a step. Without rotary the same calls take
+# 0.58 / 1.00 / 1.55 at 128 x 4 x 8 against 1.14 / 1.67 / 1.61: the
+# forward and dq are bound by their elementwise work and pay the
+# rotation in full, dkv hides it.
+#
+# But a body is unrolled over lane blocks x sub-blocks x heads, and is
+# traced and lowered at every start, cache or no cache. At the fastest
+# of each column (128 x 8 x 4, 256 x 4 x 2, 128 x 8 x 16: 1.01 / 1.50 /
+# 0.82 ms a call with dk written in place) ModernBERT's cell read
+# ``setup_s`` 67.4-68.7 s warm against the tiled walk's 45.9 (``jax.trace``
+# 29.1 s against 9.6; my chip run, PR 40), and Mosaic's compile of that
+# dkv is 21.0 s against 0.9. So the targets are the fastest whose
+# forward and backward trace and lower in the tiled walk's time (0.66 s
+# against 0.66 here, on the CPU): 1.21 / 1.51 / 1.05 ms a call, and the
+# cell's ``setup_s`` 49.8 s against 48.1 (medians; my chip run, PR 40).
+_ROW_TARGETS = {'fwd': (128, 4, 256), 'dq': (256, 4, 128),
+                'dkv': (128, 2, 512)}
+
+
+def _band_form(window, seq, group, asked):
+    """How a call walks its band: ``None`` without a ``window``,
+    ``'row'`` (one pass over each row block's own keys) for a band that
+    reaches no further than a lane block to either side, ``'tiles'`` (the
+    tiled walk under an online softmax) for a wider one, and for a
+    narrow one that the row form has no body for: grouped kv heads, a
+    sequence that does not split into lane-wide blocks, block sizes
+    asked for by hand (which are the tiled walk's)."""
+    if window is None:
+        return None
+    row = (max(window) <= _LANES and group == 1 and not asked
+           and seq % _LANES == 0)
+    return 'row' if row else 'tiles'
+
+
+def _rows(heads, head_dim, seq, window, sub, steps, lanes):
+    """The :class:`Rows` of one kernel: its targets cut to the sequence
+    (sub-blocks a step) and to the heads (whole lane blocks that divide
+    their count, inside ``lanes``: a step's VMEM goes by its lanes)."""
+    sub = _pick_block(seq, sub)
+    while seq % (sub * steps):
+        steps //= 2
+    corner = _LANES // 2 if max(window) <= _LANES // 2 else _LANES
+    return Rows(sub * steps, sub, corner, _whole_blocks(
+        heads, _lane_block(heads, head_dim) // head_dim, lanes // head_dim))
+
+
+def _run_pieces(blocks, seq, piece, run):
+    """``(rows, row-block index map)`` of what a step holds of an
+    operand: its own rows, or (``run``) the three pieces of its run,
+    the corners in blocks of ``piece`` rows: the last before the step's
+    own rows and the first after them, clamped into the sequence."""
+    own = (blocks.rows, lambda i: i)
+    if not run:
+        return [own]
+    per, last = blocks.rows // piece, seq // piece - 1
+    return [(piece, lambda i: jnp.maximum(i * per - 1, 0)), own,
+            (piece, lambda i: jnp.minimum((i + 1) * per, last))]
+
+
+def _row_specs(blocks, seq, width, start=0, run=False):
+    """Specs of a ``[b, s, columns]`` operand on the row form's (b, head
+    group, row block) grid: the ``width`` lanes of the head group, from
+    column ``start``."""
+    first = start // width
+    return [pl.BlockSpec((1, n, width), lambda b, h, i, row_of=row_of:
+                         (b, row_of(i), first + h))
+            for n, row_of in _run_pieces(blocks, seq, blocks.corner, run)]
+
+
+def _row_stat_specs(blocks, seq, run=False):
+    """Specs of a row statistic ``[b, h, 1, s]`` likewise; the run's
+    corners arrive as the neighbours' whole lane blocks."""
+    return [pl.BlockSpec((1, blocks.heads_per_step, 1, n),
+                         lambda b, h, i, row_of=row_of: (b, h, 0, row_of(i)))
+            for n, row_of in _run_pieces(blocks, seq, _LANES, run)]
+
+
+def _row_table_specs(blocks, seq, lanes, run=False):
+    """Specs of ``cos`` and ``sin`` by the same rows."""
+    return [pl.BlockSpec((n, lanes),
+                         lambda b, h, i, row_of=row_of: (row_of(i), 0))
+            for n, row_of in _run_pieces(blocks, seq, blocks.corner, run)
+            for _ in range(2)]
+
+
+def _run_of(pieces, tables, d, cols):
+    """The run a step holds of columns ``cols`` of q, k, v or ``do``:
+    its pieces end to end, ``[corner + rows + corner, lanes]``, each
+    rotated once by its own blocks of the position ``tables`` (three
+    ``(cos, sin)``; None for v and ``do``, and without rotary)."""
+    return jnp.concatenate([
+        _reads(ref, table, d)(_ALL, cols)
+        for ref, table in zip(pieces, tables or (None,) * 3)], axis=0)
+
+
+def _stat_run(pieces, h, corner):
+    """The run of a row statistic of head ``h``, ``[1, corner + rows +
+    corner]``: the last ``corner`` lanes of the neighbour's block before,
+    the step's own, the first ``corner`` of the block after. Lane blocks
+    end to end, and where a corner is half a block every block of the
+    result is the end of one and the start of the next (a select and a
+    roll each): no slice off the lanes' grid."""
+    before, own, after = (ref[0, h] for ref in pieces)
+    blocks = [before] + [own[:, at:at + _LANES]
+                         for at in range(0, own.shape[1], _LANES)] + [after]
+    if corner == _LANES:
+        return jnp.concatenate(blocks, axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    return jnp.concatenate([
+        pltpu.roll(jnp.where(lane >= _LANES - corner, a, b), corner, 1)
+        for a, b in zip(blocks, blocks[1:])], axis=1)
+
+
+def _run_masks(step, blocks, seq, reach):
+    """For each sub-block of grid step ``step`` the mask of its score
+    block ``[sub, sub + 2 corner]``: rows are the outer operand's
+    positions, columns the run's, which start ``corner`` before the
+    rows; ``reach``: how far the band goes towards lower and higher
+    columns. The band is the same in every sub-block; the first and the
+    last of a step may hold columns outside the sequence, where a
+    clamped index map brought another block's rows."""
+    rows, sub, corner, _ = blocks
+    shape = (sub, sub + 2 * corner)
+    ahead = jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+        - jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    back, fore = reach
+    band = jnp.logical_and(ahead >= corner - back, ahead <= corner + fore)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+    masks = []
+    for t in range(rows // sub):
+        if t in (0, rows // sub - 1):
+            pos = step * rows + (t * sub - corner) + col
+            masks.append(jnp.logical_and(
+                band, jnp.logical_and(pos >= 0, pos < seq)))
+        else:
+            masks.append(band)
+    return masks
+
+
+def _sub_blocks(blocks):
+    """``(own rows, rows of the run round them)`` of each sub-block of a
+    step, as slices of the step's rows and of its run."""
+    rows, sub, corner, _ = blocks
+    return [(slice(at, at + sub), slice(at, at + sub + 2 * corner))
+            for at in range(0, rows, sub)]
+
+
+def _row_refs(refs, runs, rotary):
+    """The refs of a row-form kernel taken apart: ``runs`` says of each
+    operand whether it comes as a run (three refs) or as the step's own
+    rows (one); behind them, with ``rotary``, cos and sin by the outer
+    operand's rows and by each piece of the inner one's run; the rest
+    are the results."""
+    refs = list(refs)
+    operands = [tuple(refs.pop(0) for _ in range(3)) if run else refs.pop(0)
+                for run in runs]
+    own = run = None
+    if rotary:
+        own, *run = [(refs.pop(0), refs.pop(0)) for _ in range(4)]
+    return operands, own, run, refs
+
+
+def _fwd_row_kernel(*refs, sm_scale, fold, blocks, seq, d, lanes, window,
+                    rotary):
+    (q_ref, k_run, v_run), q_tables, k_tables, (o_ref, lse_ref) = _row_refs(
+        refs, (False, True, True), rotary)
+    masks = _run_masks(pl.program_id(2), blocks, seq, window)
+    for cols, heads in _lane_blocks(blocks.heads_per_step, d, lanes):
+        q_rows = _reads(q_ref, q_tables, d)(_ALL, cols)
+        ks, vs = _run_of(k_run, k_tables, d, cols), _run_of(v_run, None, d,
+                                                            cols)
+        for (own, seen), mask in zip(_sub_blocks(blocks), masks):
+            k, v, o = ks[seen], vs[seen], None
+            for h, keep in heads:
+                q = q_rows[own]
+                s = _scores(_only(q * sm_scale if fold else q, keep), k,
+                            sm_scale, fold, mask)
+                m = jnp.max(s, axis=1, keepdims=True)
+                p = jnp.exp(s - m)                            # [sub, span]
+                l = jnp.sum(p, axis=1, keepdims=True)
+                o = _place(o, _dot(p.astype(v.dtype), v, _NN) / l, keep)
+                lse_ref[0, h, :, own] = _to_row(m + jnp.log(l))
+            o_ref[0, own, cols] = o.astype(o_ref.dtype)
+
+
+def _dq_row_kernel(*refs, sm_scale, fold, blocks, seq, d, lanes, window,
+                   rotary):
+    (q_ref, k_run, v_run, do_ref, o_ref, lse_ref), q_tables, k_tables, \
+        (dq_ref, delta_ref) = _row_refs(
+            refs, (False, True, True, False, False, False), rotary)
+    masks = _run_masks(pl.program_id(2), blocks, seq, window)
+    for cols, heads in _lane_blocks(blocks.heads_per_step, d, lanes):
+        q_rows = _reads(q_ref, q_tables, d)(_ALL, cols)
+        ks, vs = _run_of(k_run, k_tables, d, cols), _run_of(v_run, None, d,
+                                                            cols)
+        for (own, seen), mask in zip(_sub_blocks(blocks), masks):
+            k, v, dqs = ks[seen], vs[seen], None
+            do_rows = do_ref[0, own, cols]
+            products = (do_rows.astype(jnp.float32)
+                        * o_ref[0, own, cols].astype(jnp.float32))
+            for h, keep in heads:
+                q = q_rows[own]
+                q = _only(q * sm_scale if fold else q, keep)
+                delta = jnp.sum(_only(products, keep), axis=1, keepdims=True)
+                delta_ref[0, h, :, own] = _to_row(delta)
+                s = _scores(q, k, sm_scale, fold, mask)
+                p = jnp.exp(s - _to_col(lse_ref[0, h, :, own]))
+                ds = p * (_dot(_only(do_rows, keep), v, _NT) - delta)
+                if not fold:
+                    ds = ds * sm_scale
+                dqs = _place(dqs, _dot(ds.astype(k.dtype), k, _NN), keep)
+            dq = dqs * sm_scale if fold else dqs
+            dq_ref[0, own, cols] = _turned_back(dq, q_tables, d, own).astype(
+                dq_ref.dtype)
+
+
+def _dkv_row_kernel(*refs, sm_scale, fold, blocks, seq, d, lanes, window,
+                    rotary):
+    (q_run, k_ref, v_ref, do_run, lse_run, delta_run), k_tables, q_tables, \
+        (dk_ref, dv_ref) = _row_refs(
+            refs, (True, False, False, True, True, True), rotary)
+    # transposed score blocks [keys, queries]: a key is seen from as far
+    # behind it as the band reaches ahead of a query
+    masks = _run_masks(pl.program_id(2), blocks, seq, window[::-1])
+    for cols, heads in _lane_blocks(blocks.heads_per_step, d, lanes):
+        k_rows = _reads(k_ref, k_tables, d)(_ALL, cols)
+        qs, dos = _run_of(q_run, q_tables, d, cols), _run_of(do_run, None, d,
+                                                             cols)
+        if fold:
+            qs = qs * sm_scale         # dk = ds^T (q * scale) as well
+        stats = {h: (_stat_run(lse_run, h, blocks.corner),
+                     _stat_run(delta_run, h, blocks.corner))
+                 for h, _ in heads}
+        for (own, seen), mask in zip(_sub_blocks(blocks), masks):
+            q, do, dks, dvs = qs[seen], dos[seen], None, None
+            for h, keep in heads:
+                lse, delta = stats[h]
+                k = _only(k_rows[own], keep)
+                s = _scores(k, q, sm_scale, fold, mask)       # [sub, span]
+                p = jnp.exp(s - lse[:, seen])
+                dvs = _place(dvs, _dot(p.astype(do.dtype), do, _NN), keep)
+                dp = _dot(_only(v_ref[0, own, cols], keep), do, _NT)
+                ds = p * (dp - delta[:, seen])
+                if not fold:
+                    ds = ds * sm_scale
+                dks = _place(dks, _dot(ds.astype(q.dtype), q, _NN), keep)
+            dk_ref[0, own, cols] = _turned_back(dks, k_tables, d, own).astype(
+                dk_ref.dtype)
+            dv_ref[0, own, cols] = dvs.astype(dv_ref.dtype)
+
+
+_ROW_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=('parallel', 'parallel', 'parallel'),
+    vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _row_call(kernel, name, qkv, tables, heads, sm_scale, blocks, window,
+              interpret, operands, specs, out_specs, out_shape, into=None):
+    """One call of the row form: ``kernel`` on ``operands`` under
+    ``specs``, the position tables behind them, by the outer operand's
+    rows and by the pieces of the inner one's run. ``into``: an array
+    that comes in where it lies and goes out as the first result (the
+    kernel sees it as that result only)."""
+    ((q, _), _, _), d, _, _, lanes = _operands(qkv, heads, heads,
+                                               blocks.heads_per_step)
+    b, s, _ = q.shape
+    kernel = functools.partial(
+        kernel, sm_scale=sm_scale, fold=_is_pow2(sm_scale), blocks=blocks,
+        seq=s, d=d, lanes=lanes, window=window, rotary=tables is not None)
+    if tables is not None:
+        specs = specs + _row_table_specs(blocks, s, lanes) \
+            + _row_table_specs(blocks, s, lanes, run=True)
+        operands = operands + tuple(tables) * 4
+    aliases = {}
+    if into is not None:
+        aliases = {len(operands): 0}
+        kernel = functools.partial(_without, kernel, len(operands))
+        specs = specs + [pl.BlockSpec(memory_space=pl.ANY)]
+        operands = operands + (into,)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, heads // blocks.heads_per_step, s // blocks.rows),
+        in_specs=specs, out_specs=out_specs, out_shape=out_shape,
+        input_output_aliases=aliases, compiler_params=_ROW_COMPILER_PARAMS,
+        interpret=interpret, name=name)(*operands)
+
+
+def _fwd_row(qkv, tables, heads, sm_scale, blocks, interpret, window):
+    ((q, q0), (k, k0), (v, v0)), d, width, _, _ = _operands(
+        qkv, heads, heads, blocks.heads_per_step)
+    b, s, _ = q.shape
+    return _row_call(
+        _fwd_row_kernel, 'flash_fwd_band', qkv, tables, heads, sm_scale,
+        blocks, window, interpret, (q, k, k, k, v, v, v),
+        _row_specs(blocks, s, width, q0)
+        + _row_specs(blocks, s, width, k0, run=True)
+        + _row_specs(blocks, s, width, v0, run=True),
+        _row_specs(blocks, s, width) + _row_stat_specs(blocks, s),
+        [jax.ShapeDtypeStruct((b, s, heads * d), q.dtype),
+         jax.ShapeDtypeStruct((b, heads, 1, s), jnp.float32)])
+
+
+def _dq_row(qkv, tables, do, o, lse, heads, sm_scale, blocks, interpret,
+            window):
+    ((q, q0), (k, k0), (v, v0)), _, width, _, _ = _operands(
+        qkv, heads, heads, blocks.heads_per_step)
+    s = do.shape[1]
+    own, stat = _row_specs(blocks, s, width), _row_stat_specs(blocks, s)
+    return _row_call(
+        _dq_row_kernel, 'flash_dq_band', qkv, tables, heads, sm_scale,
+        blocks, window, interpret, (q, k, k, k, v, v, v, do, o, lse),
+        _row_specs(blocks, s, width, q0)
+        + _row_specs(blocks, s, width, k0, run=True)
+        + _row_specs(blocks, s, width, v0, run=True) + own + own + stat,
+        # dq as q is held: an array of its own, or the first run of one
+        own + stat,
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(lse.shape, jnp.float32)])
+
+
+def _dkv_row(qkv, tables, do, lse, delta, heads, sm_scale, blocks, interpret,
+             window, dqkv):
+    ((q, q0), (k, k0), (v, v0)), d, width, _, _ = _operands(
+        qkv, heads, heads, blocks.heads_per_step)
+    b, s, _ = do.shape
+    stat = _row_stat_specs(blocks, s, run=True)
+    kv_shape = (b, s, heads * d)
+    dk = jax.ShapeDtypeStruct(kv_shape, k.dtype) if dqkv is None else dqkv
+    return _row_call(
+        _dkv_row_kernel, 'flash_dkv_band', qkv, tables, heads, sm_scale,
+        blocks, window, interpret,
+        (q, q, q, k, v, do, do, do) + (lse,) * 3 + (delta,) * 3,
+        _row_specs(blocks, s, width, q0, run=True)
+        + _row_specs(blocks, s, width, k0) + _row_specs(blocks, s, width, v0)
+        + _row_specs(blocks, s, width, run=True) + stat + stat,
+        _row_specs(blocks, s, width, 0 if dqkv is None else heads * d)
+        + _row_specs(blocks, s, width),
+        [jax.ShapeDtypeStruct(dk.shape, dk.dtype),
+         jax.ShapeDtypeStruct(kv_shape, v.dtype)], into=dqkv)
 
 
 # ---------------------------------------------------------------------------
@@ -1315,8 +1740,11 @@ def _plan(shape, causal, block_q=None, block_k=None, window=None,
     and the heads a grid step holds, in whole lane blocks (with
     ``kv_heads`` fewer than ``h``: query heads of one group)."""
     _, h, s, d = shape
-    return Plan(**{kernel: _blocks(h, d, s, targets, block_q, block_k,
-                                   h // (kv_heads or h))
+    group = h // (kv_heads or h)
+    if _band_form(window, s, group, block_q or block_k) == 'row':
+        return Plan(**{kernel: _rows(h, d, s, window, *targets)
+                       for kernel, targets in _ROW_TARGETS.items()})
+    return Plan(**{kernel: _blocks(h, d, s, targets, block_q, block_k, group)
                    for kernel, targets in
                    _block_targets(s, causal, window).items()})
 
@@ -1329,15 +1757,27 @@ def _plan_tags(plan, seq, causal, window=None):
     one-pass the squares of the live row), those that hold an unmasked
     position and those the causal diagonal crosses. For a band call
     the tiles are the band's: the grid walks no others."""
-    tags = {}
-    for prefix, (bq, bk, g) in zip(('', 'dq_', 'dkv_'), plan):
+    tags = {'band_form': None if window is None else
+            'row' if isinstance(plan.fwd, Rows) else 'tiles'}
+    for prefix, blocks in zip(('', 'dq_', 'dkv_'), plan):
         transposed = prefix == 'dkv_'
-        one_pass = (bq if transposed else bk) == seq
-        tq, tk = bq, bk
-        if causal and one_pass:
-            tq = tk = bk if transposed else bq
-        tiles, live, masked = _tile_counts(seq, tq, tk, causal, window,
-                                           transposed)
+        if isinstance(blocks, Rows):
+            # a step holds ``rows`` of the outer operand and the run
+            # round them; every sub-block is one tile against its own
+            # part of the run, live, and crossed by the band's edges
+            rows, sub, corner, g = blocks
+            (bq, bk), (tq, tk) = ((n, n + 2 * corner) for n in (rows, sub))
+            if transposed:
+                bq, bk, tq, tk = bk, bq, tk, tq
+            one_pass, tiles = True, (seq // sub,) * 3
+        else:
+            bq, bk, g = blocks
+            one_pass = (bq if transposed else bk) == seq
+            tq, tk = bq, bk
+            if causal and one_pass:
+                tq = tk = bk if transposed else bq
+            tiles = _tile_counts(seq, tq, tk, causal, window, transposed)
+        tiles, live, masked = tiles
         tags.update({prefix + 'block_q': bq, prefix + 'block_k': bk,
                      prefix + 'heads_per_step': g,
                      prefix + 'one_pass': one_pass,

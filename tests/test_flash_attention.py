@@ -354,8 +354,14 @@ def test_window_none_is_todays_plan_for_the_cells():
     # the forward's step holds a pair of heads since PR 29: a lane block
     assert fa._plan((4, 16, 8192, 64), False) == fa.Plan(
         B(1024, 1024, 2), B(512, 512, 4), B(512, 512, 4))
+    # ModernBERT's window layers: the row form since PR 40 (rows of a
+    # step, of a sub-block, of a neighbour's corner, heads a step), and
+    # the tiled walk's blocks where block sizes are asked for
+    R = fa.Rows
     assert fa._plan((4, 16, 8192, 64), False, window=(64, 64)) == fa.Plan(
-        B(128, 512, 8), B(256, 256, 8), B(128, 256, 8))
+        R(512, 128, 64, 4), R(1024, 256, 64, 2), R(256, 128, 64, 8))
+    assert fa._block_targets(8192, False, (64, 64)) == {
+        'fwd': (128, 512), 'dq': (256, 256), 'dkv': (128, 256)}
     # a wide band's tiles stop at their kernel's cap, swept on the chip
     # at Mellum2's causal window of 1024 keys (PR 33): the forward's at
     # 1024 a side, the backward kernels' at 256
@@ -459,8 +465,9 @@ def test_qkv_read_from_one_array_is_three_separate_operands(heads, kind):
 
 # Rotary positions inside the kernels (PR 32): ([b, h, s, d], window,
 # blocks or None for the plan's own, rotary base). The one-block path,
-# the multi-block path (online softmax, accumulators in scratch) and a
-# band call, each at 4, 2 and 1 heads to a lane block; two bases.
+# the multi-block path (online softmax, accumulators in scratch), a
+# band call on the tiled walk and one in the row form, each at 4, 2 and
+# 1 heads to a lane block; two bases.
 _ROTARY_CASES = {
     'one_block_d64': ((2, 2, 64, 64), None, None, 10000.0),
     'one_block_d32': ((1, 4, 64, 32), None, None, 160000.0),
@@ -471,6 +478,11 @@ _ROTARY_CASES = {
     'band_d64': ((1, 2, 128, 64), (16, 16), (32, 32), 10000.0),
     'band_d32': ((1, 4, 128, 32), (16, 16), (32, 64), 160000.0),
     'band_d128': ((1, 1, 128, 128), (12, 20), (64, 32), 10000.0),
+    # the plan's own for a narrow band (PR 40): one pass over each row
+    # block's own keys, the run's pieces rotated once each
+    'row_d64': ((1, 2, 256, 64), (64, 64), None, 10000.0),
+    'row_d32': ((1, 4, 256, 32), (16, 48), None, 160000.0),
+    'row_d128': ((1, 1, 256, 128), (128, 128), None, 10000.0),
 }
 
 
@@ -513,8 +525,11 @@ def test_rotary_on_the_tile_is_rotary_before_the_call(case):
     assert [t.shape for t in tables] == [(s, fa._lane_block(h, d))] * 2
     plan = fa._plan((b, h, s, d), False, block_q, block_k, window)
     multi = case.startswith(('multi', 'band'))
-    assert all((s // bq > 1, s // bk > 1) == (multi, multi)
-               for bq, bk, _ in plan)
+    if case.startswith('row'):
+        assert all(isinstance(blocks, fa.Rows) for blocks in plan)
+    else:
+        assert all((s // bq > 1, s // bk > 1) == (multi, multi)
+                   for bq, bk, _ in plan)
 
     # 1. bf16 operands and exact products: the bits of o and lse
     def forward(operands, tables):

@@ -119,7 +119,8 @@ def test_expert_layer_lowers_for_tpu_on_a_sharded_mesh(monkeypatch, spec_kw):
     ((32, 16, 1024, 64), True, 1, None),    # gpt2-medium.s1024.c1
     ((96, 16, 512, 64), False, 4, None),    # bert-large.s512.dp4's shard_map
     # modernbert-large.s8192.c1: a global layer's calls (the multi-block
-    # path), a window layer's (the band path), and the band under dp=4
+    # path), a window layer's (the band in its row form, PR 40), and the
+    # band under dp=4
     ((4, 16, 8192, 64), False, 1, None),
     ((4, 16, 8192, 64), False, 1, (64, 64)),
     ((4, 16, 8192, 64), False, 4, (64, 64)),
@@ -209,20 +210,33 @@ def test_flash_step_is_three_named_kernels(local_shape, causal, dp, window):
     for kernel in ('', 'dq_', 'dkv_'):
         bq, bk = tags[kernel + 'tile_q'], tags[kernel + 'tile_k']
         assert local_shape[1] % tags[kernel + 'heads_per_step'] == 0
-        assert tags[kernel + 'one_pass'] == (
-            tags[kernel + ('block_q' if kernel == 'dkv_' else 'block_k')]
-            == seq)
-        tiles = [allowed[i:i + bq, j:j + bk]
-                 for i in range(0, seq, bq) for j in range(0, seq, bk)]
         if window:
-            # the grid of a band call walks the band: 2 to 4 tiles for
-            # each outer block here, of 16 to 64 in a row of the square
-            outer = seq // (bk if kernel == 'dkv_' else bq)
-            assert tags[kernel + 'tiles'] in (2 * outer, 3 * outer,
-                                              4 * outer)
-            assert tags[kernel + 'tiles'] < len(tiles)
+            # the row form (PR 40): no inner grid dimension; one tile
+            # for each sub-block of the outer operand's rows, against
+            # the run of the inner one's round it (a corner of 64 rows
+            # each side), of 32 to 64 in a row of the square; the tiles
+            # hold every pair of the band between them, and each is
+            # crossed by its edges (or by the sequence's end)
+            assert tags['band_form'] == 'row' and tags[kernel + 'one_pass']
+            transposed = kernel == 'dkv_'
+            size, span = (bk, bq) if transposed else (bq, bk)
+            assert span == size + 2 * 64
+            assert tags[kernel + 'block_' + 'kq'[transposed]] \
+                == tags[kernel + 'block_' + 'qk'[transposed]] + 2 * 64
+            assert tags[kernel + 'block_' + 'qk'[transposed]] % size == 0
+            pairs = np.pad(allowed.T if transposed else allowed,
+                           ((0, 0), (64, 64)))
+            tiles = [pairs[i:i + size, i:i + span]
+                     for i in range(0, seq, size)]
+            assert sum(t.sum() for t in tiles) == allowed.sum()
         else:
-            assert tags[kernel + 'tiles'] == len(tiles)
+            assert tags['band_form'] is None
+            assert tags[kernel + 'one_pass'] == (
+                tags[kernel + ('block_q' if kernel == 'dkv_' else 'block_k')]
+                == seq)
+            tiles = [allowed[i:i + bq, j:j + bk]
+                     for i in range(0, seq, bq) for j in range(0, seq, bk)]
+        assert tags[kernel + 'tiles'] == len(tiles)
         assert tags[kernel + 'live_tiles'] == sum(t.any() for t in tiles)
         assert tags[kernel + 'masked_tiles'] == sum(
             t.any() and not t.all() for t in tiles)
@@ -573,11 +587,23 @@ _KERNEL_MODULES = {
     's4096_causal': ((2, 12, 4096, 64), True, {
         'flash_fwd': '3ab6d97b0522dfbf', 'flash_dq': '2727da750cfd69fe',
         'flash_dkv': 'd0b2442aa14f6839'}),
+    # Mellum2's window layers (PR 33): a causal window of 1024 keys over
+    # 32 query heads on 4 kv heads of 128, rotary inside. Hashed on PR
+    # 40's parent: the row form PR 40 gave a band of ModernBERT's reach
+    # leaves a wide band's tiled walk op for op what it was.
+    's8192_gqa_band': ((4, 32, 8192, 128), True, {
+        'flash_fwd_band': 'f9c1099f1352f2f6',
+        'flash_dq_band': 'b1420991828123a8',
+        'flash_dkv_band': 'c878dd81d61e420a'},
+        dict(window=(1023, 1023), kv_heads=4)),
 }
 
 
 @pytest.mark.parametrize('case', sorted(_KERNEL_MODULES))
 def test_full_call_kernels_are_op_for_op_what_they_were(case):
+    """Every call that does not take a form a PR adds lowers to the
+    Mosaic module it lowered to before: the cells' full calls, and
+    (since PR 40) Mellum2's band call."""
     import base64
     import hashlib
     import re
@@ -589,13 +615,22 @@ def test_full_call_kernels_are_op_for_op_what_they_were(case):
 
     from autodist_tpu.kernels import flash_attention as fa
 
-    shape, causal, want = _KERNEL_MODULES[case]
+    shape, causal, want, *band = _KERNEL_MODULES[case]
     b, h, s, d = shape
+    tables = []
     if case == 's4096_causal':
         def attend(q, k, v):
             return fa.flash_attention(q, k, v, causal=causal,
                                       interpret=False)
         args = [jax.ShapeDtypeStruct(shape, jnp.bfloat16)] * 3
+    elif band:
+        def attend(qkv, *tables):
+            return fa.flash_attention_merged(
+                qkv, h, causal=causal, interpret=False, rotary=tables,
+                **band[0])
+        args = [jax.ShapeDtypeStruct(
+            (b, s, (h + 2 * band[0]['kv_heads']) * d), jnp.bfloat16)]
+        tables = [jax.ShapeDtypeStruct((s, d), jnp.float32)] * 2
     else:
         def attend(qkv):
             return fa.flash_attention_merged(qkv, h, causal=causal,
@@ -604,7 +639,7 @@ def test_full_call_kernels_are_op_for_op_what_they_were(case):
     text = jax.export.export(jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(attend(*a).astype(jnp.float32)),
         argnums=tuple(range(len(args))))), platforms=['tpu'])(
-            *args).mlir_module()
+            *args, *tables).mlir_module()
     context = jax_mlir.make_ir_context()
     context.allow_unregistered_dialects = True
     got = {}
