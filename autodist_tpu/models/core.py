@@ -369,14 +369,59 @@ class RMSNorm(Module):
                 * params['scale']).astype(self.dtype)
 
 
+class GatedGroupRMSNorm(Module):
+    """``RMSNorm(x * silu(gate))`` in f32 with the mean of squares taken
+    over each of ``groups`` groups of ``dim / groups`` lanes, one scale
+    of ``dim``: Mamba-2's gated norm with the gate INSIDE the norm."""
+
+    def __init__(self, dim, groups, axis_name='heads', eps=1e-5,
+                 dtype=jnp.float32):
+        if dim % groups:
+            raise ValueError('%d lanes do not divide over %d groups'
+                             % (dim, groups))
+        self.dim, self.groups, self.axis_name = dim, groups, axis_name
+        self.eps, self.dtype = eps, dtype
+
+    def param_defs(self):
+        return {'scale': ParamDef((self.dim,), (self.axis_name,), 'ones')}
+
+    def _group_mean(self, t):
+        """``t [..., dim]`` with every lane replaced by the mean over its
+        group: a group's run of lanes reduced, and the ``groups`` means
+        put back on their lanes by selects. (Reshaped to ``[..., groups,
+        dim / groups]`` XLA re-tiles the whole f32 tensor, a copy without
+        a name in every pass, 0.27 GB a layer at 16,384 tokens x 4096;
+        the runs concatenated cost a pass over the tensor each.)"""
+        width = self.dim // self.groups
+        lane_group = jnp.arange(self.dim) // width
+        out = jnp.zeros((), t.dtype)
+        for g in range(self.groups):
+            mean = jnp.mean(t[..., g * width:(g + 1) * width], axis=-1,
+                            keepdims=True)
+            out = jnp.where(lane_group == g, mean, out)
+        return out
+
+    def apply(self, params, x, gate):
+        x32 = x.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+        ms = self._group_mean(jnp.square(x32))
+        y = x32 * jax.lax.rsqrt(ms + self.eps)
+        return (y * params['scale']).astype(self.dtype)
+
+
 def gelu_exact(x):
     """The erf GELU (BERT's, ModernBERT's ``"gelu"``); ``jax.nn.gelu``
     alone is the tanh approximation (GPT-2's ``gelu_new``)."""
     return jax.nn.gelu(x, approximate=False)
 
 
+def relu2(x):
+    """``relu(x) ** 2`` (nemotron_h's ``mlp_hidden_act: relu2``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 # An MLP's activation by the name a configuration gives it.
-ACTIVATIONS = {'tanh': jax.nn.gelu, 'erf': gelu_exact, 'silu': jax.nn.silu}
+ACTIVATIONS = {'tanh': jax.nn.gelu, 'erf': gelu_exact, 'silu': jax.nn.silu,
+               'relu2': relu2}
 
 
 def activation(name):

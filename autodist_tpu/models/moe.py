@@ -136,7 +136,7 @@ class MoeMlp(Module):
         self.dim, self.hidden = dim, hidden
         self.scoring, self.select_bias, self.scale = (scoring, select_bias,
                                                       float(scale))
-        self.shared = None
+        self.shared, self.shared_dim = None, int(shared)
         if shared:
             self.shared = GatedMlp(dim, shared, dtype=dtype, act=act) \
                 if gated else Mlp(dim, shared, dtype=dtype, act=act,
@@ -157,7 +157,9 @@ class MoeMlp(Module):
     def param_defs(self):
         # an expert's fan-in is its own dim or hidden, not the stack's;
         # a gated expert's gate (index 0: the activated half) and up
-        # (index 1) lie side by side, as GatedMlp's
+        # (index 1) lie side by side, as GatedMlp's: `up [held, dim, 2,
+        # hidden]`; an expert without a gate (nemotron_h's relu2 ones)
+        # has the one matrix, `up [held, dim, hidden]`
         up = (self.held, self.dim) + ((2,) if self.gated else ()) \
             + (self.hidden,)
         defs = {
@@ -211,7 +213,7 @@ class MoeMlp(Module):
             order = _order(idx - first, weights, held)
         _note_plan(order, d, self.dtype, scoring=self.scoring,
                    bias=bias is not None, scale=self.scale,
-                   shared=self.shared.hidden if self.shared else 0)
+                   shared=self.shared_dim)
         y = _experts(functools.partial(_hidden, self.act, self.gated, hidden),
                      tokens.astype(self.dtype), up.reshape(held, d, -1), down,
                      order['token'], order['weight'], order['tile_group'],
@@ -301,8 +303,9 @@ class MoeMlp(Module):
 
 
 def _hidden(act, gated, f, u):
-    """``h`` of a chunk's first product ``u`` (gate and up side by side
-    for a gated expert), in f32."""
+    """``h`` of a chunk's first product ``u``, in f32: for a gated
+    expert ``u [rows, 2 f]`` holds gate and up side by side and ``h =
+    act(gate) * up``; without a gate ``u [rows, f]`` and ``h = act(u)``."""
     return act(u[:, :f]) * u[:, f:] if gated else act(u)
 
 

@@ -73,7 +73,10 @@ class TransformerConfig:
     # describes the architecture; the defaults are GPT-2's block.
     positions: str = 'learned'   # 'learned': a table of max_len rows
     #                              added to the embedding; 'rotary': none,
-    #                              q and k are rotated in every layer
+    #                              q and k are rotated in every layer;
+    #                              'none': no table and no rotation (a
+    #                              stack whose state-space layers carry
+    #                              the order)
     rope_theta: float = 10000.0  # rotary base of the global layers
     window: object = None        # keys EACH SIDE a window layer attends
     #                              to (ModernBERT's local_attention 128
@@ -134,11 +137,39 @@ class TransformerConfig:
     moe_scale: float = 1.0       # factor on the routed experts' weights
     moe_shared_dim: int = 0      # >0: an always-on expert of this width
     #                              beside the routed ones
+    # -- layers that are ONE mixer each (nemotron_h): with `mixers` layer
+    # i is `x + mixer_i(norm(x))`, its mixer by the i-th of the pattern's
+    # letters (MIXERS): 'M' a Mamba-2 layer (models/ssm.py, the sizes in
+    # `ssm`), 'E' the MLP as the fields above describe it (the expert
+    # layer with moe_experts), '*' attention. None: every layer is the
+    # attention-then-MLP Block.
+    mixers: object = None
+    ssm: object = None           # the Mamba-2 layers' sizes: a mapping
+    #                              with heads, head_dim, groups, state,
+    #                              conv and, optionally, the draw's
+    #                              dt_min, dt_max, dt_floor, a_range
 
     def __post_init__(self):
-        if self.positions not in ('learned', 'rotary'):
-            raise ValueError("positions must be 'learned' or 'rotary', "
-                             'not %r' % (self.positions,))
+        if self.positions not in POSITIONS:
+            raise ValueError('positions must be %s, not %r'
+                             % (' or '.join(map(repr, POSITIONS)),
+                                self.positions))
+        if self.mixers is not None:
+            self.mixers = ''.join(self.mixers)
+            if len(self.mixers) != self.n_layers \
+                    or set(self.mixers) - set(MIXERS):
+                raise ValueError(
+                    'mixers=%r: one of %s for each of the %d layers'
+                    % (self.mixers, ', '.join(map(repr, MIXERS)),
+                       self.n_layers))
+            if 'M' in self.mixers and not self.ssm:
+                raise ValueError("mixers=%r has Mamba-2 layers: give their "
+                                 "sizes in `ssm`" % (self.mixers,))
+            if self.window is not None or self.latent_rank \
+                    or self.dense_lead or self.embed_norm:
+                raise ValueError('single-mixer layers take no window, no '
+                                 'latent attention, no dense_lead and no '
+                                 'embed_norm')
         activation(self.gelu)
         if self.norm not in ('layer', 'rms'):
             raise ValueError("norm must be 'layer' or 'rms', not %r"
@@ -214,6 +245,12 @@ class TransformerConfig:
         return cls(**d)
 
 
+POSITIONS = ('learned', 'rotary', 'none')
+# A single-mixer layer's kinds, by the letter nemotron_h's
+# hybrid_override_pattern gives them, and the scope each runs under.
+MIXERS = {'M': 'ssm', 'E': 'mlp', '*': 'attention'}
+
+
 def _add(a, b):
     """Sum of two ``aux`` values: scalars, or ``(aux, stats)`` pairs."""
     return jax.tree.map(jnp.add, a, b)
@@ -228,6 +265,75 @@ def _norm(cfg):
 
 def _act(cfg):
     return activation(cfg.gelu)
+
+
+def _mlp(cfg, dense=False):
+    """The MLP of a layer as ``cfg`` describes it: the expert layer with
+    ``moe_experts`` (unless ``dense``: a leading dense layer, of
+    ``dense_mlp_dim``), else a gated or a plain MLP."""
+    hidden = cfg.mlp_dim or cfg.dim * cfg.mlp_ratio
+    if dense:
+        hidden = cfg.dense_mlp_dim or hidden
+    if cfg.moe_experts and not dense:
+        from autodist_tpu.models.moe import MoeMlp
+        return MoeMlp(cfg.dim, hidden,
+                      cfg.moe_experts, top_k=cfg.moe_top_k,
+                      held=cfg.held_experts(), dtype=cfg.dtype,
+                      act=_act(cfg), gated=cfg.gated_mlp,
+                      scoring=cfg.moe_scoring,
+                      select_bias=cfg.moe_scoring == 'sigmoid',
+                      scale=cfg.moe_scale,
+                      shared=cfg.moe_shared_dim)
+    if cfg.gated_mlp:
+        return GatedMlp(cfg.dim, hidden, dtype=cfg.dtype,
+                        act=_act(cfg), use_bias=cfg.mlp_bias)
+    return Mlp(cfg.dim, hidden, dtype=cfg.dtype, act=_act(cfg),
+               use_bias=cfg.mlp_bias)
+
+
+class MixerLayer(Module):
+    """A layer that is ONE mixer: ``x + mixer(norm(x))``, the mixer by
+    ``kind`` (:data:`MIXERS`): a Mamba-2 layer, the MLP (the expert
+    layer with ``cfg.moe_experts``) or attention, each under the scope
+    :data:`MIXERS` names. ``apply`` returns what :class:`Block`'s does."""
+
+    def __init__(self, cfg, kind):
+        self.cfg, self.kind = cfg, kind
+        self.norm = _norm(cfg)
+        self.attn = None
+        self.sparse = kind == 'E' and bool(cfg.moe_experts)
+        if kind == 'M':
+            from autodist_tpu.models.ssm import Mamba2Mixer
+            self.mixer = Mamba2Mixer(cfg.dim, dtype=cfg.dtype,
+                                     norm_eps=cfg.norm_eps, **cfg.ssm)
+        elif kind == 'E':
+            self.mixer = _mlp(cfg)
+        else:
+            self.mixer = self.attn = MultiHeadAttention(
+                cfg.dim, cfg.n_heads, head_dim=cfg.head_dim,
+                causal=cfg.causal, dtype=cfg.dtype,
+                rope_theta=cfg.rope_theta if cfg.positions == 'rotary'
+                else None, num_kv_heads=cfg.n_kv_heads,
+                rope_yarn=cfg.rope_yarn)
+
+    def param_defs(self):
+        return {'norm': self.norm, 'mixer': self.mixer}
+
+    @jax.named_scope('block')
+    def apply(self, params, x, tables=None, stats=False):
+        aux = jnp.zeros((), jnp.float32)
+        load = jnp.zeros((2,), jnp.float32)
+        with jax.named_scope(MIXERS[self.kind]):
+            h = self.norm.apply(params['norm'], x)
+            if self.attn is not None:
+                h = self.mixer.apply(params['mixer'], h, tables)
+            else:
+                h = self.mixer.apply(params['mixer'], h)
+            if self.sparse:
+                h, aux, load = h
+            x = x + h
+        return constrain(x, ('batch', 'seq', 'embed')), \
+            (aux, load) if stats else aux
 
 
 class Block(Module):
@@ -263,27 +369,9 @@ class Block(Module):
                 num_kv_heads=cfg.n_kv_heads,
                 rope_yarn=None if windowed else cfg.rope_yarn)
         self.ln2 = _norm(cfg)
-        hidden = cfg.mlp_dim or cfg.dim * cfg.mlp_ratio
         # (a leading dense layer of a stack of expert layers: `dense`)
         self.sparse = bool(cfg.moe_experts) and not dense
-        if dense:
-            hidden = cfg.dense_mlp_dim or hidden
-        if self.sparse:
-            from autodist_tpu.models.moe import MoeMlp
-            self.mlp = MoeMlp(cfg.dim, hidden,
-                              cfg.moe_experts, top_k=cfg.moe_top_k,
-                              held=cfg.held_experts(), dtype=cfg.dtype,
-                              act=_act(cfg), gated=cfg.gated_mlp,
-                              scoring=cfg.moe_scoring,
-                              select_bias=cfg.moe_scoring == 'sigmoid',
-                              scale=cfg.moe_scale,
-                              shared=cfg.moe_shared_dim)
-        elif cfg.gated_mlp:
-            self.mlp = GatedMlp(cfg.dim, hidden, dtype=cfg.dtype,
-                                act=_act(cfg), use_bias=cfg.mlp_bias)
-        else:
-            self.mlp = Mlp(cfg.dim, hidden, dtype=cfg.dtype, act=_act(cfg),
-                           use_bias=cfg.mlp_bias)
+        self.mlp = _mlp(cfg, dense)
 
     def param_defs(self):
         d = {'attn': self.attn, 'ln2': self.ln2, 'mlp': self.mlp}
@@ -359,6 +447,7 @@ class TransformerLM(Module):
         kinds = cfg.layer_kinds()
         # the unrolled layers' blocks, by depth; the scanned ones by kind
         self._lead_blocks = [
+            MixerLayer(cfg, cfg.mixers[i]) if cfg.mixers else
             Block(cfg, kinds[i], attn_norm=not (cfg.embed_norm and i == 0),
                   dense=i < cfg.dense_lead)
             for i in range(cfg.n_layers if not cfg.scan_layers
@@ -376,6 +465,10 @@ class TransformerLM(Module):
         period where the first layer (no attention norm after an
         embedding norm) would else fall inside the scan."""
         cfg = self.cfg
+        if cfg.mixers:
+            # single-mixer layers follow a published string that has no
+            # period: every layer unrolled, under its own name
+            return cfg.n_layers, (), 0
         kinds = cfg.layer_kinds()
         size = cfg.global_every if cfg.window is not None else 1
         lead = cfg.n_layers % size
@@ -413,10 +506,11 @@ class TransformerLM(Module):
         else:
             for i in range(self._lead):
                 d['block_%03d' % i] = self._lead_blocks[i]
-            d['blocks'] = _Kinds({
-                kind: _Stacked(block,
-                               self._periods * self._period.count(kind))
-                for kind, block in self._kind_blocks.items()})
+            if not cfg.mixers:
+                d['blocks'] = _Kinds({
+                    kind: _Stacked(block,
+                                   self._periods * self._period.count(kind))
+                    for kind, block in self._kind_blocks.items()})
         return d
 
     def apply(self, params, tokens):
@@ -478,6 +572,8 @@ class TransformerLM(Module):
 
         def tables(block):
             attn = block.attn
+            if attn is None:             # a layer of another mixer
+                return None
             shape = (b, attn.num_heads, s, attn.head_dim)
             kind = (attn.rope, attn.kernel_shape(shape))
             if kind not in made:
@@ -581,7 +677,7 @@ class TransformerLM(Module):
                 x, a = self._block_fn(block, tables(block), stats)(
                     params['block_%03d' % i], x)
                 aux_total = _add(aux_total, a)
-            if cfg.scan_layers:
+            if cfg.scan_layers and not cfg.mixers:
                 x, aux_total = self._scan_periods(params['blocks'], x,
                                                   aux_total, tables, stats)
         if stats:
@@ -602,11 +698,19 @@ class TransformerLM(Module):
         if active_manual_axes():
             return
         cfg = self.cfg
-        layers = cfg.n_layers - cfg.dense_lead      # the expert layers
+        layers = self._expert_layers()
         rows, largest = load[0] / layers, load[1] / layers
         record_counter('moe_rows_here', rows)
         record_counter('moe_load_max', largest)
         record_counter('moe_load_mean', rows / cfg.held_experts()[1])
+
+    def _expert_layers(self):
+        cfg = self.cfg
+        if not cfg.moe_experts:
+            return 0
+        if cfg.mixers:
+            return cfg.mixers.count('E')
+        return cfg.n_layers - cfg.dense_lead
 
     def _scan_periods(self, stacks, x, aux_total, tables, stats=False):
         """``periods`` scan steps over ``stacks[kind]``, each running one
@@ -654,17 +758,21 @@ class TransformerLM(Module):
         model, one scan step a layer, leaves none."""
         if not self.patterned:
             return
-        kinds = self.cfg.layer_kinds()
+        cfg = self.cfg
+        kinds = cfg.layer_kinds()
+        single = {} if not cfg.mixers else dict(
+            mixers=cfg.mixers, ssm_layers=cfg.mixers.count('M'),
+            mlp_layers=cfg.mixers.count('E'))
         telemetry.get().loop_event(
             'transformer.layers', n_layers=len(kinds),
             period=len(self._period), periods=self._periods,
             remainder=self._lead, pattern='/'.join(self._period),
-            scanned=bool(self.cfg.scan_layers),
-            global_layers=kinds.count('global'),
+            scanned=bool(cfg.scan_layers) and not cfg.mixers,
+            global_layers=cfg.mixers.count('*') if cfg.mixers
+            else kinds.count('global'),
             window_layers=kinds.count('window'),
-            dense_lead=self.cfg.dense_lead,
-            expert_layers=len(kinds) - self.cfg.dense_lead
-            if self.cfg.moe_experts else 0)
+            dense_lead=cfg.dense_lead,
+            expert_layers=self._expert_layers(), **single)
 
     def _note_remat(self, x):
         """One ``transformer.remat`` point event a trace under
@@ -686,7 +794,8 @@ class TransformerLM(Module):
         else:
             blocks = [self.block] * cfg.n_layers
         shapes = [block.attn.kernel_shape(
-            (b, cfg.n_heads, s, block.attn.head_dim)) for block in blocks]
+            (b, cfg.n_heads, s, block.attn.head_dim)) for block in blocks
+            if block.attn is not None]
         kept = [fa.saved_bytes(shape, cfg.dtype, cfg.latent_rank
                                and cfg.v_head_dim)
                 for shape in shapes if shape is not None]

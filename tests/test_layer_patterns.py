@@ -497,3 +497,56 @@ def test_latent_attention_through_the_kernels_is_the_xla_path(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)
     assert 'flash_dkv_mla' in text and 'mla_latent' in text
+
+
+
+# The five older configurations' structures at tiny sizes (BERT's and
+# GPT-2's plain stacks, ModernBERT's periods, Mellum2's causal band,
+# grouped kv heads, YaRN and softmax experts, kanana-2's latent
+# attention, leading dense layer and sigmoid experts with a shared one),
+# each lowered (loss and gradient, bf16) to the StableHLO text it
+# lowered to on PR 41's parent (jax 0.9.0): the single-mixer mechanism
+# was added beside them, and their steps are what they were.
+def _older_configurations():
+    yarn = dict(factor=16.0, original_max_position_embeddings=64,
+                beta_fast=32.0, beta_slow=1.0,
+                attention_factor=1.2772588722239782)
+    half = dict(max_len=64, dtype=jnp.bfloat16, remat=True)
+    sparse = dict(vocab=256, causal=True, tied_embeddings=False,
+                  positions='rotary', gated_mlp=True, gelu='silu',
+                  norm='rms', mlp_bias=False, moe_top_k=2, moe_aux_coef=0.0,
+                  **half)
+    return {
+        'bert': (TransformerConfig.tiny(causal=False, **half),
+                 'b82480e23cf962d4'),
+        'gpt2': (TransformerConfig.tiny(causal=True, **half),
+                 'ac71f1802497965a'),
+        'modernbert': (TransformerConfig.modernbert_large(
+            vocab=256, dim=64, n_layers=4, n_heads=4, window=8, mlp_dim=96,
+            **half), '13d913251b58bc49'),
+        'mellum2': (TransformerConfig(
+            dim=64, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16,
+            rope_theta=500000.0, rope_yarn=yarn, window=7, global_every=4,
+            global_at=3, mlp_dim=32, moe_experts=8, moe_held=4,
+            embed_init_scale=8.0, **sparse), 'cd391cab4e274eba'),
+        'kanana2': (TransformerConfig(
+            dim=32, n_layers=3, n_heads=2, rope_theta=1e6, latent_rank=16,
+            qk_nope_dim=8, qk_rope_dim=4, v_head_dim=6, mlp_dim=16,
+            dense_lead=1, dense_mlp_dim=48, moe_experts=4,
+            moe_scoring='sigmoid', moe_scale=2.448, moe_shared_dim=24,
+            embed_init_scale=16.0, **sparse), 'db644ddb651d39a9'),
+    }
+
+
+@pytest.mark.parametrize('name', ['bert', 'gpt2', 'modernbert', 'mellum2',
+                                  'kanana2'])
+def test_older_configurations_lower_to_the_step_they_had(name):
+    import hashlib
+    cfg, want = _older_configurations()[name]
+    model = TransformerLM(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    tokens = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32)
+              for k in ('tokens', 'targets')}
+    text = jax.jit(jax.value_and_grad(model.loss)).lower(
+        params, tokens).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want

@@ -402,6 +402,105 @@ def test_kanana2_stack_lowers_with_its_kernels_and_nothing_by_head():
                    % (b, s, s) for t in tensors)
 
 
+def test_ssd_scan_lowers_for_tpu_at_the_published_shape(monkeypatch):
+    """The chunked scan's two kernels at Nemotron-3-Nano's shape (PR 41:
+    2 x 8192 tokens, 64 heads of 64 in 8 groups, states of 128, bf16)
+    lower for the TPU with their gradient: Mosaic's layout checks
+    (blocks of ``[128, 8]`` and ``[8, 128]`` for what a head has one
+    number of, products contracted over rows) run without a chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.kernels import ssd_scan as ss
+
+    monkeypatch.setattr(ss, '_interpret_default', lambda: False)
+    b, s, heads, groups = 2, 8192, 64, 8
+    assert ss.supports(s, heads, groups, 64, 128)
+    args = (jax.ShapeDtypeStruct((b, s, heads * 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, s, heads), jnp.float32),
+            jax.ShapeDtypeStruct((heads,), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, groups * 128), jnp.bfloat16),
+            jax.ShapeDtypeStruct((b, s, groups * 128), jnp.bfloat16))
+    text = jax.export.export(jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(ss.ssd_scan(*a, heads, groups).astype(
+            jnp.float32)), argnums=(0, 1, 2, 3, 4))), platforms=['tpu'])(
+                *args).mlir_module()
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    assert sorted(names) == ['ssd_bwd', 'ssd_fwd']
+    calls = [line for line in text.splitlines() if '@tpu_custom_call' in line]
+    forward = next(line for line in calls if 'ssd_fwd' in line)
+    # x, B, C as the projection and the conv leave them; dt and l a
+    # column a head and l a row a head; out: y and the entering states
+    assert ('tensor<2x8192x4096xbf16>, tensor<2x8192x1024xbf16>, '
+            'tensor<2x8192x1024xbf16>, tensor<2x8x8192x8xf32>, '
+            'tensor<2x8x8192x8xf32>, tensor<2x8x8x8192xf32>) -> '
+            '(tensor<2x8192x4096xbf16>, tensor<2x64x4096x128xf32>)') \
+        in forward
+
+
+def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
+    """One layer of each kind of Nemotron-3-Nano's pattern at the
+    published widths (a Mamba-2 layer of 64 heads of 64; relu2 experts
+    of width 1856, 6 of 128 with two held to keep the test light, and a
+    shared expert of 3712; attention of 32 query heads over 2 kv heads
+    of 128 with no positions) under remat=True with its gradient, as it
+    lowers for the TPU (PR 41): the scan's kernels, the flash kernels
+    and the grouped products are there by name; the expert width 1856 =
+    14.5 x 128 lowers as one block of the whole width; nothing is
+    rotated and there is no position table."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.kernels import grouped_matmul as gm
+    from autodist_tpu.kernels import ssd_scan as ss
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    for module in (fa, gm, ss):
+        monkeypatch.setattr(module, '_interpret_default', lambda: False)
+    b, s = 1, 8192
+    cfg = TransformerConfig(
+        vocab=256, dim=2688, n_layers=3, mixers='ME*', n_heads=32,
+        n_kv_heads=2, head_dim=128, max_len=s, causal=True,
+        tied_embeddings=False, dtype=jnp.bfloat16, remat=True,
+        positions='none', mlp_dim=1856, gelu='relu2', norm='rms',
+        norm_eps=1e-5, mlp_bias=False, moe_experts=128, moe_top_k=6,
+        moe_held=2, moe_aux_coef=0.0, moe_scoring='sigmoid', moe_scale=2.5,
+        moe_shared_dim=3712,
+        ssm=dict(heads=64, head_dim=64, groups=8, state=128, conv=4))
+    assert gm._block(1856) == 1856
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(dp=1))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = {name: np.zeros((b, s), np.int32)
+             for name in ('tokens', 'targets')}
+    step = tr._ensure_step(tr._step_key(batch), state, batch)
+    shapes = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        batch, tr.batch_sharding(batch))
+    text = jax.export.export(step, platforms=['tpu'])(
+        state, shapes).mlir_module()
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    assert set(names) == {'ssd_fwd', 'ssd_bwd', 'flash_fwd', 'flash_dq',
+                          'flash_dkv', 'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw',
+                          'moe_combine', 'moe_rows_buffer'}
+    # the scan's forward runs again under the block's checkpoint (nothing
+    # of it is kept by name); the flash forward does not
+    assert (names.count('ssd_fwd'), names.count('ssd_bwd'),
+            names.count('flash_fwd')) == (2, 1, 1)
+    assert 'rotary' not in text and 'pos_embed' not in str(
+        jax.tree.map(lambda a: a.shape, state.params))
+
+
 @pytest.mark.parametrize('carried', [False, True],
                          ids=['alone', 'onto_the_sum'])
 def test_moe_combine_lowers_for_tpu_at_the_cells_shape(carried):
