@@ -17,9 +17,18 @@ bias)::
 
 The recurrence is ``kernels/ssd_scan.py``'s (the chunked form, Pallas
 kernels forward and backward where the shape allows, ``jax.numpy``
-otherwise); everything else here is XLA's, under the scope
-``ssm_mixer``, so that a trace tells the projections, the conv and the
-gate norm from the kernels (``docs/design/observability.md``).
+otherwise). The conv, its SiLU and the split are
+``kernels/ssm_conv.py``'s: a kernel pair that reads xBC where the
+projection left it (by a column offset: nothing is sliced out, nothing
+f32 of activation size is written) and writes x, B and C as the three
+arrays the scan takes; ``jax.numpy`` on a slice of the projection where
+the kernels do not take the shape (``ssm_conv.supports``: channels and
+an offset of whole lane blocks, whole row blocks, four taps). What is
+left to XLA here is the two projections, the step sizes, ``D x`` and the
+gated norm. All of it but the scan's kernels runs under the scope
+``ssm_mixer``, the conv's kernels too, so that a trace tells what the
+layer costs round its scan (``docs/design/observability.md``); each
+trace of the layer leaves its plan as the point event ``ssm.plan``.
 
 The draw: ``dt_bias`` is the inverse softplus of a step size drawn
 log-uniform in ``[dt_min, dt_max]`` and floored at ``dt_floor``;
@@ -37,56 +46,15 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
-from autodist_tpu.kernels import ssd_scan
+from autodist_tpu.kernels import ssd_scan, ssm_conv
+from autodist_tpu.kernels.ssm_conv import causal_conv  # noqa: F401
 from autodist_tpu.models.core import (Dense, GatedGroupRMSNorm, Module,
                                       ParamDef)
 from autodist_tpu.parallel.axes import (active_manual_axes, current_mesh,
                                         manual_axis, shard_map,
                                         unsharded_execution)
-
-
-@jax.custom_vjp
-def causal_conv(x, taps, bias):
-    """The depthwise causal conv of ``x [b, s, c]`` over ``taps [k, c]``
-    as ``k`` shifted products, plus ``bias [c]``, in f32: ``out_t =
-    sum_i taps_i x_{t - (k - 1) + i}``, zeros before the sequence.
-
-    The backward pass is written out: ``dx_t = sum_i taps_i g_{t + (k -
-    1) - i}`` is the same sum of shifted products over the cotangent
-    (zeros after the sequence). Left to autodiff it is ``k`` padded f32
-    copies of the cotangent written and read again, 2.4 GB a layer at
-    16,384 tokens x 6144 channels, in a fusion that carries no scope's
-    name."""
-    return _conv_forward(x, taps, bias)
-
-
-def _shifted_sum(x, taps, offsets, pad):
-    s = x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), pad, (0, 0)))
-    return sum(padded[:, o:o + s] * taps[i] for i, o in enumerate(offsets))
-
-
-def _conv_forward(x, taps, bias):
-    k = taps.shape[0]
-    return _shifted_sum(x, taps, range(k), (k - 1, 0)) + bias
-
-
-def _conv_fwd(x, taps, bias):
-    return _conv_forward(x, taps, bias), (x, taps)
-
-
-def _conv_bwd(res, g):
-    x, taps = res
-    k, s = taps.shape[0], x.shape[1]
-    dx = _shifted_sum(g, taps, range(k - 1, -1, -1), (0, k - 1))
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    d_taps = jnp.stack([jnp.sum(g * padded[:, i:i + s], axis=(0, 1))
-                        for i in range(k)])
-    return dx.astype(x.dtype), d_taps, jnp.sum(g, axis=(0, 1))
-
-
-causal_conv.defvjp(_conv_fwd, _conv_bwd)
 
 
 class Mamba2Mixer(Module):
@@ -146,9 +114,7 @@ class Mamba2Mixer(Module):
         with jax.named_scope('ssm_mixer'):
             zxbcdt = self.w_in.apply(params['in'], u)
             z = zxbcdt[..., :inner]
-            xbc = self._conv(params, zxbcdt[..., inner:inner + self.conv_dim])
-            x = xbc[..., :inner]
-            b, c = jnp.split(xbc[..., inner:], 2, axis=-1)
+            x, b, c = self._conv(params, zxbcdt)
             dt = jax.nn.softplus(
                 zxbcdt[..., inner + self.conv_dim:].astype(jnp.float32)
                 + params['dt_bias'])
@@ -160,10 +126,32 @@ class Mamba2Mixer(Module):
             y = self.norm.apply(params['norm'], y, z)
             return self.w_out.apply(params['out'], y)
 
-    def _conv(self, params, xbc):
-        """``silu(conv(xBC) + b_conv)``, in f32."""
-        out = causal_conv(xbc, params['conv'], params['conv_bias'])
-        return jax.nn.silu(out).astype(self.dtype)
+    def _conv(self, params, zxbcdt):
+        """``x, B, C = split(silu(conv(xBC) + b_conv))`` from the
+        projection's output as it is: ``kernels/ssm_conv.py``'s kernels on
+        its own columns where they take the shape, ``jax.numpy`` on a
+        slice of it otherwise; f32 inside, the model's dtype out. Under a
+        mesh that shards the batch, on each device's batch as
+        :meth:`_scan`. Each trace leaves one ``ssm.plan`` point event."""
+        states = self.groups * self.state
+        widths = (self.inner, states, states)
+        how = ssm_conv.plan(zxbcdt.shape[1], zxbcdt.shape[2], self.inner,
+                            widths, self.conv)
+        telemetry.get().loop_event(
+            'ssm.plan', conv='pallas' if how else 'xla',
+            channels=self.conv_dim, taps=self.conv,
+            block_rows=how.block_rows if how else None,
+            block_lanes=how.tiles if how else None,
+            in_place=how is not None, split_outputs=how is not None)
+
+        def conv(zxbcdt, taps, bias):
+            return ssm_conv.conv_silu(zxbcdt, taps, bias, self.inner, widths)
+        if unsharded_execution():
+            return conv(zxbcdt, params['conv'], params['conv_bias'])
+        rows = P(AXIS_DATA, None, None)
+        return shard_map(conv, current_mesh(), (rows, P(), P()),
+                         (rows, rows, rows))(
+                             zxbcdt, params['conv'], params['conv_bias'])
 
     def _scan(self, x, dt, a, b, c):
         """The scan on device-local data; under a mesh that shards the

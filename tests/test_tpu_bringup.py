@@ -440,14 +440,57 @@ def test_ssd_scan_lowers_for_tpu_at_the_published_shape(monkeypatch):
         in forward
 
 
+def test_ssm_conv_lowers_for_tpu_at_the_published_shape(monkeypatch):
+    """The conv's kernel pair at Nemotron-3-Nano's shape (PR 42: the
+    in-projection's ``[2, 8192, 10304]`` in bf16, 6144 channels from lane
+    4096 as x | B | C, four taps) lowers for the TPU with its gradient:
+    both calls take the projection's output ITSELF (the forward twice:
+    the tile and the halo before it; the backward three times) and
+    nothing of ``[2, 8192, 6144]``; the outputs and the columns'
+    cotangents are the three arrays the scan takes; Mosaic's checks of
+    the sublane rotations and of the blocks at a column offset run
+    without a chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.kernels import ssm_conv as sc
+
+    monkeypatch.setattr(sc, '_interpret_default', lambda: False)
+    widths = (4096, 1024, 1024)
+    assert sc.supports(8192, 10304, 4096, widths, 4)
+    args = (jax.ShapeDtypeStruct((2, 8192, 10304), jnp.bfloat16),
+            jax.ShapeDtypeStruct((4, 6144), jnp.float32),
+            jax.ShapeDtypeStruct((6144,), jnp.float32))
+    text = jax.export.export(jax.jit(jax.value_and_grad(
+        lambda *a: sum(jnp.sum(part.astype(jnp.float32))
+                       for part in sc.conv_silu(*a, 4096, widths)),
+        argnums=(0, 1, 2))), platforms=['tpu'])(*args).mlir_module()
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    assert sorted(names) == ['ssm_conv_bwd', 'ssm_conv_fwd']
+    calls = {name: next(line for line in text.splitlines()
+                        if '@tpu_custom_call' in line and name in line)
+             for name in names}
+    whole = 'tensor<2x8192x10304xbf16>'
+    parts = ', '.join('tensor<2x8192x%dxbf16>' % w for w in widths)
+    assert calls['ssm_conv_fwd'].count(whole) == 2 * 3
+    assert '-> (%s)' % parts in calls['ssm_conv_fwd']
+    assert calls['ssm_conv_bwd'].count(whole) == 3 * 3
+    for w in widths:
+        assert 'tensor<40x%dxf32>' % w in calls['ssm_conv_bwd']
+    assert '8192x6144' not in text
+
+
 def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
     """One layer of each kind of Nemotron-3-Nano's pattern at the
     published widths (a Mamba-2 layer of 64 heads of 64; relu2 experts
     of width 1856, 6 of 128 with two held to keep the test light, and a
     shared expert of 3712; attention of 32 query heads over 2 kv heads
     of 128 with no positions) under remat=True with its gradient, as it
-    lowers for the TPU (PR 41): the scan's kernels, the flash kernels
-    and the grouped products are there by name; the expert width 1856 =
+    lowers for the TPU (PR 41): the scan's kernels, the conv's (PR 42),
+    the flash kernels and the grouped products are there by name; the
+    expert width 1856 =
     14.5 x 128 lowers as one block of the whole width; nothing is
     rotated and there is no position table."""
     import re
@@ -461,11 +504,12 @@ def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
     from autodist_tpu.kernels import flash_attention as fa
     from autodist_tpu.kernels import grouped_matmul as gm
     from autodist_tpu.kernels import ssd_scan as ss
+    from autodist_tpu.kernels import ssm_conv as sc
     from autodist_tpu.models.transformer import (TransformerConfig,
                                                  TransformerLM)
     from autodist_tpu.parallel.axes import ParallelSpec
 
-    for module in (fa, gm, ss):
+    for module in (fa, gm, ss, sc):
         monkeypatch.setattr(module, '_interpret_default', lambda: False)
     b, s = 1, 8192
     cfg = TransformerConfig(
@@ -490,13 +534,16 @@ def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
     text = jax.export.export(step, platforms=['tpu'])(
         state, shapes).mlir_module()
     names = re.findall(r'kernel_name = "(\w+)"', text)
-    assert set(names) == {'ssd_fwd', 'ssd_bwd', 'flash_fwd', 'flash_dq',
+    assert set(names) == {'ssd_fwd', 'ssd_bwd', 'ssm_conv_fwd',
+                          'ssm_conv_bwd', 'flash_fwd', 'flash_dq',
                           'flash_dkv', 'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw',
                           'moe_combine', 'moe_rows_buffer'}
-    # the scan's forward runs again under the block's checkpoint (nothing
-    # of it is kept by name); the flash forward does not
+    # the scan's forward and the conv's run again under the block's
+    # checkpoint (nothing of them is kept by name); the flash forward
+    # does not
     assert (names.count('ssd_fwd'), names.count('ssd_bwd'),
-            names.count('flash_fwd')) == (2, 1, 1)
+            names.count('ssm_conv_fwd'), names.count('ssm_conv_bwd'),
+            names.count('flash_fwd')) == (2, 1, 2, 1, 1)
     assert 'rotary' not in text and 'pos_embed' not in str(
         jax.tree.map(lambda a: a.shape, state.params))
 
