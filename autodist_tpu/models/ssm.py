@@ -23,12 +23,18 @@ projection left it (by a column offset: nothing is sliced out, nothing
 f32 of activation size is written) and writes x, B and C as the three
 arrays the scan takes; ``jax.numpy`` on a slice of the projection where
 the kernels do not take the shape (``ssm_conv.supports``: channels and
-an offset of whole lane blocks, whole row blocks, four taps). What is
-left to XLA here is the two projections, the step sizes, ``D x`` and the
-gated norm. All of it but the scan's kernels runs under the scope
-``ssm_mixer``, the conv's kernels too, so that a trace tells what the
-layer costs round its scan (``docs/design/observability.md``); each
-trace of the layer leaves its plan as the point event ``ssm.plan``.
+an offset of whole lane blocks, whole row blocks, four taps). ``D x``,
+the gate and the group norm are ``kernels/ssm_gate_norm.py``'s: a kernel
+pair that reads ``y``, ``x`` and z's columns of the projection in place
+and hands the out-projection its operand in the model's dtype, nothing
+f32 of activation size between them; ``GatedGroupRMSNorm`` in
+``jax.numpy`` where the kernels do not take the shape
+(``ssm_gate_norm.supports``: groups of whole lane blocks, whole row
+blocks). What is left to XLA here is the two projections and the step
+sizes. All of it but the scan's kernels runs under the scope
+``ssm_mixer``, the other two pairs of kernels too, so that a trace tells
+what the layer costs round its scan (``docs/design/observability.md``);
+each trace of the layer leaves its plan as the point event ``ssm.plan``.
 
 The draw: ``dt_bias`` is the inverse softplus of a step size drawn
 log-uniform in ``[dt_min, dt_max]`` and floored at ``dt_floor``;
@@ -48,7 +54,7 @@ from jax.sharding import PartitionSpec as P
 
 from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
-from autodist_tpu.kernels import ssd_scan, ssm_conv
+from autodist_tpu.kernels import ssd_scan, ssm_conv, ssm_gate_norm
 from autodist_tpu.kernels.ssm_conv import causal_conv  # noqa: F401
 from autodist_tpu.models.core import (Dense, GatedGroupRMSNorm, Module,
                                       ParamDef)
@@ -110,10 +116,10 @@ class Mamba2Mixer(Module):
 
     def apply(self, params, u):
         self._check_layout()
-        inner, heads = self.inner, self.heads
+        inner = self.inner
         with jax.named_scope('ssm_mixer'):
             zxbcdt = self.w_in.apply(params['in'], u)
-            z = zxbcdt[..., :inner]
+            self._record_plan(*zxbcdt.shape[1:])
             x, b, c = self._conv(params, zxbcdt)
             dt = jax.nn.softplus(
                 zxbcdt[..., inner + self.conv_dim:].astype(jnp.float32)
@@ -121,10 +127,26 @@ class Mamba2Mixer(Module):
             a = -jnp.exp(params['a_log'])
         y = self._scan(x, dt, a, b, c)
         with jax.named_scope('ssm_mixer'):
-            skip = jnp.repeat(params['d'], self.head_dim)
-            y = y.astype(jnp.float32) + skip * x.astype(jnp.float32)
-            y = self.norm.apply(params['norm'], y, z)
+            y = self._gate_norm(params, y, x, zxbcdt)
             return self.w_out.apply(params['out'], y)
+
+    def _record_plan(self, seq, width):
+        """One ``ssm.plan`` point event a trace of the layer: how the
+        conv's and the gate norm's kernels walk the projection's output
+        ``[., seq, width]``, or that ``jax.numpy`` does."""
+        states = self.groups * self.state
+        conv = ssm_conv.plan(seq, width, self.inner,
+                             (self.inner, states, states), self.conv)
+        gate = ssm_gate_norm.plan(seq, width, 0, self.inner, self.groups)
+        telemetry.get().loop_event(
+            'ssm.plan', conv='pallas' if conv else 'xla',
+            channels=self.conv_dim, taps=self.conv,
+            block_rows=conv.block_rows if conv else None,
+            block_lanes=conv.tiles if conv else None,
+            in_place=conv is not None, split_outputs=conv is not None,
+            gate_norm='pallas' if gate else 'xla',
+            gate_norm_block_rows=gate.block_rows if gate else None,
+            gate_norm_group_lanes=gate.group_lanes if gate else None)
 
     def _conv(self, params, zxbcdt):
         """``x, B, C = split(silu(conv(xBC) + b_conv))`` from the
@@ -132,17 +154,9 @@ class Mamba2Mixer(Module):
         its own columns where they take the shape, ``jax.numpy`` on a
         slice of it otherwise; f32 inside, the model's dtype out. Under a
         mesh that shards the batch, on each device's batch as
-        :meth:`_scan`. Each trace leaves one ``ssm.plan`` point event."""
+        :meth:`_scan`."""
         states = self.groups * self.state
         widths = (self.inner, states, states)
-        how = ssm_conv.plan(zxbcdt.shape[1], zxbcdt.shape[2], self.inner,
-                            widths, self.conv)
-        telemetry.get().loop_event(
-            'ssm.plan', conv='pallas' if how else 'xla',
-            channels=self.conv_dim, taps=self.conv,
-            block_rows=how.block_rows if how else None,
-            block_lanes=how.tiles if how else None,
-            in_place=how is not None, split_outputs=how is not None)
 
         def conv(zxbcdt, taps, bias):
             return ssm_conv.conv_silu(zxbcdt, taps, bias, self.inner, widths)
@@ -152,6 +166,32 @@ class Mamba2Mixer(Module):
         return shard_map(conv, current_mesh(), (rows, P(), P()),
                          (rows, rows, rows))(
                              zxbcdt, params['conv'], params['conv_bias'])
+
+    def _gate_norm(self, params, y, x, zxbcdt):
+        """``GroupRMSNorm((y + D x) silu(z)) scale`` in the model's dtype,
+        z the projection's first ``inner`` columns:
+        ``kernels/ssm_gate_norm.py``'s kernels on ``y``, ``x`` and those
+        columns in place where they take the shape, else
+        ``GatedGroupRMSNorm`` on a slice; f32 inside either way. Under a
+        mesh that shards the batch, on each device's batch as
+        :meth:`_scan`."""
+        skip = jnp.repeat(params['d'], self.head_dim)
+        scale = params['norm']['scale']
+        if not ssm_gate_norm.supports(*zxbcdt.shape[1:], 0, self.inner,
+                                      self.groups):
+            y = y.astype(jnp.float32) + skip * x.astype(jnp.float32)
+            return self.norm.apply(params['norm'], y,
+                                   zxbcdt[..., :self.inner])
+
+        def gate_norm(y, x, zxbcdt, skip, scale):
+            return ssm_gate_norm.gate_norm(y, x, zxbcdt, skip, scale, 0,
+                                           self.groups, self.norm.eps)
+        if unsharded_execution():
+            return gate_norm(y, x, zxbcdt, skip, scale)
+        rows = P(AXIS_DATA, None, None)
+        return shard_map(gate_norm, current_mesh(),
+                         (rows, rows, rows, P(), P()), rows)(
+                             y, x, zxbcdt, skip, scale)
 
     def _scan(self, x, dt, a, b, c):
         """The scan on device-local data; under a mesh that shards the

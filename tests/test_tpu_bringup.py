@@ -482,6 +482,45 @@ def test_ssm_conv_lowers_for_tpu_at_the_published_shape(monkeypatch):
     assert '8192x6144' not in text
 
 
+def test_ssm_gate_norm_lowers_for_tpu_at_the_published_shape(monkeypatch):
+    """The gate norm's kernel pair at Nemotron-3-Nano's shape (PR 43:
+    ``y`` and ``x [2, 8192, 4096]`` and the in-projection's ``[2, 8192,
+    10304]`` in bf16, z its first 4096 columns, 8 groups of 512 lanes)
+    lowers for the TPU with its gradient: both calls take the
+    projection's output ITSELF and nothing sliced from it; the forward
+    returns one bf16 tensor, the backward ``dy``, ``dx``, ``dz`` and the
+    row blocks' partial sums, nothing f32 of activation size; Mosaic's
+    checks of the blocks at a column offset and of the lane
+    slices inside a group's loop run without a chip."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.kernels import ssm_gate_norm as gn
+
+    monkeypatch.setattr(gn, '_interpret_default', lambda: False)
+    assert gn.supports(8192, 10304, 0, 4096, 8)
+    part = jax.ShapeDtypeStruct((2, 8192, 4096), jnp.bfloat16)
+    lanes = jax.ShapeDtypeStruct((4096,), jnp.float32)
+    args = (part, part, jax.ShapeDtypeStruct((2, 8192, 10304), jnp.bfloat16),
+            lanes, lanes)
+    text = jax.export.export(jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(gn.gate_norm(*a, 0, 8, 1e-5).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))), platforms=['tpu'])(*args).mlir_module()
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    assert sorted(names) == ['ssm_gate_norm_bwd', 'ssm_gate_norm_fwd']
+    calls = {name: next(line for line in text.splitlines()
+                        if '@tpu_custom_call' in line and name in line)
+             for name in names}
+    whole, tile = 'tensor<2x8192x10304xbf16>', 'tensor<2x8192x4096xbf16>'
+    assert (': (%s, %s, %s, tensor<1x4096xf32>, tensor<1x4096xf32>) -> %s'
+            % (tile, tile, whole, tile)) in calls['ssm_gate_norm_fwd']
+    assert calls['ssm_gate_norm_bwd'].count(whole) == 1
+    assert ('-> (%s, %s, %s, tensor<2x8x16x4096xf32>)' % (tile, tile, tile)
+            in calls['ssm_gate_norm_bwd'])
+
+
 def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
     """One layer of each kind of Nemotron-3-Nano's pattern at the
     published widths (a Mamba-2 layer of 64 heads of 64; relu2 experts
@@ -489,7 +528,8 @@ def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
     shared expert of 3712; attention of 32 query heads over 2 kv heads
     of 128 with no positions) under remat=True with its gradient, as it
     lowers for the TPU (PR 41): the scan's kernels, the conv's (PR 42),
-    the flash kernels and the grouped products are there by name; the
+    the gate norm's (PR 43), the flash kernels and the grouped products
+    are there by name; the
     expert width 1856 =
     14.5 x 128 lowers as one block of the whole width; nothing is
     rotated and there is no position table."""
@@ -505,11 +545,12 @@ def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
     from autodist_tpu.kernels import grouped_matmul as gm
     from autodist_tpu.kernels import ssd_scan as ss
     from autodist_tpu.kernels import ssm_conv as sc
+    from autodist_tpu.kernels import ssm_gate_norm as gn
     from autodist_tpu.models.transformer import (TransformerConfig,
                                                  TransformerLM)
     from autodist_tpu.parallel.axes import ParallelSpec
 
-    for module in (fa, gm, ss, sc):
+    for module in (fa, gm, ss, sc, gn):
         monkeypatch.setattr(module, '_interpret_default', lambda: False)
     b, s = 1, 8192
     cfg = TransformerConfig(
@@ -535,15 +576,19 @@ def test_nemotron_h_stack_lowers_with_its_kernels(monkeypatch):
         state, shapes).mlir_module()
     names = re.findall(r'kernel_name = "(\w+)"', text)
     assert set(names) == {'ssd_fwd', 'ssd_bwd', 'ssm_conv_fwd',
-                          'ssm_conv_bwd', 'flash_fwd', 'flash_dq',
+                          'ssm_conv_bwd', 'ssm_gate_norm_fwd',
+                          'ssm_gate_norm_bwd', 'flash_fwd', 'flash_dq',
                           'flash_dkv', 'moe_gmm', 'moe_gmm_dx', 'moe_gmm_dw',
                           'moe_combine', 'moe_rows_buffer'}
-    # the scan's forward and the conv's run again under the block's
-    # checkpoint (nothing of them is kept by name); the flash forward
-    # does not
+    # the scan's forward, the conv's and the gate norm's run again under
+    # the block's checkpoint (nothing of them is kept by name); the
+    # flash forward does not
     assert (names.count('ssd_fwd'), names.count('ssd_bwd'),
             names.count('ssm_conv_fwd'), names.count('ssm_conv_bwd'),
-            names.count('flash_fwd')) == (2, 1, 2, 1, 1)
+            names.count('ssm_gate_norm_fwd'),
+            names.count('ssm_gate_norm_bwd'),
+            names.count('flash_fwd')) == (2, 1, 2, 1, 2, 1, 1)
+    assert '8192x4096xf32' not in text
     assert 'rotary' not in text and 'pos_embed' not in str(
         jax.tree.map(lambda a: a.shape, state.params))
 

@@ -41,13 +41,13 @@ WIDTHS = (4096, 1024, 1024)
 _SWEEP = ((512, 1024), (32, 64, 128), (512, 1024, 2048))  # ROWS, SUB, MAX_TILE
 
 
-def _device_ms(call, *args, repeats=5):
+def device_ms(call, *args, repeats=5):
     """Mean ms a call of every operation ``call`` runs on the chip, by
     the operation's name, without the copy of an operand into the
     layout the call wants."""
     from jax.profiler import ProfileData
     jax.block_until_ready(call(*args))
-    trace_dir = tempfile.mkdtemp(prefix='ssm_conv_bench.')
+    trace_dir = tempfile.mkdtemp(prefix='ssm_bench.')
     jax.profiler.start_trace(trace_dir)
     for _ in range(repeats):
         out = call(*args)
@@ -70,14 +70,15 @@ def _device_ms(call, *args, repeats=5):
     return ops
 
 
-def _kernel_ms(call, *args):
-    """The one Pallas call's ms among what ``call`` runs."""
-    ms, = (ms for name, ms in _device_ms(call, *args).items()
-           if name.startswith('ssm_conv_'))
+def kernel_ms(call, *args, prefix='ssm_conv_'):
+    """The ms of the one Pallas call named ``prefix``... among what
+    ``call`` runs."""
+    ms, = (ms for name, ms in device_ms(call, *args).items()
+           if name.startswith(prefix))
     return ms
 
 
-def _worst(got, want):
+def worst(got, want):
     """Largest ``|got - want|`` as a share of the largest ``|want|``, and
     the L2 distance as a share of ``want``'s norm."""
     got, want = (np.asarray(x, np.float32) for x in (got, want))
@@ -133,8 +134,8 @@ def main():
     x_fwd, x_bwd = _xla()
     want = x_fwd(proj, taps, bias)
     want_proj, want_taps, want_bias = x_bwd(proj, taps, bias, cts)
-    out['xla_ops_ms'] = {'fwd': _device_ms(x_fwd, proj, taps, bias),
-                         'fwd_and_bwd': _device_ms(x_bwd, proj, taps, bias,
+    out['xla_ops_ms'] = {'fwd': device_ms(x_fwd, proj, taps, bias),
+                         'fwd_and_bwd': device_ms(x_bwd, proj, taps, bias,
                                                    cts)}
     out['xla_ms'] = {name: sum(ops.values())
                      for name, ops in out['xla_ops_ms'].items()}
@@ -146,16 +147,16 @@ def main():
         parts = cts if compare else [jnp.concatenate(cts, axis=-1)]
         d_cols, d_taps, d_bias = bwd(proj, taps, bias, parts)
         res = {'plan': how._asdict(),
-               'fwd_ms': _kernel_ms(fwd, proj, taps, bias),
-               'bwd_ms': _kernel_ms(bwd, proj, taps, bias, parts)}
+               'fwd_ms': kernel_ms(fwd, proj, taps, bias),
+               'bwd_ms': kernel_ms(bwd, proj, taps, bias, parts)}
         got = jnp.concatenate(got, axis=-1)
         d_cols = jnp.concatenate(d_cols, axis=-1)
         res['against_xla'] = {
-            'out': _worst(got, jnp.concatenate(want, axis=-1)),
-            'd_cols': _worst(d_cols,
+            'out': worst(got, jnp.concatenate(want, axis=-1)),
+            'd_cols': worst(d_cols,
                              want_proj[..., OFFSET:OFFSET + channels]),
-            'd_taps': _worst(d_taps, want_taps),
-            'd_bias': _worst(d_bias, want_bias)}
+            'd_taps': worst(d_taps, want_taps),
+            'd_bias': worst(d_bias, want_bias)}
         return res
     out['kernels'] = run(WIDTHS, True)
     print('kernels', out['kernels'], flush=True)
