@@ -9,8 +9,8 @@ Three invariants over the whole tree:
    registry, or carry an explicit entry in :data:`ALLOWED_RAW_READS`
    with a reason. A raw read of an undeclared name is a knob with no
    validation, no documentation surface and no forwarding decision —
-   exactly how ``AUTODIST_FUSED_CONV`` and ``AUTODIST_PP_STASH_LIMIT_MB``
-   lived unregistered for several PRs.
+   exactly how ``AUTODIST_PP_STASH_LIMIT_MB`` lived unregistered for
+   several PRs.
 2. **Forwarding** — every ENV member must either ride the
    coordinator's ``_FORWARDED_FLAGS`` (worker-affecting knobs reach
    every launched worker) or appear in :data:`FORWARD_EXEMPT` with the

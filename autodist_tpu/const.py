@@ -216,12 +216,6 @@ class ENV(Enum):
     # copied like the strategy) because env assignments ride the remote
     # command line, which is world-readable in `ps` on the worker host.
     AUTODIST_COORD_TOKEN_FILE = (lambda v: v if v else '',)
-    # opt-in space-to-depth stem transform for narrow-channel stride-2
-    # stem convs (measured neutral on v5e — BASELINE.md round-5; kept
-    # for TPU generations where stems bind). Forwarded to launched
-    # workers (coordinator _FORWARDED_FLAGS) so every traced host
-    # agrees — divergent HLO across SPMD hosts deadlocks.
-    AUTODIST_S2D_STEM = (lambda v: (v == 'True' or v == '1'),)
     # byte cap for fused gradient all-reduce buckets (0 = derive from
     # the strategy's chunk_size; see const.BUCKET_BYTES_PER_CHUNK).
     AUTODIST_BUCKET_BYTES = (lambda v: int(v) if v else 0,)
@@ -369,29 +363,12 @@ class ENV(Enum):
                                    5.0),)
     AUTODIST_SWAP_MAX_RETRIES = \
         (lambda v: _min_int('AUTODIST_SWAP_MAX_RETRIES', v, 3, lo=0),)
-    # opt-in DenseNet dense-block form: preallocated buffer +
-    # dynamic-update-slice instead of per-layer concat (O(L) vs O(L^2)
-    # copy traffic; exactness tested, on-chip A/B pending — see
-    # BASELINE.md). Forwarded like the other tracing flags: divergent
-    # HLO across SPMD hosts deadlocks.
-    AUTODIST_DENSENET_DUS = (lambda v: (v == 'True' or v == '1'),)
-    # opt-in fused conv+BN Pallas kernel (models/vision.py; measured
-    # neutral-to-negative on v5e, BASELINE.md round-6 — kept for TPU
-    # generations where the BN passes bind) and its row-count ceiling
-    # (huge early-stage activations pay more in layout-conversion
-    # copies than the fused kernel saves). Forwarded like the other
-    # tracing flags: the kernel choice is part of the traced program,
-    # and divergent HLO across SPMD hosts deadlocks.
-    AUTODIST_FUSED_CONV = (lambda v: (v == 'True' or v == '1'),)
-    # row ceiling for the fused kernel; 0 = no limit (validated >= 0)
-    AUTODIST_FUSED_CONV_MAX_ROWS = \
-        (lambda v: _min_int('AUTODIST_FUSED_CONV_MAX_ROWS', v, 120000,
-                            lo=0),)
     # pipeline-parallel 1F1B variant='auto' threshold (parallel/
     # pipeline.py): stash (keep boundary activations) when the stash
     # fits under this many MiB, else remat. The variant is part of the
-    # traced program, so every pipeline host must agree — forwarded
-    # like the other tracing flags.
+    # traced program, so every pipeline host must agree (divergent HLO
+    # across SPMD hosts deadlocks) — forwarded to launched workers
+    # (coordinator _FORWARDED_FLAGS).
     AUTODIST_PP_STASH_LIMIT_MB = \
         (lambda v: _positive_float('AUTODIST_PP_STASH_LIMIT_MB', v,
                                    2048.0),)

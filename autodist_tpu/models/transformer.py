@@ -822,8 +822,7 @@ class TransformerLM(Module):
         targets = batch['targets']
         pipe_axis = manual_axis(AXIS_PIPELINE)
         if pipe_axis is not None and \
-                ctx_option('pp_schedule', 'gpipe') == '1f1b' and \
-                ctx_option('pp_variant', 'auto') != 'legacy':
+                ctx_option('pp_schedule', 'gpipe') == '1f1b':
             return self._loss_1f1b(params, batch, pipe_axis)
         x, aux = self.hidden_with_aux(params, batch['tokens'])
         b, s = targets.shape

@@ -21,75 +21,6 @@ import jax.numpy as jnp
 from autodist_tpu.models.core import Dense, Module, ParamDef
 
 
-def _s2d_stem_enabled():
-    """Opt-in gate for the space-to-depth stem transform
-    (``AUTODIST_S2D_STEM=1``). Default OFF: the round-5 A/B measured it
-    NEUTRAL on v5e for ResNet-101/DenseNet-121 and ~1% slower for
-    InceptionV3 (BASELINE.md round-5 s2d section) — XLA's conv emitter
-    already handles the narrow stem; the family's MFU gap lives in the
-    wide mid-network convs, not the one stem conv (~0.5% of FLOPs)."""
-    from autodist_tpu.const import ENV
-    return ENV.AUTODIST_S2D_STEM.val
-
-
-def _densenet_dus_enabled():
-    """Opt-in gate for the DenseNet buffer/dynamic-update-slice block
-    form (``AUTODIST_DENSENET_DUS=1``); see DenseNet._apply_dus."""
-    from autodist_tpu.const import ENV
-    return ENV.AUTODIST_DENSENET_DUS.val
-
-
-def space_to_depth_conv(x, kernel, stride=2, padding='SAME'):
-    """Stride-2 conv computed in space-to-depth form.
-
-    The classic TPU stem trick (MLPerf ResNet): a k×k stride-2 conv on
-    a narrow-channel input (C=3 pads to 128 MXU lanes, wasting ~97% of
-    the systolic array's contraction dim) is numerically IDENTICAL to a
-    ceil(k/2)×ceil(k/2) stride-1 conv on the 2×2-space-to-depth'd input
-    (C→4C) with correspondingly rearranged weights — same dot products,
-    4× wider contraction, 4× fewer input spatial positions. This is a
-    graph-level rewrite: XLA still emits a plain convolution, no custom
-    kernel, no layout pinning (the round-4 Pallas lesson).
-
-    ``kernel`` is the ORIGINAL [kh, kw, C, O] weights (param shape
-    unchanged — checkpoints and init are oblivious); stride must be 2
-    (the stem case), padding 'SAME' or 'VALID'.
-    """
-    assert stride == 2 and padding in ('SAME', 'VALID')
-    n, h, w, c = x.shape
-    kh, kw, _, o = kernel.shape
-    if padding == 'SAME':
-        out_h, out_w = -(-h // 2), -(-w // 2)
-        pl_h = max((out_h - 1) * 2 + kh - h, 0) // 2
-        pl_w = max((out_w - 1) * 2 + kw - w, 0) // 2
-    else:
-        out_h, out_w = (h - kh) // 2 + 1, (w - kw) // 2 + 1
-        pl_h = pl_w = 0
-    # kernel zero-padded to even extents (zero taps read zero-padded
-    # input — output unchanged); input padded (or cropped: VALID may
-    # discard a tail row the strided windows never covered) so one
-    # VALID pass covers exactly the original window set
-    kh2, kw2 = -(-kh // 2) * 2, -(-kw // 2) * 2
-    in_h, in_w = (out_h - 1) * 2 + kh2, (out_w - 1) * 2 + kw2
-    if in_h - pl_h < h:
-        x = x[:, :in_h - pl_h]
-    if in_w - pl_w < w:
-        x = x[:, :, :in_w - pl_w]
-    x = jnp.pad(x, ((0, 0), (pl_h, max(in_h - x.shape[1] - pl_h, 0)),
-                    (pl_w, max(in_w - x.shape[2] - pl_w, 0)), (0, 0)))
-    k = jnp.pad(kernel, ((0, kh2 - kh), (0, kw2 - kw), (0, 0), (0, 0)))
-    # space-to-depth both operands with matching block order
-    x = x.reshape(n, in_h // 2, 2, in_w // 2, 2, c)
-    x = x.transpose(0, 1, 3, 2, 4, 5).reshape(
-        n, in_h // 2, in_w // 2, 4 * c)
-    k = k.reshape(kh2 // 2, 2, kw2 // 2, 2, c, o)
-    k = k.transpose(0, 2, 1, 3, 4, 5).reshape(
-        kh2 // 2, kw2 // 2, 4 * c, o)
-    return jax.lax.conv_general_dilated(
-        x, k, window_strides=(1, 1), padding='VALID',
-        dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-
-
 class Conv(Module):
     """NHWC conv, HWIO kernel."""
 
@@ -112,20 +43,11 @@ class Conv(Module):
         return d
 
     def apply(self, params, x):
-        if (self.stride == (2, 2) and
-                self.padding in ('SAME', 'VALID') and
-                self.in_ch <= 4 and _s2d_stem_enabled()):
-            # narrow-channel stride-2 stem: space-to-depth form (same
-            # numbers, MXU-friendlier — see space_to_depth_conv)
-            y = space_to_depth_conv(x.astype(self.dtype),
-                                    params['kernel'].astype(self.dtype),
-                                    padding=self.padding)
-        else:
-            y = jax.lax.conv_general_dilated(
-                x.astype(self.dtype),
-                params['kernel'].astype(self.dtype),
-                window_strides=self.stride, padding=self.padding,
-                dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
+        y = jax.lax.conv_general_dilated(
+            x.astype(self.dtype),
+            params['kernel'].astype(self.dtype),
+            window_strides=self.stride, padding=self.padding,
+            dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
         if self.use_bias:
             y = y + params['bias'].astype(self.dtype)
         return y
@@ -154,39 +76,27 @@ class BatchNorm(Module):
                 'ema_var': ParamDef((self.ch,), (None,), 'ones',
                                     trainable=False)}
 
-    def coeffs_from_moments(self, params, mean, m2):
-        """Folded normalize+affine coefficients (a, b) from first/second
-        raw moments — the moments may come from an XLA reduce over the
-        activation OR from the fused conv kernel's epilogue sums
-        (kernels/conv_bn.py), which cost zero extra HBM traffic.
-        Records the EMA state updates (training mode)."""
-        from autodist_tpu.models.core import record_state_update
-        var = jnp.maximum(m2 - jnp.square(mean), 0.0)
-        m = self.momentum
-        record_state_update(
-            self, 'ema_mean', m * params['ema_mean'] + (1 - m) * mean)
-        record_state_update(
-            self, 'ema_var', m * params['ema_var'] + (1 - m) * var)
-        a = params['scale'] * jax.lax.rsqrt(var + self.eps)
-        b = params['bias'] - mean * a
-        return a, b
-
     def coeffs(self, params, x):
-        """(a, b) such that the normalized output is ``x*a + b``."""
-        from autodist_tpu.models.core import is_training
+        """(a, b) such that the normalized output is ``x*a + b``; in
+        training mode also records the EMA state updates."""
+        from autodist_tpu.models.core import (is_training,
+                                              record_state_update)
         if is_training():
-            # fused-BN formulation: one pass of f32-ACCUMULATED moments
-            # (E[x], E[x^2]); the f32 convert fuses into the reduces, so
-            # no [B,H,W,C] f32 temporary hits HBM. (The profile shows
-            # XLA emits these as multi-output reduce fusions already; a
-            # custom variadic-reduce variant — kernels/batch_norm.py
-            # moments() — measured neutral-to-slower, see apply().)
+            # one pass of f32-ACCUMULATED moments (E[x], E[x^2]): the f32
+            # convert fuses into the reduces, and XLA already emits them
+            # as one multi-output reduce fusion, so no kernel is here.
             xf = x.astype(jnp.float32)
             mean = jnp.mean(xf, axis=(0, 1, 2))
             m2 = jnp.mean(jnp.square(xf), axis=(0, 1, 2))
-            return self.coeffs_from_moments(params, mean, m2)
-        mean = params['ema_mean']
-        var = params['ema_var']
+            var = jnp.maximum(m2 - jnp.square(mean), 0.0)
+            m = self.momentum
+            record_state_update(
+                self, 'ema_mean', m * params['ema_mean'] + (1 - m) * mean)
+            record_state_update(
+                self, 'ema_var', m * params['ema_var'] + (1 - m) * var)
+        else:
+            mean = params['ema_mean']
+            var = params['ema_var']
         a = params['scale'] * jax.lax.rsqrt(var + self.eps)
         b = params['bias'] - mean * a
         return a, b
@@ -195,18 +105,9 @@ class BatchNorm(Module):
         # normalize+affine folded to one per-channel multiply-add: the
         # [C]-vector coefficients are computed in f32, the elementwise
         # pass over the activations reads and writes the model dtype
-        # (bf16 on TPU).
-        #
-        # Round-4 measurement note: a fully hand-scheduled BN
-        # (kernels/batch_norm.py batch_norm_train: variadic one-pass
-        # moments + closed-form two-pass backward) was built and is
-        # numerically exact, but benches SLIGHTLY SLOWER here (v5e
-        # ResNet-101 train 180 ms vs 174 ms, fwd 66 vs 55) — the
-        # per-op profile shows XLA already emits multi-output
-        # reduce+elementwise fusions for this formulation (one pass
-        # computing dbeta, dgamma AND dx), and the custom_vjp boundary
-        # blocks some cross-op fusion. Kept as an opt-in building
-        # block; this graph-level form stays the default.
+        # (bf16 on TPU). XLA fuses this form's backward into one pass
+        # (dbeta, dgamma and dx) and a custom_vjp boundary blocks that
+        # fusion, so no hand-scheduled BN is here.
         a, b = self.coeffs(params, x)
         y = x.astype(self.dtype) * a.astype(self.dtype) + \
             b.astype(self.dtype)
@@ -230,84 +131,6 @@ def global_avg_pool(x):
     return jnp.mean(x, axis=(1, 2))
 
 
-def _fused_conv_enabled():
-    """Fused-pointwise dispatch gate: '1' opts in to the Pallas
-    conv+BN kernel (interpret mode on CPU — the test tier); default
-    OFF. Measured on v5e (ResNet-101, batch 256): the kernel's MXU
-    throughput is fine late-stage, but Pallas pins its operands to
-    default tiled layouts, and the layout-conversion copies at every
-    kernel boundary cost more than the saved BN passes (train step
-    241 ms gated / 317 ms ungated vs 174 ms without the kernel; the
-    per-op profile shows XLA already emits the BN statistics and
-    backward as single multi-output fusions, so there was less to save
-    than the fusion names suggested). Full measurement notes in
-    BASELINE.md."""
-    from autodist_tpu.const import ENV
-    return ENV.AUTODIST_FUSED_CONV.val
-
-
-def _fused_max_rows():
-    """Row-count ceiling for the fused kernel (0 = no limit). Pallas
-    forces default tiled layouts on its operands, so every kernel call
-    pays layout-conversion copies at its boundaries; on the huge
-    early-stage activations those copies outweigh the saved BN passes
-    (measured on v5e), while late stages win. Tunable for benchmarking."""
-    from autodist_tpu.const import ENV
-    return ENV.AUTODIST_FUSED_CONV_MAX_ROWS.val
-
-
-def _fused_pointwise_ok(conv, x):
-    from autodist_tpu.kernels import conv_bn as cb
-    if conv.kernel != (1, 1) or conv.use_bias:
-        return False
-    sh, sw = conv.stride
-    if sh != sw:   # fused_pointwise subsamples both dims by one stride
-        return False
-    b, h, w, _ = x.shape
-    h, w = -(-h // sh), -(-w // sw)
-    rows = b * h * w
-    limit = _fused_max_rows()
-    if limit and rows > limit:
-        return False
-    return cb.supports(rows, conv.in_ch, conv.out_ch)
-
-
-def _fold(y, a, b, dt, relu=False, add=None):
-    """The deferred BN epilogue ``relu?(y*a + b (+ add))`` as one
-    elementwise pass in the model dtype (single definition for every
-    fused call site)."""
-    out = y.astype(dt) * a.astype(dt) + b.astype(dt)
-    if add is not None:
-        out = out + add
-    return jax.nn.relu(out) if relu else out
-
-
-def _pointwise_raw_coeffs(conv, bn, conv_params, bn_params, x,
-                          prologue=None):
-    """Fused 1x1 conv via the Pallas kernel: RAW conv output + the
-    FOLLOWING BatchNorm's folded (a, b). ``prologue=(scale, bias,
-    relu?)`` is the PREVIOUS BN's fold, applied on the way into the
-    MXU. Moments come from the kernel epilogue (training) or the EMAs
-    (eval). Shared by ConvBn.raw_coeffs and DenseLayer (one place to
-    fix the stats fold)."""
-    from autodist_tpu.models.core import is_training
-    from autodist_tpu.kernels.conv_bn import fused_pointwise
-    training = is_training()
-    kern = conv_params['kernel'].reshape(conv.in_ch, conv.out_ch)
-    scale, bias, prelu = (None, None, False) if prologue is None \
-        else prologue
-    y, s1, s2 = fused_pointwise(
-        x.astype(conv.dtype), kern, scale=scale, bias=bias,
-        prologue_relu=prelu, want_stats=training,
-        stride=conv.stride[0])
-    if training:
-        n = y.shape[0] * y.shape[1] * y.shape[2]
-        a, b = bn.coeffs_from_moments(bn_params, s1 / n, s2 / n)
-    else:
-        a, b = bn.coeffs(bn_params, y)
-    return y, (a, b)
-
-
 class ConvBn(Module):
     """conv + BN + optional relu — the CNN workhorse."""
 
@@ -322,35 +145,9 @@ class ConvBn(Module):
         return {'conv': self.conv, 'bn': self.bn}
 
     def apply(self, params, x):
-        if _fused_conv_enabled() and _fused_pointwise_ok(self.conv, x):
-            # standalone fused form: the BN stats come from the MXU
-            # epilogue (no stats pass); normalize+relu is one
-            # elementwise pass (XLA-fused)
-            y, (a, b) = self.raw_coeffs(params, x)
-            return _fold(y, a, b, self.conv.dtype, relu=self.relu)
         y = self.bn.apply(params['bn'],
                           self.conv.apply(params['conv'], x))
         return jax.nn.relu(y) if self.relu else y
-
-    # -- fused (deferred-normalize) protocol ------------------------------
-    # raw_coeffs returns the RAW conv output plus this BN's folded
-    # (a, b): the caller applies ``relu?(y*a + b)`` itself — usually by
-    # folding it into the NEXT conv's prologue, so the normalize pass
-    # never touches HBM (kernels/conv_bn.py design note).
-    def raw_coeffs(self, params, x, prologue=None):
-        """``(y_raw, (a, b))``. ``prologue=(scale, bias, relu?)`` is the
-        PREVIOUS BN's fold, applied to ``x`` on the way in. 1x1 convs
-        ride the Pallas fused kernel (BN moments from the epilogue);
-        others take the XLA conv + reduce path."""
-        if _fused_pointwise_ok(self.conv, x):
-            return _pointwise_raw_coeffs(self.conv, self.bn,
-                                         params['conv'], params['bn'],
-                                         x, prologue)
-        if prologue is not None:
-            scale, bias, prelu = prologue
-            x = _fold(x, scale, bias, self.conv.dtype, relu=prelu)
-        y = self.conv.apply(params['conv'], x)
-        return y, self.bn.coeffs(params['bn'], y)
 
 
 # ---------------------------------------------------------------------------
@@ -378,34 +175,11 @@ class Bottleneck(Module):
         return d
 
     def apply(self, params, x):
-        if _fused_conv_enabled() and \
-                _fused_pointwise_ok(self.a.conv, x):
-            return self._apply_fused(params, x)
         sc = x if self.proj is None else self.proj.apply(params['proj'], x)
         y = self.a.apply(params['a'], x)
         y = self.b.apply(params['b'], y)
         y = self.c.apply(params['c'], y)
         return jax.nn.relu(y + sc)
-
-    def _apply_fused(self, params, x):
-        """Bandwidth-lean bottleneck (kernels/conv_bn.py): the two 1x1
-        convs ride the Pallas fused kernel — their BN moments come from
-        the MXU epilogue (no stats pass over the activations) and bn2's
-        normalize+ReLU folds into conv-c's prologue (no apply pass).
-        Remaining full-tensor passes: bn1 apply into the 3x3's input,
-        bn2's stats reduce, and ONE residual-add epilogue."""
-        dt = self.a.conv.dtype
-        y1, (a1, b1) = self.a.raw_coeffs(params['a'], x)
-        y1n = _fold(y1, a1, b1, dt, relu=True)
-        y2, (a2, b2) = self.b.raw_coeffs(params['b'], y1n)
-        y3, (a3, b3) = self.c.raw_coeffs(params['c'], y2,
-                                         prologue=(a2, b2, True))
-        if self.proj is None:
-            sc = x.astype(dt)
-        else:
-            ysc, (asc, bsc) = self.proj.raw_coeffs(params['proj'], x)
-            sc = _fold(ysc, asc, bsc, dt)
-        return _fold(y3, a3, b3, dt, relu=True, add=sc)
 
 
 class ResNet(Module):
@@ -537,26 +311,12 @@ class DenseLayer(Module):
         return {'bn1': self.bn1, 'conv1': self.conv1,
                 'bn2': self.bn2, 'conv2': self.conv2}
 
-    def growth_out(self, params, x):
-        """The layer's NEW features only ([..., growth] — no concat):
-        the caller decides how to append them (concat, or a
-        dynamic-update-slice into a preallocated block buffer)."""
-        if _fused_conv_enabled() and _fused_pointwise_ok(self.conv1, x):
-            dt = self.conv1.dtype
-            a1, b1 = self.bn1.coeffs(params['bn1'], x)
-            y, (a2, b2) = _pointwise_raw_coeffs(
-                self.conv1, self.bn2, params['conv1'], params['bn2'], x,
-                prologue=(a1, b1, True))
-            yn = _fold(y, a2, b2, dt, relu=True)
-            return self.conv2.apply(params['conv2'], yn)
+    def apply(self, params, x):
         y = self.conv1.apply(params['conv1'], jax.nn.relu(
             self.bn1.apply(params['bn1'], x)))
-        return self.conv2.apply(params['conv2'], jax.nn.relu(
+        y = self.conv2.apply(params['conv2'], jax.nn.relu(
             self.bn2.apply(params['bn2'], y)))
-
-    def apply(self, params, x):
-        return jnp.concatenate([x, self.growth_out(params, x)],
-                               axis=-1)
+        return jnp.concatenate([x, y], axis=-1)
 
 
 class DenseNet(Module):
@@ -593,63 +353,10 @@ class DenseNet(Module):
     def apply(self, params, x):
         y = self.stem.apply(params['stem'], x)
         y = max_pool(y, 3, 2)
-        if _densenet_dus_enabled():
-            return self._apply_dus(params, y)
         for i, (kind, m) in enumerate(self.layers):
             y = m.apply(params['layer_%03d' % i], y)
             if kind == 'trans':
                 y = avg_pool(y, 2, 2, 'VALID')
-        y = jax.nn.relu(self.bn_f.apply(params['bn_f'], y))
-        y = global_avg_pool(y)
-        return self.head.apply(params['head'], y).astype(jnp.float32)
-
-    def _apply_dus(self, params, y):
-        """Dense blocks via a preallocated buffer + dynamic-update-slice
-        (AUTODIST_DENSENET_DUS=1): per layer only the ``growth`` new
-        channels are WRITTEN, where the concat form rewrites the whole
-        accumulated feature map — O(L) vs O(L^2) copy traffic per
-        block. Numerically identical (buffer[..., :ch] == the concat
-        prefix at every step; reads are unavoidable either way)."""
-        i = 0
-        n = len(self.layers)
-        while i < n:
-            kind, m = self.layers[i]
-            if kind == 'trans':
-                y = m.apply(params['layer_%03d' % i], y)
-                y = avg_pool(y, 2, 2, 'VALID')
-                i += 1
-                continue
-            # a run of dense layers: preallocate the block's final width
-            run = 0
-            while i + run < n and self.layers[i + run][0] == 'dense':
-                run += 1
-            ch = y.shape[-1]
-            growth = self.layers[i][1].conv2.out_ch
-            # the buffer is sized from the FIRST layer's growth; a
-            # heterogeneous-growth block would silently clamp later
-            # layers' writes into a too-small buffer — refuse instead
-            growths = [self.layers[i + j][1].conv2.out_ch
-                       for j in range(run)]
-            if any(g != growth for g in growths):
-                raise ValueError(
-                    'AUTODIST_DENSENET_DUS requires every dense layer '
-                    'in a block to share conv2.out_ch (growth); got %s '
-                    'for layers %d..%d — use the concat form for '
-                    'heterogeneous growth' % (growths, i, i + run - 1))
-            buf = jnp.zeros(y.shape[:-1] + (ch + growth * run,),
-                            y.dtype)
-            buf = jax.lax.dynamic_update_slice_in_dim(
-                buf, y, 0, axis=-1)
-            for j in range(run):
-                _, layer = self.layers[i + j]
-                x_in = jax.lax.slice_in_dim(buf, 0, ch, axis=-1)
-                new = layer.growth_out(
-                    params['layer_%03d' % (i + j)], x_in)
-                buf = jax.lax.dynamic_update_slice_in_dim(
-                    buf, new.astype(buf.dtype), ch, axis=-1)
-                ch += growth
-            y = buf
-            i += run
         y = jax.nn.relu(self.bn_f.apply(params['bn_f'], y))
         y = global_avg_pool(y)
         return self.head.apply(params['head'], y).astype(jnp.float32)

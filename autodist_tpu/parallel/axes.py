@@ -74,11 +74,7 @@ class ParallelSpec:
     # fused-1F1B backward variant: 'remat' (pp-bounded activation
     # stash, ~3 fwd passes), 'stash' (one boundary activation per
     # microbatch, ~2 fwd passes), 'auto' (stash while it fits
-    # AUTODIST_PP_STASH_LIMIT_MB per rank), 'legacy'
-    # (autodiff-through-the-schedule: zero recompute but GPipe-class
-    # memory — full-batch head/tail + all M+pp-1 step residuals live at
-    # the boundary; measured SLOWEST wall in the BASELINE.md round-5
-    # table, kept for A/B comparison)
+    # AUTODIST_PP_STASH_LIMIT_MB per rank)
     pp_variant: str = 'auto'
     sp_mode: str = 'ring'          # 'ring' | 'ulysses' (sp>1 attention)
     grad_accum: int = 1            # gradient-accumulation chunks

@@ -13,11 +13,10 @@ Two schedules:
   reverse scan. Simple and composes with anything, but the backward
   starts only after every microbatch's forward: all ``M`` microbatches'
   residuals are live at the fwd/bwd boundary (the GPipe memory profile).
-- :func:`one_f_one_b` with ``tail_params`` — a REAL 1F1B: a
-  ``jax.custom_vjp`` with a hand-written interleaved backward. The
-  head/loss folds into the last stage (``tail_fn``) and the embedding
-  into the first (``head_fn``). Two variants of the backward
-  (``variant=``, default ``'auto'``):
+- :func:`one_f_one_b` — a REAL 1F1B: a ``jax.custom_vjp`` with a
+  hand-written interleaved backward. The head/loss folds into the last
+  stage (``tail_fn``) and the embedding into the first (``head_fn``).
+  Two variants of the backward (``variant=``, default ``'auto'``):
 
   * ``'remat'`` — the forward saves NO activations; the backward
     re-runs the forward chain and interleaves one recompute-vjp per
@@ -93,15 +92,6 @@ def _inject(own, reg, t, share, pp):
 def _back_rotation(pp):
     """Full backward rotation (toward stage 0): one relay hop/step."""
     return [(i, (i - 1) % pp) for i in range(pp)]
-
-
-def _reassemble(own_out, axis_name, pp, share, mb, M, B):
-    """all_gather each rank's owned outputs and restore microbatch
-    order j = slot*pp + rank; slice off residency padding."""
-    gathered = lax.all_gather(own_out, axis_name)   # [pp, share, mb,...]
-    out = jnp.moveaxis(gathered, 0, 1)              # [share, pp, mb,...]
-    out = out.reshape((share * pp * mb,) + out.shape[3:])
-    return out[:B]
 
 
 def _scatter_own(own_out, rank, pp, share, mb, B):
@@ -209,27 +199,19 @@ def one_f_one_b(block_fn, stacked_params, x, axis_name, microbatches,
 
     Same fill/steady/drain forward timing as :func:`gpipe` (the forward
     bubble is inherent); the memory contract differs — full-batch
-    activations never live across the schedule. Two modes:
-
-    - **fused (pass ``tail_params``)** — the real 1F1B: a custom-vjp
-      with a hand-written interleaved backward (see the module
-      docstring for the ``variant`` trade: ``'remat'`` bounds each
-      rank's live activations at a ``2(pp-1)+1``-slot circular stash,
-      ``'stash'`` saves one boundary activation per microbatch and
-      skips the chain re-forward, ``'auto'`` picks ``'stash'`` while
-      it fits ``AUTODIST_PP_STASH_LIMIT_MB``). Fold
-      the head + loss into ``tail_fn(tail_params, h, extra_mb)`` (runs
-      on the last stage per microbatch) and the embedding into
-      ``head_fn(head_params, x_mb)`` (first stage) so the region's
-      inputs/outputs are token-sized, not activation-sized. Gradients
-      flow to ``stacked_params`` (local stage shard), ``tail_params``
-      and ``head_params`` (replicated via psum), and to a floating
-      ``x``. ``M % pp`` may be ragged.
-    - **legacy (no ``tail_params``)** — forward schedule differentiated
-      by autodiff's reverse scan; per-step residuals are
-      microbatch-sized but all ``M + pp - 1`` of them are live at the
-      fwd/bwd boundary. ``tail_fn(h, extra_mb)`` here CLOSES OVER its
-      params (autodiff sees through the closure).
+    activations never live across the schedule. It is a custom-vjp with
+    a hand-written interleaved backward (see the module docstring for the
+    ``variant`` trade: ``'remat'`` bounds each rank's live activations
+    at a ``2(pp-1)+1``-slot circular stash, ``'stash'`` saves one
+    boundary activation per microbatch and skips the chain re-forward,
+    ``'auto'`` picks ``'stash'`` while it fits
+    ``AUTODIST_PP_STASH_LIMIT_MB``). Fold the head + loss into
+    ``tail_fn(tail_params, h, extra_mb)`` (runs on the last stage per
+    microbatch) and the embedding into ``head_fn(head_params, x_mb)``
+    (first stage) so the region's inputs/outputs are token-sized, not
+    activation-sized. Gradients flow to ``stacked_params`` (local stage
+    shard), ``tail_params`` and ``head_params`` (replicated via psum),
+    and to a floating ``x``. ``M % pp`` may be ragged.
 
     Inputs ride a backward-rotating ppermute relay (one mb hop per link
     per step); only the small per-microbatch tail outputs use masked
@@ -237,119 +219,22 @@ def one_f_one_b(block_fn, stacked_params, x, axis_name, microbatches,
     """
     pp = jax.lax.axis_size(axis_name)
     M = int(microbatches)
-    stack = _local_stack_fn(block_fn)
-
+    if tail_fn is not None and tail_params is None:
+        raise ValueError(
+            '1F1B needs the param-explicit tail convention: pass '
+            'tail_params with tail_fn(tail_params, h, extra_mb) — a '
+            'closure-style tail_fn(h, extra) would silently lose its '
+            'parameter gradients in the hand-written backward')
     if pp == 1:
         if head_fn is not None:
             x = head_fn(head_params, x)
-        h, aux = stack(stacked_params, x)
+        h, aux = _local_stack_fn(block_fn)(stacked_params, x)
         if tail_fn is not None:
-            h = tail_fn(tail_params, h, extra) if tail_params is not None \
-                else tail_fn(h, extra)
+            h = tail_fn(tail_params, h, extra)
         return h, aux
-
-    if tail_params is not None or head_params is not None:
-        if tail_fn is not None and tail_params is None:
-            raise ValueError(
-                'fused 1F1B (head_params given) needs the param-explicit '
-                'tail convention: pass tail_params with '
-                'tail_fn(tail_params, h, extra_mb) — a closure-style '
-                'tail_fn(h, extra) would silently lose its parameter '
-                'gradients')
-        return _fused_1f1b(block_fn, stacked_params, x, axis_name, M,
-                           tail_fn, extra, tail_params, head_fn,
-                           head_params, variant)
-    if head_fn is not None:
-        # the legacy schedule has no head slot; silently skipping it
-        # would diverge from the pp==1 branch above
-        raise ValueError(
-            'head_fn requires the fused 1F1B mode: pass head_params '
-            '(and tail_params if a tail_fn is used)')
-    return _legacy_1f1b(block_fn, stacked_params, x, axis_name, M,
-                        tail_fn, extra)
-
-
-def _legacy_1f1b(block_fn, stacked_params, x, axis_name, M, tail_fn,
-                 extra):
-    """Autodiff-through-the-scan 1F1B memory profile (see
-    :func:`one_f_one_b`)."""
-    pp = jax.lax.axis_size(axis_name)
-    rank = lax.axis_index(axis_name)
-    B = x.shape[0]
-    assert B % M == 0, 'batch %d not divisible by microbatches %d' % (B, M)
-    mb = B // M
-    share = _ceil_div(M, pp)
-    stack = _local_stack_fn(block_fn)
-
-    def to_mb(a):
-        return a.reshape(M, mb, *a.shape[1:])
-
-    own_in = _own_slices(to_mb(x), rank, pp, share, M)
-    own_extra = None if extra is None else \
-        _own_slices(to_mb(extra), rank, pp, share, M)
-    fwd_perm = [(i, i + 1) for i in range(pp - 1)]
-    back_rot = _back_rotation(pp)
-    zero_h = jnp.zeros((mb,) + x.shape[1:], x.dtype)
-    zero_e = None if extra is None else \
-        jnp.zeros((mb,) + extra.shape[1:], extra.dtype)
-
-    def tail(h, e):
-        return h if tail_fn is None else tail_fn(h, e)
-
-    out_shape = jax.eval_shape(tail, zero_h, zero_e)
-    zero_out = jnp.zeros(out_shape.shape, out_shape.dtype)
-
-    def step(carry, t):
-        reg_x, reg_e, state_h, state_e, own_out, aux_acc = carry
-        # input relay: every pp steps each rank injects its next owned
-        # microbatch; one backward hop per step delivers one microbatch
-        # per step to stage 0
-        reg_x = _inject(own_in, reg_x, t, share, pp)
-        if extra is not None:
-            reg_e = _inject(own_extra, reg_e, t, share, pp)
-        inp_h = jnp.where(rank == 0, reg_x, state_h)
-        inp_e = None if extra is None else \
-            jnp.where(rank == 0, reg_e, state_e)
-        valid = jnp.logical_and(t >= rank, t - rank < M)
-        h, aux = lax.cond(
-            valid, lambda v: stack(stacked_params, v),
-            lambda v: (v, jnp.zeros((), jnp.float32)), inp_h)
-        aux_acc = aux_acc + aux
-        # the last stage's per-microbatch tail (head/loss when folded)
-        # runs UNCONDITIONALLY and is masked after: rank-divergent conds
-        # around code with sharding constraints deadlock when the
-        # partitioner inserts resharding collectives in one branch only
-        # (the full-batch head this replaces also ran on every rank)
-        j = t - (pp - 1)
-        is_out = jnp.logical_and(rank == pp - 1,
-                                 jnp.logical_and(j >= 0, j < M))
-        out_val = tail(h, inp_e)
-        # output delivery: microbatch j leaves the last stage this step
-        # (masked psum of the SMALL tail output)
-        done = lax.psum(jnp.where(is_out, out_val, zero_out), axis_name)
-        take = jnp.logical_and(jnp.logical_and(j >= 0, j < M),
-                               jnp.mod(j, pp) == rank)
-        slot_out = jnp.clip(j // pp, 0, share - 1)
-        prev = lax.dynamic_index_in_dim(own_out, slot_out, 0,
-                                        keepdims=False)
-        own_out = lax.dynamic_update_index_in_dim(
-            own_out, jnp.where(take, done, prev), slot_out, 0)
-        nxt_h = lax.ppermute(h, axis_name, fwd_perm)
-        nxt_e = None if extra is None else \
-            lax.ppermute(inp_e, axis_name, fwd_perm)
-        reg_x = lax.ppermute(reg_x, axis_name, back_rot)
-        if extra is not None:
-            reg_e = lax.ppermute(reg_e, axis_name, back_rot)
-        return (reg_x, reg_e, nxt_h, nxt_e, own_out, aux_acc), None
-
-    own_out = jnp.zeros((share,) + zero_out.shape, zero_out.dtype)
-    carry0 = (zero_h, zero_e, zero_h, zero_e, own_out,
-              jnp.zeros((), jnp.float32))
-    (_, _, _, _, own_out, aux_acc), _ = lax.scan(
-        step, carry0, jnp.arange(M + pp - 1))
-    out = _reassemble(own_out, axis_name, pp, share, mb, M, B)
-    aux = lax.psum(aux_acc, axis_name) / M
-    return out, aux
+    return _fused_1f1b(block_fn, stacked_params, x, axis_name, M,
+                       tail_fn, extra, tail_params, head_fn,
+                       head_params, variant)
 
 
 def _fused_1f1b(block_fn, stacked_params, x, axis_name, M, tail_fn,
@@ -390,15 +275,15 @@ def _fused_1f1b(block_fn, stacked_params, x, axis_name, M, tail_fn,
         # tail cotangent for it is discarded); int targets — the lm/
         # classification case — have no cotangent, but a float extra
         # (soft labels, distillation targets) would silently train with
-        # d(extra)=0. Refuse rather than diverge from the legacy path.
+        # d(extra)=0. Refuse rather than train on wrong gradients.
         raise ValueError(
             'fused 1F1B does not backpropagate into a floating-point '
-            "`extra` stream; use integer targets or the legacy "
-            'schedule (no tail_params)')
+            '`extra` stream; use integer targets')
     x_differentiable = jnp.issubdtype(jnp.asarray(x).dtype, jnp.inexact)
 
     if variant not in ('auto', 'remat', 'stash'):
-        raise ValueError('unknown 1F1B variant %r' % (variant,))
+        raise ValueError("unknown 1F1B variant %r; it is one of 'auto', "
+                         "'remat', 'stash'" % (variant,))
     if variant == 'auto':
         from autodist_tpu.const import ENV
         probe = jax.eval_shape(

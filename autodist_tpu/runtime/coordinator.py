@@ -37,12 +37,9 @@ _FORWARDED_FLAGS = (ENV.AUTODIST_MIN_LOG_LEVEL, ENV.AUTODIST_IS_TESTING,
                     # quantization block layout is part of the traced
                     # program (compressor) AND the PS frame format
                     ENV.AUTODIST_QUANT_BLOCK,
-                    ENV.AUTODIST_S2D_STEM, ENV.AUTODIST_DENSENET_DUS,
-                    # kernel-choice + pipeline-variant tracing flags:
-                    # part of the traced program, and divergent HLO
-                    # across SPMD hosts deadlocks
-                    ENV.AUTODIST_FUSED_CONV,
-                    ENV.AUTODIST_FUSED_CONV_MAX_ROWS,
+                    # pipeline-variant tracing flag: part of the
+                    # traced program, and divergent HLO across SPMD
+                    # hosts deadlocks
                     ENV.AUTODIST_PP_STASH_LIMIT_MB,
                     # hierarchical node-group layout is part of the
                     # traced program (two-level collective schedules)

@@ -7,8 +7,8 @@ Drives both engines once, in ONE process (a chip belongs to one process
 at a time), through the entry points a user calls:
 
 1. builds the native coord service from ``autodist_tpu/native/*.cc``;
-2. compiles every Pallas kernel the package ships, non-interpreted, at
-   the shapes the models use, and compares each with plain jnp;
+2. compiles the flash attention kernels, non-interpreted, at the
+   shapes the models use, and compares each with plain jnp;
 3. trains BERT-large at its published widths (24 x 1024 x 16, vocab
    30522, bf16, remat) at seq 512 through ``Trainer`` for a few steps —
    at dp=1, and on four or more devices also at dp=4 and dp=2 x tp=2;
@@ -55,7 +55,6 @@ C0_EXPECTED_B = 0.01 * 4.17503   # reference c0 ground truth, one SGD step
 # ulps — about 2.5x the worst ratio seen on a v5e (0.6%, dq at seq
 # 4096) — while a wrong mask, block index or scale is off by O(1).
 KERNEL_RTOL = 2.0 ** -6
-STATS_RTOL = 1e-4          # conv+BN moment sums: f32 accumulators
 
 # an HLO instruction reads "... <shape> all-reduce(<operands>)"; operand
 # references are "%all-reduce.7" and never match
@@ -192,60 +191,20 @@ def _check_flash(shape, causal, interpret):
     return elapsed
 
 
-def _check_conv_bn(shape, c_out, interpret):
-    """The fused 1x1 conv + BatchNorm kernel (opt-in through
-    ``AUTODIST_FUSED_CONV``) at one ResNet bottleneck shape: the
-    previous BN's normalize+ReLU as prologue, the next BN's moment sums
-    as epilogue."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from autodist_tpu.kernels.conv_bn import fused_pointwise
-
-    rng = np.random.RandomState(0)
-    c_in = shape[-1]
-    x = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-    w = jnp.asarray(rng.randn(c_in, c_out) * 0.05, jnp.bfloat16)
-    scale = jnp.asarray(rng.rand(c_in) + 0.5, jnp.float32)
-    bias = jnp.asarray(rng.randn(c_in) * 0.1, jnp.float32)
-
-    t0 = time.perf_counter()
-    y, s1, s2 = jax.block_until_ready(jax.jit(
-        lambda x, w, a, b: fused_pointwise(
-            x, w, scale=a, bias=b, prologue_relu=True,
-            interpret=interpret))(x, w, scale, bias))
-    elapsed = time.perf_counter() - t0
-    xn = jnp.maximum(x.astype(jnp.float32) * scale + bias, 0)
-    ref = jnp.einsum('bhwc,cd->bhwd',
-                     xn.astype(jnp.bfloat16).astype(jnp.float32),
-                     w.astype(jnp.float32), precision='highest')
-    ratio = _check_close('conv_bn y', y, ref, KERNEL_RTOL)
-    _check_close('conv_bn sum(y)', s1, ref.sum((0, 1, 2)), STATS_RTOL)
-    _check_close('conv_bn sum(y^2)', s2, (ref ** 2).sum((0, 1, 2)),
-                 STATS_RTOL)
-    say('kernel conv_bn %s x [%d,%d]: output and moment sums match '
-        'plain jnp (error/scale %.2g)' % (shape, c_in, c_out, ratio))
-    return elapsed
-
-
 def phase_kernels(dry_run):
-    """Every Pallas kernel the package ships, compiled by Mosaic (not
+    """The flash attention kernels, compiled by Mosaic (not
     interpreted) at the shapes the models use: BERT-large attention
     (16 heads x 64, seq 512, blocks 256/512) and the long-context LM's
     (12 heads x 64, seq 4096 causal, blocks 512/1024)."""
     if dry_run:
         # same code, interpreted, at sizes the CPU can chew
         flash_shapes = [((1, 2, 512, 16), False)]
-        conv_shape, c_out = (2, 8, 8, 128), 256
     else:
         flash_shapes = [((4, 16, 512, 64), False),
                         ((2, 12, 4096, 64), True)]
-        conv_shape, c_out = (16, 28, 28, 128), 512
     interpret = dry_run   # explicit: never whatever a default picks
     elapsed = sum(_check_flash(shape, causal, interpret)
                   for shape, causal in flash_shapes)
-    elapsed += _check_conv_bn(conv_shape, c_out, interpret)
     if not dry_run:
         say('observation: kernel phase compile+run %.1f s' % elapsed)
 

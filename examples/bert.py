@@ -41,7 +41,7 @@ def main():
                         'activations bounded by the pipe depth '
                         '(embed/head folded into the first/last stages)')
     p.add_argument('--pp-variant', default='auto',
-                   choices=['auto', 'remat', 'stash', 'legacy'],
+                   choices=['auto', 'remat', 'stash'],
                    help='1f1b backward: remat (pp-bounded memory, ~3 '
                         'fwd passes) | stash (per-microbatch boundary '
                         'stash, ~2 fwd) | auto (stash while it fits)')

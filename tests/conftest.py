@@ -149,3 +149,31 @@ def kernel_calls():
     """Counts the Pallas kernel calls of a jaxpr by name; interpret mode
     keeps the name that the compiled call carries."""
     return _kernel_calls
+
+
+@contextlib.contextmanager
+def _events_of(name):
+    """A list that holds, once the block ends, the loop ring's records
+    called ``name`` that the block left, read by ``id`` (a count of the
+    process that outlives ``reset()``): the ring keeps ``LOOP_RING``
+    records, so a count of them before and after does not move once a
+    new record pushes an old one of that name out. The ring is first
+    filled with such records, the state that a worker's earlier files
+    may or may not have left."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.telemetry.core import LOOP_RING
+    ring = telemetry.get()
+    for _ in range(LOOP_RING):
+        ring.loop_event(name, filler=True)
+    last = max(r['id'] for r in ring.loop_records())
+    events = []
+    yield events
+    events.extend(r for r in ring.loop_records()
+                  if r['id'] > last and r['name'] == name)
+
+
+@pytest.fixture
+def events_of():
+    """``with events_of(name) as events``: the ring's records of one
+    name that the block leaves, whatever ran in this process before."""
+    return _events_of

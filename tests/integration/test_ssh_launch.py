@@ -128,7 +128,7 @@ def _chief_env(tmp_path, resource_file, extra_path=None):
     env['AUTODIST_COORD_SERVICE_ADDR'] = '127.0.0.1:%d' % free_port()
     # a registry tracing flag: must ride the shipped worker command
     # line (divergent HLO across SPMD hosts deadlocks)
-    env['AUTODIST_S2D_STEM'] = '1'
+    env['AUTODIST_PP_STASH_LIMIT_MB'] = '1024'
     env['SHIM_LOG'] = str(tmp_path / 'shim.log')
     if extra_path:
         env['PATH'] = extra_path + os.pathsep + env.get('PATH', '')
@@ -181,7 +181,7 @@ def test_ssh_launch_path_executes(tmp_path):
     assert 'scp' in log and '127.0.0.2' in log, log
     assert 'AUTODIST_WORKER=127.0.0.2' in log, log
     assert 'AUTODIST_STRATEGY_ID=' in log, log
-    assert 'AUTODIST_S2D_STEM=1' in log, log   # registry flag forwarded
+    assert 'AUTODIST_PP_STASH_LIMIT_MB=1024' in log, log   # forwarded
     assert 'mv -f' in log, log   # atomic strategy placement
 
 

@@ -175,6 +175,43 @@ def test_fused_1f1b_direct_no_head(variant):
                                    atol=1e-4)
 
 
+def test_pp_variant_legacy_is_refused(batch):
+    """The autodiff-through-the-schedule variant is gone (PR 44): the
+    spec's value reaches the schedule's own check, which names the
+    three that exist."""
+    model = TransformerLM(TransformerConfig.tiny(dtype=jnp.float32,
+                                                 n_layers=4))
+    tr = Trainer(model, optax.adam(1e-2), spec=ParallelSpec(
+        pp=2, microbatches=4, pp_schedule='1f1b', pp_variant='legacy'))
+    state = tr.init(jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="'legacy'.*'auto', 'remat', "
+                                         "'stash'"):
+        tr.step(state, batch)
+
+
+def test_1f1b_closure_style_tail_is_refused():
+    """A ``tail_fn(h, extra)`` that closes over its parameters would
+    lose their gradients in the hand-written backward: refused at
+    pp 2, where it used to select the autodiff schedule."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from autodist_tpu.parallel.pipeline import one_f_one_b
+    mesh = Mesh(np.array(jax.devices()[:2]), ('pipe',))
+    w = jnp.zeros((2, 1, 8, 8), jnp.float32)
+    out = jnp.ones((8,), jnp.float32)
+
+    def inner(w_, x_):
+        return one_f_one_b(
+            lambda p, h: (jnp.tanh(h @ p), jnp.zeros((), jnp.float32)),
+            w_[0], x_, 'pipe', 2, tail_fn=lambda h, e: h @ out)[0]
+
+    mapped = jax.shard_map(inner, mesh=mesh, in_specs=(P('pipe'), P()),
+                           out_specs=P(), axis_names={'pipe'},
+                           check_vma=False)
+    with pytest.raises(ValueError, match='tail_params'):
+        jax.eval_shape(mapped, w, jnp.zeros((4, 8), jnp.float32))
+
+
 def test_pipeline_1f1b_reduces_peak_memory():
     """The point of 1F1B: the custom-vjp backward interleaves
     recompute-forwards and backwards with a 2(pp-1)+1-slot circular
@@ -268,25 +305,25 @@ def test_moe_expert_parallel_matches_dp(batch):
     assert base[-1] < base[0]
 
 
-def test_ring_attention_matches_dense():
+@pytest.mark.parametrize('causal', [True, False])
+def test_ring_attention_matches_dense(causal):
     from jax.sharding import Mesh, PartitionSpec as P
     B, H, S, D = 2, 4, 64, 16
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.randn(B, H, S, D).astype('f4'))
                for _ in range(3))
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(8), ('seq',))
-    for causal in (True, False):
-        ref = local_flash_attention(q, k, v, causal=causal)
-        f = jax.jit(jax.shard_map(
-            lambda q, k, v, c=causal: ring_attention(q, k, v, 'seq',
-                                                     causal=c),
-            mesh=mesh, in_specs=(P(None, None, 'seq'),) * 3,
-            out_specs=P(None, None, 'seq')))
-        err = float(jnp.max(jnp.abs(f(q, k, v) - ref)))
-        assert err < 1e-5, (causal, err)
+    ref = local_flash_attention(q, k, v, causal=causal)
+    f = jax.jit(jax.shard_map(
+        lambda q, k, v: ring_attention(q, k, v, 'seq', causal=causal),
+        mesh=mesh, in_specs=(P(None, None, 'seq'),) * 3,
+        out_specs=P(None, None, 'seq')))
+    err = float(jnp.max(jnp.abs(f(q, k, v) - ref)))
+    assert err < 1e-5, err
 
 
-def test_ulysses_attention_matches_dense():
+@pytest.mark.parametrize('causal', [True, False])
+def test_ulysses_attention_matches_dense(causal):
     from jax.sharding import Mesh, PartitionSpec as P
 
     from autodist_tpu.parallel.ulysses import ulysses_attention
@@ -295,15 +332,13 @@ def test_ulysses_attention_matches_dense():
     q, k, v = (jnp.asarray(rng.randn(B, H, S, D).astype('f4'))
                for _ in range(3))
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(4), ('seq',))
-    for causal in (True, False):
-        ref = local_flash_attention(q, k, v, causal=causal)
-        f = jax.jit(jax.shard_map(
-            lambda q, k, v, c=causal: ulysses_attention(q, k, v, 'seq',
-                                                        causal=c),
-            mesh=mesh, in_specs=(P(None, None, 'seq'),) * 3,
-            out_specs=P(None, None, 'seq')))
-        err = float(jnp.max(jnp.abs(f(q, k, v) - ref)))
-        assert err < 1e-5, (causal, err)
+    ref = local_flash_attention(q, k, v, causal=causal)
+    f = jax.jit(jax.shard_map(
+        lambda q, k, v: ulysses_attention(q, k, v, 'seq', causal=causal),
+        mesh=mesh, in_specs=(P(None, None, 'seq'),) * 3,
+        out_specs=P(None, None, 'seq')))
+    err = float(jnp.max(jnp.abs(f(q, k, v) - ref)))
+    assert err < 1e-5, err
 
 
 def test_ulysses_attention_grads_match_dense():

@@ -55,87 +55,6 @@ def test_vision_models_train_sharded(name):
            lr=lr, require_decrease=(name != 'inception'))
 
 
-@pytest.mark.parametrize('h,k,pad', [
-    (224, 7, 'SAME'),      # ResNet/DenseNet stem
-    (299, 3, 'VALID'),     # InceptionV3 stem
-    (225, 7, 'SAME'),      # odd spatial
-    (230, 4, 'VALID'),     # even kernel
-    (231, 4, 'VALID'),     # even kernel, crop branch (tail row a
-                           # strided window never covers)
-])
-def test_space_to_depth_conv_is_exact(h, k, pad):
-    """The s2d stem rewrite is numerically the SAME conv (same dot
-    products, rearranged): max |diff| at f32 noise level."""
-    from autodist_tpu.models.vision import space_to_depth_conv
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, h, h, 3).astype('f4'))
-    w = jnp.asarray(rng.randn(k, k, 3, 16).astype('f4'))
-    ref = jax.lax.conv_general_dilated(
-        x, w, (2, 2), pad, dimension_numbers=('NHWC', 'HWIO', 'NHWC'))
-    got = space_to_depth_conv(x, w, padding=pad)
-    assert got.shape == ref.shape
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=1e-4)
-
-
-def test_s2d_stem_gate_matches_plain_model(monkeypatch):
-    """Full-model forward with the stem flag on vs off: identical
-    (the transform only changes HOW the stem conv is computed)."""
-    from autodist_tpu.models import vision
-    model = vision.ResNet((1, 1), num_classes=10)
-    params = model.init(jax.random.PRNGKey(0))
-    batch = _image_batch(hw=32)
-    x = jnp.asarray(batch['images'])
-    monkeypatch.setenv('AUTODIST_S2D_STEM', '0')
-    off = model.apply(params, x)
-    monkeypatch.setenv('AUTODIST_S2D_STEM', '1')
-    on = model.apply(params, x)
-    np.testing.assert_allclose(np.asarray(on), np.asarray(off),
-                               atol=2e-5)
-
-
-def test_densenet_dus_block_form_is_exact(monkeypatch):
-    """The buffer/dynamic-update-slice dense-block form
-    (AUTODIST_DENSENET_DUS=1) is numerically the SAME model: outputs
-    and gradients match the concat form exactly (buffer[..., :ch] ==
-    the concat prefix at every layer)."""
-    from autodist_tpu.models import vision
-    model = vision.DenseNet((2, 2), num_classes=4)
-    params = model.init(jax.random.PRNGKey(0))
-    rng = np.random.RandomState(0)
-    batch = {'images': rng.rand(2, 32, 32, 3).astype('f4'),
-             'labels': np.array([1, 2], np.int32)}
-    x = jnp.asarray(batch['images'])
-    monkeypatch.setenv('AUTODIST_DENSENET_DUS', '0')
-    plain = model.apply(params, x)
-    g0 = jax.grad(model.loss)(params, batch)
-    monkeypatch.setenv('AUTODIST_DENSENET_DUS', '1')
-    dus = model.apply(params, x)
-    g1 = jax.grad(model.loss)(params, batch)
-    np.testing.assert_allclose(np.asarray(dus), np.asarray(plain),
-                               atol=1e-5)
-    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=2e-5)
-
-
-def test_densenet_dus_heterogeneous_growth_raises(monkeypatch):
-    """The DUS buffer is sized from the FIRST layer's growth; a
-    heterogeneous-growth block must error instead of silently clamping
-    later layers' writes (ISSUE 1 satellite)."""
-    from autodist_tpu.models import vision
-    model = vision.DenseNet((2, 2), num_classes=4)
-    # the guard fires at trace time, so eval_shape (no compile) covers it
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    x = jax.ShapeDtypeStruct((1, 32, 32, 3), jnp.float32)
-    # simulate a heterogeneous block: second dense layer grows wider
-    model.layers[1][1].conv2.out_ch = \
-        model.layers[1][1].conv2.out_ch + 8
-    monkeypatch.setenv('AUTODIST_DENSENET_DUS', '1')
-    with pytest.raises(ValueError, match='conv2.out_ch'):
-        jax.eval_shape(model.apply, params, x)
-
-
 def test_vgg_wrong_spatial_raises():
     from autodist_tpu.models import vision
     model = vision.VGG((8, 'M'), num_classes=5)   # fc sized for 7x7
@@ -170,46 +89,53 @@ def test_ncf_trains():
     _train(NCF(50, 30, mf_dim=4, mlp_dims=(8, 4)), batch, lr=0.5)
 
 
-def test_vision_output_shapes():
+@pytest.mark.parametrize('name', ['resnet', 'vgg', 'densenet'])
+def test_vision_output_shapes(name):
     from autodist_tpu.models import vision
-    x = jnp.zeros((2, 32, 32, 3), jnp.float32)
-    for model in (vision.ResNet((1, 1), num_classes=7),
-                  vision.VGG((8, 'M'), num_classes=7, fc_spatial=16),
-                  vision.DenseNet((2,), num_classes=7)):
-        params = model.init(jax.random.PRNGKey(0))
-        out = model.apply(params, x)
-        assert out.shape == (2, 7), type(model).__name__
+    model = {
+        'resnet': lambda: vision.ResNet((1, 1), num_classes=7),
+        'vgg': lambda: vision.VGG((8, 'M'), num_classes=7, fc_spatial=16),
+        'densenet': lambda: vision.DenseNet((2,), num_classes=7),
+    }[name]()
+    params = model.init(jax.random.PRNGKey(0))
+    out = model.apply(params, jnp.zeros((2, 32, 32, 3), jnp.float32))
+    assert out.shape == (2, 7)
 
 
-def test_chunked_ce_and_remat_modes_match_plain():
-    """loss_chunk and remat ('save_attn'/full) must not change the math:
-    same loss and same gradients as the unchunked, non-remat forward."""
+def _tiny_lm_loss_and_grads(**kw):
     from autodist_tpu.models.transformer import (TransformerConfig,
                                                  TransformerLM)
     rng = np.random.RandomState(0)
     batch = {'tokens': rng.randint(0, 256, (4, 128), dtype=np.int32),
              'targets': rng.randint(0, 256, (4, 128), dtype=np.int32)}
-    variants = {
-        'plain': dict(),
-        'chunked': dict(loss_chunk=64),
-        'save_attn': dict(remat='save_attn', loss_chunk=64),
-        'full_remat': dict(remat=True, loss_chunk=64),
-        'dots': dict(remat='dots', loss_chunk=64),
-        'dots_no_batch': dict(remat='dots_no_batch', loss_chunk=64),
-    }
-    ref_loss = ref_grads = None
-    for name, kw in variants.items():
-        cfg = TransformerConfig.tiny(dtype=jnp.float32, max_len=128, **kw)
-        m = TransformerLM(cfg)
-        params = m.init(jax.random.PRNGKey(0))
-        loss, grads = jax.jit(jax.value_and_grad(m.loss))(params, batch)
-        if ref_loss is None:
-            ref_loss, ref_grads = float(loss), grads
-            continue
-        assert abs(float(loss) - ref_loss) < 1e-5, name
-        for a, b in zip(jax.tree.leaves(grads),
-                        jax.tree.leaves(ref_grads)):
-            np.testing.assert_allclose(a, b, atol=5e-5, err_msg=name)
+    m = TransformerLM(TransformerConfig.tiny(dtype=jnp.float32,
+                                             max_len=128, **kw))
+    params = m.init(jax.random.PRNGKey(0))
+    loss, grads = jax.jit(jax.value_and_grad(m.loss))(params, batch)
+    return float(loss), grads
+
+
+@pytest.fixture(scope='module')
+def plain_lm():
+    """(loss, grads) of the unchunked, non-remat forward."""
+    return _tiny_lm_loss_and_grads()
+
+
+@pytest.mark.parametrize('kw', [
+    dict(loss_chunk=64),
+    dict(remat='save_attn', loss_chunk=64),
+    dict(remat=True, loss_chunk=64),
+    dict(remat='dots', loss_chunk=64),
+    dict(remat='dots_no_batch', loss_chunk=64),
+], ids=['chunked', 'save_attn', 'full_remat', 'dots', 'dots_no_batch'])
+def test_chunked_ce_and_remat_modes_match_plain(plain_lm, kw):
+    """loss_chunk and remat ('save_attn'/full) must not change the math:
+    same loss and same gradients as the unchunked, non-remat forward."""
+    ref_loss, ref_grads = plain_lm
+    loss, grads = _tiny_lm_loss_and_grads(**kw)
+    assert abs(loss - ref_loss) < 1e-5
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(ref_grads)):
+        np.testing.assert_allclose(a, b, atol=5e-5)
 
 
 def test_chunked_ce_indivisible_rows_falls_back():

@@ -217,27 +217,23 @@ def test_layer_walks_its_chunks_in_passes(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
 
 
-def test_the_layer_leaves_its_plan_in_the_ring():
+def test_the_layer_leaves_its_plan_in_the_ring(events_of):
     """One ``moe.plan`` point event a trace of the layer, none for a
     call that is not traced again: the buffer a pass of the combine
     holds, and which of the two movements is a kernel."""
     layer = MoeMlp(32, 16, 8, top_k=2, held=(0, 4))
     p = layer.init(jax.random.PRNGKey(0))
     x = jnp.ones((2, 40, 32))
-
-    def plans():
-        return [r for r in telemetry.get().loop_records()
-                if r['name'] == 'moe.plan']
-    before = len(plans())
     run = jax.jit(lambda p, x: layer.apply(p, x)[0])
-    run(p, x)
-    run(p, x)
-    assert len(plans()) == before + 1
+    with events_of('moe.plan') as plans:
+        run(p, x)
+        run(p, x)
+    assert len(plans) == 1
     rows = moe.buffer_rows(80, 2, 4)
     # of the order the checkpoint keeps the sorted pairs [t * k], token
     # and weight by row, a tile's expert, the live tiles, row_of [t,
     # held] and the held experts' sizes
-    assert plans()[-1]['tags'] == dict(
+    assert plans[0]['tags'] == dict(
         rows=rows, chunk_tiles=moe.CHUNK_TILES, pass_chunks=1,
         token_block=mc.TOKEN_BLOCK, window_rows=mc.WINDOW_ROWS,
         combine='pallas', gather='xla', buffer_bytes=rows * 32 * 4,

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from autodist_tpu import telemetry
 from autodist_tpu.kernels import ssm_conv as sc
 
 
@@ -213,7 +212,7 @@ def with_the_conv_in_jax_numpy(run):
 
 
 @pytest.mark.parametrize('conv,taken', [(4, 'pallas'), (3, 'xla')])
-def test_the_mixer_says_which_conv_it_traced(conv, taken):
+def test_the_mixer_says_which_conv_it_traced(conv, taken, events_of):
     """One ``ssm.plan`` point event a trace of a ``Mamba2Mixer``: the
     kernels on the projection's own columns where they take the shape,
     XLA on a slice of it where they do not (three taps); the layer's
@@ -221,12 +220,10 @@ def test_the_mixer_says_which_conv_it_traced(conv, taken):
     layer = mixer(conv=conv)
     params = layer.init(jax.random.PRNGKey(0))
     u = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 32))
-    ring = telemetry.get()
-    before = len([r for r in ring.loop_records() if r['name'] == 'ssm.plan'])
-    out = jax.jit(layer.apply)(params, u)
-    events = [r for r in ring.loop_records() if r['name'] == 'ssm.plan']
-    assert len(events) == before + 1
-    tags = events[-1]['tags']
+    with events_of('ssm.plan') as events:
+        out = jax.jit(layer.apply)(params, u)
+    assert len(events) == 1
+    tags = events[0]['tags']
     assert tags['conv'] == taken
     assert (tags['channels'], tags['taps']) == (384, conv)
     assert tags['in_place'] == tags['split_outputs'] == (taken == 'pallas')

@@ -10,7 +10,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from autodist_tpu import telemetry
 from autodist_tpu.kernels import ssm_gate_norm as gn
 from autodist_tpu.models.core import GatedGroupRMSNorm
 
@@ -213,7 +212,7 @@ def with_the_gate_norm_in_jax_numpy(run):
 
 # heads of 64 in two groups: groups of 128 lanes, or of 192
 @pytest.mark.parametrize('heads,taken', [(4, 'pallas'), (6, 'xla')])
-def test_the_mixer_says_which_gate_norm_it_traced(heads, taken):
+def test_the_mixer_says_which_gate_norm_it_traced(heads, taken, events_of):
     """The one ``ssm.plan`` point event a trace of a ``Mamba2Mixer``
     carries the gate norm's three tags beside the conv's: the kernels
     where they take the shape, XLA on ``GatedGroupRMSNorm`` where they do
@@ -223,12 +222,10 @@ def test_the_mixer_says_which_gate_norm_it_traced(heads, taken):
     layer = mixer(heads=heads)
     params = layer.init(jax.random.PRNGKey(0))
     u = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 32))
-    ring = telemetry.get()
-    before = len([r for r in ring.loop_records() if r['name'] == 'ssm.plan'])
-    out = jax.jit(layer.apply)(params, u)
-    events = [r for r in ring.loop_records() if r['name'] == 'ssm.plan']
-    assert len(events) == before + 1
-    tags = events[-1]['tags']
+    with events_of('ssm.plan') as events:
+        out = jax.jit(layer.apply)(params, u)
+    assert len(events) == 1
+    tags = events[0]['tags']
     assert tags['gate_norm'] == taken
     assert tags['conv'] == 'pallas'
     if taken == 'pallas':

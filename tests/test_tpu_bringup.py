@@ -863,7 +863,7 @@ def test_cpu_dry_run_drives_every_phase():
     lines = out.stdout.strip().splitlines()
     assert lines[0].startswith('CPU DRY RUN'), lines[0]
     assert json.loads(lines[-1]) == {'ok': True, 'cpu_dry_run': True}
-    for leg in ('kernel flash', 'kernel conv_bn', 'bert dp=1 tp=1',
+    for leg in ('kernel flash', 'bert dp=1 tp=1',
                 'bert dp=4 tp=1', 'bert dp=2 tp=2', 'session c0',
                 'session dense (PartitionedPS', 'loose mode'):
         assert any(l.startswith(leg) for l in lines), (leg, out.stdout)

@@ -1,11 +1,11 @@
 """Measure the pipeline-schedule trade table.
 
-For pp in {2, 4}: GPipe vs legacy-1F1B vs fused-1F1B(remat) vs
-fused-1F1B(stash), all through the same Trainer/TransformerLM path on
-the 8-device virtual CPU mesh. Reported per config:
+For pp in {2, 4}: GPipe vs fused-1F1B(remat) vs fused-1F1B(stash), all
+through the same Trainer/TransformerLM path on the 8-device virtual CPU
+mesh. Reported per config:
 
 - compiled FLOPs (``compiled.cost_analysis()['flops']``) — recorded
-  but NOT comparable across these four programs (while-loop bodies
+  but NOT comparable across these three programs (while-loop bodies
   count once and the schedules have different loop structures — see
   the BASELINE.md round-5 caveats),
 - temp memory (``memory_analysis().temp_size_in_bytes``) — the
@@ -77,7 +77,6 @@ def main():
     for pp in (2, 4):
         for label, schedule, variant in (
                 ('gpipe', 'gpipe', 'auto'),
-                ('legacy-1f1b', '1f1b', 'legacy'),
                 ('fused-remat', '1f1b', 'remat'),
                 ('fused-stash', '1f1b', 'stash')):
             r = measure(model, batch, pp, schedule, variant, M)
