@@ -359,13 +359,156 @@ def _tile_counts(seq, bq, bk, causal, window=None, transposed=False):
     return len(grid), len(live), sum(_tile_crossed(*t, bq, bk) for t in live)
 
 
-def check_window(window, causal=False):
+# A block-diffusion call (``block_diffusion = B``; BD3-LM, arXiv:
+# 2503.09573) is the third description of live pairs: the ``2 L`` rows of
+# a sequence are a noised copy ``x_t`` (rows ``0 .. L - 1``) and the clean
+# one ``x_0`` (rows ``L .. 2 L - 1``), row ``i`` of either in block ``i //
+# B``, and query row ``r`` sees key row ``c`` iff
+#
+#   r in x_t, c in x_t:  blk(c) == blk(r)     (a noised block sees itself)
+#   r in x_t, c in x_0:  blk(c) <  blk(r)     (and the clean earlier blocks)
+#   r in x_0, c in x_0:  blk(c) <= blk(r)     (the clean copy: block-causal)
+#   r in x_0, c in x_t:  never
+#
+# ``L^2 + L B`` pairs, a quarter of the square. The three kernels take
+# SQUARE tiles of ``t`` rows (``B | t | L``), ``n = L / t`` a half, so a
+# tile lies in one quadrant and the mask crosses only the tiles of equal
+# index in their halves (three diagonals of the ``2 n x 2 n`` square):
+# there it is two shifts and two compares on the tile's own iotas. A
+# query tile ``i`` of either half walks the clean key tiles ``0 .. i``
+# (inner steps ``0 .. n - 1``; those past ``i`` are dead and fetch
+# nothing) and, at inner step ``n``, the noised tile ``i`` (dead for a
+# clean query tile): one online softmax over both sources. A noised row
+# of the sequence's first block meets no clean key at all; its running
+# max stays NEG_INF through the clean tiles and the noised tile's real
+# max wipes out what they left, as in a band call. Transposed
+# (``flash_dkv_bd``): a clean key tile ``i`` takes the query tiles ``i ..
+# n - 1`` of BOTH halves, a noised key tile its own query tile alone; the
+# inner dimension walks all ``2 n`` query tiles, the noised first.
+
+Bd = collections.namedtuple('Bd', 'block tiles')   # B, tiles a half
+
+
+def _bd_of(block_diffusion, seq, size):
+    """The :class:`Bd` of a kernel whose square tiles are ``size`` rows,
+    over ``seq = 2 L`` rows; None without the mask."""
+    if block_diffusion is None:
+        return None
+    return Bd(block_diffusion, seq // 2 // size)
+
+
+def _bd_inner(outer, j, n, transposed=False):
+    """The inner tile of grid step ``j``: a query tile's clean key tile
+    ``j`` and, at ``j == n``, the noised tile of the query tile's own
+    index; transposed, query tile ``j`` of the ``2 n``."""
+    if transposed:
+        return j
+    return jnp.where(j == n, outer % n, n + j)
+
+
+def _bd_tile_live(qi, ki, n):
+    """Whether tile (qi, ki) of the ``2 n x 2 n`` square holds a live
+    pair: a clean key tile up to the query tile's index in its half, or
+    a noised query tile's own noised key tile."""
+    return ((ki >= n) & (ki - n <= qi % n)) | ((ki < n) & (ki == qi))
+
+
+def _bd_tile_crossed(qi, ki, n):
+    """True for the live tiles the mask crosses: those of equal index in
+    their halves."""
+    return ki % n == qi % n
+
+
+def _bd_tile_counts(n):
+    """(tiles of the square, live ones, those of them the mask crosses)
+    of one (batch, head): ``n (n + 1) / 2`` clean tiles for either half
+    of the queries and the ``n`` noised ones."""
+    return 4 * n * n, n * n + 2 * n, 3 * n
+
+
+def _bd_mask(qi, ki, size, bd, transposed=False):
+    """Boolean tile of the block-diffusion mask on a crossed tile
+    ``[size, size]`` (rows are keys where ``transposed``): with ``diff``
+    the key's block less the query's, ``== 0`` noised on noised, ``< 0``
+    noised on clean, ``<= 0`` clean on clean, as one pair of bounds
+    chosen by the tile's quadrant."""
+    block, n = bd
+    shift = block.bit_length() - 1
+    q_dim = 1 if transposed else 0
+    blk = [jax.lax.shift_right_logical(jax.lax.broadcasted_iota(
+        jnp.int32, (size, size), dim), shift) for dim in (q_dim, 1 - q_dim)]
+    diff = blk[1] - blk[0]
+    k_noised = ki < n
+    lo = jnp.where(k_noised, 0, -size)
+    hi = jnp.where(jnp.logical_and(qi < n, jnp.logical_not(k_noised)), -1, 0)
+    return jnp.logical_and(diff >= lo, diff <= hi)
+
+
+def _for_each_bd_tile(tile, qi, ki, size, bd, transposed=False):
+    """:func:`_for_each_tile_kind` under the block-diffusion mask."""
+    is_live = _bd_tile_live(qi, ki, bd.tiles)
+    crossed = _bd_tile_crossed(qi, ki, bd.tiles)
+
+    @pl.when(jnp.logical_and(is_live, crossed))
+    def _():
+        tile(_bd_mask(qi, ki, size, bd, transposed))
+    if bd.tiles > 1:
+        @pl.when(jnp.logical_and(is_live, jnp.logical_not(crossed)))
+        def _():
+            tile(None)
+
+
+def block_diffusion_mask(rows, block):
+    """The block-diffusion mask as a boolean ``[rows, rows]`` array
+    (``rows = 2 L``: the noised copy, then the clean one), straight from
+    the four rules: what the XLA path masks its scores with
+    (``parallel/ring_attention.local_flash_attention(mask=...)``: short
+    sequences, the CPU tests) and the kernels' witness."""
+    half = rows // 2
+    at = jnp.arange(rows)
+    clean, blk = at >= half, (at % half) // block
+    q_clean, k_clean = clean[:, None], clean[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return jnp.where(
+        q_clean, k_clean & (k_blk <= q_blk),
+        jnp.where(k_clean, k_blk < q_blk, k_blk == q_blk))
+
+
+def check_block_diffusion(block_diffusion, causal=False, window=None):
+    """``block_diffusion`` as an int, or None. The mask is a description
+    of live pairs of its own: beside ``causal`` or a ``window`` it
+    raises."""
+    if block_diffusion is None:
+        return None
+    block = int(block_diffusion)
+    if block < 1:
+        raise ValueError('flash_attention: block_diffusion=%r must be a '
+                         'positive block length' % (block_diffusion,))
+    check_window(window, causal, block)
+    if causal:
+        raise ValueError(
+            'flash_attention: block_diffusion=%d beside causal=True: the '
+            'block-diffusion mask is the third description of live pairs '
+            '(after causal and the band) and holds its own mask, the clean '
+            'copy\'s block-causal one included; give causal=False'
+            % block)
+    return block
+
+
+def check_window(window, causal=False, block_diffusion=None):
     """``window`` as a pair of ints, or None. Under a causal mask the
     band is ``(left, 0)``: query i sees keys i - left .. i, and the band
     holds the mask (a caller then runs the band call with no causal
-    flag beside it)."""
+    flag beside it). A band beside the block-diffusion mask raises: the
+    two are descriptions of the same thing."""
     if window is None:
         return None
+    if block_diffusion is not None:
+        raise ValueError(
+            'flash_attention: window %r beside block_diffusion=%r: the '
+            'block-diffusion mask is the third description of live pairs '
+            '(after causal and the band) and holds its own mask; give one '
+            'of the two' % (window, block_diffusion))
     left, right = (int(w) for w in window)
     if left < 0 or right < 0:
         raise ValueError('flash_attention: window %r must be (left, right) '
@@ -373,7 +516,8 @@ def check_window(window, causal=False):
     return (left, 0) if causal else (left, right)
 
 
-def supports(shape, block=128, window=None, kv_heads=None):
+def supports(shape, block=128, window=None, kv_heads=None,
+             block_diffusion=None):
     """Whether flash_attention can run for [B, H, S, D], with or without
     a ``window``: S divisible into >=8-row blocks, and heads that tile
     the lanes of ``[B, S, H * D]`` (:func:`_lane_block`: H * D a
@@ -381,11 +525,20 @@ def supports(shape, block=128, window=None, kv_heads=None):
     more than one lane block). Grouped kv heads (``kv_heads`` fewer than
     H, dividing it) need a head to be its own lane block (D a multiple
     of 128): a kv head's block is then read for its group's query heads
-    with no lane moved."""
-    check_window(window)
+    with no lane moved. Under ``block_diffusion`` S is the ``2 L`` rows
+    of the two copies: L has to split into such blocks, of whole
+    diffusion blocks whose length is a power of two (the mask on a tile
+    is shifts and compares)."""
+    check_window(window, block_diffusion=block_diffusion)
     _, h, s, d = shape
     if kv_heads not in (None, h) and (h % kv_heads or d % _LANES):
         return False
+    if block_diffusion is not None:
+        size = None if s % 2 else _pick_block(s // 2, block)
+        if size is None or size % block_diffusion \
+                or block_diffusion & (block_diffusion - 1):
+            return False
+        s = s // 2
     return _pick_block(s, block) is not None and (
         _lane_block(h, d) == max(_LANES, d) or h * d <= _LANES)
 
@@ -398,7 +551,7 @@ def supports(shape, block=128, window=None, kv_heads=None):
 MIN_KERNEL_SEQ = 512
 
 
-def preferred(shape, window=None, kv_heads=None):
+def preferred(shape, window=None, kv_heads=None, block_diffusion=None):
     """True when the Pallas kernel is expected to beat XLA's fused
     attention for this [B, H, S, D] shape; the same sequences for a
     band call (``window``) as for a full one, where XLA's side is the
@@ -408,6 +561,11 @@ def preferred(shape, window=None, kv_heads=None):
     block target, 256); a longer sequence that only splits into slivers
     is XLA's to win anyway."""
     s = shape[2]
+    if block_diffusion is not None:
+        # (a tile of either copy is lane-wide: ``lse`` runs over both)
+        return (s >= MIN_KERNEL_SEQ and s % (2 * _LANES) == 0
+                and supports(shape, kv_heads=kv_heads,
+                             block_diffusion=block_diffusion))
     return (s >= MIN_KERNEL_SEQ and (s % _LANES == 0 or s <= 256)
             and supports(shape, window=window, kv_heads=kv_heads))
 
@@ -441,11 +599,14 @@ def _window_mask(qi, ki, bq, bk, window, transposed=False):
 
 
 def _for_each_tile_kind(tile, qi, ki, bq, bk, seq, causal,
-                        transposed=False, window=None):
+                        transposed=False, window=None, bd=None):
     """Run ``tile(mask)`` for the kind of tile (qi, ki) is: not at all
     for a dead one, with the causal (or the band's) mask where the
     diagonal (an edge of the band) crosses it, with ``None`` otherwise.
     A kind the static grid does not contain is not emitted."""
+    if bd is not None:
+        _for_each_bd_tile(tile, qi, ki, bq, bd, transposed)
+        return
     if not causal and window is None:
         tile(None)
         return
@@ -681,10 +842,14 @@ def _readers(q_ref, k_ref, tables, d, group):
 # forward
 # ---------------------------------------------------------------------------
 
-def _inner_block(outer, j, size, inner_size, window, transposed=False):
+def _inner_block(outer, j, size, inner_size, window, transposed=False,
+                 bd=None):
     """The inner block of grid step ``j``: ``j`` itself, or for a band
     call the ``j``-th block the band reaches from ``outer`` (which may
-    lie outside the sequence: a dead tile)."""
+    lie outside the sequence: a dead tile), or under the block-diffusion
+    mask :func:`_bd_inner`."""
+    if bd is not None:
+        return _bd_inner(outer, j, bd.tiles, transposed)
     if window is None:
         return j
     back, _ = _band_reach(window, transposed)
@@ -693,9 +858,9 @@ def _inner_block(outer, j, size, inner_size, window, transposed=False):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
-                n_inner, group, tables=None):
+                n_inner, group, bd=None, tables=None):
     qi, j = pl.program_id(2), pl.program_id(3)
-    ki = _inner_block(qi, j, bq, bk, window)
+    ki = _inner_block(qi, j, bq, bk, window, bd=bd)
     blocks = _lane_blocks(g, d, lanes)
 
     def query(read_q, cols, keep):
@@ -765,7 +930,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             acc_scr[c] = acc_scr[c] * alphas + pv
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
-                        window=window)
+                        window=window, bd=bd)
 
     @pl.when(j == n_inner - 1)
     def _emit():
@@ -783,31 +948,39 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
-def _inner_blocks(s, blocks, window, transposed=False):
+def _inner_blocks(s, blocks, window, transposed=False, bd=None):
     """Length of the inner (sequential) grid dimension: every block of
-    the inner operand, or the longest run of them a band reaches."""
+    the inner operand, or the longest run of them a band reaches; under
+    the block-diffusion mask the clean key tiles and one noised
+    (transposed: every query tile of both copies)."""
     bq, bk, _ = blocks
     size, inner = (bk, bq) if transposed else (bq, bk)
+    if bd is not None:
+        return 2 * bd.tiles if transposed else bd.tiles + 1
     if window is None:
         return s // inner
     return _band_inner_blocks(s, size, inner, window, transposed)
 
 
 def _static(kernel, s, heads, kv_heads, d, causal, sm_scale, blocks, window,
-            transposed=False):
+            transposed=False, bd=None):
     """``kernel`` with what a call fixes at trace time."""
     bq, bk, g = blocks
     return functools.partial(
         kernel, sm_scale=sm_scale, fold=_is_pow2(sm_scale), causal=causal,
         bq=bq, bk=bk, nq=s // bq, nk=s // bk, g=g, d=d,
         lanes=_lane_block(heads, d), window=window,
-        n_inner=_inner_blocks(s, blocks, window, transposed),
-        group=heads // kv_heads)
+        n_inner=_inner_blocks(s, blocks, window, transposed, bd),
+        group=heads // kv_heads, bd=bd)
 
 
-def _name(kernel, window):
-    """The ``pallas_call`` name: a band call is told from a full one in
-    a trace (``flash_fwd_band``), and the benchmark reads both."""
+def _name(kernel, window, bd=None):
+    """The ``pallas_call`` name: a band call and one under the
+    block-diffusion mask are told from a full one in a trace
+    (``flash_fwd_band``, ``flash_fwd_bd``), and the benchmark reads
+    them all."""
+    if bd is not None:
+        return kernel + '_bd'
     return kernel if window is None else kernel + '_band'
 
 
@@ -906,11 +1079,22 @@ def _tabled(kernel, at, *refs):
                   tables=(refs[at:at + 2], refs[at + 2:at + 4]))
 
 
-def _kv_row(causal, bq, bk, window=None, seq=None):
+def _kv_row(causal, bq, bk, window=None, seq=None, bd=None):
     """Row block of K/V at step (i, j) of a (b, h, qi, ki) grid. A dead
     causal tile asks for the last live block of its row again, which the
     pipeline already holds, so it fetches nothing; a band call walks the
-    band's blocks only (:func:`_band_fetch`)."""
+    band's blocks only (:func:`_band_fetch`); under the block-diffusion
+    mask the clean tiles up to the query tile's index (the dead ones
+    past it ask for that one again) and, at the last step of a noised
+    query tile, its own noised tile."""
+    if bd is not None:
+        n = bd.tiles
+
+        def row(i, j):
+            local = i % n
+            return jnp.where(jnp.logical_and(j == n, i < n), local,
+                             n + jnp.minimum(j, local))
+        return row
     if window is not None:
         return lambda i, j: _band_fetch(i, j, bq, bk, seq, window)
     if not causal:
@@ -919,7 +1103,7 @@ def _kv_row(causal, bq, bk, window=None, seq=None):
 
 
 def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
-         window=None):
+         window=None, bd=None):
     if isinstance(blocks, Rows):
         return _fwd_row(qkv, tables, heads, sm_scale, blocks, interpret,
                         window)
@@ -928,10 +1112,11 @@ def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
         qkv, heads, kv_heads, g)
     b, s, _ = q.shape
     nk = s // bk
-    kv_row = _kv_row(causal, bq, bk, window, s)
+    bd = _bd_of(bd, s, bq)
+    kv_row = _kv_row(causal, bq, bk, window, s, bd)
     kv_of = _kv_group_of(heads, kv_heads, g)
     kernel = _static(_fwd_kernel, s, heads, kv_heads, d, causal, sm_scale,
-                     blocks, window)
+                     blocks, window, bd=bd)
     in_specs = [_rows_spec(bq, width, _outer, q0),
                 _rows_spec(bk, kv_width, kv_row, k0, kv_of),
                 _rows_spec(bk, kv_width, kv_row, v0, kv_of)]
@@ -947,7 +1132,8 @@ def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
     ]
     o, lse = pl.pallas_call(
         kernel,
-        grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
+        grid=(b, heads // g, s // bq,
+              _inner_blocks(s, blocks, window, bd=bd)),
         in_specs=in_specs,
         out_specs=[
             _rows_spec(bq, width, _outer),
@@ -960,7 +1146,7 @@ def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
         scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name=_name('flash_fwd', window),
+        name=_name('flash_fwd', window, bd),
     )(*operands)
     return o, lse
 
@@ -971,9 +1157,9 @@ def _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, blocks, interpret,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
                *scratch, sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes,
-               window, n_inner, group, tables=None):
+               window, n_inner, group, bd=None, tables=None):
     qi, j = pl.program_id(2), pl.program_id(3)
-    ki = _inner_block(qi, j, bq, bk, window)
+    ki = _inner_block(qi, j, bq, bk, window, bd=bd)
     blocks = _lane_blocks(g, d, lanes)
 
     def delta_of(cols, h, keep):
@@ -1044,7 +1230,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
                 lambda cols, h, keep: delta_scr[h])
 
     _for_each_tile_kind(tile, qi, ki, bq, bk, nq * bq, causal,
-                        window=window)
+                        window=window, bd=bd)
 
     @pl.when(j == n_inner - 1)
     def _emit():
@@ -1055,7 +1241,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, delta_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *scratch,
                 sm_scale, fold, causal, bq, bk, nq, nk, g, d, lanes, window,
-                n_inner, group, tables=None):
+                n_inner, group, bd=None, tables=None):
     # With grouped kv heads the head dimension of the grid walks the kv
     # heads, and the inner dimension the group's query heads, ``g`` at a
     # step, each over the q-blocks: dk and dv of the kv head add up over
@@ -1063,7 +1249,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     steps = group // g if group > 1 else 1
     ki, step = pl.program_id(2), pl.program_id(3)
     j = step % n_inner if steps > 1 else step
-    qi = _inner_block(ki, j, bk, bq, window, transposed=True)
+    qi = _inner_block(ki, j, bk, bq, window, transposed=True, bd=bd)
     blocks = _lane_blocks(g, d, lanes)
 
     def grads(readers, cols, heads, parts):
@@ -1129,7 +1315,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           window=window)
     else:
         _for_each_tile_kind(lambda mask: add([(0, bq, mask)]), qi, ki, bq, bk,
-                            nq * bq, causal, transposed=True, window=window)
+                            nq * bq, causal, transposed=True, window=window,
+                            bd=bd)
 
     @pl.when(step == steps * n_inner - 1)
     def _emit():
@@ -1140,7 +1327,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dq(qkv, tables, do, o, lse, heads, kv_heads, causal, sm_scale, blocks,
-        interpret, window=None):
+        interpret, window=None, bd=None):
     """``(dq, delta)``: ``delta = rowsum(dO * O)`` of each head is
     computed here, from the two merged tensors a block at a time, and
     left as ``[b, h, 1, s]`` for ``flash_dkv``."""
@@ -1151,11 +1338,12 @@ def _dq(qkv, tables, do, o, lse, heads, kv_heads, causal, sm_scale, blocks,
     ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
         qkv, heads, kv_heads, g)
     b, s, _ = do.shape
-    kv_row = _kv_row(causal, bq, bk, window, s)
+    bd = _bd_of(bd, s, bq)
+    kv_row = _kv_row(causal, bq, bk, window, s, bd)
     kv_of = _kv_group_of(heads, kv_heads, g)
     q_spec, row_spec = _rows_spec(bq, width, _outer), _stat_spec(g, bq, _outer)
     kernel = _static(_dq_kernel, s, heads, kv_heads, d, causal, sm_scale,
-                     blocks, window)
+                     blocks, window, bd=bd)
     in_specs = [_rows_spec(bq, width, _outer, q0),
                 _rows_spec(bk, kv_width, kv_row, k0, kv_of),
                 _rows_spec(bk, kv_width, kv_row, v0, kv_of),
@@ -1167,7 +1355,8 @@ def _dq(qkv, tables, do, o, lse, heads, kv_heads, causal, sm_scale, blocks,
         operands += tuple(tables) * 2
     return pl.pallas_call(
         kernel,
-        grid=(b, heads // g, s // bq, _inner_blocks(s, blocks, window)),
+        grid=(b, heads // g, s // bq,
+              _inner_blocks(s, blocks, window, bd=bd)),
         in_specs=in_specs,
         # dq as q is held: an array of its own, or the first run of
         # one (whose other columns this call leaves unwritten)
@@ -1179,12 +1368,12 @@ def _dq(qkv, tables, do, o, lse, heads, kv_heads, causal, sm_scale, blocks,
             pltpu.VMEM((g, bq, 1), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name=_name('flash_dq', window),
+        name=_name('flash_dq', window, bd),
     )(*operands)
 
 
 def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
-         blocks, interpret, window=None, dqkv=None):
+         blocks, interpret, window=None, dqkv=None, bd=None):
     """``(dk, dv)``; or with ``dqkv``, the ``[b, s, (heads + 2 kv_heads)
     * d]`` array whose first run ``flash_dq`` wrote, ``(dqkv, dv)``: dk
     goes into the second run of that array, in place. With grouped kv
@@ -1198,7 +1387,8 @@ def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
     ((q, q0), (k, k0), (v, v0)), d, width, kv_width, lanes = _operands(
         qkv, heads, kv_heads, g)
     b, s, _ = do.shape
-    n_inner = _inner_blocks(s, blocks, window, transposed=True)
+    bd = _bd_of(bd, s, bq)
+    n_inner = _inner_blocks(s, blocks, window, transposed=True, bd=bd)
     grouped = kv_heads != heads
     steps = heads // kv_heads // g if grouped else 1
 
@@ -1206,8 +1396,18 @@ def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
         return i % n_inner if steps > 1 else i
     # the grid iterates q-blocks innermost for each kv-block; the dead
     # causal tiles come first there, and ask for the first live q-block;
-    # a band call walks the q-blocks its kv-block is seen from
-    if window is not None:
+    # a band call walks the q-blocks its kv-block is seen from; under
+    # the block-diffusion mask a noised kv-block holds its own q-block
+    # throughout, a clean one walks both copies' q-blocks, the dead ones
+    # of each (before its own index) asking for the first live one
+    if bd is not None:
+        n = bd.tiles
+
+        def q_row(j, i):
+            qi = inner(i)
+            first = jnp.where(qi < n, j - n, j)
+            return jnp.where(j < n, j, jnp.maximum(qi, first))
+    elif window is not None:
         def q_row(j, i):
             return _band_fetch(j, inner(i), bk, bq, s, window,
                                transposed=True)
@@ -1224,7 +1424,7 @@ def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
     row_spec = _stat_spec(g, bq, q_row, q_of)
     acc = pltpu.VMEM((kv_width // lanes, bk, lanes), jnp.float32)
     kernel = _static(_dkv_kernel, s, heads, kv_heads, d, causal, sm_scale,
-                     blocks, window, transposed=True)
+                     blocks, window, transposed=True, bd=bd)
     in_specs = [_rows_spec(bq, width, q_row, q0, q_of),
                 _rows_spec(bk, kv_width, _outer, k0),
                 _rows_spec(bk, kv_width, _outer, v0),
@@ -1259,7 +1459,7 @@ def _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal, sm_scale,
         input_output_aliases=aliases,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        name=_name('flash_dkv', window),
+        name=_name('flash_dkv', window, bd),
     )(*operands)
 
 
@@ -1670,21 +1870,21 @@ CHECKPOINT_NAMES = ('flash_o', 'flash_lse')
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9))
+                   nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(qkv, tables, heads, kv_heads, causal, sm_scale, plan, interpret,
-           window=None, named=False):
+           window=None, named=False, bd=None):
     """``qkv``: a tuple of q ``[b, s, heads * d]``, k and v ``[b, s,
     kv_heads * d]``, or of the one ``[b, s, (heads + 2 kv_heads) * d]``
     that holds them side by side; ``tables``: None, or the rotary
     positions' ``(cos, sin)``; ``o [b, s, heads * d]``."""
     return _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan.fwd,
-                interpret, window)[0]
+                interpret, window, bd)[0]
 
 
 def _flash_fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan,
-               interpret, window, named):
+               interpret, window, named, bd):
     o, lse = _fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan.fwd,
-                  interpret, window)
+                  interpret, window, bd)
     if named:
         # both as the kernel writes them: o lane-dense, what the output
         # projection reads; lse [b, h, 1, s], which XLA tiles T(1, 128)
@@ -1695,21 +1895,21 @@ def _flash_fwd(qkv, tables, heads, kv_heads, causal, sm_scale, plan,
 
 
 def _flash_bwd(heads, kv_heads, causal, sm_scale, plan, interpret, window,
-               named, res, do):
+               named, bd, res, do):
     qkv, tables, o, lse = res
     # (the position tables are constants: the None beside the cotangent
     # of qkv is theirs)
     dq, delta = _dq(qkv, tables, do, o, lse, heads, kv_heads, causal,
-                    sm_scale, plan.dq, interpret, window)
+                    sm_scale, plan.dq, interpret, window, bd)
     if len(qkv) == 3:
         dk, dv = _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal,
-                      sm_scale, plan.dkv, interpret, window)
+                      sm_scale, plan.dkv, interpret, window, bd=bd)
         return (dq, dk, dv), None
     # the cotangent of one array that holds q, k and v is one array: dq
     # is its first run as flash_dq returns it, flash_dkv writes dk into
     # the second in place, and dv is written over the last
     dqkv, dv = _dkv(qkv, tables, do, lse, delta, heads, kv_heads, causal,
-                    sm_scale, plan.dkv, interpret, window, dqkv=dq)
+                    sm_scale, plan.dkv, interpret, window, dqkv=dq, bd=bd)
     return (jax.lax.dynamic_update_slice_in_dim(
         dqkv, dv, dqkv.shape[-1] - dv.shape[-1], axis=2),), None
 
@@ -1732,15 +1932,32 @@ def _blocks(heads, head_dim, seq, targets, block_q, block_k, group=1):
                                           *sizes, per_block))
 
 
+# The square tile of every kernel under the block-diffusion mask: the
+# backward pair's target of a full call, and the forward's too: its 1024
+# x 1024 would leave 80 of the square's 256 tiles live at 8192 positions
+# a copy, 19% over the pairs that are (288 of 1024 tiles at 512: 12.5%).
+_BD_TILE = 512
+
+
 def _plan(shape, causal, block_q=None, block_k=None, window=None,
-          kv_heads=None):
+          kv_heads=None, block_diffusion=None):
     """The static plan of a call on ``h`` heads of ``d`` over ``s``
     positions (``shape``: [b, h, s, d]): for each kernel the block sizes
     (the arguments, else the kernel's targets, cut to divisors of ``s``)
     and the heads a grid step holds, in whole lane blocks (with
-    ``kv_heads`` fewer than ``h``: query heads of one group)."""
+    ``kv_heads`` fewer than ``h``: query heads of one group). Under
+    ``block_diffusion`` the tiles are square and divide a copy's ``s /
+    2`` rows."""
     _, h, s, d = shape
     group = h // (kv_heads or h)
+    if block_diffusion is not None:
+        asked = block_q or block_k
+        if block_q and block_k and block_q != block_k:
+            raise ValueError('flash_attention: the block-diffusion mask '
+                             'takes square tiles, not %d x %d'
+                             % (block_q, block_k))
+        return Plan(*[_blocks(h, d, s // 2, (_BD_TILE, _BD_TILE), asked,
+                              asked, group)] * 3)
     if _band_form(window, s, group, block_q or block_k) == 'row':
         return Plan(**{kernel: _rows(h, d, s, window, *targets)
                        for kernel, targets in _ROW_TARGETS.items()})
@@ -1749,16 +1966,20 @@ def _plan(shape, causal, block_q=None, block_k=None, window=None,
                    _block_targets(s, causal, window).items()})
 
 
-def _plan_tags(plan, seq, causal, window=None):
+def _plan_tags(plan, seq, causal, window=None, block_diffusion=None):
     """The plan as the ``flash.plan`` event records it. Per kernel
     (the forward's keys have no prefix): blocks, heads a step, whether
     one inner block is the row, and per (batch, head) the tiles it
     computes on (``tile_q`` x ``tile_k``: the block, or under the causal
     one-pass the squares of the live row), those that hold an unmasked
     position and those the causal diagonal crosses. For a band call
-    the tiles are the band's: the grid walks no others."""
+    the tiles are the band's: the grid walks no others. Under the
+    block-diffusion mask ``tiles`` are those of the whole ``2 L x 2 L``
+    square: ``live_tiles / tiles`` is the quarter that is live and the
+    three diagonals' excess."""
     tags = {'band_form': None if window is None else
-            'row' if isinstance(plan.fwd, Rows) else 'tiles'}
+            'row' if isinstance(plan.fwd, Rows) else 'tiles',
+            'block_diffusion': block_diffusion}
     for prefix, blocks in zip(('', 'dq_', 'dkv_'), plan):
         transposed = prefix == 'dkv_'
         if isinstance(blocks, Rows):
@@ -1776,7 +1997,10 @@ def _plan_tags(plan, seq, causal, window=None):
             tq, tk = bq, bk
             if causal and one_pass:
                 tq = tk = bk if transposed else bq
-            tiles = _tile_counts(seq, tq, tk, causal, window, transposed)
+            if block_diffusion is not None:
+                tiles = _bd_tile_counts(seq // 2 // bq)
+            else:
+                tiles = _tile_counts(seq, tq, tk, causal, window, transposed)
         tiles, live, masked = tiles
         tags.update({prefix + 'block_q': bq, prefix + 'block_k': bk,
                      prefix + 'heads_per_step': g,
@@ -1788,7 +2012,8 @@ def _plan_tags(plan, seq, causal, window=None):
 
 
 def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
-                    block_k=None, interpret=None, window=None):
+                    block_k=None, interpret=None, window=None,
+                    block_diffusion=None):
     """Exact attention over [batch, heads, seq, head_dim] tensors.
 
     Differentiable (custom VJP, flash backward). Requires ``seq`` to
@@ -1807,6 +2032,13 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     divisor of its count (grouped kv heads; ``supports``): query head
     ``i`` attends kv head ``i // group``.
 
+    ``block_diffusion = B`` (static; with ``causal=False`` and no
+    ``window``) is the block-diffusion training mask over the ``seq = 2
+    L`` rows of a noised copy followed by the clean one, in blocks of
+    ``B`` positions (the section "A block-diffusion call" above has the
+    four rules); the kernels are named ``flash_fwd_bd``, ``flash_dq_bd``
+    and ``flash_dkv_bd``.
+
     The kernels work on ``[batch, seq, heads * head_dim]``
     (:func:`flash_attention_merged`); this is that call between the
     transposes into its layout and out of it, for callers that hold
@@ -1819,13 +2051,14 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     b, h, s, d = q.shape
     o = _planned(tuple(jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, -1)
                        for x in (q, k, v)), None, h, k.shape[1], causal,
-                 sm_scale, block_q, block_k, interpret, window, named=False)
+                 sm_scale, block_q, block_k, interpret, window, named=False,
+                 block_diffusion=block_diffusion)
     return jnp.transpose(o.reshape(b, s, h, d), (0, 2, 1, 3))
 
 
 def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
                            interpret=None, window=None, rotary=None,
-                           kv_heads=None):
+                           kv_heads=None, block_diffusion=None):
     """:func:`flash_attention` in the kernels' own layout, which is the
     model's: ``qkv`` is the qkv projection's output
     ``[batch, seq, (heads + 2 * kv_heads) * head_dim]`` (q, k and v side
@@ -1846,7 +2079,10 @@ def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
     operands' dtype, ``flash_dq`` and ``flash_dkv`` turn dq and dk back,
     and the cotangent is that of the unrotated ``qkv``: the call is
     ``flash_attention_merged`` of the rotated q and k, without their
-    copies. With ``rotary=None`` nothing of it is on the path.
+    copies. With ``rotary=None`` nothing of it is on the path. The
+    tables are read by row, so positions that repeat (``0 .. L - 1``
+    twice under ``block_diffusion``) are tables made from such
+    positions.
 
     The forward rule's residuals are named for a checkpoint policy
     (``CHECKPOINT_NAMES``): that output, and ``lse``. Under
@@ -1857,7 +2093,8 @@ def flash_attention_merged(qkv, heads, causal=True, sm_scale=None,
     if not isinstance(qkv, (tuple, list)):
         qkv = (qkv,)
     return _planned(tuple(qkv), rotary, heads, kv_heads or heads, causal,
-                    sm_scale, None, None, interpret, window, named=True)
+                    sm_scale, None, None, interpret, window, named=True,
+                    block_diffusion=block_diffusion)
 
 
 def rotary_angles(positions, theta, head_dim):
@@ -1901,7 +2138,8 @@ def saved_bytes(shape, dtype, v_dim=None):
 
 
 def _planned(qkv, tables, heads, kv_heads, causal, sm_scale, block_q, block_k,
-             interpret, window, named):
+             interpret, window, named, block_diffusion=None):
+    block_diffusion = check_block_diffusion(block_diffusion, causal, window)
     window = check_window(window, causal)
     causal = causal and window is None     # a causal band holds the mask
     b, s, _ = qkv[0].shape
@@ -1927,8 +2165,16 @@ def _planned(qkv, tables, heads, kv_heads, causal, sm_scale, block_q, block_k,
     if sm_scale is None:
         sm_scale = d ** -0.5
     sm_scale = float(sm_scale)
+    if block_diffusion is not None:
+        if not supports((b, heads, s, d), block_q or block_k or _LANES,
+                        kv_heads=kv_heads, block_diffusion=block_diffusion):
+            raise ValueError(
+                'flash_attention: block_diffusion=%d over %d rows (two '
+                'copies of %s) is not supported: a copy splits into tiles '
+                'of whole blocks whose length is a power of two; check '
+                'supports() first' % (block_diffusion, s, s / 2))
     plan = _plan((b, heads, s, d), causal, block_q, block_k, window,
-                 kv_heads)
+                 kv_heads, block_diffusion)
     if interpret is None:
         interpret = _interpret_default()
     telemetry.get().loop_event(
@@ -1937,9 +2183,9 @@ def _planned(qkv, tables, heads, kv_heads, causal, sm_scale, block_q, block_k,
         window=None if window is None else list(window),
         layout='bsd', lane_block=lanes, heads_per_lane_block=lanes // d,
         rotary=tables is not None, kv_heads=kv_heads,
-        **_plan_tags(plan, s, causal, window))
+        **_plan_tags(plan, s, causal, window, block_diffusion))
     return _flash(qkv, tables, heads, kv_heads, causal, sm_scale, plan,
-                  interpret, window, named)
+                  interpret, window, named, block_diffusion)
 
 
 # ---------------------------------------------------------------------------

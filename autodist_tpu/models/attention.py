@@ -17,7 +17,8 @@ from jax.sharding import PartitionSpec as P
 
 from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
 from autodist_tpu.kernels import flash_attention as fa
-from autodist_tpu.models.core import Dense, Module, RMSNorm, constrain
+from autodist_tpu.models.core import (Dense, Module, RMSNorm, constrain,
+                                      group_mean)
 from autodist_tpu.parallel.axes import (active_manual_axes, ctx_option,
                                         current_mesh, live_mesh_axis,
                                         manual_axis, shard_map,
@@ -93,6 +94,45 @@ def rotary(x, positions, theta, heads=None):
             + turned.astype(jnp.float32) * sin).astype(x.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def head_rms_norm(x, scale, d, eps):
+    """``x [..., lanes]`` with each of its first ``len(scale) / d`` heads
+    of ``d`` lanes RMS-normalised over its own lanes and multiplied by
+    its lanes of ``scale`` (f32), the lanes behind them passed through;
+    computed in f32, in ``x``'s dtype and layout. The backward is written
+    out over the same runs of lanes, so that neither pass reshapes the
+    lanes into heads."""
+    return _head_rms_norm(x, scale, d, eps)[0]
+
+
+def _head_rms_norm(x, scale, d, eps):
+    normed = scale.shape[0]
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(group_mean(jnp.square(x32), normed // d, d) + eps)
+    lane = jnp.arange(x.shape[-1])
+    w = jnp.pad(scale, (0, x.shape[-1] - normed))
+    y = jnp.where(lane < normed, x32 * inv * w, x32)
+    return y.astype(x.dtype), (x, scale)
+
+
+def _head_rms_norm_bwd(d, eps, res, dy):
+    x, scale = res
+    normed = scale.shape[0]
+    x32, dy32 = x.astype(jnp.float32), dy.astype(jnp.float32)
+    inv = jax.lax.rsqrt(group_mean(jnp.square(x32), normed // d, d) + eps)
+    unit = x32 * inv
+    lane = jnp.arange(x.shape[-1])
+    u = dy32 * jnp.pad(scale, (0, x.shape[-1] - normed))
+    dx = inv * (u - unit * group_mean(u * unit, normed // d, d))
+    dx = jnp.where(lane < normed, dx, dy32)
+    rows = tuple(range(x.ndim - 1))
+    return dx.astype(x.dtype), jnp.sum(dy32 * unit, axis=rows)[:normed]
+
+
+head_rms_norm.defvjp(lambda x, scale, d, eps: _head_rms_norm(x, scale, d, eps),
+                     _head_rms_norm_bwd)
+
+
 class MultiHeadAttention(Module):
     """Causal (or full) self-attention; [batch, seq, embed] in/out.
 
@@ -113,11 +153,26 @@ class MultiHeadAttention(Module):
     ``causal`` the band is ``(left, 0)``. It is handed to every
     attention path that takes one: the flash kernels, their
     nested-manual route under dp/tp, and the XLA path. The
-    sequence-parallel paths take none and raise."""
+    sequence-parallel paths take none and raise.
+
+    ``qk_norm`` (Qwen3's ``q_norm`` / ``k_norm``): an RMSNorm over the
+    ``head_dim`` lanes of every q head and every k head, one weight of
+    ``head_dim`` for q and one for k that the heads share, between the
+    projection and the rotation; under XLA on every path, scope
+    ``qk_norm``.
+
+    ``block_diffusion = B`` (with ``causal=False``, no ``window``): ``x``
+    is the ``2 L`` rows of a noised copy of a sequence followed by the
+    clean one, row ``i`` of either at position ``i`` (:meth:`positions`),
+    under the block-diffusion mask in blocks of ``B``
+    (``kernels/flash_attention.py``: the kernels ``flash_*_bd``; on the
+    XLA path the same mask as a boolean array). The sequence-parallel
+    paths raise."""
 
     def __init__(self, dim, num_heads, head_dim=None, causal=True,
                  dtype=jnp.float32, rope_theta=None, window=None,
-                 num_kv_heads=None, rope_yarn=None):
+                 num_kv_heads=None, rope_yarn=None, qk_norm=False,
+                 norm_eps=1e-6, block_diffusion=None):
         self.dim = dim
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads or num_heads
@@ -133,6 +188,8 @@ class MultiHeadAttention(Module):
             rope_theta, self.head_dim, rope_yarn)
         if isinstance(window, int):
             window = (window, window)
+        self.block_diffusion = fa.check_block_diffusion(block_diffusion,
+                                                        causal, window)
         self.window = fa.check_window(window, causal)
         h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
         # the runs of q, k and v in the projection's output
@@ -142,9 +199,42 @@ class MultiHeadAttention(Module):
                           use_bias=False, dtype=dtype)
         self.wo = Dense(h * d, dim, 'heads', 'embed',
                         use_bias=False, dtype=dtype)
+        self.q_norm = self.k_norm = None
+        if qk_norm:
+            self.q_norm, self.k_norm = (
+                RMSNorm(d, axis_name=None, eps=norm_eps, dtype=dtype)
+                for _ in range(2))
 
     def param_defs(self):
-        return {'qkv': self.wqkv, 'out': self.wo}
+        d = {'qkv': self.wqkv, 'out': self.wo}
+        if self.q_norm is not None:
+            d.update(q_norm=self.q_norm, k_norm=self.k_norm)
+        return d
+
+    def positions(self, s):
+        """The position of each of ``s`` rows in the current trace, the
+        one place the rotary tables of the kernels and the XLA path's
+        rotation take them from: row ``i`` at ``i`` (offset by the
+        shard's start under sequence parallelism), under
+        ``block_diffusion`` ``0 .. s / 2 - 1`` twice."""
+        pos = jnp.arange(s)
+        seq_axis = manual_axis(AXIS_SEQUENCE)
+        if seq_axis is not None:
+            pos = pos + jax.lax.axis_index(seq_axis) * s
+        if self.block_diffusion is not None:
+            pos = pos % (s // 2)
+        return pos
+
+    @jax.named_scope('qk_norm')
+    def _qk_normed(self, params, qkv):
+        """The projection's output with every q head and every k head
+        RMS-normalised over its own lanes (v as it is), in the same
+        layout (:func:`head_rms_norm`)."""
+        h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
+        scale = jnp.concatenate(
+            [jnp.tile(params['q_norm']['scale'], h),
+             jnp.tile(params['k_norm']['scale'], kv)]).astype(jnp.float32)
+        return head_rms_norm(qkv, scale, d, self.q_norm.eps)
 
     def apply(self, params, x, tables=None):
         """``tables``: what ``position_tables`` gives for ``x``, where a
@@ -152,6 +242,8 @@ class MultiHeadAttention(Module):
         b, s, _ = x.shape
         h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
         qkv = self.wqkv.apply(params['qkv'], x)     # [b, s, (h + 2 kv) d]
+        if self.q_norm is not None:
+            qkv = self._qk_normed(params, qkv)
         local = self.kernel_shape((b, h, s, d))
         if local is not None:
             # long device-local sequences: the Pallas flash kernels
@@ -176,9 +268,7 @@ class MultiHeadAttention(Module):
         seq_axis = manual_axis(AXIS_SEQUENCE)
         if self.rope is not None:
             # global positions, as the position table's (transformer.py)
-            pos = jnp.arange(s)
-            if seq_axis is not None:
-                pos = pos + jax.lax.axis_index(seq_axis) * s
+            pos = self.positions(s)
             q = rotary(q, pos, self.rope)
             k = rotary(k, pos, self.rope)
         if kv != h:
@@ -189,6 +279,12 @@ class MultiHeadAttention(Module):
         # a band under a causal mask holds the mask: (left, 0)
         causal = self.causal and window is None
         if seq_axis is not None:
+            if self.block_diffusion is not None:
+                raise ValueError(
+                    'block-diffusion attention under sequence parallelism: '
+                    'ring_attention and ulysses_attention know the causal '
+                    'mask only, and a shard would hold rows of one copy; '
+                    'use sp=1')
             if window is not None:
                 raise ValueError(
                     'attention window %r under sequence parallelism: '
@@ -200,7 +296,10 @@ class MultiHeadAttention(Module):
             else:
                 o = ring_attention(q, k, v, seq_axis, causal=causal)
         else:
-            o = local_flash_attention(q, k, v, causal=causal, window=window)
+            mask = None if self.block_diffusion is None else \
+                fa.block_diffusion_mask(s, self.block_diffusion)
+            o = local_flash_attention(q, k, v, causal=causal, window=window,
+                                      mask=mask)
             o = constrain(o, ('batch', 'heads', 'seq', 'kv'))
         o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, s, h * d)
         return self.wo.apply(params['out'], o)
@@ -216,7 +315,7 @@ class MultiHeadAttention(Module):
         if local is None:
             return None
         with jax.named_scope('rotary'):
-            return fa.rotary_tables(jnp.arange(shape[2]), self.rope,
+            return fa.rotary_tables(self.positions(shape[2]), self.rope,
                                     local[1], self.head_dim)
 
     def _kernel_attention(self, qkv, local_heads, tables):
@@ -248,7 +347,8 @@ class MultiHeadAttention(Module):
         def attend(operands, tables):
             return fa.flash_attention_merged(
                 operands, local_heads, causal=self.causal,
-                window=self.window, rotary=tables, kv_heads=kv_heads)
+                window=self.window, rotary=tables, kv_heads=kv_heads,
+                block_diffusion=self.block_diffusion)
 
         if mesh is None:
             return attend(operands, tables)
@@ -276,7 +376,8 @@ class MultiHeadAttention(Module):
         """``fa.preferred`` for a device's ``[b, h, s, d]`` of q, with
         its share of the kv heads."""
         kv_heads, rest = divmod(local[1] * self.num_kv_heads, self.num_heads)
-        return not rest and fa.preferred(local, self.window, kv_heads)
+        return not rest and fa.preferred(local, self.window, kv_heads,
+                                         self.block_diffusion)
 
     # -- nested-manual flash under dp/tp GSPMD -----------------------------
     def _tp_manual_shape(self, shape):

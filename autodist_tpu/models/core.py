@@ -369,6 +369,25 @@ class RMSNorm(Module):
                 * params['scale']).astype(self.dtype)
 
 
+def group_mean(t, groups, width):
+    """``t [..., lanes]`` with the lanes of each of the first ``groups``
+    runs of ``width`` lanes replaced by the run's mean, and 0 on the
+    lanes behind them: a run reduced where it lies, and the means put
+    back on their lanes by selects. (Reshaped to ``[..., groups, width]``
+    XLA lays the whole f32 tensor out anew for the reduction, copies
+    without a name in every pass: 0.27 GB a layer at 16,384 tokens x
+    4096 in ``GatedGroupRMSNorm``, three of 671 MB a pass at 32,768 rows
+    of 5120 lanes in attention's q/k norm, PERF.md section 6, PR 41 and
+    45; the runs concatenated cost a pass over the tensor each.)"""
+    lane_group = jnp.arange(t.shape[-1]) // width
+    out = jnp.zeros((), t.dtype)
+    for g in range(groups):
+        mean = jnp.mean(t[..., g * width:(g + 1) * width], axis=-1,
+                        keepdims=True)
+        out = jnp.where(lane_group == g, mean, out)
+    return out
+
+
 class GatedGroupRMSNorm(Module):
     """``RMSNorm(x * silu(gate))`` in f32 with the mean of squares taken
     over each of ``groups`` groups of ``dim / groups`` lanes, one scale
@@ -385,25 +404,10 @@ class GatedGroupRMSNorm(Module):
     def param_defs(self):
         return {'scale': ParamDef((self.dim,), (self.axis_name,), 'ones')}
 
-    def _group_mean(self, t):
-        """``t [..., dim]`` with every lane replaced by the mean over its
-        group: a group's run of lanes reduced, and the ``groups`` means
-        put back on their lanes by selects. (Reshaped to ``[..., groups,
-        dim / groups]`` XLA re-tiles the whole f32 tensor, a copy without
-        a name in every pass, 0.27 GB a layer at 16,384 tokens x 4096;
-        the runs concatenated cost a pass over the tensor each.)"""
-        width = self.dim // self.groups
-        lane_group = jnp.arange(self.dim) // width
-        out = jnp.zeros((), t.dtype)
-        for g in range(self.groups):
-            mean = jnp.mean(t[..., g * width:(g + 1) * width], axis=-1,
-                            keepdims=True)
-            out = jnp.where(lane_group == g, mean, out)
-        return out
-
     def apply(self, params, x, gate):
         x32 = x.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
-        ms = self._group_mean(jnp.square(x32))
+        ms = group_mean(jnp.square(x32), self.groups,
+                        self.dim // self.groups)
         y = x32 * jax.lax.rsqrt(ms + self.eps)
         return (y * params['scale']).astype(self.dtype)
 

@@ -148,6 +148,20 @@ class TransformerConfig:
     #                              with heads, head_dim, groups, state,
     #                              conv and, optionally, the draw's
     #                              dt_min, dt_max, dt_floor, a_range
+    qk_norm: bool = False        # an RMSNorm (norm_eps) over every q head
+    #                              and every k head before the rotation, one
+    #                              weight of head_dim each for q and for k
+    #                              (Qwen3's q_norm / k_norm)
+    # -- the objective. None: one row of logits a token of `tokens`,
+    # scored against `targets` (next-token or masked-LM, by the batch).
+    # A block length B: a block-diffusion step (BD3-LM, SDAR): the stack
+    # runs on the 2 L rows [tokens ; targets] of every sequence, the
+    # noised copy and then the clean one, under the block-diffusion mask
+    # in blocks of B (kernels/flash_attention.py), row i of either copy at
+    # position i; the final norm, the head and the loss on the L noised
+    # rows alone, at the same position (no shift), weighed by the batch's
+    # `mask` (TransformerLM.loss)
+    block_length: object = None
 
     def __post_init__(self):
         if self.positions not in POSITIONS:
@@ -187,6 +201,16 @@ class TransformerConfig:
                                  or self.window is not None):
             raise ValueError('latent attention takes rotary positions and '
                              'no window')
+        if self.block_length is not None and (
+                self.window is not None or self.latent_rank or self.mixers
+                or self.positions == 'learned' or self.loss_chunk):
+            raise ValueError(
+                'block_length=%r (the block-diffusion objective) runs '
+                'attention-then-MLP blocks of one kind under its own mask: '
+                'no window (the band and this mask are two descriptions of '
+                'live pairs), no latent attention, no single-mixer layers, '
+                'no learned position table (a row\'s position is not its '
+                'index) and no chunked loss' % (self.block_length,))
         if self.dense_lead and not (self.moe_experts and 0 < self.dense_lead
                                     < self.n_layers):
             raise ValueError('dense_lead=%d: the dense layers lead a stack '
@@ -364,10 +388,15 @@ class Block(Module):
         else:
             self.attn = MultiHeadAttention(
                 cfg.dim, cfg.n_heads, head_dim=cfg.head_dim,
-                causal=cfg.causal, dtype=cfg.dtype, rope_theta=theta,
+                # (the block-diffusion mask holds the clean copy's
+                # block-causal one)
+                causal=cfg.causal and cfg.block_length is None,
+                dtype=cfg.dtype, rope_theta=theta,
                 window=cfg.window if windowed else None,
                 num_kv_heads=cfg.n_kv_heads,
-                rope_yarn=None if windowed else cfg.rope_yarn)
+                rope_yarn=None if windowed else cfg.rope_yarn,
+                qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps,
+                block_diffusion=cfg.block_length)
         self.ln2 = _norm(cfg)
         # (a leading dense layer of a stack of expert layers: `dense`)
         self.sparse = bool(cfg.moe_experts) and not dense
@@ -640,7 +669,10 @@ class TransformerLM(Module):
 
     def hidden_with_aux(self, params, tokens):
         """Final hidden states (post ln_f) and the MoE aux loss —
-        everything except the lm-head, so losses can chunk the head."""
+        everything except the lm-head, so losses can chunk the head.
+        Under the block-diffusion objective ``tokens`` are the ``2 L``
+        rows of each sequence (:meth:`_step_rows`) and the states are
+        those of the ``L`` noised rows."""
         cfg = self.cfg
         x = self._embedded(params, tokens)
         tables = self._position_tables(x)
@@ -655,6 +687,12 @@ class TransformerLM(Module):
         self._note_remat(x)
         if pipe_axis is not None:
             self._check_pipelined()
+            if cfg.block_length is not None:
+                raise ValueError(
+                    'the block-diffusion objective under pipeline '
+                    'parallelism: the schedules score every row of a '
+                    'microbatch against `targets`, and here the clean copy '
+                    'is input and half the rows have no loss; use pp=1')
             from autodist_tpu.parallel.pipeline import gpipe, one_f_one_b
             pipe_fn = one_f_one_b \
                 if ctx_option('pp_schedule', 'gpipe') == '1f1b' else gpipe
@@ -684,8 +722,29 @@ class TransformerLM(Module):
             aux_total, load = aux_total
             self._count_load(load)
         with jax.named_scope('head_loss'):
+            if cfg.block_length is not None:
+                x = x[:, :x.shape[1] // 2]          # the noised rows
             x = self.ln_f.apply(params['ln_f'], x)
         return x, aux_total
+
+    def _step_rows(self, batch):
+        """The ids the stack runs on: ``batch['tokens']``, or under the
+        block-diffusion objective each sequence's noised copy followed
+        by its clean one, ``[b, 2 L]``, with the step's counter
+        ``bd_mask_rows``: the share of those rows that the noise
+        replaced (whose id is the mask's)."""
+        tokens = batch['tokens']
+        if self.cfg.block_length is None:
+            return tokens
+        clean = batch['targets']
+        if tokens.shape[1] % self.cfg.block_length:
+            raise ValueError('a sequence of %d positions is no whole number '
+                             'of blocks of %d' % (tokens.shape[1],
+                                                  self.cfg.block_length))
+        if not active_manual_axes():
+            record_counter('bd_mask_rows', jnp.mean(
+                (tokens != clean).astype(jnp.float32)) / 2)
+        return jnp.concatenate([tokens, clean], axis=1)
 
     def _count_load(self, load):
         """The step's counters of the expert layers (``load``: rows held
@@ -756,13 +815,16 @@ class TransformerLM(Module):
         """One ``transformer.layers`` point event a trace of a patterned
         stack: how it is run (docs/design/observability.md). The plain
         model, one scan step a layer, leaves none."""
-        if not self.patterned:
-            return
         cfg = self.cfg
+        if not self.patterned and cfg.block_length is None:
+            return
         kinds = cfg.layer_kinds()
         single = {} if not cfg.mixers else dict(
             mixers=cfg.mixers, ssm_layers=cfg.mixers.count('M'),
             mlp_layers=cfg.mixers.count('E'))
+        if cfg.block_length is not None:
+            single = dict(objective='block_diffusion',
+                          block_length=cfg.block_length, rows_per_token=2)
         telemetry.get().loop_event(
             'transformer.layers', n_layers=len(kinds),
             period=len(self._period), periods=self._periods,
@@ -813,7 +875,10 @@ class TransformerLM(Module):
 
     def per_token_loss_with_aux(self, params, batch):
         """([batch, seq] token NLL, aux loss); expects {'tokens',
-        'targets'}.
+        'targets'}. Under the block-diffusion objective
+        (``cfg.block_length``) ``tokens`` is the noised copy of
+        ``targets``, both run through the stack (:meth:`_step_rows`),
+        and the NLL is that of the noised rows at their own positions.
 
         Shape-preserving on purpose: in sequence-parallel mode this runs
         inside shard_map over local seq shards and the trainer reduces.
@@ -824,7 +889,7 @@ class TransformerLM(Module):
         if pipe_axis is not None and \
                 ctx_option('pp_schedule', 'gpipe') == '1f1b':
             return self._loss_1f1b(params, batch, pipe_axis)
-        x, aux = self.hidden_with_aux(params, batch['tokens'])
+        x, aux = self.hidden_with_aux(params, self._step_rows(batch))
         b, s = targets.shape
         n = self._ce_chunks(s, b * s)
         with jax.named_scope('head_loss'):
@@ -912,7 +977,12 @@ class TransformerLM(Module):
         return n
 
     def loss(self, params, batch):
-        """Mean token cross-entropy (+ MoE balance loss), optional mask."""
+        """Mean token cross-entropy (+ MoE balance loss). With
+        ``batch['mask']``, ``[batch, seq]``, the positions' WEIGHTS: 0 /
+        1 to leave positions out, or any non-negative numbers (the
+        block-diffusion step's ``1 / t`` on the masked positions); the
+        loss is ``sum(w nll) / sum(w)`` (divided by the weights' sum, not
+        the count of positions; by 1 where the sum is smaller)."""
         nll, aux = self.per_token_loss_with_aux(params, batch)
         mask = batch.get('mask')
         with jax.named_scope('head_loss'):

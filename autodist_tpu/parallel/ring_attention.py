@@ -96,7 +96,8 @@ def ring_attention(q, k, v, axis_name, causal=True, sm_scale=None):
     return out.astype(q.dtype)
 
 
-def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None):
+def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None,
+                          mask=None):
     """Single-device exact attention with the same accumulation; used as
     the non-SP fallback so numerics match ring_attention bit-for-bit-ish.
 
@@ -105,7 +106,14 @@ def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None):
     the mask. A sequence several bands long is
     computed in query blocks against the keys each block's band reaches
     (:func:`_band_attention`), so no ``[s, s]`` score matrix exists; a
-    short one under a dense mask."""
+    short one under a dense mask. ``mask``: a boolean ``[queries, keys]``
+    array of the pairs that are live, for a mask that is neither (the
+    block-diffusion mask of a short sequence:
+    ``flash_attention.block_diffusion_mask``); given with
+    ``causal=False`` and no ``window``."""
+    if mask is not None and (causal or window is not None):
+        raise ValueError('local_flash_attention: a mask array is given '
+                         'with causal=False and window=None')
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if window is not None:
@@ -123,6 +131,8 @@ def local_flash_attention(q, k, v, causal=True, sm_scale=None, window=None):
     elif window is not None:
         ahead = jnp.arange(sk)[None, :] - jnp.arange(sq)[:, None]
         s = jnp.where((ahead >= -window[0]) & (ahead <= window[1]), s, -1e30)
+    elif mask is not None:
+        s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum('bhqk,bhkd->bhqd', p.astype(v.dtype), v)
 

@@ -1028,3 +1028,55 @@ def test_launcher_parent_initializes_no_backend(tmp_path):
     assert refused.returncode == 2, refused.stderr[-2000:]
     assert 'declares tpus: no list' in refused.stderr
     assert 'child ran' not in refused.stdout
+
+
+def test_block_diffusion_kernels_lower_for_tpu_at_the_published_shape(
+        monkeypatch):
+    """The three kernels under the block-diffusion mask at SDAR's shape
+    (PR 45: 2 sequences of 8192, so ``[2, 16384, 5120]`` of q, k, v side
+    by side in bf16, 32 query heads over 4 kv heads of 128, blocks of 4,
+    rotary tables whose positions repeat) lower for the TPU with their
+    gradient: the calls are ``flash_fwd_bd``, ``flash_dq_bd`` and
+    ``flash_dkv_bd``, each reads the projection's output ITSELF three
+    times and the tables four, the cotangent is one array of its shape,
+    and nothing of the step is a score square or by head."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.kernels import flash_attention as fa
+
+    monkeypatch.setattr(fa, '_interpret_default', lambda: False)
+    b, seq, heads, kv, d = 2, 8192, 32, 4, 128
+    rows = 2 * seq
+    assert fa.preferred((b, heads, rows, d), kv_heads=kv, block_diffusion=4)
+
+    def call(qkv):
+        tables = fa.rotary_tables(jnp.arange(rows) % seq, 1e6, heads, d)
+        return jnp.sum(fa.flash_attention_merged(
+            qkv, heads, causal=False, rotary=tables, kv_heads=kv,
+            block_diffusion=4).astype(jnp.float32))
+    text = jax.export.export(jax.jit(jax.value_and_grad(call)),
+                             platforms=['tpu'])(jax.ShapeDtypeStruct(
+                                 (b, rows, (heads + 2 * kv) * d),
+                                 jnp.bfloat16)).mlir_module()
+    names = re.findall(r'kernel_name = "(\w+)"', text)
+    assert sorted(names) == ['flash_dkv_bd', 'flash_dq_bd', 'flash_fwd_bd']
+    calls = {name: next(line for line in text.splitlines()
+                        if '@tpu_custom_call' in line and name in line)
+             for name in names}
+    whole, table = 'tensor<2x16384x5120xbf16>', 'tensor<16384x128xf32>'
+    for name, line in calls.items():
+        operands = line.split(' : (', 1)[1].split(') -> ')[0]
+        assert operands.startswith(', '.join([whole] * 3)), name
+        assert operands.count(table) == 4, name
+    assert calls['flash_dq_bd'].split(') -> ')[1].startswith(
+        '(%s, tensor<2x32x1x16384xf32>)' % whole)
+    # dk goes into dq's array in place; dv is the kv heads' own width
+    assert calls['flash_dkv_bd'].split(') -> ')[1].startswith(
+        '(%s, tensor<2x16384x512xbf16>)' % whole)
+    tensors = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|i1)>', text))
+    assert not [t for t in tensors if t.endswith('16384x16384')]
+    assert not [t for t in tensors
+                if re.search(r'(^|x)(16384x32x|32x16384x)128$', t)]
