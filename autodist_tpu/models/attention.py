@@ -15,8 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from autodist_tpu import telemetry
 from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
 from autodist_tpu.kernels import flash_attention as fa
+from autodist_tpu.kernels import qk_norm
 from autodist_tpu.models.core import (Dense, Module, RMSNorm, constrain,
                                       group_mean)
 from autodist_tpu.parallel.axes import (active_manual_axes, ctx_option,
@@ -158,8 +160,12 @@ class MultiHeadAttention(Module):
     ``qk_norm`` (Qwen3's ``q_norm`` / ``k_norm``): an RMSNorm over the
     ``head_dim`` lanes of every q head and every k head, one weight of
     ``head_dim`` for q and one for k that the heads share, between the
-    projection and the rotation; under XLA on every path, scope
-    ``qk_norm``.
+    projection and the rotation, scope ``qk_norm``: where attention
+    takes the flash kernels and ``kernels/qk_norm.py`` takes the shape
+    (heads of whole 128-lane blocks, rows in whole blocks) its kernel
+    pair on the projection's output where it lies, :func:`head_rms_norm`
+    under XLA on every other path; which, the point event
+    ``qk_norm.plan`` of a trace says.
 
     ``block_diffusion = B`` (with ``causal=False``, no ``window``): ``x``
     is the ``2 L`` rows of a noised copy of a sequence followed by the
@@ -226,15 +232,61 @@ class MultiHeadAttention(Module):
         return pos
 
     @jax.named_scope('qk_norm')
-    def _qk_normed(self, params, qkv):
+    def _qk_normed(self, params, qkv, local=None):
         """The projection's output with every q head and every k head
         RMS-normalised over its own lanes (v as it is), in the same
-        layout (:func:`head_rms_norm`)."""
+        layout: ``kernels/qk_norm.py``'s kernels where attention takes
+        the flash kernels (``local``: what :meth:`kernel_shape` said) and
+        they take a device's shape, :func:`head_rms_norm` in
+        ``jax.numpy`` otherwise; f32 inside either way. Under a mesh the
+        kernels run on each device's rows in a manual region, as
+        :meth:`_kernel_attention`'s; where heads are sharded, on a
+        shard's q heads and its k heads, two runs of their own."""
         h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
-        scale = jnp.concatenate(
-            [jnp.tile(params['q_norm']['scale'], h),
-             jnp.tile(params['k_norm']['scale'], kv)]).astype(jnp.float32)
-        return head_rms_norm(qkv, scale, d, self.q_norm.eps)
+        eps = self.q_norm.eps
+        scales = tuple(params[name]['scale'].astype(jnp.float32)
+                       for name in ('q_norm', 'k_norm'))
+        mesh = None if unsharded_execution() else current_mesh()
+        heads_axis = live_mesh_axis('heads') if mesh is not None else None
+        how = None
+        if local is not None:
+            rows, here = local[0] * local[2], local[1]
+            kv_here = here * kv // h
+            # (heads of all, normed heads) of each operand a device holds
+            runs = ((here, here), (kv_here, kv_here)) if heads_axis else \
+                ((here + 2 * kv_here, here + kv_here),)
+            plans = [qk_norm.plan(rows, heads * d, normed, d)
+                     for heads, normed in runs]
+            how = None if None in plans else plans[0]
+        telemetry.get().loop_event(
+            'qk_norm.plan', path='pallas' if how else 'xla', heads=h,
+            kv_heads=kv, head_dim=d,
+            block_rows=how.block_rows if how else None,
+            tile_lanes=how.tile if how else None,
+            passes_v='copy' if how else None)
+
+        def normed_by(form, h, kv):
+            """``form`` on ``(qkv,)``, or on ``(q, k)`` apart, of ``h``
+            and ``kv`` heads."""
+            def normed(operands, q_scale, k_scale):
+                lanes = (jnp.tile(q_scale, h), jnp.tile(k_scale, kv))
+                if len(operands) == 1:
+                    lanes = (jnp.concatenate(lanes),)
+                return tuple(form(x, scale, d, eps)
+                             for x, scale in zip(operands, lanes))
+            return normed
+        if how is None:
+            return normed_by(head_rms_norm, h, kv)((qkv,), *scales)[0]
+        kernels = normed_by(qk_norm.head_norm, here, kv_here)
+        if mesh is None:
+            return kernels((qkv,), *scales)[0]
+        operands = tuple(jnp.split(qkv, self._runs, axis=-1)) \
+            if heads_axis else (qkv,)
+        q_k, v = operands[:2], operands[2:]
+        data = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
+        specs = (P(data, None, heads_axis),) * len(q_k)
+        q_k = shard_map(kernels, mesh, (specs, P(), P()), specs)(q_k, *scales)
+        return jnp.concatenate(q_k + v, axis=-1) if v else q_k[0]
 
     def apply(self, params, x, tables=None):
         """``tables``: what ``position_tables`` gives for ``x``, where a
@@ -242,9 +294,9 @@ class MultiHeadAttention(Module):
         b, s, _ = x.shape
         h, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
         qkv = self.wqkv.apply(params['qkv'], x)     # [b, s, (h + 2 kv) d]
-        if self.q_norm is not None:
-            qkv = self._qk_normed(params, qkv)
         local = self.kernel_shape((b, h, s, d))
+        if self.q_norm is not None:
+            qkv = self._qk_normed(params, qkv, local)
         if local is not None:
             # long device-local sequences: the Pallas flash kernels
             # (the [s, s] score matrix never reaches HBM), in the layout

@@ -1080,3 +1080,69 @@ def test_block_diffusion_kernels_lower_for_tpu_at_the_published_shape(
     assert not [t for t in tensors if t.endswith('16384x16384')]
     assert not [t for t in tensors
                 if re.search(r'(^|x)(16384x32x|32x16384x)128$', t)]
+
+
+def test_qk_norm_lowers_for_tpu_at_the_published_shape(monkeypatch):
+    """The q/k norm's kernel pair at SDAR's shape (PR 46: the
+    projection's ``[2, 16384, 5120]`` in bf16, 32 q heads and 4 k heads
+    of 128 normed, v's 512 lanes behind them) lowers for the TPU with its
+    gradient, alone and inside the attention layer: both calls take the
+    projection's output ITSELF as ``[32768, 5120]`` and the scale's one
+    row; the forward returns one bf16 tensor, the backward ``dx`` and
+    the row blocks' partial sums; and the layer's step holds no f32
+    tensor of the projection's size and no reshape of its lanes into
+    heads."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.kernels import qk_norm as qn
+    from autodist_tpu.models.attention import MultiHeadAttention
+
+    for module in (fa, qn):
+        monkeypatch.setattr(module, '_interpret_default', lambda: False)
+    b, rows, heads, kv, d = 2, 16384, 32, 4, 128
+    width = (heads + 2 * kv) * d
+    assert qn.supports(b * rows, width, heads + kv, d)
+
+    def kernels_of(text):
+        names = re.findall(r'kernel_name = "(\w+)"', text)
+        return names, {name: next(
+            line for line in text.splitlines()
+            if '@tpu_custom_call' in line and name in line)
+            for name in names if name.startswith('qk_norm')}
+    text = jax.export.export(jax.jit(jax.value_and_grad(
+        lambda x, scale: jnp.sum(
+            qn.head_norm(x, scale, d, 1e-6).astype(jnp.float32)),
+        argnums=(0, 1))), platforms=['tpu'])(
+            jax.ShapeDtypeStruct((b, rows, width), jnp.bfloat16),
+            jax.ShapeDtypeStruct(((heads + kv) * d,),
+                                 jnp.float32)).mlir_module()
+    names, calls = kernels_of(text)
+    assert sorted(names) == ['qk_norm_bwd', 'qk_norm_fwd']
+    whole, row = 'tensor<32768x5120xbf16>', 'tensor<1x5120xf32>'
+    assert ': (%s, %s) -> %s' % (whole, row, whole) in calls['qk_norm_fwd']
+    assert (': (%s, %s, %s) -> (%s, tensor<8x8x5120xf32>)'
+            % (whole, whole, row, whole)) in calls['qk_norm_bwd']
+
+    attn = MultiHeadAttention(2048, heads, head_dim=d, num_kv_heads=kv,
+                              causal=False, dtype=jnp.bfloat16,
+                              rope_theta=1e6, qk_norm=True,
+                              block_diffusion=4)
+    params = jax.eval_shape(attn.init, jax.random.PRNGKey(0))
+    text = jax.export.export(jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(attn.apply(p, x).astype(jnp.float32)),
+        argnums=(0, 1))), platforms=['tpu'])(
+            params, jax.ShapeDtypeStruct((b, rows, 2048),
+                                         jnp.bfloat16)).mlir_module()
+    names, calls = kernels_of(text)
+    assert sorted(names) == ['flash_dkv_bd', 'flash_dq_bd', 'flash_fwd_bd',
+                             'qk_norm_bwd', 'qk_norm_fwd']
+    assert calls['qk_norm_fwd'].count(whole) == 2
+    assert calls['qk_norm_bwd'].count(whole) == 3
+    tensors = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32)>', text))
+    assert not re.search(r'(16384|32768)x5120xf32', text)
+    assert not [t for t in tensors
+                if re.search(r'16384x(32|36|40)x128$', t)]
