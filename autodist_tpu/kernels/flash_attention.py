@@ -264,11 +264,11 @@ def _whole_blocks(heads, per_block, target):
                            and c * per_block <= max(target, per_block))
 
 
-def _heads_per_step(heads, bq, bk, per_block=1):
+def _heads_per_step(heads, bq, bk, per_block=1, least=1):
     """Heads a grid step holds: whole lane blocks that divide the head
-    count, as many as stay inside the step budget and
-    ``_MAX_HEADS_PER_STEP``."""
-    return _whole_blocks(heads, per_block, max(1, min(
+    count, as many as stay inside the step budget (``least`` of them
+    whatever the budget) and ``_MAX_HEADS_PER_STEP``."""
+    return _whole_blocks(heads, per_block, max(least, min(
         _MAX_HEADS_PER_STEP, _STEP_TILE_ELEMS // (bq * bk))))
 
 
@@ -384,7 +384,9 @@ def _tile_counts(seq, bq, bk, causal, window=None, transposed=False):
 # max wipes out what they left, as in a band call. Transposed
 # (``flash_dkv_bd``): a clean key tile ``i`` takes the query tiles ``i ..
 # n - 1`` of BOTH halves, a noised key tile its own query tile alone; the
-# inner dimension walks all ``2 n`` query tiles, the noised first.
+# inner dimension walks all ``2 n`` query tiles, the noised first. Each
+# kernel has a ``t`` of its own (``_BD_TARGETS``): everything above is by
+# the kernel's own tile, and what passes between them is by row.
 
 Bd = collections.namedtuple('Bd', 'block tiles')   # B, tiles a half
 
@@ -1917,7 +1919,8 @@ def _flash_bwd(heads, kv_heads, causal, sm_scale, plan, interpret, window,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _blocks(heads, head_dim, seq, targets, block_q, block_k, group=1):
+def _blocks(heads, head_dim, seq, targets, block_q, block_k, group=1,
+            least_heads=1):
     sizes = []
     for asked, target in zip((block_q, block_k), targets):
         size = seq if not asked and seq <= target else \
@@ -1929,14 +1932,29 @@ def _blocks(heads, head_dim, seq, targets, block_q, block_k, group=1):
     per_block = _lane_block(heads, head_dim) // head_dim
     # (grouped kv heads: a step's query heads share one kv head)
     return Blocks(*sizes, _heads_per_step(heads if group == 1 else group,
-                                          *sizes, per_block))
+                                          *sizes, per_block, least_heads))
 
 
-# The square tile of every kernel under the block-diffusion mask: the
-# backward pair's target of a full call, and the forward's too: its 1024
-# x 1024 would leave 80 of the square's 256 tiles live at 8192 positions
-# a copy, 19% over the pairs that are (288 of 1024 tiles at 512: 12.5%).
-_BD_TILE = 512
+# (Square tile, fewest heads a step) of each kernel under the
+# block-diffusion mask. Measured on a v5e at SDAR's shape (32 query heads
+# over 4 kv heads of 128, 2 x 2 x 8192 rows, B = 4, rotary inside;
+# ``tools/flash_bd_bench.py``: the device's ms a call alone; my chip
+# runs, PR 47; docs/design/kernels.md has the table). The forward at 256
+# / 512 / 1024 / 2048 a side: 54.73 / 37.06 / 25.71 / 28.34 ms, 11.99 /
+# 7.67 / 4.79 / 4.40 ps an element it multiplies: bound by what the
+# online softmax does once a step whatever the keys, so an element costs
+# less the more keys a step walks, until 2048's dead elements (100.7M a
+# batch and head for 1024's 83.9M and 512's 75.5M) cost more than its
+# steps save. At 1024 x 1024 the step budget gives ONE head a step and
+# nothing fills a head's softmax with another's matmuls: two heads 23.21
+# ms, four 22.18 (16 MB of f32 scores; eight compile for 44 s where four
+# take 16). The backward pair pays for every dead element with matmuls:
+# dq 29.45 / 25.38 / 31.10 ms and dkv 39.10 / 32.72 / 35.73 at 256 / 512
+# / 1024, so 512 as in a full call. Tried and not kept: a crossed
+# forward tile multiplied by its live 512-row sub-tiles alone, 75.5M
+# elements again: 21.99 for 22.18 ms (25.65 for 25.71 at one head a
+# step): the crossed tiles' time is their steps', not their elements'.
+_BD_TARGETS = {'fwd': (1024, 4), 'dq': (512, 1), 'dkv': (512, 1)}
 
 
 def _plan(shape, causal, block_q=None, block_k=None, window=None,
@@ -1947,7 +1965,9 @@ def _plan(shape, causal, block_q=None, block_k=None, window=None,
     and the heads a grid step holds, in whole lane blocks (with
     ``kv_heads`` fewer than ``h``: query heads of one group). Under
     ``block_diffusion`` the tiles are square and divide a copy's ``s /
-    2`` rows."""
+    2`` rows, each kernel's its own (``_BD_TARGETS``; ``o`` and ``lse``
+    are by row, so the forward's need not be the backward pair's); tiles
+    asked for set all three alike."""
     _, h, s, d = shape
     group = h // (kv_heads or h)
     if block_diffusion is not None:
@@ -1956,8 +1976,10 @@ def _plan(shape, causal, block_q=None, block_k=None, window=None,
             raise ValueError('flash_attention: the block-diffusion mask '
                              'takes square tiles, not %d x %d'
                              % (block_q, block_k))
-        return Plan(*[_blocks(h, d, s // 2, (_BD_TILE, _BD_TILE), asked,
-                              asked, group)] * 3)
+        return Plan(**{
+            kernel: _blocks(h, d, s // 2, (tile, tile), asked, asked, group,
+                            1 if asked else heads)
+            for kernel, (tile, heads) in _BD_TARGETS.items()})
     if _band_form(window, s, group, block_q or block_k) == 'row':
         return Plan(**{kernel: _rows(h, d, s, window, *targets)
                        for kernel, targets in _ROW_TARGETS.items()})

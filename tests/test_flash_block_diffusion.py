@@ -1,9 +1,10 @@
 """The block-diffusion mask (PR 45), the flash kernels' third
 description of live pairs: the three kernels in interpret mode and the
 XLA path's boolean mask against dense masked attention (forward and
-every gradient; one tile, two, many; grouped heads; with and without
-rotary positions; f32 and bf16), the plan and its counts, what the mask
-MEANS for a model's rows, and the refusals."""
+every gradient; one tile, two, many; a forward tile twice the backward
+pair's (PR 47); grouped heads; with and without rotary positions; f32
+and bf16), the plan and its counts, what the mask MEANS for a model's
+rows, and the refusals."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,7 +71,14 @@ def case(seq, heads, kv_heads, d, dtype, seed=0, b=2):
 
 # (seq a copy, block length, tile, heads, kv heads, head dim, dtype):
 # one tile a copy, two, many; heads that share a lane block, a head that
-# is one; grouped kv heads (a head is then a lane block of its own)
+# is one; grouped kv heads (a head is then a lane block of its own). A
+# tile that is a number is asked for, and all three kernels' alike; a
+# pair is the (forward's, backward pair's) TARGETS of a plan that is
+# asked nothing: the forward at one tile a copy and at two while dq and
+# dkv have two and four (the forward then has its floor of heads a step,
+# four where the heads or their group allow). In every case the noised
+# rows of the sequence's first block meet no clean key: a noised query
+# tile's clean tiles leave its first rows at NEG_INF, whatever the tile.
 KERNEL_CASES = [
     (16, 4, 16, 2, 2, 16, jnp.float32),
     (32, 4, 16, 2, 2, 64, jnp.float32),
@@ -78,18 +86,36 @@ KERNEL_CASES = [
     (64, 8, 16, 2, 2, 64, jnp.bfloat16),
     (48, 2, 16, 4, 1, 128, jnp.bfloat16),
     (128, 4, 32, 2, 2, 128, jnp.float32),
+    (64, 4, (64, 32), 2, 2, 64, jnp.float32),
+    (64, 4, (64, 32), 4, 2, 128, jnp.bfloat16),
+    (128, 4, (64, 32), 4, 1, 128, jnp.float32),
+    (128, 8, (64, 32), 2, 2, 64, jnp.bfloat16),
+    (96, 4, (32, 16), 8, 2, 128, jnp.float32),
 ]
+
+
+def tiles_of(tile, monkeypatch):
+    """(what a call asks for, the forward's tile, the backward pair's);
+    a pair of targets is put where ``_plan`` reads its own, and a step's
+    budget at ONE head of the forward's tile, what it is at 1024 x 1024."""
+    if isinstance(tile, int):
+        return tile, tile, tile
+    monkeypatch.setattr(fa, '_BD_TARGETS', {
+        'fwd': (tile[0], 4), 'dq': (tile[1], 1), 'dkv': (tile[1], 1)})
+    monkeypatch.setattr(fa, '_STEP_TILE_ELEMS', tile[0] ** 2)
+    return (None,) + tile
 
 
 @pytest.mark.parametrize('seq,block,tile,heads,kv,d,dtype', KERNEL_CASES)
 def test_kernels_against_dense_masked_attention(seq, block, tile, heads, kv,
-                                                d, dtype):
+                                                d, dtype, monkeypatch):
     q, k, v, w = case(seq, heads, kv, d, dtype)
     mask = jnp.asarray(rules_mask(seq, block))
+    asked, fwd_tile, bwd_tile = tiles_of(tile, monkeypatch)
 
     def kernels(q, k, v):
-        return fa.flash_attention(q, k, v, causal=False, block_q=tile,
-                                  block_k=tile, block_diffusion=block)
+        return fa.flash_attention(q, k, v, causal=False, block_q=asked,
+                                  block_k=asked, block_diffusion=block)
 
     def of(f):
         return lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w)
@@ -103,26 +129,37 @@ def test_kernels_against_dense_masked_attention(seq, block, tile, heads, kv,
         assert rel(g, wg) < tol
     plan = [r['tags'] for r in telemetry.get().loop_records()
             if r['name'] == 'flash.plan'][-1]
-    n = seq // tile
     assert plan['block_diffusion'] == block and plan['window'] is None
-    for kernel in ('', 'dq_', 'dkv_'):
+    # each kernel's counts are of its own tiles
+    for kernel, tile in (('', fwd_tile), ('dq_', bwd_tile),
+                         ('dkv_', bwd_tile)):
+        n = seq // tile
         assert (plan[kernel + 'block_q'], plan[kernel + 'block_k']) == (
             tile, tile)
         assert (plan[kernel + 'tiles'], plan[kernel + 'live_tiles'],
                 plan[kernel + 'masked_tiles']) == (
                     4 * n * n, n * n + 2 * n, 3 * n)
+    if asked is None:
+        # the budget is one head of the forward's tile: its floor of four
+        # heads a step holds, as far as the heads (of a group) go
+        assert plan['heads_per_step'] == min(
+            4, heads if kv == heads else heads // kv)
 
 
 @pytest.mark.parametrize('seq,block,tile,heads,kv,d,dtype', [
     (32, 4, 16, 2, 2, 64, jnp.float32),
     (64, 4, 16, 4, 2, 128, jnp.float32),
     (32, 4, 16, 4, 1, 128, jnp.bfloat16),
+    (32, 4, (32, 16), 2, 2, 64, jnp.float32),
+    (64, 4, (32, 16), 4, 2, 128, jnp.float32),
+    (64, 8, (32, 16), 4, 1, 128, jnp.bfloat16),
 ])
 def test_kernels_with_rotary_positions_that_repeat(seq, block, tile, heads,
-                                                   kv, d, dtype):
+                                                   kv, d, dtype, monkeypatch):
     """The merged layout, rotary on the tile, positions ``0 .. L - 1``
     twice: against the rotation under XLA and dense masked attention."""
     q, k, v, w = case(seq, heads, kv, d, dtype)
+    tile, fwd_tile, bwd_tile = tiles_of(tile, monkeypatch)
     b, rows = q.shape[0], 2 * seq
     pos = jnp.arange(rows) % seq
     mask = jnp.asarray(rules_mask(seq, block))
@@ -142,6 +179,8 @@ def test_kernels_with_rotary_positions_that_repeat(seq, block, tile, heads,
     def got_of(qkv):
         plan = fa._plan((b, heads, rows, d), False, tile, tile, None, kv,
                         block)
+        assert (plan.fwd.block_q, plan.dq.block_q, plan.dkv.block_k) == (
+            fwd_tile, bwd_tile, bwd_tile)
         return fa._flash((qkv,), fa.rotary_tables(pos, 1e4, heads, d), heads,
                          kv, False, d ** -0.5, plan, True, None, True, block)
 
@@ -208,21 +247,41 @@ def test_tile_counts_equal_a_walk_of_the_square(n):
 
 
 def test_the_plan_at_the_published_shape():
-    """32 query heads over 4 kv heads of 128 at 2 x 8192 rows: square
-    tiles of 512, four heads a step, 288 of the square's 1024 tiles
-    live (a quarter and the diagonals: under 0.30), 48 of them crossed."""
+    """32 query heads over 4 kv heads of 128 at 2 x 8192 rows. The
+    forward: square tiles of 1024, four heads a step, 80 of the square's
+    256 tiles live and 24 of them crossed; the backward pair: square
+    tiles of 512, four heads a step, 288 of 1024 live (a quarter and the
+    diagonals: under 0.30), 48 of them crossed."""
     shape = (2, 32, 16384, 128)
     assert fa.supports(shape, kv_heads=4, block_diffusion=4)
     assert fa.preferred(shape, kv_heads=4, block_diffusion=4)
     plan = fa._plan(shape, False, kv_heads=4, block_diffusion=4)
-    assert all(blocks == fa.Blocks(512, 512, 4) for blocks in plan)
+    assert plan.fwd == fa.Blocks(1024, 1024, 4)
+    assert plan.dq == plan.dkv == fa.Blocks(512, 512, 4)
     tags = fa._plan_tags(plan, 16384, False, None, 4)
     assert tags['block_diffusion'] == 4 and tags['band_form'] is None
-    for kernel in ('', 'dq_', 'dkv_'):
+    assert (tags['tile_q'], tags['tile_k'], tags['tiles'],
+            tags['live_tiles'], tags['masked_tiles']) == (
+                1024, 1024, 256, 80, 24)
+    for kernel in ('dq_', 'dkv_'):
+        assert (tags[kernel + 'tile_q'], tags[kernel + 'tile_k']) == (
+            512, 512)
         assert (tags[kernel + 'tiles'], tags[kernel + 'live_tiles'],
                 tags[kernel + 'masked_tiles']) == (1024, 288, 48)
-        assert not tags[kernel + 'one_pass']
-    assert tags['live_tiles'] / tags['tiles'] < 0.30
+    assert not any(tags[kernel + 'one_pass'] for kernel in ('', 'dq_',
+                                                             'dkv_'))
+    assert tags['dq_live_tiles'] / tags['dq_tiles'] < 0.30
+    # what the forward multiplies beyond the backward pair's tiles: 11%
+    assert tags['live_tiles'] * tags['tile_q'] ** 2 / (
+        tags['dq_live_tiles'] * tags['dq_tile_q'] ** 2) == pytest.approx(
+            1.11, abs=0.005)
+    # a copy no longer than a target is one tile; tiles asked for set
+    # all three alike, heads a step too
+    short = fa._plan((2, 32, 1024, 128), False, kv_heads=4,
+                     block_diffusion=4)
+    assert list(short) == [fa.Blocks(512, 512, 4)] * 3
+    asked = fa._plan(shape, False, 1024, 1024, kv_heads=4, block_diffusion=4)
+    assert list(asked) == [fa.Blocks(1024, 1024, 1)] * 3
     # without the mask the plan and its tags are what they were
     assert fa._plan_tags(fa._plan(shape, True, kv_heads=4), 16384,
                          True)['block_diffusion'] is None
