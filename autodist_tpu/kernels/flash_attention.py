@@ -2663,7 +2663,8 @@ _flash_latent.defvjp(_flash_latent_fwd, _flash_latent_bwd)
 
 
 def flash_attention_latent(q, kv, c, heads, dims, rotary, causal=True,
-                           block_q=None, block_k=None, interpret=None):
+                           block_q=None, block_k=None, interpret=None,
+                           sm_scale=None):
     """Exact attention of ``heads`` heads whose q/k head is ``dims.nope``
     lanes of its own beside ``dims.rope`` rotary lanes that share ONE
     key, and whose v head is ``dims.v`` wide (``dims = (nope, rope,
@@ -2673,14 +2674,18 @@ def flash_attention_latent(q, kv, c, heads, dims, rotary, causal=True,
     (every head's k_nope, then every head's v), ``c [b, s, >= 128]``
     whose first ``rope`` columns are the rotary key, ``rotary`` the
     tables of ``rotary_tables(positions, theta, heads, rope)``. Returns
-    ``o [b, s, heads * v]``. The scale is ``(nope + rope) ** -0.5``. The rotary parts of q and the key are rotated on the tile
-    (half-split pairs), their gradients turned back; the cotangents are
+    ``o [b, s, heads * v]``. ``sm_scale`` is the caller's (a Python
+    number, static: a family whose YaRN factor multiplies the scores gives
+    the product), ``(nope + rope) ** -0.5`` where it gives none; the
+    kernels multiply the f32 scores by it, whatever its value. The rotary
+    parts of q and the key are rotated on the tile (half-split pairs),
+    their gradients turned back; the cotangents are
     those of ``q``, ``kv`` and ``c`` (zero beside the key's columns).
     The three calls are ``flash_fwd_mla``, ``flash_dq_mla``,
     ``flash_dkv_mla``; ``o`` and ``lse`` are named for a checkpoint
     policy as :func:`flash_attention_merged`'s. One ``flash.plan`` event
-    a trace, with ``qk_dim``, ``v_dim``, ``rope_dim`` and
-    ``shared_rope_key`` beside the plan."""
+    a trace, with ``qk_dim``, ``v_dim``, ``rope_dim``, ``shared_rope_key``
+    and ``sm_scale`` beside the plan."""
     dims = Latent(*dims)
     b, s, _ = q.shape
     shape = (b, heads, s, dims.nope + dims.rope)
@@ -2698,7 +2703,8 @@ def flash_attention_latent(q, kv, c, heads, dims, rotary, causal=True,
             'flash_attention_latent: rotary=(cos, sin) must be two f32 [%d, '
             '%d] (rotary_tables at the rotary part\'s width); got %s'
             % (s, _LANES, [(t.shape, str(t.dtype)) for t in tables]))
-    sm_scale = float((dims.nope + dims.rope) ** -0.5)
+    sm_scale = float((dims.nope + dims.rope) ** -0.5 if sm_scale is None
+                     else sm_scale)
     per = _per_block(dims)
     plan = Plan(**{
         kernel: _latent_blocks(heads, s, targets, block_q, block_k, per)
@@ -2710,7 +2716,7 @@ def flash_attention_latent(q, kv, c, heads, dims, rotary, causal=True,
         causal=bool(causal), fold_scale=False, window=None, layout='bsd',
         lane_block=_LANES, heads_per_lane_block=per, rotary=True,
         kv_heads=heads, qk_dim=dims.nope + dims.rope, v_dim=dims.v,
-        rope_dim=dims.rope, shared_rope_key=True,
+        rope_dim=dims.rope, shared_rope_key=True, sm_scale=sm_scale,
         **_plan_tags(plan, s, causal))
     return _flash_latent(q, kv, c, tables, heads, dims, bool(causal),
                          sm_scale, plan, interpret, True)

@@ -456,15 +456,20 @@ class MultiHeadAttention(Module):
 
 
 class LatentAttention(Module):
-    """Multi-head latent attention as DeepSeek-V2 publishes it (no q
-    down-projection), on the training path: [batch, seq, embed] in/out.
+    """Multi-head latent attention as DeepSeek-V2 and V3 publish it, on
+    the training path: [batch, seq, embed] in/out.
 
-    ``q = x W_q`` in ``num_heads`` heads of ``nope_dim + rope_dim``; ``c
-    = x W_kva`` is the rotary key (``rope_dim``: ONE for all heads) and
-    the latent (``rank``); ``RMSNorm(latent) W_kvb`` gives every head
-    its ``k_nope [nope_dim]`` and ``v [v_dim]``. Head n: ``softmax((
-    q_nope_n k_nope_n^T + rot(q_rope_n) rot(k_rope)^T) / sqrt(nope_dim
-    + rope_dim)) v_n``, then ``W_o [num_heads * v_dim, dim]``.
+    ``q = x W_q`` in ``num_heads`` heads of ``nope_dim + rope_dim``, or
+    with ``q_rank`` (V3's ``q_lora_rank``) ``q = RMSNorm(x W_qa) W_qb``
+    through a down-projection of that width; ``c = x W_kva`` is the
+    rotary key (``rope_dim``: ONE for all heads) and the latent
+    (``rank``); ``RMSNorm(latent) W_kvb`` gives every head its ``k_nope
+    [nope_dim]`` and ``v [v_dim]``. Head n: ``softmax((q_nope_n
+    k_nope_n^T + rot(q_rope_n) rot(k_rope)^T) scale) v_n`` with ``scale =
+    (nope_dim + rope_dim) ** -0.5``, then ``W_o [num_heads * v_dim,
+    dim]``. ``rope_yarn`` (:func:`rope_frequencies`' mapping) puts YaRN's
+    frequencies and factor on the rotary lanes, and its ``score_factor``,
+    where it has one, multiplies ``scale`` (the family's ``mscale ** 2``).
 
     The columns of the three projections are laid out for the flash
     kernels, which read their outputs where they lie
@@ -483,15 +488,24 @@ class LatentAttention(Module):
 
     def __init__(self, dim, num_heads, rank, nope_dim, rope_dim, v_dim,
                  causal=True, dtype=jnp.float32, rope_theta=10000.0,
-                 norm_eps=1e-6):
+                 norm_eps=1e-6, q_rank=None, rope_yarn=None):
         self.dim, self.num_heads = dim, num_heads
         self.dims = fa.Latent(nope_dim, rope_dim, v_dim)
         self.head_dim, self.v_dim = nope_dim + rope_dim, v_dim
         self.causal, self.dtype = causal, dtype
-        self.rope = rope_theta
+        self.rope = rope_frequencies(rope_theta, rope_dim, rope_yarn)
+        self.sm_scale = self.head_dim ** -0.5 * float(
+            (rope_yarn or {}).get('score_factor', 1.0))
         h = num_heads
-        self.wq = Dense(dim, h * self.head_dim, 'embed', 'heads',
+        self.wq = Dense(q_rank or dim, h * self.head_dim,
+                        None if q_rank else 'embed', 'heads',
                         use_bias=False, dtype=dtype)
+        self.wq_a = self.q_norm = None
+        if q_rank:
+            self.wq_a = Dense(dim, q_rank, 'embed', None, use_bias=False,
+                              dtype=dtype)
+            self.q_norm = RMSNorm(q_rank, axis_name=None, eps=norm_eps,
+                                  dtype=dtype)
         self.wkv_a = Dense(dim, rope_dim + rank, 'embed', None,
                            use_bias=False, dtype=dtype)
         self.kv_norm = RMSNorm(rank, axis_name=None, eps=norm_eps,
@@ -502,8 +516,11 @@ class LatentAttention(Module):
                         dtype=dtype)
 
     def param_defs(self):
-        return {'q': self.wq, 'kv_a': self.wkv_a, 'kv_norm': self.kv_norm,
-                'kv_b': self.wkv_b, 'out': self.wo}
+        d = {'q': self.wq, 'kv_a': self.wkv_a, 'kv_norm': self.kv_norm,
+             'kv_b': self.wkv_b, 'out': self.wo}
+        if self.wq_a is not None:
+            d.update(q_a=self.wq_a, q_norm=self.q_norm)
+        return d
 
     def apply(self, params, x, tables=None):
         b, s, _ = x.shape
@@ -513,7 +530,9 @@ class LatentAttention(Module):
                              'head width; use sp=1')
         nope, rope, _ = self.dims
         with jax.named_scope('mla_latent'):
-            q = self.wq.apply(params['q'], x)
+            q = x if self.wq_a is None else self.q_norm.apply(
+                params['q_norm'], self.wq_a.apply(params['q_a'], x))
+            q = self.wq.apply(params['q'], q)
             c = self.wkv_a.apply(params['kv_a'], x)   # [k_rope | latent]
             kv = self.wkv_b.apply(params['kv_b'], self.kv_norm.apply(
                 params['kv_norm'], c[..., rope:]))
@@ -533,7 +552,8 @@ class LatentAttention(Module):
         def attend(q, kv, c, tables):
             return fa.flash_attention_latent(q, kv, c, self.num_heads,
                                              self.dims, tables,
-                                             causal=self.causal)
+                                             causal=self.causal,
+                                             sm_scale=self.sm_scale)
         mesh = None if unsharded_execution() else current_mesh()
         if mesh is None:
             return attend(q, kv, c, tables)
@@ -560,7 +580,8 @@ class LatentAttention(Module):
         q = jnp.concatenate([by_head(q_nope), q_rope], -1)
         k = jnp.concatenate(
             [by_head(k_nope), jnp.broadcast_to(k_rope, q_rope.shape)], -1)
-        o = local_flash_attention(q, k, by_head(v), causal=self.causal)
+        o = local_flash_attention(q, k, by_head(v), causal=self.causal,
+                                  sm_scale=self.sm_scale)
         return by_head(o).reshape(b, s, h * v_dim)
 
     def position_tables(self, shape):

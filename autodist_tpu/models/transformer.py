@@ -21,6 +21,8 @@ from autodist_tpu.models.attention import (LatentAttention,
 from autodist_tpu.models.core import (Dense, Embedding, GatedMlp, LayerNorm,
                                       Mlp, Module, ParamDef, RMSNorm,
                                       activation, constrain, record_counter)
+from autodist_tpu.models.hyper_connections import (HyperConnection,
+                                                    split_streams)
 from autodist_tpu.parallel.axes import (active_manual_axes, ctx_option,
                                         manual_axis)
 
@@ -116,13 +118,17 @@ class TransformerConfig:
     decoder_bias: bool = False
     embed_init_scale: float = 0.02   # std of an embedding row's elements
     #                              as drawn at init
-    # -- latent attention (DeepSeek-V2's MLA, no q down-projection):
-    # with `latent_rank` every layer's attention is
-    # models/attention.LatentAttention, a q/k head of qk_nope_dim lanes
-    # of its own + qk_rope_dim rotary lanes whose key all heads share,
-    # a v head of v_head_dim; `head_dim`, `n_kv_heads` and `window` are
-    # then not used
+    # -- latent attention (DeepSeek-V2's MLA): with `latent_rank` every
+    # layer's attention is models/attention.LatentAttention, a q/k head
+    # of qk_nope_dim lanes of its own + qk_rope_dim rotary lanes whose
+    # key all heads share, a v head of v_head_dim; `head_dim`,
+    # `n_kv_heads` and `window` are then not used. `rope_yarn` applies to
+    # the rotary lanes and may carry `score_factor`, what the family
+    # multiplies the softmax scale by (DeepSeek's mscale^2)
     latent_rank: object = None   # the kv latent's width
+    latent_q_rank: object = None   # q through a down-projection of this
+    #                              width, an RMSNorm and an up-projection;
+    #                              None: straight from the hidden state
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
@@ -162,6 +168,16 @@ class TransformerConfig:
     # rows alone, at the same position (no shift), weighed by the batch's
     # `mask` (TransformerLM.loss)
     block_length: object = None
+    # -- the residual path as `hc_streams` streams, mixed a sublayer by
+    # manifold-constrained hyper-connections
+    # (models/hyper_connections.py): the stack carries [b, s, hc_streams
+    # * dim], the embedding copied to every stream at the entry, the
+    # streams summed before the final norm. None: x + f(norm(x)) on one
+    hc_streams: object = None
+    hc_iters: int = 20           # Sinkhorn-Knopp rounds of the stream mix
+    hc_clamp: tuple = (-30.0, 30.0)   # of that mix's logits, before exp
+    hc_eps: float = 1e-6         # in the coefficients' norm and in every
+    #                              round's sums
 
     def __post_init__(self):
         if self.positions not in POSITIONS:
@@ -184,6 +200,17 @@ class TransformerConfig:
                 raise ValueError('single-mixer layers take no window, no '
                                  'latent attention, no dense_lead and no '
                                  'embed_norm')
+        if self.hc_streams is not None and (self.hc_streams < 2
+                                            or self.mixers):
+            raise ValueError(
+                'hc_streams=%r: two or more residual streams, around the '
+                'two sublayers of attention-then-MLP blocks; single-mixer '
+                'layers (mixers=%r) take one stream'
+                % (self.hc_streams, self.mixers))
+        if self.latent_q_rank and not self.latent_rank:
+            raise ValueError('latent_q_rank=%r is latent attention\'s q '
+                             'down-projection: give latent_rank too'
+                             % (self.latent_q_rank,))
         activation(self.gelu)
         if self.norm not in ('layer', 'rms'):
             raise ValueError("norm must be 'layer' or 'rms', not %r"
@@ -384,7 +411,8 @@ class Block(Module):
             self.attn = LatentAttention(
                 cfg.dim, cfg.n_heads, cfg.latent_rank, cfg.qk_nope_dim,
                 cfg.qk_rope_dim, cfg.v_head_dim, causal=cfg.causal,
-                dtype=cfg.dtype, rope_theta=theta, norm_eps=cfg.norm_eps)
+                dtype=cfg.dtype, rope_theta=theta, norm_eps=cfg.norm_eps,
+                q_rank=cfg.latent_q_rank, rope_yarn=cfg.rope_yarn)
         else:
             self.attn = MultiHeadAttention(
                 cfg.dim, cfg.n_heads, head_dim=cfg.head_dim,
@@ -401,34 +429,73 @@ class Block(Module):
         # (a leading dense layer of a stack of expert layers: `dense`)
         self.sparse = bool(cfg.moe_experts) and not dense
         self.mlp = _mlp(cfg, dense)
+        # with residual streams: each sublayer's connection
+        self.hc_attn = self.hc_mlp = None
+        if cfg.hc_streams:
+            self.hc_attn, self.hc_mlp = (
+                HyperConnection(cfg.dim, cfg.hc_streams, cfg.hc_iters,
+                                cfg.hc_clamp, cfg.hc_eps, cfg.dtype)
+                for _ in range(2))
 
     def param_defs(self):
         d = {'attn': self.attn, 'ln2': self.ln2, 'mlp': self.mlp}
         if self.ln1 is not None:
             d['ln1'] = self.ln1
+        if self.hc_attn is not None:
+            d.update(hc_attn=self.hc_attn, hc_mlp=self.hc_mlp)
         return d
 
     @jax.named_scope('block')
     def apply(self, params, x, tables=None, stats=False):
         """``tables``: the attention's ``position_tables`` for ``x``,
-        where the model made them once for its layers."""
-        with jax.named_scope('attention'):
-            a = x if self.ln1 is None else self.ln1.apply(params['ln1'], x)
-            x = x + self.attn.apply(params['attn'], a, tables)
+        where the model made them once for its layers. With residual
+        streams (``cfg.hc_streams``) ``x`` is ``[b, s, streams * dim]``
+        and each sublayer reads and writes it through its connection
+        (``hc_attn``, ``hc_mlp``: scope ``hc``, beside ``attention`` and
+        ``mlp``); the stats then have a third number, the two connections'
+        ``err`` summed."""
+        streams = self.hc_attn is not None
+        if not streams:
+            x = self._attention(params, x, tables, residual=x)
+        else:
+            a, held = self.hc_attn.enter(params['hc_attn'], x)
+            x, err = self.hc_attn.leave(
+                x, self._attention(params, a, tables), held)
         # named so remat='save_attn' can keep it while recomputing the rest
         x = checkpoint_name(x, 'attn_out')
-        with jax.named_scope('mlp'):
-            h = self.mlp.apply(params['mlp'],
-                               self.ln2.apply(params['ln2'], x))
-            aux = jnp.zeros((), jnp.float32)
-            if self.sparse:
-                h, aux, load = h
-            elif stats:
-                load = jnp.zeros((2,), jnp.float32)
+        if not streams:
+            x, aux = self._mlp(params, x, stats, residual=x)
+        else:
+            h, held = self.hc_mlp.enter(params['hc_mlp'], x)
+            h, aux = self._mlp(params, h, stats)
+            x, err_mlp = self.hc_mlp.leave(x, h, held)
             if stats:
-                aux = (aux, load)
-            x = x + h
+                aux = (aux[0], jnp.concatenate([aux[1],
+                                                (err + err_mlp)[None]]))
         return constrain(x, ('batch', 'seq', 'embed')), aux
+
+    @jax.named_scope('attention')
+    def _attention(self, params, a, tables, residual=None):
+        """The attention sublayer with its norm, added to ``residual``
+        where that is given."""
+        if self.ln1 is not None:
+            a = self.ln1.apply(params['ln1'], a)
+        a = self.attn.apply(params['attn'], a, tables)
+        return a if residual is None else residual + a
+
+    @jax.named_scope('mlp')
+    def _mlp(self, params, h, stats, residual=None):
+        """``(h, aux)`` of the MLP sublayer with its norm, as
+        :meth:`apply` returns them."""
+        h = self.mlp.apply(params['mlp'], self.ln2.apply(params['ln2'], h))
+        aux = jnp.zeros((), jnp.float32)
+        if self.sparse:
+            h, aux, load = h
+        elif stats:
+            load = jnp.zeros((2,), jnp.float32)
+        if stats:
+            aux = (aux, load)
+        return h if residual is None else residual + h, aux
 
 
 class TransformerLM(Module):
@@ -585,6 +652,9 @@ class TransformerLM(Module):
             x = x + self.pos_embed.apply(params['pos_embed'], pos)[None]
         if self.cfg.embed_norm:
             x = self.ln_embed.apply(params['ln_embed'], x)
+        if self.cfg.hc_streams:
+            # the residual streams' entry: every stream the embedding
+            x = jnp.concatenate([x] * self.cfg.hc_streams, axis=-1)
         return constrain(x, ('batch', 'seq', 'embed'))
 
     def _position_tables(self, x):
@@ -674,16 +744,26 @@ class TransformerLM(Module):
         rows of each sequence (:meth:`_step_rows`) and the states are
         those of the ``L`` noised rows."""
         cfg = self.cfg
+        pipe_axis = manual_axis(AXIS_PIPELINE)
+        if cfg.hc_streams and manual_axis(AXIS_SEQUENCE) is not None:
+            raise ValueError(
+                'hc_streams=%d under sequence parallelism: the connections\' '
+                'coefficients are made from whole rows of [b, s, streams * '
+                'dim] and no sequence-parallel path has been run with them; '
+                'use sp=1' % cfg.hc_streams)
         x = self._embedded(params, tokens)
         tables = self._position_tables(x)
         aux_total = jnp.zeros((), jnp.float32)
-        pipe_axis = manual_axis(AXIS_PIPELINE)
-        # the expert layers' load rides beside aux through the layer
-        # loops (not through the pipeline schedules, whose aux is a scalar)
-        stats = bool(cfg.moe_experts) and pipe_axis is None
+        # the expert layers' load (and the stream connections' err) rides
+        # beside aux through the layer loops (not through the pipeline
+        # schedules, whose aux is a scalar)
+        stats = bool(cfg.moe_experts or cfg.hc_streams) \
+            and pipe_axis is None
         if stats:
-            aux_total = (aux_total, jnp.zeros((2,), jnp.float32))
+            aux_total = (aux_total, jnp.zeros(
+                (3 if cfg.hc_streams else 2,), jnp.float32))
         self._note_layers()
+        self._note_streams()
         self._note_remat(x)
         if pipe_axis is not None:
             self._check_pipelined()
@@ -724,6 +804,9 @@ class TransformerLM(Module):
         with jax.named_scope('head_loss'):
             if cfg.block_length is not None:
                 x = x[:, :x.shape[1] // 2]          # the noised rows
+            if cfg.hc_streams:
+                # the residual streams' exit: their sum
+                x = sum(split_streams(x, cfg.hc_streams)).astype(x.dtype)
             x = self.ln_f.apply(params['ln_f'], x)
         return x, aux_total
 
@@ -749,7 +832,8 @@ class TransformerLM(Module):
     def _count_load(self, load):
         """The step's counters of the expert layers (``load``: rows held
         here and the largest load of a held expert, summed over the
-        layers), for the trainer to read back with the loss
+        layers; with residual streams the connections' ``err`` behind
+        them), for the trainer to read back with the loss
         (``core.record_counter``): the mean over the layers of the rows
         held here, of the largest and of the mean load of a held
         expert. Not inside a manual region, whose values cannot leave
@@ -757,6 +841,13 @@ class TransformerLM(Module):
         if active_manual_axes():
             return
         cfg = self.cfg
+        if cfg.hc_streams:
+            # the connections' err (HyperConnection.leave), mean over
+            # the layers' two sublayers
+            record_counter('hc_res_col_sum_err',
+                           load[2] / (2 * cfg.n_layers))
+        if not cfg.moe_experts:
+            return
         layers = self._expert_layers()
         rows, largest = load[0] / layers, load[1] / layers
         record_counter('moe_rows_here', rows)
@@ -800,6 +891,11 @@ class TransformerLM(Module):
 
     def _check_pipelined(self):
         cfg = self.cfg
+        if cfg.hc_streams:
+            raise ValueError(
+                'hc_streams=%d under pipeline parallelism: the schedules '
+                'carry [b, s, dim] between stages, one stream; use pp=1'
+                % cfg.hc_streams)
         if not cfg.scan_layers:
             raise ValueError(
                 'pipeline parallelism requires scan_layers=True '
@@ -816,7 +912,8 @@ class TransformerLM(Module):
         stack: how it is run (docs/design/observability.md). The plain
         model, one scan step a layer, leaves none."""
         cfg = self.cfg
-        if not self.patterned and cfg.block_length is None:
+        if not self.patterned and cfg.block_length is None \
+                and not cfg.hc_streams:
             return
         kinds = cfg.layer_kinds()
         single = {} if not cfg.mixers else dict(
@@ -825,6 +922,8 @@ class TransformerLM(Module):
         if cfg.block_length is not None:
             single = dict(objective='block_diffusion',
                           block_length=cfg.block_length, rows_per_token=2)
+        if cfg.hc_streams:
+            single['streams'] = cfg.hc_streams
         telemetry.get().loop_event(
             'transformer.layers', n_layers=len(kinds),
             period=len(self._period), periods=self._periods,
@@ -836,6 +935,19 @@ class TransformerLM(Module):
             dense_lead=cfg.dense_lead,
             expert_layers=self._expert_layers(), **single)
 
+    def _note_streams(self):
+        """One ``hc.plan`` point event a trace of a model with residual
+        streams: the connection as it runs
+        (``models/hyper_connections.py``)."""
+        cfg = self.cfg
+        if not cfg.hc_streams:
+            return
+        telemetry.get().loop_event(
+            'hc.plan', streams=cfg.hc_streams, iters=cfg.hc_iters,
+            clamp=list(cfg.hc_clamp), eps=cfg.hc_eps,
+            layout='streams [b, s, n dim]; coefficients [n (n + 2), b, s]',
+            path='xla')
+
     def _note_remat(self, x):
         """One ``transformer.remat`` point event a trace under
         ``remat=True``: what the blocks' checkpoint keeps of block input
@@ -843,7 +955,10 @@ class TransformerLM(Module):
         here (``MultiHeadAttention.kernel_shape``) and keep
         ``saved_bytes_per_layer`` each on a device, for as long as the
         layer inputs live; 0 layers, on any other attention path, is
-        the checkpoint without a policy."""
+        the checkpoint without a policy. With residual streams ``x`` is
+        ``[b, s, streams * dim]`` and the bytes count it: what a layer
+        keeps then is mostly its input, ``streams`` times a plain
+        block's."""
         cfg = self.cfg
         if cfg.remat is not True:
             return
@@ -861,10 +976,14 @@ class TransformerLM(Module):
         kept = [fa.saved_bytes(shape, cfg.dtype, cfg.latent_rank
                                and cfg.v_head_dim)
                 for shape in shapes if shape is not None]
+        # (a device's rows: the kernels' batch where they run)
+        rows = next((shape[0] for shape in shapes if shape is not None), b)
+        carried = rows * s * x.shape[-1] * x.dtype.itemsize \
+            if cfg.hc_streams else 0
         telemetry.get().loop_event(
             'transformer.remat', policy='save_only_these_names',
             saved=list(self._saved_names()), layers=len(kept),
-            saved_bytes_per_layer=max(kept, default=0))
+            saved_bytes_per_layer=max(kept, default=0) + carried)
 
     def per_token_loss(self, params, batch):
         return self.per_token_loss_with_aux(params, batch)[0]

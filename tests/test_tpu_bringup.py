@@ -327,6 +327,80 @@ def test_mellum2_period_lowers_with_its_kernels_and_no_repeated_kv():
     assert scatters == {'%dx64' % s, '256x2304'}
 
 
+def test_xing4_stack_lowers_with_its_streams_side_by_side():
+    """Xing4.0's dense layer and one expert layer at the published widths
+    (3584 wide on four residual streams, latent attention with a q rank of
+    768 under YaRN at 4096 keys, a dense MLP of 9216, sigmoid 4 of 64
+    experts of width 1024, two of them held to keep the test light, a
+    shared expert) under remat=True with its gradient, as it lowers for the
+    TPU (PR 48): the three latent flash calls are there by name and read q
+    from the up-projection's output where it lies; the layers carry the
+    streams as ``[b, s, 4 * 3584]`` and no tensor of the step has a stream
+    axis of 4 between the positions and the lanes; the coefficients' work
+    is ``[., b, s]``, tokens last, and none of it ``[b, s, 4, 4]``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu.api import Trainer
+    from autodist_tpu.kernels import flash_attention as fa
+    from autodist_tpu.kernels import grouped_matmul as gm
+    from autodist_tpu.models.transformer import (TransformerConfig,
+                                                 TransformerLM)
+    from autodist_tpu.parallel.axes import ParallelSpec
+
+    b, s, n, d = 1, 4096, 4, 3584
+    cfg = TransformerConfig(
+        vocab=256, dim=d, n_layers=2, n_heads=32, max_len=s, causal=True,
+        tied_embeddings=False, dtype=jnp.bfloat16, remat=True,
+        positions='rotary', rope_theta=1e4, latent_rank=512,
+        latent_q_rank=768, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        rope_yarn=dict(factor=64, original_max_position_embeddings=4096,
+                       beta_fast=32, beta_slow=1, attention_factor=1.0,
+                       score_factor=2.00474),
+        mlp_dim=1024, gated_mlp=True, gelu='silu', norm='rms',
+        mlp_bias=False, moe_experts=64, moe_top_k=4, moe_held=2,
+        moe_aux_coef=0.0, dense_lead=1, dense_mlp_dim=9216,
+        moe_scoring='sigmoid', moe_scale=2.0, moe_shared_dim=1024,
+        hc_streams=n)
+    tr = Trainer(TransformerLM(cfg), optax.sgd(0.1),
+                 spec=ParallelSpec(dp=1))
+    state = tr.init(jax.random.PRNGKey(0))
+    batch = {name: np.zeros((b, s), np.int32)
+             for name in ('tokens', 'targets')}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, '_interpret_default', lambda: False)
+        patch.setattr(gm, '_interpret_default', lambda: False)
+        step = tr._ensure_step(tr._step_key(batch), state, batch)
+        shapes = jax.tree.map(
+            lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sh),
+            batch, tr.batch_sharding(batch))
+        text = jax.export.export(step, platforms=['tpu'])(
+            state, shapes).mlir_module()
+    names = set(re.findall(r'kernel_name = "(\w+)"', text))
+    assert {'flash_fwd_mla', 'flash_dq_mla', 'flash_dkv_mla'} <= names
+    flash_calls = [line for line in text.splitlines()
+                   if '@tpu_custom_call' in line and 'flash_' in line]
+    assert len(flash_calls) == 2 * 3
+    for line in flash_calls:
+        operands = line.split(' : (', 1)[1].split(') -> ')[0]
+        assert operands.startswith(
+            'tensor<%dx%dx6144xbf16>, tensor<%dx%dx8192xbf16>, '
+            'tensor<%dx%dx8192xbf16>, tensor<%dx%dx576xbf16>'
+            % ((b, s) * 4)), line
+    tensors = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|i1)>', text))
+    assert '%dx%dx%d' % (b, s, n * d) in tensors
+    assert '%dx%dx%d' % (n, b, s) in tensors            # H_pre, H_post
+    assert '%dx%dx%dx%d' % (n, n, b, s) in tensors      # a Sinkhorn round
+    wrong = [t for t in tensors if re.search(
+        r'x%dx(%d|%d)$|(^|x)%dx%dx%d$' % (n, d, n, s, n, n), t)]
+    assert not wrong, wrong
+
+
 def test_kanana2_stack_lowers_with_its_kernels_and_nothing_by_head():
     """kanana-2's dense layer and one expert layer at the published
     widths (latent attention: 32 heads of 128 + 64 on one rotary key, v
