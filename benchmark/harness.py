@@ -15,13 +15,18 @@ import math
 import os
 import shutil
 import statistics
+import sys
 import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 WARMUP_STEPS = 2
-MIN_STEPS = 10          # the loss check compares first five with last five
+# The loss check compares the first five steps with the last five, so a run
+# has ten or more. A cell's file may ask for more (``min_steps``) where ten
+# steps' fall is inside the batches' noise: its traced run is then that
+# long too, with the last ``trace_steps`` of them traced.
+MIN_STEPS = 10
 PROBE_PER_CHIP = 2      # sequences per chip the reference is compared on
 
 # The program computes in bf16 (8 significant bits, a relative rounding
@@ -232,7 +237,31 @@ def hlo_facts(hlo):
 
 
 def close(a, b, rtol):
-    return math.isfinite(a) and abs(a - b) <= rtol * abs(b)
+    """A Python ``bool`` whatever number types it is handed: a comparison
+    of numpy scalars is a ``numpy.bool``, which ``json`` refuses."""
+    return bool(math.isfinite(a) and abs(a - b) <= rtol * abs(b))
+
+
+def compared(reference, losses):
+    """``{name: [number, limit]}`` of what ``checks`` compares with a
+    limit, for the run's last lines: the loss's and the gradient norm's
+    distance from the reference's as shares of it (the family may have
+    raised that norm by its worst leaf, in units of the leaf's limit), and
+    the fall of the loss from the first five steps' mean to the last
+    five's, which has to be over nothing."""
+    def apart(a, b):
+        return abs(a - b) / abs(b) if b else math.inf
+    out = {'loss_apart': [apart(reference['loss'],
+                                reference['reference_loss']), LOSS_RTOL],
+           'grad_norm_apart': [apart(reference['grad_norm'],
+                                     reference['reference_grad_norm']),
+                               GRAD_NORM_RTOL]}
+    if len(losses) >= MIN_STEPS:
+        out['loss_fall'] = [statistics.fmean(losses[:5])
+                            - statistics.fmean(losses[-5:]), 0.0]
+    # (a number that is not finite as ``None``: the last line stays JSON)
+    return {name: [float(x) if math.isfinite(x) else None, float(limit)]
+            for name, (x, limit) in out.items()}
 
 
 def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
@@ -300,8 +329,11 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
         config, ref_params, probe)
     del ref_params
     phase('probe_reference')
-    reference = {'loss': got_loss, 'reference_loss': ref_loss,
-                 'grad_norm': got_norm, 'reference_grad_norm': ref_norm}
+    # the report's types are the harness's to keep, whatever a family or
+    # an engine hands back: every value of ``reference`` a float
+    reference = {'loss': float(got_loss), 'reference_loss': float(ref_loss),
+                 'grad_norm': float(got_norm),
+                 'reference_grad_norm': float(ref_norm)}
 
     warm = Feed([next(data) for _ in range(WARMUP_STEPS + 2)])
     state, _ = engine.fit(state, warm, WARMUP_STEPS)
@@ -310,10 +342,11 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
     phase('warmup')
 
     trace_steps = cell['trace_steps']
+    min_steps = cell.get('min_steps', MIN_STEPS)
     if trace:
-        steps = max(MIN_STEPS, trace_steps + 1)
+        steps = max(min_steps, trace_steps + 1)
     else:
-        steps = max(MIN_STEPS, int(seconds / step_s))
+        steps = max(min_steps, int(seconds / step_s))
     trace_dir = os.path.join(out_dir, 'trace')
     if trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
@@ -334,13 +367,14 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
     # -- checks ------------------------------------------------------------
     finite = [math.isfinite(x) for x in losses]
     expects = cell['expects']
+    numbers = compared(reference, losses)
+    fall = numbers.get('loss_fall', [None])[0]
     checks = {
         'reference_loss': close(got_loss, ref_loss, LOSS_RTOL),
         'reference_grad_norm': close(got_norm, ref_norm, GRAD_NORM_RTOL),
         'steps_ran': len(losses) == steps,
         'losses_finite': all(finite),
-        'loss_falls': len(losses) >= MIN_STEPS and all(finite) and
-        bool(np.mean(losses[-5:]) < np.mean(losses[:5])),
+        'loss_falls': all(finite) and fall is not None and fall > 0.0,
         'pallas_custom_calls':
             (facts['pallas_custom_calls'] > 0) ==
             bool(expects['pallas_custom_calls']),
@@ -350,6 +384,9 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
         'params_span_mesh': facts['params_span_mesh'],
         'no_compile_in_window': window_compiles == 0,
     }
+    # ... and every value of ``checks`` a bool, or the last line is not
+    # written (``json`` refuses a ``numpy.bool``)
+    checks = {name: bool(ok) for name, ok in checks.items()}
 
     # -- metrics -----------------------------------------------------------
     tokens = generator.tokens_per_step(traffic)
@@ -373,6 +410,7 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
 
     metrics = {}
     breakdown = None
+    readers_s = {}      # seconds each per-layer reader took, for the report
     if trace:
         xplane = find_xplane(trace_dir)
         reduced = trace_reduce.load_file(xplane)
@@ -383,7 +421,9 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
                'step_times': feed.step_times[-(trace_steps + 1):]}
         for name in metrics_for(cell['name'], bench['per_layer']):
             module = load_module('layer_metrics', name)
+            began = time.perf_counter()
             value = module.reduce(reduced, run)
+            readers_s[name] = time.perf_counter() - began
             if value is not None:
                 metrics[name] = {'value': value, 'unit': module.UNIT}
         if not reduced.ops and platform == 'tpu':
@@ -405,10 +445,12 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
         'workload': cell['name'], 'seed': seed, 'trace': int(trace),
         'steps': steps, 'window_s': t1 - t0, 'warmup_step_s': step_s,
         'losses': losses, 'reference': reference, 'checks': checks,
+        'compared': numbers,
         'hlo': facts, 'memory_analysis': memory,
         'memory_stats': stats, 'setup_phases_s': phases,
         'compile_setup': setup_compiles,
         'compile_requests_in_window': window_compiles,
+        'readers_s': readers_s,
         'end_to_end': {k: v[0] for k, v in end_to_end.items()},
         # the rate over the whole call, stalls included, for the record
         'whole_call_tokens_per_s_per_chip':
@@ -425,6 +467,12 @@ def run_cell(workload, seed, seconds, trace, t_start, out_dir, say,
               'device': device}
     if breakdown is not None:
         result['breakdown'] = breakdown
+    # each number compared beside its limit: the run's last lines on
+    # standard error, and the last key of its last line
+    for name, (x, limit) in numbers.items():
+        print('compared %s %r limit %r' % (name, x, limit), file=sys.stderr,
+              flush=True)
+    result['compared'] = numbers
     return result
 
 

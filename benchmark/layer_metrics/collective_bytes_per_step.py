@@ -1,8 +1,9 @@
 """Result bytes of the collectives a chip executed per step: the shapes
 from the compiled step's HLO, the executions from the trace, so a
 collective inside a ``while`` body (the per-layer gradient all-reduce of
-the backward scan) counts once per iteration. (The HLO half is copied
-from ``bench.py`` ``collective_bytes``, which counts such a one once.)"""
+the backward scan) counts once per iteration. (Counted from the HLO
+alone, as the repo's first benchmark script did, such a one counts
+once.)"""
 import re
 
 from benchmark import trace_reduce as tr

@@ -28,6 +28,7 @@ The host plane and the device planes of one trace agree only to about
 a millisecond, which is the size of what is split here, so the device
 planes are shifted first (:func:`device_shift_ns`).
 """
+import bisect
 import statistics
 
 from benchmark import trace_reduce as tr
@@ -77,7 +78,8 @@ def spans_on_trace(records, offset_ns, window):
 
 
 def intersect(a, b):
-    """The part of union ``a`` that union ``b`` covers."""
+    """The part of union ``a`` that union ``b`` covers (two walks of
+    ``trace_reduce.subtract``, each linear in the two lists)."""
     return tr.subtract(a, tr.subtract(a, b))
 
 
@@ -127,10 +129,12 @@ def chip_split(gaps, spans):
     under_input = tr.union_ns(intersect(gaps, spans.get(INPUT, [])))
     dispatch = tr.union_ns(intersect(gaps, spans.get(STEP, [])))
     readback = 0.0
+    gaps = sorted(gaps)
+    starts = [g_start for g_start, _ in gaps]
     for start, end in spans.get(READBACK, []):
-        for g_start, g_end in gaps:
-            if g_start < end <= g_end:       # the gap the span ends in
-                readback += end - max(start, g_start)
+        i = bisect.bisect_left(starts, end) - 1      # last gap open by then
+        if i >= 0 and end <= gaps[i][1]:     # the gap the span ends in
+            readback += end - max(start, gaps[i][0])
     return {'input': under_input, 'dispatch': dispatch,
             'readback': readback}
 
@@ -187,9 +191,10 @@ def gap_split(trace, run):
 
 
 def gap_ms(trace, run, part):
-    """One of :data:`PARTS`; the four readers share what is said, so
-    only the first says it."""
-    if part != PARTS[0]:
-        run = dict(run, say=lambda line: None)
-    split = gap_split(trace, run)
+    """One of :data:`PARTS`. The split is made once for the ``run`` it is
+    asked of and kept on it (``run['host_gap_split']``): the four readers
+    share it, and what it says is said once."""
+    if 'host_gap_split' not in run:
+        run['host_gap_split'] = gap_split(trace, run)
+    split = run['host_gap_split']
     return None if split is None else split[part]

@@ -162,21 +162,21 @@ def union_ns(intervals):
 
 
 def subtract(a, b):
-    """The part of union ``a`` not covered by union ``b``."""
+    """The part of union ``a`` not covered by union ``b``: one walk over
+    the two merged lists (both sorted, so an interval of ``b`` that ends
+    before one of ``a`` starts is behind every later one of ``a`` too)."""
     out = []
-    b = merge(b)
-    for s, t in merge(a):
-        cur = s
-        for bs, bt in b:
-            if bt <= cur:
-                continue
-            if bs >= t:
-                break
-            if bs > cur:
-                out.append((cur, bs))
-            cur = max(cur, bt)
-            if cur >= t:
-                break
+    a, b = merge(a), merge(b)
+    j = 0
+    for s, t in a:
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        cur, k = s, j
+        while k < len(b) and b[k][0] < t:
+            if b[k][0] > cur:
+                out.append((cur, b[k][0]))
+            cur = max(cur, b[k][1])
+            k += 1
         if cur < t:
             out.append((cur, t))
     return out

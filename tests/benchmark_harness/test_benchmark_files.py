@@ -109,6 +109,43 @@ def test_cell_file(name):
     assert product == cell['chips']
 
 
+CELL_KEYS = {'name', 'config', 'traffic', 'chips', 'engine', 'parallel',
+             'trace_steps', 'expects', 'why'}
+
+
+@pytest.mark.parametrize('name', names('workloads', '.json'))
+def test_cell_file_keys_and_its_windows_least_steps(name):
+    """What ``harness.run_cell`` reads of a cell's file, and no other key.
+    ``min_steps`` (PR 50) is the cell's to leave out: a run then has
+    ``harness.MIN_STEPS``; where it is there it asks for MORE, and leaves
+    steps before the traced ones."""
+    from benchmark import harness
+    cell = load('workloads', name)
+    assert CELL_KEYS <= set(cell) <= CELL_KEYS | {'min_steps'}
+    least = cell.get('min_steps', harness.MIN_STEPS)
+    assert isinstance(least, int) and least >= harness.MIN_STEPS
+    if 'min_steps' in cell:
+        assert least > harness.MIN_STEPS and least > cell['trace_steps']
+    # the loss's fall is read between the first five steps and the last five
+    assert max(least, cell['trace_steps'] + 1) >= 10
+
+
+def test_the_cell_with_streams_says_its_windows_steps():
+    # what its 20 s window has already (PERF.md section 6, PR 50)
+    cell = load('workloads', 'xing4.0-29b-a4b.s4096.c1')
+    assert (cell['min_steps'], cell['trace_steps']) == (45, 6)
+
+
+def test_both_four_chip_cells_report_the_collectives():
+    four = sorted(w['name'] for w in CELLS.values() if w['chips'] == 4)
+    for name in ('collective_ms_per_step', 'collective_exposed_pct',
+                 'collective_bytes_per_step'):
+        assert sorted(PER_LAYER[name]['workloads']) == four
+        for cell in four:
+            assert 'all-reduce' in load('workloads',
+                                        cell)['expects']['collectives']
+
+
 @pytest.mark.parametrize('name', names('layer_metrics', '.py'))
 def test_layer_metric_file(name):
     module = importlib.import_module('benchmark.layer_metrics.' + name)

@@ -293,9 +293,14 @@ def ring(monkeypatch):
 
 
 def metric(name, trace, run, said=None):
+    """One reader's value. A ``run`` that has its ``say`` is handed on as
+    it is, as the harness hands ONE run to every reader (what a reader
+    keeps on it, the next one finds); else a run of its own."""
     module = importlib.import_module('benchmark.layer_metrics.' + name)
-    say = (lambda line: None) if said is None else said.append
-    return module.reduce(trace, dict(run, say=say))
+    if 'say' not in run:
+        say = (lambda line: None) if said is None else said.append
+        run = dict(run, say=say)
+    return module.reduce(trace, run)
 
 
 @pytest.mark.parametrize('name', sorted(HAND_VALUES))
@@ -307,7 +312,8 @@ def test_new_layer_metric_on_the_hand_built_trace(hand, ring, name):
 
 def test_the_parts_add_up_to_the_whole(hand, ring):
     said = []
-    value = {n: metric(n, hand, RUN, said) for n in list(HAND_VALUES) + [
+    run = dict(RUN, say=said.append)       # one run, as the harness's
+    value = {n: metric(n, hand, run) for n in list(HAND_VALUES) + [
         'device_step_ms', 'flash_ms_per_step', 'host_gap_ms']}
     busy = value['device_step_ms']
     assert busy == pytest.approx(1900e-6)
@@ -322,9 +328,11 @@ def test_the_parts_add_up_to_the_whole(hand, ring):
     assert kernel_ms == pytest.approx({'flash_fwd': 400e-6, 'flash_dq': 150e-6,
                                        'flash_dkv': 250e-6})
     assert sum(kernel_ms.values()) == pytest.approx(value['flash_ms_per_step'])
+    # the split is made once for the run and kept on it: said once
     assert [line for line in said if 'host gap split' in line][1:] == [
         'host gap split: device planes shifted by +100 ns, to start each '
         'step where its trainer.step span ends']
+    assert set(run['host_gap_split']) == set(span_reduce.PARTS)
     assert any(line.startswith('flash_fwd: ') and '2 calls' in line
                and 'bound by memory' in line for line in said)
 
@@ -358,7 +366,8 @@ def test_a_step_without_the_programs_names_reads_as_nothing_not_as_zero(hand):
 def test_a_program_without_the_ring_reads_as_nothing(hand, monkeypatch):
     monkeypatch.setattr(span_reduce, 'ring_records', lambda: None)
     said = []
-    assert [metric('host_gap_%s_ms' % p, hand, RUN, said)
+    run = dict(RUN, say=said.append)
+    assert [metric('host_gap_%s_ms' % p, hand, run)
             for p in span_reduce.PARTS] == [None] * 4
     assert said == ['host gap split: the program has no loop ring '
                     '(autodist_tpu.telemetry.get().loop_records)']

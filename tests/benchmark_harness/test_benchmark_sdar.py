@@ -487,9 +487,11 @@ def test_rehearsal_of_the_strong_scaling_cell(tmp_path):
     report = json.loads(lines[-1])
     assert result['correct'] is True, report['checks']
     assert 'all-reduce' in report['hlo']['collectives']
-    # it reports the metrics that have no list; the three collective_*
-    # list bert-large.s512.dp4 alone (a benchmark PR's to extend)
+    # it reports the metrics that have no list and, since PR 50 listed it
+    # under them, the three collective_*
     listed = harness.metrics_for(cell['name'], benchmark_json()['per_layer'])
-    assert not [m for m in listed if m.startswith('collective_')]
+    assert sorted(m for m in listed if m.startswith('collective_')) == [
+        'collective_bytes_per_step', 'collective_exposed_pct',
+        'collective_ms_per_step']
     assert {'device_step_ms', 'flash_ms_per_step', 'attention_ms_per_step',
             'step_hbm_gb'} <= set(listed)

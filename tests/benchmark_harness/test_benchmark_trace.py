@@ -5,11 +5,14 @@ worked out from its event list when it was recorded (PR 22)."""
 import importlib
 import json
 import os
+import statistics
 
+import numpy as np
 import pytest
 
 from bench_paths import BENCH
 
+from benchmark import span_reduce
 from benchmark import trace_reduce as tr
 from benchmark.trim_trace import text_proto
 
@@ -127,6 +130,66 @@ def test_interval_arithmetic():
     assert tr.subtract([(0, 10)], [(2, 3), (5, 12)]) == [(0, 2), (3, 5)]
     assert tr.subtract([(0, 4), (6, 9)], [(3, 7)]) == [(0, 3), (7, 9)]
     assert tr.subtract([(0, 4)], []) == [(0, 4)]
+
+
+def old_subtract(a, b):
+    """``trace_reduce.subtract`` as it was until PR 50, which scanned ``b``
+    from its start for every interval of ``a``: the oracle of the walk."""
+    out = []
+    b = tr.merge(b)
+    for s, t in tr.merge(a):
+        cur = s
+        for bs, bt in b:
+            if bt <= cur:
+                continue
+            if bs >= t:
+                break
+            if bs > cur:
+                out.append((cur, bs))
+            cur = max(cur, bt)
+            if cur >= t:
+                break
+        if cur < t:
+            out.append((cur, t))
+    return out
+
+
+def old_intersect(a, b):
+    return old_subtract(a, old_subtract(a, b))
+
+
+def old_chip_split(gaps, spans):
+    """``span_reduce.chip_split`` as it was until PR 50."""
+    readback = 0.0
+    for start, end in spans.get(span_reduce.READBACK, []):
+        for g_start, g_end in gaps:
+            if g_start < end <= g_end:
+                readback += end - max(start, g_start)
+    return {'input': tr.union_ns(old_intersect(
+                gaps, spans.get(span_reduce.INPUT, []))),
+            'dispatch': tr.union_ns(old_intersect(
+                gaps, spans.get(span_reduce.STEP, []))),
+            'readback': readback}
+
+
+@pytest.mark.parametrize('seed', range(4))
+def test_the_interval_walk_gives_what_the_rescan_gave(seed):
+    """A thousand random pairs of interval lists a seed, on a coarse grid
+    so that intervals touch, nest, repeat and are empty: one walk over the
+    two merged lists against the old rescan, interval for interval."""
+    rng = np.random.default_rng(seed)
+    for _ in range(1000):
+        lists = []
+        for _ in range(2):
+            n = int(rng.integers(0, 40))
+            starts = rng.integers(0, 200, n)
+            lists.append([(int(s), int(s + d)) for s, d in zip(
+                starts, rng.integers(0, 12 if seed % 2 else 60, n))])
+        a, b = lists
+        assert tr.subtract(a, b) == old_subtract(a, b)
+        assert span_reduce.intersect(a, b) == old_intersect(a, b)
+        assert tr.union_ns(tr.subtract(a, b)) \
+            + tr.union_ns(span_reduce.intersect(a, b)) == tr.union_ns(a)
 
 
 def test_busy_union_and_idle_gaps(hand):
@@ -276,6 +339,55 @@ def test_recorded_trace_collective_time_and_its_exposed_part(recorded):
     # by hand from the HLO: 24 x (25,176,064 + 16,384) in the backward
     # scan + 125,018,112 + 1,048,576 + 8,196 after it = 730,693,636 bytes
     assert values[2] == pytest.approx(730.693636, rel=1e-9)
+
+
+def test_recorded_trace_host_gap_parts_as_the_old_walk_read_them(
+        recorded, monkeypatch):
+    """The four parts of ``host_gap_ms`` over the recorded step's thousands
+    of idle gaps, with a loop ring laid on it by hand (an input, a dispatch
+    that ends where the device restarts, a read-back that ends in the
+    step's last gap, and a second input across the middle of the step,
+    over thousands of pauses): the merge walk and the one shared split read
+    what the quadratic walk read, to 1e-9 ms, and each reader finds the
+    split the first one made."""
+    trace, run, _ = recorded
+    lo, hi = trace.window
+    t0 = 100.0                                   # perf_counter at ``lo``
+    firsts, lasts = [], []
+    for chip in trace.ops:
+        gaps = sorted(tr.idle_gaps(trace, chip)[:2])
+        firsts.append(gaps[0][1])
+        lasts.append(gaps[1])
+    restart = statistics.median(firsts)          # the shift puts it at 2 ms
+    shift = lo + 2e6 - restart
+    back = max(s for s, _ in lasts) + shift + 0.25 * min(
+        e - s for s, e in lasts)
+
+    def record(name, start, end):
+        return {'name': name, 't0': t0 + (start - lo) / 1e9,
+                'dur': (end - start) / 1e9}
+    records = [record(span_reduce.INPUT, lo, lo + 1e6),
+               record(span_reduce.STEP, lo + 1e6, lo + 2e6),
+               record(span_reduce.READBACK, lo + 2e6, back),
+               record(span_reduce.INPUT, lo + 0.2 * (hi - lo),
+                      lo + 0.6 * (hi - lo))]
+    monkeypatch.setattr(span_reduce, 'ring_records', lambda: records)
+    said = []
+    run = dict(run, step_times=[t0, t0 + (hi - lo) / 1e9], say=said.append)
+    new = {part: importlib.import_module(
+        'benchmark.layer_metrics.host_gap_%s_ms' % part).reduce(trace, run)
+        for part in span_reduce.PARTS}
+    assert len([line for line in said if 'host gap split' in line]) == 2
+    assert new == run['host_gap_split']
+    monkeypatch.setattr(tr, 'subtract', old_subtract)
+    monkeypatch.setattr(span_reduce, 'intersect', old_intersect)
+    monkeypatch.setattr(span_reduce, 'chip_split', old_chip_split)
+    old = span_reduce.gap_split(trace, dict(run, say=said.append))
+    assert new == pytest.approx(old, abs=1e-9)
+    assert all(new[part] > 0.01 for part in span_reduce.PARTS)
+    whole = importlib.import_module(
+        'benchmark.layer_metrics.host_gap_ms').reduce(trace, run)
+    assert sum(new.values()) == pytest.approx(whole, abs=1e-9)
 
 
 def test_recorded_trace_breakdown(recorded):

@@ -5,6 +5,7 @@ deliberate fault against the cell's own limits, and a rehearsal of the
 cell's run loop on the CPU. The tiny configuration and the faults are
 ``test_benchmark_xing4.py``'s."""
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,26 @@ def probed(case):
         assert a.sharding.device_set == set(engine.devices)
         np.testing.assert_array_equal(np.asarray(a), b)
     return probe, got, gradients
+
+
+@pytest.fixture(scope='module')
+def wanted(case):
+    """The sound reference's gradient on the case's probe, once."""
+    config, _, params, probe, _ = case
+    return reference_grads(config, xing4.to_reference_params(params),
+                           probe)[1]
+
+
+def pushed(grads, layer=1, share=0.05):
+    """``grads`` (the reference's names) with ``b_post`` of an expert
+    layer's MLP connection moved by ``share`` of that connection's
+    ``phi_post``'s norm: far past the bias's own size, a twentieth on the
+    connection's pooled scale."""
+    mlp = {k: np.array(v, np.float64)
+           for k, v in grads['layers']['hc_mlp'].items()}
+    mlp['b_post'][layer] += share * np.linalg.norm(
+        mlp['phi_post'][layer]) / 2.0            # four numbers: norm 1
+    return dict(grads, layers=dict(grads['layers'], hc_mlp=mlp))
 
 
 def through_the_harness(config, params, probed, **switches):
@@ -111,15 +132,14 @@ def test_the_cells_own_comparison_catches_each_fault(case, probed, capsys,
                    for name in xing4.NOTHING_AT_ENTRY)
 
 
-def test_a_gradient_where_nothing_reaches_is_read(case):
+def test_a_gradient_where_nothing_reaches_is_read(case, wanted):
     """A program that did have a gradient at the first connection's
     ``H_pre`` (its streams not copies at the entry) reads 1 there, on the
     next connection's scale (read and printed, held to nothing: the family
     module says why), and a selection bias with one counts as a thousand
     limits."""
-    config, _, params, probe, (_, got_grads) = case
-    _, want = reference_grads(config, xing4.to_reference_params(params),
-                              probe)
+    _, _, _, _, (_, got_grads) = case
+    want = wanted
     first = dict(got_grads['dense']['hc_attn'])
     for name in ('phi_pre', 'b_pre', 'alpha_pre'):
         first[name] = want['dense']['hc_mlp'][name]
@@ -139,16 +159,15 @@ def test_a_gradient_where_nothing_reaches_is_read(case):
 @pytest.mark.parametrize('fault,reads', [
     ('sound', 0.0), ('missing', 1.0), ('wrong_sign', 2.0), ('halved', 0.5),
     ('noise_at_right_angles', 0.0)])
-def test_the_reads_and_the_mixes_are_held_along_the_reference(case, fault,
-                                                              reads):
+def test_the_reads_and_the_mixes_are_held_along_the_reference(case, wanted,
+                                                              fault, reads):
     """A connection's ``pre`` and ``res`` leaves are held by the component
     of the program's gradient ALONG the reference's (the family module
     says why): a missing gradient reads 1, a wrong sign 2, half the size
     0.5, and noise at right angles to the reference, as large as the
     reference itself, next to nothing where the L2 reading has 1."""
-    config, _, params, probe, (_, got_grads) = case
-    _, want = reference_grads(config, xing4.to_reference_params(params),
-                              probe)
+    _, _, _, _, (_, got_grads) = case
+    want = wanted
     ref = want['layers']['hc_mlp']
     mlp = dict(got_grads['layers']['hc_mlp'])
     for name in ('phi_res', 'b_res', 'alpha_res'):
@@ -182,25 +201,128 @@ def test_the_reads_and_the_mixes_are_held_along_the_reference(case, fault,
         ('layers/hc_mlp', ['/0', '/1'])) for k in xing4.HC_MIX for i in idx}
 
 
+def test_the_write_back_is_one_reading_a_connection(case, wanted):
+    """``phi_post``, ``b_post`` and the gate of a connection are held by ONE
+    L2 reading over the three together (the family module says why: the
+    bias's and the gate's own are remainders that swing with the seed). A
+    bias moved by a twentieth of the connection's scale reads a twentieth
+    on all three and nothing anywhere else; what the bias reads alone, many
+    times its own size, is kept beside, held to nothing. Every reading is a
+    Python float."""
+    _, _, _, _, (_, got_grads) = case
+    apart = {}
+    sound = xing4.connection_differences(got_grads, wanted)
+    readings = xing4.connection_differences(pushed(got_grads), wanted,
+                                            post_apart=apart)
+    assert all(type(x) is float for x in readings.values())
+    assert all(type(x) is float for x in apart.values())
+    ref = wanted['layers']['hc_mlp']
+    scale = np.sqrt(sum(np.sum(np.square(np.asarray(ref[leaf][1], np.float64)))
+                        for leaf in ('phi_post', 'b_post', 'alpha_post')))
+    moved = 0.05 * np.linalg.norm(np.asarray(ref['phi_post'][1], np.float64))
+    three = [readings['layers/hc_mlp/%s/1' % leaf]
+             for leaf in ('phi_post', 'b_post', 'alpha_post')]
+    assert three[0] == three[1] == three[2] == pytest.approx(
+        moved / scale, rel=1e-2)
+    assert 0.045 < three[0] < 0.05
+    for name, reading in readings.items():
+        if not name.startswith('layers/hc_mlp/') or not name.endswith(
+                ('_post/1',)):
+            assert reading == sound[name] and reading < 1e-3, name
+    assert set(apart) == {name for name in readings if name.split('/')[2]
+                          in ('b_post', 'alpha_post')}
+    # on the bias's own scale (the walk's size: 0.05 x sqrt(n C), n C 128)
+    assert apart['layers/hc_mlp/b_post/1'] == pytest.approx(0.566, rel=0.01)
+    assert apart['layers/hc_mlp/alpha_post/1'] < 1e-3
+    assert apart['layers/hc_mlp/b_post/0'] < 1e-3
+
+
+@pytest.mark.parametrize('worst', ['a_matrix', 'a_connection'])
+def test_the_raised_norm_is_a_python_float_whichever_leaf_is_worst(
+        case, wanted, capsys, worst):
+    """``held_to_every_leaf`` hands ``harness.close`` a Python float, and
+    the comparison a ``bool`` that ``json`` takes, when the worst leaf in
+    units of its limit is a connection's as when it is a matrix's: until
+    PR 50 the connections' readings were numpy scalars, and a run whose
+    worst leaf was one of them died writing its last line (PERF.md section
+    6, PR 49)."""
+    _, _, _, _, (_, got_grads) = case
+    got = pushed(got_grads) if worst == 'a_connection' else dict(
+        got_grads, head=1.01 * got_grads['head'])
+    norm = xing4.held_to_every_leaf(np.float64(3.0), got, wanted, 1)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    # (the write-back's three leaves carry one reading: any of them)
+    assert line['worst'] == 'head' if worst == 'a_matrix' else re.fullmatch(
+        r'layers/hc_mlp/\w+_post/1', line['worst'])
+    assert type(norm) is float
+    assert norm == pytest.approx(3.0 * (
+        1 + harness.GRAD_NORM_RTOL * line['worst_in_limits']), rel=1e-12)
+    if worst == 'a_connection':
+        assert line['worst_in_limits'] == pytest.approx(
+            0.05 / xing4.HC_LEAF_RTOL, rel=0.02)
+        assert line['hc_post_apart']['layers/hc_mlp/b_post/1'] > 0.5
+    ok = harness.close(np.float64(3.0), norm, harness.GRAD_NORM_RTOL)
+    assert type(ok) is bool and ok
+    json.dumps({'reference_grad_norm': ok, 'norm': norm})
+
+
 @pytest.mark.parametrize('trace', [False, True], ids=['untraced', 'traced'])
-def test_rehearsal_of_the_cell(tmp_path, trace):
+def test_rehearsal_of_the_cell(tmp_path, trace, monkeypatch, capsys):
     """The run loop with the new family and engine at the tiny size on the
     CPU, under the real cell's name so that ``BENCHMARK.json``'s lists
     apply. In f32: a connection's coefficients are one number for all of a
     token's lanes, so bf16's rounding of them averages over TOKENS alone,
     and the probe's 64 tokens here leave the leaves 5-20% apart where the
     cell's 8,192 leave them inside the limits (PERF.md section 6); the
-    bf16 step itself is driven below."""
+    bf16 step itself is driven below.
+
+    The traced rehearsal is the cell's as PR 50 left it. Its file says
+    ``min_steps``, so the traced ``fit`` has that many steps with the last
+    ``trace_steps`` traced. And the probe's ``b_post`` gradient of an expert
+    layer's MLP connection is pushed until a CONNECTION'S leaf is the worst
+    in units of its limit, which the rehearsals never had and one run in
+    six on the chip did: the run goes through to its last line and the line
+    is JSON (on PR 48's tree it died there: ``Object of type bool is not
+    JSON serializable``)."""
     cell = dict(name=CELL, config='tiny', traffic='tiny', chips=1,
                 engine='trainer_leaves_parked', parallel={'dp': 1},
                 trace_steps=3,
                 expects={'pallas_custom_calls': False, 'collectives': []})
+    if trace:
+        from benchmark.engines import trainer_leaves, trainer_leaves_parked
+        cell['min_steps'] = 12
+        probe = trainer_leaves_parked.Engine.loss_and_grad_norm
+
+        def pushed_probe(self, state, batch):
+            got = probe(self, state, batch)
+            grads = trainer_leaves.PROBE['gradients']
+            hc = dict(grads['blocks']['global']['hc_mlp'])
+            bias, n = np.array(hc['bias']), config['hc_mult']
+            bias[0, n:2 * n] += 0.05 / 2.0 * np.linalg.norm(
+                np.asarray(hc['phi'])[0][:, n:2 * n])
+            hc['bias'] = bias
+            stack = dict(grads['blocks']['global'], hc_mlp=hc)
+            trainer_leaves.PROBE['gradients'] = dict(
+                grads, blocks=dict(grads['blocks'], **{'global': stack}))
+            return got
+        monkeypatch.setattr(trainer_leaves_parked.Engine,
+                            'loss_and_grad_norm', pushed_probe)
+    config = tiny_config('float32', num_hidden_layers=2)
     result, lines = harness.rehearse(
-        cell, tiny_config('float32', num_hidden_layers=2), TRAFFIC, PEAKS,
-        seed=2147483693,
+        cell, config, TRAFFIC, PEAKS, seed=2147483693,
         trace=trace, out_dir=str(tmp_path))
     report = json.loads(lines[-1])
     assert result['correct'] is True, report['checks']
+    assert json.loads(json.dumps(result)) == result
+    assert all(type(ok) is bool for ok in report['checks'].values())
+    assert all(type(x) is float for x in report['reference'].values())
+    leaves = [json.loads(line) for line in capsys.readouterr().out.splitlines()
+              if line.startswith('{"gradient_leaves"')][-1]
+    if trace:
+        assert re.fullmatch(r'layers/hc_mlp/\w+_post/0', leaves['worst'])
+        assert 0.5 < leaves['worst_in_limits'] < 0.65
+    assert result['attempted'] == report['steps'] == (
+        12 if trace else harness.MIN_STEPS)
     assert result['device']['platform'] == 'cpu'
     bench = benchmark_json()
     if trace:
