@@ -38,19 +38,39 @@ not at it: gates 0.01, ``H_pre`` 1 / n each (the sublayer reads the
 streams' mean), ``H_post`` 1, ``H_res`` within ``exp(-RES_INIT)`` of the
 identity; ``phi`` N(0, 1 / (n dim)).
 
+Paths. Where ``kernels/hyper_connections.supports`` takes a device's
+rows (``dim`` whole 128-lane blocks, rows whole blocks of 128, a block
+that fits VMEM: :meth:`HyperConnection.kernel_plan`), a connection is four
+Pallas kernels, each one pass over the streams where they lie:
+``hc_enter_fwd`` / ``hc_enter_bwd`` (the coefficients AND the read, one
+``jax.custom_vjp``) and ``hc_leave_fwd`` / ``hc_leave_bwd`` (the write-back
+and the stream mix); under a data-parallel mesh on each device's rows, in
+a manual region. Every other shape (tiny test widths, a mesh that shards
+anything but the batch) keeps the ``jax.numpy`` form below, which is also
+what the kernels are held to in the tests. The choice is by shape alone.
+
 Scopes: ``hc`` around everything here (inside ``block``, beside
 ``attention`` and ``mlp``, not around them), ``hc_coeff`` (the norm, the
-``phi`` product, Sinkhorn) and ``hc_mix`` (the three mixes) inside it; the
+``phi`` product, Sinkhorn; on the kernels' path ``hc_enter_*``, which also
+hold the read) and ``hc_mix`` (the three mixes; on the kernels' path
+``hc_leave_*``: the write-back and the stream mix alone) inside it; the
 model leaves one point event ``hc.plan`` a trace (``TransformerLM``,
 docs/design/observability.md).
 """
+import collections
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
+from autodist_tpu.const import AXIS_DATA, AXIS_SEQUENCE
+from autodist_tpu.kernels import hyper_connections as hc_kernels
 from autodist_tpu.models.core import Module, ParamDef
+from autodist_tpu.parallel.axes import (active_manual_axes, current_mesh,
+                                        manual_axis, shard_map,
+                                        unsharded_execution)
 
 ALPHA_INIT = 0.01
 RES_INIT = 8.0       # H_res's diagonal over its off-diagonal, as a logit
@@ -75,6 +95,16 @@ def split_streams(x, n):
     return [x[..., i * d:(i + 1) * d].astype(jnp.float32) for i in range(n)]
 
 
+# What the kernels' `enter` holds for `leave`: ``H_post | H_res`` as ``[n (n
+# + 1), rows]`` f32 (the tokens on the lanes) and the streams as `enter`
+# read them. `leave` reads THESE streams, so that the two cotangents of
+# ``x`` meet in ``hc_enter_bwd`` and are added on its block.
+_KernelHeld = collections.namedtuple('_KernelHeld', 'coefficients streams')
+# where a device's rows are, under a data-parallel mesh: the leading axis
+# of the streams, the trailing one of the held coefficients
+_ROWS, _HELD = P(AXIS_DATA), P(None, AXIS_DATA)
+
+
 class HyperConnection(Module):
     """One sublayer's connection over ``streams`` streams of ``dim``
     (the module's docstring), in two parts around the sublayer, which
@@ -82,7 +112,7 @@ class HyperConnection(Module):
     ``u, held = enter(params, x)``, ``y = F(u)``, ``x', err = leave(x, y,
     held)``; ``err`` is the mean over the tokens of ``max_j |sum_i H_res[i,
     j] - 1|`` (how far the rounds are from converged at these weights; no
-    gradient)."""
+    gradient). ``held`` is :meth:`leave`'s alone to read."""
 
     def __init__(self, dim, streams, iters=20, clamp=(-30.0, 30.0),
                  eps=1e-6, dtype=jnp.float32):
@@ -147,19 +177,70 @@ class HyperConnection(Module):
              + post[i][..., None] * y).astype(x.dtype)
             for i in range(self.streams)], axis=-1)
 
+    def kernel_plan(self, shape, dtype):
+        """``kernels/hyper_connections.plan`` for the rows a device holds
+        of streams ``[b, s, n dim]`` in the current trace, or None where
+        the connection runs in ``jax.numpy``: a shape the kernels do not
+        take, or a mesh that shards anything but the batch."""
+        b, s, _ = shape
+        if dtype != self.dtype or manual_axis(AXIS_SEQUENCE) is not None:
+            return None
+        if not unsharded_execution():
+            mesh = current_mesh()
+            dp = mesh.shape.get(AXIS_DATA, 1)
+            others = [a for a, size in mesh.shape.items()
+                      if size > 1 and a != AXIS_DATA]
+            if active_manual_axes() or others or b % dp:
+                return None
+            b //= dp
+        return hc_kernels.plan(b * s, self.streams, self.dim, dtype,
+                               self.iters)
+
+    @staticmethod
+    def _on_each_device(kernels, operands, in_specs, out_specs):
+        """``kernels(*operands)``; under a data-parallel mesh on each
+        device's rows, in a manual region (``MultiHeadAttention.
+        _qk_normed``'s)."""
+        mesh = None if unsharded_execution() else current_mesh()
+        if mesh is None:
+            return kernels(*operands)
+        return shard_map(kernels, mesh, in_specs, out_specs)(*operands)
+
     def enter(self, params, x):
-        """``(u, held)``: what the sublayer reads, ``[b, s, dim]``, and the
-        coefficients :meth:`leave` writes its output back with."""
+        """``(u, held)``: what the sublayer reads, ``[b, s, dim]``, and
+        what :meth:`leave` writes its output back with."""
         with jax.named_scope('hc'):
-            pre, post, res = self.coefficients(params, x)
-            return self.read(x, pre), (post, res)
+            if self.kernel_plan(x.shape, x.dtype) is None:
+                pre, post, res = self.coefficients(params, x)
+                return self.read(x, pre), (post, res)
+
+            def kernels(x, phi, alpha, bias):
+                return hc_kernels.enter(x, phi, alpha, bias, self.streams,
+                                        self.iters, self.clamp, self.eps)
+            with jax.named_scope('hc_coeff'):
+                u, coefficients, x = self._on_each_device(
+                    kernels, (x, params['phi'].astype(self.dtype),
+                              params['alpha'], params['bias']),
+                    (_ROWS, P(), P(), P()), (_ROWS, _HELD, _ROWS))
+            return u, _KernelHeld(coefficients, x)
 
     def leave(self, x, y, held):
         """``(x', err)`` from the streams ``x`` the sublayer read and its
         output ``y [b, s, dim]``."""
-        post, res = held
         with jax.named_scope('hc'):
-            x = self.write(x, y, post, res)
+            if isinstance(held, _KernelHeld):
+                def kernels(x, y, coefficients):
+                    return hc_kernels.leave(x, y, coefficients, self.streams,
+                                            self.iters)
+                with jax.named_scope('hc_mix'):
+                    x = self._on_each_device(
+                        kernels, (held.streams, y, held.coefficients),
+                        (_ROWS, _ROWS, _HELD), _ROWS)
+                n = self.streams
+                res = held.coefficients[n:].reshape(n, n, -1)
+            else:
+                post, res = held
+                x = self.write(x, y, post, res)
             with jax.named_scope('hc_coeff'):
                 err = jax.lax.stop_gradient(jnp.mean(jnp.max(
                     jnp.abs(jnp.sum(res, axis=0) - 1.0), axis=0)))

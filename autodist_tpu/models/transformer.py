@@ -653,9 +653,16 @@ class TransformerLM(Module):
         if self.cfg.embed_norm:
             x = self.ln_embed.apply(params['ln_embed'], x)
         if self.cfg.hc_streams:
-            # the residual streams' entry: every stream the embedding
-            x = jnp.concatenate([x] * self.cfg.hc_streams, axis=-1)
+            x = self._entered(x)
         return constrain(x, ('batch', 'seq', 'embed'))
+
+    @jax.named_scope('embed')
+    def _entered(self, row):
+        """The residual streams' entry: every stream the embedding
+        ``row [b, s, dim]``."""
+        return constrain(
+            jnp.concatenate([row] * self.cfg.hc_streams, axis=-1),
+            ('batch', 'seq', 'embed'))
 
     def _position_tables(self, x):
         """``tables(block)``: the rotary positions' ``(cos, sin)`` that
@@ -680,11 +687,17 @@ class TransformerLM(Module):
             return made[kind]
         return tables
 
-    def _block_fn(self, block=None, tables=None, stats=False):
+    def _block_fn(self, block=None, tables=None, stats=False, enters=False):
         """Single-block apply (``block``: the plain model's by default;
         ``tables``: its ``_position_tables``, which it closes over;
         ``stats``: the expert layers' load beside ``aux``) with the
-        remat policy applied.
+        remat policy applied. ``enters``: the block takes the first of
+        the residual streams and makes the streams from it
+        (:meth:`_entered`) INSIDE its checkpoint: what the step keeps of
+        this layer's input is then the row and not ``hc_streams`` copies
+        of it (the connections' kernels take whole arrays, so XLA no
+        longer makes the copies again by cloning a fusion: 0.16 GB in
+        Xing4.0's cell, ``PERF.md`` section 6, PR 51).
 
         ``cfg.remat``: False (no remat), True (recompute the block in
         the backward, all but the flash kernel's forward call: the
@@ -706,6 +719,11 @@ class TransformerLM(Module):
             block_fn = functools.partial(block_fn, tables=tables)
         if stats:
             block_fn = functools.partial(block_fn, stats=True)
+        if enters:
+            on_streams = block_fn
+
+            def block_fn(layer_params, row):
+                return on_streams(layer_params, self._entered(row))
         if isinstance(cfg.remat, str):
             policies = {
                 'save_attn':
@@ -763,7 +781,7 @@ class TransformerLM(Module):
             aux_total = (aux_total, jnp.zeros(
                 (3 if cfg.hc_streams else 2,), jnp.float32))
         self._note_layers()
-        self._note_streams()
+        self._note_streams(x)
         self._note_remat(x)
         if pipe_axis is not None:
             self._check_pipelined()
@@ -792,8 +810,12 @@ class TransformerLM(Module):
                 body, (x, aux_total), params['blocks'])
         else:
             for i, block in enumerate(self._lead_blocks):
-                x, a = self._block_fn(block, tables(block), stats)(
-                    params['block_%03d' % i], x)
+                # (at the entry every stream is the embedding row:
+                # `_embedded`)
+                enters = bool(cfg.hc_streams) and i == 0
+                x, a = self._block_fn(block, tables(block), stats, enters)(
+                    params['block_%03d' % i],
+                    x[..., :cfg.dim] if enters else x)
                 aux_total = _add(aux_total, a)
             if cfg.scan_layers and not cfg.mixers:
                 x, aux_total = self._scan_periods(params['blocks'], x,
@@ -935,18 +957,26 @@ class TransformerLM(Module):
             dense_lead=cfg.dense_lead,
             expert_layers=self._expert_layers(), **single)
 
-    def _note_streams(self):
+    def _note_streams(self, x):
         """One ``hc.plan`` point event a trace of a model with residual
-        streams: the connection as it runs
-        (``models/hyper_connections.py``)."""
+        streams: the connection as it runs on the streams ``x [b, s, n
+        dim]`` (``models/hyper_connections.py``): ``path`` the kernels'
+        (``'pallas'``, with the rows a grid step holds, the rows a pass of
+        its body computes and the calls' VMEM limit) or ``jax.numpy``'s
+        (``'xla'``, the three ``None``)."""
         cfg = self.cfg
         if not cfg.hc_streams:
             return
+        # (every connection of the stack is built alike)
+        how = self.block.hc_attn.kernel_plan(x.shape, x.dtype)
         telemetry.get().loop_event(
             'hc.plan', streams=cfg.hc_streams, iters=cfg.hc_iters,
             clamp=list(cfg.hc_clamp), eps=cfg.hc_eps,
             layout='streams [b, s, n dim]; coefficients [n (n + 2), b, s]',
-            path='xla')
+            path='pallas' if how else 'xla',
+            block_rows=how.block_rows if how else None,
+            sub_rows=how.sub_rows if how else None,
+            vmem_limit_bytes=how.vmem_limit_bytes if how else None)
 
     def _note_remat(self, x):
         """One ``transformer.remat`` point event a trace under

@@ -238,6 +238,38 @@ def test_the_carry_and_the_scopes_follow_the_streams(streams):
             assert wrong not in text, wrong
 
 
+def test_the_first_unrolled_layer_makes_its_streams_inside_its_checkpoint():
+    """Where the stack's first layer runs unrolled, its checkpoint takes
+    the embedding row ``[b, s, dim]`` and makes the streams inside
+    (``_block_fn(enters=True)``): the step keeps the row for that layer's
+    backward, not ``hc_streams`` copies of it; every later layer's takes
+    the streams. The loss and the gradients are those of the streams made
+    outside."""
+    model = TransformerLM(tiny(scan_layers=False, n_layers=2, remat=True))
+    params = drawn(model.init(jax.random.PRNGKey(0)))
+    batch = {k: jnp.asarray(np.random.RandomState(i).randint(
+        0, 256, (2, 32))) for i, k in enumerate(('tokens', 'targets'))}
+    taken = [[v.aval.shape for v in eqn.invars
+              if hasattr(v.aval, 'shape') and len(v.aval.shape) == 3]
+             for eqn in jax.make_jaxpr(model.loss)(params, batch).eqns
+             if eqn.primitive.name in ('checkpoint', 'remat2')]
+    assert len(taken) == 2
+    assert (2, 32, DIM) in taken[0] and (2, 32, N * DIM) not in taken[0]
+    assert (2, 32, N * DIM) in taken[1]
+    got = jax.value_and_grad(model.loss)(params, batch)
+    outside = TransformerLM(tiny(scan_layers=False, n_layers=2, remat=True))
+    inside = outside._block_fn
+
+    def streams_made_outside(block, tables, stats, enters=False):
+        fn = inside(block, tables, stats)
+        return (lambda p, row: fn(p, outside._entered(row))) if enters \
+            else fn
+    outside._block_fn = streams_made_outside
+    want = jax.value_and_grad(outside.loss)(params, batch)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
 def test_streams_refuse_what_carries_one_stream():
     with pytest.raises(ValueError, match='hc_streams=4.*mixers'):
         TransformerConfig.tiny(n_layers=2, mixers='E*', hc_streams=4)
@@ -279,6 +311,9 @@ def test_events_and_the_counter_of_a_training_step():
     plan = events['hc.plan'][0]
     assert (plan['streams'], plan['iters'], plan['clamp'], plan['eps'],
             plan['path']) == (N, 20, [-30.0, 30.0], 1e-6, 'xla')
+    # streams of 32 lanes: no kernels, and none of their tags
+    assert (plan['block_rows'], plan['sub_rows'],
+            plan['vmem_limit_bytes']) == (None, None, None)
     assert 'n dim' in plan['layout']
     assert len(events['hc.plan']) == 1          # once a trace
     layers = events['transformer.layers'][0]
@@ -291,6 +326,49 @@ def test_events_and_the_counter_of_a_training_step():
     assert 0 <= float(metrics['hc_res_col_sum_err']) < 1e-2
     assert {'moe_rows_here', 'moe_load_max', 'moe_load_mean'} <= set(metrics)
     # and the streams train: the loss falls on one batch
+    first = float(metrics['loss'])
+    for _ in range(4):
+        state, metrics = tr.step(state, batch)
+    assert float(metrics['loss']) < first
+
+
+def test_events_of_a_training_step_on_a_shape_the_kernels_take():
+    """Streams of 128 lanes and 128 rows a device: ``hc.plan`` says
+    ``'pallas'`` with the kernels' plan, once a trace; the four calls are
+    in the lowered step inside the scopes their metrics read; the counter
+    is the held ``H_res``'s and the loss falls."""
+    from autodist_tpu.kernels import hyper_connections as hk
+    cfg = TransformerConfig.tiny(
+        dim=128, n_heads=4, n_layers=2, positions='rotary', norm='rms',
+        gated_mlp=True, gelu='silu', mlp_bias=False, tied_embeddings=False,
+        mlp_dim=48, hc_streams=2, remat=True, max_len=64)
+    model = TransformerLM(cfg)
+    tr = Trainer(model, optax.adamw(1e-3), spec=ParallelSpec(dp=1))
+    state = tr.init(None, params=drawn(model.init(jax.random.PRNGKey(0))))
+    batch = {k: np.random.RandomState(i).randint(0, 256, (2, 64)).astype(
+        np.int32) for i, k in enumerate(('tokens', 'targets'))}
+    t_before = time.perf_counter()
+    state, metrics = tr.step(state, batch)
+    plans = [r['tags'] for r in telemetry.get().loop_records()
+             if r['t0'] >= t_before and r['name'] == 'hc.plan']
+    assert len(plans) == 1                      # once a trace
+    how = hk.plan(2 * 64, 2, 128, cfg.dtype)
+    assert (plans[0]['path'], plans[0]['block_rows'], plans[0]['sub_rows'],
+            plans[0]['vmem_limit_bytes']) == (
+        'pallas', 128, 64, how.vmem_limit_bytes)
+    assert (plans[0]['streams'], plans[0]['iters']) == (2, 20)
+    text = jax.jit(jax.grad(model.loss)).lower(
+        state.params, {k: jnp.asarray(v) for k, v in batch.items()}
+    ).as_text(debug_info=True)
+    for call in ('hc/hc_coeff/jit(_enter_fwd_call)',
+                 'hc/hc_coeff/jit(_enter_bwd_call)',
+                 'hc/hc_mix/jit(_leave_fwd_call)',
+                 'hc/hc_mix/jit(_leave_bwd_call)'):
+        assert call in text, call
+    for name in ('hc_enter_fwd', 'hc_enter_bwd', 'hc_leave_fwd',
+                 'hc_leave_bwd'):
+        assert name + '/pallas_call' in text, name
+    assert 0 <= float(metrics['hc_res_col_sum_err']) < 1e-2
     first = float(metrics['loss'])
     for _ in range(4):
         state, metrics = tr.step(state, batch)

@@ -336,8 +336,11 @@ def test_xing4_stack_lowers_with_its_streams_side_by_side():
     TPU (PR 48): the three latent flash calls are there by name and read q
     from the up-projection's output where it lies; the layers carry the
     streams as ``[b, s, 4 * 3584]`` and no tensor of the step has a stream
-    axis of 4 between the positions and the lanes; the coefficients' work
-    is ``[., b, s]``, tokens last, and none of it ``[b, s, 4, 4]``."""
+    axis of 4 between the positions and the lanes; the connections are the
+    four kernels of ``kernels/hyper_connections.py`` by name (PR 51), on
+    the streams where they lie and with no f32 copy of them; what they
+    hold between a sublayer's two halves is ``[n (n + 1), b s]``, tokens
+    last, and nothing is ``[b, s, 4, 4]``."""
     import re
 
     import jax
@@ -348,6 +351,7 @@ def test_xing4_stack_lowers_with_its_streams_side_by_side():
     from autodist_tpu.api import Trainer
     from autodist_tpu.kernels import flash_attention as fa
     from autodist_tpu.kernels import grouped_matmul as gm
+    from autodist_tpu.kernels import hyper_connections as hk
     from autodist_tpu.models.transformer import (TransformerConfig,
                                                  TransformerLM)
     from autodist_tpu.parallel.axes import ParallelSpec
@@ -374,6 +378,7 @@ def test_xing4_stack_lowers_with_its_streams_side_by_side():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(fa, '_interpret_default', lambda: False)
         patch.setattr(gm, '_interpret_default', lambda: False)
+        patch.setattr(hk, '_interpret_default', lambda: False)
         step = tr._ensure_step(tr._step_key(batch), state, batch)
         shapes = jax.tree.map(
             lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype,
@@ -383,6 +388,9 @@ def test_xing4_stack_lowers_with_its_streams_side_by_side():
             state, shapes).mlir_module()
     names = set(re.findall(r'kernel_name = "(\w+)"', text))
     assert {'flash_fwd_mla', 'flash_dq_mla', 'flash_dkv_mla'} <= names
+    assert {'hc_enter_fwd', 'hc_leave_fwd', 'hc_leave_bwd',
+            'hc_enter_bwd'} <= names
+    assert not re.search(r'%dx%dxf32' % (b * s, n * d), text)
     flash_calls = [line for line in text.splitlines()
                    if '@tpu_custom_call' in line and 'flash_' in line]
     assert len(flash_calls) == 2 * 3
@@ -394,8 +402,8 @@ def test_xing4_stack_lowers_with_its_streams_side_by_side():
             % ((b, s) * 4)), line
     tensors = set(re.findall(r'tensor<([0-9x]+)x(?:bf16|f32|i32|i1)>', text))
     assert '%dx%dx%d' % (b, s, n * d) in tensors
-    assert '%dx%dx%d' % (n, b, s) in tensors            # H_pre, H_post
-    assert '%dx%dx%dx%d' % (n, n, b, s) in tensors      # a Sinkhorn round
+    assert '%dx%d' % (n * (n + 1), b * s) in tensors    # H_post | H_res
+    assert '%dx%dx%dx%d' % (n, n, b, s) not in tensors  # no round under XLA
     wrong = [t for t in tensors if re.search(
         r'x%dx(%d|%d)$|(^|x)%dx%dx%d$' % (n, d, n, s, n, n), t)]
     assert not wrong, wrong
